@@ -703,14 +703,14 @@ class FFModel:
 
         num_devices = len(devices) if devices is not None else cfg.resolve_num_devices()
 
-        # compiled-step persistence half of the artifact store: point
-        # XLA's cache under the store root BEFORE anything jit-executes
-        # so a restarted replica re-loads executables instead of
-        # recompiling (store/, docs/STORE.md)
-        if cfg.compilation_cache:
-            from .store import enable_compilation_cache
+        # compiled-step persistence half of the artifact store: settle
+        # where XLA's cache lives BEFORE anything jit-executes so a
+        # restarted process re-loads executables instead of recompiling
+        # (env var > config > on-by-default on accelerators; store/,
+        # docs/STORE.md)
+        from .store import enable_compilation_cache
 
-            enable_compilation_cache(cfg)
+        enable_compilation_cache(cfg)
 
         if strategy is None and cfg.import_strategy_file:
             strategy = Strategy.load(cfg.import_strategy_file)

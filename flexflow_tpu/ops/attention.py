@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..parallel.shard_map_compat import shard_map as _shard_map
-
 from ..fftype import DataType, OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT, GlorotUniform
 from ..tensor import ParallelDim, ParallelTensorShape
@@ -540,7 +538,7 @@ class MultiHeadAttention(Op):
             batch_spec, _, head_spec = self._view_specs()
             qspec = PartitionSpec(batch_spec, None, head_spec, None)
             pool_spec = PartitionSpec(None, None, head_spec, None)
-            ctx = _shard_map(
+            ctx = jax.shard_map(
                 lambda q_, k_, v_, bt_, ps_: paged_attention(
                     q_, k_, v_, bt_, ps_, scale),
                 mesh=mesh,
@@ -676,7 +674,7 @@ class MultiHeadAttention(Op):
         batch_spec, _, head_spec = self._view_specs()
         spec = PartitionSpec(batch_spec, None, head_spec, None)
         fn = functools.partial(mha_flash, scale=scale, causal=p.causal)
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(qh, kh, vh)

@@ -7,11 +7,20 @@ reference oracle) materializes a dense ``[slots, decode_max_seq, h, d]``
 K/V view from the block pool every step, so per-step HBM traffic is
 proportional to the TABLE WIDTH regardless of how many tokens are
 actually live.  This kernel instead makes the block table part of the
-kernel's index maps: grid ``(slots, heads, table_width)`` with the
-table and the per-slot sequence lengths as SCALAR-PREFETCH operands,
-so the K/V BlockSpecs resolve ``(block_table[i, kb], 0, h, 0)`` —
-Pallas's pipeline DMAs exactly the physical pages a row owns, straight
-from the pool's HBM layout, no dense view ever exists.
+kernel's index maps: grid ``(slots, table_width)`` with the table and
+the per-slot sequence lengths as SCALAR-PREFETCH operands, so the K/V
+BlockSpecs resolve ``(block_table[i, kb], 0, 0, 0)`` — Pallas's
+pipeline DMAs exactly the physical pages a row owns, straight from the
+pool's HBM layout, no dense view ever exists.
+
+Block shapes (what Mosaic accepts for the pool layout
+``[num_blocks, page, h, d]``): one grid step takes ALL local heads of
+one physical page — K/V blocks ``(1, page, h, d)``, whose last two
+dims equal the array's (the TPU rule: divisible by (8, 128) or equal
+to the full dim; a per-head ``(1, page, 1, d)`` block is refused for
+every h > 1).  Under `--serving-tp` the shard_map'd local pool is
+``[nb, page, h/tp, d]`` and the same rule holds.  In VMEM the page is
+swapped to ``[h, page, d]`` and both dots are batched over heads.
 
 Traffic discipline: a row with ``pos`` tokens live owns
 ``pos // page + 1`` blocks.  Grid steps past that are mapped to the
@@ -34,13 +43,13 @@ Two entry points mirror the host-side twins (decoding.py):
     bytes, byte-identical to the oracle's), the kernel absorbs the
     read side.
 
-Both accumulate the online softmax in f32 (m/l running rows + an
-[s, d] accumulator in VMEM scratch carried across the kb grid axis),
-like ops/pallas/flash_attention.py.  Off-TPU the same kernel runs
-under ``interpret=True`` — the CPU parity tests
+Both accumulate the online softmax in f32 (m/l running columns + an
+[h, s, d] accumulator in VMEM scratch carried across the kb grid
+axis), like ops/pallas/flash_attention.py.  On the CPU backend the
+same kernel runs under ``interpret=True`` — the parity tests
 (tests/test_paged_kernel.py) execute the real kernel logic against
-the gather oracle, the `_HAVE_PALLAS` / fallback discipline follows
-the flash_attention precedent.
+the gather oracle; on TPU it is always compiled by Mosaic, never
+interpreted.
 """
 from __future__ import annotations
 
@@ -99,10 +108,11 @@ def blocks_read(seq_lens: np.ndarray, live_mask: np.ndarray, chunk: int,
 def _paged_kernel(btab_ref, slen_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page: int, scale: float,
                   table_width: int, chunk: int):
-    """One grid program = (row i, head h, table column kb): fold the
-    physical page `block_table[i, kb]` into row i's online softmax."""
+    """One grid program = (row i, table column kb): fold the physical
+    page `block_table[i, kb]` — all heads of it — into row i's online
+    softmax."""
     i = pl.program_id(0)
-    kb = pl.program_id(2)
+    kb = pl.program_id(1)
 
     @pl.when(kb == 0)
     def _init():
@@ -115,41 +125,37 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(kb < live)
     def _fold():
-        q = q_ref[0, 0]        # [chunk, dk] — this head's queries
-        k = k_ref[0, :, 0, :]  # [page, dk]  — one physical page
-        v = v_ref[0, :, 0, :]  # [page, dv]
+        q = q_ref[0]                      # [h, chunk, dk]
+        k = jnp.swapaxes(k_ref[0], 0, 1)  # [page, h, dk] -> [h, page, dk]
+        v = jnp.swapaxes(v_ref[0], 0, 1)  # [h, page, dv]
         if k.dtype != q.dtype:  # VMEM-tile cast (bf16 query, f32 pool)
             k = k.astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [chunk, page] f32
+        s = jnp.einsum(
+            "hsd,hpd->hsp", q, k, preferred_element_type=jnp.float32,
+        ) * scale  # [h, chunk, page] f32
         # chunk token j attends key positions <= pos + j: causal within
         # the chunk, visible-prefix across steps — exactly the gather
         # oracle's mask, so partial tail blocks and scratch rows
         # (pos 0, all-zero table) fall out of the same comparison
-        k_pos = kb * page + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, page), 1)
-        q_pos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, page), 0)
+        k_pos = kb * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]               # [h, chunk, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "hsp,hpd->hsd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32,
         )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        m_ref[...] = m_new
 
     @pl.when(kb == table_width - 1)
     def _write():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         l_safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
@@ -165,10 +171,17 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
                  already scattered into the pool by the caller)
     ->           [b, s, h, dv] context, qh's dtype
 
-    `interpret` defaults to running the real TPU kernel on TPU and the
-    Pallas interpreter elsewhere (the CPU parity-test vehicle)."""
+    `interpret` defaults from the backend: the Mosaic-compiled kernel
+    on TPU, the Pallas interpreter on CPU (the parity-test vehicle).
+    Interpreting on TPU is refused — a run that asked for the kernel
+    must never quietly time the interpreter."""
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "paged_attention(interpret=True) on the TPU backend: the "
+            "kernel must run compiled there")
     b, s, h, dk = qh.shape
     page, dv = k_pool.shape[1], v_pool.shape[-1]
     table_width = block_table.shape[1]
@@ -176,27 +189,28 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
     block_table = block_table.astype(jnp.int32)
     seq_lens = seq_lens.reshape(b).astype(jnp.int32)
 
-    def kv_map(i, hh, kb, btab, slen):
+    def q_map(i, kb, btab, slen):
+        return i, 0, 0, 0
+
+    def kv_map(i, kb, btab, slen):
         # out-of-range kb repeats the row's last live block: Pallas
         # elides the re-fetch, so HBM traffic follows live tokens
         live = _live_block_count(slen[i], s, page, table_width)
-        return btab[i, jnp.minimum(kb, live - 1)], 0, hh, 0
+        return btab[i, jnp.minimum(kb, live - 1)], 0, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, table_width),
+        grid=(b, table_width),
         in_specs=[
-            pl.BlockSpec((1, 1, s, dk),
-                         lambda i, hh, kb, btab, slen: (i, hh, 0, 0)),
-            pl.BlockSpec((1, page, 1, dk), kv_map),
-            pl.BlockSpec((1, page, 1, dv), kv_map),
+            pl.BlockSpec((1, h, s, dk), q_map),
+            pl.BlockSpec((1, page, h, dk), kv_map),
+            pl.BlockSpec((1, page, h, dv), kv_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, s, dv), lambda i, hh, kb, btab, slen: (i, hh, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, s, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((s, 128), jnp.float32),  # running max
-            pltpu.VMEM((s, 128), jnp.float32),  # running denominator
-            pltpu.VMEM((s, dv), jnp.float32),   # context accumulator
+            pltpu.VMEM((h, s, 1), jnp.float32),   # running max
+            pltpu.VMEM((h, s, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((h, s, dv), jnp.float32),  # context accumulator
         ],
     )
     out = pl.pallas_call(
@@ -205,6 +219,7 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, dv), qh.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_table, seq_lens, qt, k_pool, v_pool)
     return out.transpose(0, 2, 1, 3)
 

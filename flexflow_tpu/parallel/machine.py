@@ -20,11 +20,14 @@ the reference similarly filters views, graph.h:205-210).
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+_log = logging.getLogger("flexflow_tpu.parallel")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,7 +173,13 @@ def make_mesh(
     """Build a named mesh over the given devices (default: all).
 
     On real TPU slices `jax.experimental.mesh_utils` picks an ICI-friendly
-    device order; on CPU test meshes plain reshape is fine.
+    device order; on CPU test meshes plain reshape is fine.  On the 2x2
+    v5e host mesh_utils accepts every factorisation of all four chips
+    but refuses device SUBSETS that are not a whole sub-box of the torus
+    (AssertionError) — which elastic recompiles on survivors and tp
+    replicas on a device pair legitimately pass.  Those fall back to
+    enumeration order with a warning that names the refusal, never
+    silently.
     """
     names = tuple(axis_sizes.keys())
     sizes = tuple(axis_sizes.values())
@@ -181,13 +190,20 @@ def make_mesh(
         raise ValueError(f"need {n} devices for mesh {axis_sizes}, have {len(devices)}")
     devices = list(devices)[:n]
     if devices and devices[0].platform == "tpu" and n > 1:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(sizes, devices=devices)
-            return Mesh(dev_array, names)
-        except Exception:
-            pass
+        try:
+            return Mesh(
+                mesh_utils.create_device_mesh(sizes, devices=devices), names
+            )
+        except (AssertionError, NotImplementedError, ValueError) as e:
+            _log.warning(
+                "mesh_utils.create_device_mesh refused mesh %s over "
+                "devices %s (%s: %s); using enumeration order — "
+                "collectives may cross more ICI hops than necessary",
+                dict(axis_sizes), [d.id for d in devices],
+                type(e).__name__, e,
+            )
     dev_array = np.asarray(devices).reshape(sizes)
     return Mesh(dev_array, names)
 

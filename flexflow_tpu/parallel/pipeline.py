@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .shard_map_compat import shard_map as _shard_map
-
 
 def gpipe(
     stage_fn: Callable,
@@ -228,7 +226,7 @@ def pipelined_apply(
         lambda a: P(pp_axis, *([None] * (a.ndim - 1))), stacked_params
     )
     in_x = P(dp_axis, *([None] * (x.ndim - 1)))
-    return _shard_map(
+    return jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(param_specs, in_x),
@@ -393,7 +391,7 @@ def make_pipelined_transformer_step(
 
     @jax.jit
     def ofob_step(params, x, y):
-        loss, grads = _shard_map(
+        loss, grads = jax.shard_map(
             spmd_1f1b, mesh=mesh,
             in_specs=(param_specs, in_x, in_y),
             out_specs=(P(), param_specs),

@@ -18,8 +18,6 @@ import functools
 from typing import Optional
 
 import jax
-
-from .shard_map_compat import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
@@ -268,7 +266,7 @@ def _ring_flash_trainable(qh, kh, vh, mesh, seq_axis, spec, sp, scale,
 
 def _ring_flash_trainable_fwd(qh, kh, vh, mesh, seq_axis, spec, sp,
                               scale, causal, interpret):
-    out, lse = _shard_map(
+    out, lse = jax.shard_map(
         functools.partial(_ring_flash_fwd_sharded, axis_name=seq_axis,
                           sp=sp, scale=scale, causal=causal,
                           interpret=interpret),
@@ -283,7 +281,7 @@ def _ring_flash_trainable_bwd(mesh, seq_axis, spec, sp, scale, causal,
                               interpret, res, dout):
     qh, kh, vh, out, lse = res
     lse_spec = PartitionSpec(spec[0], spec[2], seq_axis)
-    dq, dk, dv = _shard_map(
+    dq, dk, dv = jax.shard_map(
         functools.partial(_ring_flash_bwd_sharded, axis_name=seq_axis,
                           sp=sp, scale=scale, causal=causal,
                           interpret=interpret),
@@ -352,7 +350,7 @@ def ring_attention(
         scale=scale,
         causal=causal,
     )
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

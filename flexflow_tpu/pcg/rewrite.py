@@ -418,13 +418,13 @@ def default_substitution_catalog() -> Optional[str]:
     verdicts are disk-cached (taso._verified_verdicts), so the
     default-on load costs one JSON/pb parse after the first run.
 
-    Resolution order (first hit wins):
+    Resolution order (first hit wins) — nothing here depends on the
+    working directory or on files outside the checkout, so the
+    search's rule set is the same on every host that runs one commit:
       1. $FLEXFLOW_TPU_SUBSTITUTIONS — a catalog file path; set EMPTY
          to disable default-on entirely;
-      2. <repo-root>/substitutions/ then ./substitutions/ — first
-         graph_subst*.pb / graph_subst*.json;
-      3. a colocated reference checkout's shipped catalog (dev/CI
-         layout: /root/reference/substitutions/graph_subst_3_v2.pb).
+      2. <repo-root>/substitutions/ — first graph_subst*.pb /
+         graph_subst*.json.
     """
     import glob
     import os
@@ -434,14 +434,10 @@ def default_substitution_catalog() -> Optional[str]:
         return env or None
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    for d in (os.path.join(repo_root, "substitutions"), "substitutions"):
-        for pat in ("graph_subst*.pb", "graph_subst*.json"):
-            hits = sorted(glob.glob(os.path.join(d, pat)))
-            if hits:
-                return hits[0]
-    ref = "/root/reference/substitutions/graph_subst_3_v2.pb"
-    if os.path.exists(ref):
-        return ref
+    for pat in ("graph_subst*.pb", "graph_subst*.json"):
+        hits = sorted(glob.glob(os.path.join(repo_root, "substitutions", pat)))
+        if hits:
+            return hits[0]
     return None
 
 

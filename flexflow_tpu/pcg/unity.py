@@ -827,6 +827,14 @@ class UnitySearch:
         agg["term_hits"] = self._sim.term_hits
         agg["term_misses"] = self._sim.term_misses
         agg["op_cost_hits"] = getattr(self.cost_model, "cost_hits", 0)
+        # calibration provenance: costs timed on the live backend by
+        # this search vs read back from the persisted op-cost cache
+        agg["op_costs_measured"] = getattr(
+            self.cost_model, "measured_fresh", 0)
+        agg["op_costs_replayed"] = getattr(
+            self.cost_model, "measured_replayed", 0)
+        agg["calibration_seconds"] = getattr(
+            self.cost_model, "measure_seconds", 0.0)
         return agg
 
     def _stage_variants(self, strategy: Strategy, time: float,

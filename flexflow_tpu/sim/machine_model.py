@@ -40,20 +40,40 @@ V5E_DEVICE = DeviceSpec(
 )
 V5P_DEVICE = DeviceSpec()
 
+#: `jax.Device.device_kind`, verbatim as the chip reports it -> spec.
+#: "TPU v5 lite" is what a v5e answers (read on the chip, PR 21).  An
+#: accelerator missing here is an ERROR in detect_device_spec, never a
+#: default: a wrong roofline silently skews every search cost and MFU.
+DEVICE_SPECS: Dict[str, DeviceSpec] = {
+    "TPU v5 lite": V5E_DEVICE,
+}
+
+#: what the CPU backend is costed as — the hermetic search tests need
+#: SOME roofline and have always been priced on v5p peaks.  Never
+#: served to an accelerator.
+CPU_BACKEND_DEVICE = V5P_DEVICE
+
 
 def detect_device_spec() -> DeviceSpec:
-    """Spec for the LIVE accelerator by device_kind — the reference
+    """Spec for the LIVE backend by device_kind — the reference
     profiles the actual GPU (model.cu:38); calibrated analytic costs
-    need the actual chip's roofline too."""
-    try:
-        import jax
+    need the actual chip's roofline too.  A backend that cannot
+    initialise raises out of jax.devices(); an accelerator whose kind
+    is not in DEVICE_SPECS raises here."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return V5P_DEVICE
-    if "lite" in kind or "v5e" in kind:
-        return V5E_DEVICE
-    return V5P_DEVICE
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_BACKEND_DEVICE
+    spec = DEVICE_SPECS.get(dev.device_kind)
+    if spec is None:
+        raise ValueError(
+            f"no DeviceSpec for {dev.platform} device_kind "
+            f"{dev.device_kind!r} (known: {sorted(DEVICE_SPECS)}); add "
+            "its published peaks to sim/machine_model.py DEVICE_SPECS "
+            "— costing it as another chip would misprice every "
+            "strategy")
+    return spec
 
 
 class MachineModel:
@@ -288,8 +308,9 @@ class TpuPodModel(MachineModel):
 
 def make_machine_model(config, num_devices: int) -> MachineModel:
     """Build from FFConfig (--machine-model-version/-file parity).
-    Device roofline auto-matches the live chip (cpu -> v5p defaults,
-    keeping hermetic tests deterministic).  --slices > 1 selects the
+    Device roofline matches the live chip (detect_device_spec: the CPU
+    backend is costed as CPU_BACKEND_DEVICE, keeping hermetic tests
+    deterministic; an unknown accelerator raises).  --slices > 1 selects the
     multi-slice hierarchy (topology/hierarchy.py SliceHierarchy: ICI
     inside each slice, DCN between) regardless of model version — the
     hierarchy is what the searches must see; 1 slice is exactly the

@@ -27,6 +27,7 @@ peak live activations — feeding the memory-aware search
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -263,6 +264,11 @@ class OpCostModel:
         self.device_key = device_key
         self.measured_hits = 0  # cost() calls answered by a measurement
         self.cost_hits = 0      # cost() calls answered by the node_key cache
+        # where those measurements came from: timed on the live backend
+        # by THIS search, or read back from the persisted cache file
+        self.measured_fresh = 0
+        self.measured_replayed = 0
+        self.measure_seconds = 0.0  # wall time spent inside measure_fn
         self._persistent: Dict[str, float] = {}
         self._dirty = False
         if cache_path:
@@ -357,9 +363,13 @@ class OpCostModel:
             return None
         skey = f"v{self.MEASURE_CACHE_VERSION}|{self.device_key}|{key!r}"
         if skey in self._persistent:
+            self.measured_replayed += 1
             return self._persistent[skey]
+        t0 = time.perf_counter()
         measured = self.measure_fn(op)
+        self.measure_seconds += time.perf_counter() - t0
         if measured is not None:
+            self.measured_fresh += 1
             self._persistent[skey] = measured
             self._dirty = True
         return measured
@@ -409,13 +419,10 @@ def make_cost_model(cfg, machine: MachineModel) -> OpCostModel:
     if cfg.should_calibrate():
         from ..profiler import make_measure_fn
 
-        measure_fn = make_measure_fn()
-        try:
-            import jax
+        import jax
 
-            device_key = jax.devices()[0].device_kind
-        except Exception:
-            device_key = "unknown"
+        measure_fn = make_measure_fn()
+        device_key = jax.devices()[0].device_kind
         if cache_path is None:
             import os
 

@@ -11,13 +11,16 @@ makes that a first-class, content-addressed tier:
   * cached_search — the one consult-then-publish wrapper every search
     site uses: FFModel.compile, the resilience supervisor's elastic
     re-search, and (through compile) serving replica spin-up;
-  * enable_compilation_cache — JAX persistent compilation cache wired
-    under the store root, so the compiled step function itself
-    survives process death alongside the strategy that produced it.
+  * enable_compilation_cache — where JAX's persistent compilation
+    cache lives ($JAX_COMPILATION_CACHE_DIR, else the config, else
+    <checkout>/.jax_cache on accelerators), so the compiled step
+    function itself survives process death alongside the strategy
+    that produced it.
 
 Config surface: FFConfig.strategy_store / --strategy-store DIR /
 --no-strategy-store (or the FLEXFLOW_TPU_STORE_DIR env var for fleet
-deployments), FFConfig.compilation_cache / --compilation-cache [DIR].
+deployments), FFConfig.compilation_cache / --compilation-cache [DIR]
+(yields to $JAX_COMPILATION_CACHE_DIR).
 """
 from __future__ import annotations
 
@@ -101,50 +104,81 @@ def store_from_config(cfg, registry=None) -> Optional[StrategyStore]:
         return None
 
 
+#: jax's own env var for the persistent cache directory.  When it is
+#: set the operator (or the chip tool) has placed the cache from
+#: OUTSIDE: jax reads it itself and this package sets no directory.
+COMPILATION_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: accelerator default when neither the env var nor the config names a
+#: directory: <checkout>/.jax_cache, derived from this package's own
+#: location.  The path is part of what a later process must find again,
+#: so it is fixed — never a tempfile, a pid or a timestamp.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
 def enable_compilation_cache(cfg) -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    FFConfig.compilation_cache ('auto' = <store root>/xla_cache), so a
-    restarted process re-loads its XLA executables from disk instead of
-    recompiling.  Returns the cache dir, or None when off.  GLOBAL jax
-    config: the most recent compile's setting wins for the whole
-    process, so point every model in one process at the same cache
-    (content-addressed internally — sharing is safe; split dirs only
-    cost duplicate executables)."""
-    spec = cfg.compilation_cache
-    if not spec:
-        return None
-    if str(spec).strip().lower() == "auto":
-        root = resolve_store_dir(cfg)
-        if root is None:
-            raise ValueError(
-                "compilation_cache='auto' ties the XLA cache to the "
-                "strategy store root, but no store is configured — set "
-                f"--strategy-store/${STORE_DIR_ENV} or pass an explicit "
-                "--compilation-cache DIR"
-            )
-        path = os.path.join(root, "xla_cache")  # StrategyStore layout
-    else:
-        path = str(spec)
-    os.makedirs(path, exist_ok=True)
+    """Decide where JAX's persistent compilation cache lives for this
+    process, so a restarted process re-loads its XLA executables from
+    disk instead of recompiling.  Returns the cache dir, or None when
+    off.  Precedence:
+
+      1. $JAX_COMPILATION_CACHE_DIR set -> jax already reads it; NO
+         directory is set from code, and a disagreeing
+         FFConfig.compilation_cache is ignored with one log line;
+      2. FFConfig.compilation_cache = DIR, or 'auto' = <store
+         root>/xla_cache;
+      3. neither: ON at DEFAULT_COMPILATION_CACHE_DIR on an accelerator
+         backend (cold start is minutes there), off on the CPU backend.
+
+    GLOBAL jax config: the most recent compile's setting wins for the
+    whole process, so point every model in one process at the same
+    cache (content-addressed internally — sharing is safe; split dirs
+    only cost duplicate executables)."""
     import jax
 
-    try:
+    spec = cfg.compilation_cache
+    on_accelerator = jax.default_backend() != "cpu"
+    path = os.environ.get(COMPILATION_CACHE_ENV) or None
+    if path is not None:
+        if spec and os.path.abspath(str(spec)) != os.path.abspath(path):
+            store_logger.info(
+                "compilation_cache=%r ignored: $%s=%s places the XLA "
+                "cache for this process", spec, COMPILATION_CACHE_ENV,
+                path,
+            )
+    else:
+        if not spec:
+            if not on_accelerator:
+                return None
+            path = DEFAULT_COMPILATION_CACHE_DIR
+        elif str(spec).strip().lower() == "auto":
+            root = resolve_store_dir(cfg)
+            if root is None:
+                raise ValueError(
+                    "compilation_cache='auto' ties the XLA cache to the "
+                    "strategy store root, but no store is configured — "
+                    f"set --strategy-store/${STORE_DIR_ENV} or pass an "
+                    "explicit --compilation-cache DIR"
+                )
+            path = os.path.join(root, "xla_cache")  # StrategyStore layout
+        else:
+            path = str(spec)
+        os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        if jax.default_backend() not in ("cpu",):
-            # cache EVERY executable on accelerators: cold start is the
-            # point, and the store root is operator-provisioned space
-            # (gc via docs/STORE.md).  On the CPU backend keep jax's
-            # conservative defaults — force-caching sub-second CPU
-            # executables makes their deserialization path segfault
-            # (observed on jax 0.4.37 CPU meshes), and a CPU recompile
-            # is cheaper than the risk
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (AttributeError, ValueError) as e:  # older/newer jax knob drift
-        store_logger.info(
-            "jax persistent compilation cache tuning unavailable (%s); "
-            "cache dir still set where supported", e,
-        )
+    if on_accelerator:
+        # cache EVERY executable on accelerators: cold start is the
+        # point, and the cache dir is operator-provisioned space (gc
+        # via docs/STORE.md).  The CPU backend keeps jax's thresholds:
+        # the reload crash once seen on jax 0.4.37 does not reproduce
+        # on 0.9.0 (re-checked PR 21), but every reload of a
+        # force-cached CPU executable logs an XLA:CPU machine-feature
+        # mismatch and a CPU recompile is sub-second
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
@@ -190,6 +224,8 @@ def cached_search(model, num_devices: int,
 
 
 __all__ = [
+    "COMPILATION_CACHE_ENV",
+    "DEFAULT_COMPILATION_CACHE_DIR",
     "MANIFEST_VERSION",
     "STORE_DIR_ENV",
     "BlobNotFound",

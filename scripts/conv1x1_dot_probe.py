@@ -2,7 +2,9 @@
 XLA fuse a BN-stats reduction into the dot's epilogue (it cannot fuse
 into a conv custom-call)?  ResNet-50 b256 shapes, bf16, NHWC."""
 import sys, time
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 import jax.numpy as jnp

@@ -12,7 +12,9 @@ reduce), running at 290-520 GB/s vs the 819 peak.  Variants:
              no conv inside reduce fusions)
 """
 import sys
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -28,7 +30,7 @@ from flexflow_tpu.ops import norm as norm_mod
 from flexflow_tpu.ops.norm import BatchNormParams
 
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 B, px = leg["batch"], leg["px"]
 

@@ -8,7 +8,9 @@ Forward identical to base (precomputed scale/shift, one fused pass, no
 xhat materialization).  Backward: exactly two passes over (dy, x[, y]).
 """
 import sys, functools
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -24,7 +26,7 @@ from flexflow_tpu.ops import norm as norm_mod
 from flexflow_tpu.ops.norm import BatchNormParams
 
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 B, px = leg["batch"], leg["px"]
 

@@ -11,7 +11,9 @@ Plus a microbench: XLA fused bn-apply+relu+residual vs a Pallas
 single-pass kernel at representative resnet shapes.
 """
 import sys, time
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -76,7 +78,7 @@ for (m, c) in [(256*56*56, 256), (256*28*28, 512), (256*14*14, 1024), (256*7*7, 
 print("\n-- whole model --", flush=True)
 import bench
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.torch_frontend.model import PyTorchModel

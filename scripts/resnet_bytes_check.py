@@ -2,7 +2,9 @@
 cost_analysis is exact for static shapes): did the dot form let XLA fuse
 the BN stats pass into the GEMM (bytes drop ~4GB) or not?"""
 import sys
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,7 @@ from flexflow_tpu.ops import dense as dense_mod
 from flexflow_tpu.ops.dense import Conv2DParams, apply_activation
 
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 B, px = leg["batch"], leg["px"]
 

@@ -6,7 +6,9 @@ step at 94.5% of HBM peak: only removing passes can help).
 import sys
 import collections
 
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 
@@ -18,7 +20,7 @@ from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.torch_frontend.model import PyTorchModel
 
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 
 B, px = leg["batch"], leg["px"]

@@ -2,7 +2,9 @@
 the device trace for the top ops by self time (replaces byte-model
 guesswork with measured per-op time)."""
 import sys, glob, gzip, json, collections
-sys.path.insert(0, "/root/repo")
+import os
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 import numpy as np
 import jax
 
@@ -14,7 +16,7 @@ from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.torch_frontend.model import PyTorchModel
 
 leg = bench.MANIFEST["legs"]["resnet50"]
-sys.path.insert(0, "/root/repo/examples/python/pytorch")
+sys.path.insert(0, os.path.join(_REPO, "examples", "python", "pytorch"))
 from resnet50_search import ResNet50
 B, px = leg["batch"], leg["px"]
 

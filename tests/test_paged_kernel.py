@@ -211,7 +211,7 @@ def trained(devices8):
 def _collect_avals(jaxpr, acc):
     """Every intermediate aval in `jaxpr`, recursing into sub-jaxprs
     (pjit bodies, scan bodies, the pallas kernel jaxpr, ...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(val):
         if isinstance(val, ClosedJaxpr):

@@ -1,0 +1,262 @@
+"""What a CPU sandbox can hold the chip bring-up to (PR 21):
+
+  * every Pallas entry point chip_smoke.py reaches lowers for TPU at
+    the smoke's own shapes (`lowering_platforms=("tpu",)`), so a
+    block-shape refusal is caught here, not on the chip — and, where
+    libtpu's compile-only topology is available, also COMPILES for a
+    v5e (Mosaic included);
+  * the compile cache is placed from outside when
+    $JAX_COMPILATION_CACHE_DIR is set, at the fixed checkout path when
+    it is not, and an explicit directory is still honoured;
+  * an accelerator nobody has entered peaks for is an error, never a
+    default roofline;
+  * a flash shape the tiling cannot cover is visible on TPU.
+"""
+import importlib.util
+import os
+import tempfile
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from flexflow_tpu.config import FFConfig
+from flexflow_tpu.ops.pallas import flash_attention as fa
+from flexflow_tpu.ops.pallas import paged_attention as pk
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+chip_smoke = _load_chip_smoke()
+FULL = chip_smoke.FULL
+
+
+# -- (a) Pallas entry points at the smoke's shapes ----------------------
+
+def _flash_cases():
+    s = FULL["bert_long"]
+    bh, seq = s["batch"] * s["heads"], s["seq"]
+    d = s["hidden"] // s["heads"]
+    x = jax.ShapeDtypeStruct((bh, seq, d), jnp.dtype(FULL["dtype"]))
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+
+    def fwd(q, k, v):
+        return fa._flash_fwd_pallas(
+            q, k, v, 0.125, False, *fa._pick_blocks("fwd", seq, seq))
+
+    def bwd(q, k, v, o, lse, do):
+        return fa._flash_bwd_pallas(
+            q, k, v, o, lse, do, 0.125, False,
+            *fa._pick_blocks("dq", seq, seq),
+            dkv_blocks=fa._pick_blocks("dkv", seq, seq))
+
+    return [("flash_fwd", fwd, (x, x, x), 1),
+            ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)]
+
+
+def _paged_args(s, dtype, sharding=lambda *_: None):
+    slots, page, h, d, tw, nb = chip_smoke.paged_geometry(FULL)
+
+    def S(shape, dt, kind):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding(kind))
+
+    return (S((slots, s, h, d), dtype, "heads"),
+            S((nb, page, h, d), dtype, "heads"),
+            S((nb, page, h, d), dtype, "heads"),
+            S((slots, tw), jnp.int32, "rep"),
+            S((slots,), jnp.int32, "rep"))
+
+
+def _paged(q, k, v, bt, sl):
+    return pk.paged_attention(q, k, v, bt, sl, 0.125, interpret=False)
+
+
+def _paged_cases():
+    cases = []
+    for s in (1, FULL["chunk"]):
+        for dtype in (jnp.float32, jnp.bfloat16):
+            cases.append((f"paged_s{s}_{jnp.dtype(dtype).name}", _paged,
+                          _paged_args(s, dtype), 1))
+    return cases
+
+
+def _paged_tp2(mesh):
+    """The --serving-tp dispatch: shard_map over the head axis, each
+    shard sees the local [nb, page, h/2, d] pool."""
+    heads = P(None, None, "model", None)
+
+    def f(q, k, v, bt, sl):
+        return jax.shard_map(
+            _paged, mesh=mesh,
+            in_specs=(heads, heads, heads, P(None, None), P(None)),
+            out_specs=heads, check_vma=False)(q, k, v, bt, sl)
+
+    def sharding(kind):
+        return NamedSharding(mesh, heads if kind == "heads" else P())
+
+    return [(f"paged_tp2_s{s}", f,
+             _paged_args(s, jnp.bfloat16, sharding), 1)
+            for s in (1, FULL["chunk"])]
+
+
+def _lower(fn, args):
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def test_pallas_entry_points_lower_for_tpu(devices8):
+    mesh = Mesh(np.array(devices8[:2]).reshape(1, 2), ("data", "model"))
+    for name, fn, args, n_calls in (_flash_cases() + _paged_cases()
+                                    + _paged_tp2(mesh)):
+        text = _lower(fn, args).as_text()
+        assert text.count("tpu_custom_call") >= n_calls, name
+
+
+def test_pallas_entry_points_compile_for_v5e():
+    """Stronger than lowering: the TPU compiler itself (Mosaic, VMEM
+    allocation) accepts every kernel for a v5e 2x2 host.  Needs no
+    chip — libtpu's compile-only topology — and is skipped where that
+    cannot be created."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no topology
+        pytest.skip(f"no compile-only TPU topology here: {e!r}")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on(args, sh):
+        return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+                     for a in args)
+
+    for name, fn, args, _ in _flash_cases() + _paged_cases():
+        _lower(fn, on(args, one)).compile()
+    mesh = Mesh(np.array(topo.devices[:2]).reshape(1, 2),
+                ("data", "model"))
+    for name, fn, args, _ in _paged_tp2(mesh):
+        _lower(fn, args).compile()
+
+
+def test_paged_kernel_never_interpreted_on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jnp.zeros(a.shape, a.dtype) for a in
+            _paged_args(1, jnp.float32)]
+    with pytest.raises(ValueError, match="must run compiled"):
+        pk.paged_attention(*args, 0.125, interpret=True)
+
+
+def test_flash_unsupported_shape_is_visible_on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ok = jax.ShapeDtypeStruct((4, 2048, 64), jnp.bfloat16)
+    odd = jax.ShapeDtypeStruct((4, 2000, 64), jnp.bfloat16)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert fa._use_pallas(ok, ok)
+    with pytest.warns(UserWarning, match=r"no Pallas tiling.*2000"):
+        assert not fa._use_pallas(odd, odd)
+
+
+# -- (b) compile-cache precedence ---------------------------------------
+
+class _ConfigRecorder:
+    def __init__(self, monkeypatch, backend):
+        self.updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: self.updates.append((k, v)))
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    def dirs(self):
+        return [v for k, v in self.updates
+                if k == "jax_compilation_cache_dir"]
+
+
+def test_cache_env_var_places_it_from_outside(monkeypatch, tmp_path):
+    from flexflow_tpu.store import enable_compilation_cache
+
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    rec = _ConfigRecorder(monkeypatch, "tpu")
+    assert enable_compilation_cache(FFConfig()) == env_dir
+    # a disagreeing --compilation-cache is ignored, not applied
+    other = FFConfig(compilation_cache=str(tmp_path / "other"))
+    assert enable_compilation_cache(other) == env_dir
+    auto = FFConfig(compilation_cache="auto")  # no store: must not raise
+    assert enable_compilation_cache(auto) == env_dir
+    assert rec.dirs() == []
+    assert not os.path.exists(tmp_path / "other")
+    # the accelerator-side thresholds still apply
+    assert ("jax_persistent_cache_min_compile_time_secs", 0) in rec.updates
+    assert ("jax_persistent_cache_min_entry_size_bytes", -1) in rec.updates
+
+
+def test_cache_default_is_the_fixed_checkout_path(monkeypatch):
+    from flexflow_tpu.store import (DEFAULT_COMPILATION_CACHE_DIR,
+                                    enable_compilation_cache)
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert DEFAULT_COMPILATION_CACHE_DIR == want
+    assert tempfile.gettempdir() not in want
+    existed = os.path.isdir(want)
+    rec = _ConfigRecorder(monkeypatch, "tpu")
+    try:
+        assert enable_compilation_cache(FFConfig()) == want
+        assert enable_compilation_cache(FFConfig()) == want
+        assert rec.dirs() == [want, want]
+    finally:
+        if not existed and os.path.isdir(want) and not os.listdir(want):
+            os.rmdir(want)
+    # the CPU backend keeps the default off
+    rec_cpu = _ConfigRecorder(monkeypatch, "cpu")
+    assert enable_compilation_cache(FFConfig()) is None
+    assert rec_cpu.updates == []
+
+
+def test_cache_explicit_dir_still_honoured(monkeypatch, tmp_path):
+    from flexflow_tpu.store import enable_compilation_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    rec = _ConfigRecorder(monkeypatch, "cpu")
+    explicit = str(tmp_path / "explicit")
+    cfg = FFConfig(compilation_cache=explicit)
+    assert enable_compilation_cache(cfg) == explicit
+    assert rec.dirs() == [explicit] and os.path.isdir(explicit)
+
+
+# -- (c) device specs ------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_detect_device_spec_refuses_unknown_accelerators(monkeypatch):
+    from flexflow_tpu.sim import machine_model as mm
+
+    assert mm.detect_device_spec() is mm.CPU_BACKEND_DEVICE  # live: cpu
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
+    assert mm.detect_device_spec() is mm.V5E_DEVICE
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU v9 mega")])
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        mm.detect_device_spec()
+
+    def boom(*a):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        mm.detect_device_spec()
