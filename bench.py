@@ -2347,7 +2347,7 @@ def bench_serving_trace(dev, on_tpu):
         f"migrate decision but no migration span: {missing_migration}"
     # spec verify rounds ride shared batch spans the decode spans ref
     n_spec_batch = sum(1 for b in batch.values()
-                       if b["name"] == "spec_verify")
+                       if b["name"] == trace_analyze.SPEC_VERIFY_SPAN)
     spec_rounds = sum(
         s["args"].get("spec_rounds", 0)
         for spans in traces.values() for s in spans

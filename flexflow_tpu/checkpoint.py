@@ -59,7 +59,7 @@ import jax
 import numpy as np
 
 from .obs.metrics import registry_of
-from .obs.trace import tracer_of
+from .obs.trace import span
 
 _log = logging.getLogger("flexflow_tpu.checkpoint")
 
@@ -249,12 +249,11 @@ class CheckpointManager:
         }
         meta = _meta(ff, step)
         meta["leaf_specs"] = _tree_specs(state)
-        tracer = tracer_of(ff)
         registry = registry_of(ff)
         t0 = time.perf_counter()
-        with tracer.span("checkpoint_write", cat="checkpoint", step=step,
-                         backend="orbax", mode="sync" if wait else "async"):
-            with tracer.span("snapshot", cat="checkpoint", step=step):
+        with span("checkpoint_write", step=step, backend="orbax",
+                  mode="sync" if wait else "async"):
+            with span("snapshot", step=step):
                 self._mgr.save(
                     step,
                     args=ocp.args.Composite(
@@ -263,7 +262,7 @@ class CheckpointManager:
                     ),
                 )
             if wait:
-                with tracer.span("flush", cat="checkpoint", step=step):
+                with span("flush", step=step):
                     self._mgr.wait_until_finished()
                 self._latest.advance(step)
                 if registry is not None:
@@ -819,11 +818,10 @@ class LocalCheckpointManager:
         writer publishes it — drain() to wait for that."""
         from jax.tree_util import keystr, tree_flatten_with_path
 
-        tracer = tracer_of(ff)
         registry = registry_of(ff)
-        with tracer.span("checkpoint_write", cat="checkpoint", step=step,
-                         backend="local", mode="sync" if wait else "async"):
-            with tracer.span("snapshot", cat="checkpoint", step=step):
+        with span("checkpoint_write", step=step, backend="local",
+                  mode="sync" if wait else "async"):
+            with span("snapshot", step=step):
                 # async snapshots must own their memory: np.asarray can
                 # alias a live device buffer on CPU backends, and the
                 # next step DONATES those buffers — a view would be
@@ -835,7 +833,7 @@ class LocalCheckpointManager:
                 flat = {keystr(path): leaf for path, leaf in leaves}
                 meta = _meta(ff, step)
             if wait:
-                with tracer.span("flush", cat="checkpoint", step=step):
+                with span("flush", step=step):
                     self._write_and_publish(step, flat, meta, registry)
             else:
                 writer = self._writer_obj()
@@ -855,16 +853,14 @@ class LocalCheckpointManager:
                     writer.wait()  # failures stay for the owner's drain()
                 writer.submit(
                     step,
-                    lambda: self._flush_job(step, flat, meta, tracer,
-                                            registry),
+                    lambda: self._flush_job(step, flat, meta, registry),
                 )
 
-    def _flush_job(self, step, flat, meta, tracer, registry):
+    def _flush_job(self, step, flat, meta, registry):
         """Writer-thread half of an async save (shows up in the trace
         as a `flush` span on the writer's tid, overlapping the next
         training steps)."""
-        with tracer.span("flush", cat="checkpoint", step=step,
-                         backend="local", mode="async"):
+        with span("flush", step=step, backend="local", mode="async"):
             self._write_and_publish(step, flat, meta, registry)
 
     def _write_and_publish(self, step, flat, meta, registry=None):

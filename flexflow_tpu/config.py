@@ -360,9 +360,10 @@ class FFConfig:
     # -- observability (obs/, docs/OBSERVABILITY.md).  trace_dir turns
     #    on the full telemetry pipeline and names where the artifacts
     #    land (trace.json Chrome trace + run_telemetry.jsonl metrics);
-    #    telemetry=True records in memory without writing files (drain
-    #    via FFModel.telemetry).  Disabled (the default) is zero-cost on
-    #    the step hot path: no span objects are ever allocated.
+    #    telemetry=True captures logs, the fidelity record and request
+    #    traces in memory without writing files (drain via
+    #    FFModel.telemetry).  Host spans (obs.trace.span) are recorded
+    #    whatever these say: no field turns them on or off.
     trace_dir: Optional[str] = None
     telemetry: bool = False
     # jax.profiler.trace device capture around a step window,

@@ -29,6 +29,7 @@ import numpy as np
 
 from .fftype import LossType, OperatorType
 from .model import FFModel
+from .obs.trace import span
 from .optimizer import SGDOptimizer
 
 
@@ -192,6 +193,14 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     # compile-initialized placeholder carries it): on a tp replica
     # mesh this shards the trained weights over the model axis; at
     # tp=1 it is the identity placement.
+    with span("serve.copy_weights"):
+        ffd._weights = _transfer_weights(ffd, ff_train, tp)
+    return ffd
+
+
+def _transfer_weights(ffd: FFModel, ff_train: FFModel, tp: int) -> Dict:
+    """The decode twin's weight pytree filled from the trained model's,
+    each entry on the twin's own sharding."""
     import jax
 
     missing = []
@@ -218,8 +227,7 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     if missing:
         raise ValueError(f"decode graph weights missing in trained "
                          f"model: {missing}")
-    ffd._weights = new_w
-    return ffd
+    return new_w
 
 
 def gpt_generate_cached(ffd: FFModel, prompt_ids, max_new_tokens: int,

@@ -20,6 +20,7 @@ from __future__ import annotations
 import logging
 import math
 import time
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -42,6 +43,7 @@ from .fftype import (
 from .initializer import Initializer
 from .loss import Loss
 from .metrics import Metrics, PerfMetrics
+from .obs.trace import span
 from .ops.attention import MultiHeadAttention, MultiHeadAttentionParams
 from .ops.dense import (
     BatchMatmul,
@@ -137,8 +139,9 @@ def device_put_like(saved, current):
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
         self.config = config or FFConfig()
-        # run telemetry (obs/): NULL-tracer + in-memory registry unless
-        # FFConfig.trace_dir/telemetry turns recording on
+        # run telemetry (obs/): the in-memory registry always; files,
+        # log capture and request traces when FFConfig.trace_dir /
+        # telemetry asks.  Spans (obs.trace.span) need neither.
         from .obs import RunTelemetry
 
         self.telemetry = RunTelemetry.from_config(self.config)
@@ -156,6 +159,9 @@ class FFModel:
         self._state = None
         self._step_fn = None
         self._step_cache: Dict[int, tuple] = {}
+        # step functions that have run once (their first call compiles)
+        self._stepped_fns = weakref.WeakSet()
+        self._train_steps = 0  # train_step calls, the span's step index
         self._eval_fn = None
         self._rng = None
         self._label_replication = 1
@@ -643,15 +649,15 @@ class FFModel:
         devices: Optional[Sequence] = None,
         seed: Optional[int] = None,
     ):
-        tel = self.telemetry
         t0 = time.perf_counter()
-        with tel.tracer.span("compile", cat="compile"):
+        with span("compile") as sp:
             result = self._compile_inner(
                 optimizer=optimizer, loss_type=loss_type, metrics=metrics,
                 comp_mode=comp_mode, strategy=strategy, devices=devices,
                 seed=seed,
             )
-        tel.metrics.gauge("compile/total_ms").set(
+            sp.set(ops=len(self.operators.topo_order()))
+        self.telemetry.metrics.gauge("compile/total_ms").set(
             (time.perf_counter() - t0) * 1e3
         )
         return result
@@ -737,10 +743,12 @@ class FFModel:
                     return s
 
                 t_search = time.perf_counter()
-                with tel.tracer.span("search", cat="search",
-                                     algo=cfg.search_algo,
-                                     devices=num_devices):
+                with span("search", algo=cfg.search_algo,
+                          devices=num_devices) as sp:
                     strategy = cached_search(self, num_devices, _run_search)
+                    sp.set(evaluations=int((getattr(
+                        strategy, "search_stats", None) or {}).get(
+                            "evals", 0)))
                 tel.metrics.gauge("compile/search_ms").set(
                     (time.perf_counter() - t_search) * 1e3
                 )
@@ -749,7 +757,56 @@ class FFModel:
         self.strategy = strategy
         if cfg.export_strategy_file:
             strategy.save(cfg.export_strategy_file)
+        with span("compile.passes"):
+            self._build_executor(strategy, devices, num_devices, comp_mode)
+        # init_weights jit-executes eagerly, so this span IS a real XLA
+        # compile; build_step/eval/forward only stage traces (their XLA
+        # compile lands in the first train step — docs/OBSERVABILITY.md)
+        with span("init_weights"):
+            self._weights, self._state = self.executor.init_weights(
+                seed if seed is not None else cfg.seed
+            )
+        # ZeRO-1 layout: slots move to their 1/N per-device shard here,
+        # so every downstream consumer (step fn, checkpoint save/restore,
+        # recompile's device_put_like) inherits the sharded placement
+        with span("compile.opt_state"):
+            self._opt_state = self.executor.shard_opt_state(
+                self.optimizer.init_state(self._weights)
+            )
+        with span("build_step_fns"):
+            self._step_fn = self.executor.build_step()
+            self._eval_fn = self.executor.build_eval_step()
+            self._fwd_fn = self.executor.build_forward()
+        self._step_cache[self.iter_config.seq_length] = (
+            self._step_fn, self._eval_fn, self._fwd_fn,
+        )
+        self._rng = jax.random.key(cfg.seed)
+        if cfg.export_compgraph_file:
+            self.layers.export_dot(cfg.export_compgraph_file)
+        if cfg.export_taskgraph_file:
+            cost_fn = None
+            if cfg.include_costs_dot_graph:
+                # reference --include-costs-dot-graph (config.h:145):
+                # annotate each node with its simulated forward cost
+                from .sim.machine_model import make_machine_model
+                from .sim.simulator import OpCostModel
 
+                cm = OpCostModel(make_machine_model(cfg, num_devices))
+                cost_fn = lambda op: cm.cost(op).forward_time  # noqa: E731
+            self.operators.export_dot(
+                cfg.export_taskgraph_file,
+                include_costs=cfg.include_costs_dot_graph,
+                cost_fn=cost_fn,
+            )
+        return self
+
+    def _build_executor(self, strategy: Strategy, devices, num_devices: int,
+                        comp_mode: CompMode) -> None:
+        """The graph passes between the strategy and the weights: replay
+        the rewrite trace, fuse, apply the strategy, assign views, make
+        the mesh, and build the GraphExecutor over them."""
+        cfg = self.config
+        tel = self.telemetry
         # replay the strategy's graph-rewrite trace (reference: the
         # winning GraphXfer rewrites applied by graph_optimize,
         # substitution.cc:1898-1945), then apply + cancel redundant
@@ -934,45 +991,6 @@ class FFModel:
             # compile/recompile (ops are rebuilt, the config persists)
             op._iter_seq_length = self.iter_config.seq_length
         self._step_cache = {}
-        # init_weights jit-executes eagerly, so this span IS a real XLA
-        # compile; build_step/eval/forward only stage traces (their XLA
-        # compile lands in the first fit step — see docs/OBSERVABILITY.md)
-        with tel.tracer.span("init_weights", cat="compile"):
-            self._weights, self._state = self.executor.init_weights(
-                seed if seed is not None else cfg.seed
-            )
-        # ZeRO-1 layout: slots move to their 1/N per-device shard here,
-        # so every downstream consumer (step fn, checkpoint save/restore,
-        # recompile's device_put_like) inherits the sharded placement
-        self._opt_state = self.executor.shard_opt_state(
-            self.optimizer.init_state(self._weights)
-        )
-        with tel.tracer.span("build_step_fns", cat="compile"):
-            self._step_fn = self.executor.build_step()
-            self._eval_fn = self.executor.build_eval_step()
-            self._fwd_fn = self.executor.build_forward()
-        self._step_cache[self.iter_config.seq_length] = (
-            self._step_fn, self._eval_fn, self._fwd_fn,
-        )
-        self._rng = jax.random.key(cfg.seed)
-        if cfg.export_compgraph_file:
-            self.layers.export_dot(cfg.export_compgraph_file)
-        if cfg.export_taskgraph_file:
-            cost_fn = None
-            if cfg.include_costs_dot_graph:
-                # reference --include-costs-dot-graph (config.h:145):
-                # annotate each node with its simulated forward cost
-                from .sim.machine_model import make_machine_model
-                from .sim.simulator import OpCostModel
-
-                cm = OpCostModel(make_machine_model(cfg, num_devices))
-                cost_fn = lambda op: cm.cost(op).forward_time  # noqa: E731
-            self.operators.export_dot(
-                cfg.export_taskgraph_file,
-                include_costs=cfg.include_costs_dot_graph,
-                cost_fn=cost_fn,
-            )
-        return self
 
     # ------------------------------------------------------------------
     # training surface
@@ -1059,8 +1077,7 @@ class FFModel:
             op._iter_seq_length = seq_length
         cached = self._step_cache.get(seq_length)
         if cached is None:
-            with self.telemetry.tracer.span("build_step_fns", cat="compile",
-                                            seq_length=seq_length):
+            with span("build_step_fns", seq_length=seq_length):
                 self._step_fn = self.executor.build_step()
                 self._eval_fn = self.executor.build_eval_step()
                 self._fwd_fn = self.executor.build_forward()
@@ -1075,18 +1092,28 @@ class FFModel:
         """One jitted iteration: forward + loss + backward + metrics + update."""
         self._check_not_decode_graph("train_step()")
         self.set_iteration_config(seq_length)
-        tel = self.telemetry
-        if tel.enabled:
-            with tel.tracer.span("host_transfer", cat="data"):
+        step_fn = self._step_fn
+        # first=1: this call traces and compiles the step (or loads it
+        # from the persistent cache)
+        first = step_fn not in self._stepped_fns
+        with span("train_step", step=self._train_steps, first=int(first)):
+            with span("host_transfer"):
                 put_inputs, put_labels = self._device_put_batch(inputs, labels)
-        else:  # hot path: no span objects when telemetry is off
-            put_inputs, put_labels = self._device_put_batch(inputs, labels)
-        self._rng, step_rng = jax.random.split(self._rng)
-        self._weights, self._opt_state, self._state, m = self._step_fn(
-            self._weights, self._opt_state, self._state, put_inputs, put_labels,
-            step_rng,
-        )
-        return self._update_caches(dict(m))
+            with span("train_step.rng_split"):
+                self._rng, step_rng = jax.random.split(self._rng)
+            # the enqueue AND, once the runtime's queue is full, the wait
+            # for room in it: the step itself runs asynchronously
+            with span("train_step.dispatch"):
+                self._weights, self._opt_state, self._state, m = step_fn(
+                    self._weights, self._opt_state, self._state, put_inputs,
+                    put_labels, step_rng,
+                )
+            with span("train_step.caches"):
+                m = self._update_caches(dict(m))
+        if first:
+            self._stepped_fns.add(step_fn)
+        self._train_steps += 1
+        return m
 
     def eval_step(self, inputs: Dict[str, np.ndarray], labels: np.ndarray):
         self._check_not_decode_graph("eval_step()")
@@ -1125,25 +1152,19 @@ class FFModel:
             from .profiler import print_profile, profile_operators
 
             print_profile(profile_operators(self))
-        # telemetry: all per-step work lives behind ONE boolean so the
-        # disabled path allocates no span objects on the hot loop
-        tel = self.telemetry
-        tracing = tel.enabled
-        tracer = tel.tracer
-        step_hist = tel.metrics.histogram("fit/step_ms") if tracing else None
         for cb in callbacks:
             cb.on_train_begin(self)
         try:
             return self._fit_loop(
                 loader, epochs, callbacks, verbose, batch_size, num_batches,
-                history, tel, tracing, tracer, step_hist,
+                history,
             )
         finally:
             # flush in ALL exits: a crashed traced run (the case
             # observability exists for) still writes its artifacts, and
             # an interrupted --profile-steps window stops the profiler
-            if tracing:
-                tel.flush()
+            # (a no-op without a trace_dir)
+            self.telemetry.flush()
             # drain checkpoint-manager callbacks (ModelCheckpoint with
             # async_save): queued background saves must land even when
             # the fit loop died before on_train_end ran
@@ -1153,27 +1174,30 @@ class FFModel:
                     drain()
 
     def _fit_loop(self, loader, epochs, callbacks, verbose, batch_size,
-                  num_batches, history, tel, tracing, tracer, step_hist):
+                  num_batches, history):
+        tel = self.telemetry
+        # steps dispatch asynchronously: this is the host's time in
+        # train_step (the first one carries the XLA compile), not a
+        # step's; device time shows in the epoch's device_drain span
+        # and the fidelity record
+        dispatch_hist = tel.metrics.histogram("fit/dispatch_ms")
         global_step = 0
         epoch_step_s: List[float] = []  # per-epoch seconds/step
         for epoch in range(epochs):
             pm = PerfMetrics()
             t0 = time.perf_counter()
-            for batch, labels in loader:
-                if tracing:
-                    tel.on_step(global_step)  # jax.profiler window
-                    ts = time.perf_counter()
-                    # NOTE: steps dispatch asynchronously, so this span
-                    # is host dispatch time (the first one also carries
-                    # the XLA compile); device time shows up in the
-                    # epoch's device_drain span and the fidelity record
-                    with tracer.span("step", cat="train", step=global_step,
-                                     epoch=epoch):
-                        m = self.train_step(batch, labels)
-                    step_hist.observe((time.perf_counter() - ts) * 1e3)
-                    global_step += 1
-                else:
-                    m = self.train_step(batch, labels)
+            batches = iter(loader)
+            while True:
+                with span("fit.dataloader_wait"):
+                    item = next(batches, None)
+                if item is None:
+                    break
+                batch, labels = item
+                tel.on_step(global_step)  # jax.profiler window
+                ts = time.perf_counter()
+                m = self.train_step(batch, labels)
+                dispatch_hist.observe((time.perf_counter() - ts) * 1e3)
+                global_step += 1
                 # device-side accumulation: float(v) here would force a
                 # per-step host<->device sync that breaks the donated
                 # step chain; PerfMetrics sums on device and converts
@@ -1185,12 +1209,12 @@ class FFModel:
                     fn = getattr(op, "score_fn", None)
                     if fn is not None and op._is_legacy_score():
                         op.update_score(float(fn(self)))
-            with tracer.span("device_drain", cat="train", epoch=epoch):
+            with span("device_drain", epoch=epoch):
                 jax.block_until_ready(jax.tree.leaves(self._weights)[0])
             dt = time.perf_counter() - t0
             pm.finalize()  # the epoch's single metrics host transfer
             throughput = num_batches * batch_size / dt
-            if tracing:
+            if tel.enabled:
                 epoch_step_s.append(dt / max(1, num_batches))
                 tel.metrics.histogram("fit/epoch_s").observe(dt)
                 tel.metrics.gauge("fit/throughput_sps").set(throughput)
@@ -1211,7 +1235,7 @@ class FFModel:
                 break
         for cb in callbacks:
             cb.on_train_end(self)
-        if tracing and epoch_step_s:
+        if epoch_step_s:
             # fidelity record: predicted vs measured step time.  The
             # best epoch is the steady-state measurement (epoch 0 pays
             # the step fn's XLA compile; with a single epoch that cost

@@ -2,18 +2,19 @@
 
 Three pillars (docs/OBSERVABILITY.md):
 
-  * `trace`    — Chrome trace-event timeline spans (Perfetto-viewable)
-                 plus `jax.named_scope` op attribution in device profiles;
+  * `trace`    — the one span primitive (`obs.trace.span`): always on,
+                 into a bounded ring and the jax profiler's host plane;
+                 dumped as a Chrome trace when a `trace_dir` is set;
   * `metrics`  — typed counters/gauges/histograms unifying search stats,
                  resilience counters and PerfMetrics into one JSONL;
   * `fidelity` — per-run predicted-vs-measured step-time records.
 
 `RunTelemetry` bundles them per-FFModel, wired through FFConfig
 (`trace_dir`, `profile_steps`, `telemetry`) / CLI (`--trace-dir`,
-`--profile-steps`, `--telemetry`).  Disabled is the default and is
-zero-cost on the step hot path: the tracer is the shared NULL_TRACER
-and `fit` never constructs a span (tests/test_telemetry.py guards the
-no-allocation property).
+`--profile-steps`, `--telemetry`).  Spans are recorded whatever the
+configuration says; `enabled` decides whether files are written, the
+library's log records are captured, the fidelity record is computed and
+per-request traces are sampled.
 """
 from __future__ import annotations
 
@@ -37,7 +38,7 @@ from .reqtrace import (
     ReqTracer,
     TraceContext,
 )
-from .trace import NULL_TRACER, Tracer, span_allocations, tracer_of
+from .trace import Tracer, span
 
 TRACE_FILENAME = "trace.json"
 TELEMETRY_FILENAME = "run_telemetry.jsonl"
@@ -68,9 +69,9 @@ def parse_profile_steps(spec: Optional[str]) -> Optional[Tuple[int, int]]:
 
 class RunTelemetry:
     """Per-run telemetry bundle: tracer + metrics registry + artifact
-    paths.  The metrics registry always exists (searches/supervisors
-    fold their counters unconditionally — one dict walk per run); the
-    tracer and the on-disk artifacts only when enabled."""
+    paths.  The metrics registry and the span ring's writer always
+    exist (searches/supervisors fold their counters unconditionally —
+    one dict walk per run); the on-disk artifacts only when enabled."""
 
     def __init__(
         self,
@@ -84,12 +85,11 @@ class RunTelemetry:
         self.enabled = bool(trace_dir) if enabled is None else bool(enabled)
         self.run_id = run_id or f"run-{int(time.time())}-{os.getpid()}"
         self.metrics = MetricsRegistry()
-        self.tracer = Tracer(run_id=self.run_id) if self.enabled else NULL_TRACER
+        self.tracer = Tracer(run_id=self.run_id)
         # per-request serving traces (obs/reqtrace.py): spans drain into
         # the same registry/JSONL and merge into trace.json at flush()
         self.reqtrace = (
-            ReqTracer(registry=self.metrics, sample=trace_sample,
-                      run_id=self.run_id)
+            ReqTracer(registry=self.metrics, sample=trace_sample)
             if self.enabled else NULL_REQTRACER
         )
         self.profile_window = parse_profile_steps(profile_steps)
@@ -132,8 +132,8 @@ class RunTelemetry:
     # -- jax profiler window --------------------------------------------
     def on_step(self, step: int) -> None:
         """Drive the optional `jax.profiler.trace` capture window around
-        the configured [start, stop) steps.  Called from `fit` only when
-        telemetry is enabled."""
+        the configured [start, stop) steps (every `fit` step; a no-op
+        without a window)."""
         if self.profile_window is None or self.trace_dir is None:
             return
         start, stop = self.profile_window
@@ -145,17 +145,14 @@ class RunTelemetry:
                 os.path.join(self.trace_dir, "jax_profile")
             )
             self._profiling = True
-            self.tracer.instant("jax_profiler_start", cat="profile",
-                                step=step)
         elif step >= stop and self._profiling:
-            self._stop_profiler(step)
+            self._stop_profiler()
 
-    def _stop_profiler(self, step: int) -> None:
+    def _stop_profiler(self) -> None:
         import jax
 
         jax.profiler.stop_trace()
         self._profiling = False
-        self.tracer.instant("jax_profiler_stop", cat="profile", step=step)
 
     # -- artifacts -------------------------------------------------------
     @property
@@ -173,12 +170,12 @@ class RunTelemetry:
         )
 
     def flush(self) -> Dict[str, str]:
-        """Write/refresh the run artifacts: the Chrome trace JSON (full
-        rewrite — events accumulate over the run) and the telemetry
-        JSONL (append of newly drained records).  No-op when disabled
-        or no trace_dir is set."""
+        """Write/refresh the run artifacts: the Chrome trace JSON (the
+        span ring since this run began, bounded by the ring) and the
+        telemetry JSONL (append of newly drained records).  No-op when
+        disabled or no trace_dir is set."""
         if self._profiling:  # a fit that ended inside the window
-            self._stop_profiler(-1)
+            self._stop_profiler()
         if not self.enabled or not self.trace_dir:
             return {}
         os.makedirs(self.trace_dir, exist_ok=True)
@@ -199,7 +196,6 @@ __all__ = [
     "FRONT_PID",
     "MetricsRegistry",
     "NULL_REQTRACER",
-    "NULL_TRACER",
     "NullReqTracer",
     "ReqTracer",
     "RunTelemetry",
@@ -212,6 +208,5 @@ __all__ = [
     "parse_profile_steps",
     "registry_of",
     "report_fidelity",
-    "span_allocations",
-    "tracer_of",
+    "span",
 ]

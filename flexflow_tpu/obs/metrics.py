@@ -276,8 +276,7 @@ def to_prometheus(registry: MetricsRegistry) -> str:
 
 def registry_of(ff) -> Optional[MetricsRegistry]:
     """The model's metrics registry, or None for anything without a
-    telemetry bundle (plain executors, tests poking internals) — the
-    counterpart of `obs.trace.tracer_of` for metric call sites."""
+    telemetry bundle (plain executors, tests poking internals)."""
     tel = getattr(ff, "telemetry", None)
     return tel.metrics if tel is not None else None
 

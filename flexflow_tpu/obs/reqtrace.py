@@ -22,30 +22,39 @@ Spans land in two places:
   front), so a cross-replica migration renders as one connected tree
   in Perfetto.
 
-Zero-cost-when-disabled contract: a front built without a `ReqTracer`
-(or one whose sampler rejects the request) carries `req.trace = None`
-and every hot-path call site guards on that — the decode loop
-allocates NO span objects, extending the `obs.trace.span_allocations`
-guard (every real `ReqSpan` construction bumps the same counter the
-training-side `Span` does).
+Per-request trees are SAMPLED and off by default, unlike the host
+spans of `obs/trace.py`, which are always on: a front built without a
+`ReqTracer` (or one whose sampler rejects the request) carries
+`req.trace = None`, every hot-path call site guards on that, and the
+decode loop allocates no `ReqSpan` (`span_allocations()` counts them;
+tests/test_reqtrace.py guards it).  Both kinds of span read one clock,
+`time.monotonic()`, and share one id space, so a request's phase span
+references the scheduler's per-dispatch span (`sched.*.dispatch`) by
+`span_id` instead of wrapping the same call in a second span.
 """
 from __future__ import annotations
 
 import itertools
-import json
-import os
 import random
 import threading
 import time
 from typing import Dict, List, Optional
 
-from . import trace as _trace
+from .trace import next_span_id
 
 # the front's Perfetto track; replica spans use pid = replica id (>= 0)
 FRONT_PID = -1
 
 __all__ = ["FRONT_PID", "ReqSpan", "TraceContext", "ReqTracer",
-           "NullReqTracer", "NULL_REQTRACER"]
+           "NullReqTracer", "NULL_REQTRACER", "span_allocations"]
+
+# every ReqSpan construction bumps it; an unsampled request never does
+_SPAN_ALLOCS = 0
+
+
+def span_allocations() -> int:
+    """How many ReqSpan objects have been constructed process-wide."""
+    return _SPAN_ALLOCS
 
 
 class ReqSpan:
@@ -60,9 +69,8 @@ class ReqSpan:
     def __init__(self, tracer: "ReqTracer", trace_id: Optional[str],
                  span_id: int, parent_id: Optional[int], name: str,
                  pid: int, args: Dict):
-        # same process-wide counter the training-side Span bumps: the
-        # disabled-path guard test covers both tracers at once
-        _trace._SPAN_ALLOCS += 1
+        global _SPAN_ALLOCS
+        _SPAN_ALLOCS += 1
         self.tracer = tracer
         self.trace_id = trace_id
         self.span_id = span_id
@@ -172,25 +180,21 @@ class ReqTracer:
     enabled = True
 
     def __init__(self, registry=None, sample: float = 1.0, seed: int = 0,
-                 run_id: Optional[str] = None, max_spans: int = 200_000):
+                 max_spans: int = 200_000):
         if not 0.0 <= sample <= 1.0:
             raise ValueError(f"trace sample must be in [0, 1], got {sample}")
         self.registry = registry
         self.sample = float(sample)
-        self.run_id = run_id
         self.max_spans = int(max_spans)
         self._rng = random.Random(seed)
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
-        self._span_ids = itertools.count(1)
         self._trace_ids = itertools.count(1)
         self.spans: List[Dict] = []
         self.traces_started = 0
         self.spans_recorded = 0
         self.spans_dropped = 0
 
-    def now(self) -> float:
-        return time.perf_counter() - self._t0
+    now = staticmethod(time.monotonic)
 
     # -- trace/span construction ------------------------------------------
     def trace(self, name: str = "request", pid: int = FRONT_PID,
@@ -212,15 +216,16 @@ class ReqTracer:
 
     def _span(self, trace_id: Optional[str], parent_id: Optional[int],
               name: str, pid: int, args: Dict) -> ReqSpan:
-        return ReqSpan(self, trace_id, next(self._span_ids), parent_id,
+        return ReqSpan(self, trace_id, next_span_id(), parent_id,
                        name, pid, args)
 
-    def batch_span(self, name: str, pid: int, **args) -> ReqSpan:
-        """A shared per-dispatch span (prefill chunk, decode step, spec
-        verify round) that serves EVERY traced request in the batch:
-        it belongs to no single trace (trace_id None) and per-request
-        spans reference it by span id instead of duplicating it."""
-        return self._span(None, None, name, pid, args)
+    def shared_span(self, span, pid: int) -> None:
+        """Record a finished per-dispatch host span (`obs.trace.span`:
+        `sched.prefill.dispatch`, `sched.decode.dispatch`,
+        `sched.spec.verify.dispatch`) that served EVERY traced request
+        in the batch: it belongs to no single trace (trace_id None) and
+        per-request spans reference it by span id."""
+        self._push(span, None, None, pid)
 
     def begin_remote(self, wire: Optional[Dict], name: str,
                      pid: Optional[int] = None, **args
@@ -236,13 +241,17 @@ class ReqTracer:
 
     # -- sinks --------------------------------------------------------------
     def _record(self, span: ReqSpan) -> None:
+        self._push(span, span.trace_id, span.parent_id, span.pid)
+
+    def _push(self, span, trace_id: Optional[str],
+              parent_id: Optional[int], pid: int) -> None:
         rec = {
             "kind": "span",
             "name": span.name,
-            "trace_id": span.trace_id,
+            "trace_id": trace_id,
             "span_id": span.span_id,
-            "parent_id": span.parent_id,
-            "pid": span.pid,
+            "parent_id": parent_id,
+            "pid": pid,
             "t_start_us": round(span.t_start * 1e6, 1),
             "dur_us": round((span.t_end - span.t_start) * 1e6, 1),
             "args": span.args,
@@ -291,20 +300,6 @@ class ReqTracer:
             })
         return events
 
-    def write(self, path: str) -> int:
-        """A standalone Perfetto-loadable trace.json of just the
-        request spans (runs without a `Tracer` — bare fronts in tests
-        and bench legs — still get a Chrome artifact)."""
-        events = sorted(self.chrome_events(), key=lambda e: e["ts"])
-        doc = {"traceEvents": events, "displayTimeUnit": "ms"}
-        if self.run_id:
-            doc["otherData"] = {"run_id": self.run_id}
-        tmp = f"{path}.tmp-{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(doc, f)
-        os.replace(tmp, path)
-        return len(events)
-
     def stats(self) -> Dict:
         with self._lock:
             return {
@@ -332,9 +327,6 @@ class NullReqTracer:
 
     def chrome_events(self) -> List[Dict]:
         return []
-
-    def write(self, path: str) -> int:
-        return 0
 
     def stats(self) -> Dict:
         return {"sample": 0.0, "traces_started": 0,

@@ -52,7 +52,7 @@ from ..checkpoint import CheckpointVerifyError
 from ..executor import NonFiniteLossError, check_step_health
 from ..logger import resilience_logger
 from ..obs.metrics import emit_counters, registry_of
-from ..obs.trace import tracer_of
+from ..obs.trace import span
 from .faults import (
     CheckpointWriteFault,
     DeviceLossFault,
@@ -282,8 +282,7 @@ class TrainingSupervisor:
         # a pending async save may be the newest durable state — let it
         # land (or fail) before picking the restore target
         self._drain_writer()
-        with tracer_of(self.ff).span("restart", cat="resilience",
-                                     failed_step=step):
+        with span("restart", failed_step=step):
             restored = int(self.manager.restore(self.ff))
         self.counters["restarts"] += 1
         self.counters["lost_steps"] += max(0, step - restored)
@@ -347,8 +346,7 @@ class TrainingSupervisor:
         """Re-search placement for `survivors`, recompile onto them,
         and reshard-restore the latest checkpoint so trained state
         carries over to the rebuilt executor."""
-        with tracer_of(self.ff).span("re_search", cat="resilience",
-                                     survivors=len(survivors), reason=reason):
+        with span("re_search", survivors=len(survivors), reason=reason):
             strategy = self._search_strategy(len(survivors))
         self.counters["re_searches"] += 1
         # recompile rebuilds the executor (fresh shardings, fresh
@@ -465,8 +463,7 @@ class TrainingSupervisor:
         (_preempt_rendezvous); the emergency step is force-mirrored
         regardless of cadence."""
         registry = registry_of(self.ff)
-        with tracer_of(self.ff).span("emergency_checkpoint", cat="resilience",
-                                     step=step, reason=self._preempt):
+        with span("emergency_checkpoint", step=step, reason=self._preempt):
             # drain FIRST: a queued async save may still be flushing on
             # the writer thread, and the sync emergency write must not
             # race it on the step dir / LATEST pointer

@@ -46,6 +46,7 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
 
 from ..logger import resilience_logger
+from ..obs.trace import span
 from ..resilience.faults import FaultPlan
 from ..resilience.retry import RetryPolicy
 from .handoff import HandoffPaused
@@ -241,7 +242,8 @@ class ServingFront:
         self.retired: List[ServingReplica] = []
         self.retired_keep = 16
         self._retired_dropped = 0
-        self._retired_folded = {"batches_run": 0, "tokens_generated": 0}
+        self._retired_folded = {"batches_run": 0, "tokens_generated": 0,
+                                "admitted": 0, "queue_wait_s_sum": 0.0}
         self._model_factory = model_factory
         plans = fault_plans or {}
         self._replica_kw = dict(
@@ -328,8 +330,8 @@ class ServingFront:
         cfg = ff_train.config
         # inherit the run's telemetry bundle unless the caller wires
         # its own: --trace-dir alone gives the serving fleet SLO
-        # metrics AND per-request traces (obs/reqtrace.py) — the
-        # NULL_REQTRACER's enabled=False keeps the disabled path free
+        # metrics AND per-request traces (obs/reqtrace.py); without it
+        # the bundle's NULL_REQTRACER samples nothing
         tel = getattr(ff_train, "telemetry", None)
         if tel is not None:
             if registry is None and getattr(tel, "enabled", False):
@@ -351,30 +353,35 @@ class ServingFront:
             devs = devices
             if survivors is not None and devs is not None:
                 devs = devs[:survivors]
-            draft_model = None
-            if spec_decode == "draft":
-                draft_model = PagedKVDecodeModel(
-                    draft_ff,
+            # decode graph, its compile, weights, state and pool
+            with span("serve.build_twin", replica=replica_id) as sp:
+                draft_model = None
+                if spec_decode == "draft":
+                    draft_model = PagedKVDecodeModel(
+                        draft_ff,
+                        batch_slots=cfg.serving_slots,
+                        page_size=cfg.kv_page_size,
+                        devices=devs,
+                        paged_kernel=getattr(cfg, "paged_kernel",
+                                             "gather"),
+                    )
+                model = PagedKVDecodeModel(
+                    ff_train,
                     batch_slots=cfg.serving_slots,
                     page_size=cfg.kv_page_size,
+                    num_blocks=cfg.kv_pool_blocks or None,
                     devices=devs,
-                    paged_kernel=getattr(cfg, "paged_kernel",
-                                         "gather"),
+                    prefill_chunk=getattr(cfg, "prefill_chunk", 0),
+                    prefix_cache=getattr(cfg, "prefix_cache", True),
+                    paged_kernel=getattr(cfg, "paged_kernel", "gather"),
+                    tp=getattr(cfg, "serving_tp", 1),
+                    spec_decode=spec_decode,
+                    spec_k=spec_k,
+                    draft_model=draft_model,
                 )
-            return PagedKVDecodeModel(
-                ff_train,
-                batch_slots=cfg.serving_slots,
-                page_size=cfg.kv_page_size,
-                num_blocks=cfg.kv_pool_blocks or None,
-                devices=devs,
-                prefill_chunk=getattr(cfg, "prefill_chunk", 0),
-                prefix_cache=getattr(cfg, "prefix_cache", True),
-                paged_kernel=getattr(cfg, "paged_kernel", "gather"),
-                tp=getattr(cfg, "serving_tp", 1),
-                spec_decode=spec_decode,
-                spec_k=spec_k,
-                draft_model=draft_model,
-            )
+                sp.set(slots=model.batch_slots,
+                       pool_blocks=model.num_blocks)
+            return model
 
         kw.setdefault("step_timeout", cfg.serving_step_timeout)
         kw.setdefault("max_restarts", cfg.serving_max_restarts)
@@ -396,11 +403,13 @@ class ServingFront:
                 f"--serving-chip-budget {budget} cannot hold the "
                 f"initial fleet: {n} replica(s) x --serving-tp {tp} "
                 f"= {n * tp} chip(s)")
-        return cls(
-            factory, n,
-            eos_id=eos_id, registry=registry, fault_plans=fault_plans,
-            **kw,
-        )
+        with span("serve.build_front", replicas=n,
+                  slots=cfg.serving_slots):
+            return cls(
+                factory, n,
+                eos_id=eos_id, registry=registry, fault_plans=fault_plans,
+                **kw,
+            )
 
     # -- replica events --------------------------------------------------
     def _on_replica_state(self, replica: ServingReplica) -> None:
@@ -518,7 +527,7 @@ class ServingFront:
                     st = old.stats()
                     self._retired_dropped += 1
                     for k in self._retired_folded:
-                        self._retired_folded[k] += int(st.get(k, 0))
+                        self._retired_folded[k] += st.get(k, 0)
                     dropped.append(old)
             self._cv.notify_all()
         for old in dropped:
@@ -1350,6 +1359,14 @@ class ServingFront:
             "steps": (folded["batches_run"]
                       + sum(r["batches_run"] for r in replicas)
                       + sum(r["batches_run"] for r in retired)),
+            # requests the schedulers gave a slot, and their summed
+            # waits from submit to that admission (mean = sum / count)
+            "admitted": (folded["admitted"]
+                         + sum(r["admitted"] for r in replicas + retired)),
+            "queue_wait_s_sum": round(
+                folded["queue_wait_s_sum"]
+                + sum(r["queue_wait_s_sum"] for r in replicas + retired),
+                6),
             "ttft": self.ttft_stats(),
             "latency": self.latency_stats(),
             "replicas": replicas,

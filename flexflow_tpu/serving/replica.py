@@ -50,7 +50,7 @@ FATAL_DECODE_FAULTS = (DeviceLossFault, HungStepFault, HungStepTimeout)
 
 #: per-replica scheduler counters folded into `stats()` across restarts
 _CARRIED_COUNTERS = ("batches_run", "requests_done", "tokens_generated",
-                     "step_failures")
+                     "step_failures", "admitted", "queue_wait_s_sum")
 
 
 class SupervisedDecodeModel:
@@ -366,7 +366,7 @@ class ServingReplica:
         if sched is None:
             return
         for k in _CARRIED_COUNTERS:
-            self._carried[k] += int(getattr(sched, k, 0))
+            self._carried[k] += getattr(sched, k, 0)
 
     def _supervise(self) -> None:
         """Restart loop: each observed death costs one unit of the
@@ -543,10 +543,12 @@ class ServingReplica:
             "last_recovery_s": self.last_recovery_s,
         }
         for k in _CARRIED_COUNTERS:
-            out[k] = self._carried[k] + int(getattr(sched, k, 0) or 0)
+            out[k] = self._carried[k] + (getattr(sched, k, 0) or 0)
         if sched is not None:
             sstats = sched.stats()
             out["queue_depth"] = sstats["queue_depth"]
+            # this engine's pool (peak_used_blocks since its last build)
+            out["kv_pool"] = sstats["kv_pool"]
             # prefix-cache visibility per replica (each pool caches
             # independently; shared blocks counted once per pool)
             if "prefix_cache" in sstats:

@@ -41,6 +41,7 @@ run_telemetry.jsonl and surfaced in /v2/stats (docs/SERVING.md).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -50,6 +51,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs.trace import span
 from .kv_pool import KVPool
 
 
@@ -146,6 +148,7 @@ class PagedKVDecodeModel:
             build_paged_prefill_step(self.ffd, self.prefill_chunk)
             if self.prefill_chunk else None)
         self._copy_fn = build_paged_copy_block(self.ffd)
+        self._called = set()  # step programs that have run once
         # speculative verify twin (docs/SERVING.md "Speculative
         # decoding"): ONE [slots, spec_k+1] program scores a pending
         # token plus up to spec_k drafts per row — per-position logits
@@ -230,20 +233,31 @@ class PagedKVDecodeModel:
         # per-token hot path: the block table / seq_lens override
         # happens INSIDE the jitted step and the state pytree is
         # donated — no host-side dict rebuild, no per-layer pool copy
-        logits, self._state = self._step_fn(
-            self.ffd._weights, self._state, tokens, seq_lens,
-            block_tables,
-        )
-        return np.asarray(logits, np.float32)
+        with span("model.enqueue", first=self._first_call("step")):
+            logits, self._state = self._step_fn(
+                self.ffd._weights, self._state, tokens, seq_lens,
+                block_tables,
+            )
+        # the wait for the device, then the logits' copy to the host
+        with span("model.fetch"):
+            return np.asarray(logits, np.float32)
+
+    def _first_call(self, program: str) -> int:
+        """1 on a program's first call (its lazy compile), else 0."""
+        if program in self._called:
+            return 0
+        self._called.add(program)
+        return 1
 
     def prefill_step(self, tokens: np.ndarray, positions: np.ndarray,
                      block_tables: np.ndarray) -> None:
         """Chunked prefill: scatter tokens[b, C] at positions[b]..+C-1
         into the pool.  No logits come back — prefill ignores them."""
-        self._state = self._prefill_fn(
-            self.ffd._weights, self._state, tokens, positions,
-            block_tables,
-        )
+        with span("model.enqueue", first=self._first_call("prefill")):
+            self._state = self._prefill_fn(
+                self.ffd._weights, self._state, tokens, positions,
+                block_tables,
+            )
 
     def verify_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
                     counts: np.ndarray,
@@ -254,11 +268,13 @@ class PagedKVDecodeModel:
         to what the decode step would have produced feeding
         tokens[i, j] at seq_lens[i]+j (docs/SERVING.md "Speculative
         decoding").  Built only when spec_decode != "off"."""
-        logits, self._state = self._verify_fn(
-            self.ffd._weights, self._state, tokens,
-            seq_lens, counts, block_tables,
-        )
-        return np.asarray(logits, np.float32)
+        with span("model.enqueue", first=self._first_call("verify")):
+            logits, self._state = self._verify_fn(
+                self.ffd._weights, self._state, tokens,
+                seq_lens, counts, block_tables,
+            )
+        with span("model.fetch"):
+            return np.asarray(logits, np.float32)
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write: clone physical block src -> dst in every
@@ -412,9 +428,10 @@ class _LiveTrace:
     traced live row.  The row owns exactly one open PHASE span at a
     time ("prefill" until its first generated token, then "decode");
     batched dispatches (prefill chunks, decode steps, verify rounds)
-    each get ONE shared batch span per dispatch, and the per-request
-    phase span REFERENCES those by span id instead of duplicating
-    them — N rows riding one dispatch never write N copies of it."""
+    each have ONE host span (`sched.*.dispatch`, obs/trace.py), and the
+    per-request phase span REFERENCES those by span id instead of
+    duplicating them — N rows riding one dispatch never write N copies
+    of it."""
 
     __slots__ = ("ctx", "pid", "span", "chunks", "chunk_refs",
                  "steps", "spec_rounds", "batch_refs")
@@ -428,22 +445,22 @@ class _LiveTrace:
                               prefix_hit_tokens=hit_tokens,
                               prompt_len=plen)
         self.chunks = 0        # chunked-prefill dispatches ridden
-        self.chunk_refs: List[int] = []  # their batch span ids
+        self.chunk_refs: List[int] = []  # their dispatch span ids
         self.steps = 0         # decode/verify dispatches ridden
         self.spec_rounds = 0   # of which were speculative verifies
-        self.batch_refs: List[int] = []  # decode-phase batch span ids
+        self.batch_refs: List[int] = []  # decode-phase dispatch span ids
 
-    def ref_chunk(self, batch_span) -> None:
+    def ref_chunk(self, dispatch) -> None:
         self.chunks += 1
-        if batch_span is not None and len(self.chunk_refs) < self.MAX_REFS:
-            self.chunk_refs.append(batch_span.span_id)
+        if len(self.chunk_refs) < self.MAX_REFS:
+            self.chunk_refs.append(dispatch.span_id)
 
-    def ref_step(self, batch_span, spec: bool = False) -> None:
+    def ref_step(self, dispatch, spec: bool = False) -> None:
         self.steps += 1
         if spec:
             self.spec_rounds += 1
-        if batch_span is not None and len(self.batch_refs) < self.MAX_REFS:
-            self.batch_refs.append(batch_span.span_id)
+        if len(self.batch_refs) < self.MAX_REFS:
+            self.batch_refs.append(dispatch.span_id)
 
     def to_decode(self) -> None:
         """First generated token: close the prefill phase, open decode."""
@@ -587,6 +604,8 @@ class ContinuousScheduler:
         self._draining = False
         self._on_drained = None
         self.batches_run = 0       # decode steps executed
+        self.admitted = 0          # requests given a slot
+        self.queue_wait_s_sum = 0.0  # their submit-to-admit waits, summed
         self.requests_done = 0
         self.tokens_generated = 0
         self.step_failures = 0
@@ -813,6 +832,8 @@ class ContinuousScheduler:
             "step_failures": self.step_failures,
             "step_ms_ewma": round(self.step_ms_ewma, 4),
             "queue_depth": self._queue.qsize() + len(self._waiting),
+            "admitted": self.admitted,
+            "queue_wait_s_sum": round(self.queue_wait_s_sum, 6),
             "live_sequences": len(live),
             "kv_pool": {
                 "page_size": self.pool.page_size,
@@ -1068,6 +1089,8 @@ class ContinuousScheduler:
                 break
             self._waiting.popleft()
             self._next_seq_id += 1
+            self.admitted += 1
+            self.queue_wait_s_sum += time.monotonic() - req.t_submit
             hit = self.pool.admit_hit_tokens(sid)
             if rs is not None:
                 # a live handoff may have shipped the partial tail
@@ -1224,6 +1247,25 @@ class ContinuousScheduler:
         if self._proposer is not None:
             self._proposer.reset()
 
+    def _share_dispatch(self, dispatch, lives) -> None:
+        """Request tracing: a finished dispatch span that a sampled
+        request rode is recorded once with the request tracer, as the
+        shared span those requests' phase spans reference by id."""
+        if self._reqtrace is not None and any(
+                live is not None and live.tspan is not None
+                for live in lives):
+            self._reqtrace.shared_span(dispatch, self._trace_pid)
+
+    @contextlib.contextmanager
+    def _sample_span(self):
+        """`sched.sample` around the per-row work after a dispatch,
+        carrying what it emitted: tokens and finished requests."""
+        with span("sched.sample") as sp:
+            t0, d0 = self.tokens_generated, self.requests_done
+            yield
+            sp.set(tokens=self.tokens_generated - t0,
+                   finished=self.requests_done - d0)
+
     def _note_step_time(self, dt_s: float) -> None:
         """EWMA of per-dispatch wall time (decode + chunked-prefill).
         The disagg dispatcher prices a re-prefill as chunked steps x
@@ -1262,39 +1304,37 @@ class ContinuousScheduler:
         writes safe.  Returns False after a transient fault (already
         handled); fatal faults propagate."""
         C = self._chunk
-        tok = np.zeros((self.model.batch_slots, C), np.int32)
-        slen = np.zeros(self.model.batch_slots, np.int32)
-        btab = np.zeros_like(self._btab)
-        plan = []
-        for i, live in pre:
-            flen = len(live.feed)
-            upto = min(live.pos + C, flen - 1)
-            self.pool.extend(live.seq_id, upto, written=live.pos)
-            self._btab[i] = self.pool.table_row(live.seq_id)
-            tok[i, :upto - live.pos] = live.feed[live.pos:upto]
-            slen[i] = live.pos
-            btab[i] = self._btab[i]
-            plan.append((i, live, upto))
-        bspan = None
-        if self._reqtrace is not None and any(
-                live.tspan is not None for _, live in pre):
-            bspan = self._reqtrace.batch_span(
-                "prefill_chunk", self._trace_pid,
-                rows=len(plan), chunk=C)
-        t0 = time.monotonic()
+        with span("sched.prefill.prepare"):
+            tok = np.zeros((self.model.batch_slots, C), np.int32)
+            slen = np.zeros(self.model.batch_slots, np.int32)
+            btab = np.zeros_like(self._btab)
+            plan = []
+            real = 0  # prompt tokens this dispatch really advances
+            for i, live in pre:
+                flen = len(live.feed)
+                upto = min(live.pos + C, flen - 1)
+                self.pool.extend(live.seq_id, upto, written=live.pos)
+                self._btab[i] = self.pool.table_row(live.seq_id)
+                tok[i, :upto - live.pos] = live.feed[live.pos:upto]
+                slen[i] = live.pos
+                btab[i] = self._btab[i]
+                plan.append((i, live, upto))
+                real += upto - live.pos
         try:
-            self.model.prefill_step(tok, slen, btab)
+            with span("sched.prefill.dispatch", rows=len(plan),
+                      tokens=real,
+                      capacity=self.model.batch_slots * C) as dispatch:
+                self.model.prefill_step(tok, slen, btab)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise
             self._fail_inflight(e)
             return False
-        if bspan is not None:
-            bspan.end()
-            for _, live in pre:
-                if live.tspan is not None:
-                    live.tspan.ref_chunk(bspan)
-        self._note_step_time(time.monotonic() - t0)
+        self._share_dispatch(dispatch, [live for _, live in pre])
+        for _, live in pre:
+            if live.tspan is not None:
+                live.tspan.ref_chunk(dispatch)
+        self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.prefill_steps += 1
         if self._paged_kernel == "pallas":
             # the prefill program scans the seq-1 kernel C times per
@@ -1400,19 +1440,13 @@ class ContinuousScheduler:
                 self.pool.extend(live.seq_id, live.pos + m,
                                  written=live.pos)
                 self._btab[i] = self.pool.table_row(live.seq_id)
-        bspan = None
-        if self._reqtrace is not None and any(
-                s is not None and s.tspan is not None
-                for s in self._slots):
-            bspan = self._reqtrace.batch_span(
-                "spec_verify", self._trace_pid,
-                rows=int((counts > 0).sum()),
-                drafted=len(props), fed=int(counts.sum()),
-                **self._proposer.trace_attrs())
-        t0 = time.monotonic()
         try:
-            logits = self.model.verify_step(
-                tok, self._slens, counts, self._btab)
+            with span("sched.spec.verify.dispatch",
+                      rows=int((counts > 0).sum()),
+                      drafted=len(props), fed=int(counts.sum()),
+                      **self._proposer.trace_attrs()) as dispatch:
+                logits = self.model.verify_step(
+                    tok, self._slens, counts, self._btab)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise  # hung verify / device loss: drain-and-die
@@ -1429,9 +1463,8 @@ class ContinuousScheduler:
                 self.registry.counter(
                     "serving/spec_verify_faults").inc()
             return False
-        if bspan is not None:
-            bspan.end()
-        self._note_step_time(time.monotonic() - t0)
+        self._share_dispatch(dispatch, self._slots)
+        self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.batches_run += 1
         self.spec_rounds += 1
         if self._spec_t0 is None:
@@ -1448,6 +1481,16 @@ class ContinuousScheduler:
                 blocks += blocks_read(self._slens + j, mask, 1,
                                       self.pool.page_size, tw)
             self._note_kernel_reads(blocks, bs * tw * C)
+        with self._sample_span():
+            self._accept_rows(logits, tok, counts, dispatch)
+        if self.registry is not None:
+            self.registry.counter("serving/spec_rounds").inc()
+        return True
+
+    def _accept_rows(self, logits, tok, counts, dispatch) -> None:
+        """After a verify dispatch: per row, accept the longest prefix
+        of its drafts that matches the model's own chain, roll the
+        pool back past it, retire the finished."""
         now = time.monotonic()
         for i, live in enumerate(self._slots):
             if live is None:
@@ -1462,7 +1505,7 @@ class ContinuousScheduler:
                 self._tokens[i] = live.next_token
                 self._slens[i] = live.pos
                 if live.tspan is not None:
-                    live.tspan.ref_step(bspan, spec=True)
+                    live.tspan.ref_step(dispatch, spec=True)
                 continue
             # decode-phase: walk the model's own token chain across
             # the fed positions — position j's output is valid iff
@@ -1521,7 +1564,7 @@ class ContinuousScheduler:
                         "serving/spec_accepted_per_round").observe(
                         accepted)
             if live.tspan is not None:
-                live.tspan.ref_step(bspan, spec=True)
+                live.tspan.ref_step(dispatch, spec=True)
             if not live.generated:
                 live.req.t_first_token = now
                 with self._lat_lock:
@@ -1544,137 +1587,162 @@ class ContinuousScheduler:
                 live.next_token = out[-1]
                 self._tokens[i] = out[-1]
                 self._slens[i] = live.pos
-        if self.registry is not None:
-            self.registry.counter("serving/spec_rounds").inc()
-        return True
 
     def _decode_loop(self):
-        page = self.pool.page_size
         while not self._stop.is_set():
-            self._run_services()
-            self._admit()
-            if all(s is None for s in self._slots):
-                if (self._draining and not self._waiting
-                        and self._queue.empty()):
-                    # drain complete: nothing live, nothing queued —
-                    # exit cleanly (a submit that raced past the
-                    # drain() cutoff sits in _queue and was admitted
-                    # above, so it is NOT abandoned here)
+            with span("sched.iteration"):
+                if not self._iteration():
                     return
-                # idle: park on the arrival queue instead of spinning
+
+    def _iteration(self) -> bool:
+        """One turn of the decode loop; False once a drain is complete.
+        Every stretch of it runs under a named host span (children of
+        `sched.iteration`; docs/OBSERVABILITY.md lists them), so device
+        idle time can be laid at what the host was doing."""
+        page = self.pool.page_size
+        with span("sched.services"):
+            self._run_services()
+        with span("sched.admit") as sp:
+            n0, w0 = self.admitted, self.queue_wait_s_sum
+            self._admit()
+            sp.set(admitted=self.admitted - n0,
+                   queue_depth=len(self._waiting),
+                   wait_ms=round(1e3 * (self.queue_wait_s_sum - w0), 3))
+        if all(s is None for s in self._slots):
+            if (self._draining and not self._waiting
+                    and self._queue.empty()):
+                # drain complete: nothing live, nothing queued —
+                # exit cleanly (a submit that raced past the
+                # drain() cutoff sits in _queue and was admitted
+                # above, so it is NOT abandoned here)
+                return False
+            # idle: park on the arrival queue instead of spinning
+            with span("sched.idle_wait"):
                 try:
                     self._waiting.append(self._queue.get(timeout=0.05))
                 except queue.Empty:
                     pass
-                continue
-            if self._chunk:
-                # chunked prefill first: mid-prefill rows jump up to C
-                # positions, then everyone (them included) takes the
-                # normal one-token decode step below
-                pre = [(i, live) for i, live in enumerate(self._slots)
-                       if live is not None
-                       and live.pos < len(live.feed) - 1]
-                if pre and not self._prefill_chunk_step(pre):
-                    continue
+            return True
+        if self._chunk:
+            # chunked prefill first: mid-prefill rows jump up to C
+            # positions, then everyone (them included) takes the
+            # normal one-token decode step below
+            pre = [(i, live) for i, live in enumerate(self._slots)
+                   if live is not None
+                   and live.pos < len(live.feed) - 1]
+            if pre and not self._prefill_chunk_step(pre):
+                return True
+        props = None
+        with span("sched.decode.prepare"):
+            decoding = feeding = 0
             for i, live in enumerate(self._slots):
                 if live is None:
                     continue
+                if live.pos + 1 < len(live.feed):
+                    feeding += 1  # its logits will be ignored
+                else:
+                    decoding += 1
                 # crossing a page boundary: allocate the next block
                 # (admission reserved it, so this cannot fail)
                 if live.pos and live.pos % page == 0:
                     self.pool.extend(live.seq_id, live.pos + 1)
                     self._btab[i] = self.pool.table_row(live.seq_id)
             if self._spec != "off" and not self._spec_broken:
-                props = self._spec_proposals()
-                if props:
-                    # speculative round: every live row rides ONE
-                    # verify dispatch (drafted rows multi-token,
-                    # everyone else count-1)
-                    if self._spec_round(props):
-                        self._observe_step()
-                    continue
-                # no proposals anywhere: fall through to the plain
-                # [slots, 1] decode step — the required empty-round
-                # fallback (and the whole path when spec is off)
-                self.spec_fallback_rounds += 1
-            bspan = None
-            if self._reqtrace is not None and any(
-                    s is not None and s.tspan is not None
-                    for s in self._slots):
-                bspan = self._reqtrace.batch_span(
-                    "decode_step", self._trace_pid,
-                    rows=sum(1 for s in self._slots if s is not None))
-            t0 = time.monotonic()
-            try:
+                with span("sched.spec.propose"):
+                    props = self._spec_proposals()
+                if not props:
+                    # no proposals anywhere: the plain [slots, 1]
+                    # decode step below is the required empty-round
+                    # fallback (and the whole path when spec is off)
+                    self.spec_fallback_rounds += 1
+        if props:
+            # speculative round: every live row rides ONE verify
+            # dispatch (drafted rows multi-token, everyone else
+            # count-1)
+            if self._spec_round(props):
+                with span("sched.observe"):
+                    self._observe_step()
+            return True
+        try:
+            with span("sched.decode.dispatch", rows=decoding,
+                      feeding=feeding,
+                      slots=self.model.batch_slots) as dispatch:
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab)
-            except Exception as e:
-                if getattr(e, "fatal_to_engine", False):
-                    # device-loss-style fault (hung dispatch, lost
-                    # device — serving/replica.py marks them): the
-                    # ENGINE is gone, not just this batch.  Propagate
-                    # so _loop drains everything and fires on_death —
-                    # the supervisor restarts the replica.
-                    raise
-                self._fail_inflight(e)
-                continue
-            if bspan is not None:
-                bspan.end()
-            self._note_step_time(time.monotonic() - t0)
-            self.batches_run += 1
-            if self._paged_kernel == "pallas":
-                from ..ops.pallas.paged_attention import blocks_read
+        except Exception as e:
+            if getattr(e, "fatal_to_engine", False):
+                # device-loss-style fault (hung dispatch, lost
+                # device — serving/replica.py marks them): the
+                # ENGINE is gone, not just this batch.  Propagate
+                # so _loop drains everything and fires on_death —
+                # the supervisor restarts the replica.
+                raise
+            self._fail_inflight(e)
+            return True
+        self._share_dispatch(dispatch, self._slots)
+        self._note_step_time(dispatch.t_end - dispatch.t_start)
+        self.batches_run += 1
+        if self._paged_kernel == "pallas":
+            from ..ops.pallas.paged_attention import blocks_read
 
-                self._note_kernel_reads(
-                    blocks_read(
-                        self._slens,
-                        np.array([s is not None for s in self._slots]),
-                        1, page, self.pool.max_blocks_per_seq),
-                    self.model.batch_slots
-                    * self.pool.max_blocks_per_seq)
-            now = time.monotonic()
-            for i, live in enumerate(self._slots):
-                if live is None:
-                    continue
-                live.pos += 1
-                # keep the pool's written-token watermark current so
-                # fragmentation never over-reports a mid-page tail
-                self.pool.note_written(live.seq_id, live.pos)
-                if live.pos < len(live.feed):
-                    # prefill: the next token is given, logits ignored
-                    live.next_token = live.feed[live.pos]
-                    self._tokens[i] = live.next_token
-                    self._slens[i] = live.pos
-                    if live.tspan is not None:
-                        live.tspan.ref_step(bspan)
-                    continue
-                tok = int(self._sample(logits[i], live))
-                if live.tspan is not None:
-                    live.tspan.ref_step(bspan)
-                if not live.generated:
-                    live.req.t_first_token = now
-                    with self._lat_lock:
-                        self._ttfts.append(now - live.req.t_submit)
-                    if self.registry is not None:
-                        self.registry.histogram(
-                            "serving/ttft_ms").observe(
-                            (now - live.req.t_submit) * 1e3,
-                            exemplar=(live.req.trace.trace_id
-                                      if live.req.trace is not None
-                                      else None))
-                    if live.tspan is not None:
-                        live.tspan.to_decode()
-                live.generated.append(tok)
-                self.tokens_generated += 1
-                done = (len(live.generated) >= live.max_new
-                        or (self.eos_id >= 0 and tok == self.eos_id))
-                if done:
-                    self._finish(i, live)
-                else:
-                    live.next_token = tok
-                    self._tokens[i] = tok
-                    self._slens[i] = live.pos
+            self._note_kernel_reads(
+                blocks_read(
+                    self._slens,
+                    np.array([s is not None for s in self._slots]),
+                    1, page, self.pool.max_blocks_per_seq),
+                self.model.batch_slots
+                * self.pool.max_blocks_per_seq)
+        with self._sample_span():
+            self._sample_rows(logits, dispatch)
+        with span("sched.observe"):
             self._observe_step()
+        return True
+
+    def _sample_rows(self, logits, dispatch) -> None:
+        """After a decode dispatch: advance every live row, sample the
+        rows past their prompt, retire the finished."""
+        now = time.monotonic()
+        for i, live in enumerate(self._slots):
+            if live is None:
+                continue
+            live.pos += 1
+            # keep the pool's written-token watermark current so
+            # fragmentation never over-reports a mid-page tail
+            self.pool.note_written(live.seq_id, live.pos)
+            if live.pos < len(live.feed):
+                # prefill: the next token is given, logits ignored
+                live.next_token = live.feed[live.pos]
+                self._tokens[i] = live.next_token
+                self._slens[i] = live.pos
+                if live.tspan is not None:
+                    live.tspan.ref_step(dispatch)
+                continue
+            tok = int(self._sample(logits[i], live))
+            if live.tspan is not None:
+                live.tspan.ref_step(dispatch)
+            if not live.generated:
+                live.req.t_first_token = now
+                with self._lat_lock:
+                    self._ttfts.append(now - live.req.t_submit)
+                if self.registry is not None:
+                    self.registry.histogram(
+                        "serving/ttft_ms").observe(
+                        (now - live.req.t_submit) * 1e3,
+                        exemplar=(live.req.trace.trace_id
+                                  if live.req.trace is not None
+                                  else None))
+                if live.tspan is not None:
+                    live.tspan.to_decode()
+            live.generated.append(tok)
+            self.tokens_generated += 1
+            done = (len(live.generated) >= live.max_new
+                    or (self.eos_id >= 0 and tok == self.eos_id))
+            if done:
+                self._finish(i, live)
+            else:
+                live.next_token = tok
+                self._tokens[i] = tok
+                self._slens[i] = live.pos
 
     def _sample(self, row_logits: np.ndarray, live: _Live) -> int:
         if live.req.temperature <= 0.0:  # greedy hot path: one argmax

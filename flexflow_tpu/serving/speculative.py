@@ -81,10 +81,10 @@ class Proposer:
         return {}
 
     def trace_attrs(self) -> Dict:
-        """Small JSON-safe attribute dict stamped onto each spec_verify
-        batch span (obs/reqtrace.py) — which drafter produced the
+        """Small JSON-safe attribute dict stamped onto each round's
+        `sched.spec.verify.dispatch` span — which drafter produced the
         round's proposals, plus any cheap per-proposer counters.
-        Called on the scheduler worker thread, once per traced round."""
+        Called on the scheduler worker thread, once per round."""
         return {"proposer": type(self).__name__}
 
 

@@ -25,6 +25,7 @@ from flexflow_tpu.checkpoint import (
     LocalCheckpointManager,
 )
 from flexflow_tpu.fftype import ActiMode
+from flexflow_tpu.obs.trace import spans
 from flexflow_tpu.resilience import (
     FaultKind,
     FaultPlan,
@@ -528,11 +529,12 @@ def test_ckpt_spans_and_counters(devices8, tmp_path):
     ff = _model(devices8, telemetry=True)
     ff.fit(xs, ys, epochs=1, verbose=False)
     mgr = LocalCheckpointManager(str(tmp_path / "t"))
+    before = {r.span_id for r in spans()}
     mgr.save(ff, step=1, wait=True)
     mgr.save(ff, step=2, wait=False)
     assert mgr.drain() == []
 
-    names = [e["name"] for e in ff.telemetry.tracer.events if e["ph"] == "B"]
+    names = [r.name for r in spans() if r.span_id not in before]
     assert names.count("checkpoint_write") == 2
     assert names.count("snapshot") == 2
     assert names.count("flush") == 2  # sync inline + async on the writer tid

@@ -18,7 +18,8 @@ import pytest
 from flexflow_tpu.obs.metrics import MetricsRegistry, to_prometheus
 from flexflow_tpu.obs.reqtrace import (FRONT_PID, NULL_REQTRACER,
                                        ReqTracer)
-from flexflow_tpu.obs.trace import span_allocations
+from flexflow_tpu.obs.reqtrace import span_allocations
+from flexflow_tpu.obs.trace import Tracer, span
 from flexflow_tpu.serving import DisaggServingFront
 from flexflow_tpu.serving.scheduler import ContinuousScheduler
 from flexflow_tpu.serving.server import serve_http
@@ -123,25 +124,43 @@ def test_wire_round_trips_and_begin_remote_joins_tree():
     assert tr.begin_remote({"parent": 3}, "kv_adopt") is None
 
 
-def test_batch_spans_chrome_export_and_write(tmp_path):
-    tr = ReqTracer(run_id="r0")
+def test_shared_dispatch_span_chrome_export_through_the_single_writer(
+        tmp_path):
+    """The scheduler's per-dispatch host span is recorded once for the
+    sampled requests that rode it; the run's one writer (`Tracer.write`)
+    merges the request tracks into the ring's dump."""
+    writer = Tracer(run_id="r0")
+    tr = ReqTracer()
     ctx = tr.trace()
-    b = tr.batch_span("decode_step", pid=2, rows=2)
-    b.end()
+    with span("sched.decode.dispatch", rows=2) as dispatch:
+        pass
+    tr.shared_span(dispatch, pid=2)
     ctx.finish(ok=True)
     events = tr.chrome_events()
     x = [e for e in events if e["ph"] == "X"]
     meta = [e for e in events if e["ph"] == "M"]
-    assert {e["name"] for e in x} == {"decode_step", "request"}
-    batch_ev = next(e for e in x if e["name"] == "decode_step")
+    assert {e["name"] for e in x} == {"sched.decode.dispatch", "request"}
+    batch_ev = next(e for e in x if e["name"] == "sched.decode.dispatch")
     assert batch_ev["pid"] == 2 and "trace_id" not in batch_ev["args"]
+    assert batch_ev["args"]["span_id"] == dispatch.span_id
+    assert batch_ev["args"]["rows"] == 2
     assert {e["args"]["name"] for e in meta} == \
         {"serving front", "serving replica 2"}
     path = tmp_path / "trace.json"
-    assert tr.write(str(path)) == len(events)
+    writer.write(str(path), extra_events=events)
     doc = json.loads(path.read_text())
     assert doc["otherData"]["run_id"] == "r0"
-    assert len(doc["traceEvents"]) == len(events)
+    got = doc["traceEvents"]
+    ts = [e["ts"] for e in got]
+    assert ts == sorted(ts)
+    # the ring's copy of the dispatch span and the request tracks, on
+    # one clock: the request began before the dispatch and ended after
+    ring_ev = next(e for e in got if e.get("cat") == "span"
+                   and e["args"]["span_id"] == dispatch.span_id)
+    req_ev = next(e for e in got if e["name"] == "request")
+    assert req_ev["ts"] <= ring_ev["ts"]
+    assert ring_ev["ts"] + ring_ev["dur"] <= req_ev["ts"] + req_ev["dur"]
+    assert len([e for e in got if e.get("cat") != "span"]) == len(events)
 
 
 def test_span_overflow_drops_not_grows():
@@ -324,7 +343,7 @@ def test_spec_verify_rounds_ride_shared_batch_spans():
     assert dec["args"]["spec_rounds"] > 0
     assert dec["args"]["spec_accepted"] == dec["args"]["spec_proposed"] > 0
     verify = [batch[r] for r in dec["args"]["batch_spans"]
-              if batch[r]["name"] == "spec_verify"]
+              if batch[r]["name"] == ta.SPEC_VERIFY_SPAN]
     assert verify
     assert all(v["args"]["proposer"] == "NGramProposer" for v in verify)
     # the analyzer buckets referenced verify time into spec_verify

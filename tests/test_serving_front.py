@@ -296,6 +296,14 @@ def test_front_metrics_and_summary(tmp_path):
         hs = [front.generate_async([1 + i], 6) for i in range(4)]
         for h in hs:
             h.wait(30.0)
+        # the first request goes to replica 0, so its death on its third
+        # step is certain; its restart, on the supervisor's thread, is
+        # not over when the requeued requests finish elsewhere.  Wait
+        # for it on the front's own condition (every replica state
+        # change notifies it) before closing.
+        with front._cv:
+            assert front._cv.wait_for(
+                lambda: front.replicas[0].restarts >= 1, timeout=30.0)
         front.stats()  # refreshes the replicas_live gauge
     finally:
         front.close()

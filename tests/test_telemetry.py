@@ -1,6 +1,6 @@
 """Unified run telemetry (flexflow_tpu/obs/): trace-event schema,
 metrics-registry semantics, named_scope HLO attribution, fidelity
-records, and the zero-cost disabled path."""
+records, and what a run without a trace_dir leaves behind."""
 import json
 import logging
 import os
@@ -13,8 +13,8 @@ from flexflow_tpu.obs import (
     MetricsRegistry,
     RunTelemetry,
     parse_profile_steps,
-    span_allocations,
 )
+from flexflow_tpu.obs import trace
 from flexflow_tpu.obs.metrics import emit_counters
 
 
@@ -35,23 +35,22 @@ def _data(n=64, in_dim=32, classes=10):
             rng.randint(0, classes, n).astype(np.int32))
 
 
-def _match_be_pairs(events):
-    """Walk B/E events per (pid, tid) with stack discipline; returns
-    the matched (name, dur) list and asserts nothing dangles."""
-    stacks, pairs = {}, []
-    for ev in events:
-        key = (ev["pid"], ev["tid"])
-        if ev["ph"] == "B":
-            stacks.setdefault(key, []).append(ev)
-        elif ev["ph"] == "E":
-            stack = stacks.get(key)
-            assert stack, f"E event with empty stack: {ev}"
-            b = stack.pop()
-            assert ev["ts"] >= b["ts"]
-            pairs.append((b["name"], ev["ts"] - b["ts"]))
-    for key, stack in stacks.items():
-        assert not stack, f"unclosed B events on {key}: {stack}"
-    return pairs
+def _span_durations(events):
+    """(name, dur) of the run's spans in a trace.json: complete "X"
+    events of the ring's dump, each with a span id and non-negative
+    duration; a child names a parent that is in the document."""
+    spans = [e for e in events if e.get("cat") == "span"]
+    ids = {e["args"]["span_id"] for e in spans}
+    assert len(ids) == len(spans)
+    for e in spans:
+        assert e["ph"] == "X" and e["dur"] >= 0
+    named = {e["args"]["span_id"]: e for e in spans}
+    for e in spans:
+        parent = named.get(e["args"].get("parent_id"))
+        if parent is not None:
+            assert parent["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+    return [(e["name"], e["dur"]) for e in spans]
 
 
 # ---------------------------------------------------------------------------
@@ -74,14 +73,16 @@ def test_fit_trace_and_telemetry_8dev(tmp_path, devices8):
     assert events
     ts = [e["ts"] for e in events]
     assert ts == sorted(ts)  # serialized sorted by timestamp
-    pairs = _match_be_pairs(events)
-    names = [n for n, _ in pairs]
-    # 4 batches/epoch x 2 epochs; one step + one host_transfer span each
-    assert names.count("step") == 8
+    names = [n for n, _ in _span_durations(events)]
+    # 4 batches/epoch x 2 epochs; one train_step with its children each,
+    # and one wait on the loader a batch plus the one that ends an epoch
+    assert names.count("train_step") == 8
     assert names.count("host_transfer") == 8
+    assert names.count("train_step.dispatch") == 8
+    assert names.count("fit.dataloader_wait") == 10
+    assert names.count("device_drain") == 2
     assert "compile" in names
     assert "init_weights" in names  # the eager XLA compile inside compile()
-    assert all(d >= 0 for _, d in pairs)
 
     recs = [json.loads(line)
             for line in open(os.path.join(td, "run_telemetry.jsonl"))]
@@ -90,7 +91,7 @@ def test_fit_trace_and_telemetry_8dev(tmp_path, devices8):
     for r in recs:
         by_kind.setdefault(r["kind"], []).append(r)
     hists = {r["name"]: r for r in by_kind["histogram"]}
-    assert hists["fit/step_ms"]["count"] == 8
+    assert hists["fit/dispatch_ms"]["count"] == 8
     gauges = {r["name"]: r for r in by_kind["gauge"]}
     assert gauges["compile/total_ms"]["value"] > 0
     assert "fit/metrics/train_all" in gauges  # PerfMetrics unified
@@ -123,7 +124,7 @@ def test_supervisor_emits_checkpoint_and_restart_spans(tmp_path, devices8):
 
     with open(os.path.join(td, "trace.json")) as f:
         events = json.load(f)["traceEvents"]
-    names = [n for n, _ in _match_be_pairs(events)]
+    names = [n for n, _ in _span_durations(events)]
     assert "checkpoint_write" in names
     assert "restart" in names
     recs = [json.loads(line)
@@ -161,8 +162,8 @@ def test_crashed_fit_still_writes_artifacts(tmp_path, devices8):
                callbacks=[Crasher()])
     with open(os.path.join(td, "trace.json")) as f:
         events = json.load(f)["traceEvents"]
-    names = [n for n, _ in _match_be_pairs(events)]
-    assert names.count("step") == 4  # epoch 0's steps made it to disk
+    names = [n for n, _ in _span_durations(events)]
+    assert names.count("train_step") == 4  # epoch 0's steps made it to disk
     assert os.path.exists(os.path.join(td, "run_telemetry.jsonl"))
 
 
@@ -265,17 +266,27 @@ def test_named_scope_op_names_in_step_hlo():
 
 
 # ---------------------------------------------------------------------------
-# disabled path: zero allocation on the step hot path
+# no trace_dir: spans are recorded all the same, nothing is written
 # ---------------------------------------------------------------------------
 
-def test_disabled_fit_allocates_no_spans():
+def test_fit_without_trace_dir_writes_no_file_and_ring_stays_bounded(
+        tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
     cfg = FFConfig(batch_size=16, num_devices=1)
     ff = _build_mlp(cfg)
-    assert not ff.telemetry.enabled
+    assert not ff.telemetry.enabled and ff.telemetry.trace_dir is None
     X, y = _data(64)
-    before = span_allocations()
+    before = {r.span_id for r in trace.spans()}
     ff.fit(X, y, batch_size=16, epochs=2, verbose=False)
-    assert span_allocations() == before
+    mine = [r for r in trace.spans() if r.span_id not in before]
+    # one code path: the spans exist whatever the configuration says
+    assert sum(r.name == "train_step" for r in mine) == 8
+    assert sum(r.name == "train_step.dispatch" for r in mine) == 8
+    assert len(trace.spans()) <= trace.RING_SIZE
+    assert ff.telemetry.flush() == {}
+    assert list(tmp_path.iterdir()) == []  # no trace.json, no JSONL
+    # the registry is in memory only; the renamed histogram counts steps
+    assert ff.telemetry.metrics.histogram("fit/dispatch_ms").count == 8
 
 
 # ---------------------------------------------------------------------------

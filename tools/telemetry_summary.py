@@ -100,7 +100,7 @@ def summarize(records: List[Dict]) -> str:
     events = [r for r in records if r.get("kind") == "event"]
     out: List[str] = []
 
-    step = metrics.get("fit/step_ms")
+    step = metrics.get("fit/dispatch_ms")
     rows = []
     if step:
         rows += [
@@ -115,6 +115,13 @@ def summarize(records: List[Dict]) -> str:
     tput = metrics.get("fit/throughput_sps")
     if tput:
         rows.append(("throughput samples/s", tput.get("value", 0.0)))
+    # the last epoch's PerfMetrics (fit/metrics/*: counts, loss sums,
+    # accuracy)
+    rows += [
+        (name.split("/", 2)[-1], rec.get("value", 0.0))
+        for name, rec in sorted(metrics.items())
+        if name.startswith("fit/metrics/")
+    ]
     out.append(_section("Steps", rows))
 
     rows = [
@@ -123,6 +130,9 @@ def summarize(records: List[Dict]) -> str:
         for name, rec in sorted(metrics.items())
         if name.startswith("compile/")
     ]
+    fallback = metrics.get("parallel/zero_fallback_leaves")
+    if fallback:
+        rows.append(("zero fallback leaves", fallback.get("value", 0)))
     out.append(_section("Compile (ms)", rows))
 
     rows = [

@@ -27,6 +27,9 @@ from typing import Dict, List, Optional, Tuple
 #: overlaps the decode phase rather than extending the critical path)
 PHASES = ("queue", "dispatch", "prefill", "migration", "kv_adopt",
           "decode", "spec_verify")
+#: the scheduler's host span around one verify dispatch, shared by the
+#: traced requests that rode it
+SPEC_VERIFY_SPAN = "sched.spec.verify.dispatch"
 
 
 def load_records(path: str) -> List[Dict]:
@@ -53,8 +56,9 @@ def load_records(path: str) -> List[Dict]:
 def build_traces(records: List[Dict]
                  ) -> Tuple[Dict[str, List[Dict]], Dict[int, Dict]]:
     """(traces, batch_spans): spans grouped by trace_id, plus the
-    shared batch spans (trace_id None — prefill_chunk / decode_step /
-    spec_verify dispatches) indexed by span_id for ref resolution."""
+    shared dispatch spans (trace_id None — `sched.prefill.dispatch` /
+    `sched.decode.dispatch` / `sched.spec.verify.dispatch`) indexed by
+    span_id for ref resolution."""
     traces: Dict[str, List[Dict]] = {}
     batch: Dict[int, Dict] = {}
     for rec in records:
@@ -94,7 +98,7 @@ def phase_breakdown(spans: List[Dict], batch: Dict[int, Dict]
             out[name] = out.get(name, 0.0) + float(s.get("dur_us", 0.0))
         for ref in (s.get("args") or {}).get("batch_spans") or ():
             b = batch.get(ref)
-            if b is not None and b["name"] == "spec_verify":
+            if b is not None and b["name"] == SPEC_VERIFY_SPAN:
                 out["spec_verify"] = (out.get("spec_verify", 0.0)
                                       + float(b.get("dur_us", 0.0)))
     return out
