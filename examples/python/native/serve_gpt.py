@@ -84,7 +84,7 @@ def main():
         # engine (--prefill-chunk / --no-prefix-cache)
         ff.config.prefill_chunk = serving_cfg.prefill_chunk
         ff.config.prefix_cache = serving_cfg.prefix_cache
-        # --paged-kernel {gather,pallas}: which paged-attention
+        # --paged-kernel {auto,gather,pallas}: which paged-attention
         # formulation every replica's decode step runs (validated +
         # logged at engine build, docs/SERVING.md "Fused paged
         # attention")

@@ -48,8 +48,10 @@ SERVING_MODES = ("continuous", "static")
 # valid FFConfig.paged_kernel values (docs/SERVING.md "Fused paged
 # attention"): "gather" = the dense block-gather formulation, the
 # bit-identity reference oracle; "pallas" = the fused PagedAttention
-# kernel reading KV blocks in place (ops/pallas/paged_attention.py).
-PAGED_KERNELS = ("gather", "pallas")
+# kernel reading KV blocks in place (ops/pallas/paged_attention.py);
+# "auto" (the default) = whichever of the two the backend runs well,
+# decided at engine build (resolve_paged_kernel).
+PAGED_KERNELS = ("auto", "gather", "pallas")
 
 # valid FFConfig.kv_transfer values (serving/kv_transfer.py): the
 # fabric a disaggregated fleet streams KV blocks over — "inproc" =
@@ -72,18 +74,29 @@ class ConfigError(ValueError):
 
 
 def resolve_paged_kernel(paged_kernel: str) -> str:
-    """Validate the paged-attention formulation choice against this
-    runtime.  The "pallas" kernel needs jax.experimental.pallas; when
-    it is missing, selecting the kernel raises ConfigError HERE — at
-    engine build time — instead of an ImportError from inside a trace.
-    Returns the validated value."""
+    """The paged-attention formulation an engine built now will run:
+    "gather" or "pallas", never "auto".
+
+    "auto" follows the backend, the one thing that decides which of
+    the two is the fast one: on a TPU the kernel Mosaic compiles reads
+    each row's live pages in place; anywhere else the kernel exists
+    only under the Pallas interpreter (a test vehicle), so the dense
+    gather runs.  An explicit value is validated against this runtime:
+    "pallas" needs jax.experimental.pallas, and when it is missing,
+    selecting the kernel raises ConfigError HERE — at engine build time
+    — instead of an ImportError from inside a trace."""
     if paged_kernel not in PAGED_KERNELS:
         raise ConfigError(
             f"paged_kernel must be one of {PAGED_KERNELS}, "
             f"got {paged_kernel!r}")
-    if paged_kernel == "pallas":
-        from .ops.pallas.paged_attention import have_paged_kernel
+    from .ops.pallas.paged_attention import have_paged_kernel
 
+    if paged_kernel == "auto":
+        import jax
+
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and have_paged_kernel() else "gather"
+    if paged_kernel == "pallas":
         if not have_paged_kernel():
             raise ConfigError(
                 "--paged-kernel pallas needs jax.experimental.pallas, "
@@ -394,11 +407,13 @@ class FFConfig:
     # paged-attention read formulation (docs/SERVING.md "Fused paged
     # attention"): "gather" keeps the dense block-gather view — the
     # bit-identity reference oracle; "pallas" runs the fused
-    # PagedAttention kernel that streams KV blocks in place through
-    # the block table, so per-step HBM reads scale with live tokens
-    # instead of decode_max_seq.  Validated against the runtime at
-    # engine build time (resolve_paged_kernel).
-    paged_kernel: str = "gather"
+    # PagedAttention kernel that reads each row's live KV pages in
+    # place through the block table, so a step's cost follows live
+    # tokens instead of slots x decode_max_seq.  "auto" resolves by
+    # backend at engine build time (resolve_paged_kernel): the kernel
+    # on a TPU, the gather everywhere else; the explicit values are
+    # for the tests and the oracle.
+    paged_kernel: str = "auto"
     # replicated front (serving/front.py, docs/SERVING.md "Replicated
     # front"): N supervised ContinuousScheduler replicas behind one
     # admission queue.  1 = single supervised replica (still gains the
@@ -851,7 +866,7 @@ class FFConfig:
         p.add_argument("--no-prefix-cache", dest="prefix_cache",
                        action="store_false")
         p.add_argument("--paged-kernel", dest="paged_kernel", type=str,
-                       default="gather", choices=PAGED_KERNELS)
+                       default="auto", choices=PAGED_KERNELS)
         p.add_argument("--serving-replicas", dest="serving_replicas",
                        type=int, default=1)
         p.add_argument("--serving-step-timeout",

@@ -7,27 +7,43 @@ reference oracle) materializes a dense ``[slots, decode_max_seq, h, d]``
 K/V view from the block pool every step, so per-step HBM traffic is
 proportional to the TABLE WIDTH regardless of how many tokens are
 actually live.  This kernel instead makes the block table part of the
-kernel's index maps: grid ``(slots, table_width)`` with the table and
-the per-slot sequence lengths as SCALAR-PREFETCH operands, so the K/V
-BlockSpecs resolve ``(block_table[i, kb], 0, 0, 0)`` — Pallas's
+kernel's index maps: grid ``(slots, table_width / P)`` with the table
+and the per-slot sequence lengths as SCALAR-PREFETCH operands, and P
+K and P V BlockSpecs a grid program (``PAGES_PER_STEP``), the p-th of
+which resolves ``(block_table[i, kb * P + p], 0, 0, 0)`` — Pallas's
 pipeline DMAs exactly the physical pages a row owns, straight from the
-pool's HBM layout, no dense view ever exists.
+pool's HBM layout, no dense view ever exists.  Every shape and the
+grid are static: how many tokens are live is data, so one program
+serves every mix of lengths.
 
 Block shapes (what Mosaic accepts for the pool layout
-``[num_blocks, page, h, d]``): one grid step takes ALL local heads of
-one physical page — K/V blocks ``(1, page, h, d)``, whose last two
-dims equal the array's (the TPU rule: divisible by (8, 128) or equal
-to the full dim; a per-head ``(1, page, 1, d)`` block is refused for
-every h > 1).  Under `--serving-tp` the shard_map'd local pool is
-``[nb, page, h/tp, d]`` and the same rule holds.  In VMEM the page is
-swapped to ``[h, page, d]`` and both dots are batched over heads.
+``[num_blocks, page, h, d]``): a K/V block is ALL local heads of one
+physical page, ``(1, page, h, d)``, whose last two dims equal the
+array's (the TPU rule: divisible by (8, 128) or equal to the full dim;
+a per-head ``(1, page, 1, d)`` block is refused for every h > 1).
+Under `--serving-tp` the shard_map'd local pool is
+``[nb, page, h/tp, d]`` and the same rule holds.  (Leaving the pool in
+HBM and copying pages by hand, with a trip count that follows the
+row's length, is refused by Mosaic for d = 64: a slice of a ref whose
+minor dim is padded to 128 lanes.)
+
+The math stays in that layout, heads on sublanes and the head dim on
+lanes, and runs on the VECTOR unit: with one query a row a score is
+the lane reduction of ``k * q`` and the context the sum over key
+positions of ``p * v``; bf16 products are exact in f32.  Handing the
+MXU a matrix would mean transposing every page to head-major first
+(what this kernel did until PR 28: 0.47 us a page against 0.30 now, my
+chip run, PR 28).
 
 Traffic discipline: a row with ``pos`` tokens live owns
-``pos // page + 1`` blocks.  Grid steps past that are mapped to the
+``pos // page + 1`` blocks.  Table columns past that are mapped to the
 row's LAST live block — a repeated block index, which Pallas's
 pipeline elides (no re-fetch) — and their compute is skipped with
 ``pl.when``, so per-step HBM reads scale with live tokens, not
-``decode_max_seq``.  Partial tail blocks and the scratch rows idle
+``decode_max_seq``.  What does NOT scale with live tokens is the grid:
+``slots * table_width / P`` programs of ``2 P + 1`` index maps each,
+0.07 ms a layer at the serving cell's 16 x 64 (PERF.md, PR 28).
+Partial tail blocks and the scratch rows idle
 slots park on (table all zeros, seq_len 0) are handled by the same
 per-position mask the gather oracle uses: key positions past a row's
 own length never enter the softmax.
@@ -44,7 +60,7 @@ Two entry points mirror the host-side twins (decoding.py):
     read side.
 
 Both accumulate the online softmax in f32 (m/l running columns + an
-[h, s, d] accumulator in VMEM scratch carried across the kb grid
+[s, h, d] accumulator in VMEM scratch carried across the kb grid
 axis), like ops/pallas/flash_attention.py.  On the CPU backend the
 same kernel runs under ``interpret=True`` — the parity tests
 (tests/test_paged_kernel.py) execute the real kernel logic against
@@ -61,6 +77,11 @@ import jax.numpy as jnp
 import numpy as np
 
 _NEG_INF = -1e30
+
+#: physical pages one grid program folds (paged_attention): the grid is
+#: (rows, table_width / this), so a dispatch's fixed cost follows it,
+#: and a row's live pages are fetched this many at a time
+PAGES_PER_STEP = 8
 
 try:  # lazy-safe: CPU-only envs without pallas never touch the kernel
     from jax.experimental import pallas as pl
@@ -105,12 +126,62 @@ def blocks_read(seq_lens: np.ndarray, live_mask: np.ndarray, chunk: int,
     return int(per_row.sum())
 
 
-def _paged_kernel(btab_ref, slen_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page: int, scale: float,
-                  table_width: int, chunk: int):
-    """One grid program = (row i, table column kb): fold the physical
-    page `block_table[i, kb]` — all heads of it — into row i's online
-    softmax."""
+def scan_blocks_read(seq_lens: np.ndarray, counts: np.ndarray,
+                     page: int, table_width: int) -> int:
+    """`blocks_read` for a program that SCANS the seq-1 read: row i
+    runs `counts[i]` positions starting at `seq_lens[i]` (0 = the row
+    is idle or rides along on scratch), each a seq-1 dispatch over the
+    row's prefix so far.  A decode dispatch is counts in {0, 1}; the
+    scanned prefill and verify programs count up to their chunk."""
+    pos = np.asarray(seq_lens, np.int64)[:, None]
+    counts = np.asarray(counts, np.int64)[:, None]
+    j = np.arange(max(int(counts.max(initial=0)), 1))[None, :]
+    last = np.minimum(pos + j, table_width * page - 1)
+    return int(np.where(j < counts, last // page + 1, 0).sum())
+
+
+def _fold_page(q_ref, k, v, m_ref, l_ref, acc_ref, col, pos, *,
+               page: int, scale: float):
+    """Fold one physical page (table column `col`) into the row's
+    online softmax.  k, v: [page, h, d] as the pool holds them."""
+    # bf16 products are exact in f32: operands as the pool holds them,
+    # accumulation in f32
+    q = q_ref[0].astype(jnp.float32) * scale   # [chunk, h, dk]
+    k = k.astype(jnp.float32)
+    v = v.astype(jnp.float32)
+    s = jnp.sum(q[:, None] * k[None], axis=-1,
+                keepdims=True)                 # [chunk, page, h, 1]
+    # chunk token j attends key positions <= pos + j: causal within
+    # the chunk, visible-prefix across steps — exactly the gather
+    # oracle's mask, so partial tail blocks and scratch rows (pos 0,
+    # all-zero table) fall out of the same comparison
+    k_pos = col * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+    m_prev = m_ref[...]                        # [chunk, h, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    pr = jnp.exp(s - m_new[:, None])
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=1)
+    acc_ref[...] = acc_ref[...] * corr + jnp.sum(
+        pr * v[None], axis=1)                  # [chunk, h, dv]
+    m_ref[...] = m_new
+
+
+def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
+                  scale: float, table_width: int, chunk: int, pages: int):
+    """One grid program = (row i, table columns kb*pages ..
+    kb*pages+pages-1): fold up to `pages` physical pages of row i —
+    all heads of each — into the row's online softmax.
+
+    The page stays in the pool's own layout ``[page, h, d]`` (heads on
+    sublanes, the head dim on lanes) and the math runs on the vector
+    unit: a score is the lane reduction of ``k * q``, the context the
+    sum over key positions of ``p * v``.  With ONE query a row there
+    is no matrix to hand the MXU without first transposing every page
+    to head-major, which costs more than the products themselves."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     i = pl.program_id(0)
     kb = pl.program_id(1)
 
@@ -123,35 +194,15 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, k_ref, v_ref, o_ref,
     pos = slen_ref[i]
     live = _live_block_count(pos, chunk, page, table_width)
 
-    @pl.when(kb < live)
-    def _fold():
-        q = q_ref[0]                      # [h, chunk, dk]
-        k = jnp.swapaxes(k_ref[0], 0, 1)  # [page, h, dk] -> [h, page, dk]
-        v = jnp.swapaxes(v_ref[0], 0, 1)  # [h, page, dv]
-        if k.dtype != q.dtype:  # VMEM-tile cast (bf16 query, f32 pool)
-            k = k.astype(q.dtype)
-        s = jnp.einsum(
-            "hsd,hpd->hsp", q, k, preferred_element_type=jnp.float32,
-        ) * scale  # [h, chunk, page] f32
-        # chunk token j attends key positions <= pos + j: causal within
-        # the chunk, visible-prefix across steps — exactly the gather
-        # oracle's mask, so partial tail blocks and scratch rows
-        # (pos 0, all-zero table) fall out of the same comparison
-        k_pos = kb * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m_prev = m_ref[...]               # [h, chunk, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
-            "hsp,hpd->hsd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+    for p in range(pages):
+        col = kb * pages + p
 
-    @pl.when(kb == table_width - 1)
+        @pl.when(col < live)
+        def _fold(p=p, col=col):
+            _fold_page(q_ref, k_refs[p][0], v_refs[p][0], m_ref, l_ref,
+                       acc_ref, col, pos, page=page, scale=scale)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
     def _write():
         l = l_ref[...]
         l_safe = jnp.where(l > 0.0, l, 1.0)
@@ -159,7 +210,8 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
-                    scale: float, *, interpret: Optional[bool] = None):
+                    scale: float, *, interpret: Optional[bool] = None,
+                    pages_per_step: int = PAGES_PER_STEP):
     """Fused paged attention over the pool.
 
     qh:          [b, s, h, dk]  this step's queries (s = 1 or chunk C)
@@ -170,6 +222,10 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
                  occupies positions seq_lens[i] .. seq_lens[i]+s-1,
                  already scattered into the pool by the caller)
     ->           [b, s, h, dv] context, qh's dtype
+
+    Every shape and the grid are static in (b, s, table_width): how
+    many tokens are live is DATA (`seq_lens`), so one program serves
+    every mix of lengths.
 
     `interpret` defaults from the backend: the Mosaic-compiled kernel
     on TPU, the Pallas interpreter on CPU (the parity-test vehicle).
@@ -185,43 +241,52 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
     b, s, h, dk = qh.shape
     page, dv = k_pool.shape[1], v_pool.shape[-1]
     table_width = block_table.shape[1]
-    qt = qh.transpose(0, 2, 1, 3)  # [b, h, s, dk]
+    pages = max(1, min(int(pages_per_step), table_width))
     block_table = block_table.astype(jnp.int32)
     seq_lens = seq_lens.reshape(b).astype(jnp.int32)
 
     def q_map(i, kb, btab, slen):
         return i, 0, 0, 0
 
-    def kv_map(i, kb, btab, slen):
-        # out-of-range kb repeats the row's last live block: Pallas
-        # elides the re-fetch, so HBM traffic follows live tokens
-        live = _live_block_count(slen[i], s, page, table_width)
-        return btab[i, jnp.minimum(kb, live - 1)], 0, 0, 0
+    # a column past the row's live pages repeats its LAST live block:
+    # Pallas elides the re-fetch, so HBM traffic follows live tokens.
+    # The clamp is applied to the table HERE, once a dispatch (XLA
+    # shares it between the layers of a pass): the scalar core then
+    # evaluates 2 * pages index maps a grid step, each one SMEM load
+    # (measured, PERF.md PR 28: a fifth of a short row's cost when the
+    # maps did the clamp themselves)
+    steps = -(-table_width // pages)
+    live = _live_block_count(seq_lens, s, page, table_width)
+    cols = jnp.arange(steps * pages, dtype=jnp.int32)[None, :]
+    block_table = jnp.take_along_axis(
+        block_table, jnp.minimum(cols, live[:, None] - 1), axis=1)
+
+    def kv_map(p):
+        def index(i, kb, btab, slen):
+            return btab[i, kb * pages + p], 0, 0, 0
+        return index
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, table_width),
-        in_specs=[
-            pl.BlockSpec((1, h, s, dk), q_map),
-            pl.BlockSpec((1, page, h, dk), kv_map),
-            pl.BlockSpec((1, page, h, dv), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, h, s, dv), q_map),
+        grid=(b, steps),
+        in_specs=[pl.BlockSpec((1, s, h, dk), q_map)]
+        + [pl.BlockSpec((1, page, h, dk), kv_map(p)) for p in range(pages)]
+        + [pl.BlockSpec((1, page, h, dv), kv_map(p)) for p in range(pages)],
+        out_specs=pl.BlockSpec((1, s, h, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((h, s, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, s, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((h, s, dv), jnp.float32),  # context accumulator
+            pltpu.VMEM((s, h, 1), jnp.float32),   # running max
+            pltpu.VMEM((s, h, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((s, h, dv), jnp.float32),  # context accumulator
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_paged_kernel, page=page, scale=scale,
-                          table_width=table_width, chunk=s),
+                          table_width=table_width, chunk=s, pages=pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, dv), qh.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, h, dv), qh.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(block_table, seq_lens, qt, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)
+    )(block_table, seq_lens, qh, *([k_pool] * pages), *([v_pool] * pages))
 
 
 def paged_decode_attention(qh, k_pool, v_pool, block_table, seq_lens,

@@ -363,7 +363,7 @@ class ServingFront:
                         page_size=cfg.kv_page_size,
                         devices=devs,
                         paged_kernel=getattr(cfg, "paged_kernel",
-                                             "gather"),
+                                             "auto"),
                     )
                 model = PagedKVDecodeModel(
                     ff_train,
@@ -373,7 +373,7 @@ class ServingFront:
                     devices=devs,
                     prefill_chunk=getattr(cfg, "prefill_chunk", 0),
                     prefix_cache=getattr(cfg, "prefix_cache", True),
-                    paged_kernel=getattr(cfg, "paged_kernel", "gather"),
+                    paged_kernel=getattr(cfg, "paged_kernel", "auto"),
                     tp=getattr(cfg, "serving_tp", 1),
                     spec_decode=spec_decode,
                     spec_k=spec_k,

@@ -89,7 +89,7 @@ class PagedKVDecodeModel:
                  page_size: int = 16, num_blocks: Optional[int] = None,
                  devices=None, prefill_chunk: int = 0,
                  prefix_cache: bool = True,
-                 paged_kernel: str = "gather", tp: int = 1,
+                 paged_kernel: str = "auto", tp: int = 1,
                  spec_decode: str = "off", spec_k: int = 4,
                  draft_model=None):
         from ..config import (ConfigError, resolve_serving_tp,
@@ -619,7 +619,7 @@ class ContinuousScheduler:
                      eos_id: int = -1, registry=None,
                      seed: int = 0, prefill_chunk: int = 0,
                      prefix_cache: bool = True,
-                     paged_kernel: str = "gather",
+                     paged_kernel: str = "auto",
                      check_invariants: bool = False,
                      tp: int = 1, spec_decode: str = "off",
                      spec_k: int = 4, draft_ff=None,
@@ -1274,11 +1274,31 @@ class ContinuousScheduler:
         self.step_ms_ewma = (ms if self.step_ms_ewma == 0.0
                              else 0.9 * self.step_ms_ewma + 0.1 * ms)
 
-    def _note_kernel_reads(self, blocks: int, dense_blocks: int):
-        """Account one fused-kernel dispatch's KV reads: `blocks`
-        physical blocks actually streamed vs the `dense_blocks` the
-        gather formulation would have materialized for the same
-        dispatch (obs: serving/paged_kernel_* counters)."""
+    def _kv_reads(self, seq_lens, counts, steps: int = 1) -> Dict:
+        """The `kv_blocks_read` / `kv_blocks_dense` args of a dispatch
+        span: physical KV blocks the dispatch's attention reads
+        against what the dense [slots, decode_max_seq] view holds, for
+        a program that runs `counts[i]` seq-1 positions of row i from
+        `seq_lens[i]` in `steps` scanned passes.  The gather
+        formulation reads the whole view whatever is live."""
+        tw = self.pool.max_blocks_per_seq
+        dense = self.model.batch_slots * tw * steps
+        if self._paged_kernel != "pallas":
+            return {"kv_blocks_read": dense, "kv_blocks_dense": dense}
+        from ..ops.pallas.paged_attention import scan_blocks_read
+
+        return {"kv_blocks_read": scan_blocks_read(
+                    seq_lens, counts, self.pool.page_size, tw),
+                "kv_blocks_dense": dense}
+
+    def _note_kernel_reads(self, reads: Dict) -> None:
+        """Sum one completed dispatch's `_kv_reads` into the fused
+        kernel's counters (stats()["paged_kernel"], obs:
+        serving/paged_kernel_*); they stay zero under the gather."""
+        if self._paged_kernel != "pallas":
+            return
+        blocks = reads["kv_blocks_read"]
+        dense_blocks = reads["kv_blocks_dense"]
         self.kernel_blocks_read += blocks
         self.kernel_dense_blocks += dense_blocks
         if self.registry is None:
@@ -1325,6 +1345,13 @@ class ContinuousScheduler:
                       tokens=real,
                       capacity=self.model.batch_slots * C) as dispatch:
                 self.model.prefill_step(tok, slen, btab)
+                # (the program is enqueued: this runs beside it) the
+                # prefill program scans the seq-1 read C times a plan
+                # row; riders sit on scratch and read nothing
+                counts = np.zeros_like(slen)
+                counts[[i for i, _, _ in plan]] = C
+                reads = self._kv_reads(slen, counts, steps=C)
+                dispatch.set(**reads)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise
@@ -1336,21 +1363,7 @@ class ContinuousScheduler:
                 live.tspan.ref_chunk(dispatch)
         self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.prefill_steps += 1
-        if self._paged_kernel == "pallas":
-            # the prefill program scans the seq-1 kernel C times per
-            # row: account each scan position as one seq-1 dispatch
-            # over the plan rows (shared formula with the kernel:
-            # paged_attention.blocks_read)
-            from ..ops.pallas.paged_attention import blocks_read
-
-            tw = self.pool.max_blocks_per_seq
-            slens = np.array([live.pos for _, live, _ in plan])
-            mask = np.ones(len(plan), bool)
-            blocks = sum(
-                blocks_read(slens + j, mask, 1, self.pool.page_size, tw)
-                for j in range(C))
-            self._note_kernel_reads(
-                blocks, self.model.batch_slots * tw * C)
+        self._note_kernel_reads(reads)
         for i, live, upto in plan:
             live.pos = upto
             # the freshly written prompt blocks join the prefix index
@@ -1440,10 +1453,14 @@ class ContinuousScheduler:
                 self.pool.extend(live.seq_id, live.pos + m,
                                  written=live.pos)
                 self._btab[i] = self.pool.table_row(live.seq_id)
+        # the verify program scans the seq-1 read over each row's fed
+        # positions
+        reads = self._kv_reads(self._slens, counts, steps=C)
         try:
             with span("sched.spec.verify.dispatch",
                       rows=int((counts > 0).sum()),
                       drafted=len(props), fed=int(counts.sum()),
+                      **reads,
                       **self._proposer.trace_attrs()) as dispatch:
                 logits = self.model.verify_step(
                     tok, self._slens, counts, self._btab)
@@ -1469,18 +1486,7 @@ class ContinuousScheduler:
         self.spec_rounds += 1
         if self._spec_t0 is None:
             self._spec_t0 = time.monotonic()
-        if self._paged_kernel == "pallas":
-            from ..ops.pallas.paged_attention import blocks_read
-
-            tw = self.pool.max_blocks_per_seq
-            blocks = 0
-            for j in range(C):
-                mask = counts > j
-                if not mask.any():
-                    break
-                blocks += blocks_read(self._slens + j, mask, 1,
-                                      self.pool.page_size, tw)
-            self._note_kernel_reads(blocks, bs * tw * C)
+        self._note_kernel_reads(reads)
         with self._sample_span():
             self._accept_rows(logits, tok, counts, dispatch)
         if self.registry is not None:
@@ -1667,6 +1673,10 @@ class ContinuousScheduler:
             with span("sched.decode.dispatch", rows=decoding,
                       feeding=feeding,
                       slots=self.model.batch_slots) as dispatch:
+                reads = self._kv_reads(
+                    self._slens,
+                    [live is not None for live in self._slots])
+                dispatch.set(**reads)
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab)
         except Exception as e:
@@ -1682,16 +1692,7 @@ class ContinuousScheduler:
         self._share_dispatch(dispatch, self._slots)
         self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.batches_run += 1
-        if self._paged_kernel == "pallas":
-            from ..ops.pallas.paged_attention import blocks_read
-
-            self._note_kernel_reads(
-                blocks_read(
-                    self._slens,
-                    np.array([s is not None for s in self._slots]),
-                    1, page, self.pool.max_blocks_per_seq),
-                self.model.batch_slots
-                * self.pool.max_blocks_per_seq)
+        self._note_kernel_reads(reads)
         with self._sample_span():
             self._sample_rows(logits, dispatch)
         with span("sched.observe"):

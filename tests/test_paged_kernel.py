@@ -7,7 +7,12 @@ jaxpr assertion that the kernel-path decode step materializes NO dense
 [slots, decode_max_seq] K/V view; and scheduler-level greedy
 token-identity between `--paged-kernel gather` and `pallas` on the
 shared-prefix smoke workload (docs/SERVING.md "Fused paged
-attention")."""
+attention"); and, since PR 28, the kernel as the TPU's default: parity
+and pool bytes at the benchmark cell's own shapes, the default resolved
+by backend, one lowering a step program whatever the lengths, and the
+read share on the dispatch spans."""
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,6 +125,106 @@ def test_kernel_decode_and_chunk_twins_agree():
                                    rtol=2e-6, atol=2e-6)
 
 
+# -- the serving cell's own shapes (gpt2-medium-serve: 16 slots, table
+# width 64, page 16, 16 heads x 64, bf16 pool of 513 blocks) ----------
+
+CELL = dict(b=16, tw=64, page=16, h=16, d=64, nb=513)
+
+
+def _cell_case(s):
+    """Queries, this step's k/v, pools, tables and positions at the
+    cell's shapes.  Rows: 0-3 idle, parked on scratch (zero table,
+    position 0); 4 a live row of length 0; 5 a partial tail page; 6 a
+    page boundary; 7 the last position a step of `s` can start from
+    without pads (max_seq - s); 8 (s > 1 only) a chunk whose pads run
+    past the table; the rest mixed lengths."""
+    c = CELL
+    rng = np.random.RandomState(28 + s)
+    n = c["tw"] * c["page"]
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+
+    kp, vp = (rand(c["nb"], c["page"], c["h"], c["d"]) for _ in "kv")
+    qh, kh, vh = (rand(c["b"], s, c["h"], c["d"]) for _ in "qkv")
+    slen = np.zeros(c["b"], np.int32)
+    slen[5:8] = 37, 16 * 9, n - s
+    slen[8] = n - 1 if s == 1 else n - s + 3
+    slen[9:] = rng.randint(1, n - s, c["b"] - 9)
+    free = iter(rng.permutation(np.arange(1, c["nb"])))
+    btab = np.zeros((c["b"], c["tw"]), np.int32)
+    for i in range(4, c["b"]):
+        # the pool holds half the dense footprint: give a row the
+        # blocks its positions need, as the scheduler would
+        need = min((slen[i] + s - 1) // c["page"] + 1, c["tw"])
+        if i >= 9:
+            need = min(need, 24)
+            slen[i] = min(slen[i], need * c["page"] - s)
+        btab[i, :need] = [next(free) for _ in range(need)]
+    return qh, kh, vh, kp, vp, jnp.asarray(btab), jnp.asarray(slen)
+
+
+class _Attn:
+    """`MultiHeadAttention`'s paged step without a graph around it."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention as _M
+
+    _attend_decode_paged = _M._attend_decode_paged
+    _attend_decode_paged_kernel = _M._attend_decode_paged_kernel
+
+    def __init__(self, kernel):
+        self.params = SimpleNamespace(num_heads=CELL["h"])
+        self._kv_page_size, self._kv_kernel = CELL["page"], kernel
+        self.shard = SimpleNamespace(channel=1)
+
+
+@pytest.mark.parametrize("s", [1, 8])
+def test_step_at_cell_shapes_matches_oracle_and_pool_bytes(s):
+    """The whole paged step (scatter, then read) at the serving cell's
+    shapes: the kernel's context equals the gather oracle's to bf16
+    rounding on every row a scheduler would look at, and the pools
+    come out byte-identical: the read side changed, the writes did
+    not.  (With s = 8 row 8's pads run past the table: the kernel
+    path routes them to scratch block 0, the oracle's are dropped, so
+    block 0 is left out there.)"""
+    qh, kh, vh, kp, vp, btab, slen = _cell_case(s)
+    scale = 1.0 / np.sqrt(CELL["d"])
+    got, gk, gv = _Attn("pallas")._attend_decode_paged(
+        qh, kh, vh, kp, vp, btab, slen, scale)
+    # the oracle's softmax runs in the operands' bf16; judge both
+    # against the same math in float32
+    f32 = [x.astype(jnp.float32) for x in (qh, kh, vh, kp, vp)]
+    want, wk, wv = _Attn("gather")._attend_decode_paged(
+        *f32, btab, slen, scale)
+    n = CELL["tw"] * CELL["page"]
+    real = np.asarray(slen)[:, None] + np.arange(s)[None, :] < n
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[real], np.asarray(want)[real],
+        rtol=1e-2, atol=1e-2)
+    first = 1 if s > 1 else 0
+    for a, b in ((gk, wk), (gv, wv)):
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32)[first:],
+            np.asarray(b.astype(jnp.bfloat16), np.float32)[first:])
+    # the row of length 0 attends its own first token only
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[4, 0],
+        np.asarray(vh, np.float32)[4, 0], rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 8, 64])
+def test_pages_per_step_is_not_semantic(pages):
+    """How many pages a grid program folds only shapes the grid: any
+    value (dividing the table width or not) reads the same context."""
+    rng = np.random.RandomState(5)
+    qh, kp, vp, btab, slen = _random_case(
+        rng, b=4, s=2, h=2, d=8, page=4, table_width=7)
+    want = _gather_oracle(qh, kp, vp, btab, slen, 0.3)
+    got = pk.paged_attention(qh, kp, vp, btab, slen, 0.3,
+                             interpret=True, pages_per_step=pages)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+
+
 def test_blocks_read_scales_with_live_tokens():
     """The host telemetry twin of the kernel's traffic discipline:
     per-step blocks follow live tokens, not the table width — the
@@ -140,6 +245,12 @@ def test_blocks_read_scales_with_live_tokens():
     # ...but never past the table
     assert pk.blocks_read(np.array([30]), np.array([True]), 4, page, tw) \
         == tw
+    # a scanned program is so many seq-1 reads a row: the same sum
+    assert pk.scan_blocks_read(seq_lens, live, page, tw) == 7
+    assert pk.scan_blocks_read(seq_lens, [0, 3, 2, 0], page, tw) == sum(
+        pk.blocks_read(seq_lens + j, np.array([0, 3, 2, 0]) > j, 1,
+                       page, tw) for j in range(3))
+    assert pk.scan_blocks_read(seq_lens, np.zeros(4, int), page, tw) == 0
 
 
 # -- config gate ------------------------------------------------------
@@ -162,9 +273,34 @@ def test_paged_kernel_flag_validated_and_parsed():
         FFConfig(paged_kernel="fused")
     with pytest.raises(ConfigError, match="paged_kernel"):
         resolve_paged_kernel("fused")
-    assert FFConfig.from_args([]).paged_kernel == "gather"
-    assert FFConfig.from_args(
-        ["--paged-kernel", "pallas"]).paged_kernel == "pallas"
+    assert FFConfig().paged_kernel == "auto"
+    assert FFConfig.from_args([]).paged_kernel == "auto"
+    for explicit in ("gather", "pallas"):
+        assert FFConfig.from_args(
+            ["--paged-kernel", explicit]).paged_kernel == explicit
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", "gather"),
+                                          ("gpu", "gather"),
+                                          ("tpu", "pallas")])
+def test_default_formulation_follows_the_backend(monkeypatch, backend,
+                                                 want):
+    """`auto` is resolved from what the code can observe, the backend:
+    the in-place read where Mosaic compiles it, the gather where the
+    kernel would only be interpreted.  Explicit values are honoured on
+    every backend."""
+    from flexflow_tpu.serving.engine import resolve_paged_formulation
+
+    assert jax.default_backend() == "cpu"
+    assert resolve_paged_kernel("auto") == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_paged_kernel("auto") == want
+    assert resolve_paged_formulation("auto") == want
+    for explicit in ("gather", "pallas"):
+        assert resolve_paged_formulation(explicit) == explicit
+    # a jax without Pallas never resolves to the kernel on its own
+    monkeypatch.setattr(pk, "_HAVE_PALLAS", False)
+    assert resolve_paged_kernel("auto") == "gather"
 
 
 def test_dense_cache_rejects_kernel_selection():
@@ -402,3 +538,71 @@ def test_scheduler_greedy_token_identical_gather_vs_kernel(trained,
     assert 0 < kk["blocks_read"] < kk["dense_blocks_equiv"]
     assert kk["bytes_read"] > 0
     assert kk["dense_bytes_avoided"] > 0
+
+
+@pytest.mark.parametrize("paged_kernel", ["auto", "pallas"])
+def test_engine_lowers_each_step_program_once(trained, devices8,
+                                              paged_kernel):
+    """One `jit_step` and one `jit_prefill` an engine, whatever the mix
+    of lengths: the block table and the positions are data, so after
+    the first dispatch of each nothing is lowered again.  Every
+    dispatch span says how many KV blocks its attention read against
+    the dense view's, and the replica's stats carry the sums."""
+    import jax.monitoring as mon
+
+    from flexflow_tpu.obs.trace import spans
+    from flexflow_tpu.serving import ServingFront
+
+    ff, _ = trained
+    lowered, watching = [], [True]
+
+    def on_duration(event, secs, fun_name=None, **_):
+        if watching[0] and event.endswith("jaxpr_to_mlir_module_duration"):
+            lowered.append(fun_name)
+
+    mon.register_event_duration_secs_listener(on_duration)
+    cfg = ff.config
+    old = (cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk,
+           cfg.paged_kernel)
+    cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk = B, 4, 4
+    cfg.paged_kernel = paged_kernel
+    t_start = spans()[-1].t_end if spans() else 0.0
+    try:
+        front = ServingFront.from_trained(ff, devices=devices8[:1])
+        try:
+            rng = np.random.RandomState(2)
+            front.generate_async(rng.randint(0, V, 9).tolist(), 2).wait(120)
+            warm = list(lowered)
+            handles = [front.generate_async(
+                rng.randint(0, V, n).tolist(), m)
+                for n, m in ((1, 3), (6, 2), (11, 4), (3, 5), (13, 2),
+                             (2, 1), (10, 3))]
+            for h in handles:
+                h.wait(120)
+            stats = front.stats()["replicas"][0]["paged_kernel"]
+        finally:
+            front.close(30.0)
+    finally:
+        watching[0] = False
+        (cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk,
+         cfg.paged_kernel) = old
+    # (the module names the profiler shows as jit_step, jit_prefill)
+    assert warm.count("jit(step)") == 1
+    assert warm.count("jit(prefill)") == 1
+    assert lowered[len(warm):] == []
+    want = "gather" if paged_kernel == "auto" else "pallas"  # CPU here
+    assert stats["formulation"] == want
+    dispatches = [r for r in spans() if r.t_start > t_start and r.name in
+                  ("sched.decode.dispatch", "sched.prefill.dispatch")]
+    assert {r.name for r in dispatches} == {"sched.decode.dispatch",
+                                            "sched.prefill.dispatch"}
+    read = sum(r.args["kv_blocks_read"] for r in dispatches)
+    dense = sum(r.args["kv_blocks_dense"] for r in dispatches)
+    assert all(0 <= r.args["kv_blocks_read"] <= r.args["kv_blocks_dense"]
+               for r in dispatches)
+    if want == "pallas":
+        assert 0 < read < dense
+        assert (stats["blocks_read"], stats["dense_blocks_equiv"]) \
+            == (read, dense)
+    else:  # the gather reads the whole dense view
+        assert read == dense and stats["blocks_read"] == 0

@@ -65,8 +65,14 @@ def _flash_cases():
             ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)]
 
 
-def _paged_args(s, dtype, sharding=lambda *_: None):
-    slots, page, h, d, tw, nb = chip_smoke.paged_geometry(FULL)
+#: benchmarks/configs/gpt2-medium-serve.json as its cell runs it: 16
+#: slots, page 16, 16 heads x 64, table width 64, 513 blocks
+CELL_GEOMETRY = (16, 16, 16, 64, 64, 513)
+
+
+def _paged_args(s, dtype, sharding=lambda *_: None, geometry=None):
+    slots, page, h, d, tw, nb = (geometry
+                                 or chip_smoke.paged_geometry(FULL))
 
     def S(shape, dt, kind):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding(kind))
@@ -88,6 +94,11 @@ def _paged_cases():
         for dtype in (jnp.float32, jnp.bfloat16):
             cases.append((f"paged_s{s}_{jnp.dtype(dtype).name}", _paged,
                           _paged_args(s, dtype), 1))
+    # the seq-1 read the serving cell's step programs scan, and the
+    # seq-8 chunk twin, at the cell's own widths
+    for s in (1, 8):
+        cases.append((f"paged_cell_s{s}", _paged, _paged_args(
+            s, jnp.bfloat16, geometry=CELL_GEOMETRY), 1))
     return cases
 
 
