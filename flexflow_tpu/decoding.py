@@ -16,49 +16,79 @@ Two drivers:
     as ONE jitted lax.scan program: zero host round trips until the
     final token buffer lands: T host dispatches and syncs become 1.
 
-`make_gpt_decoder` builds the seq-1 decode twin of a trained
-models.transformer.build_gpt model by introspecting its graph and
-copies the weights across (shapes are seq-independent; the position
-table is shared via build_gpt's max_positions).
+`make_decoder` builds the seq-1 decode twin of a model a `models/`
+builder built: the builder the model recorded (`DecoderRecipe`), called
+again at seq 1 with cache state, and handed the model's weights (shapes
+are seq-independent).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+import dataclasses
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
-from .fftype import LossType, OperatorType
+from .fftype import LossType
 from .model import FFModel
 from .obs.trace import span
 from .optimizer import SGDOptimizer
 
 
-def _gpt_dims(ff: FFModel) -> Dict[str, int]:
-    """Read the build_gpt hyperparameters back off a built graph."""
-    by_name = {op.name: op for op in ff.layers.topo_order()}
-    attn = [
-        op for op in ff.layers.topo_order()
-        if op.op_type == OperatorType.MULTIHEAD_ATTENTION
-    ]
-    if (not attn or "tok_embed" not in by_name or "pos_embed" not in by_name
-            or "ffn1_0" not in by_name):
+@dataclasses.dataclass(frozen=True)
+class DecoderRecipe:
+    """What a `models/` builder records on the model it builds
+    (`ff.decoder_recipe`), so that a decode twin is THAT BUILDER again
+    at seq 1 with cache state, not a graph recognised by its op names.
+
+    `build(ff, batch_size=, seq_length=, **kwargs, decode_max_seq=,
+    kv_page_size=, kv_num_blocks=, kv_kernel=)` rebuilds the graph;
+    `kwargs` are twin-ready (no dropout, the trained position range);
+    `dims` is what the serving tier reads (`num_layers`, `num_heads`,
+    `vocab_size`, `max_seq`, ...); `carries` names what the family
+    supports beyond being built (`require_carried`)."""
+
+    family: str
+    build: Callable
+    kwargs: Dict
+    dims: Dict
+    carries: frozenset
+
+
+def decoder_recipe(ff: FFModel) -> DecoderRecipe:
+    recipe = getattr(ff, "decoder_recipe", None)
+    if recipe is None:
         raise ValueError(
-            "make_gpt_decoder expects a models.transformer.build_gpt "
-            "graph (tok_embed/pos_embed/attn_i/ffn1_i naming)"
-        )
-    p = attn[0].params
-    tok = by_name["tok_embed"].params
-    pos = by_name["pos_embed"].params
-    ffn1 = by_name["ffn1_0"].params
-    return {
-        "num_layers": len(attn),
-        "hidden_size": p.embed_dim,
-        "num_heads": p.num_heads,
-        "dropout": p.dropout,
-        "vocab_size": tok.num_entries,
-        "max_seq": pos.num_entries,
-        "intermediate_size": ffn1.out_channels,
-    }
+            "a decode twin needs a model built by a models/ builder that "
+            "records its recipe (models.transformer.build_gpt, "
+            "models.kimi_k2.build_kimi_k2)")
+    return recipe
+
+
+def _gpt_dims(ff: FFModel) -> Dict[str, int]:
+    """The builder's hyperparameters, from the recipe it recorded."""
+    return decoder_recipe(ff).dims
+
+
+def require_carried(ff: FFModel, feature: str, asked_by: str) -> None:
+    """ConfigError, by name, where a serving feature was asked of a
+    model family that does not carry it: never a silent fallback."""
+    recipe = decoder_recipe(ff)
+    if feature not in recipe.carries:
+        from .config import ConfigError
+
+        raise ConfigError(
+            f"{recipe.family} does not carry {feature} ({asked_by}); it "
+            f"carries {sorted(recipe.carries)}")
+
+
+def cache_entries(ff: FFModel) -> Dict[str, tuple]:
+    """{op name: its state entries that hold cached keys, values or
+    latents}: on a paged twin, the block pools.  THE predicate for
+    "this state entry is a paged pool" (block bytes, copy-on-write,
+    block export and import, beam reordering): asked of the op, so a
+    latent pool is found like a k/v pool."""
+    return {op.name: op.cache_entries()
+            for op in ff.operators.topo_order() if op.cache_entries()}
 
 
 def gpt_decode_tp_strategy(tp: int, num_layers: int):
@@ -80,16 +110,24 @@ def gpt_decode_tp_strategy(tp: int, num_layers: int):
     return s
 
 
-def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
-                     devices=None, kv_page_size: int = 0,
-                     kv_num_blocks: int = 0,
-                     step_tokens: int = 1,
-                     kv_kernel: str = "gather",
-                     tp: int = 1) -> FFModel:
-    """Build + compile the KV-cache decode twin of a trained GPT and
-    transfer its weights.  The decode graph is seq-`step_tokens`
-    (default 1) with decode_max_seq = the trained model's
-    position-table size.
+def make_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
+                 devices=None, kv_page_size: int = 0,
+                 kv_num_blocks: int = 0,
+                 step_tokens: int = 1,
+                 kv_kernel: str = "gather",
+                 tp: int = 1) -> FFModel:
+    """Build + compile the cached decode twin of a model a `models/`
+    builder built (whatever the family: the twin is the builder the
+    model recorded, `ff.decoder_recipe`, at seq `step_tokens` with
+    cache state) and hand it the model's weights.  decode_max_seq = the
+    model's position range.  What a family does not carry (a dense
+    cache, the chunk twin, tp > 1) is a ConfigError here, by name.
+
+    A model compiled with `defer_weights=True` (a server's holder:
+    weights set once, in the precision they are served in) gets a twin
+    compiled the same way: nothing is drawn at random only to be
+    overwritten, and the twin takes the holder's arrays as they are,
+    so the weights are resident once.
 
     kv_page_size > 0 builds the PAGED twin (serving/scheduler.py):
     every attention layer's k/v cache is a [kv_num_blocks,
@@ -121,8 +159,8 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     the head count and visible devices HERE (resolve_serving_tp) —
     never a mid-compile shape error."""
     from .config import FFConfig, resolve_paged_kernel, resolve_serving_tp
-    from .models.transformer import build_gpt
 
+    recipe = decoder_recipe(ff_train)
     if step_tokens < 1:
         raise ValueError(f"step_tokens must be >= 1, got {step_tokens}")
     if step_tokens > 1 and not kv_page_size:
@@ -138,11 +176,17 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
             f"kv_kernel={kv_kernel!r} needs the paged twin "
             "(kv_page_size > 0): the dense cache has no block table "
             "to stream through")
-    dims = _gpt_dims(ff_train)
+    dims = recipe.dims
+    if not kv_page_size:
+        require_carried(ff_train, "dense_cache", "kv_page_size=0")
+    if step_tokens > 1:
+        require_carried(ff_train, "chunk_twin", f"step_tokens={step_tokens}")
     tp = resolve_serving_tp(
         tp, num_heads=dims["num_heads"],
         visible_devices=len(devices) if devices is not None else None,
     )
+    if tp > 1:
+        require_carried(ff_train, "tensor_parallel", f"--serving-tp {tp}")
     b = batch_size or ff_train.config.batch_size
     cfg = FFConfig(
         batch_size=b, num_devices=tp,
@@ -158,13 +202,9 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
         compilation_cache=ff_train.config.compilation_cache,
     )
     ffd = FFModel(cfg)
-    build_gpt(
-        ffd, batch_size=b, seq_length=step_tokens,
-        hidden_size=dims["hidden_size"], num_layers=dims["num_layers"],
-        num_heads=dims["num_heads"],
-        intermediate_size=dims["intermediate_size"],
-        vocab_size=dims["vocab_size"], dropout=0.0,
-        max_positions=dims["max_seq"], decode_max_seq=dims["max_seq"],
+    recipe.build(
+        ffd, batch_size=b, seq_length=step_tokens, **recipe.kwargs,
+        decode_max_seq=dims["max_seq"],
         kv_page_size=kv_page_size, kv_num_blocks=kv_num_blocks,
         kv_kernel=kv_kernel,
     )
@@ -186,6 +226,7 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
         loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
         strategy=strategy,
         devices=devices,
+        defer_weights=ff_train.weights_deferred,
     )
     # weight transfer by (op, spec) name — all shapes are
     # seq-independent, so the trained pytree drops straight in.
@@ -200,12 +241,19 @@ def make_gpt_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
 
 def _transfer_weights(ffd: FFModel, ff_train: FFModel, tp: int) -> Dict:
     """The decode twin's weight pytree filled from the trained model's,
-    each entry on the twin's own sharding."""
+    each entry on the twin's own sharding.  A twin whose weights are
+    deferred takes every entry in the dtype it is held in (the
+    precision the server computes in; a router kept float32)."""
     import jax
 
+    if ff_train._weights is None:
+        raise ValueError(
+            "the model was compiled with defer_weights=True and has no "
+            "weights yet: set_weights() before building a server")
+    deferred = ffd.weights_deferred
     missing = []
     new_w = {}
-    for op_name, entries in ffd._weights.items():
+    for op_name, entries in ffd.executor.abstract_weights().items():
         src = ff_train._weights.get(op_name)
         new_entries = {}
         for k, v in entries.items():
@@ -219,7 +267,8 @@ def _transfer_weights(ffd: FFModel, ff_train: FFModel, tp: int) -> Dict:
                     f"decode weight {op_name}.{k}: trained shape "
                     f"{tuple(sv.shape)} != decode shape {tuple(v.shape)}"
                 )
-            sv = sv if sv.dtype == v.dtype else sv.astype(v.dtype)
+            if not deferred and sv.dtype != v.dtype:
+                sv = sv.astype(v.dtype)
             if tp > 1:
                 sv = jax.device_put(np.asarray(sv), v.sharding)
             new_entries[k] = sv
@@ -233,7 +282,7 @@ def _transfer_weights(ffd: FFModel, ff_train: FFModel, tp: int) -> Dict:
 def gpt_generate_cached(ffd: FFModel, prompt_ids, max_new_tokens: int,
                         temperature: float = 0.0, seed: int = 0,
                         top_k: int = 0, top_p: float = 0.0) -> np.ndarray:
-    """Host-loop KV-cache generation on a make_gpt_decoder model:
+    """Host-loop KV-cache generation on a make_decoder model:
     prefill feeds prompt tokens one per step (caches fill as a side
     effect), then each sampled token feeds back.  Exactly matches
     gpt_generate's outputs at temperature 0 (same model, same math,
@@ -283,11 +332,12 @@ def _reorder_cache_rows(ffd: FFModel, perm: np.ndarray):
     if np.array_equal(perm, np.arange(len(perm))):
         return
     idx = jnp.asarray(perm)
+    caches = cache_entries(ffd)
     new_state = {}
     for op, entries in ffd._state.items():
         ne = {}
         for k, v in entries.items():
-            if k in ("k_cache", "v_cache"):
+            if k in caches.get(op, ()):
                 ne[k] = jax.device_put(jnp.take(v, idx, axis=0), v.sharding)
             else:
                 ne[k] = v
@@ -298,7 +348,7 @@ def _reorder_cache_rows(ffd: FFModel, perm: np.ndarray):
 def gpt_beam_search_cached(ffd: FFModel, prompt_ids, max_new_tokens: int,
                            beam_size: int = 4, length_penalty: float = 0.0,
                            eos_id: int = -1):
-    """KV-cached, batched beam search on a make_gpt_decoder model
+    """KV-cached, batched beam search on a make_decoder model
     (VERDICT r4 #3: the O(T) replacement for
     models.transformer.gpt_beam_search, which re-runs the full forward
     per token and takes a single prompt).
@@ -317,6 +367,7 @@ def gpt_beam_search_cached(ffd: FFModel, prompt_ids, max_new_tokens: int,
     prompt_ids = np.asarray(prompt_ids, np.int32)
     if prompt_ids.ndim == 1:
         prompt_ids = prompt_ids[None]
+    require_carried(ffd, "beam_search", "gpt_beam_search_cached")
     dims = _gpt_dims(ffd)
     max_seq = dims["max_seq"]
     P, plen = prompt_ids.shape
@@ -487,7 +538,7 @@ def run_generate_scan(ffd: FFModel, prompt_pad: np.ndarray,
 
 def build_paged_decode_step(ffd: FFModel):
     """ONE compiled step function for continuous batching on a paged
-    decode twin (make_gpt_decoder with kv_page_size > 0):
+    decode twin (make_decoder with kv_page_size > 0):
 
         step(weights, state, tokens[b], positions[b], block_table)
             -> (logits [b, vocab], new_state)
@@ -685,7 +736,7 @@ def build_paged_verify_step(ffd: FFModel, chunk: int):
 
 def build_paged_chunk_step(ffd: FFModel):
     """Step function for a CHUNKED paged twin built with
-    make_gpt_decoder(step_tokens=C): one true seq-C forward per call,
+    make_decoder(step_tokens=C): one true seq-C forward per call,
 
         step(weights, state, tokens[b, C], positions[b], block_table)
             -> (logits [b, C, vocab], new_state)
@@ -698,7 +749,7 @@ def build_paged_chunk_step(ffd: FFModel):
     program's, so the continuous engine's byte-identity oracle uses
     build_paged_prefill_step instead; this program is for
     throughput-first deployments and is the fused Pallas kernel's
-    natural host-side twin (make_gpt_decoder(kv_kernel="pallas",
+    natural host-side twin (make_decoder(kv_kernel="pallas",
     step_tokens=C) runs the whole chunk's attention as ONE kernel
     dispatch per layer — ops/pallas/paged_attention.py)."""
     import jax
@@ -735,7 +786,8 @@ def build_paged_copy_block(ffd: FFModel):
         copy(state, src, dst) -> new_state
 
     copies physical block `src`'s page to block `dst` in EVERY layer's
-    k/v pool (scalar int32 ids; state donated, so on TPU the copy is
+    pools (k/v or latent: `cache_entries`; scalar int32 ids; state
+    donated, so on TPU the copy is
     in-place scatter, not a pool clone).  The prefix cache's COW path
     (serving/kv_pool.py ensure_writable) runs this before a full-hit
     request's first write, so shared blocks stay immutable while the
@@ -744,12 +796,13 @@ def build_paged_copy_block(ffd: FFModel):
     import jax.numpy as jnp
 
     ex = ffd.executor
+    pools = cache_entries(ffd)
 
     def copy(state, src, dst):
         return {
             op: {
                 k: (v.at[dst].set(v[src])
-                    if k in ("k_cache", "v_cache") else v)
+                    if k in pools.get(op, ()) else v)
                 for k, v in entries.items()
             }
             for op, entries in state.items()

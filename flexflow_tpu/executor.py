@@ -442,11 +442,29 @@ class GraphExecutor:
         return NamedSharding(self.mesh, PartitionSpec(first))
 
     # -- weight init -----------------------------------------------------
-    def init_weights(self, seed: int = 0):
+    def abstract_weights(self) -> Dict[str, Dict[str, jax.ShapeDtypeStruct]]:
+        """The weight pytree as shapes, dtypes and shardings, nothing
+        on the device: what a model whose weights are set later (or
+        handed over from another model) is checked against."""
+        w_shardings = self.master_weight_shardings()
+        return {
+            op.name: {
+                spec.name: jax.ShapeDtypeStruct(
+                    pt.shape.logical_shape, pt.dtype.np_dtype,
+                    sharding=w_shardings[op.name][spec.name])
+                for spec, pt in list(zip(op.weight_specs, op.weights))[
+                    :_num_trainable(op)]
+            }
+            for op in self.order
+            if _num_trainable(op) and op.guid not in self._block_guids
+        }
+
+    def init_weights(self, seed: int = 0, state_only: bool = False):
         """Initialize weight + state pytrees, sharded via out_shardings
         (stage 3 initializes master weights directly onto their
-        scattered resident layout)."""
-        w_shardings = self.master_weight_shardings()
+        scattered resident layout).  `state_only` draws no weight and
+        returns (None, state): the model's weights arrive later."""
+        w_shardings = None if state_only else self.master_weight_shardings()
         s_shardings = self.state_shardings()
 
         def build():
@@ -457,12 +475,15 @@ class GraphExecutor:
                 if op.guid in self._block_guids:
                     continue
                 nt = _num_trainable(op)
+                caches = op.cache_entries()
                 for i, (spec, pt) in enumerate(zip(op.weight_specs, op.weights)):
                     key, sub = jax.random.split(key)
+                    if state_only and i < nt:
+                        continue
                     dtype = pt.dtype.np_dtype
                     if (
                         i >= nt
-                        and spec.name in ("k_cache", "v_cache")
+                        and spec.name in caches
                         and self.compute_dtype is not None
                     ):
                         # decode caches live in the compute dtype: their
@@ -478,7 +499,7 @@ class GraphExecutor:
                         weights.setdefault(op.name, {})[short] = arr
                     else:
                         state.setdefault(op.name, {})[short] = arr
-            if self.pipeline_plan is not None:
+            if self.pipeline_plan is not None and not state_only:
                 # per-block independent inits stacked on a leading dim
                 # sharded over the pp axis
                 for j, t_op in enumerate(self.pipeline_plan.blocks[0]):
@@ -498,7 +519,7 @@ class GraphExecutor:
                         weights.setdefault("__pipeline__", {})[
                             f"{j}.{spec.name}"
                         ] = jnp.stack(layers)
-            return weights, state
+            return (None if state_only else weights), state
 
         out_shardings = (w_shardings, s_shardings)
         with self.mesh:
@@ -673,7 +694,8 @@ class GraphExecutor:
             w = src[op.name][spec.name]
             if i < nt and self._z3_gather is not None:
                 w = self._z3_fetch(op.name, spec.name, w, ctx)
-            ws.append(to_compute(w))
+            ws.append(w if spec.name in op.float32_weights
+                      else to_compute(w))
         op_rng = None
         if ctx["rng"] is not None:
             op_rng = jax.random.fold_in(ctx["rng"], op.guid)

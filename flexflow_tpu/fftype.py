@@ -108,6 +108,9 @@ class OperatorType(enum.Enum):
     EMBEDDING = "embedding"
     MULTIHEAD_ATTENTION = "multihead_attention"
     BATCH_MATMUL = "batch_matmul"
+    GATED_MLP = "gated_mlp"
+    MLA_ATTENTION = "mla_attention"
+    ROUTED_EXPERTS = "routed_experts"
     # Elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"
@@ -115,6 +118,7 @@ class OperatorType(enum.Enum):
     POOL2D = "pool2d"
     BATCH_NORM = "batch_norm"
     LAYER_NORM = "layer_norm"
+    RMS_NORM = "rms_norm"
     SOFTMAX = "softmax"
     # Shape
     CONCAT = "concat"

@@ -155,6 +155,13 @@ class FFModel:
         self.metrics: Optional[Metrics] = None
         self.iter_config = FFIterationConfig()
         self._weights = None
+        # compile(defer_weights=True): no weight is drawn and no
+        # optimizer state made; set_weights() brings the weights, in
+        # whatever precision they are to be held in
+        self.weights_deferred = False
+        # what a models/ builder records so that a decode twin is that
+        # builder again (decoding.DecoderRecipe)
+        self.decoder_recipe = None
         self._opt_state = None
         self._state = None
         self._step_fn = None
@@ -337,6 +344,35 @@ class FFModel:
                                kv_kernel=kv_kernel)
         )
 
+    def mla_attention(self, input, positions, params, name=None,
+                      decode_max_seq: int = 0, kv_page_size: int = 0,
+                      kv_num_blocks: int = 0, kv_kernel: str = "gather"):
+        """Multi-head latent attention (ops/mla.py): `params` is an
+        `MLAParams`; the cache keywords are `multihead_attention`'s,
+        and build the paged LATENT cache."""
+        from .ops.mla import MLAttention
+
+        return self._add(MLAttention(
+            params, [input, positions],
+            name=self._name("mla_attention", name),
+            decode_max_seq=decode_max_seq, kv_page_size=kv_page_size,
+            kv_num_blocks=kv_num_blocks, kv_kernel=kv_kernel))
+
+    def gated_mlp(self, input, intermediate_size: int, name=None):
+        from .ops.dense import GatedMLP, GatedMLPParams
+
+        return self._add(GatedMLP(GatedMLPParams(intermediate_size), [input],
+                                  name=self._name("gated_mlp", name)))
+
+    def routed_experts(self, input, params, name=None):
+        """One chip's share of a routed-expert layer plus its shared
+        expert (ops/routed_experts.py): `params` is a
+        `RoutedExpertsParams` naming the experts held here."""
+        from .ops.routed_experts import RoutedExperts
+
+        return self._add(RoutedExperts(
+            params, [input], name=self._name("routed_experts", name)))
+
     def batch_matmul(
         self,
         a: ParallelTensor,
@@ -451,6 +487,12 @@ class FFModel:
     ):
         p = LayerNormParams(tuple(axes), elementwise_affine, eps)
         return self._add(LayerNorm(p, [input], name=self._name("layer_norm", name)))
+
+    def rms_norm(self, input, eps: float = 1e-5, name=None):
+        from .ops.norm import RMSNorm, RMSNormParams
+
+        return self._add(RMSNorm(RMSNormParams(eps), [input],
+                                 name=self._name("rms_norm", name)))
 
     def batch_norm(self, input, relu: bool = True, eps: float = 1e-5,
                    momentum: float = 0.9, name=None):
@@ -648,13 +690,20 @@ class FFModel:
         strategy: Optional[Strategy] = None,
         devices: Optional[Sequence] = None,
         seed: Optional[int] = None,
+        defer_weights: bool = False,
     ):
+        """`defer_weights=True` compiles a model that is only ever
+        SERVED: the graph passes and the executor, op state (cache
+        pools), but no weight drawn at random, no optimizer state and
+        no train step.  `set_weights()` then holds what it is given as
+        it is given (a server's bf16 weights stay bf16: resident once,
+        in the precision they are computed in)."""
         t0 = time.perf_counter()
         with span("compile") as sp:
             result = self._compile_inner(
                 optimizer=optimizer, loss_type=loss_type, metrics=metrics,
                 comp_mode=comp_mode, strategy=strategy, devices=devices,
-                seed=seed,
+                seed=seed, defer_weights=defer_weights,
             )
             sp.set(ops=len(self.operators.topo_order()))
         self.telemetry.metrics.gauge("compile/total_ms").set(
@@ -688,9 +737,11 @@ class FFModel:
         strategy: Optional[Strategy] = None,
         devices: Optional[Sequence] = None,
         seed: Optional[int] = None,
+        defer_weights: bool = False,
     ):
         cfg = self.config
         tel = self.telemetry
+        self.weights_deferred = bool(defer_weights)
         self._compile_args = {
             "loss_type": loss_type,
             "metrics": tuple(metrics),
@@ -759,6 +810,12 @@ class FFModel:
             strategy.save(cfg.export_strategy_file)
         with span("compile.passes"):
             self._build_executor(strategy, devices, num_devices, comp_mode)
+        if defer_weights:
+            with span("init_state"):
+                self._weights, self._state = self.executor.init_weights(
+                    seed if seed is not None else cfg.seed, state_only=True)
+            self._rng = jax.random.key(cfg.seed)
+            return self
         # init_weights jit-executes eagerly, so this span IS a real XLA
         # compile; build_step/eval/forward only stage traces (their XLA
         # compile lands in the first train step — docs/OBSERVABILITY.md)
@@ -1091,6 +1148,10 @@ class FFModel:
                    seq_length: Optional[int] = None):
         """One jitted iteration: forward + loss + backward + metrics + update."""
         self._check_not_decode_graph("train_step()")
+        if self.weights_deferred:
+            raise RuntimeError(
+                "train_step() on a model compiled with defer_weights=True "
+                "(it has no optimizer state and no train step)")
         self.set_iteration_config(seq_length)
         step_fn = self._step_fn
         # first=1: this call traces and compiles the step (or loads it
@@ -1347,16 +1408,18 @@ class FFModel:
         self._decode_pos = pos
 
     def reset_decode_state(self):
-        """Zero the decode caches (k_cache/v_cache/cache_pos state
-        entries, plus the paged-mode block_table/seq_lens) so the next
+        """Zero the decode caches (each op's `cache_entries()` and
+        cache_pos, plus the paged-mode block_table/seq_lens) so the next
         decode_step starts a fresh sequence."""
         import jax.numpy as jnp
 
-        names = ("k_cache", "v_cache", "cache_pos", "block_table",
-                 "seq_lens")
+        caches = {op.name: op.cache_entries()
+                  for op in self.operators.topo_order()}
+        names = ("cache_pos", "block_table", "seq_lens")
         self._state = {
             op: {
-                k: (jnp.zeros_like(v) if k in names else v)
+                k: (jnp.zeros_like(v)
+                    if k in names or k in caches.get(op, ()) else v)
                 for k, v in entries.items()
             }
             for op, entries in self._state.items()
@@ -1532,9 +1595,30 @@ class FFModel:
         # master layout: the strategy shardings below ZeRO stage 3,
         # the scattered resident layout at stage 3
         shardings = self.executor.master_weight_shardings()
-        self._weights = jax.tree.map(
-            lambda v, s: jax.device_put(jnp.asarray(v), s), weights, shardings
-        )
+        if self.weights_deferred:
+            self._check_deferred_weights(weights)
+        with span("serve.set_weights" if self.weights_deferred
+                  else "set_weights") as sp:
+            # (an array already on its sharding is held, not copied)
+            self._weights = jax.tree.map(
+                lambda v, s: jax.device_put(jnp.asarray(v), s), weights,
+                shardings
+            )
+            sp.set(bytes=sum(int(v.nbytes)
+                             for v in jax.tree.leaves(self._weights)))
+
+    def _check_deferred_weights(self, weights) -> None:
+        """A served model takes its weights as given, so what is given
+        has to be the whole tree at the right shapes."""
+        want = self.executor.abstract_weights()
+        for op_name, entries in want.items():
+            for k, v in entries.items():
+                got = weights.get(op_name, {}).get(k)
+                if got is None or tuple(got.shape) != tuple(v.shape):
+                    raise ValueError(
+                        f"set_weights: {op_name}.{k} wants shape "
+                        f"{tuple(v.shape)}, got "
+                        f"{None if got is None else tuple(got.shape)}")
 
     def get_parameter(self, op_name: str, weight_name: str) -> np.ndarray:
         return np.asarray(self._weights[op_name][weight_name])

@@ -11,6 +11,7 @@ which is both the real workload and the TP/SP search target.
 """
 from __future__ import annotations
 
+from ..decoding import DecoderRecipe
 from ..fftype import ActiMode
 from ..model import FFModel
 
@@ -164,6 +165,24 @@ def build_gpt(
         t = ff.add(t, h, name=f"ffn_res_{i}")
     t = ff.layer_norm(t, axes=[-1], name="final_ln")
     logits = ff.dense(t, vocab_size, use_bias=False, name="lm_head")
+    # what a decode twin is built from (decoding.make_decoder): this
+    # builder again, without dropout, over the same position table
+    max_seq = max_positions or seq_length
+    ff.decoder_recipe = DecoderRecipe(
+        family="gpt", build=build_gpt,
+        kwargs=dict(hidden_size=hidden_size, num_layers=num_layers,
+                    num_heads=num_heads,
+                    intermediate_size=intermediate_size,
+                    vocab_size=vocab_size, dropout=0.0,
+                    max_positions=max_seq),
+        dims={"num_layers": num_layers, "hidden_size": hidden_size,
+              "num_heads": num_heads, "dropout": dropout,
+              "vocab_size": vocab_size, "max_seq": max_seq,
+              "intermediate_size": intermediate_size},
+        carries=frozenset({
+            "dense_cache", "paged", "prefix_cache", "chunked_prefill",
+            "chunk_twin", "speculative", "tensor_parallel", "handoff",
+            "disaggregated", "beam_search", "pallas_read"}))
     return logits
 
 

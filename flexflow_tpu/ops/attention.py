@@ -149,6 +149,9 @@ class MultiHeadAttention(Op):
         return self._decode_n() > 0 and \
             int(getattr(self, "_kv_page_size", 0) or 0) > 0
 
+    def cache_entries(self):
+        return ("k_cache", "v_cache") if self._decode_n() else ()
+
     def ctor_kwargs(self) -> dict:
         n = self._decode_n()
         if not n:

@@ -402,3 +402,53 @@ class BatchMatmul(Op):
         import numpy as np
 
         return 2.0 * float(np.prod(a)) * n
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """W_down (silu(x W_gate) * (x W_up)): the SiLU-gated MLP."""
+    return jnp.matmul(jax.nn.silu(jnp.matmul(x, w_gate))
+                      * jnp.matmul(x, w_up), w_down)
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedMLPParams:
+    intermediate_size: int
+    dtype: DataType = DataType.FLOAT
+
+
+class GatedMLP(Op):
+    """SiLU-gated MLP over the last axis, no biases: three weights
+    `w_gate`, `w_up` [e, f] and `w_down` [f, e].  One op rather than
+    three Linears and two elementwise ops because the gate and the up
+    product are never wanted apart."""
+
+    op_type = OperatorType.GATED_MLP
+
+    def infer_output_shapes(self, input_shapes):
+        (ishape,) = input_shapes
+        last = [d for d in ishape.dims if not d.is_replica_dim][-1]
+        if last.degree != 1 or not self.shard.is_trivial():
+            raise ShapeError(f"{self.name}: the gated MLP is not sharded "
+                             "inside (shard its batch)")
+        return [ParallelTensorShape(ishape.dims, self.params.dtype)]
+
+    def make_weight_specs(self, input_shapes):
+        (ishape,) = input_shapes
+        p: GatedMLPParams = self.params
+        e, f = ishape.logical_shape[-1], p.intermediate_size
+        rep = ParallelDim(1, ishape.total_degree, is_replica_dim=True)
+
+        def w(rows, cols):
+            return ParallelTensorShape(
+                (ParallelDim(rows), ParallelDim(cols), rep), p.dtype)
+
+        return [WeightSpec("w_gate", w(e, f), DEFAULT_WEIGHT_INIT),
+                WeightSpec("w_up", w(e, f), DEFAULT_WEIGHT_INIT),
+                WeightSpec("w_down", w(f, e), DEFAULT_WEIGHT_INIT)]
+
+    def forward(self, inputs, weights, *, training=False, rng=None):
+        return [gated_mlp(inputs[0], *weights)]
+
+    def flops(self):
+        return (6.0 * self.inputs[0].shape.num_elements()
+                * self.params.intermediate_size)

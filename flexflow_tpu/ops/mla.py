@@ -1,0 +1,318 @@
+"""Multi-head latent attention (MLA) with decoupled, YaRN-scaled RoPE.
+
+    c_q = RMS(h W_qa);  q = c_q W_qb -> heads of [q_nope | q_rope]
+    [c_kv | k_r] = h W_kva;  c_kv <- RMS(c_kv)
+    RoPE on q_rope (per head) and on k_r (ONE vector for all heads)
+    [k_nope | v]_head = c_kv W_kvb
+    score = (q_nope . k_nope + q_rope . k_r) * s, causal softmax,
+    out = concat_heads(sum p v) W_o
+
+What a token leaves behind is `(c_kv, RoPE(k_r))`: `kv_lora_rank +
+qk_rope_head_dim` values a layer (576 at the published sizes, against
+`2 x heads x head_dim` = 16,384 for full keys and values).
+
+Two formulations, one set of weights:
+
+* no cache (`decode_max_seq == 0`): keys and values are EXPANDED from
+  the latent and attention is ordinary causal attention over the
+  step's own tokens: the graph a trainer or a one-shot forward runs;
+* paged latent cache (`decode_max_seq`, `kv_page_size`,
+  `kv_num_blocks`): the state is ONE pool `latent_cache [num_blocks,
+  page, rank + rope]` plus the host-owned `block_table` / `seq_lens`
+  every paged op carries.  A seq-1 step writes its token's latent at
+  the row's own position and attends with `W_kvb` ABSORBED: the query
+  is taken into the latent space (`q_nope W_kvb_k^T`), scores and the
+  weighted sum run on the gathered `[slots, max_seq, rank + rope]`
+  view, and the head's values come out of the latent at the end.  The
+  pool is read by gather only: `kv_kernel` "gather" and "pallas" are
+  the same formulation here (no in-place latent kernel is written;
+  Mosaic refused a 64-wide slice in PR 28, and the rope part is 64
+  wide).
+
+Positions arrive as the op's second input; RoPE angles are computed
+from them in float32.  The published code de-interleaves the rope
+channels before rotating half against half; rotating adjacent pairs
+`(2i, 2i+1)` as here gives the same scores.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..fftype import DataType, OperatorType
+from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
+                           ZeroInitializer)
+from ..tensor import ParallelDim, ParallelTensorShape
+from .norm import rms_normalize
+from .op import Op, ShapeError, WeightSpec
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAParams:
+    embed_dim: int
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    # YaRN (rope_scaling of the published config); factor 1 = plain RoPE
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    eps: float = 1e-5
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token leaves in the cache, a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(p: MLAParams) -> np.ndarray:
+    """Rotation per position of each adjacent pair of rope channels,
+    [qk_rope_head_dim / 2] float64: the published YaRN blend of the
+    extrapolated (`theta^(-2i/d)`) and the interpolated (`/ factor`)
+    frequencies over a linear ramp between the pairs that turn
+    `beta_fast` and `beta_slow` times in the original context."""
+    d = p.qk_rope_head_dim
+    extra = p.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if p.rope_factor <= 1:
+        return extra
+
+    def pair_of(turns):
+        return (d * math.log(p.rope_original_max / (2 * math.pi * turns))
+                / (2 * math.log(p.rope_theta)))
+
+    low = max(math.floor(pair_of(p.beta_fast)), 0)
+    high = min(math.ceil(pair_of(p.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / p.rope_factor * ramp + extra * (1.0 - ramp)
+
+
+def softmax_scale(p: MLAParams) -> float:
+    """(nope + rope)^-0.5 * mscale(all_dim)^2."""
+    m = yarn_mscale(p.rope_factor, p.mscale_all_dim)
+    return (p.qk_nope_head_dim + p.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def rope(x, positions, p: MLAParams):
+    """Rotate adjacent channel pairs of x [b, s, ..., d] by
+    positions [b, s] x the YaRN frequencies, in float32; cos and sin
+    are scaled by mscale / mscale(all_dim), 1 at the published
+    values."""
+    ratio = (yarn_mscale(p.rope_factor, p.mscale)
+             / yarn_mscale(p.rope_factor, p.mscale_all_dim))
+    angle = (positions.astype(jnp.float32)[..., None]
+             * jnp.asarray(yarn_frequencies(p), jnp.float32))
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3)
+                          + angle.shape[-1:])
+    cos, sin = jnp.cos(angle) * ratio, jnp.sin(angle) * ratio
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+class MLAttention(Op):
+    op_type = OperatorType.MLA_ATTENTION
+
+    def __init__(self, params, inputs, name="", shard=None,
+                 decode_max_seq: int = 0, kv_page_size: int = 0,
+                 kv_num_blocks: int = 0, kv_kernel: str = "gather"):
+        from .op import ShardConfig
+
+        # must exist before Op.__init__ runs make_weight_specs
+        self._decode_max_seq = int(decode_max_seq)
+        self._kv_page_size = int(kv_page_size)
+        self._kv_num_blocks = int(kv_num_blocks)
+        self._kv_kernel = str(kv_kernel or "gather")
+        super().__init__(params, inputs, name=name,
+                         shard=shard or ShardConfig())
+
+    def _paged(self) -> bool:
+        return self._decode_max_seq > 0
+
+    def ctor_kwargs(self) -> dict:
+        if not self._paged():
+            return {}
+        return {"decode_max_seq": self._decode_max_seq,
+                "kv_page_size": self._kv_page_size,
+                "kv_num_blocks": self._kv_num_blocks,
+                "kv_kernel": self._kv_kernel}
+
+    def cache_entries(self):
+        return ("latent_cache",) if self._paged() else ()
+
+    def infer_output_shapes(self, input_shapes):
+        x, pos = input_shapes
+        xd = [d for d in x.dims if not d.is_replica_dim]
+        if len(xd) != 3 or pos.logical_shape != x.logical_shape[:2]:
+            raise ShapeError(
+                f"{self.name}: expect x [batch, seq, embed] and positions "
+                f"[batch, seq], got {x.logical_shape} and "
+                f"{pos.logical_shape}")
+        if xd[1].degree != 1 or xd[2].degree != 1 \
+                or not self.shard.is_trivial():
+            raise ShapeError(
+                f"{self.name}: latent attention is sharded over the "
+                "batch only (heads over a model axis are not built yet)")
+        if self.params.qk_rope_head_dim % 2:
+            raise ShapeError(f"{self.name}: rope width must be even")
+        return [x]
+
+    def num_trainable_weights(self) -> int:
+        return 7
+
+    def make_weight_specs(self, input_shapes):
+        x, _ = input_shapes
+        p: MLAParams = self.params
+        xd = [d for d in x.dims if not d.is_replica_dim]
+        rep = ParallelDim(1, x.total_degree, is_replica_dim=True)
+
+        def w(*sizes, dtype=x.dtype, replica=rep):
+            return ParallelTensorShape(
+                tuple(ParallelDim(s) for s in sizes) + (replica,), dtype)
+
+        init, one = DEFAULT_WEIGHT_INIT, ConstantInitializer(1.0)
+        e, h = p.embed_dim, p.num_heads
+        specs = [
+            WeightSpec("wq_a", w(e, p.q_lora_rank), init),
+            WeightSpec("q_norm", w(p.q_lora_rank), one),
+            WeightSpec("wq_b", w(p.q_lora_rank, h,
+                                 p.qk_nope_head_dim + p.qk_rope_head_dim),
+                       init),
+            WeightSpec("wkv_a", w(e, p.latent_width), init),
+            WeightSpec("kv_norm", w(p.kv_lora_rank), one),
+            WeightSpec("wkv_b", w(p.kv_lora_rank, h,
+                                  p.qk_nope_head_dim + p.v_head_dim), init),
+            WeightSpec("wo", w(h, p.v_head_dim, e), init),
+        ]
+        if not self._paged():
+            return specs
+        n, page, nb = (self._decode_max_seq, self._kv_page_size,
+                       self._kv_num_blocks)
+        if xd[1].size != 1:
+            raise ShapeError(
+                f"{self.name}: the latent cache is stepped one token at "
+                f"a time (seq 1), got seq {xd[1].size}; prefill scans the "
+                "seq-1 step")
+        if xd[0].degree != 1:
+            raise ShapeError(
+                f"{self.name}: paged decode needs an unsharded batch dim "
+                "(slots are host-owned)")
+        if page < 1 or n % page:
+            raise ShapeError(
+                f"{self.name}: kv_page_size {page} must divide "
+                f"decode_max_seq {n}")
+        if nb < 2:
+            raise ShapeError(
+                f"{self.name}: kv_num_blocks {nb} < 2 (block 0 is the "
+                "scratch block idle slots write into)")
+        zero, one_rep = ZeroInitializer(), ParallelDim(
+            1, 1, is_replica_dim=True)
+        return specs + [
+            WeightSpec("latent_cache",
+                       w(nb, page, p.latent_width, replica=one_rep), zero),
+            WeightSpec("block_table",
+                       w(xd[0].size, n // page, dtype=DataType.INT32,
+                         replica=one_rep), zero),
+            WeightSpec("seq_lens",
+                       w(xd[0].size, dtype=DataType.INT32,
+                         replica=one_rep), zero),
+        ]
+
+    # -- forward --------------------------------------------------------
+    def forward(self, inputs, weights, *, training=False, rng=None):
+        x, positions = inputs
+        p: MLAParams = self.params
+        wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo = weights[:7]
+        dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
+        cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
+        q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
+        q_nope, q_rope = q[..., :dn], rope(q[..., dn:], positions, p)
+        kv = jnp.matmul(x, wkv_a)
+        latent = jnp.concatenate(
+            [rms_normalize(kv[..., :rk], kv_norm, p.eps),
+             rope(kv[..., rk:], positions, p)], axis=-1)  # [b, s, rk + dr]
+        if self._paged():
+            pool, btab, slen = weights[7:]
+            ctx, pool = self._attend_paged(q_nope[:, 0], q_rope[:, 0],
+                                           latent[:, 0], wkv_b, pool,
+                                           btab, slen)
+            out = jnp.einsum("bhd,hde->be", ctx, wo)[:, None]
+            return [out.astype(x.dtype), pool, btab, slen]
+        # expanded: keys and values out of the latent, causal attention
+        # over the step's own tokens
+        c, k_rope = latent[..., :rk], latent[..., rk:]
+        kvh = jnp.einsum("bsc,chd->bshd", c, wkv_b)
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kvh[..., :dn],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                               preferred_element_type=jnp.float32))
+        s = x.shape[1]
+        keep = jnp.tril(jnp.ones((s, s), bool))
+        scores = jnp.where(keep, scores * softmax_scale(p),
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kvh[..., dn:])
+        return [jnp.einsum("bqhd,hde->bqe", ctx, wo).astype(x.dtype)]
+
+    def _attend_paged(self, q_nope, q_rope, latent, wkv_b, pool, btab,
+                      slen):
+        """One token a row through the paged latent cache: write the
+        token's latent at the row's own position (idle slots point at
+        scratch block 0), then attend over the row's gathered blocks
+        with `W_kvb` absorbed.  Gathered slots past a row's length hold
+        other sequences' bytes; the per-row position mask takes them
+        out of the softmax exactly."""
+        p: MLAParams = self.params
+        dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
+        b, page = latent.shape[0], self._kv_page_size
+        pos = slen.reshape(b).astype(jnp.int32)
+        blk = jnp.take_along_axis(btab, (pos // page)[:, None], axis=1)[:, 0]
+        pool = pool.at[blk, pos % page].set(latent.astype(pool.dtype))
+        n = btab.shape[1] * page
+        view = jnp.take(pool, btab, axis=0).reshape(b, n, -1) \
+            .astype(q_nope.dtype)
+        q_lat = jnp.einsum("bhd,chd->bhc", q_nope, wkv_b[..., :dn])
+        scores = jnp.einsum(
+            "bhc,bnc->bhn", jnp.concatenate([q_lat, q_rope], axis=-1), view,
+            preferred_element_type=jnp.float32) * softmax_scale(p)
+        live = jnp.arange(n, dtype=jnp.int32)[None, :] <= pos[:, None]
+        scores = jnp.where(live[:, None, :], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+        out_lat = jnp.einsum("bhn,bnc->bhc", probs, view[..., :rk])
+        return jnp.einsum("bhc,chd->bhd", out_lat, wkv_b[..., dn:]), pool
+
+    def flops(self):
+        p: MLAParams = self.params
+        b, s, e = self.inputs[0].shape.logical_shape
+        h = p.num_heads
+        dq = p.qk_nope_head_dim + p.qk_rope_head_dim
+        proj = 2.0 * b * s * (
+            e * p.q_lora_rank + p.q_lora_rank * h * dq
+            + e * p.latent_width + h * p.v_head_dim * e)
+        if self._paged():
+            # absorbed: the query into the latent and the values out of
+            # it, scores and the weighted sum over the gathered view
+            n = self._decode_max_seq
+            return proj + 2.0 * b * s * h * (
+                p.kv_lora_rank * (p.qk_nope_head_dim + p.v_head_dim)
+                + n * (p.latent_width + p.kv_lora_rank))
+        expand = 2.0 * b * s * p.kv_lora_rank * h * (
+            p.qk_nope_head_dim + p.v_head_dim)
+        return proj + expand + 2.0 * b * h * s * s * (dq + p.v_head_dim)

@@ -173,3 +173,45 @@ class Softmax(Op):
 
     def forward(self, inputs, weights, *, training=False, rng=None):
         return [jax.nn.softmax(inputs[0], axis=self.params.axis)]
+
+
+def rms_normalize(x, gamma, eps: float):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, reduced in
+    float32 whatever x's dtype (a bf16 mean of 7,168 squares loses the
+    low bits the scale needs), returned in x's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class RMSNormParams:
+    eps: float = 1e-5
+
+
+class RMSNorm(Op):
+    """Root-mean-square norm over the last axis, one gain per channel,
+    no bias and no mean subtraction."""
+
+    op_type = OperatorType.RMS_NORM
+
+    def infer_output_shapes(self, input_shapes):
+        (ishape,) = input_shapes
+        last = [d for d in ishape.dims if not d.is_replica_dim][-1]
+        if last.degree != 1:
+            raise ShapeError(f"{self.name}: normalized axis is partitioned")
+        return [ishape]
+
+    def make_weight_specs(self, input_shapes):
+        (ishape,) = input_shapes
+        dims = (ParallelDim(ishape.logical_shape[-1]),
+                ParallelDim(1, ishape.total_degree, is_replica_dim=True))
+        return [WeightSpec("gamma", ParallelTensorShape(dims, ishape.dtype),
+                           ConstantInitializer(1.0))]
+
+    def forward(self, inputs, weights, *, training=False, rng=None):
+        return [rms_normalize(inputs[0], weights[0], self.params.eps)]
+
+    def flops(self):
+        return 4.0 * self.inputs[0].shape.num_elements()

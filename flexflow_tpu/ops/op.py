@@ -134,6 +134,19 @@ class Op:
         """Forward FLOPs for one full (unsharded) application."""
         return 0.0
 
+    # -- what the executor and the serving tier ask of an op ------------
+    #: weights the executor hands over in their stored dtype whatever
+    #: `compute_dtype` says (a router whose top-k flips under bf16)
+    float32_weights: Tuple[str, ...] = ()
+
+    def cache_entries(self) -> Tuple[str, ...]:
+        """Names of this op's state entries that hold cached keys,
+        values or latents.  They live in the compute dtype; on a paged
+        twin each is a `[num_blocks, page, ...]` pool addressed by the
+        host-owned `block_table`, so block bytes, copy-on-write and
+        block export ask here instead of spelling entry names."""
+        return ()
+
     def memory_bytes(self) -> int:
         total = sum(t.shape.size_bytes() for t in self.outputs)
         total += sum(w.shape.size_bytes() for w in self.weights)

@@ -431,6 +431,10 @@ def build_front(ff_train, cfg=None, *, eos_id: int = -1, registry=None,
     if roles is None:
         return ServingFront.from_trained(
             ff_train, eos_id=eos_id, registry=registry, **kw)
+    from ..decoding import require_carried
+
+    require_carried(ff_train, "disaggregated",
+                    f"--serving-roles {cfg.serving_roles}")
     if fabric is None:
         from .kv_transfer import resolve_kv_transfer
 

@@ -325,9 +325,12 @@ class ServingFront:
         the missing drafter is a build-time ConfigError, not a
         per-replica death loop."""
         from ..config import resolve_spec_decode
+        from ..decoding import require_carried
         from .scheduler import PagedKVDecodeModel
 
         cfg = ff_train.config
+        if kw.get("handoff", getattr(cfg, "serving_handoff", False)):
+            require_carried(ff_train, "handoff", "--serving-handoff")
         # inherit the run's telemetry bundle unless the caller wires
         # its own: --trace-dir alone gives the serving fleet SLO
         # metrics AND per-request traces (obs/reqtrace.py); without it

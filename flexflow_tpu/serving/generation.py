@@ -3,7 +3,7 @@ batcher/HTTP surface (VERDICT r4 #4 — the scope the reference's
 triton/ backend never reached: it is forward-only inference,
 triton/README.md:3-6).
 
-`GenerationEngine` owns a decode twin (decoding.make_gpt_decoder) of a
+`GenerationEngine` owns a decode twin (decoding.make_decoder) of a
 trained GPT and runs whole generations as single XLA scan programs
 (decoding.run_generate_scan): per-row prompt lengths are a traced
 operand, so one compiled program per (total-length bucket, temperature)
@@ -26,7 +26,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..decoding import _gpt_dims, make_gpt_decoder, run_generate_scan
+from ..decoding import _gpt_dims, make_decoder, run_generate_scan
 from ..model import FFModel
 
 
@@ -48,7 +48,7 @@ class GenerationEngine:
 
     def __init__(self, ff_train: FFModel, batch_size: int = 8,
                  devices=None, eos_id: int = -1):
-        self.ffd = make_gpt_decoder(ff_train, batch_size=batch_size,
+        self.ffd = make_decoder(ff_train, batch_size=batch_size,
                                     devices=devices)
         self.batch_size = batch_size
         self.max_seq = _gpt_dims(self.ffd)["max_seq"]

@@ -3,7 +3,7 @@ SOSP'23, on the host side) — now a PREFIX CACHE with copy-on-write
 block sharing (the RadixAttention idea, SGLang arXiv:2312.07104).
 
 The device holds per-layer block pools ([num_blocks, page, heads, d]
-state arrays built by `make_gpt_decoder(kv_page_size=...)`); this
+state arrays built by `make_decoder(kv_page_size=...)`); this
 module is the single source of truth for WHICH physical block belongs
 to WHICH sequence.  All layers allocate in lockstep (every layer's
 cache has the same sequence structure), so one free list and one block

@@ -109,6 +109,12 @@ class SupervisedDecodeModel:
         if reset is not None:
             reset()
 
+    @property
+    def moe_last(self):
+        # routed-expert counts of the last decode dispatch (None where
+        # the model has no such layer)
+        return getattr(self._model, "moe_last", None)
+
     def step(self, tokens, seq_lens, block_tables):
         idx = next(self._steps)
         try:
@@ -560,6 +566,10 @@ class ServingReplica:
             # tensor-parallel geometry: chips spanned + per-chip KV share
             if "tp" in sstats:
                 out["tp"] = sstats["tp"]
+            # routed-expert layers: pairs on held experts, dropped (0),
+            # fullest expert's rows, experts hit, over decode dispatches
+            if "moe" in sstats:
+                out["moe"] = sstats["moe"]
         return out
 
     def close(self, timeout_s: Optional[float] = None) -> None:

@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..obs.trace import span
+from ..ops.routed_experts import MOE_STATS
 from .kv_pool import KVPool
 
 
@@ -97,14 +98,26 @@ class PagedKVDecodeModel:
         from ..decoding import (_gpt_dims, build_paged_copy_block,
                                 build_paged_decode_step,
                                 build_paged_prefill_step,
-                                build_paged_verify_step,
-                                make_gpt_decoder)
+                                build_paged_verify_step, cache_entries,
+                                decoder_recipe, make_decoder,
+                                require_carried)
         from .engine import resolve_paged_formulation
 
         self.paged_kernel = resolve_paged_formulation(paged_kernel)
+        if (self.paged_kernel == "pallas" and "pallas_read"
+                not in decoder_recipe(ff_train).carries):
+            # `auto` resolves to a formulation the family's attention
+            # op HAS; asking for the kernel by name is refused
+            if paged_kernel != "auto":
+                require_carried(ff_train, "pallas_read",
+                                f"--paged-kernel {paged_kernel}")
+            self.paged_kernel = "gather"
         self.spec_decode = resolve_spec_decode(spec_decode, spec_k)
         self.spec_k = int(spec_k)
         dims = _gpt_dims(ff_train)
+        if self.spec_decode != "off":
+            require_carried(ff_train, "speculative",
+                            f"--spec-decode {self.spec_decode}")
         # tensor-parallel replica degree (docs/SERVING.md
         # "Tensor-parallel replicas"): the decode twin compiles over a
         # tp-chip {"data": 1, "model": tp} mesh, heads + KV pools
@@ -128,7 +141,7 @@ class PagedKVDecodeModel:
             # 1 + batch_slots * max_blocks
             num_blocks = 1 + max(max_blocks,
                                  (batch_slots * max_blocks + 1) // 2)
-        self.ffd = make_gpt_decoder(
+        self.ffd = make_decoder(
             ff_train, batch_size=batch_slots, devices=devices,
             kv_page_size=page_size, kv_num_blocks=num_blocks,
             kv_kernel=self.paged_kernel, tp=self.tp,
@@ -199,12 +212,19 @@ class PagedKVDecodeModel:
         # Shapes here are GLOBAL (GSPMD arrays report the logical
         # shape); each of a tp replica's chips holds 1/tp of the head
         # axis, so per-chip bytes are the global count / tp.
+        self._pools = cache_entries(self.ffd)
         self.kv_block_bytes = sum(
-            int(np.prod(v.shape[1:])) * v.dtype.itemsize
-            for entries in self._state.values()
-            for k, v in entries.items()
-            if k in ("k_cache", "v_cache"))
+            int(np.prod(self._state[op][k].shape[1:]))
+            * self._state[op][k].dtype.itemsize
+            for op, names in self._pools.items() for k in names)
         self.kv_block_bytes_per_chip = self.kv_block_bytes // self.tp
+        # routed-expert layers count their routing in a state entry the
+        # step program returns anyway (ops/routed_experts.py MOE_STATS);
+        # `step` fetches it with the logits and keeps the last
+        # dispatch's counts, summed over layers, here
+        self._moe_ops = [op for op, entries in self._state.items()
+                         if "moe_stats" in entries]
+        self.moe_last: Optional[Dict[str, int]] = None
         self.mesh_shape = {
             str(k): int(s)
             for k, s in zip(self.ffd.mesh.axis_names,
@@ -240,6 +260,15 @@ class PagedKVDecodeModel:
             )
         # the wait for the device, then the logits' copy to the host
         with span("model.fetch"):
+            if not self._moe_ops:
+                return np.asarray(logits, np.float32)
+            import jax
+
+            logits, stats = jax.device_get(
+                (logits, [self._state[op]["moe_stats"]
+                          for op in self._moe_ops]))
+            self.moe_last = dict(zip(
+                MOE_STATS, (int(v) for v in np.sum(stats, axis=0))))
             return np.asarray(logits, np.float32)
 
     def _first_call(self, program: str) -> int:
@@ -288,15 +317,11 @@ class PagedKVDecodeModel:
     def export_block(self, block: int) -> Dict[str, np.ndarray]:
         """Device->host read of ONE physical block across every layer's
         k/v pool — the migration export path (serving/kv_transfer.py).
-        Keyed "<op>/<k_cache|v_cache>" so import lands each page back
-        in the matching layer.  Worker-thread only: the state pytree is
+        Keyed "<op>/<pool entry>" so import lands each page back in the
+        matching layer.  Worker-thread only: the state pytree is
         donated to the step programs, so reads must sit between steps."""
-        out: Dict[str, np.ndarray] = {}
-        for name, entries in self._state.items():
-            for k in ("k_cache", "v_cache"):
-                if k in entries:
-                    out[f"{name}/{k}"] = np.asarray(entries[k][block])
-        return out
+        return {f"{name}/{k}": np.asarray(self._state[name][k][block])
+                for name, names in self._pools.items() for k in names}
 
     def import_block(self, block: int,
                      arrays: Dict[str, np.ndarray]) -> None:
@@ -310,12 +335,10 @@ class PagedKVDecodeModel:
         state = {}
         for name, entries in self._state.items():
             e = dict(entries)
-            for k in ("k_cache", "v_cache"):
-                if k in e:
-                    v = e[k]
-                    page = jnp.asarray(arrays[f"{name}/{k}"], v.dtype)
-                    e[k] = jax.device_put(v.at[block].set(page),
-                                          v.sharding)
+            for k in self._pools.get(name, ()):
+                v = e[k]
+                page = jnp.asarray(arrays[f"{name}/{k}"], v.dtype)
+                e[k] = jax.device_put(v.at[block].set(page), v.sharding)
             state[name] = e
         self._state = state
 
@@ -520,6 +543,9 @@ class ContinuousScheduler:
         self._kv_block_bytes = int(getattr(model, "kv_block_bytes", 0))
         self.kernel_blocks_read = 0   # physical blocks streamed
         self.kernel_dense_blocks = 0  # gather-equivalent block reads
+        # routed-expert counters summed over decode dispatches (a model
+        # without such layers leaves them None): stats()["moe"]
+        self.moe_totals: Optional[Dict[str, int]] = None
         # bench/debug: run the pool's full invariant sweep after every
         # scheduler step (the serving_prefix leg's acceptance bar)
         self._check_invariants = bool(check_invariants)
@@ -843,6 +869,10 @@ class ContinuousScheduler:
                 "peak_used_blocks": self.pool.peak_used,
                 "occupancy": round(self.pool.occupancy(), 4),
                 "fragmentation": round(self.pool.fragmentation(), 4),
+                # all layers' pools: 576 values a layer for a latent
+                # cache, 2 x heads x head_dim for keys and values
+                "bytes_per_token":
+                    self._kv_block_bytes // self.pool.page_size,
             },
             "prefix_cache": self.pool.prefix_stats(),
             "speculative": {
@@ -891,6 +921,8 @@ class ContinuousScheduler:
             },
             "ttft": self.ttft_stats(),
             "latency": self.latency_stats(),
+            **({"moe": dict(self.moe_totals)}
+               if self.moe_totals is not None else {}),
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1280,16 +1312,17 @@ class ContinuousScheduler:
         against what the dense [slots, decode_max_seq] view holds, for
         a program that runs `counts[i]` seq-1 positions of row i from
         `seq_lens[i]` in `steps` scanned passes.  The gather
-        formulation reads the whole view whatever is live."""
-        tw = self.pool.max_blocks_per_seq
-        dense = self.model.batch_slots * tw * steps
-        if self._paged_kernel != "pallas":
-            return {"kv_blocks_read": dense, "kv_blocks_dense": dense}
+        formulation reads the whole view whatever is live;
+        `kv_blocks_live` is what an in-place read would touch, under
+        either formulation."""
         from ..ops.pallas.paged_attention import scan_blocks_read
 
-        return {"kv_blocks_read": scan_blocks_read(
-                    seq_lens, counts, self.pool.page_size, tw),
-                "kv_blocks_dense": dense}
+        tw = self.pool.max_blocks_per_seq
+        dense = self.model.batch_slots * tw * steps
+        live = scan_blocks_read(seq_lens, counts, self.pool.page_size, tw)
+        return {"kv_blocks_read": (live if self._paged_kernel == "pallas"
+                                   else dense),
+                "kv_blocks_dense": dense, "kv_blocks_live": live}
 
     def _note_kernel_reads(self, reads: Dict) -> None:
         """Sum one completed dispatch's `_kv_reads` into the fused
@@ -1679,6 +1712,15 @@ class ContinuousScheduler:
                 dispatch.set(**reads)
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab)
+                moe = getattr(self.model, "moe_last", None)
+                if moe is not None:
+                    dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})
+                    if self.moe_totals is None:
+                        self.moe_totals = dict.fromkeys(
+                            (*moe, "dispatches"), 0)
+                    for k, v in moe.items():
+                        self.moe_totals[k] += v
+                    self.moe_totals["dispatches"] += 1
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 # device-loss-style fault (hung dispatch, lost

@@ -14,7 +14,7 @@ print("device:", dev, flush=True)
 
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.decoding import (
-    gpt_generate_cached, gpt_generate_scan, make_gpt_decoder,
+    gpt_generate_cached, gpt_generate_scan, make_decoder,
 )
 from flexflow_tpu.models.transformer import build_gpt, gpt_generate
 
@@ -29,7 +29,7 @@ rng = np.random.RandomState(0)
 prompt = rng.randint(1, 50257, size=(B, 64)).astype(np.int32)
 
 print("building decoder twin...", flush=True)
-ffd = make_gpt_decoder(ff, devices=[dev])
+ffd = make_decoder(ff, devices=[dev])
 
 # warm each path once on a short run, then time one full generation
 for name, fn in [

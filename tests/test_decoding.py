@@ -16,7 +16,7 @@ from flexflow_tpu.decoding import (
     gpt_beam_search_cached,
     gpt_generate_cached,
     gpt_generate_scan,
-    make_gpt_decoder,
+    make_decoder,
 )
 from flexflow_tpu.models.transformer import (
     build_gpt,
@@ -49,7 +49,7 @@ def _trained_gpt(devices8, steps=40):
 
 def test_cached_decode_matches_full_forward(devices8):
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     prompt = ids[:, :5]
     full = gpt_generate(ff, prompt, max_new_tokens=6)
     cached = gpt_generate_cached(ffd, prompt, max_new_tokens=6)
@@ -58,7 +58,7 @@ def test_cached_decode_matches_full_forward(devices8):
 
 def test_scan_decode_matches_full_forward(devices8):
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     prompt = ids[:, :5]
     full = gpt_generate(ff, prompt, max_new_tokens=6)
     scanned = gpt_generate_scan(ffd, prompt, max_new_tokens=6)
@@ -69,7 +69,7 @@ def test_cache_reset_between_sequences(devices8):
     """A second generation with a different prompt must not see stale
     cache rows from the first."""
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     p1, p2 = ids[:, :5], ids[:, 3:8]
     out2_fresh = gpt_generate_cached(ffd, p2, 4)
     _ = gpt_generate_cached(ffd, p1, 4)
@@ -79,7 +79,7 @@ def test_cache_reset_between_sequences(devices8):
 
 def test_cached_sampling_runs(devices8):
     ff, ids = _trained_gpt(devices8, steps=5)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     prompt = ids[:, :4]
     out = gpt_generate_cached(ffd, prompt, 5, temperature=0.8,
                               top_k=8, top_p=0.9, seed=3)
@@ -96,7 +96,7 @@ def test_decoder_introspection_rejects_non_gpt(devices8):
     x = ff.create_tensor([2, 8], name="x")
     ff.dense(x, 4)
     with pytest.raises(ValueError):
-        make_gpt_decoder(ff)
+        make_decoder(ff)
 
 
 def test_decode_graph_rejects_kv_append():
@@ -115,7 +115,7 @@ def test_forward_refuses_decode_graph(devices8):
     """forward()/eval on a decode graph would drop the cache updates
     and compute against cache_pos=0 forever — it must raise."""
     ff, ids = _trained_gpt(devices8, steps=1)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     with pytest.raises(RuntimeError, match="decode_step"):
         ffd.forward({"input": ids[:, :1],
                      "positions": np.zeros((B, 1), np.int32)})
@@ -125,7 +125,7 @@ def test_decode_guard_syncs_from_device_state(devices8):
     """The host-side overflow-guard counter rebuilds from the device
     cache_pos after an external state swap (checkpoint restore path)."""
     ff, ids = _trained_gpt(devices8, steps=1)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     ffd.reset_decode_state()
     for t in range(3):
         ffd.decode_step({"input": ids[:, t:t + 1],
@@ -161,7 +161,7 @@ def test_scan_generate_one_program_per_total(devices8):
     """Prompt length is a traced operand: two different plens with the
     same total reuse one compiled scan program."""
     ff, ids = _trained_gpt(devices8, steps=1)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     gpt_generate_scan(ffd, ids[:, :4], max_new_tokens=5)   # total 9
     gpt_generate_scan(ffd, ids[:, :6], max_new_tokens=3)   # total 9
     assert len(ffd._scan_gen_cache) == 1
@@ -175,7 +175,7 @@ def test_cached_beam_search_matches_full_forward(devices8):
     """The O(T) KV-cached beam search reproduces the O(T^2) reference
     path exactly: same tokens, same score (single prompt)."""
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])  # batch B=4 beams
+    ffd = make_decoder(ff, devices=devices8[:1])  # batch B=4 beams
     prompt = ids[:1, :5]
     want_toks, want_score = gpt_beam_search(ff, prompt, max_new_tokens=6,
                                             beam_size=4)
@@ -189,7 +189,7 @@ def test_cached_beam_search_eos_and_length_penalty(devices8):
     """eos freezing and GNMT length normalization agree with the
     reference path (frozen beams compete at their final score)."""
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     prompt = ids[:1, :4]
     eos = int(ids[0, 6])  # an id the greedy continuation will hit
     want_toks, want_score = gpt_beam_search(
@@ -207,7 +207,7 @@ def test_cached_beam_search_batched_prompts(devices8):
     full-forward beam search (cache-row reordering keeps each row's
     cache consistent with its hypothesis)."""
     ff, ids = _trained_gpt(devices8)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])  # batch 4 = 2x2
+    ffd = make_decoder(ff, devices=devices8[:1])  # batch 4 = 2x2
     prompts = np.stack([ids[0, :5], ids[2, 1:6]])
     got_toks, got_scores = gpt_beam_search_cached(
         ffd, prompts, max_new_tokens=5, beam_size=2)
@@ -222,7 +222,7 @@ def test_decode_overflow_guard(devices8):
     """Stepping past decode_max_seq raises instead of silently
     clamping the cache write (device dynamic_update_slice clamps)."""
     ff, ids = _trained_gpt(devices8, steps=1)
-    ffd = make_gpt_decoder(ff, devices=devices8[:1])
+    ffd = make_decoder(ff, devices=devices8[:1])
     ffd.reset_decode_state()
     for t in range(S):
         ffd.decode_step({"input": ids[:, t:t + 1],

@@ -1,0 +1,444 @@
+"""The kimi_k2 language model (MLA over a paged latent cache, a share of
+sigmoid-routed experts with a shared expert, RMSNorm, gated MLP)
+against its plain float32 reference (benchmarks/families/kimi_k2.py) on
+seeded weights, at a toy size on the CPU, comparing LOGITS.
+
+Tolerances.  The program and the reference compute the same float32
+arithmetic in another order (absorbed against expanded attention, one
+batched product over experts against one expert at a time), so they
+differ by rounding only: 1e-5 of the compared tensor's largest
+magnitude for one op, 2e-5 for logits that went through every layer.
+A bf16 run misses that by three orders of magnitude
+(`test_bf16_logits_leave_the_float32_tolerance`).
+"""
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference as ref
+from benchmarks.families import kimi_k2 as fam
+from flexflow_tpu import FFConfig, FFModel
+from flexflow_tpu.config import ConfigError
+from flexflow_tpu.models.kimi_k2 import build_kimi_k2
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmarks", "configs", "toy-kimi.json")) as f:
+    CFG = json.load(f)
+D = fam.dims(CFG)
+SEED = 11
+KEY = ref.seed_key(SEED)
+OP_TOL, LOGIT_TOL = 1e-5, 2e-5
+
+
+def close(got, want, tol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape
+    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
+    assert err <= tol, err
+
+
+def holder(cfg=CFG, seq=None, precision=None, **ffconfig):
+    """The served model's holder with the seed's weights set."""
+    cfg = dict(cfg, precision=precision or cfg["precision"])
+    dep = cfg["deployment"]
+    ff = FFModel(FFConfig(
+        batch_size=1, num_devices=1, compute_dtype=cfg["precision"],
+        serving_slots=dep["serving_slots"], kv_page_size=dep["kv_page_size"],
+        kv_pool_blocks=dep["kv_pool_blocks"], **ffconfig))
+    build_kimi_k2(ff, 1, seq or cfg["n_positions"], **fam.published(cfg))
+    ff.compile(devices=jax.devices()[:1], defer_weights=True)
+    ff.set_weights(fam.make_weights(cfg, SEED, "program"))
+    return ff
+
+
+def reference_logits(tokens):
+    return np.asarray(fam.logits_fn(
+        fam.make_weights(CFG, SEED, "reference"),
+        jnp.asarray(tokens, jnp.int32), "float32"))
+
+
+# -- 1. each op alone ----------------------------------------------------------
+def one_op_model(build):
+    """A model of input -> one op: (ff, the op's name)."""
+    ff = FFModel(FFConfig(batch_size=2, num_devices=1))
+    x = ff.create_tensor([2, 12, D.e], name="x")
+    pos = ff.create_tensor([2, 12], dtype="int32", name="positions")
+    out = build(ff, x, pos)
+    ff.compile(devices=jax.devices()[:1], defer_weights=True)
+    return ff, out.owner_op.name
+
+
+def mla_params():
+    return holder_graph_op("attn_0").params
+
+
+def holder_graph_op(name):
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    build_kimi_k2(ff, 1, 8, **fam.published(CFG))
+    return next(op for op in ff.layers.topo_order() if op.name == name)
+
+
+OPS = {
+    "rms_norm": (
+        lambda ff, x, pos: ff.rms_norm(x, D.eps, name="op"), "norm",
+        lambda x, w: fam.rms(x, w["gamma"], D.eps)),
+    "gated_mlp": (
+        lambda ff, x, pos: ff.gated_mlp(x, D.f_dense, name="op"), "mlp",
+        lambda x, w: fam.gated(x, w["w_gate"], w["w_up"], w["w_down"],
+                               lambda v: v)),
+    "mla": (
+        lambda ff, x, pos: ff.mla_attention(x, pos, mla_params(), name="op"),
+        "attn", lambda x, w: fam.attention(x, w, D, lambda v: v)),
+    "routed_experts": (
+        lambda ff, x, pos: ff.routed_experts(
+            x, holder_graph_op("moe_1").params, name="op"),
+        "moe", None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(OPS))
+def test_op_alone_matches_the_reference(name):
+    build, kind, want_fn = OPS[name]
+    ff, op_name = one_op_model(build)
+    w = jax.tree.map(np.asarray, fam.make_op(
+        KEY, 1, d=D, kind=kind, dtype=jnp.dtype("float32")))
+    ff.set_weights({op_name: w})
+    x = np.asarray(jax.random.normal(jax.random.key(3), (2, 12, D.e)))
+    pos = np.tile(np.arange(12, dtype=np.int32), (2, 1))
+    got = np.asarray(ff.forward({"x": x, "positions": pos}))
+    with jax.default_matmul_precision("highest"):
+        if name == "routed_experts":
+            want = [sum(fam.experts(jnp.asarray(row), KEY, 1, D,
+                                    lambda v: v)) for row in x]
+        else:
+            want = [want_fn(jnp.asarray(row), w) for row in x]
+    close(got, np.stack(want), OP_TOL)
+
+
+# -- 2. prefill then decode through the paged latent cache -----------------------
+class Recorder:
+    """Wraps a scheduler's model so that every decode dispatch's logits
+    are kept beside (request, position) of the row they belong to."""
+
+    def __init__(self, sched):
+        self.sched, self.rows, model = sched, [], sched.model
+        inner = model.step
+
+        def step(tokens, seq_lens, block_tables):
+            logits = inner(tokens, seq_lens, block_tables)
+            for i, live in enumerate(sched._slots):
+                if live is not None:
+                    self.rows.append((live.req, live.pos, logits[i].copy()))
+            return logits
+
+        model.step = step
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One scheduler over the toy model and a scenario with chunked
+    prefill, a full-prompt prefix hit (copy-on-write), a partial hit
+    and a slot reused after a finished request: (recorded rows,
+    handles, scheduler stats)."""
+    from flexflow_tpu.serving.scheduler import ContinuousScheduler
+
+    ff = holder()
+    sched = ContinuousScheduler.from_trained(
+        ff, batch_slots=3, page_size=4, num_blocks=40, prefill_chunk=4,
+        prefix_cache=True, devices=jax.devices()[:1])
+    rec = Recorder(sched)
+    try:
+        rng = np.random.default_rng(5)
+        a = rng.integers(1, D.v, 16).tolist()  # four full pages
+        b = a[:8] + rng.integers(1, D.v, 7).tolist()
+        c = rng.integers(1, D.v, 9).tolist()
+        handles = [sched.generate_async(a, 6, 0.0)]
+        handles[0].wait(120)
+        # a again (every block cached: the tail block is copied before
+        # the first write), b (shares two blocks), c: three slots at once
+        handles += [sched.generate_async(p, 5, 0.0) for p in (a, b, c)]
+        for h in handles[1:]:
+            h.wait(120)
+        handles.append(sched.generate_async(c[:5], 4, 0.0))  # a slot again
+        handles[-1].wait(120)
+        stats = sched.stats()
+    finally:
+        sched.close(10)
+    return rec.rows, handles, stats
+
+
+def test_served_logits_equal_the_reference_full_forward(served):
+    rows, handles, _ = served
+    want = {id(h): reference_logits(h.result) for h in handles}
+    assert len(rows) >= 25
+    for req, pos, logits in rows:
+        close(logits, want[id(req)][pos], LOGIT_TOL)
+
+
+def test_scenario_hit_the_prefix_cache_copied_a_block_and_reused_a_slot(
+        served):
+    _, handles, stats = served
+    assert handles[1].prefix_hit_tokens >= 12      # the full-prompt hit
+    assert handles[2].prefix_hit_tokens == 8       # the shared two pages
+    assert stats["prefix_cache"]["cow_copies"] >= 1
+    assert stats["requests_done"] == 5 and stats["prefill_steps"] > 0
+    assert handles[0].result[:-1] == handles[1].result
+
+
+def test_latent_cache_holds_576_values_a_token_a_layer_at_published_widths():
+    """From the state's shapes, with the catalog's widths (no array is
+    made: the op only states its specs)."""
+    from flexflow_tpu.ops.mla import MLAParams
+
+    ff = FFModel(FFConfig(batch_size=32, num_devices=1))
+    x = ff.create_tensor([32, 1, 7168], name="x")
+    pos = ff.create_tensor([32, 1], dtype="int32", name="positions")
+    ff.mla_attention(x, pos, MLAParams(
+        embed_dim=7168, num_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
+        name="attn", decode_max_seq=2048, kv_page_size=16,
+        kv_num_blocks=4097)
+    op = ff.layers.topo_order()[-1]
+    state = {s.name: s.shape.logical_shape
+             for s in op.weight_specs[op.num_trainable_weights():]}
+    assert op.cache_entries() == ("latent_cache",)
+    assert state["latent_cache"] == (4097, 16, 576)
+    assert state["block_table"] == (32, 128) and state["seq_lens"] == (32,)
+    assert 2 * 64 * 128 == 16384  # what full keys and values would hold
+
+
+# -- 3. the share test -----------------------------------------------------------
+def test_every_share_of_an_expert_layer_adds_up_to_the_uncut_layer():
+    """The routed parts that all `total / held` shares give, with the
+    shared expert counted once, are the uncut reference's whole layer:
+    through the PROGRAM's op for each share, against the reference
+    given every expert."""
+    x = np.asarray(jax.random.normal(jax.random.key(7), (2, 12, D.e)))
+    pos = np.zeros((2, 12), np.int32)
+    with jax.default_matmul_precision("highest"):
+        whole = np.stack([sum(fam.experts(
+            jnp.asarray(row), KEY, 1, D, lambda v: v, held=(0, D.total)))
+            for row in x])
+        shared = np.stack([fam.experts(
+            jnp.asarray(row), KEY, 1, D, lambda v: v, held=(0, 0))[1]
+            for row in x])
+    total = np.zeros_like(whole)
+    for first in range(0, D.total, D.held):
+        cfg = dict(CFG, deployment=dict(CFG["deployment"],
+                                        first_held_expert=first))
+        d = fam.dims(cfg)
+        params = dict(fam.published(cfg))
+        ff, name = one_op_model(lambda ff, x, pos: ff.routed_experts(
+            x, _experts_params(params), name="op"))
+        ff.set_weights({name: jax.tree.map(np.asarray, fam.make_op(
+            KEY, 1, d=d, kind="moe", dtype=jnp.dtype("float32")))})
+        total += np.asarray(ff.forward({"x": x, "positions": pos})) - shared
+    close(total + shared, whole, OP_TOL)
+
+
+def _experts_params(kw):
+    from flexflow_tpu.ops.routed_experts import RoutedExpertsParams
+
+    return RoutedExpertsParams(
+        experts_total=kw["n_routed_experts_total"],
+        experts_held=kw["n_routed_experts"],
+        first_held=kw["first_held_expert"], top_k=kw["num_experts_per_tok"],
+        expert_hidden=kw["moe_intermediate_size"],
+        shared_hidden=kw["n_shared_experts"] * kw["moe_intermediate_size"],
+        routed_scaling_factor=kw["routed_scaling_factor"])
+
+
+# -- 4. no pair is dropped ---------------------------------------------------------
+def test_no_pair_is_dropped_when_every_token_chooses_one_expert():
+    from flexflow_tpu.ops.routed_experts import MOE_STATS
+
+    ff, name = one_op_model(lambda ff, x, pos: ff.routed_experts(
+        x, _experts_params(fam.published(CFG)), name="op"))
+    w = jax.tree.map(np.asarray, fam.make_op(
+        KEY, 1, d=D, kind="moe", dtype=jnp.dtype("float32")))
+    w["router_bias"] = w["router_bias"].copy()
+    w["router_bias"][D.first_held + 1] = 100.0  # everyone's first choice
+    ff.set_weights({name: w})
+    x = jax.random.normal(jax.random.key(9), (2, 12, D.e))
+    _, state, _, _ = ff.executor.run_forward(
+        ff._weights, ff._state,
+        {"x": x, "positions": jnp.zeros((2, 12), jnp.int32)},
+        training=False, rng=None)
+    stats = dict(zip(MOE_STATS, np.asarray(state[name]["moe_stats"])))
+    assert stats["dropped"] == 0
+    assert stats["max_rows"] == 24           # all 24 rows on that expert
+    assert stats["pairs"] >= 24 and 1 <= stats["hit"] <= D.held
+
+
+# -- 5. closed forms, and the router's precision -------------------------------------
+def test_yarn_frequencies_and_softmax_scale_against_the_closed_forms():
+    """ISSUE 29's closed forms at the PUBLISHED values."""
+    from flexflow_tpu.ops.mla import (MLAParams, softmax_scale,
+                                      yarn_frequencies)
+
+    p = MLAParams(embed_dim=7168, num_heads=64, q_lora_rank=1536,
+                  kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=50000.0,
+                  rope_factor=64.0, rope_original_max=4096, beta_fast=32,
+                  beta_slow=1, mscale=1.0, mscale_all_dim=1.0)
+    d, theta = 64, 50000.0
+    i = np.arange(32)
+    extra = theta ** (-2.0 * i / d)
+    dim = lambda r: d * np.log(4096 / (2 * np.pi * r)) / (2 * np.log(theta))  # noqa: E731
+    low, high = max(np.floor(dim(32)), 0), min(np.ceil(dim(1)), d - 1)
+    ramp = np.clip((i - low) / (high - low), 0, 1)
+    np.testing.assert_allclose(
+        yarn_frequencies(p), extra / 64 * ramp + extra * (1 - ramp),
+        rtol=1e-12)
+    m = 0.1 * 1.0 * np.log(64) + 1
+    assert abs(m - 1.4159) < 1e-4
+    assert abs(softmax_scale(p) - 192 ** -0.5 * m * m) < 1e-12
+    # the reference's own copy of the same forms agrees at the toy size
+    np.testing.assert_allclose(
+        yarn_frequencies(mla_params()), fam.yarn_frequencies(D), rtol=1e-12)
+    assert abs(softmax_scale(mla_params()) - fam.softmax_scale(D)) < 1e-12
+
+
+def test_router_chooses_in_float32_when_compute_dtype_is_bfloat16():
+    """The executor hands the router's weights over in float32, and the
+    experts chosen for bf16-computed activations are the float32
+    top-k of those activations, where a bf16 product picks others."""
+    from flexflow_tpu.ops.routed_experts import route
+
+    ff = holder(precision="bfloat16")
+    assert ff._weights["moe_1"]["router"].dtype == jnp.float32
+    assert ff._weights["moe_1"]["w_gate"].dtype == jnp.bfloat16
+    op = next(o for o in ff.operators.topo_order() if o.name == "moe_1")
+    seen = {}
+    inner = op.forward
+
+    def spy(inputs, weights, **kw):
+        seen["dtypes"] = [w.dtype for w in weights]
+        return inner(inputs, weights, **kw)
+
+    op.forward = spy
+    ids = np.arange(1, 13, dtype=np.int32)[None]
+    ff.forward({"input": np.pad(ids, ((0, 0), (0, D.p - 12))),
+                "positions": np.arange(D.p, dtype=np.int32)[None]})
+    assert seen["dtypes"][:2] == [jnp.float32, jnp.float32]  # router, bias
+    assert seen["dtypes"][2] == jnp.bfloat16
+    # a wide router (the published 384 outputs, top 8) over bf16
+    # activations: the float32 choice differs from a bf16 product's
+    h = jax.random.normal(jax.random.key(1), (64, 256)).astype(jnp.bfloat16)
+    r = 0.02 * jax.random.normal(jax.random.key(2), (256, 384))
+    p = _experts_params(dict(fam.published(CFG), n_routed_experts_total=384,
+                             num_experts_per_tok=8))
+    chosen, _ = route(h, r, jnp.zeros(384), p)
+    want = jax.lax.top_k(jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), r, precision="highest")), 8)[1]
+    low = jax.lax.top_k(jax.nn.sigmoid(jnp.matmul(
+        h, r.astype(jnp.bfloat16)).astype(jnp.float32)), 8)[1]
+    assert np.array_equal(np.sort(chosen), np.sort(want))
+    assert not np.array_equal(np.sort(low), np.sort(want))
+
+
+# -- 6. the comparison tells the precisions apart --------------------------------------
+def test_bf16_logits_leave_the_float32_tolerance():
+    tokens = np.random.default_rng(3).integers(1, D.v, D.p)
+    inputs = {"input": tokens[None].astype(np.int32),
+              "positions": np.arange(D.p, dtype=np.int32)[None]}
+    want = reference_logits(tokens)
+    close(holder().forward(inputs)[0], want, LOGIT_TOL)
+    got = np.asarray(holder(precision="bfloat16").forward(inputs)[0],
+                     np.float32)
+    err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+    assert err > 100 * LOGIT_TOL, err
+
+
+# -- 7. what the family does not carry ---------------------------------------------------
+def _front(**ffconfig):
+    from flexflow_tpu.serving import build_front
+
+    return build_front(holder(**ffconfig))
+
+
+def _beam():
+    from flexflow_tpu.decoding import gpt_beam_search_cached, make_decoder
+
+    twin = make_decoder(holder(), batch_size=2, kv_page_size=4,
+                        kv_num_blocks=20, devices=jax.devices()[:1])
+    gpt_beam_search_cached(twin, [[1, 2, 3]], 2, beam_size=2)
+
+
+def _dense_cache():
+    from flexflow_tpu.decoding import make_decoder
+
+    make_decoder(holder(), batch_size=2, devices=jax.devices()[:1])
+
+
+def _kernel_by_name():
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    PagedKVDecodeModel(holder(), batch_slots=2, page_size=4,
+                       paged_kernel="pallas", devices=jax.devices()[:1])
+
+
+NOT_CARRIED = {
+    "speculative": lambda: _front(spec_decode="ngram"),
+    "tensor_parallel": lambda: _front(serving_tp=2),
+    "disaggregated": lambda: _front(serving_roles="prefill=1,decode=1"),
+    "handoff": lambda: _front(serving_handoff=True),
+    "beam_search": _beam,
+    "dense_cache": _dense_cache,
+    "pallas_read": _kernel_by_name,
+}
+
+
+@pytest.mark.parametrize("feature", sorted(NOT_CARRIED))
+def test_feature_not_carried_is_a_config_error_by_name(feature):
+    with pytest.raises(ConfigError, match=f"kimi_k2 does not carry {feature}"):
+        NOT_CARRIED[feature]()
+
+
+# -- the served model holds its weights once ---------------------------------------------
+def test_deferred_weights_are_held_once_in_the_precision_given():
+    from flexflow_tpu.decoding import make_decoder
+
+    ff = holder(precision="bfloat16")
+    assert ff._opt_state is None and ff._step_fn is None
+    twin = make_decoder(ff, batch_size=2, kv_page_size=4, kv_num_blocks=20,
+                        devices=jax.devices()[:1])
+    for op, entries in twin._weights.items():
+        for k, v in entries.items():
+            assert v is ff._weights[op][k], (op, k)  # no second copy
+    dtypes = {str(v.dtype) for e in twin._weights.values()
+              for v in e.values()}
+    assert dtypes == {"bfloat16", "float32"}
+    floats = sum(v.size for e in twin._weights.values() for v in e.values()
+                 if v.dtype == jnp.float32)
+    assert floats == 2 * (D.e * D.total + D.total)  # the routers only
+    assert twin._state["attn_0"]["latent_cache"].dtype == jnp.bfloat16
+    with pytest.raises(RuntimeError, match="defer_weights"):
+        ff.train_step({}, np.zeros(1))
+
+
+def test_gpt_twin_state_and_pool_predicate_are_unchanged():
+    """`cache_entries` names the GPT-2 twin's k/v pools; its state
+    pytree is what it was."""
+    from flexflow_tpu import LossType, SGDOptimizer
+    from flexflow_tpu.decoding import cache_entries, make_decoder
+    from flexflow_tpu.models.transformer import build_gpt
+
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    build_gpt(ff, 1, 16, hidden_size=32, num_layers=2, num_heads=2,
+              intermediate_size=64, vocab_size=50)
+    ff.compile(optimizer=SGDOptimizer(lr=0.01),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               devices=jax.devices()[:1])
+    twin = make_decoder(ff, batch_size=2, kv_page_size=4, kv_num_blocks=9,
+                        devices=jax.devices()[:1])
+    assert cache_entries(twin) == {"attn_0": ("k_cache", "v_cache"),
+                                   "attn_1": ("k_cache", "v_cache")}
+    assert {op: sorted(e) for op, e in twin._state.items()} == {
+        f"attn_{i}": ["block_table", "k_cache", "seq_lens", "v_cache"]
+        for i in range(2)}
+    assert twin._state["attn_0"]["k_cache"].shape == (9, 4, 2, 16)

@@ -305,9 +305,9 @@ def test_default_formulation_follows_the_backend(monkeypatch, backend,
 
 def test_dense_cache_rejects_kernel_selection():
     """kv_kernel='pallas' without a paged pool has no block table to
-    stream through — refused loudly at make_gpt_decoder time."""
+    stream through — refused loudly at make_decoder time."""
     from flexflow_tpu import FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.decoding import make_gpt_decoder
+    from flexflow_tpu.decoding import make_decoder
     from flexflow_tpu.models.transformer import build_gpt
 
     ff = FFModel(FFConfig(batch_size=2, num_devices=1))
@@ -315,7 +315,7 @@ def test_dense_cache_rejects_kernel_selection():
               num_layers=1, num_heads=2, intermediate_size=32,
               vocab_size=16)
     with pytest.raises(ValueError, match="kv_page_size"):
-        make_gpt_decoder(ff, kv_kernel="pallas")
+        make_decoder(ff, kv_kernel="pallas")
 
 
 # -- model-level: the compiled decode step ----------------------------
@@ -369,11 +369,11 @@ def _collect_avals(jaxpr, acc):
 
 def _decode_step_avals(ff, devices8, kv_kernel):
     from flexflow_tpu.decoding import (build_paged_decode_step,
-                                       make_gpt_decoder)
+                                       make_decoder)
 
     page = 4
     nb = 1 + B * (S // page)
-    paged = make_gpt_decoder(ff, devices=devices8[:1], kv_page_size=page,
+    paged = make_decoder(ff, devices=devices8[:1], kv_page_size=page,
                              kv_num_blocks=nb, kv_kernel=kv_kernel)
     step = build_paged_decode_step(paged)
     btab = np.arange(1, nb, dtype=np.int32).reshape(B, S // page)
@@ -446,7 +446,7 @@ def test_kernel_chunk_twin_matches_gather_chunk_twin(trained, devices8):
     corrupts a real block — the kernel scatter carries the same
     scratch-routing clamp build_paged_prefill_step pins."""
     from flexflow_tpu.decoding import (build_paged_chunk_step,
-                                       make_gpt_decoder)
+                                       make_decoder)
 
     ff, ids = trained
     page, C = 4, 4
@@ -455,7 +455,7 @@ def test_kernel_chunk_twin_matches_gather_chunk_twin(trained, devices8):
     btab = np.arange(1, nb, dtype=np.int32).reshape(B, max_blocks)
 
     def twin(kv_kernel):
-        m = make_gpt_decoder(ff, devices=devices8[:1], kv_page_size=page,
+        m = make_decoder(ff, devices=devices8[:1], kv_page_size=page,
                              kv_num_blocks=nb, step_tokens=C,
                              kv_kernel=kv_kernel)
         return m, build_paged_chunk_step(m)

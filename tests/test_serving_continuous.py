@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-from flexflow_tpu.decoding import build_paged_decode_step, make_gpt_decoder
+from flexflow_tpu.decoding import build_paged_decode_step, make_decoder
 from flexflow_tpu.models.transformer import build_gpt
 from flexflow_tpu.serving import ContinuousScheduler, GenerationEngine
 from flexflow_tpu.serving.loadgen import run_loadgen, sample_workload
@@ -46,10 +46,10 @@ def test_paged_decode_step_bit_identical_to_dense(trained, devices8):
     import jax.numpy as jnp
 
     ff, ids = trained
-    dense = make_gpt_decoder(ff, devices=devices8[:1])
+    dense = make_decoder(ff, devices=devices8[:1])
     page = 4
     max_blocks = S // page
-    paged = make_gpt_decoder(ff, devices=devices8[:1], kv_page_size=page,
+    paged = make_decoder(ff, devices=devices8[:1], kv_page_size=page,
                              kv_num_blocks=1 + B * max_blocks)
     step = build_paged_decode_step(paged)
 
@@ -172,7 +172,7 @@ def test_chunked_prefill_writes_bit_identical_cache(trained, devices8):
     max_blocks = S // page
 
     def fresh():
-        paged = make_gpt_decoder(ff, devices=devices8[:1],
+        paged = make_decoder(ff, devices=devices8[:1],
                                  kv_page_size=page,
                                  kv_num_blocks=1 + B * max_blocks)
         btab = np.zeros((B, max_blocks), np.int32)
@@ -218,7 +218,7 @@ def test_chunked_prefill_writes_bit_identical_cache(trained, devices8):
 
 
 def test_chunk_twin_multi_token_attention_matches(trained, devices8):
-    """The true seq-C paged twin (make_gpt_decoder(step_tokens=C) +
+    """The true seq-C paged twin (make_decoder(step_tokens=C) +
     build_paged_chunk_step — the fused TPU-native prefill shape)
     agrees with one-token stepping to float tolerance (its batched
     matmuls are not rowwise-bitwise-stable on XLA:CPU, which is
@@ -237,7 +237,7 @@ def test_chunk_twin_multi_token_attention_matches(trained, devices8):
         for i in range(B):
             btab[i, j] = blocks.pop(0)
 
-    ref = make_gpt_decoder(ff, devices=devices8[:1], kv_page_size=page,
+    ref = make_decoder(ff, devices=devices8[:1], kv_page_size=page,
                            kv_num_blocks=nb)
     ref_step = build_paged_decode_step(ref)
     state = ref._state
@@ -249,7 +249,7 @@ def test_chunk_twin_multi_token_attention_matches(trained, devices8):
                                  jnp.asarray(btab))
         want.append(np.asarray(logits))
 
-    twin = make_gpt_decoder(ff, devices=devices8[:1], kv_page_size=page,
+    twin = make_decoder(ff, devices=devices8[:1], kv_page_size=page,
                             kv_num_blocks=nb, step_tokens=C)
     chunk_step = build_paged_chunk_step(twin)
     logits, _ = chunk_step(twin._weights, twin._state,
@@ -322,7 +322,7 @@ def test_chunk_pad_overflow_never_writes_real_blocks(trained, devices8):
     max_blocks = S // page  # 4 columns: positions 0..15
 
     def fresh():
-        paged = make_gpt_decoder(ff, devices=devices8[:1],
+        paged = make_decoder(ff, devices=devices8[:1],
                                  kv_page_size=page,
                                  kv_num_blocks=1 + B * max_blocks)
         btab = np.arange(1, 1 + B * max_blocks,

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-from flexflow_tpu.decoding import gpt_generate_cached, make_gpt_decoder
+from flexflow_tpu.decoding import gpt_generate_cached, make_decoder
 from flexflow_tpu.models.transformer import build_gpt, gpt_generate
 from flexflow_tpu.serving import GenerationBatcher, GenerationEngine
 from flexflow_tpu.serving.server import serve_http
@@ -54,7 +54,7 @@ def test_engine_matches_reference_decode(trained, gen_engine):
     ff, ids = trained
     prompts = [ids[i, :5].tolist() for i in range(B)]
     got = gen_engine.generate(prompts, max_new_tokens=6)
-    ffd = make_gpt_decoder(ff, devices=None)
+    ffd = make_decoder(ff, devices=None)
     want = gpt_generate_cached(ffd, ids[:, :5], max_new_tokens=6)
     for i in range(B):
         np.testing.assert_array_equal(got[i], want[i])
@@ -80,7 +80,7 @@ def test_engine_mixed_prompt_lengths(trained, gen_engine):
 
 def test_engine_eos_trimming(trained, devices8):
     ff, ids = trained
-    ffd_ref = make_gpt_decoder(ff, devices=None)
+    ffd_ref = make_decoder(ff, devices=None)
     want = gpt_generate_cached(ffd_ref, ids[:, :4], max_new_tokens=8)
     eos = int(want[0, 6])  # force a hit inside row 0's continuation
     eng = GenerationEngine(ff, batch_size=B, devices=devices8[:1],

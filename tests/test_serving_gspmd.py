@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-from flexflow_tpu.decoding import make_gpt_decoder
+from flexflow_tpu.decoding import make_decoder
 from flexflow_tpu.models.transformer import build_gpt
 from flexflow_tpu.serving import ContinuousScheduler
 
@@ -206,18 +206,18 @@ def test_tp_strategy_served_through_store(trained, devices8, tmp_path):
     old = trained.config.strategy_store
     trained.config.strategy_store = str(store)
     try:
-        d1 = make_gpt_decoder(trained, batch_size=2, kv_page_size=4,
+        d1 = make_decoder(trained, batch_size=2, kv_page_size=4,
                               kv_num_blocks=12, tp=2,
                               devices=devices8[:2])
         assert d1.strategy.search_stats["store_hit"] is False
-        d2 = make_gpt_decoder(trained, batch_size=2, kv_page_size=4,
+        d2 = make_decoder(trained, batch_size=2, kv_page_size=4,
                               kv_num_blocks=12, tp=2,
                               devices=devices8[:2])
         assert d2.strategy.search_stats["store_hit"] is True
         assert d2.strategy.search_stats["store_key"] == \
             d1.strategy.search_stats["store_key"]
         # a different mesh degree is a different key — no false hit
-        d4 = make_gpt_decoder(trained, batch_size=2, kv_page_size=4,
+        d4 = make_decoder(trained, batch_size=2, kv_page_size=4,
                               kv_num_blocks=12, tp=4,
                               devices=devices8[:4])
         assert d4.strategy.search_stats["store_hit"] is False
