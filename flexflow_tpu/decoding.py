@@ -45,7 +45,9 @@ class DecoderRecipe:
     `kwargs` are twin-ready (no dropout, the trained position range);
     `dims` is what the serving tier reads (`num_layers`, `num_heads`,
     `vocab_size`, `max_seq`, ...); `carries` names what the family
-    supports beyond being built (`require_carried`)."""
+    supports beyond being built (`require_carried`), and what its
+    graph allows: `prefill_pass` = the seq-1 twin's graph over [b, C]
+    inputs is the seq-C forward (build_paged_prefill_pass)."""
 
     family: str
     build: Callable
@@ -140,7 +142,13 @@ def make_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     causally within the chunk — the multi-token prefill shape
     (build_paged_chunk_step).  Its state pytree is congruent with the
     seq-1 twin's (pools, tables and seq_lens are all seq-independent),
-    so both programs thread one shared state.
+    so both programs thread one shared state.  That twin returns
+    logits and is GPT's (`chunk_twin`).  The ENGINE's chunked prefill
+    needs no second twin: GPT scans this seq-1 twin's step
+    (build_paged_prefill_step, byte-equal to one-token prefill); a
+    family whose recipe carries `prefill_pass` (kimi_k2) runs this
+    same twin once over [b, C] (build_paged_prefill_pass), so its
+    weights stay resident once and are streamed once a dispatch.
 
     kv_kernel selects the paged READ formulation (docs/SERVING.md
     "Fused paged attention"): "gather" (default) is the dense
@@ -536,6 +544,21 @@ def run_generate_scan(ffd: FFModel, prompt_pad: np.ndarray,
     return out
 
 
+def _host_owned(state, block_table, seq_lens):
+    """The state pytree with every paged op's host-owned entries
+    (`block_table`, `seq_lens`) replaced by the dispatch's own: inside
+    the trace, so the per-step override costs nothing at run time and
+    the host never rebuilds the state dict."""
+    return {
+        op: {
+            k: (block_table if k == "block_table"
+                else seq_lens if k == "seq_lens" else v)
+            for k, v in entries.items()
+        }
+        for op, entries in state.items()
+    }
+
+
 def build_paged_decode_step(ffd: FFModel):
     """ONE compiled step function for continuous batching on a paged
     decode twin (make_decoder with kv_page_size > 0):
@@ -565,14 +588,7 @@ def build_paged_decode_step(ffd: FFModel):
     ex = ffd.executor
 
     def step(weights, state, tokens, positions, block_table):
-        state = {
-            op: {
-                k: (block_table if k == "block_table"
-                    else positions if k == "seq_lens" else v)
-                for k, v in entries.items()
-            }
-            for op, entries in state.items()
-        }
+        state = _host_owned(state, block_table, positions)
         logits, new_state, _, _ = ex.run_forward(
             weights, state,
             {"input": tokens[:, None],
@@ -636,14 +652,7 @@ def build_paged_prefill_step(ffd: FFModel, chunk: int):
             # byte-level contract either way.
             bt_j = jnp.where((pos_j < max_seq)[:, None], block_table, 0)
             pos_j = jnp.minimum(pos_j, max_seq - 1)
-            st = {
-                op: {
-                    k: (bt_j if k == "block_table"
-                        else pos_j if k == "seq_lens" else v)
-                    for k, v in entries.items()
-                }
-                for op, entries in carry.items()
-            }
+            st = _host_owned(carry, bt_j, pos_j)
             _, new_state, _, _ = ex.run_forward(
                 weights, st,
                 {"input": tok[:, None], "positions": pos_j[:, None]},
@@ -657,6 +666,49 @@ def build_paged_prefill_step(ffd: FFModel, chunk: int):
              jnp.arange(chunk, dtype=jnp.int32)),
         )
         return state
+
+    with ex.mesh:
+        return jax.jit(prefill, donate_argnums=(1,))
+
+
+def build_paged_prefill_pass(ffd: FFModel, chunk: int):
+    """build_paged_prefill_step's contract (same signature, state
+    donated, no logits, the jitted function still named `prefill`) as
+    ONE forward of the twin over [slots, C]: a dispatch streams the
+    weights once and builds each layer's gathered view once, where the
+    scan does both C times.
+
+    For a family whose recipe carries `prefill_pass`
+    (PagedKVDecodeModel chooses by that, never by a flag or a name).
+    The twin's graph is interpreted over [b, C] inputs as it stands:
+    the recipe's claim is that every op of it is per-token or takes
+    the step's length from its input (ops/mla.py `_attend_paged_chunk`,
+    which also keeps the pad contract: positions >= max_seq write
+    scratch).  What the claim gives up is the scan's byte equality with
+    seq-1 stepping (a [b*C, e] product is not rowwise-bitwise a [b, e]
+    one); such a family's outputs are held to its reference by
+    tolerance, and it carries neither `speculative` nor `handoff`.
+    Without logits XLA drops the last layer's attention read, experts
+    and the head."""
+    import jax
+    import jax.numpy as jnp
+
+    if chunk < 2:
+        raise ValueError(f"chunk must be >= 2, got {chunk}")
+    require_carried(ffd, "prefill_pass", "build_paged_prefill_pass")
+    ex = ffd.executor
+    max_seq = _gpt_dims(ffd)["max_seq"]
+
+    def prefill(weights, state, tokens, positions, block_table):
+        positions = positions.astype(jnp.int32)
+        state = _host_owned(state, block_table, positions)
+        grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+        _, new_state, _, _ = ex.run_forward(
+            weights, state,
+            {"input": tokens, "positions": jnp.minimum(grid, max_seq - 1)},
+            training=False, rng=None,
+        )
+        return new_state
 
     with ex.mesh:
         return jax.jit(prefill, donate_argnums=(1,))
@@ -708,14 +760,7 @@ def build_paged_verify_step(ffd: FFModel, chunk: int):
             # matter the gather/scatter out-of-range mode.
             bt_j = jnp.where(live[:, None], block_table, 0)
             pos_j = jnp.where(live, pos_j, 0)
-            st = {
-                op: {
-                    k: (bt_j if k == "block_table"
-                        else pos_j if k == "seq_lens" else v)
-                    for k, v in entries.items()
-                }
-                for op, entries in carry.items()
-            }
+            st = _host_owned(carry, bt_j, pos_j)
             logits, new_state, _, _ = ex.run_forward(
                 weights, st,
                 {"input": tok[:, None], "positions": pos_j[:, None]},
@@ -761,14 +806,7 @@ def build_paged_chunk_step(ffd: FFModel):
         positions = positions.astype(jnp.int32)
         chunk = tokens.shape[1]
         pos_grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
-        state = {
-            op: {
-                k: (block_table if k == "block_table"
-                    else positions if k == "seq_lens" else v)
-                for k, v in entries.items()
-            }
-            for op, entries in state.items()
-        }
+        state = _host_owned(state, block_table, positions)
         logits, new_state, _, _ = ex.run_forward(
             weights, state,
             {"input": tokens, "positions": pos_grid},

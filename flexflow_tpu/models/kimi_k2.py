@@ -118,7 +118,12 @@ def build_kimi_k2(
     logits = ff.dense(t, vocab_size, use_bias=False, name="lm_head")
 
     # what a decode twin is built from (decoding.make_decoder): this
-    # builder again, at seq 1 with paged state
+    # builder again, at seq 1 with paged state.  `prefill_pass`: every
+    # op of this graph is per-token or, as MLAttention's paged path,
+    # takes the step's length from its input, and nothing the family
+    # carries rests on byte equality with the seq-1 step, so the engine
+    # prefills a chunk in ONE forward of the twin over [slots, C]
+    # (decoding.build_paged_prefill_pass) where GPT scans the seq-1 step
     ff.decoder_recipe = DecoderRecipe(
         family="kimi_k2", build=build_kimi_k2,
         kwargs=dict(
@@ -143,6 +148,6 @@ def build_kimi_k2(
         dims={"num_layers": num_hidden_layers, "hidden_size": hidden_size,
               "num_heads": num_attention_heads, "vocab_size": vocab_size,
               "max_seq": max_position_embeddings},
-        # one token a step through the paged latent pool, one chip
-        carries=frozenset({"paged", "prefix_cache", "chunked_prefill"}))
+        carries=frozenset({"paged", "prefix_cache", "chunked_prefill",
+                           "prefill_pass"}))
     return logits

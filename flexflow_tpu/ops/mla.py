@@ -19,15 +19,18 @@ Two formulations, one set of weights:
 * paged latent cache (`decode_max_seq`, `kv_page_size`,
   `kv_num_blocks`): the state is ONE pool `latent_cache [num_blocks,
   page, rank + rope]` plus the host-owned `block_table` / `seq_lens`
-  every paged op carries.  A seq-1 step writes its token's latent at
-  the row's own position and attends with `W_kvb` ABSORBED: the query
-  is taken into the latent space (`q_nope W_kvb_k^T`), scores and the
-  weighted sum run on the gathered `[slots, max_seq, rank + rope]`
-  view, and the head's values come out of the latent at the end.  The
-  pool is read by gather only: `kv_kernel` "gather" and "pallas" are
-  the same formulation here (no in-place latent kernel is written;
-  Mosaic refused a 64-wide slice in PR 28, and the rope part is 64
-  wide).
+  every paged op carries.  A step of s tokens a row writes their
+  latents at the row's own positions `seq_lens[i] + j` and attends
+  with `W_kvb` ABSORBED: the queries are taken into the latent space
+  (`q_nope W_kvb_k^T`), scores and the weighted sum run on the
+  gathered `[slots, max_seq, rank + rope]` view, built ONCE a step,
+  and the heads' values come out of the latent at the end.  s == 1 is
+  the decode step; s == C is a prefill chunk in one pass over the
+  weights (decoding.build_paged_prefill_pass), query j masked to
+  `key_pos <= seq_lens[i] + j`.  The pool is read by gather only:
+  `kv_kernel` "gather" and "pallas" are the same formulation here (no
+  in-place latent kernel is written; Mosaic refused a 64-wide slice in
+  PR 28, and the rope part is 64 wide).
 
 Positions arrive as the op's second input; RoPE angles are computed
 from them in float32.  The published code de-interleaves the rope
@@ -127,6 +130,13 @@ def rope(x, positions, p: MLAParams):
 
 
 class MLAttention(Op):
+    """See the module docstring.  Paged, `forward` takes the step's
+    length from its input: seq 1 traces the decode step
+    (`_attend_paged`), seq C a prefill chunk in one pass
+    (`_attend_paged_chunk`), which is what lets a family built on this
+    op carry `prefill_pass`; GPT's attention keeps per-position
+    shapes instead, for the byte equality its scanned prefill gives."""
+
     op_type = OperatorType.MLA_ATTENTION
 
     def __init__(self, params, inputs, name="", shard=None,
@@ -204,11 +214,6 @@ class MLAttention(Op):
             return specs
         n, page, nb = (self._decode_max_seq, self._kv_page_size,
                        self._kv_num_blocks)
-        if xd[1].size != 1:
-            raise ShapeError(
-                f"{self.name}: the latent cache is stepped one token at "
-                f"a time (seq 1), got seq {xd[1].size}; prefill scans the "
-                "seq-1 step")
         if xd[0].degree != 1:
             raise ShapeError(
                 f"{self.name}: paged decode needs an unsharded batch dim "
@@ -249,6 +254,12 @@ class MLAttention(Op):
              rope(kv[..., rk:], positions, p)], axis=-1)  # [b, s, rk + dr]
         if self._paged():
             pool, btab, slen = weights[7:]
+            if x.shape[1] > 1:
+                ctx, pool = self._attend_paged_chunk(
+                    q_nope, q_rope, latent, wkv_b, pool, btab, slen)
+                out = jnp.einsum("bshd,hde->bse", ctx, wo)
+                return [out.astype(x.dtype), pool, btab, slen]
+            # seq 1 keeps the decode step's own trace (no size-1 axes)
             ctx, pool = self._attend_paged(q_nope[:, 0], q_rope[:, 0],
                                            latent[:, 0], wkv_b, pool,
                                            btab, slen)
@@ -297,6 +308,49 @@ class MLAttention(Op):
         probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
         out_lat = jnp.einsum("bhn,bnc->bhc", probs, view[..., :rk])
         return jnp.einsum("bhc,chd->bhd", out_lat, wkv_b[..., dn:]), pool
+
+    def _attend_paged_chunk(self, q_nope, q_rope, latent, wkv_b, pool,
+                            btab, slen):
+        """s > 1 tokens a row through the paged latent cache in one
+        pass: row i's token j is written at `slen[i] + j`, ALL s before
+        the read, the row's view is gathered once, and query j attends
+        `key_pos <= slen[i] + j`.  Writing the whole chunk first equals
+        write-then-attend a token at a time: a later chunk position
+        lands on a key the earlier queries' masks exclude (the argument
+        `MultiHeadAttention._attend_decode_paged_kernel` makes).  Same
+        precision and formulation as `_attend_paged`.
+
+        The pad contract of a chunked prefill is kept here, explicitly:
+        a position `>= max_seq` (a near-full row's trailing pads) is
+        written to scratch block 0, as through a zeroed table row, at a
+        position clamped in range, whatever jax's out-of-range gather
+        and scatter modes are; a rider (all-zero table row, `slen` 0)
+        writes scratch only.  Scratch writes collide; no query that
+        matters reads them."""
+        p: MLAParams = self.params
+        dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
+        b, s = latent.shape[:2]
+        page = self._kv_page_size
+        n = btab.shape[1] * page
+        pos = (slen.reshape(b, 1).astype(jnp.int32)
+               + jnp.arange(s, dtype=jnp.int32))  # [b, s]
+        at = jnp.minimum(pos, n - 1)
+        blk = jnp.where(pos < n,
+                        jnp.take_along_axis(btab, at // page, axis=1), 0)
+        pool = pool.at[blk, at % page].set(latent.astype(pool.dtype))
+        view = jnp.take(pool, btab, axis=0).reshape(b, n, -1) \
+            .astype(q_nope.dtype)
+        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, wkv_b[..., :dn])
+        scores = jnp.einsum(
+            "bshc,bnc->bhsn", jnp.concatenate([q_lat, q_rope], axis=-1),
+            view, preferred_element_type=jnp.float32) * softmax_scale(p)
+        live = jnp.arange(n, dtype=jnp.int32)[None, None, :] \
+            <= pos[:, :, None]  # [b, s, n]
+        scores = jnp.where(live[:, None], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+        out_lat = jnp.einsum("bhsn,bnc->bshc", probs, view[..., :rk])
+        return jnp.einsum("bshc,chd->bshd", out_lat, wkv_b[..., dn:]), pool
 
     def flops(self):
         p: MLAParams = self.params

@@ -71,6 +71,8 @@ class SupervisedDecodeModel:
         # prefix-cache / chunked-prefill surface (PagedKVDecodeModel;
         # absent on bare test fakes -> the scheduler degrades cleanly)
         self.prefill_chunk = getattr(model, "prefill_chunk", 0)
+        self.prefill_passes = getattr(model, "prefill_passes",
+                                      self.prefill_chunk)
         self.prefix_cache = getattr(model, "prefix_cache", True)
         # fused-kernel surface: which paged formulation runs + the
         # per-block byte unit the scheduler's read telemetry uses
@@ -553,6 +555,9 @@ class ServingReplica:
         if sched is not None:
             sstats = sched.stats()
             out["queue_depth"] = sstats["queue_depth"]
+            # weight passes of one prefill dispatch (the scan: the
+            # chunk; a family's one-pass program: 1)
+            out["prefill_passes"] = sstats["prefill_passes"]
             # this engine's pool (peak_used_blocks since its last build)
             out["kv_pool"] = sstats["kv_pool"]
             # prefix-cache visibility per replica (each pool caches

@@ -26,7 +26,9 @@ tokens per dispatch — docs/SERVING.md "Prefix cache & chunked
 prefill".  Greedy output is token-identical with sharing and chunking
 on or off: shared bytes were written by the same programs at the same
 positions, and the chunk program scans the seq-1 graph so every op
-keeps the decode step's shapes.
+keeps the decode step's shapes (a family whose recipe carries
+`prefill_pass` runs the chunk as one forward instead, equal by
+tolerance: PagedKVDecodeModel).
 
 Shape discipline (the TPU-native part): one compiled [slots, 1] step
 program serves the engine's whole lifetime — admissions, retirements
@@ -66,12 +68,20 @@ class PagedKVDecodeModel:
     scheduler data written into the op-state pytree each step.
 
     prefill_chunk = C > 1 additionally compiles the [b, C]
-    chunked-prefill program (decoding.build_paged_prefill_step): one
-    dispatch fills C prompt tokens per row at its own positions, so a
-    P-token prompt costs ~P/C steps.  Internally it scans the SAME
-    seq-1 graph, so the K/V bytes it writes are bit-identical to
-    one-token prefill — chunked greedy output stays token-identical to
-    the unchunked oracle.
+    chunked-prefill program: one dispatch fills C prompt tokens per
+    row at its own positions, so a P-token prompt costs ~P/C steps.
+    Which program is ONE choice at build, read from the family's
+    recipe (`prefill_passes` says which: weight passes a dispatch):
+      * the scan (decoding.build_paged_prefill_step; GPT): C passes of
+        the SAME seq-1 graph, so the K/V bytes it writes are
+        bit-identical to one-token prefill — chunked greedy output
+        stays token-identical to the unchunked oracle, which
+        `speculative`, `handoff` and `disaggregated` are built on;
+      * the pass (decoding.build_paged_prefill_pass; a recipe that
+        carries `prefill_pass`: kimi_k2's latent cache): one forward
+        over [b, C], the weights streamed and each layer's view built
+        once a dispatch; equal to seq-1 stepping by tolerance, not by
+        bytes, for a family that carries none of those three.
 
     copy_block(src, dst) is the prefix cache's copy-on-write primitive
     (one physical block cloned across every layer's pool, compiled
@@ -97,6 +107,7 @@ class PagedKVDecodeModel:
                               resolve_spec_decode)
         from ..decoding import (_gpt_dims, build_paged_copy_block,
                                 build_paged_decode_step,
+                                build_paged_prefill_pass,
                                 build_paged_prefill_step,
                                 build_paged_verify_step, cache_entries,
                                 decoder_recipe, make_decoder,
@@ -157,8 +168,14 @@ class PagedKVDecodeModel:
             self.prefill_chunk = 0  # a 1-token chunk IS the decode step
         self.prefix_cache = bool(prefix_cache)
         self._step_fn = build_paged_decode_step(self.ffd)
+        # the chunked-prefill program: one pass over [slots, C] where
+        # the family's recipe says its graph allows it, else the scan of
+        # the seq-1 step; `prefill_passes` = weight passes a dispatch
+        one_pass = "prefill_pass" in decoder_recipe(ff_train).carries
+        self.prefill_passes = 1 if one_pass else self.prefill_chunk
         self._prefill_fn = (
-            build_paged_prefill_step(self.ffd, self.prefill_chunk)
+            (build_paged_prefill_pass if one_pass
+             else build_paged_prefill_step)(self.ffd, self.prefill_chunk)
             if self.prefill_chunk else None)
         self._copy_fn = build_paged_copy_block(self.ffd)
         self._called = set()  # step programs that have run once
@@ -529,6 +546,9 @@ class ContinuousScheduler:
         # model's second compiled program (0 = one-token prefill, the
         # PR 6 path); COW needs the model's device block copy
         self._chunk = int(getattr(model, "prefill_chunk", 0) or 0)
+        # weight passes of one prefill dispatch: C for the scanned
+        # seq-1 step, 1 for a family's one-pass program
+        self._passes = int(getattr(model, "prefill_passes", self._chunk))
         if self._chunk and getattr(model, "prefill_step", None) is None:
             self._chunk = 0
         self._can_cow = getattr(model, "copy_block", None) is not None
@@ -853,6 +873,7 @@ class ContinuousScheduler:
             "steps": self.batches_run,
             "prefill_steps": self.prefill_steps,
             "prefill_chunk": self._chunk,
+            "prefill_passes": self._passes,
             "requests_done": self.requests_done,
             "tokens_generated": self.tokens_generated,
             "step_failures": self.step_failures,
@@ -1310,9 +1331,10 @@ class ContinuousScheduler:
         """The `kv_blocks_read` / `kv_blocks_dense` args of a dispatch
         span: physical KV blocks the dispatch's attention reads
         against what the dense [slots, decode_max_seq] view holds, for
-        a program that runs `counts[i]` seq-1 positions of row i from
-        `seq_lens[i]` in `steps` scanned passes.  The gather
-        formulation reads the whole view whatever is live;
+        a program that reads row i's prefix `counts[i]` times, at
+        positions `seq_lens[i]` on, in `steps` passes that each build
+        the view.  The gather formulation reads the whole view
+        whatever is live;
         `kv_blocks_live` is what an in-place read would touch, under
         either formulation."""
         from ..ops.pallas.paged_attention import scan_blocks_read
@@ -1356,7 +1378,7 @@ class ContinuousScheduler:
         the table padding — the same argument that makes idle-slot
         writes safe.  Returns False after a transient fault (already
         handled); fatal faults propagate."""
-        C = self._chunk
+        C, passes = self._chunk, self._passes
         with span("sched.prefill.prepare"):
             tok = np.zeros((self.model.batch_slots, C), np.int32)
             slen = np.zeros(self.model.batch_slots, np.int32)
@@ -1375,15 +1397,18 @@ class ContinuousScheduler:
                 real += upto - live.pos
         try:
             with span("sched.prefill.dispatch", rows=len(plan),
-                      tokens=real,
+                      tokens=real, passes=passes,
                       capacity=self.model.batch_slots * C) as dispatch:
                 self.model.prefill_step(tok, slen, btab)
-                # (the program is enqueued: this runs beside it) the
-                # prefill program scans the seq-1 read C times a plan
-                # row; riders sit on scratch and read nothing
+                # (the program is enqueued: this runs beside it) a plan
+                # row's prefix is read once a pass: by the scan at each
+                # of its C positions, by the one-pass program once, up
+                # to the chunk's last; riders sit on scratch and read
+                # nothing
                 counts = np.zeros_like(slen)
-                counts[[i for i, _, _ in plan]] = C
-                reads = self._kv_reads(slen, counts, steps=C)
+                counts[[i for i, _, _ in plan]] = passes
+                first_read = slen + (C - passes)
+                reads = self._kv_reads(first_read, counts, steps=passes)
                 dispatch.set(**reads)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):

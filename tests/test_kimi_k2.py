@@ -179,6 +179,41 @@ def test_served_logits_equal_the_reference_full_forward(served):
         close(logits, want[id(req)][pos], LOGIT_TOL)
 
 
+def test_served_scenario_prefilled_in_one_weight_pass_a_dispatch(served):
+    """The family's recipe carries `prefill_pass`, so the scheduler
+    built the one-pass program: the counter says the mechanism ran."""
+    _, _, stats = served
+    assert stats["prefill_chunk"] == 4 and stats["prefill_passes"] == 1
+    assert stats["prefill_steps"] > 0
+
+
+def test_front_reports_one_pass_a_replica_and_the_dispatch_span_carries_it():
+    """Through `build_front` (the supervised model wrapper between the
+    scheduler and the programs): `stats()` a replica and the `passes`
+    arg of `sched.prefill.dispatch`, whose block counts are ONE view's."""
+    from flexflow_tpu.obs.trace import next_span_id, spans
+
+    front = _front(prefill_chunk=4)
+    try:
+        first = next_span_id()
+        front.generate(list(range(1, 14)), 3, 0.0)
+        replicas = front.stats()["replicas"]
+    finally:
+        front.close()
+    assert [r["prefill_passes"] for r in replicas] == [1] * len(replicas)
+    mine = [r for r in spans() if r.name == "sched.prefill.dispatch"
+            and r.span_id > first]
+    dep = CFG["deployment"]
+    view = dep["serving_slots"] * D.p // dep["kv_page_size"]
+    # a decode dispatch feeds the row one prompt token between chunks:
+    # the chunks start at 0, 5 and 10 and read up to their last position
+    assert [r.args["tokens"] for r in mine] == [4, 4, 2]
+    assert [r.args["kv_blocks_live"] for r in mine] == [1, 3, 4]
+    for r in mine:
+        assert r.args["passes"] == 1
+        assert r.args["kv_blocks_dense"] == r.args["kv_blocks_read"] == view
+
+
 def test_scenario_hit_the_prefix_cache_copied_a_block_and_reused_a_slot(
         served):
     _, handles, stats = served
@@ -209,6 +244,167 @@ def test_latent_cache_holds_576_values_a_token_a_layer_at_published_widths():
     assert state["latent_cache"] == (4097, 16, 576)
     assert state["block_table"] == (32, 128) and state["seq_lens"] == (32,)
     assert 2 * 64 * 128 == 16384  # what full keys and values would hold
+
+
+# -- 2b. a prefill chunk in one pass against the scanned seq-1 step ----------------
+CHUNK, PAGE, SLOTS = 4, 4, 4
+#: row -> position its chunk starts at; `rider` is a decode-phase row of
+#: a prefill dispatch: an all-zero table row at position 0
+STARTS = {"fills_a_page": 0, "crosses_a_page": 6, "mid_page": 3, "rider": 0}
+
+
+def _pools(state):
+    return {op: np.asarray(e["latent_cache"], np.float32)
+            for op, e in state.items() if "latent_cache" in e}
+
+
+@pytest.fixture(scope="module")
+def twin():
+    """The paged seq-1 twin with its decode step and both prefill
+    programs (state is donated: every call gets a copy)."""
+    from flexflow_tpu.decoding import (build_paged_decode_step,
+                                       build_paged_prefill_pass,
+                                       build_paged_prefill_step,
+                                       make_decoder)
+
+    ffd = make_decoder(holder(), batch_size=SLOTS, kv_page_size=PAGE,
+                       kv_num_blocks=1 + SLOTS * D.p // PAGE,
+                       devices=jax.devices()[:1])
+    fns = {"step": build_paged_decode_step(ffd),
+           "scan": build_paged_prefill_step(ffd, CHUNK),
+           "pass": build_paged_prefill_pass(ffd, CHUNK)}
+    btab = np.arange(1, 1 + SLOTS * D.p // PAGE,
+                     dtype=np.int32).reshape(SLOTS, -1)
+
+    def run(name, state, tokens, positions, table):
+        """`step`: (logits, state); `scan` / `pass`: state."""
+        return fns[name](ffd._weights, jax.tree.map(jnp.copy, state),
+                         jnp.asarray(tokens, jnp.int32),
+                         jnp.asarray(positions, jnp.int32),
+                         jnp.asarray(table, jnp.int32))
+
+    return ffd, run, btab
+
+
+@pytest.fixture(scope="module")
+def chunk_pair(twin):
+    """Rows at different positions fed one chunk by the scan (seq 1
+    stepped CHUNK times) and by the pass from the SAME pool, then one
+    decode step each: (rows' tokens, tables, state before, {program:
+    (state after the chunk, the decode step's logits)})."""
+    ffd, run, btab = twin
+    starts = np.array(list(STARTS.values()), np.int32)
+    table = btab.copy()
+    table[list(STARTS).index("rider")] = 0
+    tokens = np.random.default_rng(17).integers(
+        1, D.v, (SLOTS, int(starts.max()) + CHUNK + 1)).astype(np.int32)
+    state = ffd._state
+    for t in range(int(starts.max())):  # each row's history, a token a step
+        live = starts > t
+        _, state = run("step", state, np.where(live, tokens[:, t], 0),
+                       np.where(live, t, 0), np.where(live[:, None], table, 0))
+    cols = starts[:, None] + np.arange(CHUNK + 1)
+    feed = np.take_along_axis(tokens, cols, axis=1)
+    after = {}
+    for name in ("scan", "pass"):
+        st = run(name, state, feed[:, :CHUNK], starts, table)
+        logits, _ = run("step", st, feed[:, CHUNK], starts + CHUNK, table)
+        after[name] = (st, np.asarray(logits, np.float32))
+    return tokens, table, state, after
+
+
+@pytest.mark.parametrize("row", [r for r in STARTS if r != "rider"])
+def test_chunk_in_one_pass_equals_seq1_stepped_over_the_chunk(chunk_pair, row):
+    """By tolerance, not bytes: the latent pool's live entries of the
+    row, the next decode step's logits, and those logits against the
+    reference's full forward of the row's tokens."""
+    tokens, table, _, after = chunk_pair
+    i, end = list(STARTS).index(row), STARTS[row] + CHUNK
+    blocks = table[i, :-(-end // PAGE)]
+    scan, one = _pools(after["scan"][0]), _pools(after["pass"][0])
+    assert len(one) == D.L
+    for op in one:
+        close(one[op][blocks], scan[op][blocks], OP_TOL)
+    close(after["pass"][1][i], after["scan"][1][i], LOGIT_TOL)
+    close(after["pass"][1][i], reference_logits(tokens[i, :end + 1])[end],
+          LOGIT_TOL)
+
+
+def test_a_rider_of_the_pass_writes_scratch_only(chunk_pair):
+    """Blocks no fed row wrote stay byte-equal; the fed rows' blocks
+    are covered above, scratch (block 0) may hold anything."""
+    _, table, before, after = chunk_pair
+    wrote = {0} | {int(table[i, c]) for i, row in enumerate(STARTS)
+                   for c in range(-(-(STARTS[row] + CHUNK) // PAGE))}
+    rest = sorted(set(range(before["attn_0"]["latent_cache"].shape[0]))
+                  - wrote)
+    was, now = _pools(before), _pools(after["pass"][0])
+    for op in now:
+        assert np.array_equal(now[op][rest], was[op][rest]), op
+
+
+def test_pass_keeps_the_pad_contract_at_the_end_of_the_position_range(twin):
+    """A row within CHUNK of max_seq: its positions >= max_seq go to
+    scratch, so every other block is byte-unchanged outside the row's
+    own frontier, and the last in-range position holds the token fed
+    THERE, not a later pad clamped onto it."""
+    ffd, run, btab = twin
+    rng = np.random.default_rng(23)
+    state = {op: {k: (jnp.asarray(rng.standard_normal(v.shape), v.dtype)
+                      if k == "latent_cache" else v)
+                  for k, v in e.items()} for op, e in ffd._state.items()}
+    starts = np.array([D.p - 2, D.p - 3, 0, 0], np.int32)
+    table = btab.copy()
+    table[2:] = 0
+    feed = rng.integers(1, D.v, (SLOTS, CHUNK)).astype(np.int32)
+    was = _pools(state)
+    scan = _pools(run("scan", state, feed, starts, table))
+    one = _pools(run("pass", state, feed, starts, table))
+    frontier = np.zeros(was["attn_0"].shape[:2], bool)
+    frontier[0] = True                                   # scratch
+    for i in (0, 1):
+        for pos in range(starts[i], D.p):
+            frontier[table[i, pos // PAGE], pos % PAGE] = True
+    assert frontier.sum() == PAGE + 2 + 3
+    for op in one:
+        assert np.array_equal(one[op][~frontier], was[op][~frontier]), op
+        frontier[0] = False
+        close(one[op][frontier], scan[op][frontier], OP_TOL)
+        assert not np.allclose(one[op][frontier], was[op][frontier])
+        frontier[0] = True
+
+
+def test_gpt_scheduler_keeps_the_scanned_prefill_program():
+    """GPT's recipe does not carry `prefill_pass`: its scheduler counts
+    `prefill_chunk` passes a dispatch and its prefill program is
+    `build_paged_prefill_step`'s, lowered to the same text."""
+    from flexflow_tpu import LossType, SGDOptimizer
+    from flexflow_tpu.decoding import build_paged_prefill_step
+    from flexflow_tpu.models.transformer import build_gpt
+    from flexflow_tpu.serving.scheduler import ContinuousScheduler
+
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    build_gpt(ff, 1, 16, hidden_size=32, num_layers=2, num_heads=2,
+              intermediate_size=64, vocab_size=50)
+    ff.compile(optimizer=SGDOptimizer(lr=0.01),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               devices=jax.devices()[:1])
+    assert "prefill_pass" not in ff.decoder_recipe.carries
+    sched = ContinuousScheduler.from_trained(
+        ff, batch_slots=2, page_size=4, num_blocks=9, prefill_chunk=4,
+        devices=jax.devices()[:1])
+    try:
+        model = sched.model
+        assert sched.stats()["prefill_passes"] == 4 == model.prefill_passes
+        fn = model._prefill_fn
+        assert "build_paged_prefill_step.<locals>" in fn.__wrapped__.__qualname__
+        args = (model.ffd._weights, model._state,
+                jnp.zeros((2, 4), jnp.int32), jnp.zeros(2, jnp.int32),
+                jnp.zeros((2, 4), jnp.int32))
+        assert fn.lower(*args).as_text() == build_paged_prefill_step(
+            model.ffd, 4).lower(*args).as_text()
+    finally:
+        sched.close(10)
 
 
 # -- 3. the share test -----------------------------------------------------------
