@@ -84,11 +84,6 @@ def main():
         # engine (--prefill-chunk / --no-prefix-cache)
         ff.config.prefill_chunk = serving_cfg.prefill_chunk
         ff.config.prefix_cache = serving_cfg.prefix_cache
-        # --paged-kernel {auto,gather,pallas}: which paged-attention
-        # formulation every replica's decode step runs (validated +
-        # logged at engine build, docs/SERVING.md "Fused paged
-        # attention")
-        ff.config.paged_kernel = serving_cfg.paged_kernel
         ff.config.serving_step_timeout = \
             serving_cfg.serving_step_timeout
         ff.config.serving_max_restarts = \

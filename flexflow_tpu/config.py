@@ -45,14 +45,6 @@ NAN_POLICIES = ("raise", "skip_step", "restore", "off")
 # fallback (one program per coalesced batch, dense per-slot caches).
 SERVING_MODES = ("continuous", "static")
 
-# valid FFConfig.paged_kernel values (docs/SERVING.md "Fused paged
-# attention"): "gather" = the dense block-gather formulation, the
-# bit-identity reference oracle; "pallas" = the fused PagedAttention
-# kernel reading KV blocks in place (ops/pallas/paged_attention.py);
-# "auto" (the default) = whichever of the two the backend runs well,
-# decided at engine build (resolve_paged_kernel).
-PAGED_KERNELS = ("auto", "gather", "pallas")
-
 # valid FFConfig.kv_transfer values (serving/kv_transfer.py): the
 # fabric a disaggregated fleet streams KV blocks over — "inproc" =
 # same-host handoff, "blob" = store-tier hop (store/blobstore.py).
@@ -71,39 +63,6 @@ class ConfigError(ValueError):
     """A configuration that can never run in this build/runtime —
     raised at BUILD time with the fix spelled out, so a bad flag never
     surfaces as a deep ImportError mid-compile."""
-
-
-def resolve_paged_kernel(paged_kernel: str) -> str:
-    """The paged-attention formulation an engine built now will run:
-    "gather" or "pallas", never "auto".
-
-    "auto" follows the backend, the one thing that decides which of
-    the two is the fast one: on a TPU the kernel Mosaic compiles reads
-    each row's live pages in place; anywhere else the kernel exists
-    only under the Pallas interpreter (a test vehicle), so the dense
-    gather runs.  An explicit value is validated against this runtime:
-    "pallas" needs jax.experimental.pallas, and when it is missing,
-    selecting the kernel raises ConfigError HERE — at engine build time
-    — instead of an ImportError from inside a trace."""
-    if paged_kernel not in PAGED_KERNELS:
-        raise ConfigError(
-            f"paged_kernel must be one of {PAGED_KERNELS}, "
-            f"got {paged_kernel!r}")
-    from .ops.pallas.paged_attention import have_paged_kernel
-
-    if paged_kernel == "auto":
-        import jax
-
-        on_tpu = jax.default_backend() == "tpu"
-        return "pallas" if on_tpu and have_paged_kernel() else "gather"
-    if paged_kernel == "pallas":
-        if not have_paged_kernel():
-            raise ConfigError(
-                "--paged-kernel pallas needs jax.experimental.pallas, "
-                "which this jax build does not provide — use "
-                "--paged-kernel gather (the reference formulation) or "
-                "install a jax with Pallas support")
-    return paged_kernel
 
 
 def resolve_serving_tp(
@@ -384,9 +343,9 @@ class FFConfig:
     profile_steps: Optional[str] = None
     # per-request serving trace sampling probability
     # (obs/reqtrace.py, docs/OBSERVABILITY.md "Request tracing"):
-    # 1.0 traces every admitted request (tests/smoke), loadgen/prod
-    # runs rate-limit by sampling down; 0.0 disables request tracing
-    # even with telemetry on
+    # 1.0 traces every admitted request (tests/smoke), load tests and
+    # production runs rate-limit by sampling down; 0.0 disables request
+    # tracing even with telemetry on
     trace_sample: float = 1.0
 
     # -- serving (serving/, docs/SERVING.md): generation tier mode and
@@ -404,16 +363,6 @@ class FFConfig:
     # prefill, the PR 6 path).  Both preserve greedy token-identity.
     prefill_chunk: int = 8
     prefix_cache: bool = True
-    # paged-attention read formulation (docs/SERVING.md "Fused paged
-    # attention"): "gather" keeps the dense block-gather view — the
-    # bit-identity reference oracle; "pallas" runs the fused
-    # PagedAttention kernel that reads each row's live KV pages in
-    # place through the block table, so a step's cost follows live
-    # tokens instead of slots x decode_max_seq.  "auto" resolves by
-    # backend at engine build time (resolve_paged_kernel): the kernel
-    # on a TPU, the gather everywhere else; the explicit values are
-    # for the tests and the oracle.
-    paged_kernel: str = "auto"
     # replicated front (serving/front.py, docs/SERVING.md "Replicated
     # front"): N supervised ContinuousScheduler replicas behind one
     # admission queue.  1 = single supervised replica (still gains the
@@ -517,11 +466,6 @@ class FFConfig:
             raise ValueError(
                 f"prefill_chunk must be >= 0 (0 = one-token prefill), "
                 f"got {self.prefill_chunk}"
-            )
-        if self.paged_kernel not in PAGED_KERNELS:
-            raise ValueError(
-                f"paged_kernel must be one of {PAGED_KERNELS}, "
-                f"got {self.paged_kernel!r}"
             )
         if self.serving_replicas < 1:
             raise ValueError(
@@ -865,8 +809,6 @@ class FFConfig:
                        type=int, default=8)
         p.add_argument("--no-prefix-cache", dest="prefix_cache",
                        action="store_false")
-        p.add_argument("--paged-kernel", dest="paged_kernel", type=str,
-                       default="auto", choices=PAGED_KERNELS)
         p.add_argument("--serving-replicas", dest="serving_replicas",
                        type=int, default=1)
         p.add_argument("--serving-step-timeout",
@@ -988,7 +930,6 @@ class FFConfig:
             serving_slots=args.serving_slots,
             prefill_chunk=args.prefill_chunk,
             prefix_cache=args.prefix_cache,
-            paged_kernel=args.paged_kernel,
             serving_replicas=args.serving_replicas,
             serving_step_timeout=args.serving_step_timeout,
             serving_max_restarts=args.serving_max_restarts,

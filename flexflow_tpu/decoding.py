@@ -150,11 +150,11 @@ def make_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     same twin once over [b, C] (build_paged_prefill_pass), so its
     weights stay resident once and are streamed once a dispatch.
 
-    kv_kernel selects the paged READ formulation (docs/SERVING.md
-    "Fused paged attention"): "gather" (default) is the dense
-    block-gather oracle; "pallas" streams blocks in place through the
-    fused kernel.  Validated against the runtime HERE — a pallas-less
-    jax fails with ConfigError before any graph is built.
+    kv_kernel is the paged READ formulation (docs/SERVING.md "Fused
+    paged attention"), already decided, as the ops take it: "gather"
+    (default) is the dense block-gather oracle; "pallas" streams blocks
+    in place through the fused kernel.  The engine decides it
+    (serving/scheduler.py pick_paged_read).
 
     tp > 1 compiles the twin over a tp-chip {"data": 1, "model": tp}
     replica mesh under GSPMD (docs/SERVING.md "Tensor-parallel
@@ -166,7 +166,7 @@ def make_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
     publish path training compiles use at spin-up.  Validated against
     the head count and visible devices HERE (resolve_serving_tp) —
     never a mid-compile shape error."""
-    from .config import FFConfig, resolve_paged_kernel, resolve_serving_tp
+    from .config import FFConfig, resolve_serving_tp
 
     recipe = decoder_recipe(ff_train)
     if step_tokens < 1:
@@ -176,9 +176,9 @@ def make_decoder(ff_train: FFModel, batch_size: Optional[int] = None,
             "step_tokens > 1 needs the paged twin (kv_page_size > 0): "
             "the dense cache's scalar position counter cannot express "
             "per-row chunk positions")
-    # validate the NAME first so a typo gets the "must be one of"
-    # diagnostic, not advice to turn on paging
-    kv_kernel = resolve_paged_kernel(kv_kernel)
+    if kv_kernel not in ("gather", "pallas"):
+        raise ValueError(
+            f"kv_kernel must be 'gather' or 'pallas', got {kv_kernel!r}")
     if kv_kernel != "gather" and not kv_page_size:
         raise ValueError(
             f"kv_kernel={kv_kernel!r} needs the paged twin "

@@ -73,9 +73,9 @@ class MultiHeadAttention(Op):
         # paged READ formulation: "gather" materializes the dense
         # [b, N, h, d] view (the bit-identity oracle); "pallas" streams
         # blocks in place through the fused kernel
-        # (ops/pallas/paged_attention.py).  Callers validate the value
-        # and Pallas availability BEFORE building the graph
-        # (config.resolve_paged_kernel).
+        # (ops/pallas/paged_attention.py).  The engine decides it and
+        # checks Pallas availability BEFORE building the graph
+        # (serving/scheduler.py pick_paged_read).
         self._kv_kernel = str(kv_kernel or "gather")
         super().__init__(params, inputs, name=name,
                          shard=shard or ShardConfig())

@@ -94,9 +94,9 @@ except Exception:  # pragma: no cover
 
 def have_paged_kernel() -> bool:
     """Whether the fused kernel can be built at all in this runtime
-    (config-time guard: selecting --paged-kernel pallas without this
-    must raise ConfigError at BUILD time, never a deep ImportError
-    mid-compile)."""
+    (asking for the kernel by name without this is a ConfigError at
+    engine build, serving/scheduler.py pick_paged_read, never a deep
+    ImportError mid-compile)."""
     return _HAVE_PALLAS
 
 

@@ -511,6 +511,10 @@ class CheckpointOffloader:
             self._writer.depth_cb = gauge.set
         self._submitted = 0  # verified local publishes seen (cadence clock)
         self._last_queued: Optional[int] = None
+        # newest step a saturated uploader skipped with nothing queued
+        # since: the run's end mirrors it (supervisor._drain_offloader),
+        # there being no later cadence point to catch up at
+        self.skipped_step: Optional[int] = None
         # last step that completed upload + remote verification (written
         # on the uploader thread; int read is atomic enough for dedupe)
         self._mirrored: Optional[int] = None
@@ -561,9 +565,11 @@ class CheckpointOffloader:
                 "(local tier still holds it)",
                 self._writer.queue_depth, step,
             )
+            self.skipped_step = step
             return False
         self._writer.submit(step, lambda: self._upload_job(step, files))
         self._last_queued = step
+        self.skipped_step = None
         return True
 
     @property

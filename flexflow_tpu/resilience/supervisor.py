@@ -272,6 +272,12 @@ class TrainingSupervisor:
         logic; anything returned here is an uploader-thread crash."""
         if self.offloader is None:
             return
+        skipped = self.offloader.skipped_step
+        if skipped is not None and hasattr(self.manager, "offload_step"):
+            # the uploader was saturated at the newest cadence point and
+            # the run has no later one: without this its last checkpoint
+            # would exist on this host only
+            self.manager.offload_step(skipped)
         for failed_step, err in self.offloader.drain():
             self.counters["checkpoint_failures"] += 1
             self.log.info(

@@ -123,7 +123,7 @@ class ServingAutoscaler:
         # predictive scaling (--autoscale-predictive): project the
         # admission queue forward from the measured admission-rate
         # slope and scale BEFORE the reactive thresholds breach — a
-        # loadgen ramp is visible in the slope several intervals
+        # ramp of arrivals is visible in the slope several intervals
         # before it is visible in the queue
         self.predictive = bool(predictive)
         self.predict_horizon_s = float(predict_horizon_s)
@@ -300,7 +300,7 @@ class ServingAutoscaler:
                 f"p99 TTFT {s['p99_ttft_s'] * 1e3:.0f}ms > SLO "
                 f"{self.slo_ttft_s * 1e3:.0f}ms")
         if (self.predictive and s.get("admit_rate_rps") is not None):
-            # loadgen ramp: the admission-rate slope projects a queue
+            # a ramp of arrivals: the admission-rate slope projects a queue
             # breach before the reactive threshold sees it
             drain = s.get("drain_rate_rps") or 0.0
             growth = s["admit_rate_rps"] - drain

@@ -26,7 +26,7 @@ import numpy as np
 def percentile_summary(values, ps=(0.50, 0.95, 0.99)) -> Dict[str, float]:
     """n / p*_ms / mean_ms summary of latencies in SECONDS — the one
     percentile implementation (batchers' ring windows, the continuous
-    scheduler's TTFT stats, and the loadgen report all use it)."""
+    scheduler's TTFT stats and the fronts' all use it)."""
     lats = sorted(values)
     if not lats:
         return {"n": 0}

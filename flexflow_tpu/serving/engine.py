@@ -9,29 +9,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..fftype import CompMode
-from ..logger import serving_logger
 from ..model import FFModel
-
-
-def resolve_paged_formulation(paged_kernel: str, *,
-                              logger=serving_logger) -> str:
-    """Engine-build gate for the paged-attention read formulation
-    (docs/SERVING.md "Fused paged attention"): resolves "auto" by
-    backend, validates an explicit value against the runtime
-    (selecting the Pallas kernel on a pallas-less jax raises
-    config.ConfigError HERE, at build time, never a deep ImportError
-    mid-compile) and logs which formulation the engine will run — the
-    operator-visible record of what the hot path is."""
-    from ..config import resolve_paged_kernel
-
-    kernel = resolve_paged_kernel(paged_kernel)
-    logger.info(
-        "paged attention formulation: %s%s (%s)", kernel,
-        "" if kernel == paged_kernel else f" (from {paged_kernel!r})",
-        "fused Pallas kernel, block reads in place"
-        if kernel == "pallas"
-        else "dense block-gather, the bit-identity oracle")
-    return kernel
 
 
 def _value_info_shape(vi):

@@ -65,7 +65,7 @@ class ServiceUnavailable(RuntimeError):
 
 class FrontRequest:
     """Front-level future for one admitted request.  Mirrors the
-    scheduler handle surface the loadgen and server consume (wait /
+    scheduler handle surface load generators and the server consume (wait /
     t_submit / t_first_token / t_done / n_generated), independent of
     which replica — or how many, after requeues — ran it."""
 
@@ -365,8 +365,6 @@ class ServingFront:
                         batch_slots=cfg.serving_slots,
                         page_size=cfg.kv_page_size,
                         devices=devs,
-                        paged_kernel=getattr(cfg, "paged_kernel",
-                                             "auto"),
                     )
                 model = PagedKVDecodeModel(
                     ff_train,
@@ -376,7 +374,6 @@ class ServingFront:
                     devices=devs,
                     prefill_chunk=getattr(cfg, "prefill_chunk", 0),
                     prefix_cache=getattr(cfg, "prefix_cache", True),
-                    paged_kernel=getattr(cfg, "paged_kernel", "auto"),
                     tp=getattr(cfg, "serving_tp", 1),
                     spec_decode=spec_decode,
                     spec_k=spec_k,

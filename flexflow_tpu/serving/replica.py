@@ -29,7 +29,7 @@ permanently ``dead`` and `/v2/health` says so.
 
 Fault injection is the training side's seeded `FaultPlan`: the plan's
 step index counts DECODE steps (cumulative across restarts), so a
-replica-kill benchmark replays exactly (bench.py serving_resilience).
+seeded replica kill replays exactly (tests/test_serving_front.py).
 """
 from __future__ import annotations
 

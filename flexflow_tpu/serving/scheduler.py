@@ -53,9 +53,55 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..logger import serving_logger
 from ..obs.trace import span
 from ..ops.routed_experts import MOE_STATS
 from .kv_pool import KVPool
+
+
+def pick_paged_read(asked: str = "auto", *, backend: str,
+                    have_kernel: bool, carries, family: str) -> str:
+    """THE choice of paged-attention READ formulation (docs/SERVING.md
+    "Fused paged attention"), made once, at engine build: "gather"
+    (the dense [slots, decode_max_seq] view: the CPU path and the
+    tests' oracle) or "pallas" (each row's live blocks read in place,
+    ops/pallas/paged_attention.py).
+
+    "auto" follows what decides which of the two is the fast one: on a
+    TPU, for a family whose attention op has the kernel (`pallas_read`
+    in its recipe's `carries`), Mosaic compiles the in-place read;
+    anywhere else the kernel exists only under the Pallas interpreter,
+    so the gather runs.  A formulation asked for by name is honoured
+    or refused, never replaced: ConfigError for an unknown name, for
+    "pallas" on a jax without Pallas and for "pallas" on a family that
+    does not carry it, here and not from inside a trace.  Logs which
+    formulation the engine runs."""
+    from ..config import ConfigError
+
+    if asked not in ("auto", "gather", "pallas"):
+        raise ConfigError(
+            "paged_kernel must be one of ('auto', 'gather', 'pallas'), "
+            f"got {asked!r}")
+    if asked == "pallas" and not have_kernel:
+        raise ConfigError(
+            "paged_kernel='pallas' needs jax.experimental.pallas, which "
+            "this jax build does not provide: use 'gather' (the "
+            "reference formulation) or a jax with Pallas support")
+    if asked == "pallas" and "pallas_read" not in carries:
+        raise ConfigError(
+            f"{family} does not carry pallas_read (paged_kernel="
+            f"'pallas'); it carries {sorted(carries)}")
+    kernel = asked
+    if asked == "auto":
+        kernel = ("pallas" if backend == "tpu" and have_kernel
+                  and "pallas_read" in carries else "gather")
+    serving_logger.info(
+        "paged attention formulation: %s%s (%s)", kernel,
+        "" if kernel == asked else f" (from {asked!r})",
+        "fused Pallas kernel, block reads in place"
+        if kernel == "pallas"
+        else "dense block-gather, the bit-identity oracle")
+    return kernel
 
 
 class PagedKVDecodeModel:
@@ -88,13 +134,11 @@ class PagedKVDecodeModel:
     once); prefix_cache=False lets the scheduler skip sharing without
     rebuilding the twin.
 
-    paged_kernel picks the attention READ formulation (docs/SERVING.md
-    "Fused paged attention"): "gather" (default) materializes the
-    dense [slots, decode_max_seq] K/V view — the bit-identity oracle;
-    "pallas" streams each row's blocks in place through the fused
-    kernel (ops/pallas/paged_attention.py), so per-step HBM reads
-    scale with live tokens.  Validated + logged at build time
-    (engine.resolve_paged_formulation)."""
+    paged_kernel is what the caller asks of the attention READ
+    formulation: "auto" (the default: by backend and family), or
+    "gather" / "pallas" by name — how the tests run the kernel under
+    the interpreter against the gather.  Decided by pick_paged_read;
+    `self.paged_kernel` is what it decided."""
 
     def __init__(self, ff_train, batch_slots: int = 8,
                  page_size: int = 16, num_blocks: Optional[int] = None,
@@ -103,6 +147,8 @@ class PagedKVDecodeModel:
                  paged_kernel: str = "auto", tp: int = 1,
                  spec_decode: str = "off", spec_k: int = 4,
                  draft_model=None):
+        import jax
+
         from ..config import (ConfigError, resolve_serving_tp,
                               resolve_spec_decode)
         from ..decoding import (_gpt_dims, build_paged_copy_block,
@@ -112,17 +158,13 @@ class PagedKVDecodeModel:
                                 build_paged_verify_step, cache_entries,
                                 decoder_recipe, make_decoder,
                                 require_carried)
-        from .engine import resolve_paged_formulation
+        from ..ops.pallas.paged_attention import have_paged_kernel
 
-        self.paged_kernel = resolve_paged_formulation(paged_kernel)
-        if (self.paged_kernel == "pallas" and "pallas_read"
-                not in decoder_recipe(ff_train).carries):
-            # `auto` resolves to a formulation the family's attention
-            # op HAS; asking for the kernel by name is refused
-            if paged_kernel != "auto":
-                require_carried(ff_train, "pallas_read",
-                                f"--paged-kernel {paged_kernel}")
-            self.paged_kernel = "gather"
+        recipe = decoder_recipe(ff_train)
+        self.paged_kernel = pick_paged_read(
+            paged_kernel, backend=jax.default_backend(),
+            have_kernel=have_paged_kernel(), carries=recipe.carries,
+            family=recipe.family)
         self.spec_decode = resolve_spec_decode(spec_decode, spec_k)
         self.spec_k = int(spec_k)
         dims = _gpt_dims(ff_train)
@@ -171,7 +213,7 @@ class PagedKVDecodeModel:
         # the chunked-prefill program: one pass over [slots, C] where
         # the family's recipe says its graph allows it, else the scan of
         # the seq-1 step; `prefill_passes` = weight passes a dispatch
-        one_pass = "prefill_pass" in decoder_recipe(ff_train).carries
+        one_pass = "prefill_pass" in recipe.carries
         self.prefill_passes = 1 if one_pass else self.prefill_chunk
         self._prefill_fn = (
             (build_paged_prefill_pass if one_pass
@@ -362,8 +404,8 @@ class PagedKVDecodeModel:
 
 class _PendingSeq:
     """Future-style handle for one continuous-mode request.  Besides
-    the final token list it records the SLO timestamps the loadgen and
-    telemetry consume: submit, first generated token (TTFT), done.
+    the final token list it records the SLO timestamps load generators
+    and telemetry consume: submit, first generated token (TTFT), done.
 
     `on_done` (set at submission, never after) fires exactly once when
     the request settles — success, fault, or drain — on whichever
@@ -520,7 +562,7 @@ class ContinuousScheduler:
 
     API-compatible with GenerationBatcher (generate / generate_async /
     latency_stats / close / batches_run / requests_done), so serve_http
-    and the loadgen drive either engine unchanged.  `batches_run`
+    and a load generator drive either engine unchanged.  `batches_run`
     counts decode steps here — the unit of batching is the step."""
 
     def __init__(self, model, pool: Optional[KVPool] = None,

@@ -63,10 +63,9 @@ def fit_cost_scales(
 
 def measure_step_time(ff, inputs, labels, iters: int = 12,
                       windows: int = 3) -> float:
-    """Best-of-N windows of serial steps with ONE hard sync each (the
-    bench.py `_steady_state` discipline: the steps chain on-device and
-    the host waits once, so launch latency stays out of the per-step
-    number)."""
+    """Best-of-N windows of serial steps with ONE hard sync each: the
+    steps chain on-device and the host waits once, so launch latency
+    stays out of the per-step number."""
     for _ in range(2):
         m = ff.train_step(inputs, labels)
     _ = float(m["loss"])

@@ -1,65 +1,39 @@
 #!/usr/bin/env bash
-# Tier-1 test suite, split into three deterministic tranches.
+# Tier-1 test suite, run the way the driver runs it: ONE pytest run of
+# tests/ on six xdist workers, a test file a worker (--dist loadfile),
+# under one 1,470 s limit (the driver's run takes about 340 s).
 #
-# The single-shot tier-1 run outgrew its 870 s wall-clock budget, so
-# this script sorts tests/test_*.py lexically and deals the list
-# round-robin into three tranches, running each under its own 870 s
-# timeout with the exact flags from ROADMAP.md.  Round-robin (not a
-# contiguous split) matters: the expensive serving tests cluster
-# alphabetically, and a contiguous split piles them all into one
-# tranche that then blows the budget on its own.  The deal is purely
-# lexical — no timing data, no randomness — so any test lands in the
-# same tranche on every machine.
+# Output contract:
+#   the 20 slowest tests (--durations=20)
+#   T1_DIR=<dir>      this run's own directory, a fresh
+#                     ${TMPDIR:-/tmp}/t1.XXXXXX, holding t1.log and
+#                     t1.xml (the junit report); two checkouts on one
+#                     machine never share it
+#   DOTS_PASSED=<n>   passed tests, from the junit report; a run that
+#                     wrote no report prints DOTS_PASSED=0 and exits
+#                     non-zero
+#   exit code = pytest's (124: the limit cut the run)
 #
-# Output contract (matches the old one-shot verify line):
-#   DOTS_PASSED=<total>   merged passed-dot count across tranches
-#   exit 0 iff ALL tranches exit 0.
+# The driver also exports ALLOW_MULTIPLE_LIBTPU_LOAD=1.  This script
+# does not: tests/test_tpu_bringup.py is the one file that loads the
+# TPU's library, inside a fixture, so one worker loads it.
 #
 # Usage: scripts/tier1.sh [extra pytest args...]
 set -u -o pipefail
 
 cd "$(dirname "$0")/.."
 
-mapfile -t FILES < <(ls tests/test_*.py | LC_ALL=C sort)
-n=${#FILES[@]}
-if [ "$n" -eq 0 ]; then
-    echo "tier1.sh: no test files found" >&2
-    exit 2
-fi
-
-T1=() T2=() T3=()
-for i in "${!FILES[@]}"; do
-    case $(( i % 3 )) in
-        0) T1+=("${FILES[$i]}") ;;
-        1) T2+=("${FILES[$i]}") ;;
-        2) T3+=("${FILES[$i]}") ;;
-    esac
-done
-
-run_tranche() {
-    local idx="$1"; shift
-    local log="/tmp/_t1_tranche${idx}.log"
-    rm -f "$log"
-    echo "== tier-1 tranche ${idx}: $# file(s) =="
-    timeout -k 10 870 env JAX_PLATFORMS=cpu \
-        python -m pytest "$@" -q -m 'not slow' \
-        --continue-on-collection-errors -p no:cacheprovider \
-        -p no:xdist -p no:randomly 2>&1 | tee "$log"
-    local rc=${PIPESTATUS[0]}
-    local dots
-    dots=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$log" | tr -cd . | wc -c)
-    echo "TRANCHE${idx}_RC=${rc} TRANCHE${idx}_DOTS=${dots}"
-    TOTAL_DOTS=$(( TOTAL_DOTS + dots ))
-    return "$rc"
-}
-
-TOTAL_DOTS=0
-FINAL_RC=0
-run_tranche 1 "${T1[@]}" || FINAL_RC=$?
-run_tranche 2 "${T2[@]}" || rc2=$?
-run_tranche 3 "${T3[@]}" || rc3=$?
-[ "${rc2:-0}" -ne 0 ] && [ "$FINAL_RC" -eq 0 ] && FINAL_RC=$rc2
-[ "${rc3:-0}" -ne 0 ] && [ "$FINAL_RC" -eq 0 ] && FINAL_RC=$rc3
-
-echo "DOTS_PASSED=${TOTAL_DOTS}"
-exit "$FINAL_RC"
+d=$(mktemp -d "${TMPDIR:-/tmp}/t1.XXXXXX") || exit 1
+timeout -k 10 1470 env JAX_PLATFORMS=cpu \
+    python -m pytest tests/ -q -m 'not slow' \
+    --continue-on-collection-errors -p no:cacheprovider \
+    -p xdist -n 6 --dist loadfile --junitxml="$d/t1.xml" \
+    -p no:randomly --durations=20 "$@" 2>&1 | tee "$d/t1.log"
+rc=${PIPESTATUS[0]}
+said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' \
+           "$d/t1.xml" 2>/dev/null | head -n 1 \
+       | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}')
+echo "T1_DIR=$d"
+echo "DOTS_PASSED=${said:-0}"
+[ -n "$said" ] || { echo "tier1.sh: no junit report in $d" >&2; [ "$rc" -ne 0 ] || rc=1; }
+exit "$rc"

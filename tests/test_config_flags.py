@@ -486,7 +486,7 @@ def test_serving_tp_config_validated():
 def test_resolve_serving_tp_rejects_bad_degrees():
     """--serving-tp misconfigurations must fail at BUILD time with a
     ConfigError naming the flag, never surface as a mid-compile shape
-    error (the resolve_paged_kernel discipline)."""
+    error."""
     from flexflow_tpu.config import ConfigError, resolve_serving_tp
 
     assert resolve_serving_tp(1) == 1
@@ -520,7 +520,7 @@ def test_spec_decode_config_validated():
 
 def test_resolve_spec_decode_rejects_bad_combos():
     """--spec-decode misconfigurations must fail at BUILD time with a
-    ConfigError naming the flag (the resolve_paged_kernel discipline):
+    ConfigError naming the flag:
     unknown modes, a draft budget under 1, and — because verification
     accepts the longest GREEDY-matching prefix, meaningless across
     beam hypotheses — any combination with beam search."""

@@ -14,7 +14,6 @@ import pytest
 
 from flexflow_tpu.obs.metrics import MetricsRegistry
 from flexflow_tpu.serving import ContinuousScheduler, KVPool
-from flexflow_tpu.serving.loadgen import run_loadgen, sample_workload
 from flexflow_tpu.serving.server import serve_http
 
 V = 16
@@ -230,19 +229,26 @@ def test_slo_metrics_drain_to_registry(tmp_path):
     assert "Serving" in text and "ttft_ms" in text
 
 
-def test_loadgen_against_fake_scheduler():
+def test_mixed_length_burst_completes_with_slo_stamps():
+    """Eight requests of mixed prompt and reply lengths, a quarter of
+    them long, all submitted before any finishes: each completes at its
+    own length and its handle carries the stamps TTFT and per-token
+    latency are computed from."""
     sched = ContinuousScheduler(FakeStepModel(batch_slots=2))
     try:
         rng = np.random.RandomState(0)
-        wl = sample_workload(rng, 8, V, prompt_len_range=(1, 4),
-                             max_new_range=(2, 6), long_frac=0.25,
-                             long_max_new_range=(10, 14))
-        report = run_loadgen(sched, wl, rate_rps=200.0, seed=1,
-                             timeout_s=30.0)
-        assert report["completed"] == 8 and report["failures"] == 0
-        assert report["tokens_generated"] == sum(m for _, m in wl)
-        assert report["tokens_per_s"] > 0
-        assert report["ttft"]["n"] == 8 and report["per_token"]["n"] > 0
+        wl = [(rng.randint(0, V, int(rng.randint(1, 5))).tolist(),
+               int(rng.randint(10, 15) if i % 4 == 0
+                   else rng.randint(2, 7))) for i in range(8)]
+        handles = [sched.generate_async(p, m) for p, m in wl]
+        for h, (p, m) in zip(handles, wl):
+            assert h.wait(30.0) == expected(p, m)
+            assert h.n_generated == m
+            assert h.t_submit <= h.t_first_token <= h.t_done
+        assert sched.requests_done == 8
+        st = sched.stats()
+        assert st["ttft"]["n"] == 8
+        assert st["tokens_generated"] == sum(m for _, m in wl)
     finally:
         sched.close()
 
@@ -292,6 +298,29 @@ def test_prefix_hit_skips_prefill_and_stamps_handle():
         st = sched.stats()["prefix_cache"]
         assert st["hits"] >= 1 and st["hit_tokens"] >= 12
         sched.pool.check_invariants()
+    finally:
+        sched.close()
+
+
+def test_shared_prefix_burst_stamps_hit_tokens_on_every_handle():
+    """Ten requests over two shared 8-token heads (two full pages of 4)
+    with unique tails, submitted together: every handle carries
+    `prefix_hit_tokens`, whole pages only, and the heads are re-hit."""
+    sched = ContinuousScheduler(FakeStepModel(batch_slots=2, max_seq=64),
+                                check_invariants=True)
+    try:
+        rng = np.random.RandomState(3)
+        heads = [rng.randint(0, V, 8).tolist() for _ in range(2)]
+        reqs = [(heads[i % 2]
+                 + rng.randint(0, V, int(rng.randint(1, 4))).tolist(),
+                 int(rng.randint(2, 5))) for i in range(10)]
+        handles = [sched.generate_async(p, m) for p, m in reqs]
+        for h, (p, m) in zip(handles, reqs):
+            assert h.wait(30.0) == expected(p, m)
+        hits = [h.prefix_hit_tokens for h in handles]
+        assert all(hit in (0, 4, 8) for hit in hits), hits
+        assert sum(hits) > 0
+        assert sched.stats()["prefix_cache"]["hit_tokens"] == sum(hits)
     finally:
         sched.close()
 

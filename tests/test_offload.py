@@ -9,6 +9,8 @@ import io
 import json
 import os
 import shutil
+import threading
+import zipfile
 import zlib
 
 import numpy as np
@@ -422,10 +424,16 @@ def test_restore_prefers_local_falls_back_per_checkpoint(devices8, tmp_path):
     xs, ys = _data(128)
     sup.run(xs, ys, num_steps=6)
     w6 = ff.get_weights()
-    # corrupt the newest LOCAL step's bytes
+    # corrupt the newest LOCAL step: the last byte of a named leaf's
+    # data, where the leaf's crc (and the archive's own) sees it
     state = os.path.join(ckpt, "step_00000006", "state.npz")
+    with zipfile.ZipFile(state) as zf:
+        leaf = next(i for i in zf.infolist() if "weights" in i.filename)
+        with zf.open(leaf) as member:
+            member.read()
+            end_of_data = zf.fp.tell()
     blob_bytes = bytearray(open(state, "rb").read())
-    blob_bytes[len(blob_bytes) // 2] ^= 0xFF
+    blob_bytes[end_of_data - 1] ^= 0xFF
     with open(state, "wb") as f:
         f.write(bytes(blob_bytes))
     mgr = LocalCheckpointManager(
@@ -436,6 +444,44 @@ def test_restore_prefers_local_falls_back_per_checkpoint(devices8, tmp_path):
     _weights_equal(ff.get_weights(), w6)
     # and the mirror's verified bytes were re-materialized locally
     assert LocalCheckpointManager(ckpt).restore(ff) == 6
+
+
+def test_run_end_mirrors_the_checkpoint_a_saturated_uploader_skipped(
+        devices8, tmp_path):
+    """A cadence point that finds the uploader saturated is skipped, so
+    that the step loop never waits for the mirror; the run's end is the
+    last cadence point, and what was skipped newest is mirrored there.
+    (The flicker of the case above until PR 31: under load the upload of
+    step 6 was skipped, and the corrupt local step fell back to step 4.)
+    The store is held shut until step 6 has been offered."""
+    offered = threading.Event()
+
+    class GatedBlob(LocalBlobStore):
+        def put(self, *a, **kw):
+            assert offered.wait(60.0)
+            return super().put(*a, **kw)
+
+    blob = GatedBlob(str(tmp_path / "remote"))
+    off = _offloader(blob)
+    submit = off.maybe_submit
+
+    def watched(step, files, force=False):
+        queued = submit(step, files, force=force)
+        if step == 6:
+            offered.set()
+        return queued
+
+    off.maybe_submit = watched
+    ff = _model(devices8)
+    sup = TrainingSupervisor(ff, str(tmp_path / "ckpt"), checkpoint_every=2,
+                             offloader=off, sleep=NO_SLEEP)
+    xs, ys = _data(128)
+    rep = sup.run(xs, ys, num_steps=6)
+    # steps 0 and 2 filled the queue; 4 and 6 found it full
+    assert rep.counters["offload_skipped"] == 2
+    remote = RemoteCheckpointStore(blob)
+    assert remote.list_steps() == [0, 2, 6]
+    assert remote.latest_verified_step() == 6
 
 
 def test_fresh_host_restores_from_remote_only(devices8, tmp_path):

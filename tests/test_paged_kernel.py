@@ -5,7 +5,7 @@ blocks, scratch rows, CoW-shared prefix blocks and the seq-C chunk
 twin; the build-time ConfigError gate for pallas-less runtimes; the
 jaxpr assertion that the kernel-path decode step materializes NO dense
 [slots, decode_max_seq] K/V view; and scheduler-level greedy
-token-identity between `--paged-kernel gather` and `pallas` on the
+token-identity between the gather and the kernel asked for by name on the
 shared-prefix smoke workload (docs/SERVING.md "Fused paged
 attention"); and, since PR 28, the kernel as the TPU's default: parity
 and pool bytes at the benchmark cell's own shapes, the default resolved
@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.config import ConfigError, FFConfig, resolve_paged_kernel
+from flexflow_tpu.config import ConfigError, FFConfig
 from flexflow_tpu.ops.pallas import paged_attention as pk
 
 V, S, B = 32, 16, 4
@@ -253,69 +253,86 @@ def test_blocks_read_scales_with_live_tokens():
     assert pk.scan_blocks_read(seq_lens, np.zeros(4, int), page, tw) == 0
 
 
-# -- config gate ------------------------------------------------------
+# -- the one place that picks the read ---------------------------------
 
-def test_selecting_kernel_without_pallas_is_config_error(monkeypatch):
-    """The clean-fallback satellite: a pallas-less jax fails the flag
-    at BUILD time with a ConfigError naming the fix — never a deep
-    ImportError mid-compile."""
-    assert resolve_paged_kernel("gather") == "gather"
-    assert resolve_paged_kernel("pallas") == "pallas"  # this runtime has it
-    monkeypatch.setattr(pk, "_HAVE_PALLAS", False)
-    with pytest.raises(ConfigError, match="pallas"):
-        resolve_paged_kernel("pallas")
-    # the gather oracle never needs pallas
-    assert resolve_paged_kernel("gather") == "gather"
+GPT_CARRIES = frozenset({"paged", "pallas_read"})
 
 
-def test_paged_kernel_flag_validated_and_parsed():
-    with pytest.raises(ValueError, match="paged_kernel"):
-        FFConfig(paged_kernel="fused")
-    with pytest.raises(ConfigError, match="paged_kernel"):
-        resolve_paged_kernel("fused")
-    assert FFConfig().paged_kernel == "auto"
-    assert FFConfig.from_args([]).paged_kernel == "auto"
-    for explicit in ("gather", "pallas"):
-        assert FFConfig.from_args(
-            ["--paged-kernel", explicit]).paged_kernel == explicit
+def pick(asked="auto", *, backend="cpu", have_kernel=True,
+         carries=GPT_CARRIES):
+    from flexflow_tpu.serving.scheduler import pick_paged_read
+
+    return pick_paged_read(asked, backend=backend, have_kernel=have_kernel,
+                           carries=carries, family="fam")
+
+
+def test_selecting_kernel_without_pallas_is_config_error():
+    """A pallas-less jax refuses the kernel asked for by name at engine
+    BUILD time with a ConfigError naming the fix, never a deep
+    ImportError mid-compile; nothing it was not asked for needs it."""
+    assert pick("gather") == "gather"
+    assert pick("pallas") == "pallas"  # asked by name: interpreted here
+    with pytest.raises(ConfigError, match="needs jax.experimental.pallas"):
+        pick("pallas", have_kernel=False)
+    assert pick("gather", have_kernel=False) == "gather"
+    assert pick("auto", backend="tpu", have_kernel=False) == "gather"
+
+
+def test_unknown_formulation_given_to_the_engine_is_config_error():
+    """No flag and no config field carry the choice any more: the one
+    value a caller can give is the engine's `paged_kernel=`, and an
+    unknown one is refused before any graph is built."""
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    assert not hasattr(FFConfig.from_args([]), "paged_kernel")
+    with pytest.raises(ConfigError, match="paged_kernel must be one of"):
+        pick("fused")
+    ff = _untrained_gpt()
+    with pytest.raises(ConfigError, match="paged_kernel must be one of"):
+        PagedKVDecodeModel(ff, batch_slots=2, page_size=4,
+                           paged_kernel="fused")
 
 
 @pytest.mark.parametrize("backend,want", [("cpu", "gather"),
                                           ("gpu", "gather"),
                                           ("tpu", "pallas")])
-def test_default_formulation_follows_the_backend(monkeypatch, backend,
-                                                 want):
-    """`auto` is resolved from what the code can observe, the backend:
-    the in-place read where Mosaic compiles it, the gather where the
-    kernel would only be interpreted.  Explicit values are honoured on
-    every backend."""
-    from flexflow_tpu.serving.engine import resolve_paged_formulation
-
-    assert jax.default_backend() == "cpu"
-    assert resolve_paged_kernel("auto") == "gather"
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert resolve_paged_kernel("auto") == want
-    assert resolve_paged_formulation("auto") == want
+def test_default_formulation_follows_the_backend(backend, want):
+    """`auto` is resolved from what the code can observe, the backend
+    and the family: the in-place read where Mosaic compiles it and the
+    attention op has it, the gather where the kernel would only be
+    interpreted.  A value asked for by name is honoured on every
+    backend or refused, never replaced."""
+    assert pick("auto", backend=backend) == want
     for explicit in ("gather", "pallas"):
-        assert resolve_paged_formulation(explicit) == explicit
-    # a jax without Pallas never resolves to the kernel on its own
-    monkeypatch.setattr(pk, "_HAVE_PALLAS", False)
-    assert resolve_paged_kernel("auto") == "gather"
+        assert pick(explicit, backend=backend) == explicit
+    # a family without the kernel: auto never picks it, by name refused
+    latent = frozenset({"paged", "prefill_pass"})
+    assert pick("auto", backend=backend, carries=latent) == "gather"
+    with pytest.raises(ConfigError, match="fam does not carry pallas_read"):
+        pick("pallas", backend=backend, carries=latent)
 
 
-def test_dense_cache_rejects_kernel_selection():
-    """kv_kernel='pallas' without a paged pool has no block table to
-    stream through — refused loudly at make_decoder time."""
-    from flexflow_tpu import FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.decoding import make_decoder
+def _untrained_gpt():
+    from flexflow_tpu import FFModel
     from flexflow_tpu.models.transformer import build_gpt
 
     ff = FFModel(FFConfig(batch_size=2, num_devices=1))
     build_gpt(ff, batch_size=2, seq_length=8, hidden_size=16,
               num_layers=1, num_heads=2, intermediate_size=32,
               vocab_size=16)
+    return ff
+
+
+def test_dense_cache_rejects_kernel_selection():
+    """kv_kernel='pallas' without a paged pool has no block table to
+    stream through — refused loudly at make_decoder time."""
+    from flexflow_tpu.decoding import make_decoder
+
     with pytest.raises(ValueError, match="kv_page_size"):
-        make_decoder(ff, kv_kernel="pallas")
+        make_decoder(_untrained_gpt(), kv_kernel="pallas")
+    # the twin takes a formulation already decided, as the ops do
+    with pytest.raises(ValueError, match="'gather' or 'pallas'"):
+        make_decoder(_untrained_gpt(), kv_page_size=4, kv_kernel="auto")
 
 
 # -- model-level: the compiled decode step ----------------------------
@@ -497,7 +514,7 @@ def test_kernel_chunk_twin_matches_gather_chunk_twin(trained, devices8):
 def test_scheduler_greedy_token_identical_gather_vs_kernel(trained,
                                                            devices8):
     """Acceptance: greedy completions on the shared-prefix smoke
-    workload are token-identical under --paged-kernel pallas vs the
+    workload are token-identical under the kernel asked for by name vs the
     gather oracle (prefix cache + chunked prefill ON in both), and the
     kernel's per-step KV reads actually undercut the dense-gather
     equivalent."""
@@ -561,14 +578,16 @@ def test_engine_lowers_each_step_program_once(trained, devices8,
             lowered.append(fun_name)
 
     mon.register_event_duration_secs_listener(on_duration)
-    cfg = ff.config
-    old = (cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk,
-           cfg.paged_kernel)
-    cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk = B, 4, 4
-    cfg.paged_kernel = paged_kernel
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    def factory(replica_id, survivors=None):
+        return PagedKVDecodeModel(
+            ff, batch_slots=B, page_size=4, prefill_chunk=4,
+            devices=devices8[:1], paged_kernel=paged_kernel)
+
     t_start = spans()[-1].t_end if spans() else 0.0
     try:
-        front = ServingFront.from_trained(ff, devices=devices8[:1])
+        front = ServingFront(factory, 1)
         try:
             rng = np.random.RandomState(2)
             front.generate_async(rng.randint(0, V, 9).tolist(), 2).wait(120)
@@ -584,8 +603,6 @@ def test_engine_lowers_each_step_program_once(trained, devices8,
             front.close(30.0)
     finally:
         watching[0] = False
-        (cfg.serving_slots, cfg.kv_page_size, cfg.prefill_chunk,
-         cfg.paged_kernel) = old
     # (the module names the profiler shows as jit_step, jit_prefill)
     assert warm.count("jit(step)") == 1
     assert warm.count("jit(prefill)") == 1

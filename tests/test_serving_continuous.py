@@ -3,7 +3,7 @@
 ops/attention.py): greedy token-identity against the static scan tier,
 bit-identity of the paged decode step against the dense KV cache,
 fault recovery through the donated-state reset path, and the Poisson
-loadgen end to end."""
+a mixed-length burst end to end."""
 import numpy as np
 import pytest
 
@@ -11,7 +11,6 @@ from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.decoding import build_paged_decode_step, make_decoder
 from flexflow_tpu.models.transformer import build_gpt
 from flexflow_tpu.serving import ContinuousScheduler, GenerationEngine
-from flexflow_tpu.serving.loadgen import run_loadgen, sample_workload
 
 pytestmark = pytest.mark.slow  # search/train-heavy: full tier only
 
@@ -405,23 +404,24 @@ def test_cow_divergence_bit_identical_to_independent(trained, devices8):
     assert shared[1] != shared[2]  # the seeds genuinely diverged
 
 
-def test_loadgen_end_to_end_continuous(trained, devices8):
+def test_mixed_length_burst_end_to_end_continuous(trained, devices8):
     ff, _ = trained
     sched = ContinuousScheduler.from_trained(
         ff, batch_slots=B, page_size=4, devices=devices8[:1])
     try:
         sched.generate([1, 2], 2, timeout=120.0)  # pay the compile
         rng = np.random.RandomState(5)
-        wl = sample_workload(rng, 10, V, prompt_len_range=(2, 6),
-                             max_new_range=(2, 6), long_frac=0.3,
-                             long_max_new_range=(8, 10))
-        report = run_loadgen(sched, wl, rate_rps=100.0, seed=2,
-                             timeout_s=120.0)
-        assert report["completed"] == 10 and report["failures"] == 0
-        assert report["tokens_generated"] == sum(m for _, m in wl)
-        assert report["tokens_per_s"] > 0
-        assert report["ttft"]["n"] == 10
+        wl = [(rng.randint(0, V, int(rng.randint(2, 7))).tolist(),
+               int(rng.randint(8, 11) if i % 3 == 0
+                   else rng.randint(2, 7))) for i in range(10)]
+        handles = [sched.generate_async(p, m) for p, m in wl]
+        outs = [h.wait(120.0) for h in handles]
+        assert [len(o) - len(p) for o, (p, _) in zip(outs, wl)] == \
+            [m for _, m in wl]
+        assert all(o[:len(p)] == p for o, (p, _) in zip(outs, wl))
+        assert all(h.t_first_token is not None for h in handles)
         st = sched.stats()
+        assert st["ttft"]["n"] == 11
         assert st["kv_pool"]["peak_used_blocks"] > 0
         assert st["kv_pool"]["used_blocks"] == 0
     finally:

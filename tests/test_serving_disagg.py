@@ -387,14 +387,18 @@ def trained(devices8):
     return ff
 
 
-def configure_serving(ff, kernel):
-    cfg = ff.config
-    cfg.serving_slots = 2
-    cfg.kv_page_size = 4
-    cfg.kv_pool_blocks = 12
-    cfg.paged_kernel = kernel
-    cfg.prefill_chunk = 4 if kernel == "pallas" else 0
-    return cfg
+def engine_factory(ff, kernel, devices):
+    """A front's replica factory with the paged read asked for by name
+    (a front built from the config always asks for "auto"): the kernel
+    under the interpreter against the gather."""
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    def factory(replica_id, survivors=None):
+        return PagedKVDecodeModel(
+            ff, batch_slots=2, page_size=4, num_blocks=12,
+            devices=devices, paged_kernel=kernel,
+            prefill_chunk=4 if kernel == "pallas" else 0)
+    return factory
 
 
 def run_real(front):
@@ -415,15 +419,12 @@ def test_disagg_token_identity_vs_colocated_engine(
     colocated 2-mixed front, on BOTH paged-attention formulations,
     with the pool invariant checker armed at every scheduler step and
     at least one migration actually streamed."""
-    configure_serving(trained, kernel)
-    colo = ServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1],
-        check_invariants=True)
+    factory = engine_factory(trained, kernel, devices8[:1])
+    colo = ServingFront(factory, 2, check_invariants=True)
     want, _ = run_real(colo)
 
-    disagg = DisaggServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1],
-        roles=["prefill", "decode"], check_invariants=True)
+    disagg = DisaggServingFront(
+        factory, 2, roles=["prefill", "decode"], check_invariants=True)
     got, st = run_real(disagg)
 
     assert got == want

@@ -99,6 +99,28 @@ def test_front_serves_across_replicas():
         front.close()
 
 
+def test_front_stamps_its_backlog_at_admission_on_every_handle():
+    """`queue_depth_at_admit` is the front's backlog as the request
+    came in: 0 for the first of a burst into one two-slot replica, and
+    a later request of the same burst has seen a deeper one (a step
+    takes 10 ms here, the twelve submissions far less)."""
+    front = ServingFront(
+        lambda rid, survivors=None: FakeStepModel(delay_s=0.01),
+        num_replicas=1, sleep=NO_SLEEP)
+    try:
+        rng = np.random.RandomState(0)
+        reqs = [(rng.randint(0, V, int(rng.randint(2, 7))).tolist(),
+                 int(rng.randint(2, 7))) for _ in range(12)]
+        hs = [front.generate_async(p, m) for p, m in reqs]
+        for h, (p, m) in zip(hs, reqs):
+            assert h.wait(30.0) == expected(p, m)
+        depths = [h.queue_depth_at_admit for h in hs]
+        assert all(isinstance(d, int) and d >= 0 for d in depths)
+        assert depths[0] == 0 and max(depths) > 0
+    finally:
+        front.close()
+
+
 def test_front_validates_at_admission():
     front = ServingFront(factory, num_replicas=1, sleep=NO_SLEEP)
     try:
@@ -231,6 +253,11 @@ def test_restart_budget_exhaustion_marks_replica_dead():
     )
     try:
         for i in range(6):
+            # the poisoned replica is routed to again only once its
+            # rebuild is over: six requests can all finish on the
+            # survivor while it is still "restarting"
+            assert _wait_for(
+                lambda: front.replicas[0].state != "restarting")
             assert front.generate([1 + i], 4, timeout=30.0) == \
                 expected([1 + i], 4)
         assert _wait_for(lambda: front.replicas[0].state == "dead")

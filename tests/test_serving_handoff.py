@@ -8,7 +8,7 @@ recovery through the resume record, the five-way handoff fault matrix
 (torn / header / fabric / capacity / dest_death — every fault degrades
 to replay with exact tokens and its own counter), terminate() routing
 unfinishable generations onto the handoff path, the autoscaler's
-KV-occupancy rebalance trigger, loadgen seed stamping, the config
+KV-occupancy rebalance trigger, per-request seed stamping, the config
 knobs, and the offline FFKV frame verifier (tools/kvframe_fsck.py).
 The slow section reruns the pause/resume token-identity oracle through
 real trained engines on both paged-attention kernels."""
@@ -535,22 +535,19 @@ def test_autoscaler_rejects_bad_rebalance_threshold():
         front.close()
 
 
-# -- satellites: loadgen seed stamping + config knobs --------------------
+# -- satellites: per-request seed stamping + config knobs --------------------
 
-def test_loadgen_records_carry_the_front_minted_seed():
-    from flexflow_tpu.serving.loadgen import run_loadgen
-
+def test_handles_carry_the_front_minted_seed():
     front = ServingFront(
         lambda rid, survivors=None: FakeKVModel(), num_replicas=2,
         seed=3, sleep=NO_SLEEP)
     try:
-        rep = run_loadgen(front, [([1, 2], 4)] * 4, rate_rps=500.0,
-                          detail=True, timeout_s=30.0)
+        handles = [front.generate_async([1, 2], 4) for _ in range(4)]
+        for h in handles:
+            h.wait(30.0)
     finally:
         front.close()
-    recs = [r for r in rep["records"] if r["ok"]]
-    assert len(recs) == 4
-    seeds = [r["seed"] for r in recs]
+    seeds = [h.seed for h in handles]
     assert all(isinstance(s, int) for s in seeds)
     # distinct per request: a replayed record is independently exact
     assert len(set(seeds)) == 4
@@ -651,14 +648,18 @@ def trained(devices8):
     return ff
 
 
-def configure_serving(ff, kernel):
-    cfg = ff.config
-    cfg.serving_slots = 2
-    cfg.kv_page_size = 4
-    cfg.kv_pool_blocks = 12
-    cfg.paged_kernel = kernel
-    cfg.prefill_chunk = 4 if kernel == "pallas" else 0
-    return cfg
+def engine_factory(ff, kernel, devices):
+    """A front's replica factory with the paged read asked for by name
+    (a front built from the config always asks for "auto"): the kernel
+    under the interpreter against the gather."""
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    def factory(replica_id, survivors=None):
+        return PagedKVDecodeModel(
+            ff, batch_slots=2, page_size=4, num_blocks=12,
+            devices=devices, paged_kernel=kernel,
+            prefill_chunk=4 if kernel == "pallas" else 0)
+    return factory
 
 
 def _pause_in_flight(front, h, attempts=400):
@@ -685,14 +686,12 @@ def test_mid_decode_handoff_token_identity_real_engine(
     mid-decode and migrated (or replayed) across replicas is
     byte-identical to the uninterrupted run — greedy AND seeded
     sampling, both paged-attention kernels, invariant checker armed."""
-    configure_serving(trained, kernel)
+    factory = engine_factory(trained, kernel, devices8[:1])
     attempts = 5  # the pause races a fast completion
     # the oracle mints the SAME per-request seed sequence (admission
     # order), so attempt i on the handoff front samples identically
     # to oracle request i
-    oracle = ServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1], seed=5,
-        check_invariants=True)
+    oracle = ServingFront(factory, 2, seed=5, check_invariants=True)
     try:
         wants = [oracle.generate_async(
             PROMPT_GPT, MNT_GPT, temperature).wait(240.0)
@@ -700,9 +699,8 @@ def test_mid_decode_handoff_token_identity_real_engine(
     finally:
         oracle.close()
 
-    front = ServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1], seed=5,
-        handoff=True, check_invariants=True)
+    front = ServingFront(factory, 2, seed=5, handoff=True,
+                         check_invariants=True)
     try:
         paused = False
         for i in range(attempts):
@@ -730,22 +728,19 @@ def test_decode_death_replay_token_identity_real_engine(
     """Kill a real decode replica mid-generation: the resume record
     replays on the survivor and every completion matches the
     fault-free oracle byte-for-byte."""
-    configure_serving(trained, kernel)
+    factory = engine_factory(trained, kernel, devices8[:1])
     prompts = [PROMPT_GPT, [9, 4, 1], [8, 2], [5, 5, 5, 5]]
     mnts = [11, 8, 7, 6]
-    oracle = ServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1],
-        check_invariants=True)
+    oracle = ServingFront(factory, 2, check_invariants=True)
     try:
         want = [oracle.generate_async(p, m).wait(240.0)
                 for p, m in zip(prompts, mnts)]
     finally:
         oracle.close()
 
-    front = ServingFront.from_trained(
-        trained, num_replicas=2, devices=devices8[:1],
-        check_invariants=True, retry_backoff=0.0,
-        fault_plans={0: kill_on_steps([6])})
+    front = ServingFront(factory, 2, check_invariants=True,
+                         retry_backoff=0.0,
+                         fault_plans={0: kill_on_steps([6])})
     try:
         hs = [front.generate_async(p, m)
               for p, m in zip(prompts, mnts)]

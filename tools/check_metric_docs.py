@@ -13,11 +13,21 @@ match the docs' `<i>`-placeholder convention
 (`serving/autoscaler_*`).  Wired in as a tier-1 test
 (tests/test_metric_docs.py) so the metric table cannot drift.
 
+Second check, one a document (`documents`): what a document puts
+between back-ticks has to exist.  Every `--flag` is one that
+`FFConfig.from_args` defines, or the argparse of a script the same
+document names; every path under `flexflow_tpu/`, `benchmarks/`,
+`tests/`, `scripts/`, `tools/`, `examples/` or `docs/`, and every bare
+`*.py` / `*.sh` / `*.json`, is a file of the checkout (`stale_references`).
+A document that still cites a deleted file or option fails its case.
+
 Usage: python tools/check_metric_docs.py [--root REPO]   (exit 0/1)
 """
 from __future__ import annotations
 
 import argparse
+import glob
+import itertools
 import os
 import re
 import sys
@@ -104,6 +114,82 @@ def is_documented(name: str, names: Set[str],
                for w in wild)
 
 
+TREES = ("flexflow_tpu", "benchmarks", "tests", "scripts", "tools",
+         "examples", "docs")
+#: flags of programs that are not this repository's: the chip tool's
+#: and pytest's, where the documents quote their command lines
+OUTSIDE_FLAGS = {"--chips", "--timeout", "--dist", "--durations"}
+#: files a run writes, which the documents name and no checkout holds
+RUN_OUTPUTS = {"trace.json", "manifest.json", "meta.json", "out.json"}
+
+#: fenced blocks, and inline code (which may wrap inside a paragraph)
+_TICKED = re.compile(
+    r"```[^\n]*\n(.*?)```|`([^`\n]+(?:\n[^`\n]+)*)`", re.S)
+_FLAG = re.compile(r"(?<![\w-])--[a-z][a-z0-9]*(?:-[a-z0-9]+)*(?![\w-])")
+_FLAG_DEF = re.compile(r"""["'](--[a-z][a-z0-9-]*)["']""")
+_PATH = re.compile(
+    r"(?:(?:" + "|".join(TREES) + r")/[\w./<>*{},-]*"
+    r"|[\w-]+\.(?:py|sh|json))")
+
+
+def documents(root: str) -> List[str]:
+    """The documents held to the tree."""
+    return ["README.md",
+            *sorted(os.path.relpath(p, root) for p in
+                    glob.glob(os.path.join(root, "docs", "*.md"))),
+            ".claude/skills/verify/SKILL.md"]
+
+
+def _flags_defined(path: str) -> Set[str]:
+    with open(path) as f:
+        return set(_FLAG_DEF.findall(f.read()))
+
+
+def _candidates(token: str) -> List[str]:
+    """`a/{b,c}/<name>.py` -> [`a/b/*.py`, `a/c/*.py`]: braces are
+    alternatives, `<...>` and `*` stand for anything."""
+    token = re.sub(r"<[^>]*>", "*", token.split("::")[0])
+    parts = re.split(r"\{([^}]*)\}", token)
+    choices = [p.split(",") if i % 2 else [p] for i, p in enumerate(parts)]
+    return ["".join(c) for c in itertools.product(*choices)]
+
+
+def _lookup(root: str, pattern: str) -> List[str]:
+    """A path is taken from the root; a bare file name is looked for
+    at the root and anywhere under TREES, and nowhere else (a scratch
+    checkout the tree ignores must not stand in for a deleted file)."""
+    if "/" in pattern:
+        return glob.glob(os.path.join(root, pattern), recursive=True)
+    return glob.glob(os.path.join(root, pattern)) + [
+        m for tree in TREES for m in glob.glob(
+            os.path.join(root, tree, "**", pattern), recursive=True)]
+
+
+def stale_references(root: str, doc: str) -> List[str]:
+    """What `doc` names between back-ticks that the checkout does not
+    have: flags nothing it names defines, paths that are not there."""
+    with open(os.path.join(root, doc)) as f:
+        ticked = [a or b for a, b in _TICKED.findall(f.read())]
+    tokens = {t.strip("()[],;:.\"'") for s in ticked for t in s.split()}
+    paths = {t for t in tokens if _PATH.fullmatch(t)}
+    found = {p: [m for c in _candidates(p) for m in _lookup(root, c)]
+             for p in paths}
+    defined = _flags_defined(
+        os.path.join(root, "flexflow_tpu", "config.py")) | OUTSIDE_FLAGS
+    for hits in found.values():
+        for hit in hits:
+            if hit.endswith(".py") and os.path.isfile(hit):
+                defined |= _flags_defined(hit)
+    problems = [f"`{flag}`: no parser of FFConfig or of a script this "
+                "document names defines it"
+                for flag in sorted({f for s in ticked
+                                    for f in _FLAG.findall(s)} - defined)]
+    problems += [f"`{p}`: no such file in the checkout"
+                 for p in sorted(paths)
+                 if not found[p] and p not in RUN_OUTPUTS]
+    return problems
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--root", default=os.path.dirname(
@@ -125,7 +211,11 @@ def main(argv=None) -> int:
         return 1
     print(f"ok: {len(emitted)} emitted metric name(s) all documented "
           f"({len(names)} doc names, {len(wild)} wildcard families)")
-    return 0
+    stale = {doc: problems for doc in documents(args.root)
+             if (problems := stale_references(args.root, doc))}
+    for doc, problems in stale.items():
+        print(f"{doc}:\n  " + "\n  ".join(problems), file=sys.stderr)
+    return 1 if stale else 0
 
 
 if __name__ == "__main__":
