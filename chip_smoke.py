@@ -455,6 +455,7 @@ class Smoke:
     def phase_b(self) -> dict:
         info = {}
         info.update(self._kernels_flash())
+        info.update(self._kernels_one_tile())
         info.update(self._kernels_paged())
         return info
 
@@ -535,6 +536,60 @@ class Smoke:
             info[name + "_err"] = float(f"{err:.3e}")
             out(f"B: {name} [{bh},{seq},{d}] {dtype.name}: rel err "
                 f"{err:.2e} (tol {tol:.0e})")
+            check(err <= tol, f"{name}: rel err {err:.3e} > {tol:.0e}")
+        return info
+
+    def _kernels_one_tile(self) -> dict:
+        """The short-row tiling of the same algorithm (key rows up to
+        `ONE_TILE_MAX_KV` stay whole in VMEM): forward and the fused
+        backward at bert_long's widths and 512 keys, against the same
+        jnp twin."""
+        jax, out = self.jax, self.out
+        import jax.numpy as jnp
+
+        from flexflow_tpu.ops.pallas import flash_attention as fa
+
+        s = self.sizes["bert_long"]
+        dtype = jnp.dtype(self.sizes["dtype"])
+        b, h, seq = s["batch"], s["heads"], min(s["seq"], 512)
+        d = s["hidden"] // h
+        scale = 1.0 / float(np.sqrt(d))
+        tol = KERNEL_TOL[dtype.name]
+        kw = dict(d=d, scale=scale, causal=True, interpret=self.rehearsal)
+        rng = np.random.RandomState(0)
+        q, k, v, do = (jnp.asarray(rng.randn(b, seq, h, d), dtype)
+                       for _ in range(4))
+        check(fa.pick_tiling(seq, d, "tpu") == "one_tile"
+              and fa._one_tile_supported(q, k, v),
+              f"no one-tile kernel for q{q.shape}")
+
+        def bh(x):  # the twin's [b*h, s, d]
+            return x.transpose(0, 2, 1, 3).reshape(b * h, seq, d)
+
+        f32 = [bh(x).astype(jnp.float32) for x in (q, k, v)]
+        with jax.default_matmul_precision("highest"):
+            want_o, vjp = jax.vjp(
+                lambda q, k, v: fa._ref_attention(q, k, v, scale, True),
+                *f32)
+            wants = (want_o,) + vjp(bh(do).astype(jnp.float32))
+
+        flat = [x.reshape(b, seq, h * d) for x in (q, k, v, do)]
+        t0 = time.perf_counter()
+        self._mosaic(fa._one_tile_fwd.lower(*flat[:3], **kw), 1,
+                     "one-tile fwd")
+        o, lse = fa._one_tile_fwd(*flat[:3], **kw)
+        self._mosaic(fa._one_tile_bwd.lower(*flat[:3], o, lse, flat[3],
+                                            **kw), 1, "one-tile bwd")
+        grads = jax.block_until_ready(
+            fa._one_tile_bwd(*flat[:3], o, lse, flat[3], **kw))
+        info = {"one_tile_compile_s": round(time.perf_counter() - t0, 2)}
+        for name, got, want in zip(
+                ("one_tile_fwd", "one_tile_bwd.dq", "one_tile_bwd.dk",
+                 "one_tile_bwd.dv"), (o,) + tuple(grads), wants):
+            err = rel_err(bh(got.reshape(b, seq, h, d)), want)
+            info[name + "_err"] = float(f"{err:.3e}")
+            out(f"B: {name} [{b},{seq},{h}x{d}] {dtype.name} causal: rel "
+                f"err {err:.2e} (tol {tol:.0e})")
             check(err <= tol, f"{name}: rel err {err:.3e} > {tol:.0e}")
         return info
 

@@ -20,16 +20,29 @@ from .fftype import ParameterSyncType
 
 # single source of truth for the flash-attention crossover (see the
 # flash_min_seq field comment); attention ops fall back to this when
-# used outside FFModel.compile.  Measured on a v5e under the retired
-# remote-chip set-up and jax 0.4.x (fwd+bwd, both directions real
-# Pallas kernels, best-of-trials; not re-measured on the current
-# machine): seq 512/1024 XLA and flash tie within noise; seq 2048 flash
-# ~= XLA with none of the [s,s] score HBM traffic; seq 8192 flash wins
-# ~9x (63-124 ms vs 758-822 ms — XLA falls off the HBM cliff when the
-# score matrix stops fitting in fused form).  jax's bundled
-# pallas.ops.tpu.flash_attention measured 4-10x slower than this
-# kernel at every length on the same chip.
-DEFAULT_FLASH_MIN_SEQ = 2048
+# used outside FFModel.compile.  From this key length up the attention
+# core runs in Pallas kernels that keep the [s, s] scores in VMEM
+# (ops/pallas/flash_attention.py `pick_tiling`: whole-row "one_tile"
+# kernels up to 1,024 keys, the online-softmax kernels above).
+# Measured on this machine (TPU v5e, jax 0.9.0, libtpu 0.0.34; my chip
+# runs, PR 33, scripts/attn_core_probe.py --mode sweep: b 8, h 16, d 64,
+# bf16, forward + backward of the core alone, ms a call):
+#
+#     kv      dense (XLA)   one-tile kernels   long-row kernels
+#     128        0.066          0.128              0.201
+#     256        0.131          0.242              0.360
+#     512        0.923          0.524              0.655
+#     1,024      5.355          1.643              2.076
+#
+# XLA keeps the scores of a short row on the chip by itself; from 512
+# keys on they go through HBM (14 score-sized passes a layer) and the
+# kernels win: the crossover lies between 256 and 512.  Inside
+# BERT-large's seq-512 step the dense core costs ~24 ms of 75.9, the
+# long-row kernels with their four transposes a layer ~16 (PERF.md §6,
+# PR 33).  The long-row kernels at >= 2,048 keys have no time on this
+# machine yet; docs/flash_ceiling_r5.json holds an older machine's
+# (jax 0.4.x: 18.8 % dense utilisation at seq 2,048), kept as history.
+DEFAULT_FLASH_MIN_SEQ = 512
 
 # valid FFConfig.nan_policy values (consumed by the resilience
 # supervisor's step-health handling, resilience/supervisor.py).

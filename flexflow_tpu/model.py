@@ -830,7 +830,7 @@ class FFModel:
             self._opt_state = self.executor.shard_opt_state(
                 self.optimizer.init_state(self._weights)
             )
-        with span("build_step_fns"):
+        with span("build_step_fns", **self._attention_core_counts()):
             self._step_fn = self.executor.build_step()
             self._eval_fn = self.executor.build_eval_step()
             self._fwd_fn = self.executor.build_forward()
@@ -1121,6 +1121,24 @@ class FFModel:
                 )
             }
 
+    def _attention_core_counts(self) -> Dict[str, object]:
+        """Args of the `build_step_fns` span: how many attention ops'
+        cores take a Pallas kernel / the dense [b, h, s, s] path in the
+        step these functions trace, and the kernels' tiling
+        (`MultiHeadAttention.core_plan`; off-TPU the flash branch runs
+        its jnp twin, which counts as dense)."""
+        itemsize = jnp.dtype(self.executor.compute_dtype
+                             or jnp.float32).itemsize
+        plans = [op.core_plan(itemsize)
+                 for op in self.operators.topo_order()
+                 if op.op_type == OperatorType.MULTIHEAD_ATTENTION]
+        kernels = [p for p in plans if p in ("one_tile", "online")]
+        return {
+            "attn_kernel_ops": len(kernels),
+            "attn_dense_ops": sum(p in ("dense", "jnp") for p in plans),
+            "attn_tile": "+".join(sorted(set(kernels))),
+        }
+
     def set_iteration_config(self, seq_length: Optional[int]):
         """FFIterationConfig.seq_length threading (reference
         model.cc:2415-2419): BatchMatmul ops mask positions past
@@ -1134,7 +1152,8 @@ class FFModel:
             op._iter_seq_length = seq_length
         cached = self._step_cache.get(seq_length)
         if cached is None:
-            with span("build_step_fns", seq_length=seq_length):
+            with span("build_step_fns", seq_length=seq_length,
+                      **self._attention_core_counts()):
                 self._step_fn = self.executor.build_step()
                 self._eval_fn = self.executor.build_eval_step()
                 self._fwd_fn = self.executor.build_forward()

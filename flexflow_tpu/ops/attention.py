@@ -577,10 +577,72 @@ class MultiHeadAttention(Op):
 
         return spec_of(qdims_axes[0]), qdims_axes[1], spec_of(head_axes)
 
+    def _core_plan(self, b, sq, sk, h, d, itemsize, use_dropout) -> str:
+        """Which attention core these (global) shapes take: "ring",
+        "one_tile" / "online" (a Pallas kernel, `pick_tiling`), "jnp"
+        (the flash branch's twin, off-TPU) or "dense" (einsum ->
+        softmax -> einsum with the [b, h, q, k] scores in HBM).  The
+        one decision `_attend` acts on and `core_plan` reports."""
+        from ..config import DEFAULT_FLASH_MIN_SEQ
+        from .pallas.flash_attention import pick_tiling
+
+        p: MultiHeadAttentionParams = self.params
+        if self._seq_degree() > 1:
+            return "ring"
+        kv_appended = sk - self.inputs[1].shape.logical_shape[1]
+        # FFConfig.flash_min_seq (--flash-min-seq), set on ops at compile
+        flash_min = getattr(self, "_flash_min_seq", DEFAULT_FLASH_MIN_SEQ)
+        # HBM guard: when the PER-DEVICE [b, h, q, k] score matrix would
+        # be enormous, never trust the non-flash branch's reliance on XLA
+        # fusing it away.  Shapes here are global (GSPMD traces the full
+        # array), so divide by the partition degrees (batch/seq from the
+        # input view, heads from the channel shard).
+        # Only the batch and seq partition degrees shrink the [b,h,q,k]
+        # score tensor — a hidden-dim partition does not (heads are
+        # counted once via shard.channel, replication never shrinks
+        # per-device data).
+        deg = self.inputs[0].shape.degrees
+        data_deg = int(np.prod(deg[:2])) if len(deg) >= 2 else int(deg[0])
+        part = max(1, data_deg) * max(1, self.shard.channel)
+        scores_bytes = b * h * sq * sk * itemsize // part
+        force_flash = scores_bytes > _FLASH_FORCE_SCORE_BYTES
+        blocked = use_dropout or (p.causal and kv_appended)
+        if force_flash and blocked:
+            import warnings
+
+            warnings.warn(
+                f"{self.name}: ~{scores_bytes >> 30} GiB of attention "
+                "scores will materialize per device — the flash path "
+                "cannot take over because of "
+                + ("attention dropout" if use_dropout
+                   else "causal attention with appended kv "
+                        "(add_bias_kv/add_zero_attn)")
+            )
+        if blocked or not (sk >= flash_min or force_flash):
+            return "dense"
+        return pick_tiling(sk, d)
+
+    def core_plan(self, itemsize: int, training: bool = True) -> Optional[str]:
+        """`_core_plan` for the op's declared shapes and the step's
+        compute itemsize, as a step traced now would decide it (None:
+        a decode-mode op never reaches `_attend`).  What
+        `build_step_fns` counts."""
+        if self._decode_n() > 0:
+            return None
+        p: MultiHeadAttentionParams = self.params
+        b, sq, _ = self.inputs[0].shape.logical_shape
+        sk = (self.inputs[1].shape.logical_shape[1]
+              + int(p.add_bias_kv) + int(p.add_zero_attn))
+        return self._core_plan(b, sq, sk, p.num_heads, p.k_channels,
+                               itemsize, training and p.dropout > 0.0)
+
     def _attend(self, qh, kh, vh, scale, *, training, rng):
         p: MultiHeadAttentionParams = self.params
-        sp = self._seq_degree()
-        if sp > 1:
+        use_dropout = training and p.dropout > 0.0 and rng is not None
+        b, sq, h, d = qh.shape
+        plan = self._core_plan(b, sq, kh.shape[1], h, d,
+                               jnp.dtype(qh.dtype).itemsize, use_dropout)
+        if plan == "ring":
             # sequence parallelism: ring attention over the seq mesh axis
             from ..parallel.ring_attention import ring_attention
 
@@ -597,47 +659,9 @@ class MultiHeadAttention(Op):
                 scale=scale, causal=p.causal,
                 training=training,
             )
-        kv_appended = kh.shape[1] - self.inputs[1].shape.logical_shape[1]
-        use_dropout = training and p.dropout > 0.0 and rng is not None
-        # FFConfig.flash_min_seq (--flash-min-seq), set on ops at compile
-        from ..config import DEFAULT_FLASH_MIN_SEQ
-
-        flash_min = getattr(self, "_flash_min_seq", DEFAULT_FLASH_MIN_SEQ)
-        # HBM guard: when the PER-DEVICE [b, h, q, k] score matrix would
-        # be enormous, never trust the non-flash branch's reliance on XLA
-        # fusing it away.  Shapes here are global (GSPMD traces the full
-        # array), so divide by the partition degrees (batch/seq from the
-        # input view, heads from the channel shard).
-        # Only the batch and seq partition degrees shrink the [b,h,q,k]
-        # score tensor — a hidden-dim partition does not (heads are
-        # counted once via shard.channel, replication never shrinks
-        # per-device data).
-        deg = self.inputs[0].shape.degrees
-        data_deg = int(np.prod(deg[:2])) if len(deg) >= 2 else int(deg[0])
-        part = max(1, data_deg) * max(1, self.shard.channel)
-        scores_bytes = (
-            qh.shape[0] * qh.shape[2] * qh.shape[1] * kh.shape[1]
-            * jnp.dtype(qh.dtype).itemsize
-        ) // part
-        force_flash = scores_bytes > _FLASH_FORCE_SCORE_BYTES
-        if force_flash and (use_dropout or (p.causal and kv_appended)):
-            import warnings
-
-            warnings.warn(
-                f"{self.name}: ~{scores_bytes >> 30} GiB of attention "
-                "scores will materialize per device — the flash path "
-                "cannot take over because of "
-                + ("attention dropout" if use_dropout
-                   else "causal attention with appended kv "
-                        "(add_bias_kv/add_zero_attn)")
-            )
-        if (
-            not use_dropout
-            and not (p.causal and kv_appended)
-            and (kh.shape[1] >= flash_min or force_flash)
-        ):
+        if plan != "dense":
             # hot path: flash attention (Pallas on TPU, fused jnp off-TPU)
-            from .pallas.flash_attention import mha_flash
+            from .pallas.flash_attention import flash_mha
 
             mesh = getattr(self, "_mesh", None)
             if (
@@ -649,7 +673,8 @@ class MultiHeadAttention(Op):
                 # batch/head mesh axes explicitly (both embarrassingly
                 # parallel for attention)
                 return self._flash_sharded(qh, kh, vh, scale, mesh)
-            return mha_flash(qh, kh, vh, scale, p.causal)
+            return flash_mha(qh, kh, vh, scale, p.causal)
+        kv_appended = kh.shape[1] - self.inputs[1].shape.logical_shape[1]
         scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
         if p.causal:
             qlen, klen = scores.shape[-2], scores.shape[-1]
@@ -671,12 +696,12 @@ class MultiHeadAttention(Op):
 
         from jax.sharding import PartitionSpec
 
-        from .pallas.flash_attention import mha_flash
+        from .pallas.flash_attention import flash_mha
 
         p: MultiHeadAttentionParams = self.params
         batch_spec, _, head_spec = self._view_specs()
         spec = PartitionSpec(batch_spec, None, head_spec, None)
-        fn = functools.partial(mha_flash, scale=scale, causal=p.causal)
+        fn = functools.partial(flash_mha, scale=scale, causal=p.causal)
         return jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,

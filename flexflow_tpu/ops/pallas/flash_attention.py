@@ -441,3 +441,296 @@ def mha_flash(qh, kh, vh, scale: float, causal: bool):
     v2 = vh.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     o = flash_attention(q2, k2, v2, scale, causal)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# One-tile kernels: the same algorithm tiled for SHORT rows (PR 33).
+#
+# Everything above is built for long rows: online softmax with a running
+# (m, l, acc), a fori_loop over KV blocks, a [bh, s, d] layout (four
+# transposes a layer; d = 64 fills half the lanes) and a backward in two
+# kernels that each recompute the probabilities.  When the whole key row
+# fits one VMEM tile (sk <= ONE_TILE_MAX_KV) none of that is needed:
+#
+#   * block (bq, sk) with sk the FULL key length, plain softmax in f32
+#     (the v5e's vector unit has no bf16), no rescale, no loop;
+#   * q, k, v are read straight from the [b, s, h*d] projection output
+#     through a 128-lane block: two heads of 64, or one of >= 128.  A
+#     head is picked out of the pair by zeroing the other's lanes of ONE
+#     operand of each product, which costs the MXU nothing (a 64-deep
+#     contraction fills half of the 128-deep array either way) and keeps
+#     every vector op lane-dense;
+#   * ONE backward kernel: S and P recomputed once from the saved
+#     log-sum-exp, dq, dk and dv emitted together (5 products a tile, one
+#     read of q, k, v, dO), delta = rowsum(dO * O) inside it;
+#   * the pallas_calls sit in jitted functions, so a step of N layers
+#     lowers one kernel body a direction, not N.
+#
+# `pick_tiling` chooses between the two from what can be observed (key
+# length, head dim, backend); `flash_mha` is the [b, s, h, d] entry the
+# attention op calls.  Nothing above this line changed.
+# ---------------------------------------------------------------------------
+
+#: longest key row the one-tile kernels take
+ONE_TILE_MAX_KV = 1024
+#: f32 score elements a grid cell holds at most: one [512, 1024] tile,
+#: 2 MB.  The larger the tile the better (my chip runs, PR 33, forward +
+#: backward at kv 512 / 1,024, ms: 0.664 / 1.951 at 64 Ki elements,
+#: 0.571 / 1.951 at 128 Ki, 0.523 / 1.718 at 256 Ki, - / 1.643 at 512 Ki;
+#: working through a block 128 or 256 rows at a time gained nothing)
+_ONE_TILE_ELEMS = 512 * 1024
+#: the backward kernel's tile with its f32 temporaries passes the 16 MiB
+#: a kernel may use by default; the v5e's VMEM holds 128 MiB
+_ONE_TILE_VMEM_BYTES = 64 << 20
+
+
+def pick_tiling(sk: int, d: int, backend: Optional[str] = None) -> str:
+    """Which attention core a key length and head dim get on a backend:
+    "one_tile" (whole-row Pallas kernels), "online" (the long-row Pallas
+    kernels above) or "jnp" (the twin: off-TPU, or a head dim neither
+    tiling covers).  A pure function of its arguments."""
+    backend = backend or jax.default_backend()
+    if backend != "tpu" or not _HAVE_PALLAS:
+        return "jnp"
+    if not (d == 64 or d % 128 == 0):
+        return "jnp"
+    if sk <= ONE_TILE_MAX_KV and sk % 128 == 0:
+        return "one_tile"
+    return "online"
+
+
+def _one_tile_block_q(sq: int, sk: int) -> Optional[int]:
+    cap = 128
+    while cap < 1024 and 2 * cap * sk <= _ONE_TILE_ELEMS:
+        cap *= 2
+    return _largest_dividing(sq, cap)
+
+
+def _one_tile_supported(qh, kh, vh) -> bool:
+    """[b, s, h, d] shapes the one-tile kernels cover: heads pack into
+    whole 128-lane blocks and the query length divides into blocks."""
+    _, sq, h, d = qh.shape
+    w = max(d, 128)
+    return (vh.shape[-1] == d and (h * d) % w == 0
+            and _one_tile_block_q(sq, kh.shape[1]) is not None)
+
+
+def _scale_folds(scale: float) -> bool:
+    """A power-of-two scale multiplies bf16 q exactly, which saves one
+    multiply per score element."""
+    return float(np.frexp(scale)[0]) == 0.5
+
+
+def _head_lanes(rows: int, w: int, d: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (rows, w), 1) // d
+
+
+def _one_tile_scores(qj, k, scale, fold, causal, q_start):
+    s = jax.lax.dot_general(
+        qj, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [bq, sk] f32
+    if not fold:
+        s = s * scale
+    if causal:
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    return s
+
+
+def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d: int,
+                         scale: float, causal: bool):
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]  # [bq, w], [sk, w], [sk, w]
+    bq, w = q.shape
+    heads = w // d
+    fold = _scale_folds(scale)
+    if fold:
+        q = q * jnp.asarray(scale, q.dtype)
+    q_start = pl.program_id(2) * bq
+    lane = _head_lanes(bq, w, d) if heads > 1 else None
+    out = None
+    for j in range(heads):
+        qj = q if heads == 1 else jnp.where(lane == j, q, jnp.zeros_like(q))
+        s = _one_tile_scores(qj, k, scale, fold, causal, q_start)
+        m = jnp.max(s, axis=-1)
+        p = jnp.exp(s - m[:, None])
+        l = jnp.sum(p, axis=-1)
+        oj = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) / l[:, None]  # [bq, w]: head j's lanes are its output
+        out = oj if heads == 1 else (
+            jnp.where(lane == j, oj, 0.0 if out is None else out))
+        lse_ref[0, 0, j, :] = m + jnp.log(l)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _one_tile_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                         dq_ref, dk_ref, dv_ref, *acc, d: int, scale: float,
+                         causal: bool, num_q: int):
+    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    bq, w = q.shape
+    sk = k.shape[0]
+    heads = w // d
+    fold = _scale_folds(scale)
+    qs = q * jnp.asarray(scale, q.dtype) if fold else q
+    qi = pl.program_id(2)
+    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)  # [bq, w]
+    if heads > 1:
+        lane_q, lane_k = _head_lanes(bq, w, d), _head_lanes(sk, w, d)
+    dq = jnp.zeros((bq, w), jnp.float32)
+    dk = jnp.zeros((sk, w), jnp.float32)
+    dv = jnp.zeros((sk, w), jnp.float32)
+    for j in range(heads):
+        if heads == 1:
+            qj, kj, doj, delta = qs, k, do, jnp.sum(do_o, axis=-1)
+        else:
+            # head j's lanes of ONE operand a product: the other head's
+            # lanes then add nothing to S and dP, and dq, dk, dv come
+            # out zero outside head j's lanes, so they simply add up
+            mq, mk = lane_q == j, lane_k == j
+            qj = jnp.where(mq, qs, jnp.zeros_like(qs))
+            doj = jnp.where(mq, do, jnp.zeros_like(do))
+            kj = jnp.where(mk, k, jnp.zeros_like(k))
+            delta = jnp.sum(jnp.where(mq, do_o, 0.0), axis=-1)
+        s = _one_tile_scores(qj, k, scale, fold, causal, qi * bq)
+        p = jnp.exp(s - lse_ref[0, 0, j, :][:, None])  # [bq, sk] f32
+        dv = dv + jax.lax.dot_general(
+            p.astype(do.dtype), doj, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [sk, w]
+        dp = jax.lax.dot_general(
+            doj, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bq, sk]
+        # the scale of dS is applied to the [*, w] results instead
+        ds = (p * (dp - delta[:, None])).astype(q.dtype)
+        dq = dq + jax.lax.dot_general(
+            ds, kj, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bq, w]
+        dk = dk + jax.lax.dot_general(
+            ds, qj, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [sk, w]; qj carries the scale when it folds
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    if not fold:
+        dk = dk * scale
+    if num_q == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    dk_acc, dv_acc = acc  # f32 [sk, w], carried over the q blocks
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = dk
+        dv_acc[...] = dv
+
+    @pl.when(qi > 0)
+    def _():
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    @pl.when(qi == num_q - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _one_tile_specs(q, k, d: int):
+    b, sq, e = q.shape
+    sk = k.shape[1]
+    w = max(d, 128)
+    bq = _one_tile_block_q(sq, sk)
+    grid = (b, e // w, sq // bq)
+    q_spec = pl.BlockSpec((1, bq, w), lambda i, g, j: (i, j, g))
+    kv_spec = pl.BlockSpec((1, sk, w), lambda i, g, j: (i, 0, g))
+    lse_spec = pl.BlockSpec((1, 1, w // d, bq), lambda i, g, j: (i, g, 0, j))
+    lse_shape = jax.ShapeDtypeStruct((b, e // w, w // d, sq), jnp.float32)
+    return grid, q_spec, kv_spec, lse_spec, lse_shape
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("d", "scale", "causal", "interpret"))
+def _one_tile_fwd(q, k, v, *, d: int, scale: float, causal: bool,
+                  interpret: bool = False):
+    """q [b, sq, h*d], k, v [b, sk, h*d] -> (out [b, sq, h*d],
+    lse [b, h*d/w, w/d, sq] f32)."""
+    grid, q_spec, kv_spec, lse_spec, lse_shape = _one_tile_specs(q, k, d)
+    return pl.pallas_call(
+        functools.partial(_one_tile_fwd_kernel, d=d, scale=scale,
+                          causal=causal),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), lse_shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_ONE_TILE_VMEM_BYTES),
+        interpret=interpret,
+        name="one_tile_attention_fwd",
+    )(q, k, v)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("d", "scale", "causal", "interpret"))
+def _one_tile_bwd(q, k, v, out, lse, dout, *, d: int, scale: float,
+                  causal: bool, interpret: bool = False):
+    """-> (dq, dk, dv), one kernel; dk and dv accumulate over the query
+    blocks where sq needs more than one."""
+    grid, q_spec, kv_spec, lse_spec, _ = _one_tile_specs(q, k, d)
+    num_q = grid[2]
+    sk, w = kv_spec.block_shape[1:]
+    return pl.pallas_call(
+        functools.partial(_one_tile_bwd_kernel, d=d, scale=scale,
+                          causal=causal, num_q=num_q),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((sk, w), jnp.float32)] * 2
+        if num_q > 1 else [],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_ONE_TILE_VMEM_BYTES),
+        interpret=interpret,
+        name="one_tile_attention_bwd",
+    )(q, k, v, out, dout, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def one_tile_attention(q, k, v, d: int, scale: float, causal: bool):
+    """q [b, sq, h*d], k, v [b, sk, h*d] -> [b, sq, h*d]; heads of
+    width d side by side on the last dim, as the projections leave
+    them.  Pallas only: `flash_mha` picks it on the TPU."""
+    return _one_tile_fwd(q, k, v, d=d, scale=scale, causal=causal)[0]
+
+
+def _one_tile_vjp_fwd(q, k, v, d, scale, causal):
+    out, lse = _one_tile_fwd(q, k, v, d=d, scale=scale, causal=causal)
+    return out, (q, k, v, out, lse)
+
+
+def _one_tile_vjp_bwd(d, scale, causal, res, dout):
+    return _one_tile_bwd(*res, dout, d=d, scale=scale, causal=causal)
+
+
+one_tile_attention.defvjp(_one_tile_vjp_fwd, _one_tile_vjp_bwd)
+
+
+def flash_mha(qh, kh, vh, scale: float, causal: bool):
+    """[b, s, h, d] -> [b, sq, h, d]: the attention core without a
+    [b, h, s, s] tensor in HBM, tiled by `pick_tiling`."""
+    b, sq, h, d = qh.shape
+    sk = kh.shape[1]
+    if (pick_tiling(sk, d) == "one_tile"
+            and _one_tile_supported(qh, kh, vh)):
+        o = one_tile_attention(
+            qh.reshape(b, sq, h * d), kh.reshape(b, sk, h * d),
+            vh.reshape(b, sk, h * d), d, scale, causal)
+        return o.reshape(b, sq, h, d)
+    return mha_flash(qh, kh, vh, scale, causal)

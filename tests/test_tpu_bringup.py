@@ -62,7 +62,31 @@ def _flash_cases():
             dkv_blocks=fa._pick_blocks("dkv", seq, seq))
 
     return [("flash_fwd", fwd, (x, x, x), 1),
-            ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)]
+            ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)] + _one_tile_cases()
+
+
+def _one_tile_cases():
+    """The short-row tiling at BERT-large's seq-512 step (the training
+    cells' shapes: 16 heads of 64, two a 128-lane block) and at its
+    longest row in float32, causal, where the tile is largest."""
+    cases = []
+    for name, b, s, h, d, dtype, causal in (
+            ("cell", 8, 512, 16, 64, jnp.bfloat16, False),
+            ("longest", 2, fa.ONE_TILE_MAX_KV, 4, 128, jnp.float32, True)):
+        x = jax.ShapeDtypeStruct((b, s, h * d), dtype)
+        w = max(d, 128)
+        lse = jax.ShapeDtypeStruct((b, h * d // w, w // d, s), jnp.float32)
+        kw = dict(d=d, scale=float(d) ** -0.5, causal=causal)
+
+        def fwd(q, k, v, kw=kw):
+            return fa._one_tile_fwd(q, k, v, **kw)
+
+        def bwd(q, k, v, o, lse, do, kw=kw):
+            return fa._one_tile_bwd(q, k, v, o, lse, do, **kw)
+
+        cases += [(f"one_tile_fwd_{name}", fwd, (x, x, x), 1),
+                  (f"one_tile_bwd_{name}", bwd, (x, x, x, x, lse, x), 1)]
+    return cases
 
 
 #: benchmarks/configs/gpt2-medium-serve.json as its cell runs it: 16
