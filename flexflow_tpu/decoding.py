@@ -62,7 +62,8 @@ def decoder_recipe(ff: FFModel) -> DecoderRecipe:
         raise ValueError(
             "a decode twin needs a model built by a models/ builder that "
             "records its recipe (models.transformer.build_gpt, "
-            "models.kimi_k2.build_kimi_k2)")
+            "models.kimi_k2.build_kimi_k2, "
+            "models.qwen3_next.build_qwen3_next)")
     return recipe
 
 
@@ -91,6 +92,17 @@ def cache_entries(ff: FFModel) -> Dict[str, tuple]:
     latent pool is found like a k/v pool."""
     return {op.name: op.cache_entries()
             for op in ff.operators.topo_order() if op.cache_entries()}
+
+
+def slot_state_entries(ff: FFModel) -> Dict[str, tuple]:
+    """{op name: its state entries that are per slot and fixed size}
+    (`Op.slot_state_entries`): a recurrent layer's state, allocated
+    `[slots, ...]` beside the pools.  THE predicate for "the scheduler
+    zeroes this at admission" (`build_slot_state_reset`); disjoint from
+    `cache_entries`, so nothing that handles pages touches it.  A twin
+    that has any also takes `row_tokens` in its step programs."""
+    return {op.name: op.slot_state_entries()
+            for op in ff.operators.topo_order() if op.slot_state_entries()}
 
 
 def gpt_decode_tp_strategy(tp: int, num_layers: int):
@@ -544,27 +556,32 @@ def run_generate_scan(ffd: FFModel, prompt_pad: np.ndarray,
     return out
 
 
-def _host_owned(state, block_table, seq_lens):
+def _host_owned(state, block_table, seq_lens, row_tokens=None):
     """The state pytree with every paged op's host-owned entries
     (`block_table`, `seq_lens`) replaced by the dispatch's own: inside
     the trace, so the per-step override costs nothing at run time and
-    the host never rebuilds the state dict."""
-    return {
-        op: {
-            k: (block_table if k == "block_table"
-                else seq_lens if k == "seq_lens" else v)
-            for k, v in entries.items()
-        }
-        for op, entries in state.items()
-    }
+    the host never rebuilds the state dict.  `row_tokens` (a twin with
+    per-slot recurrent state: `slot_state_entries`) is how many of the
+    step's tokens each row really advances by, replaced likewise."""
+    owned = {"block_table": block_table, "seq_lens": seq_lens}
+    if row_tokens is not None:
+        owned["row_tokens"] = row_tokens
+    return {op: {k: owned.get(k, v) for k, v in entries.items()}
+            for op, entries in state.items()}
 
 
 def build_paged_decode_step(ffd: FFModel):
     """ONE compiled step function for continuous batching on a paged
     decode twin (make_decoder with kv_page_size > 0):
 
-        step(weights, state, tokens[b], positions[b], block_table)
+        step(weights, state, tokens[b], positions[b], block_table
+             [, row_tokens[b]])
             -> (logits [b, vocab], new_state)
+
+    `row_tokens` is passed by the engine of a twin with per-slot
+    recurrent state only (1 for a live row, 0 for an idle slot, whose
+    state then stays as it is); left out, the program is the one it
+    always was.
 
     Unlike the full-generation scan (whose program is keyed by total
     length), the continuous scheduler steps every in-flight sequence by
@@ -587,8 +604,9 @@ def build_paged_decode_step(ffd: FFModel):
 
     ex = ffd.executor
 
-    def step(weights, state, tokens, positions, block_table):
-        state = _host_owned(state, block_table, positions)
+    def step(weights, state, tokens, positions, block_table,
+             row_tokens=None):
+        state = _host_owned(state, block_table, positions, row_tokens)
         logits, new_state, _, _ = ex.run_forward(
             weights, state,
             {"input": tokens[:, None],
@@ -689,7 +707,9 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     one); such a family's outputs are held to its reference by
     tolerance, and it carries neither `speculative` nor `handoff`.
     Without logits XLA drops the last layer's attention read, experts
-    and the head."""
+    and the head.  A twin with per-slot recurrent state is also passed
+    `row_tokens[b]`: the tokens of the chunk a row really has (0 for a
+    rider), which is as far as its state advances."""
     import jax
     import jax.numpy as jnp
 
@@ -699,9 +719,10 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     ex = ffd.executor
     max_seq = _gpt_dims(ffd)["max_seq"]
 
-    def prefill(weights, state, tokens, positions, block_table):
+    def prefill(weights, state, tokens, positions, block_table,
+                row_tokens=None):
         positions = positions.astype(jnp.int32)
-        state = _host_owned(state, block_table, positions)
+        state = _host_owned(state, block_table, positions, row_tokens)
         grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
         _, new_state, _, _ = ex.run_forward(
             weights, state,
@@ -816,6 +837,33 @@ def build_paged_chunk_step(ffd: FFModel):
 
     with ex.mesh:
         return jax.jit(step, donate_argnums=(1,))
+
+
+def build_slot_state_reset(ffd: FFModel):
+    """Compiled reset of ONE slot's recurrent state:
+
+        reset(state, slot) -> new_state
+
+    zeroes row `slot` of every `slot_state_entries` array (scalar int32
+    id; state donated, so on TPU it is an in-place write).  The
+    scheduler runs it when it gives the slot to a request, so a slot's
+    second request starts where a fresh server's first does."""
+    import jax
+
+    ex = ffd.executor
+    mine = slot_state_entries(ffd)
+
+    def reset(state, slot):
+        return {
+            op: {
+                k: (v.at[slot].set(0) if k in mine.get(op, ()) else v)
+                for k, v in entries.items()
+            }
+            for op, entries in state.items()
+        }
+
+    with ex.mesh:
+        return jax.jit(reset, donate_argnums=(0,))
 
 
 def build_paged_copy_block(ffd: FFModel):

@@ -475,7 +475,11 @@ class GraphExecutor:
                 if op.guid in self._block_guids:
                     continue
                 nt = _num_trainable(op)
-                caches = op.cache_entries()
+                # cached keys/values/latents, and per-slot recurrent
+                # state that the op does not keep in float32
+                caches = op.cache_entries() + tuple(
+                    n for n in op.slot_state_entries()
+                    if n not in op.float32_weights)
                 for i, (spec, pt) in enumerate(zip(op.weight_specs, op.weights)):
                     key, sub = jax.random.split(key)
                     if state_only and i < nt:

@@ -111,6 +111,7 @@ class OperatorType(enum.Enum):
     GATED_MLP = "gated_mlp"
     MLA_ATTENTION = "mla_attention"
     ROUTED_EXPERTS = "routed_experts"
+    GATED_DELTA_NET = "gated_delta_net"
     # Elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"

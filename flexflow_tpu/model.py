@@ -330,10 +330,14 @@ class FFModel:
         kv_page_size: int = 0,
         kv_num_blocks: int = 0,
         kv_kernel: str = "gather",
+        **grouped_rotary_gated,
     ) -> ParallelTensor:
+        """`grouped_rotary_gated`: the further fields of
+        `MultiHeadAttentionParams` by name (`num_kv_heads`, `qk_norm`,
+        `rotary_dim`, `output_gate`, ...), all off by default."""
         p = MultiHeadAttentionParams(
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
-            add_zero_attn, causal,
+            add_zero_attn, causal, **grouped_rotary_gated,
         )
         return self._add(
             MultiHeadAttention(p, [query, key, value],
@@ -357,6 +361,17 @@ class FFModel:
             name=self._name("mla_attention", name),
             decode_max_seq=decode_max_seq, kv_page_size=kv_page_size,
             kv_num_blocks=kv_num_blocks, kv_kernel=kv_kernel))
+
+    def gated_delta_net(self, input, params, name=None,
+                        slot_state: bool = False):
+        """A Gated-DeltaNet mixer (ops/gated_delta_net.py): `params` is
+        a `GatedDeltaNetParams`; `slot_state` builds the serving twin's
+        op, which carries a conv tail and a delta-rule matrix a slot."""
+        from .ops.gated_delta_net import GatedDeltaNet
+
+        return self._add(GatedDeltaNet(
+            params, [input], name=self._name("gated_delta_net", name),
+            slot_state=slot_state))
 
     def gated_mlp(self, input, intermediate_size: int, name=None):
         from .ops.dense import GatedMLP, GatedMLPParams
@@ -488,10 +503,11 @@ class FFModel:
         p = LayerNormParams(tuple(axes), elementwise_affine, eps)
         return self._add(LayerNorm(p, [input], name=self._name("layer_norm", name)))
 
-    def rms_norm(self, input, eps: float = 1e-5, name=None):
+    def rms_norm(self, input, eps: float = 1e-5, name=None,
+                 zero_centered: bool = False):
         from .ops.norm import RMSNorm, RMSNormParams
 
-        return self._add(RMSNorm(RMSNormParams(eps), [input],
+        return self._add(RMSNorm(RMSNormParams(eps, zero_centered), [input],
                                  name=self._name("rms_norm", name)))
 
     def batch_norm(self, input, relu: bool = True, eps: float = 1e-5,

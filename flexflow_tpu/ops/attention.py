@@ -48,6 +48,30 @@ class MultiHeadAttentionParams:
     add_bias_kv: bool = False
     add_zero_attn: bool = False
     causal: bool = False
+    # Everything below is off by default (GPT and BERT build none of it).
+    # Grouped-query heads: `num_kv_heads` key/value heads (0 -> num_heads),
+    # each shared by num_heads / num_kv_heads query heads; the caches
+    # and the paged pool hold the key/value heads only.
+    num_kv_heads: int = 0
+    # RMS norm of every query and key head over its channels, one gain
+    # per channel (`q_norm`, `k_norm`), before the rotary embedding
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    norm_zero_centered: bool = False  # gains applied as 1 + g
+    # rotary embedding on the first `rotary_dim` channels of every query
+    # and key head (first half rotated against second half), positions
+    # from the op's own mode: 0..s-1 with no cache, the cache position
+    # on, or each row's `seq_lens` on
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    # `wq` also projects a gate per head ([q | gate], k_channels +
+    # v_channels wide): out = wo (attn * sigmoid(gate))
+    output_gate: bool = False
+    # paged twin, a step of s > 1 tokens: write the chunk, gather the
+    # row's view ONCE and attend with a [s, n] mask (what a family
+    # that carries `prefill_pass` builds), instead of once a position
+    # at the decode step's shapes (GPT's byte equality)
+    paged_read_once: bool = False
 
     @property
     def k_channels(self) -> int:
@@ -56,6 +80,33 @@ class MultiHeadAttentionParams:
     @property
     def v_channels(self) -> int:
         return (self.vdim or self.embed_dim) // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def group(self) -> int:
+        """Query heads a key/value head serves."""
+        return self.num_heads // self.kv_heads
+
+
+def rotate_half(x, positions, rotary_dim: int, theta: float):
+    """Rotary embedding on the first `rotary_dim` channels of
+    x [b, s, heads, d] at positions [b, s]: channel i of the first half
+    against channel i of the second half, angle `pos * theta^(-2i /
+    rotary_dim)`, computed in float32; the other channels pass."""
+    half = rotary_dim // 2
+    freq = theta ** (-np.arange(0, rotary_dim, 2, dtype=np.float64)
+                     / rotary_dim)
+    angle = (positions.astype(jnp.float32)[..., None, None]
+             * jnp.asarray(freq, jnp.float32))  # [b, s, 1, half]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rotary_dim]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, xf[..., rotary_dim:]],
+        axis=-1).astype(x.dtype)
 
 
 class MultiHeadAttention(Op):
@@ -91,6 +142,27 @@ class MultiHeadAttention(Op):
         if p.num_heads % self.shard.channel != 0:
             raise ShapeError(f"{self.name}: heads {p.num_heads} not divisible by "
                              f"degree {self.shard.channel}")
+        if p.num_heads % p.kv_heads or p.kv_heads % self.shard.channel:
+            raise ShapeError(
+                f"{self.name}: {p.kv_heads} key/value heads must divide "
+                f"{p.num_heads} query heads and be divisible by degree "
+                f"{self.shard.channel}")
+        if p.rotary_dim % 2 or p.rotary_dim > p.k_channels:
+            raise ShapeError(
+                f"{self.name}: rotary_dim {p.rotary_dim} must be even and "
+                f"at most the head's {p.k_channels} channels")
+        if p.group > 1:
+            if p.add_bias_kv or p.add_zero_attn:
+                raise ShapeError(f"{self.name}: kv-append options "
+                                 "unsupported with grouped-query heads")
+            if self._decode_n() and not (self._paged() and p.paged_read_once
+                                         and self._kv_kernel == "gather"):
+                raise ShapeError(
+                    f"{self.name}: grouped-query heads are cached in the "
+                    "paged pool and read by one gather a step "
+                    "(kv_page_size > 0, paged_read_once, kv_kernel "
+                    "'gather'); the dense cache, the per-position read "
+                    "and the Pallas read keep one head count")
         if qd[1].degree != 1 or kd[1].degree != 1 or vd[1].degree != 1:
             # Seq partitioning lowers to ring attention — legal only
             # when q/k/v share one seq sharding (self-attention SP).
@@ -171,6 +243,8 @@ class MultiHeadAttention(Op):
             n += 4
         if p.add_bias_kv:
             n += 2
+        if p.qk_norm:
+            n += 2
         return n
 
     def make_weight_specs(self, input_shapes):
@@ -191,10 +265,11 @@ class MultiHeadAttention(Op):
 
         embed = p.embed_dim
         init = GlorotUniform(fan_in=embed, fan_out=embed)
+        q_width = p.k_channels + (p.v_channels if p.output_gate else 0)
         specs = [
-            WeightSpec("wq", w((embed, p.num_heads, p.k_channels), 1), init),
-            WeightSpec("wk", w((k.logical_shape[-1], p.num_heads, p.k_channels), 1), init),
-            WeightSpec("wv", w((v.logical_shape[-1], p.num_heads, p.v_channels), 1), init),
+            WeightSpec("wq", w((embed, p.num_heads, q_width), 1), init),
+            WeightSpec("wk", w((k.logical_shape[-1], p.kv_heads, p.k_channels), 1), init),
+            WeightSpec("wv", w((v.logical_shape[-1], p.kv_heads, p.v_channels), 1), init),
             WeightSpec("wo", w((p.num_heads, p.v_channels, embed), 0), init),
         ]
         from ..initializer import ZeroInitializer
@@ -212,6 +287,14 @@ class MultiHeadAttention(Op):
             specs += [
                 WeightSpec("bias_k", w((1, p.num_heads, p.k_channels), 1), init),
                 WeightSpec("bias_v", w((1, p.num_heads, p.v_channels), 1), init),
+            ]
+        if p.qk_norm:
+            from ..initializer import ConstantInitializer
+
+            ident = ConstantInitializer(0.0 if p.norm_zero_centered else 1.0)
+            specs += [
+                WeightSpec("q_norm", w((p.k_channels,), None), ident),
+                WeightSpec("k_norm", w((p.k_channels,), None), ident),
             ]
         n = self._decode_n()
         if n > 0:
@@ -232,7 +315,7 @@ class MultiHeadAttention(Op):
                 dims = (
                     ParallelDim(qd[0].size, qd[0].degree),
                     ParallelDim(n),
-                    ParallelDim(p.num_heads, c),
+                    ParallelDim(p.kv_heads, c),
                     ParallelDim(d_head),
                     ParallelDim(1, q.replica_degree, is_replica_dim=True),
                 )
@@ -301,7 +384,7 @@ class MultiHeadAttention(Op):
             # sharding.
             dims = (
                 ParallelDim(nb), ParallelDim(page),
-                ParallelDim(p.num_heads, self.shard.channel),
+                ParallelDim(p.kv_heads, self.shard.channel),
                 ParallelDim(d_head),
                 ParallelDim(1, 1, is_replica_dim=True),
             )
@@ -346,12 +429,29 @@ class MultiHeadAttention(Op):
             dv = vh.shape[-1]
             kh = jnp.concatenate([kh, jnp.zeros((bsz, 1, h, dk), kh.dtype)], axis=1)
             vh = jnp.concatenate([vh, jnp.zeros((bsz, 1, h, dv), vh.dtype)], axis=1)
+        gate = None
+        if p.output_gate:
+            qh, gate = qh[..., :p.k_channels], qh[..., p.k_channels:]
+        if p.qk_norm:
+            from .norm import rms_normalize
+
+            q_norm, k_norm = weights[wi : wi + 2]
+            qh = rms_normalize(qh, q_norm, p.norm_eps, p.norm_zero_centered)
+            kh = rms_normalize(kh, k_norm, p.norm_eps, p.norm_zero_centered)
+        if p.rotary_dim:
+            positions = self._positions(q.shape[0], q.shape[1], weights)
+            qh = rotate_half(qh, positions, p.rotary_dim, p.rope_theta)
+            kh = rotate_half(kh, positions, p.rotary_dim, p.rope_theta)
         scale = 1.0 / np.sqrt(p.k_channels)
         if self._paged():
             k_cache, v_cache, btab, slen = weights[-4:]
-            ctx, k_cache, v_cache = self._attend_decode_paged(
+            attend = (self._attend_decode_paged_once if p.paged_read_once
+                      else self._attend_decode_paged)
+            ctx, k_cache, v_cache = attend(
                 qh, kh, vh, k_cache, v_cache, btab, slen, scale
             )
+            if gate is not None:
+                ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
             out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
             if bo is not None:
                 out = out + bo[None, None]
@@ -361,15 +461,35 @@ class MultiHeadAttention(Op):
             ctx, k_cache, v_cache, pos = self._attend_decode(
                 qh, kh, vh, k_cache, v_cache, pos, scale
             )
+            if gate is not None:
+                ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
             out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
             if bo is not None:
                 out = out + bo[None, None]
             return [out.astype(q.dtype), k_cache, v_cache, pos]
+        if p.group > 1:
+            # no cache: every query head gets its key/value head's copy
+            kh = jnp.repeat(kh, p.group, axis=2)
+            vh = jnp.repeat(vh, p.group, axis=2)
         ctx = self._attend(qh, kh, vh, scale, training=training, rng=rng)
+        if gate is not None:
+            ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
         out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
         if bo is not None:
             out = out + bo[None, None]
         return [out.astype(q.dtype)]
+
+    def _positions(self, b, s, weights):
+        """[b, s] positions of the step's tokens, for the rotary
+        embedding: each row's `seq_lens` on (paged), the cache position
+        on (dense cache), 0..s-1 (no cache)."""
+        steps = jnp.arange(s, dtype=jnp.int32)[None, :]
+        if self._paged():
+            return weights[-1].reshape(b, 1).astype(jnp.int32) + steps
+        if self._decode_n() > 0:
+            return jnp.broadcast_to(
+                weights[-1].reshape(1, 1).astype(jnp.int32) + steps, (b, s))
+        return jnp.broadcast_to(steps, (b, s))
 
     def _attend_decode(self, qh, kh, vh, k_cache, v_cache, pos, scale):
         """Incremental attention: append this step's k/v at position
@@ -489,6 +609,47 @@ class MultiHeadAttention(Op):
                 "bhqk,bkhd->bqhd", probs, kv_v.astype(qh.dtype)))
         ctx = ctxs[0] if s == 1 else jnp.concatenate(ctxs, axis=1)
         return ctx, k_cache, v_cache
+
+    def _attend_decode_paged_once(self, qh, kh, vh, k_cache, v_cache,
+                                  btab, slen, scale):
+        """The gather read with ONE view a step, for any step length and
+        any query-head group (`paged_read_once`): row i's token j is
+        written at `slen[i] + j`, ALL s before the read (a later chunk
+        position lands on a key the earlier queries' masks exclude: the
+        argument `_attend_decode_paged_kernel` makes), each pool is
+        gathered once into `[b, n, kv_heads, d]`, and query j of the
+        `group` query heads that share a key/value head attends
+        `key_pos <= slen[i] + j`.  The pad contract of a chunked prefill
+        is kept explicitly, as `ops/mla.py _attend_paged_chunk` keeps
+        it: a position `>= n` is written to scratch block 0 at a
+        position clamped in range; a rider (all-zero table row) writes
+        scratch only.  Equal to seq-1 stepping by tolerance, not by
+        bytes."""
+        p: MultiHeadAttentionParams = self.params
+        b, s = qh.shape[:2]
+        page = self._kv_page_size
+        n = btab.shape[1] * page
+        pos = (slen.reshape(b, 1).astype(jnp.int32)
+               + jnp.arange(s, dtype=jnp.int32))  # [b, s]
+        at = jnp.minimum(pos, n - 1)
+        blk = jnp.where(pos < n,
+                        jnp.take_along_axis(btab, at // page, axis=1), 0)
+        k_cache = k_cache.at[blk, at % page].set(kh.astype(k_cache.dtype))
+        v_cache = v_cache.at[blk, at % page].set(vh.astype(v_cache.dtype))
+        kv_k = jnp.take(k_cache, btab, axis=0).reshape(
+            b, n, p.kv_heads, -1).astype(qh.dtype)
+        kv_v = jnp.take(v_cache, btab, axis=0).reshape(
+            b, n, p.kv_heads, -1).astype(qh.dtype)
+        qg = qh.reshape(b, s, p.kv_heads, p.group, -1)
+        scores = jnp.einsum("bskgd,bnkd->bkgsn", qg, kv_k,
+                            preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(n, dtype=jnp.int32)[None, None, :] \
+            <= pos[:, :, None]  # [b, s, n]
+        scores = jnp.where(live[:, None, None], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(qh.dtype)
+        ctx = jnp.einsum("bkgsn,bnkd->bskgd", probs, kv_v)
+        return ctx.reshape(b, s, p.num_heads, -1), k_cache, v_cache
 
     def _attend_decode_paged_kernel(self, qh, kh, vh, k_cache, v_cache,
                                     btab, pos, scale):
@@ -711,7 +872,11 @@ class MultiHeadAttention(Op):
         p: MultiHeadAttentionParams = self.params
         b, s, e = self.inputs[0].shape.logical_shape
         ks = self.inputs[1].shape.logical_shape[1]
-        proj = 2.0 * b * s * e * p.num_heads * p.k_channels * 3
-        proj += 2.0 * b * s * e * p.num_heads * p.v_channels
+        # q (and its gate) and the output per query head, k and v per
+        # key/value head
+        gate = p.v_channels if p.output_gate else 0
+        proj = 2.0 * b * s * e * (
+            p.num_heads * (p.k_channels + gate + p.v_channels)
+            + p.kv_heads * (p.k_channels + p.v_channels))
         attn = 2.0 * b * p.num_heads * s * ks * (p.k_channels + p.v_channels)
         return proj + attn

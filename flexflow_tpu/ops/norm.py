@@ -175,24 +175,30 @@ class Softmax(Op):
         return [jax.nn.softmax(inputs[0], axis=self.params.axis)]
 
 
-def rms_normalize(x, gamma, eps: float):
+def rms_normalize(x, gamma, eps: float, zero_centered: bool = False):
     """x / sqrt(mean(x^2) + eps) * gamma over the last axis, reduced in
     float32 whatever x's dtype (a bf16 mean of 7,168 squares loses the
-    low bits the scale needs), returned in x's dtype."""
+    low bits the scale needs), returned in x's dtype.  `zero_centered`:
+    the stored gain is the gain's distance from 1 (`* (1 + gamma)`)."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + eps)
-    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+    g = gamma.astype(jnp.float32)
+    return (y * (1.0 + g if zero_centered else g)).astype(x.dtype)
 
 
 @dataclasses.dataclass(frozen=True)
 class RMSNormParams:
     eps: float = 1e-5
+    # the gain is stored around zero and applied as 1 + gamma (the
+    # qwen3_next family); False: stored around one and applied as it is
+    zero_centered: bool = False
 
 
 class RMSNorm(Op):
-    """Root-mean-square norm over the last axis, one gain per channel,
-    no bias and no mean subtraction."""
+    """Root-mean-square norm over the last axis, one gain per channel
+    (applied as `gamma`, or as `1 + gamma` where the params say the
+    gain is stored zero-centred), no bias and no mean subtraction."""
 
     op_type = OperatorType.RMS_NORM
 
@@ -207,11 +213,13 @@ class RMSNorm(Op):
         (ishape,) = input_shapes
         dims = (ParallelDim(ishape.logical_shape[-1]),
                 ParallelDim(1, ishape.total_degree, is_replica_dim=True))
+        identity = 0.0 if self.params.zero_centered else 1.0
         return [WeightSpec("gamma", ParallelTensorShape(dims, ishape.dtype),
-                           ConstantInitializer(1.0))]
+                           ConstantInitializer(identity))]
 
     def forward(self, inputs, weights, *, training=False, rng=None):
-        return [rms_normalize(inputs[0], weights[0], self.params.eps)]
+        p: RMSNormParams = self.params
+        return [rms_normalize(inputs[0], weights[0], p.eps, p.zero_centered)]
 
     def flops(self):
         return 4.0 * self.inputs[0].shape.num_elements()

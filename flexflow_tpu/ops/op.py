@@ -147,6 +147,18 @@ class Op:
         block export ask here instead of spelling entry names."""
         return ()
 
+    def slot_state_entries(self) -> Tuple[str, ...]:
+        """Names of this op's state entries that are PER SLOT AND FIXED
+        SIZE (`[slots, ...]`: a recurrent layer's state, not a cache
+        that grows with the sequence).  The serving tier zeroes a
+        slot's rows at admission and frees them with the slot; nothing
+        that walks `cache_entries()` (block bytes, copy-on-write,
+        export, beam reordering) sees them.  They live in the compute
+        dtype unless `float32_weights` names them; the host-owned
+        `row_tokens` entry beside them says how many of a step's tokens
+        each row really advances by."""
+        return ()
+
     def memory_bytes(self) -> int:
         total = sum(t.shape.size_bytes() for t in self.outputs)
         total += sum(w.shape.size_bytes() for w in self.weights)

@@ -3,10 +3,13 @@
 The layer is TOLD WHICH EXPERTS IT HOLDS: `experts_total` is the
 router's published width, `experts_held` of them live here, starting
 at `first_held`.  It routes every token over all `experts_total` in
-float32 (sigmoid scores, the top `top_k` of score + bias, the chosen
-scores normalised over ALL of the chosen and scaled), and adds
+float32 (scores by the params' `scoring` rule: `sigmoid` of each logit,
+or `softmax` over all the logits; the top `top_k` of score + bias, the
+chosen scores normalised over ALL of the chosen and scaled), and adds
 
-    sum over experts chosen AND held here of w_e E_e(h)  +  E_shared(h)
+    sum over experts chosen AND held here of w_e E_e(h)  +  g E_shared(h)
+
+with `g = 1`, or `sigmoid(w_sg . h)` where `shared_expert_gate` is set.
 
 Experts that live on other chips add nothing: on one chip the layer
 runs without its exchange, and nothing stands in for the absent chips.
@@ -49,15 +52,19 @@ class RoutedExpertsParams:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     dtype: DataType = DataType.FLOAT
+    scoring: str = "sigmoid"  # or "softmax", over all experts_total
+    shared_expert_gate: bool = False  # shared expert times sigmoid(w . h)
 
 
 def route(h, router, bias, p: RoutedExpertsParams):
     """h [t, e] -> (chosen expert ids [t, k], their weights [t, k]),
-    all in float32 at full matmul precision: scores are sigmoids, the
+    all in float32 at full matmul precision: scores by `p.scoring`, the
     bias only chooses, the normaliser runs over all k chosen."""
-    scores = jax.nn.sigmoid(jnp.matmul(
+    logits = jnp.matmul(
         h.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, axis=-1) if p.scoring == "softmax"
+              else jax.nn.sigmoid(logits))
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), p.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if p.norm_topk_prob:
@@ -85,10 +92,17 @@ class RoutedExperts(Op):
                 f"{self.name}: held experts [{p.first_held}, "
                 f"{p.first_held + p.experts_held}) and top_k {p.top_k} "
                 f"do not fit {p.experts_total} experts")
+        if p.scoring not in ("sigmoid", "softmax"):
+            raise ShapeError(f"{self.name}: scoring {p.scoring!r} is not "
+                             "'sigmoid' or 'softmax'")
+        if p.shared_expert_gate and not p.shared_hidden:
+            raise ShapeError(f"{self.name}: shared_expert_gate without a "
+                             "shared expert")
         return [ParallelTensorShape(ishape.dims, p.dtype)]
 
     def num_trainable_weights(self) -> int:
-        return 8 if self.params.shared_hidden else 5
+        p: RoutedExpertsParams = self.params
+        return 5 + (3 if p.shared_hidden else 0) + int(p.shared_expert_gate)
 
     def make_weight_specs(self, input_shapes):
         (ishape,) = input_shapes
@@ -115,6 +129,8 @@ class RoutedExperts(Op):
                 WeightSpec("shared_up", w(e, p.shared_hidden), init),
                 WeightSpec("shared_down", w(p.shared_hidden, e), init),
             ]
+        if p.shared_expert_gate:
+            specs.append(WeightSpec("shared_expert_gate", w(e), init))
         return specs + [WeightSpec(
             "moe_stats", w(len(MOE_STATS), dtype=DataType.INT32), zero)]
 
@@ -133,7 +149,13 @@ class RoutedExperts(Op):
         up = jnp.einsum("te,xef->xtf", h, w_up)
         y = jnp.einsum("xtf,xfe->xte", jax.nn.silu(gate) * up, w_down)
         out = jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
-        if p.shared_hidden:
+        if p.shared_expert_gate:
+            g = jax.nn.sigmoid(jnp.einsum(
+                "te,e->t", h, weights[8],
+                preferred_element_type=jnp.float32))
+            out = out + gated_mlp(h, *weights[5:8]) * g[:, None].astype(
+                out.dtype)
+        elif p.shared_hidden:
             out = out + gated_mlp(h, *weights[5:8])
         rows = jnp.sum(landed, axis=(0, 1)).astype(jnp.int32)  # [held]
         pairs = jnp.sum(rows)
@@ -146,8 +168,12 @@ class RoutedExperts(Op):
         return [out.reshape(x.shape).astype(x.dtype), stats]
 
     def flops(self):
+        """The router's product (either scoring rule is a few
+        operations a logit on top), every held expert over every row,
+        the shared expert and its gate's dot product."""
         p: RoutedExpertsParams = self.params
         t = self.inputs[0].shape.num_elements()  # rows x e
         return t * (2.0 * p.experts_total
                     + 6.0 * p.experts_held * p.expert_hidden
-                    + 6.0 * p.shared_hidden)
+                    + 6.0 * p.shared_hidden
+                    + 2.0 * int(p.shared_expert_gate))

@@ -102,6 +102,11 @@ class SupervisedDecodeModel:
         if not self._has_verify:
             self.spec_decode = "off"
         self._has_copy = getattr(model, "copy_block", None) is not None
+        # per-slot recurrent state: the scheduler then passes
+        # `row_tokens` to both step programs and zeroes a slot's state
+        # at admission (`reset_slot_state`)
+        self.has_slot_state = bool(getattr(model, "has_slot_state", False))
+        self.rstate_bytes = getattr(model, "rstate_bytes", 0)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)
@@ -117,12 +122,25 @@ class SupervisedDecodeModel:
         # the model has no such layer)
         return getattr(self._model, "moe_last", None)
 
-    def step(self, tokens, seq_lens, block_tables):
+    def reset_slot_state(self, slot):
+        # a device dispatch like copy_block: same fault plan and
+        # watchdog, so a wedged reset surfaces as a hung step
         idx = next(self._steps)
         try:
             self._fault_plan.check_step(idx)
             return self._watchdog.sync(
-                lambda: self._model.step(tokens, seq_lens, block_tables),
+                lambda: self._model.reset_slot_state(slot), step=idx)
+        except FATAL_DECODE_FAULTS as e:
+            e.fatal_to_engine = True
+            raise
+
+    def step(self, tokens, seq_lens, block_tables, *row_tokens):
+        idx = next(self._steps)
+        try:
+            self._fault_plan.check_step(idx)
+            return self._watchdog.sync(
+                lambda: self._model.step(tokens, seq_lens, block_tables,
+                                         *row_tokens),
                 step=idx,
             )
         except FATAL_DECODE_FAULTS as e:
@@ -130,7 +148,7 @@ class SupervisedDecodeModel:
             e.fatal_to_engine = True
             raise
 
-    def prefill_step(self, tokens, positions, block_tables):
+    def prefill_step(self, tokens, positions, block_tables, *row_tokens):
         # chunked prefill is a decode-fleet step like any other: fault
         # injection and the hang watchdog see it under the same
         # replica-lifetime step index
@@ -139,7 +157,7 @@ class SupervisedDecodeModel:
             self._fault_plan.check_step(idx)
             return self._watchdog.sync(
                 lambda: self._model.prefill_step(
-                    tokens, positions, block_tables),
+                    tokens, positions, block_tables, *row_tokens),
                 step=idx,
             )
         except FATAL_DECODE_FAULTS as e:
@@ -575,6 +593,11 @@ class ServingReplica:
             # fullest expert's rows, experts hit, over decode dispatches
             if "moe" in sstats:
                 out["moe"] = sstats["moe"]
+            # per-slot recurrent state: rows advanced against rows the
+            # programs read and wrote, over decode and prefill
+            # dispatches, and the state's bytes
+            if "rstate" in sstats:
+                out["rstate"] = sstats["rstate"]
         return out
 
     def close(self, timeout_s: Optional[float] = None) -> None:

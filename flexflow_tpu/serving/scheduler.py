@@ -155,9 +155,10 @@ class PagedKVDecodeModel:
                                 build_paged_decode_step,
                                 build_paged_prefill_pass,
                                 build_paged_prefill_step,
-                                build_paged_verify_step, cache_entries,
+                                build_paged_verify_step,
+                                build_slot_state_reset, cache_entries,
                                 decoder_recipe, make_decoder,
-                                require_carried)
+                                require_carried, slot_state_entries)
         from ..ops.pallas.paged_attention import have_paged_kernel
 
         recipe = decoder_recipe(ff_train)
@@ -171,6 +172,14 @@ class PagedKVDecodeModel:
         if self.spec_decode != "off":
             require_carried(ff_train, "speculative",
                             f"--spec-decode {self.spec_decode}")
+        if prefix_cache:
+            # FFConfig.prefix_cache defaults to True: a family whose
+            # per-sequence state is more than pages (a page hit without
+            # the recurrent state at that position is wrong) has to be
+            # served with it off, said by name
+            require_carried(ff_train, "prefix_cache",
+                            "prefix_cache=True; pass prefix_cache=False "
+                            "/ --no-prefix-cache")
         # tensor-parallel replica degree (docs/SERVING.md
         # "Tensor-parallel replicas"): the decode twin compiles over a
         # tp-chip {"data": 1, "model": tp} mesh, heads + KV pools
@@ -284,6 +293,16 @@ class PagedKVDecodeModel:
         self._moe_ops = [op for op, entries in self._state.items()
                          if "moe_stats" in entries]
         self.moe_last: Optional[Dict[str, int]] = None
+        # per-slot recurrent state (`slot_state_entries`): [slots, ...]
+        # arrays beside the pools, of fixed size; a twin that has any
+        # takes `row_tokens` in its step programs, and the scheduler
+        # zeroes a slot's rows when it admits a request into it
+        self._slot_state = slot_state_entries(self.ffd)
+        self.rstate_bytes = sum(
+            int(self._state[op][k].nbytes)
+            for op, names in self._slot_state.items() for k in names)
+        self._reset_slot_fn = (build_slot_state_reset(self.ffd)
+                               if self._slot_state else None)
         self.mesh_shape = {
             str(k): int(s)
             for k, s in zip(self.ffd.mesh.axis_names,
@@ -307,15 +326,39 @@ class PagedKVDecodeModel:
                 jnp.zeros(x.shape, x.dtype), x.sharding),
             self.ffd._state)
 
+    @property
+    def has_slot_state(self) -> bool:
+        return bool(self._slot_state)
+
+    def _row_tokens(self, row_tokens) -> tuple:
+        """The step programs' trailing argument: `row_tokens` for a
+        twin with per-slot state (required there), nothing otherwise."""
+        if not self._slot_state:
+            return ()
+        if row_tokens is None:
+            raise ValueError(
+                "this twin carries per-slot recurrent state: its step "
+                "programs need row_tokens (how far each row advances)")
+        return (np.asarray(row_tokens, np.int32),)
+
+    def reset_slot_state(self, slot: int) -> None:
+        """Zero ONE slot's recurrent state (admission): ordered with
+        the step stream by jax's state dependency, like copy_block."""
+        import jax.numpy as jnp
+
+        with span("model.enqueue",
+                  first=self._first_call("reset_slot_state")):
+            self._state = self._reset_slot_fn(self._state, jnp.int32(slot))
+
     def step(self, tokens: np.ndarray, seq_lens: np.ndarray,
-             block_tables: np.ndarray) -> np.ndarray:
+             block_tables: np.ndarray, row_tokens=None) -> np.ndarray:
         # per-token hot path: the block table / seq_lens override
         # happens INSIDE the jitted step and the state pytree is
         # donated — no host-side dict rebuild, no per-layer pool copy
         with span("model.enqueue", first=self._first_call("step")):
             logits, self._state = self._step_fn(
                 self.ffd._weights, self._state, tokens, seq_lens,
-                block_tables,
+                block_tables, *self._row_tokens(row_tokens),
             )
         # the wait for the device, then the logits' copy to the host
         with span("model.fetch"):
@@ -338,13 +381,13 @@ class PagedKVDecodeModel:
         return 1
 
     def prefill_step(self, tokens: np.ndarray, positions: np.ndarray,
-                     block_tables: np.ndarray) -> None:
+                     block_tables: np.ndarray, row_tokens=None) -> None:
         """Chunked prefill: scatter tokens[b, C] at positions[b]..+C-1
         into the pool.  No logits come back — prefill ignores them."""
         with span("model.enqueue", first=self._first_call("prefill")):
             self._state = self._prefill_fn(
                 self.ffd._weights, self._state, tokens, positions,
-                block_tables,
+                block_tables, *self._row_tokens(row_tokens),
             )
 
     def verify_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
@@ -608,6 +651,14 @@ class ContinuousScheduler:
         # routed-expert counters summed over decode dispatches (a model
         # without such layers leaves them None): stats()["moe"]
         self.moe_totals: Optional[Dict[str, int]] = None
+        # per-slot recurrent state (a model without any leaves these
+        # off every span and out of stats()): rows whose state a
+        # dispatch had to advance against rows whose state the program
+        # read and wrote (every slot, under the plain-jnp recurrence)
+        self._rstate = bool(getattr(model, "has_slot_state", False))
+        self.rstate_totals: Optional[Dict[str, int]] = (
+            dict.fromkeys(("rows_live", "rows_touched", "dispatches"), 0)
+            if self._rstate else None)
         # bench/debug: run the pool's full invariant sweep after every
         # scheduler step (the serving_prefix leg's acceptance bar)
         self._check_invariants = bool(check_invariants)
@@ -986,6 +1037,9 @@ class ContinuousScheduler:
             "latency": self.latency_stats(),
             **({"moe": dict(self.moe_totals)}
                if self.moe_totals is not None else {}),
+            **({"rstate": dict(self.rstate_totals,
+                               bytes=int(self.model.rstate_bytes))}
+               if self.rstate_totals is not None else {}),
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1230,6 +1284,9 @@ class ContinuousScheduler:
                                         hit, plen)
             slot = free.pop(0)
             self._slots[slot] = live
+            if self._rstate:
+                # the slot's last tenant left its state behind
+                self.model.reset_slot_state(slot)
             # first private block (or a no-op after a full hit):
             # allocate-on-admit
             self.pool.extend(sid, start + 1, written=start)
@@ -1388,6 +1445,19 @@ class ContinuousScheduler:
                                    else dense),
                 "kv_blocks_dense": dense, "kv_blocks_live": live}
 
+    def _note_rstate(self, dispatch, rows_live: int) -> None:
+        """The `rstate_rows_*` args of a dispatch span and their sums:
+        rows whose recurrent state the pass had to advance, and rows
+        whose state the program read and wrote (all of them: the
+        recurrence runs over every slot and masks the rest)."""
+        touched = self.model.batch_slots
+        dispatch.set(rstate_rows_live=rows_live,
+                     rstate_rows_touched=touched)
+        t = self.rstate_totals
+        t["rows_live"] += rows_live
+        t["rows_touched"] += touched
+        t["dispatches"] += 1
+
     def _note_kernel_reads(self, reads: Dict) -> None:
         """Sum one completed dispatch's `_kv_reads` into the fused
         kernel's counters (stats()["paged_kernel"], obs:
@@ -1424,6 +1494,7 @@ class ContinuousScheduler:
         with span("sched.prefill.prepare"):
             tok = np.zeros((self.model.batch_slots, C), np.int32)
             slen = np.zeros(self.model.batch_slots, np.int32)
+            fed = np.zeros(self.model.batch_slots, np.int32)
             btab = np.zeros_like(self._btab)
             plan = []
             real = 0  # prompt tokens this dispatch really advances
@@ -1434,6 +1505,7 @@ class ContinuousScheduler:
                 self._btab[i] = self.pool.table_row(live.seq_id)
                 tok[i, :upto - live.pos] = live.feed[live.pos:upto]
                 slen[i] = live.pos
+                fed[i] = upto - live.pos
                 btab[i] = self._btab[i]
                 plan.append((i, live, upto))
                 real += upto - live.pos
@@ -1441,7 +1513,11 @@ class ContinuousScheduler:
             with span("sched.prefill.dispatch", rows=len(plan),
                       tokens=real, passes=passes,
                       capacity=self.model.batch_slots * C) as dispatch:
-                self.model.prefill_step(tok, slen, btab)
+                # (recurrent state: riders advance by 0 tokens)
+                self.model.prefill_step(
+                    tok, slen, btab, *((fed,) if self._rstate else ()))
+                if self._rstate:
+                    self._note_rstate(dispatch, len(plan))
                 # (the program is enqueued: this runs beside it) a plan
                 # row's prefix is read once a pass: by the scan at each
                 # of its C positions, by the one-pass program once, up
@@ -1777,8 +1853,13 @@ class ContinuousScheduler:
                     self._slens,
                     [live is not None for live in self._slots])
                 dispatch.set(**reads)
+                alive = ()  # recurrent state: which rows advance
+                if self._rstate:
+                    alive = (np.array([live is not None
+                                       for live in self._slots], np.int32),)
+                    self._note_rstate(dispatch, int(alive[0].sum()))
                 logits = self.model.step(
-                    self._tokens, self._slens, self._btab)
+                    self._tokens, self._slens, self._btab, *alive)
                 moe = getattr(self.model, "moe_last", None)
                 if moe is not None:
                     dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})
