@@ -7,9 +7,10 @@ calls, at the full published width and depth of models the repo ships:
   A  trainer, one chip: BERT-base from token ids (12 L, h 768, 12 heads,
      ffn 3072, vocab 30,522, seq 128, batch 64, bf16) — FFConfig ->
      FFModel -> build_bert -> compile() -> train_step()/fit();
-  B  every Pallas kernel a config flag reaches (flash fwd / dq / dkv,
-     paged decode, paged chunk) compiled by Mosaic (interpret=False),
-     run, and compared with its plain-jnp twin;
+  B  every Pallas kernel the program reaches (flash fwd / dq / dkv,
+     the one-tile attention pair, paged decode, paged chunk, the gated
+     delta rule's recurrence) compiled by Mosaic (interpret=False), run,
+     and compared with its plain-jnp twin;
   C  one BERT-base step at seq 2048, batch 8: the flash kernels inside
      the real jitted step, forward and backward;
   D  a server answering requests: GPT-2-small (12 L, h 768, 12 heads,
@@ -457,6 +458,7 @@ class Smoke:
         info.update(self._kernels_flash())
         info.update(self._kernels_one_tile())
         info.update(self._kernels_paged())
+        info.update(self._kernels_delta_rule())
         return info
 
     def _mosaic(self, lowered, n_calls: int, what: str) -> None:
@@ -591,6 +593,66 @@ class Smoke:
             out(f"B: {name} [{b},{seq},{h}x{d}] {dtype.name} causal: rel "
                 f"err {err:.2e} (tol {tol:.0e})")
             check(err <= tol, f"{name}: rel err {err:.3e} > {tol:.0e}")
+        return info
+
+    def _kernels_delta_rule(self) -> dict:
+        """The gated delta rule's per-slot recurrence (a decode step and
+        a prefill chunk; rows with none, some and all of the step's
+        tokens) against the plain `delta_rule_scan`: float32 both, rows
+        that do not advance equal to their input to the byte."""
+        jax, out = self.jax, self.out
+        import jax.numpy as jnp
+
+        from flexflow_tpu.ops.gated_delta_net import delta_rule_scan
+        from flexflow_tpu.ops.pallas import gated_delta_rule as gdr
+
+        b, h, dk, dv = 8, 4, 128, 128
+        check(gdr.pick_recurrence("tpu", True, dk, dv, 1) == "kernel",
+              "pick_recurrence keeps the scan at 128 x 128 heads")
+
+        def scanned(S, q, k, v, g, beta, count):
+            return delta_rule_scan(S, q, k, v, g, beta)
+
+        def kernel(*args):
+            return gdr.gated_delta_rule(*args, interpret=self.rehearsal)
+
+        info = {}
+        rng = np.random.RandomState(2)
+        c = self.sizes["chunk"]
+        for s, counts in ((1, [1, 0, 1, 0, 1, 1, 0, 1]),
+                          (c, [c, 0, max(1, c // 2), 0, c, 1, 0, c]),
+                          (1, [0] * b)):  # no row advances
+            counts = np.array(counts)
+            real = (np.arange(s)[None, :] < counts[:, None])[..., None]
+            k = rng.randn(b, s, h, dk)
+            k /= np.linalg.norm(k, axis=-1, keepdims=True)
+            q = rng.randn(b, s, h, dk) / dk
+            args = [jnp.asarray(a, jnp.float32) for a in (
+                rng.randn(b, h, dk, dv), q, k, rng.randn(b, s, h, dv),
+                np.where(real, -rng.rand(b, s, h), 0.0),
+                np.where(real, rng.rand(b, s, h), 0.0))]
+            args.append(jnp.asarray(counts, jnp.int32))
+            before = np.asarray(args[0])
+            want_S, want_o = jax.jit(scanned)(*args)
+            jk = jax.jit(kernel)
+            self._mosaic(jk.lower(*args), 1, f"gated_delta_rule s={s}")
+            got_S, got_o = jax.block_until_ready(jk(*args))
+            live = counts > 0
+            for name, got, want in (
+                    ("S", np.asarray(got_S)[live], np.asarray(want_S)[live]),
+                    ("o", np.asarray(got_o)[live],
+                     np.asarray(want_o)[live])):
+                if not live.any():  # no row advances: nothing to compare
+                    continue
+                err = rel_err(got, want)
+                info[f"delta_rule_s{s}_{name}_err"] = float(f"{err:.3e}")
+                out(f"B: gated_delta_rule s={s} {name} [{b},{h},{dk},{dv}]"
+                    f" float32: rel err {err:.2e} (tol 1e-05)")
+                check(err <= 1e-5,
+                      f"gated_delta_rule s={s} {name}: rel err {err:.3e}")
+            check(np.array_equal(np.asarray(got_S)[~live], before[~live]),
+                  f"gated_delta_rule s={s}: a row that does not advance "
+                  "changed")
         return info
 
     def _kernels_paged(self) -> dict:

@@ -30,9 +30,20 @@ Two shapes, one set of weights:
   count leave its state exactly as it was (`exp(0) S + k 0`), so s == 1
   is the decode step and s == C a prefill chunk in one pass.
 
-The recurrence is a `lax.scan` over the step's positions in plain
-jax.numpy: it reads and writes every slot's `S` a position, live or
-not.  A chunked scan that reads `S` once a chunk is left to a kernel.
+Which recurrence runs is chosen from what can be observed
+(`ops/pallas/gated_delta_rule.py pick_recurrence`: backend, per-slot
+state or not, head dims, step length; `GatedDeltaNet.recurrence_plan`),
+never by a flag:
+
+* "kernel": on a TPU, with per-slot state, head dims of whole 128-lane
+  tiles and a step of at most `MAX_STEP_TOKENS` tokens, ONE Pallas call
+  a layer holds a row's `S` in VMEM over the step's positions: read
+  once, written once onto the donated input, and rows whose
+  `row_tokens` is 0 neither read nor written (their `o` is 0);
+* "plain": everywhere else, a `lax.scan` of `delta_rule_step` over the
+  step's positions in plain jax.numpy, which reads and writes every
+  slot's `S` a position, live or not.  The stateless shape always takes
+  it: the kernel has no backward pass, and no cell runs that shape.
 """
 from __future__ import annotations
 
@@ -46,6 +57,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
 from ..tensor import ParallelDim, ParallelTensorShape
 from .op import Op, ShapeError, ShardConfig, WeightSpec
+from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -89,6 +101,21 @@ def delta_rule_step(S, q, k, v, g, beta):
     return S, jnp.einsum("bhkv,bhk->bhv", S, q, precision=_HIGHEST)
 
 
+def delta_rule_scan(S, q, k, v, g, beta):
+    """`delta_rule_step` over a step's positions in order, plain
+    jax.numpy: S [b, h, dk, dv], q / k [b, s, h, dk], v [b, s, h, dv],
+    g / beta [b, s, h] -> (S, o [b, s, h, dv]).  One position is the
+    step itself, more a `lax.scan` of it."""
+    if q.shape[1] == 1:
+        S, o = delta_rule_step(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                               beta[:, 0])
+        return S, o[:, None]
+    S, o = jax.lax.scan(
+        lambda S, xs: delta_rule_step(S, *xs), S,
+        tuple(jnp.swapaxes(t, 0, 1) for t in (q, k, v, g, beta)))
+    return S, jnp.swapaxes(o, 0, 1)
+
+
 class GatedDeltaNet(Op):
     op_type = OperatorType.GATED_DELTA_NET
     float32_weights = ("A_log", "dt_bias", "rec_state")
@@ -105,6 +132,13 @@ class GatedDeltaNet(Op):
 
     def slot_state_entries(self):
         return ("conv_state", "rec_state") if self._slot_state else ()
+
+    def recurrence_plan(self, step_tokens: int) -> str:
+        """"kernel" or "plain": what a step of `step_tokens` tokens a
+        row takes on this backend (`pick_recurrence`)."""
+        p: GatedDeltaNetParams = self.params
+        return pick_recurrence(jax.default_backend(), self._slot_state,
+                               p.head_k_dim, p.head_v_dim, step_tokens)
 
     def infer_output_shapes(self, input_shapes):
         (x,) = input_shapes
@@ -206,15 +240,10 @@ class GatedDeltaNet(Op):
         g = jnp.where(real, -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
             ba[..., hv:] + dt_bias.astype(f32)), 0.0)
         S = S.astype(f32)
-        if s == 1:
-            S, o = delta_rule_step(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                   beta[:, 0])
-            o = o[:, None]
+        if self.recurrence_plan(s) == "kernel":
+            S, o = gated_delta_rule(S, q, k, v, g, beta, count)
         else:
-            S, o = jax.lax.scan(
-                lambda S, xs: delta_rule_step(S, *xs), S,
-                tuple(jnp.swapaxes(t, 0, 1) for t in (q, k, v, g, beta)))
-            o = jnp.swapaxes(o, 0, 1)  # [b, s, hv, dv]
+            S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
                                        keepdims=True) + p.eps)
         y = (o * norm_w.astype(f32)

@@ -382,7 +382,8 @@ class ServingFront:
                 sp.set(slots=model.batch_slots,
                        pool_blocks=model.num_blocks)
                 if model.has_slot_state:
-                    sp.set(rstate_bytes=model.rstate_bytes)
+                    sp.set(rstate_bytes=model.rstate_bytes,
+                           **model.gdn_ops)
             return model
 
         kw.setdefault("step_timeout", cfg.serving_step_timeout)

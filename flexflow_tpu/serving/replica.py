@@ -107,6 +107,8 @@ class SupervisedDecodeModel:
         # at admission (`reset_slot_state`)
         self.has_slot_state = bool(getattr(model, "has_slot_state", False))
         self.rstate_bytes = getattr(model, "rstate_bytes", 0)
+        self.rstate_rows_touched = getattr(model, "rstate_rows_touched",
+                                           None)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)

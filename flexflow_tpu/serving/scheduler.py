@@ -53,6 +53,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..fftype import OperatorType
 from ..logger import serving_logger
 from ..obs.trace import span
 from ..ops.routed_experts import MOE_STATS
@@ -303,6 +304,21 @@ class PagedKVDecodeModel:
             for op, names in self._slot_state.items() for k in names)
         self._reset_slot_fn = (build_slot_state_reset(self.ffd)
                                if self._slot_state else None)
+        # which recurrence the built programs take, asked of each
+        # recurrent layer for each step length this twin runs
+        # (`GatedDeltaNet.recurrence_plan`): the kernel walks the rows
+        # that advance, the plain scan every slot
+        gdn = [op for op in self.ffd.operators.topo_order()
+               if op.op_type == OperatorType.GATED_DELTA_NET
+               and op.slot_state_entries()]
+        lengths = (1, *((self.prefill_chunk,) if self.prefill_chunk else ()))
+        in_kernel = [[op.recurrence_plan(s) == "kernel" for s in lengths]
+                     for op in gdn]
+        self._rstate_skips_idle = {
+            s: all(op[i] for op in in_kernel) for i, s in enumerate(lengths)}
+        kernels = sum(all(op) for op in in_kernel)
+        self.gdn_ops = {"gdn_kernel_ops": kernels,
+                        "gdn_plain_ops": len(gdn) - kernels}
         self.mesh_shape = {
             str(k): int(s)
             for k, s in zip(self.ffd.mesh.axis_names,
@@ -329,6 +345,15 @@ class PagedKVDecodeModel:
     @property
     def has_slot_state(self) -> bool:
         return bool(self._slot_state)
+
+    def rstate_rows_touched(self, rows_live: int, step_tokens: int) -> int:
+        """Rows whose recurrent state a dispatch of `step_tokens` tokens
+        a row reads and writes when `rows_live` of them advance: those
+        rows where every recurrent layer of that program takes the
+        kernel, every slot under the plain recurrence."""
+        if self._rstate_skips_idle.get(step_tokens, False):
+            return rows_live
+        return self.batch_slots
 
     def _row_tokens(self, row_tokens) -> tuple:
         """The step programs' trailing argument: `row_tokens` for a
@@ -654,7 +679,7 @@ class ContinuousScheduler:
         # per-slot recurrent state (a model without any leaves these
         # off every span and out of stats()): rows whose state a
         # dispatch had to advance against rows whose state the program
-        # read and wrote (every slot, under the plain-jnp recurrence)
+        # read and wrote (`model.rstate_rows_touched`)
         self._rstate = bool(getattr(model, "has_slot_state", False))
         self.rstate_totals: Optional[Dict[str, int]] = (
             dict.fromkeys(("rows_live", "rows_touched", "dispatches"), 0)
@@ -1445,12 +1470,16 @@ class ContinuousScheduler:
                                    else dense),
                 "kv_blocks_dense": dense, "kv_blocks_live": live}
 
-    def _note_rstate(self, dispatch, rows_live: int) -> None:
+    def _note_rstate(self, dispatch, rows_live: int,
+                     step_tokens: int) -> None:
         """The `rstate_rows_*` args of a dispatch span and their sums:
         rows whose recurrent state the pass had to advance, and rows
-        whose state the program read and wrote (all of them: the
-        recurrence runs over every slot and masks the rest)."""
-        touched = self.model.batch_slots
+        whose state the program of that step length read and wrote, as
+        the twin built it (`rstate_rows_touched`: the advanced rows
+        under the kernel, every slot under the plain recurrence)."""
+        ask = getattr(self.model, "rstate_rows_touched", None)
+        touched = (ask(rows_live, step_tokens) if ask is not None
+                   else self.model.batch_slots)
         dispatch.set(rstate_rows_live=rows_live,
                      rstate_rows_touched=touched)
         t = self.rstate_totals
@@ -1517,7 +1546,7 @@ class ContinuousScheduler:
                 self.model.prefill_step(
                     tok, slen, btab, *((fed,) if self._rstate else ()))
                 if self._rstate:
-                    self._note_rstate(dispatch, len(plan))
+                    self._note_rstate(dispatch, len(plan), C)
                 # (the program is enqueued: this runs beside it) a plan
                 # row's prefix is read once a pass: by the scan at each
                 # of its C positions, by the one-pass program once, up
@@ -1857,7 +1886,7 @@ class ContinuousScheduler:
                 if self._rstate:
                     alive = (np.array([live is not None
                                        for live in self._slots], np.int32),)
-                    self._note_rstate(dispatch, int(alive[0].sum()))
+                    self._note_rstate(dispatch, int(alive[0].sum()), 1)
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab, *alive)
                 moe = getattr(self.model, "moe_last", None)

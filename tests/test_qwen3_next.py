@@ -12,6 +12,7 @@ against one expert at a time, grouped heads against repeated ones), so
 they differ by rounding only: 1e-5 of the compared tensor's largest
 magnitude for one op, 2e-5 for logits that went through every layer.
 """
+import contextlib
 import json
 import os
 
@@ -149,8 +150,21 @@ class Recorder:
         model.step = step
 
 
-@pytest.fixture(scope="module")
-def served():
+@contextlib.contextmanager
+def forced_kernel():
+    """Every `GatedDeltaNet` with per-slot state plans the Pallas kernel
+    inside, as on a TPU at published widths: the toy's 8 x 8 heads then
+    run it under the interpreter.  Builds and first dispatches (the
+    traces) have to happen inside."""
+    from flexflow_tpu.ops.gated_delta_net import GatedDeltaNet
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(GatedDeltaNet, "recurrence_plan",
+                   lambda op, s: "kernel" if op._slot_state else "plain")
+        yield
+
+
+def serve_toy():
     """One scheduler over the toy model: a long prompt prefilled in
     chunks alone, then three prompts at once (every slot busy, so the
     prefill dispatches carry decode-phase riders), then a request into
@@ -177,6 +191,24 @@ def served():
     return rec.rows, handles, stats
 
 
+@pytest.fixture(scope="module")
+def served_plain():
+    return serve_toy()
+
+
+@pytest.fixture(scope="module")
+def served_kernel():
+    with forced_kernel():
+        return serve_toy()
+
+
+@pytest.fixture(params=["plain", "kernel"])
+def served(request):
+    """The served toy on either recurrence (the plain scan is what a
+    CPU picks; the kernel is forced, interpreted)."""
+    return request.getfixturevalue(f"served_{request.param}")
+
+
 def test_served_logits_equal_the_reference_full_forward(served):
     rows, handles, _ = served
     want = {id(h): reference_logits(h.result) for h in handles}
@@ -196,41 +228,77 @@ def test_a_reused_slot_serves_what_a_fresh_server_serves(served):
     assert stats["prefill_passes"] == 1
 
 
-def test_scheduler_counts_rows_advanced_against_rows_touched(served):
+def test_scheduler_counts_rows_advanced_against_rows_touched(
+        served, request):
+    """`rows_touched` is what the built path touches: every slot under
+    the plain recurrence, the advanced rows where the twin says the
+    kernel is in."""
     _, _, stats = served
     r = stats["rstate"]
-    assert r["rows_touched"] == 3 * r["dispatches"] > r["rows_live"] > 0
+    assert 3 * r["dispatches"] > r["rows_live"] > 0
+    if request.node.callspec.params["served"] == "kernel":
+        assert r["rows_touched"] == r["rows_live"]
+    else:
+        assert r["rows_touched"] == 3 * r["dispatches"]
     # 6 linear layers x (4 heads x 8 x 8 + a tail of 3 x 64) float32, 3 slots
     assert r["bytes"] == 6 * (4 * 8 * 8 + 3 * 64) * 4 * 3
     assert r["bytes"] == fam.rstate_row_bytes(CFG) * 3
     assert stats["prefix_cache"]["hits"] == 0
 
 
-def test_front_reports_rstate_and_the_dispatch_spans_carry_it():
+@pytest.mark.parametrize("plan", ["plain", "kernel"])
+def test_front_reports_rstate_and_the_dispatch_spans_carry_it(plan):
     from flexflow_tpu.obs.trace import next_span_id, spans
     from flexflow_tpu.serving import build_front
 
     first = next_span_id()
-    front = build_front(holder(prefill_chunk=4))
-    try:
-        front.generate(list(range(1, 14)), 3, 0.0)
-        replicas = front.stats()["replicas"]
-    finally:
-        front.close()
+    with forced_kernel() if plan == "kernel" else contextlib.nullcontext():
+        front = build_front(holder(prefill_chunk=4))
+        try:
+            front.generate(list(range(1, 14)), 3, 0.0)
+            replicas = front.stats()["replicas"]
+        finally:
+            front.close()
     slots = CFG["deployment"]["serving_slots"]
+    # one request: a dispatch advances one row; the kernel touches that
+    # row, the plain recurrence every slot
+    touched = 1 if plan == "kernel" else slots
     for r in replicas:
-        assert r["rstate"]["rows_touched"] == slots * r["rstate"]["dispatches"]
+        assert r["rstate"]["rows_touched"] == touched * r["rstate"]["dispatches"]
         assert r["rstate"]["rows_live"] == r["rstate"]["dispatches"]
     mine = [r for r in spans() if r.span_id > first]
     twin = next(r for r in mine if r.name == "serve.build_twin")
     assert twin.args["rstate_bytes"] == fam.rstate_row_bytes(CFG) * slots
+    linear = D.L - D.full_layers
+    assert (twin.args["gdn_kernel_ops"], twin.args["gdn_plain_ops"]) == (
+        (linear, 0) if plan == "kernel" else (0, linear))
     for name in ("sched.decode.dispatch", "sched.prefill.dispatch"):
         got = [r.args for r in mine if r.name == name]
         assert got and all(a["rstate_rows_live"] == 1
-                           and a["rstate_rows_touched"] == slots
+                           and a["rstate_rows_touched"] == touched
                            for a in got), name
     decode = next(r.args for r in mine if r.name == "sched.decode.dispatch")
     assert {"moe_pairs", "moe_hit", "kv_blocks_live"} <= set(decode)
+
+
+def test_twin_asks_each_step_length_for_its_recurrence(monkeypatch):
+    """`rstate_rows_touched` answers per program: a chunk too long for
+    the kernel keeps the scan (every slot) while the decode step skips
+    idle rows."""
+    from flexflow_tpu.ops.gated_delta_net import GatedDeltaNet
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    monkeypatch.setattr(
+        GatedDeltaNet, "recurrence_plan",
+        lambda op, s: "kernel" if op._slot_state and s == 1 else "plain")
+    model = PagedKVDecodeModel(holder(), batch_slots=3, page_size=4,
+                               num_blocks=40, prefill_chunk=4,
+                               prefix_cache=False,
+                               devices=jax.devices()[:1])
+    assert model.rstate_rows_touched(2, 1) == 2
+    assert model.rstate_rows_touched(2, 4) == 3
+    linear = D.L - D.full_layers
+    assert model.gdn_ops == {"gdn_kernel_ops": 0, "gdn_plain_ops": linear}
 
 
 # -- 2b. the twin's chunk pass against C single steps ------------------------------
@@ -242,15 +310,23 @@ ROWS = {"whole_chunk": (0, 4), "crosses_a_page": (6, 4), "short": (3, 2),
         "rider": (5, 0)}
 
 
-@pytest.fixture(scope="module")
-def twin():
+@pytest.fixture(scope="module", params=["plain", "kernel"])
+def twin(request):
+    """The twin's programs on either recurrence; "kernel" makes THIS
+    twin's delta-net ops plan the Pallas kernel (interpreted here), in
+    the seq-1 step and in the pass."""
     from flexflow_tpu.decoding import (build_paged_decode_step,
                                        build_paged_prefill_pass,
                                        build_slot_state_reset, make_decoder)
+    from flexflow_tpu.fftype import OperatorType
 
     ffd = make_decoder(holder(), batch_size=SLOTS, kv_page_size=PAGE,
                        kv_num_blocks=1 + SLOTS * D.p // PAGE,
                        devices=jax.devices()[:1])
+    if request.param == "kernel":
+        for op in ffd.operators.topo_order():
+            if op.op_type == OperatorType.GATED_DELTA_NET:
+                op.recurrence_plan = lambda s: "kernel"
     fns = {"step": build_paged_decode_step(ffd),
            "pass": build_paged_prefill_pass(ffd, CHUNK),
            "reset": build_slot_state_reset(ffd)}
@@ -352,6 +428,26 @@ def test_reset_zeroes_one_slot_and_leaves_the_rest_and_the_pools(
             if k in e:
                 assert np.array_equal(np.asarray(e[k]),
                                       np.asarray(before[op][k]))
+
+
+def test_twin_programs_hold_the_recurrence_their_ops_plan(twin, request):
+    """One call of the jitted kernel wrapper a delta-net layer (ONE
+    `pallas_call` body between them) in the step and in the pass when
+    the ops plan the kernel, none on the plain path."""
+    from flexflow_tpu.decoding import (build_paged_decode_step,
+                                       build_paged_prefill_pass)
+
+    ffd, _, btab = twin
+    want = (D.L - D.full_layers
+            if request.node.callspec.params["twin"] == "kernel" else 0)
+    zeros = jnp.zeros(SLOTS, jnp.int32)
+    for fn, tokens in ((build_paged_decode_step(ffd), zeros),
+                       (build_paged_prefill_pass(ffd, CHUNK),
+                        jnp.zeros((SLOTS, CHUNK), jnp.int32))):
+        text = str(jax.make_jaxpr(fn)(ffd._weights, ffd._state, tokens,
+                                      zeros, jnp.asarray(btab), zeros))
+        assert text.count("name=_rule") == want
+        assert text.count("pallas_call") == min(want, 1)
 
 
 def test_state_predicates_keep_pages_and_slot_state_apart(twin):

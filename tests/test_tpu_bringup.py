@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.ops.pallas import flash_attention as fa
+from flexflow_tpu.ops.pallas import gated_delta_rule as gdr
 from flexflow_tpu.ops.pallas import paged_attention as pk
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -145,6 +146,24 @@ def _paged_tp2(mesh):
             for s in (1, FULL["chunk"])]
 
 
+def _gdn_cases():
+    """The delta rule's kernel at the qwen3-next serving cell's widths
+    (64 slots, 32 value heads of 128 x 128, float32): its decode step,
+    its prefill chunk of 8, and the longest step the picker lets in."""
+    b, h, dk, dv = 64, 32, 128, 128
+
+    def S(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    def rule(*args):
+        return gdr.gated_delta_rule(*args, interpret=False)
+
+    return [(f"gated_delta_rule_s{s}", rule,
+             (S(b, h, dk, dv), S(b, s, h, dk), S(b, s, h, dk),
+              S(b, s, h, dv), S(b, s, h), S(b, s, h), S(b, dt=jnp.int32)), 1)
+            for s in (1, 8, gdr.MAX_STEP_TOKENS)]
+
+
 def _lower(fn, args):
     return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
 
@@ -152,7 +171,7 @@ def _lower(fn, args):
 def test_pallas_entry_points_lower_for_tpu(devices8):
     mesh = Mesh(np.array(devices8[:2]).reshape(1, 2), ("data", "model"))
     for name, fn, args, n_calls in (_flash_cases() + _paged_cases()
-                                    + _paged_tp2(mesh)):
+                                    + _gdn_cases() + _paged_tp2(mesh)):
         text = _lower(fn, args).as_text()
         assert text.count("tpu_custom_call") >= n_calls, name
 
@@ -176,7 +195,8 @@ def test_pallas_entry_points_compile_for_v5e():
         return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
                      for a in args)
 
-    for name, fn, args, _ in _flash_cases() + _paged_cases():
+    for name, fn, args, _ in (_flash_cases() + _paged_cases()
+                              + _gdn_cases()):
         _lower(fn, on(args, one)).compile()
     mesh = Mesh(np.array(topo.devices[:2]).reshape(1, 2),
                 ("data", "model"))
@@ -190,6 +210,17 @@ def test_paged_kernel_never_interpreted_on_tpu(monkeypatch):
             _paged_args(1, jnp.float32)]
     with pytest.raises(ValueError, match="must run compiled"):
         pk.paged_attention(*args, 0.125, interpret=True)
+
+
+def test_delta_rule_state_is_updated_in_place_on_tpu():
+    """The kernel's state output aliases its state input in the lowered
+    module: with the step programs' donation a row's `S` is written
+    where it was read, and a row the grid skips is never copied."""
+    name, fn, args, _ = _gdn_cases()[1]
+    text = jax.jit(fn, donate_argnums=(0,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tf.aliasing_output = 0" in text  # the donated S -> result 0
+    assert "output_operand_aliases" in text  # the custom call's own alias
 
 
 def test_flash_unsupported_shape_is_visible_on_tpu(monkeypatch):
