@@ -58,6 +58,10 @@ class DecoderRecipe:
 
 def decoder_recipe(ff: FFModel) -> DecoderRecipe:
     recipe = getattr(ff, "decoder_recipe", None)
+    if recipe is None and getattr(ff, "not_served", None):
+        from .config import ConfigError
+
+        raise ConfigError(ff.not_served)  # the builder said why, by name
     if recipe is None:
         raise ValueError(
             "a decode twin needs a model built by a models/ builder that "

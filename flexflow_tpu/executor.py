@@ -228,10 +228,15 @@ class GraphExecutor:
                 if t.guid == sink_out
                 or any(c > i for c in consumers.get(t.guid, ()))
             ]
+            # an op's state keeps its segment inline (BatchNorm's
+            # running statistics) unless the op says its state entries
+            # are counters the forward pass only writes: those leave
+            # the checkpointed function as outputs (run_forward)
             pure = (sel is None or i in sel) and all(
                 op.op_type not in impure_types
                 and op.guid not in self._block_guids
-                and _num_trainable(op) == len(op.weight_specs)
+                and (_num_trainable(op) == len(op.weight_specs)
+                     or getattr(op, "state_is_counters", False))
                 for op in seg
             )
             plan.append((seg, external_inputs(seg), out_guids, pure))
@@ -398,8 +403,9 @@ class GraphExecutor:
         the slot trees inherit each weight's strategy sharding from
         init_state, but scalar entries still get the replicated put —
         the wedge doesn't care whether ZeRO-1 is on."""
-        if self.mesh.devices.size <= 1:
-            return opt_state
+        # (on one device too: an uncommitted scalar comes back from the
+        # first step committed to the mesh, and the second step would
+        # trace and lower the whole program again for the new signature)
         rep = NamedSharding(self.mesh, PartitionSpec())
         if self.wus_axis is None:
             return {
@@ -578,14 +584,21 @@ class GraphExecutor:
 
                 def seg_fn(*in_vals, _seg=seg, _in=in_guids, _out=out_guids):
                     local = dict(zip(_in, in_vals))
+                    # counters the segment's ops write leave it as
+                    # outputs, never through the enclosing dict
+                    written = {op.name: {} for op in _seg
+                               if op.name in new_state}
+                    inner = dict(state_ctx, new_state=written)
                     for op in _seg:
-                        self._exec_op(op, local, state_ctx)
-                    return tuple(local[g] for g in _out)
+                        self._exec_op(op, local, inner)
+                    return tuple(local[g] for g in _out), written
 
-                outs = jax.checkpoint(seg_fn)(
+                outs, written = jax.checkpoint(seg_fn)(
                     *(env[g] for g in in_guids)
                 )
                 env.update(zip(out_guids, outs))
+                for name, entries in written.items():
+                    new_state[name].update(entries)
         else:
             z3_next = None
             if self._z3_gather is not None:
@@ -837,6 +850,27 @@ class GraphExecutor:
 
         return update
 
+    @property
+    def routed_expert_ops(self) -> List[Op]:
+        return [op for op in self.order
+                if op.op_type == OperatorType.ROUTED_EXPERTS
+                and op.guid not in self._block_guids]
+
+    def moe_counts(self, state) -> jax.Array:
+        """int32 [5], summed over the routed-expert layers of one step:
+        `MOE_STATS` in their order, then the rows the chosen product
+        multiplied (the grouped product counts its own in
+        `moe_rows_computed`; the dense one's is static)."""
+        total = jnp.zeros((5,), jnp.int32)
+        for op in self.routed_expert_ops:
+            entries = state[op.name]
+            rows = (entries["moe_rows_computed"][0]
+                    if "moe_rows_computed" in entries
+                    else jnp.int32(op.dense_rows_computed()))
+            total = total + jnp.concatenate(
+                [entries["moe_stats"], rows.reshape(1)])
+        return total
+
     def build_step(self):
         metrics = self.metrics
         loss_obj = self.loss
@@ -898,6 +932,8 @@ class GraphExecutor:
             new_w, new_opt_state = update_fn(weights, grads, opt_state)
             m = metrics.compute(logits, labels)
             m["loss"] = loss_val
+            if self.routed_expert_ops:
+                m["__moe__"] = self.moe_counts(new_state)
             if taps:
                 m["__cache_taps__"] = taps
             return new_w, new_opt_state, new_state, m

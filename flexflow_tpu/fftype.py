@@ -112,6 +112,7 @@ class OperatorType(enum.Enum):
     MLA_ATTENTION = "mla_attention"
     ROUTED_EXPERTS = "routed_experts"
     GATED_DELTA_NET = "gated_delta_net"
+    SHORT_CONV = "short_conv"
     # Elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"

@@ -162,6 +162,9 @@ class FFModel:
         # what a models/ builder records so that a decode twin is that
         # builder again (decoding.DecoderRecipe)
         self.decoder_recipe = None
+        # or, where the builder knows no twin of its graph can be built,
+        # why (decoding.decoder_recipe raises it as a ConfigError)
+        self.not_served = None
         self._opt_state = None
         self._state = None
         self._step_fn = None
@@ -169,6 +172,7 @@ class FFModel:
         # step functions that have run once (their first call compiles)
         self._stepped_fns = weakref.WeakSet()
         self._train_steps = 0  # train_step calls, the span's step index
+        self._pending_moe = []  # (step, counts) a step returned, unread
         self._eval_fn = None
         self._rng = None
         self._label_replication = 1
@@ -372,6 +376,15 @@ class FFModel:
         return self._add(GatedDeltaNet(
             params, [input], name=self._name("gated_delta_net", name),
             slot_state=slot_state))
+
+    def short_conv(self, input, kernel: int = 3, name=None):
+        """A gated short convolution over the sequence
+        (ops/short_conv.py), `kernel` taps a channel."""
+        from .ops.short_conv import ShortConv, ShortConvParams
+
+        return self._add(ShortConv(
+            ShortConvParams(input.shape.logical_shape[-1], kernel), [input],
+            name=self._name("short_conv", name)))
 
     def gated_mlp(self, input, intermediate_size: int, name=None):
         from .ops.dense import GatedMLP, GatedMLPParams
@@ -1149,11 +1162,16 @@ class FFModel:
                  for op in self.operators.topo_order()
                  if op.op_type == OperatorType.MULTIHEAD_ATTENTION]
         kernels = [p for p in plans if p in ("one_tile", "online")]
-        return {
+        counts = {
             "attn_kernel_ops": len(kernels),
             "attn_dense_ops": sum(p in ("dense", "jnp") for p in plans),
             "attn_tile": "+".join(sorted(set(kernels))),
         }
+        experts = [op.product_plan() for op in self.executor.routed_expert_ops]
+        if experts:  # which product each routed-expert layer takes
+            counts["expert_grouped_ops"] = experts.count("grouped")
+            counts["expert_dense_ops"] = experts.count("dense")
+        return counts
 
     def set_iteration_config(self, seq_length: Optional[int]):
         """FFIterationConfig.seq_length threading (reference
@@ -1206,10 +1224,37 @@ class FFModel:
                 )
             with span("train_step.caches"):
                 m = self._update_caches(dict(m))
+            if "__moe__" in m:
+                self._report_moe(m.pop("__moe__"))
         if first:
             self._stepped_fns.add(step_fn)
         self._train_steps += 1
         return m
+
+    def _report_moe(self, counts) -> None:
+        """The routed-expert layers' counts of this step (an int32 [5]
+        device array the step returns: `executor.moe_counts`) are kept;
+        the NEWEST earlier step whose counts have arrived is reported
+        as a `train_step.moe` child span and added to the
+        `train/moe_*` counters.  `is_ready()` asks and never waits, so
+        a step whose counts are still in flight reports nothing."""
+        pending = self._pending_moe
+        pending.append((self._train_steps, counts))
+        ready = [i for i, (_, c) in enumerate(pending[:-1]) if c.is_ready()]
+        if not ready:
+            return
+        step, counts = pending[ready[-1]]
+        del pending[:ready[-1] + 1]
+        pairs, dropped, max_rows, hit, rows_computed = (
+            int(v) for v in np.asarray(counts))
+        with span("train_step.moe", step=step, moe_pairs=pairs,
+                  moe_dropped=dropped, moe_max_rows=max_rows, moe_hit=hit,
+                  moe_rows_computed=rows_computed):
+            reg = self.telemetry.metrics
+            reg.counter("train/moe_steps").inc()
+            reg.counter("train/moe_pairs").inc(pairs)
+            reg.counter("train/moe_dropped").inc(dropped)
+            reg.counter("train/moe_rows_computed").inc(rows_computed)
 
     def eval_step(self, inputs: Dict[str, np.ndarray], labels: np.ndarray):
         self._check_not_decode_graph("eval_step()")

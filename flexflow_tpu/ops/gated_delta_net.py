@@ -58,6 +58,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
 from ..tensor import ParallelDim, ParallelTensorShape
 from .op import Op, ShapeError, ShardConfig, WeightSpec
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
+from .short_conv import causal_depthwise_conv
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -221,9 +222,7 @@ class GatedDeltaNet(Op):
         # causal depthwise conv over [the row's last K - 1 inputs | the
         # step's]: output t reads inputs t .. t + K - 1 of that window
         window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
-        conv = sum(window[:, i:i + s].astype(f32) * conv_w[:, i].astype(f32)
-                   for i in range(p.conv_kernel))
-        conv = jax.nn.silu(conv)
+        conv = jax.nn.silu(causal_depthwise_conv(window, conv_w, s))
         # the window's K - 1 inputs that end at the row's last real one
         last = count[:, None] + jnp.arange(p.conv_kernel - 1,
                                            dtype=jnp.int32)

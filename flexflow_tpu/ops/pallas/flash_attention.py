@@ -162,6 +162,7 @@ def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse.reshape(bh, sq)
 
@@ -367,6 +368,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, dout, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -392,6 +394,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
 

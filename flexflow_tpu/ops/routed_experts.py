@@ -15,18 +15,35 @@ Experts that live on other chips add nothing: on one chip the layer
 runs without its exchange, and nothing stands in for the absent chips.
 `experts_held == experts_total` is the whole layer.
 
-No token is dropped at any load.  Every held expert is applied to every
-row of the step and the result combined with the routing weights (zero
-where an expert was not chosen): a capacity of the step's rows, which
-at a decode step's few rows costs what any static-shape dispatch costs,
-the read of the held experts' weights.  `moe_stats` counts what a
-dispatch with a smaller capacity would have to get right: routed pairs
-that landed on held experts, pairs the combine left out (0), the rows
-of the fullest held expert, and the held experts that received a row.
+No token is dropped at any load, by either of the two products, of
+which `pick_expert_product` chooses one from the step's shapes:
+
+* "dense": every held expert is applied to every row of the step and
+  the result combined with the routing weights (zero where an expert
+  was not chosen): a capacity of the step's rows, which at a decode
+  step's few rows costs what any static-shape dispatch costs, the read
+  of the held experts' weights.  What the serving steps take.
+* "grouped": the step's (token, expert) pairs are sorted by expert,
+  those on held experts first; each held expert multiplies its own run
+  of rows (`grouped_matmul`: one ragged product a weight, whose groups
+  are the runs) and the results go back to their tokens weighted by the
+  routing.  The buffers are static (`GROUPED_SLACK` x the pairs an even
+  router sends here; every pair of the step when there are more), the
+  product visits the held runs only.  What a training step's thousands
+  of rows take: dense would multiply `experts_total / top_k` times what
+  the routing asked for.
+
+`moe_stats` counts what a dispatch with a smaller capacity would have
+to get right: routed pairs that landed on held experts, pairs the
+combine left out (0), the rows of the fullest held expert, and the held
+experts that received a row.  The grouped product also counts the rows
+it multiplied (`moe_rows_computed`, padding of the product's row tiles
+included); the dense product's is the static `rows x experts_held`.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +71,7 @@ class RoutedExpertsParams:
     dtype: DataType = DataType.FLOAT
     scoring: str = "sigmoid"  # or "softmax", over all experts_total
     shared_expert_gate: bool = False  # shared expert times sigmoid(w . h)
+    norm_eps: float = 1e-20  # added to the chosen scores' sum
 
 
 def route(h, router, bias, p: RoutedExpertsParams):
@@ -68,14 +86,182 @@ def route(h, router, bias, p: RoutedExpertsParams):
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), p.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if p.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_eps)
     return chosen, w * p.routed_scaling_factor
+
+
+#: routed rows a held expert has to expect before the grouped product
+#: pays for its sort and its gathers: the rows of one pass of the
+#: matrix unit
+GROUPED_MIN_ROWS_PER_EXPERT = 128
+#: rows the ragged product is counted to multiply at a time (a sublane
+#: tile): a held expert's run of n rows counts `ceil(n / 8)` tiles
+GROUPED_ROW_TILE = 8
+#: the grouped product's usual buffers hold this many times the pairs an
+#: even router sends to the held experts; a step with more takes the
+#: buffers that hold every pair
+GROUPED_SLACK = 1.5
+
+
+def pick_expert_product(rows: int, experts_held: int, experts_total: int,
+                        top_k: int, backend: str = "") -> str:
+    """"dense" or "grouped" for a step of `rows` rows.  A pure function
+    of its arguments; the backend does not enter (both products are
+    plain XLA programs), it is taken so that a caller never has to know
+    that.  Dense multiplies `rows x experts_held` rows; grouped about
+    `rows x top_k x experts_held / experts_total` and pays a sort and
+    four gathers of every pair: worth it once a held expert expects
+    `GROUPED_MIN_ROWS_PER_EXPERT` rows, which no serving step's few
+    hundred rows give it."""
+    del backend
+    if experts_held < 2:
+        return "dense"
+    expected = rows * top_k / experts_total  # routed rows an expert
+    return ("grouped" if expected >= GROUPED_MIN_ROWS_PER_EXPERT
+            else "dense")
+
+
+def dense_experts(h, combine, w_gate, w_up, w_down):
+    """The dense product: every held expert (axis x) over every row of
+    h [t, e], combined with `combine` [t, held], the routing weights
+    (zero where an expert was not chosen) -> [t, e]."""
+    gate = jnp.einsum("te,xef->xtf", h, w_gate)
+    up = jnp.einsum("te,xef->xtf", h, w_up)
+    y = jnp.einsum("xtf,xfe->xte", jax.nn.silu(gate) * up, w_down)
+    return jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """lhs [m, k] whose rows run expert after expert, `sizes[g]` rows
+    for expert g (their sum may stay below m); rhs [g, k, n] -> [m, n].
+    Rows past the last group come out as zeros."""
+    return jax.lax.ragged_dot(lhs, rhs, sizes)
+
+
+@jax.custom_vjp
+def _rows_to_slots(h, order, slot_of):
+    """h [t, e] -> [m, e]: slot s < m holds the row of the token of pair
+    `order[s]` (`order` [m]: the first m slots' pairs).  Backward is a
+    gather too (`slot_of` [t, k] is the inverse permutation; a pair
+    whose slot is m or later was not kept), not a scatter-add."""
+    return h[order // slot_of.shape[1]]
+
+
+def _rows_to_slots_fwd(h, order, slot_of):
+    return _rows_to_slots(h, order, slot_of), slot_of
+
+
+def _rows_to_slots_bwd(slot_of, d_slots):
+    return jnp.sum(_kept_rows(d_slots, slot_of), axis=1), None, None
+
+
+_rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
+
+
+def _kept_rows(ys, slot_of):
+    """ys [m, e] -> [t, k, e]: every pair's row; zeros for a pair whose
+    slot was not kept."""
+    m = ys.shape[0]
+    rows = ys[jnp.minimum(slot_of, m - 1)]
+    return jnp.where((slot_of < m)[..., None], rows, 0)
+
+
+@jax.custom_vjp
+def _slots_to_pairs(ys, order, slot_of):
+    """ys [m, e] -> [t, k, e], back in token order (`_kept_rows`);
+    backward the inverse gather."""
+    return _kept_rows(ys, slot_of)
+
+
+def _slots_to_pairs_fwd(ys, order, slot_of):
+    return _slots_to_pairs(ys, order, slot_of), order
+
+
+def _slots_to_pairs_bwd(order, d_pairs):
+    return d_pairs.reshape(-1, d_pairs.shape[-1])[order], None, None
+
+
+_slots_to_pairs.defvjp(_slots_to_pairs_fwd, _slots_to_pairs_bwd)
+
+
+def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
+                    expected_pairs: float):
+    """The grouped product: h [t, e]; landed_on [t, k] the held expert
+    (0 .. held - 1) each chosen pair landed on, or `held` for an expert
+    that lives elsewhere; w [t, k] the routing weights; `expected_pairs`
+    the pairs an even router sends to the held experts (t * k * held /
+    total) -> (out [t, e], rows multiplied, tile padding included).
+
+    The step's t * k pairs are sorted by expert, the held experts' runs
+    first.  Static shapes and no drop at any load: the buffers keep the
+    first m slots, where m is `GROUPED_SLACK` times the pairs the held
+    experts expect when they hold that many, and EVERY pair when they
+    hold more (one `lax.cond` on the count: both sizes are compiled,
+    one runs).  The products visit the held runs and nothing after."""
+    t, k = landed_on.shape
+    held = w_gate.shape[0]
+    order = jnp.argsort(landed_on.reshape(-1), stable=True)  # slot -> pair
+    slot_of = jnp.argsort(order).reshape(t, k).astype(jnp.int32)
+    order = order.astype(jnp.int32)
+    sizes = jnp.sum(jax.nn.one_hot(landed_on.reshape(-1), held,
+                                   dtype=jnp.int32), axis=0)
+    count = jnp.sum(sizes)
+    weights = jnp.where(landed_on < held, w, 0)
+
+    def kept(m, h, weights, w_gate, w_up, w_down):
+        live = (jnp.arange(m, dtype=jnp.int32) < count)[:, None]
+
+        def product(x, weight):
+            # zeros past the held runs on both sides, so that neither a
+            # value nor a gradient of a row nobody multiplied goes on
+            return jnp.where(live, grouped_matmul(
+                jnp.where(live, x, 0), weight, sizes), 0).astype(h.dtype)
+
+        xs = _rows_to_slots(h, order[:m], slot_of)
+        ys = product(jax.nn.silu(product(xs, w_gate)) * product(xs, w_up),
+                     w_down)
+        pairs = _slots_to_pairs(ys, order[:m], slot_of)
+        return jnp.einsum("tke,tk->te", pairs, weights.astype(pairs.dtype))
+
+    m_all = t * k
+    m_usual = min(m_all, -(-int(GROUPED_SLACK * expected_pairs)
+                           // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
+    args = (h, weights, w_gate, w_up, w_down)
+    if m_usual == m_all:
+        out = kept(m_all, *args)
+    else:
+        out = jax.lax.cond(count <= m_usual,
+                           functools.partial(kept, m_usual),
+                           functools.partial(kept, m_all), *args)
+    tiles = -(-sizes // GROUPED_ROW_TILE)
+    return out, jnp.sum(tiles) * GROUPED_ROW_TILE
 
 
 class RoutedExperts(Op):
     op_type = OperatorType.ROUTED_EXPERTS
     float32_weights = ("router", "router_bias")
     has_aux_state = True  # weights[num_trainable_weights():] are state
+    #: the state entries are counters the forward pass only writes:
+    #: a segment that holds this op may be rematerialised
+    #: (`GraphExecutor._build_remat_plan`)
+    state_is_counters = True
+
+    def _rows(self) -> int:
+        shape = self.inputs[0].shape
+        return shape.num_elements() // shape.logical_shape[-1]
+
+    def product_plan(self) -> str:
+        """"dense" or "grouped": what a step of this op's declared rows
+        takes (`pick_expert_product`)."""
+        p: RoutedExpertsParams = self.params
+        return pick_expert_product(self._rows(), p.experts_held,
+                                   p.experts_total, p.top_k,
+                                   jax.default_backend())
+
+    def dense_rows_computed(self) -> int:
+        """Rows the dense product multiplies a step: every held expert
+        over every row."""
+        return self.params.experts_held * self._rows()
 
     def infer_output_shapes(self, input_shapes):
         (ishape,) = input_shapes
@@ -131,8 +317,12 @@ class RoutedExperts(Op):
             ]
         if p.shared_expert_gate:
             specs.append(WeightSpec("shared_expert_gate", w(e), init))
-        return specs + [WeightSpec(
-            "moe_stats", w(len(MOE_STATS), dtype=DataType.INT32), zero)]
+        specs.append(WeightSpec(
+            "moe_stats", w(len(MOE_STATS), dtype=DataType.INT32), zero))
+        if self.product_plan() == "grouped":
+            specs.append(WeightSpec(
+                "moe_rows_computed", w(1, dtype=DataType.INT32), zero))
+        return specs
 
     def forward(self, inputs, weights, *, training=False, rng=None):
         (x,) = inputs
@@ -142,13 +332,17 @@ class RoutedExperts(Op):
         chosen, w = route(h, router, bias, p)
         # [t, k, held]: which held expert each chosen pair landed on;
         # a pair for an expert that lives elsewhere is all zeros
-        landed = jax.nn.one_hot(chosen - p.first_held, p.experts_held,
-                                dtype=jnp.float32)
+        at = chosen - p.first_held
+        landed = jax.nn.one_hot(at, p.experts_held, dtype=jnp.float32)
         combine = jnp.einsum("tkx,tk->tx", landed, w)
-        gate = jnp.einsum("te,xef->xtf", h, w_gate)
-        up = jnp.einsum("te,xef->xtf", h, w_up)
-        y = jnp.einsum("xtf,xfe->xte", jax.nn.silu(gate) * up, w_down)
-        out = jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
+        grouped = self.product_plan() == "grouped"
+        if grouped:
+            out, rows_computed = grouped_experts(
+                h, jnp.where((at >= 0) & (at < p.experts_held), at,
+                             p.experts_held), w, w_gate, w_up, w_down,
+                h.shape[0] * p.top_k * p.experts_held / p.experts_total)
+        else:
+            out = dense_experts(h, combine, w_gate, w_up, w_down)
         if p.shared_expert_gate:
             g = jax.nn.sigmoid(jnp.einsum(
                 "te,e->t", h, weights[8],
@@ -165,15 +359,22 @@ class RoutedExperts(Op):
             jnp.max(rows),
             jnp.sum(rows > 0).astype(jnp.int32),
         ])
-        return [out.reshape(x.shape).astype(x.dtype), stats]
+        out = out.reshape(x.shape).astype(x.dtype)
+        if grouped:
+            return [out, stats, rows_computed.reshape(1).astype(jnp.int32)]
+        return [out, stats]
 
     def flops(self):
         """The router's product (either scoring rule is a few
-        operations a logit on top), every held expert over every row,
+        operations a logit on top), the experts' product as the chosen
+        one multiplies it (dense: every held expert over every row;
+        grouped: the pairs that land on held experts, in expectation),
         the shared expert and its gate's dot product."""
         p: RoutedExpertsParams = self.params
         t = self.inputs[0].shape.num_elements()  # rows x e
+        experts = (p.top_k * p.experts_held / p.experts_total
+                   if self.product_plan() == "grouped" else p.experts_held)
         return t * (2.0 * p.experts_total
-                    + 6.0 * p.experts_held * p.expert_hidden
+                    + 6.0 * experts * p.expert_hidden
                     + 6.0 * p.shared_hidden
                     + 2.0 * int(p.shared_expert_gate))
