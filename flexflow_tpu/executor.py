@@ -18,6 +18,7 @@ and the NCCL optimizer sync (optimizer_kernel.cu:88) — with ONE design:
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -28,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .fftype import CompMode, OperatorType
 from .loss import Loss
 from .metrics import Metrics
-from .ops.op import Op, trainable_weight_count as _num_trainable
+from .ops.op import REMAT_KEPT, Op, trainable_weight_count as _num_trainable
 from .optimizer import Optimizer
 from .parallel.machine import view_to_spec
 from .pcg.graph import Graph
@@ -72,6 +73,143 @@ def check_step_health(metrics: Dict[str, Any], step: Optional[int] = None,
     val = watchdog.sync(read, step=step) if watchdog is not None else read()
     if not np.isfinite(val):
         raise NonFiniteLossError(val, step=step)
+
+
+#: what a checkpointed segment holds besides its boundaries, the most
+#: first; `_RematStep` takes the first level whose compiled step fits
+#: the device.  "products": the outputs of the segment's matrix
+#: products (`dot_general` without batch dimensions: the projections,
+#: not the attention scores) and the values its ops tagged
+#: (`ops.op.remat_keep`: the grouped products, the flash kernels'
+#: output and row statistics).  "none": boundaries only, every
+#: internal computed again in the backward pass.  Norms, activations,
+#: gates, masks, rotary, the sort and the casts of the weights are
+#: computed again at every level.
+_REMAT_POLICIES = {
+    "products": jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        jax.checkpoint_policies.save_only_these_names(REMAT_KEPT)),
+    "none": None,
+}
+
+
+def remat_kept(jaxpr, plan) -> Dict[int, List[Any]]:
+    """What each checkpointed segment of `plan` (by its index there)
+    holds for the backward pass besides its boundaries, as abstract
+    values, read off the differentiated step's own `jaxpr` (no segment
+    is traced for it): the inputs of the segment's backward
+    `checkpoint` equation that a forward equation of the SAME segment
+    made.  `_exec_op`'s named scopes say which op, and so which
+    segment, an equation belongs to."""
+    seg_of = {op.name: i for i, (seg, _, _, pure) in enumerate(plan)
+              if pure for op in seg}
+
+    def segment(eqns) -> Optional[int]:
+        for eqn in eqns:
+            for scope in re.findall(r"[^/()]+",
+                                    str(eqn.source_info.name_stack)):
+                if scope in seg_of:
+                    return seg_of[scope]
+        return None
+
+    made_in: Dict[Any, Optional[int]] = {}
+    kept: Dict[int, List[Any]] = {}
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("differentiated"):  # a segment's backward
+            here = segment(eqn.params["jaxpr"].eqns)
+            if here is not None:
+                kept[here] = [v.aval for v in eqn.invars
+                              if made_in.get(v, -1) == here]
+        elif "transpose(" not in str(eqn.source_info.name_stack):
+            made_in.update(dict.fromkeys(eqn.outvars, segment([eqn])))
+    return kept
+
+
+def remat_kept_bytes(jaxpr, plan) -> int:
+    """`remat_kept`, summed to bytes over the segments."""
+    return sum(a.size * a.dtype.itemsize
+               for avals in remat_kept(jaxpr, plan).values() for a in avals)
+
+
+def _device_memory_limit(mesh: Mesh) -> Optional[int]:
+    """Bytes one device of the mesh may hold; None where the backend
+    does not say (the CPU)."""
+    stats = mesh.devices.flat[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def _step_bytes(compiled) -> int:
+    """What the compiler says the program holds at once."""
+    m = compiled.memory_analysis()
+    return 0 if m is None else (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+class _RematStep:
+    """The train step of a graph with checkpointed segments, at the
+    first level of `_REMAT_POLICIES` that fits the device.  The first
+    call traces and compiles the step keeping products; if the compiler
+    refuses that program for memory, or counts more bytes for it than
+    the device has, the step is lowered once more keeping nothing
+    (whose refusal is the caller's, as it always was).  A step that
+    fits is traced, lowered and compiled once: the calls run the
+    executable the fit compiled.  `keep` / `kept_bytes` say what was
+    chosen (None before the first call)."""
+
+    def __init__(self, executor: "GraphExecutor", step):
+        self._executor = executor
+        self._step = step
+        self._compiled = None
+        self.keep: Optional[str] = None
+        self.kept_bytes: Optional[int] = None
+
+    def _at(self, keep: str):
+        """The jitted step whose segments keep `keep`, however the
+        executor stands when it is traced."""
+        def step(*args):
+            self._executor.remat_keep = keep  # run_forward reads it
+            return self._step(*args)
+
+        return jax.jit(step, donate_argnums=(0, 1, 2))
+
+    def trace(self, *args, **kwargs):
+        return self._at(self._executor.remat_keep).trace(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return self._at(self._executor.remat_keep).lower(*args, **kwargs)
+
+    def __call__(self, *args):
+        if self._compiled is None:
+            self._fit(args)
+        try:
+            return self._compiled(*args)
+        except TypeError:
+            if not any(isinstance(x, jax.core.Tracer)
+                       for x in jax.tree.leaves(args)):
+                raise
+            # under a transformation (`jax.make_jaxpr` of the step):
+            # an executable takes arrays, the jitted step takes tracers
+            return self._at(self.keep)(*args)
+
+    def _fit(self, args) -> None:
+        ex = self._executor
+        limit = _device_memory_limit(ex.mesh)
+        levels = list(_REMAT_POLICIES)
+        for keep in levels:
+            last = keep == levels[-1]
+            with ex.mesh:
+                traced = self._at(keep).trace(*args)
+                try:
+                    compiled = traced.lower().compile()
+                except jax.errors.JaxRuntimeError as e:
+                    if last or "RESOURCE_EXHAUSTED" not in str(e):
+                        raise
+                    continue  # XLA refused the program for memory
+            if last or limit is None or _step_bytes(compiled) <= limit:
+                break
+        self._compiled, self.keep = compiled, keep
+        self.kept_bytes = remat_kept_bytes(traced.jaxpr.jaxpr, ex._remat_plan)
 
 
 class GraphExecutor:
@@ -177,6 +315,9 @@ class GraphExecutor:
             # ZeRO-3 double-buffered prefetch path
             plan = None
         self._remat_plan = plan
+        # what a checkpointed segment keeps (a key of _REMAT_POLICIES),
+        # read while a step is traced
+        self.remat_keep = next(iter(_REMAT_POLICIES))
         # physical NHWC layout for CNN activations (pcg/layout.py): the
         # logical shapes stay NCHW; conversions happen at exec time
         from .pcg.layout import assign_layouts
@@ -194,6 +335,11 @@ class GraphExecutor:
         self._z3_gather = (
             self._z3_gather_map() if self.zero_stage >= 3 else None
         )
+
+    @property
+    def remat_segments(self) -> int:
+        """Segments the train step wraps in `jax.checkpoint`."""
+        return sum(pure for *_, pure in self._remat_plan or ())
 
     def _build_remat_plan(self, selected: Optional[Sequence[int]] = None):
         """[(ops, in_guids, out_guids, pure)] per segment.  Impure
@@ -593,9 +739,9 @@ class GraphExecutor:
                         self._exec_op(op, local, inner)
                     return tuple(local[g] for g in _out), written
 
-                outs, written = jax.checkpoint(seg_fn)(
-                    *(env[g] for g in in_guids)
-                )
+                outs, written = jax.checkpoint(
+                    seg_fn, policy=_REMAT_POLICIES[self.remat_keep]
+                )(*(env[g] for g in in_guids))
                 env.update(zip(out_guids, outs))
                 for name, entries in written.items():
                     new_state[name].update(entries)
@@ -938,6 +1084,9 @@ class GraphExecutor:
                 m["__cache_taps__"] = taps
             return new_w, new_opt_state, new_state, m
 
+        if self._remat_plan is not None:
+            self._step_fn = _RematStep(self, step)
+            return self._step_fn
         with self.mesh:
             self._step_fn = jax.jit(step, donate_argnums=(0, 1, 2))
         return self._step_fn

@@ -1171,6 +1171,12 @@ class FFModel:
         if experts:  # which product each routed-expert layer takes
             counts["expert_grouped_ops"] = experts.count("grouped")
             counts["expert_dense_ops"] = experts.count("dense")
+        # segments the train step checkpoints, and what the step is
+        # first lowered to keep in them (the step's first call says
+        # what fit: `train_step`'s `remat_keep`, `remat_kept_bytes`)
+        counts["remat_segments"] = self.executor.remat_segments
+        if counts["remat_segments"]:
+            counts["remat_keep"] = self.executor.remat_keep
         return counts
 
     def set_iteration_config(self, seq_length: Optional[int]):
@@ -1210,7 +1216,8 @@ class FFModel:
         # first=1: this call traces and compiles the step (or loads it
         # from the persistent cache)
         first = step_fn not in self._stepped_fns
-        with span("train_step", step=self._train_steps, first=int(first)):
+        with span("train_step", step=self._train_steps,
+                  first=int(first)) as step_span:
             with span("host_transfer"):
                 put_inputs, put_labels = self._device_put_batch(inputs, labels)
             with span("train_step.rng_split"):
@@ -1226,6 +1233,11 @@ class FFModel:
                 m = self._update_caches(dict(m))
             if "__moe__" in m:
                 self._report_moe(m.pop("__moe__"))
+            if first and getattr(step_fn, "keep", None) is not None:
+                # what the checkpointed segments hold, now that the
+                # compiled step has been held against the device
+                step_span.set(remat_keep=step_fn.keep,
+                              remat_kept_bytes=step_fn.kept_bytes)
         if first:
             self._stepped_fns.add(step_fn)
         self._train_steps += 1

@@ -28,12 +28,27 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..fftype import DataType, OperatorType
 from ..initializer import Initializer
 from ..tensor import ParallelTensor, ParallelTensorShape
 
 _op_guid = [2000]
+
+#: the one name a value is tagged with to stay alive across a
+#: checkpointed segment (`GraphExecutor`'s `remat` policy saves it
+#: beside the segment's matrix products)
+REMAT_KEPT = "remat_kept"
+
+
+def remat_keep(x):
+    """Tag `x` as dear to recompute and cheap to hold: a kernel's or a
+    grouped product's output, which a policy on `dot_general` cannot
+    see.  The op only names the value; whether a checkpointed segment
+    keeps it is the executor's choice.  Outside `jax.checkpoint` the
+    tag is the identity."""
+    return checkpoint_name(x, REMAT_KEPT)
 
 
 @dataclasses.dataclass(frozen=True)

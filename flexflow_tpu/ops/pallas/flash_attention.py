@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..op import remat_keep
+
 # Per-kernel preferred (block_q, block_k): r5 on-chip ASYMMETRIC sweep
 # (v5e, bh=96, d=64, seq2048, scan-chained timing so per-call dispatch
 # is amortized — scripts/flash_ceiling_probe.py, table in docs/PERF.md).
@@ -400,7 +402,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
 
 
 def _flash_vjp_fwd(q, k, v, scale, causal):
-    out, lse = _flash_fwd(q, k, v, scale, causal)
+    # under `remat` the forward kernel is not run again for these two
+    out, lse = map(remat_keep, _flash_fwd(q, k, v, scale, causal))
     return out, (q, k, v, out, lse)
 
 
@@ -714,7 +717,8 @@ def one_tile_attention(q, k, v, d: int, scale: float, causal: bool):
 
 
 def _one_tile_vjp_fwd(q, k, v, d, scale, causal):
-    out, lse = _one_tile_fwd(q, k, v, d=d, scale=scale, causal=causal)
+    out, lse = map(remat_keep, _one_tile_fwd(
+        q, k, v, d=d, scale=scale, causal=causal))
     return out, (q, k, v, out, lse)
 
 

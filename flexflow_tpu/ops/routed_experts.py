@@ -52,7 +52,7 @@ from ..fftype import DataType, OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT, ZeroInitializer
 from ..tensor import ParallelDim, ParallelTensorShape
 from .dense import gated_mlp
-from .op import Op, ShapeError, WeightSpec
+from .op import Op, ShapeError, WeightSpec, remat_keep
 
 #: order of the counters in the `moe_stats` state entry
 MOE_STATS = ("pairs", "dropped", "max_rows", "hit")
@@ -208,14 +208,23 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
     count = jnp.sum(sizes)
     weights = jnp.where(landed_on < held, w, 0)
 
+    m_all = t * k
+    m_usual = min(m_all, -(-int(GROUPED_SLACK * expected_pairs)
+                           // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
+
     def kept(m, h, weights, w_gate, w_up, w_down):
         live = (jnp.arange(m, dtype=jnp.int32) < count)[:, None]
 
         def product(x, weight):
+            y = grouped_matmul(jnp.where(live, x, 0), weight, sizes)
+            if m == m_usual:
+                # a checkpointed segment may hold the usual buffers'
+                # products; the overflow's (a `cond` keeps BOTH
+                # branches' residuals alive) are computed again
+                y = remat_keep(y)
             # zeros past the held runs on both sides, so that neither a
             # value nor a gradient of a row nobody multiplied goes on
-            return jnp.where(live, grouped_matmul(
-                jnp.where(live, x, 0), weight, sizes), 0).astype(h.dtype)
+            return jnp.where(live, y, 0).astype(h.dtype)
 
         xs = _rows_to_slots(h, order[:m], slot_of)
         ys = product(jax.nn.silu(product(xs, w_gate)) * product(xs, w_up),
@@ -223,9 +232,6 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
         pairs = _slots_to_pairs(ys, order[:m], slot_of)
         return jnp.einsum("tke,tk->te", pairs, weights.astype(pairs.dtype))
 
-    m_all = t * k
-    m_usual = min(m_all, -(-int(GROUPED_SLACK * expected_pairs)
-                           // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
     args = (h, weights, w_gate, w_up, w_down)
     if m_usual == m_all:
         out = kept(m_all, *args)

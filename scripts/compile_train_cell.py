@@ -60,6 +60,9 @@ def main() -> int:
     ap.add_argument("--no-step", action="store_true")
     ap.add_argument("--remat", type=int, choices=(0, 1), default=None,
                     help="override the configuration's assumed.remat")
+    ap.add_argument("--keep", default=None,
+                    help="what a checkpointed segment keeps (a key of "
+                         "executor._REMAT_POLICIES; default: the first)")
     args = ap.parse_args()
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
@@ -93,9 +96,15 @@ def main() -> int:
         import flexflow_tpu.optimizer as opt_mod
 
         real_state = opt_mod.AdamOptimizer.init_state
-        opt_mod.AdamOptimizer.init_state = lambda self, w: jax.eval_shape(
-            lambda: real_state(self, jax.tree.map(
+
+        def abstract_state(self, w):
+            state = jax.eval_shape(lambda: real_state(self, jax.tree.map(
                 lambda x: jnp.zeros(x.shape, x.dtype), w)))
+            # the step counter is placed on the mesh: a real scalar
+            return {k: v if isinstance(v, dict) else jnp.zeros(v.shape, v.dtype)
+                    for k, v in state.items()}
+
+        opt_mod.AdamOptimizer.init_state = abstract_state
         fam.compile_model(ff, cfg, jax.devices()[:1])
         inputs, labels = fam.make_batch(cfg, batch, seq,
                                         np.random.default_rng(0))
@@ -105,9 +114,18 @@ def main() -> int:
         rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
                                    sharding=chip)
         jax.default_backend = lambda: "tpu"
+        if args.keep:
+            ff.executor.remat_keep = args.keep
         t0 = time.monotonic()
-        compiled = ff._step_fn.trace(*structs, rng).lower(
-            lowering_platforms=("tpu",)).compile()
+        traced = ff._step_fn.trace(*structs, rng)
+        if getattr(ff.executor, "remat_segments", 0):
+            from flexflow_tpu.executor import remat_kept_bytes
+
+            print(f"  {ff.executor.remat_segments} segments checkpointed, "
+                  f"keeping {ff.executor.remat_keep}: "
+                  f"{gb(remat_kept_bytes(traced.jaxpr.jaxpr, ff.executor._remat_plan))}"
+                  " of kept values", flush=True)
+        compiled = traced.lower(lowering_platforms=("tpu",)).compile()
         report(f"train step, batch {batch} x seq {seq}", compiled,
                time.monotonic() - t0)
         text = compiled.as_text()

@@ -23,7 +23,7 @@ import jax.numpy as jnp  # noqa: E402
 from flexflow_tpu.ops import routed_experts as rx  # noqa: E402
 
 
-def layer(product, p):
+def layer(product, p, keep=None):
     def loss(h, router, bias, wg, wu, wd, target):
         chosen, w = rx.route(h, router, bias, p)
         at = chosen - p.first_held
@@ -38,6 +38,11 @@ def layer(product, p):
                 h.shape[0] * p.top_k * p.experts_held / p.experts_total)
         return jnp.sum(out.astype(jnp.float32) * target)
 
+    if keep is not None:
+        # the layer as a checkpointed segment of the executor's
+        from flexflow_tpu.executor import _REMAT_POLICIES
+
+        loss = jax.checkpoint(loss, policy=_REMAT_POLICIES[keep])
     return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5)))
 
 
@@ -97,10 +102,31 @@ def main() -> int:
             .astype(d) for i, (k, (s, d)) in enumerate(zip(keys, shapes))]
     want = None
     out = {"device": jax.devices()[0].device_kind, "shapes": vars(args)}
-    for name, (product, mm) in variants.items():
+    # the shipped product again as a checkpointed segment (PR 37): what
+    # the segment keeps, and with the slots' gather kept beside it
+    variants["grouped.ragged_dot.remat_none"] = (
+        "grouped", rx.grouped_matmul, "none")
+    variants["grouped.ragged_dot.remat_products"] = (
+        "grouped", rx.grouped_matmul, "products")
+    variants["grouped.ragged_dot.remat_products+gather"] = (
+        "grouped", rx.grouped_matmul, "products+gather")
+    variants["grouped.ragged_dot.remat_products+sort"] = (
+        "grouped", rx.grouped_matmul, "products+sort")
+    variants["grouped.ragged_dot.remat_products+sort+route"] = (
+        "grouped", rx.grouped_matmul, "products+sort+route")
+    rows_to_slots, argsort, route = rx._rows_to_slots, jnp.argsort, rx.route
+    for name, (product, mm, *keep) in variants.items():
         if mm is not None:
             rx.grouped_matmul = mm
-        fn = layer(product, p)
+        keep, *also = keep[0].split("+") if keep else (None,)
+        rx._rows_to_slots, jnp.argsort, rx.route = rows_to_slots, argsort, route
+        if "gather" in also:
+            rx._rows_to_slots = lambda *a: rx.remat_keep(rows_to_slots(*a))
+        if "sort" in also:  # the two permutations, 128 KB each
+            jnp.argsort = lambda *a, **kw: rx.remat_keep(argsort(*a, **kw))
+        if "route" in also:  # the chosen experts and their weights
+            rx.route = lambda *a: tuple(map(rx.remat_keep, route(*a)))
+        fn = layer(product, p, keep)
         try:
             got = jax.block_until_ready(fn(*vals))
         except Exception as ex:  # a variant the chip refuses
@@ -118,6 +144,7 @@ def main() -> int:
         err = float(jnp.linalg.norm(flat - want) / jnp.linalg.norm(want))
         out[name] = {"ms": ms, "grad_rel_l2_vs_dense": err}
         print(name, json.dumps(out[name]), flush=True)
+    rx._rows_to_slots, jnp.argsort, rx.route = rows_to_slots, argsort, route
     # does the ragged product's time follow the rows in its groups?
     lhs = vals[0].repeat(args.top_k, axis=0)  # [t * k, e]
     for share in (0.25, 1.0):
@@ -130,6 +157,20 @@ def main() -> int:
         jax.block_until_ready(r)
         out[f"ragged_dot.rows_in_groups_{share}"] = \
             1e3 * (time.monotonic() - t0) / args.iters
+    # the gather that fills the usual buffers, alone: what a backward
+    # pass that does not keep it pays again
+    m = -(-int(rx.GROUPED_SLACK * t * args.top_k * n / args.total)
+          // rx.GROUPED_ROW_TILE) * rx.GROUPED_ROW_TILE
+    order = jax.random.permutation(keys[0], t * args.top_k).astype(jnp.int32)
+    slot_of = jnp.argsort(order).reshape(t, args.top_k).astype(jnp.int32)
+    fn = jax.jit(lambda h, o, s: rows_to_slots(h, o[:m], s))
+    jax.block_until_ready(fn(vals[0], order, slot_of))
+    t0 = time.monotonic()
+    for _ in range(args.iters):
+        r = fn(vals[0], order, slot_of)
+    jax.block_until_ready(r)
+    out["rows_to_slots.gather_ms"] = \
+        1e3 * (time.monotonic() - t0) / args.iters
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/expert_product_probe.json", "w") as fh:
         json.dump(out, fh, indent=1)

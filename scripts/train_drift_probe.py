@@ -43,6 +43,11 @@ def main() -> int:
         ctx.cfg["optimizer"]["alpha"] = alpha
         ff, batch = driver.bring_up(ctx)
         batches = driver.first_step(ctx, ff, args.seed, batch)
+        first = [r.args for r in trace.spans()
+                 if r.name == "train_step" and r.args.get("first")][-1]
+        print(json.dumps({"first_step": first, "memory": {
+            k: v for k, v in (jax.devices()[0].memory_stats() or {}).items()
+            if k in ("peak_bytes_in_use", "bytes_limit")}}), flush=True)
         for start in range(0, args.steps, 10):
             seen = len(trace.spans())
             t0 = time.monotonic()

@@ -204,14 +204,17 @@ def test_build_step_fns_span_says_which_core_engaged(encoder4, monkeypatch):
     args = [r.args for r in obs_trace.spans()
             if r.name == "build_step_fns"][-1]
     # off-TPU the flash branch runs its jnp twin: no kernel
+    # (no `remat`: no segment of the step is checkpointed)
     assert args == {"attn_kernel_ops": 0, "attn_dense_ops": 4,
-                    "attn_tile": ""}
+                    "attn_tile": "", "remat_segments": 0}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert encoder4._attention_core_counts() == {
-        "attn_kernel_ops": 4, "attn_dense_ops": 0, "attn_tile": "one_tile"}
+        "attn_kernel_ops": 4, "attn_dense_ops": 0, "attn_tile": "one_tile",
+        "remat_segments": 0}
     for op in encoder4.operators.topo_order():
         op._flash_min_seq = 1024  # FFConfig.flash_min_seq, as compile sets it
     assert encoder4._attention_core_counts() == {
-        "attn_kernel_ops": 0, "attn_dense_ops": 4, "attn_tile": ""}
+        "attn_kernel_ops": 0, "attn_dense_ops": 4, "attn_tile": "",
+        "remat_segments": 0}
     for op in encoder4.operators.topo_order():
         op._flash_min_seq = 512

@@ -99,9 +99,16 @@ def test_remat_reduces_modeled_and_actual(devices8):
         )
 
     # the checkpointed step must actually recompute: optimization
-    # barriers present and more matmuls than the plain step (this part
-    # of the lowering is backend-independent)
+    # barriers present and, where its segments keep nothing, more
+    # matmuls than the plain step (this part of the lowering is
+    # backend-independent).  Since PR 37 a segment keeps its matrix
+    # products: the barriers stay, the second matmuls go.
     plain_txt = lowered_step(ff_plain).as_text()
+    kept_txt = lowered_step(ff_remat).as_text()
+    assert kept_txt.count("optimization_barrier") > 0
+    assert (kept_txt.count("stablehlo.dot")
+            == plain_txt.count("stablehlo.dot"))
+    ff_remat.executor.remat_keep = "none"
     remat_txt = lowered_step(ff_remat).as_text()
     assert remat_txt.count("optimization_barrier") > 0
     assert (remat_txt.count("stablehlo.dot")
