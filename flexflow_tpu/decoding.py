@@ -30,6 +30,7 @@ import numpy as np
 
 from .fftype import LossType
 from .model import FFModel
+from .obs import scopes
 from .obs.trace import span
 from .optimizer import SGDOptimizer
 
@@ -611,13 +612,14 @@ def build_paged_decode_step(ffd: FFModel):
     def step(weights, state, tokens, positions, block_table,
              row_tokens=None):
         state = _host_owned(state, block_table, positions, row_tokens)
+        with scopes.scope(scopes.FEED):
+            inputs = {"input": tokens[:, None],
+                      "positions": positions[:, None].astype(jnp.int32)}
         logits, new_state, _, _ = ex.run_forward(
-            weights, state,
-            {"input": tokens[:, None],
-             "positions": positions[:, None].astype(jnp.int32)},
-            training=False, rng=None,
+            weights, state, inputs, training=False, rng=None,
         )
-        return logits[:, 0], new_state
+        with scopes.scope(scopes.LOGITS):
+            return logits[:, 0], new_state
 
     with ex.mesh:
         return jax.jit(step, donate_argnums=(1,))
@@ -659,7 +661,6 @@ def build_paged_prefill_step(ffd: FFModel, chunk: int):
     def prefill(weights, state, tokens, positions, block_table):
         def body(carry, xs):
             tok, j = xs
-            pos_j = (positions + j).astype(jnp.int32)
             # a row's trailing PAD tokens can run past the position
             # table (a near-max_seq prompt whose last chunk is mostly
             # padding).  Route those writes to scratch (zeroed table
@@ -672,13 +673,14 @@ def build_paged_prefill_step(ffd: FFModel, chunk: int):
             # write into a clamped overwrite of the row's last real
             # block.  tests/test_serving_continuous.py pins the
             # byte-level contract either way.
-            bt_j = jnp.where((pos_j < max_seq)[:, None], block_table, 0)
-            pos_j = jnp.minimum(pos_j, max_seq - 1)
+            with scopes.scope(scopes.FEED):
+                pos_j = (positions + j).astype(jnp.int32)
+                bt_j = jnp.where((pos_j < max_seq)[:, None], block_table, 0)
+                pos_j = jnp.minimum(pos_j, max_seq - 1)
+                inputs = {"input": tok[:, None], "positions": pos_j[:, None]}
             st = _host_owned(carry, bt_j, pos_j)
             _, new_state, _, _ = ex.run_forward(
-                weights, st,
-                {"input": tok[:, None], "positions": pos_j[:, None]},
-                training=False, rng=None,
+                weights, st, inputs, training=False, rng=None,
             )
             return new_state, None
 
@@ -725,13 +727,15 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
 
     def prefill(weights, state, tokens, positions, block_table,
                 row_tokens=None):
-        positions = positions.astype(jnp.int32)
+        with scopes.scope(scopes.FEED):
+            positions = positions.astype(jnp.int32)
         state = _host_owned(state, block_table, positions, row_tokens)
-        grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+        with scopes.scope(scopes.FEED):
+            grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+            inputs = {"input": tokens,
+                      "positions": jnp.minimum(grid, max_seq - 1)}
         _, new_state, _, _ = ex.run_forward(
-            weights, state,
-            {"input": tokens, "positions": jnp.minimum(grid, max_seq - 1)},
-            training=False, rng=None,
+            weights, state, inputs, training=False, rng=None,
         )
         return new_state
 
@@ -777,21 +781,22 @@ def build_paged_verify_step(ffd: FFModel, chunk: int):
     def verify(weights, state, tokens, positions, counts, block_table):
         def body(carry, xs):
             tok, j = xs
-            pos_j = (positions + j).astype(jnp.int32)
-            live = (j < counts) & (pos_j < max_seq)
             # pad steps (j >= counts[i]) write to scratch at a clamped
             # position — same contract as prefill's trailing pads: the
             # row's real blocks must be unreachable from a pad step no
             # matter the gather/scatter out-of-range mode.
-            bt_j = jnp.where(live[:, None], block_table, 0)
-            pos_j = jnp.where(live, pos_j, 0)
+            with scopes.scope(scopes.FEED):
+                pos_j = (positions + j).astype(jnp.int32)
+                live = (j < counts) & (pos_j < max_seq)
+                bt_j = jnp.where(live[:, None], block_table, 0)
+                pos_j = jnp.where(live, pos_j, 0)
+                inputs = {"input": tok[:, None], "positions": pos_j[:, None]}
             st = _host_owned(carry, bt_j, pos_j)
             logits, new_state, _, _ = ex.run_forward(
-                weights, st,
-                {"input": tok[:, None], "positions": pos_j[:, None]},
-                training=False, rng=None,
+                weights, st, inputs, training=False, rng=None,
             )
-            return new_state, logits[:, 0]
+            with scopes.scope(scopes.LOGITS):
+                return new_state, logits[:, 0]
 
         state, logits = jax.lax.scan(
             body, state,
@@ -828,9 +833,11 @@ def build_paged_chunk_step(ffd: FFModel):
     ex = ffd.executor
 
     def step(weights, state, tokens, positions, block_table):
-        positions = positions.astype(jnp.int32)
         chunk = tokens.shape[1]
-        pos_grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+        with scopes.scope(scopes.FEED):
+            positions = positions.astype(jnp.int32)
+            pos_grid = positions[:, None] + jnp.arange(chunk,
+                                                       dtype=jnp.int32)
         state = _host_owned(state, block_table, positions)
         logits, new_state, _, _ = ex.run_forward(
             weights, state,

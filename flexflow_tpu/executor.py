@@ -18,7 +18,6 @@ and the NCCL optimizer sync (optimizer_kernel.cu:88) — with ONE design:
 from __future__ import annotations
 
 import functools
-import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -29,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .fftype import CompMode, OperatorType
 from .loss import Loss
 from .metrics import Metrics
+from .obs import scopes
 from .ops.op import REMAT_KEPT, Op, trainable_weight_count as _num_trainable
 from .optimizer import Optimizer
 from .parallel.machine import view_to_spec
@@ -99,17 +99,16 @@ def remat_kept(jaxpr, plan) -> Dict[int, List[Any]]:
     values, read off the differentiated step's own `jaxpr` (no segment
     is traced for it): the inputs of the segment's backward
     `checkpoint` equation that a forward equation of the SAME segment
-    made.  `_exec_op`'s named scopes say which op, and so which
+    made.  `_exec_op`'s device scopes say which op, and so which
     segment, an equation belongs to."""
     seg_of = {op.name: i for i, (seg, _, _, pure) in enumerate(plan)
               if pure for op in seg}
 
     def segment(eqns) -> Optional[int]:
         for eqn in eqns:
-            for scope in re.findall(r"[^/()]+",
-                                    str(eqn.source_info.name_stack)):
-                if scope in seg_of:
-                    return seg_of[scope]
+            name = scopes.parse(str(eqn.source_info.name_stack)).name
+            if name in seg_of:
+                return seg_of[name]
         return None
 
     made_in: Dict[Any, Optional[int]] = {}
@@ -770,10 +769,12 @@ class GraphExecutor:
         out = env[self.sink.outputs[0].guid]
         from .pcg.layout import NHWC, TO_NCHW_PERM
 
-        if self._t_layout.get(self.sink.outputs[0].guid) == NHWC:
-            out = jnp.transpose(out, TO_NCHW_PERM)  # callers see logical
-        if self.compute_dtype is not None and jnp.issubdtype(out.dtype, jnp.floating):
-            out = out.astype(jnp.float32)  # loss/metrics in full precision
+        with scopes.scope(scopes.LOGITS):
+            if self._t_layout.get(self.sink.outputs[0].guid) == NHWC:
+                out = jnp.transpose(out, TO_NCHW_PERM)  # callers see logical
+            if self.compute_dtype is not None and jnp.issubdtype(
+                    out.dtype, jnp.floating):
+                out = out.astype(jnp.float32)  # loss/metrics in full precision
         return out, new_state, aux_losses, env
 
     def _z3_fetch(self, op_name: str, wname: str, w, ctx: Dict):
@@ -801,17 +802,19 @@ class GraphExecutor:
         """Populate the gather memo for all of `op`'s scattered weights
         (emits their all-gathers at the CURRENT trace point)."""
         entry = ctx["weights"].get(op.name, {})
-        for wname in self._z3_gather.get(op.name, {}):
-            self._z3_fetch(op.name, wname, entry[wname], ctx)
+        with scopes.op_scope(op):  # the gather belongs to the op it feeds
+            for wname in self._z3_gather.get(op.name, {}):
+                self._z3_fetch(op.name, wname, entry[wname], ctx)
 
     def _exec_op(self, op: Op, env: Dict[int, jax.Array], ctx: Dict):
         """Execute one PCG op into env — the shared body of the flat
         interpreter and the remat segment functions.  The op's jax ops
-        are emitted under `jax.named_scope(op.name)` so device-side
-        profiles (jax.profiler / XLA op_name metadata) attribute to PCG
-        operator names; named_scope runs at trace time only, so the
-        compiled step pays nothing per iteration."""
-        with jax.named_scope(op.name):
+        are emitted under its device scope, `<Kind>:<name>`
+        (obs/scopes.py), so that a device profile's events (XLA's
+        op_name metadata) can be summed by operator kind and name;
+        a scope runs at trace time only, so the compiled step pays
+        nothing per iteration."""
+        with scopes.op_scope(op):
             self._exec_op_traced(op, env, ctx)
 
     def _exec_op_traced(self, op: Op, env: Dict[int, jax.Array], ctx: Dict):
@@ -857,8 +860,10 @@ class GraphExecutor:
             w = src[op.name][spec.name]
             if i < nt and self._z3_gather is not None:
                 w = self._z3_fetch(op.name, spec.name, w, ctx)
-            ws.append(w if spec.name in op.float32_weights
-                      else to_compute(w))
+            if spec.name not in op.float32_weights:
+                with scopes.scope(scopes.CAST_WEIGHTS):
+                    w = to_compute(w)
+            ws.append(w)
         op_rng = None
         if ctx["rng"] is not None:
             op_rng = jax.random.fold_in(ctx["rng"], op.guid)
@@ -898,9 +903,10 @@ class GraphExecutor:
             # block template ops are pinned logical (assign_layouts skips
             # block guids); materialize the region input to match
             act = jnp.transpose(act, TO_NCHW_PERM)
-        stacked = {
-            k: to_compute(v) for k, v in weights["__pipeline__"].items()
-        }
+        with scopes.scope(scopes.CAST_WEIGHTS):
+            stacked = {
+                k: to_compute(v) for k, v in weights["__pipeline__"].items()
+            }
         # per-layer index rides the stacked pytree so dropout rng can
         # fold in the physical block id inside the scanned body
         stacked["__layer__"] = jnp.arange(plan.num_blocks, dtype=jnp.int32)
@@ -918,7 +924,9 @@ class GraphExecutor:
                         jax.random.fold_in(rng, t_op.guid),
                         params["__layer__"],
                     )
-                outs = t_op.forward(ins, ws, training=training, rng=op_rng)
+                with scopes.op_scope(t_op):
+                    outs = t_op.forward(ins, ws, training=training,
+                                        rng=op_rng)
                 for pt, val in zip(t_op.outputs, outs):
                     local[pt.guid] = val
             return local[plan.template_out_guid]
@@ -1045,9 +1053,10 @@ class GraphExecutor:
                 logits, new_state, aux, env = self.run_forward(
                     w, state, inputs, training=True, rng=rng
                 )
-                loss_val = loss_obj(logits, labels)
-                for a in aux:
-                    loss_val = loss_val + a
+                with scopes.scope(scopes.LOSS):
+                    loss_val = loss_obj(logits, labels)
+                    for a in aux:
+                        loss_val = loss_val + a
                 # cache taps: each Cache op's live input batch, handed
                 # to the host for ring/score accounting (reference
                 # cache_update task, cache.cc:180-231); materialized
@@ -1067,19 +1076,21 @@ class GraphExecutor:
             (loss_val, (logits, new_state, taps)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(weights)
-            if grad_sh is not None:
-                # ZeRO-2+: the gradient buffer is reduce-scattered AT
-                # PRODUCTION and stays scattered through the update —
-                # per-device grad HBM drops by 1/N, and no pre-update
-                # gather ever materializes the full tree
-                grads = jax.tree.map(
-                    jax.lax.with_sharding_constraint, grads, grad_sh
-                )
-            new_w, new_opt_state = update_fn(weights, grads, opt_state)
-            m = metrics.compute(logits, labels)
-            m["loss"] = loss_val
-            if self.routed_expert_ops:
-                m["__moe__"] = self.moe_counts(new_state)
+            with scopes.scope(scopes.OPTIMIZER):
+                if grad_sh is not None:
+                    # ZeRO-2+: the gradient buffer is reduce-scattered AT
+                    # PRODUCTION and stays scattered through the update —
+                    # per-device grad HBM drops by 1/N, and no pre-update
+                    # gather ever materializes the full tree
+                    grads = jax.tree.map(
+                        jax.lax.with_sharding_constraint, grads, grad_sh
+                    )
+                new_w, new_opt_state = update_fn(weights, grads, opt_state)
+            with scopes.scope(scopes.METRICS):
+                m = metrics.compute(logits, labels)
+                m["loss"] = loss_val
+                if self.routed_expert_ops:
+                    m["__moe__"] = self.moe_counts(new_state)
             if taps:
                 m["__cache_taps__"] = taps
             return new_w, new_opt_state, new_state, m
@@ -1102,8 +1113,10 @@ class GraphExecutor:
             logits, _, _, _ = self.run_forward(
                 weights, state, inputs, training=False, rng=None
             )
-            m = metrics.compute(logits, labels)
-            m["loss"] = loss_obj(logits, labels)
+            with scopes.scope(scopes.METRICS):
+                m = metrics.compute(logits, labels)
+            with scopes.scope(scopes.LOSS):
+                m["loss"] = loss_obj(logits, labels)
             return m
 
         with self.mesh:

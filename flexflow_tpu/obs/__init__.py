@@ -1,6 +1,7 @@
 """obs — unified run telemetry (tracing, metrics, fidelity).
 
-Three pillars (docs/OBSERVABILITY.md):
+Three pillars (docs/OBSERVABILITY.md), and `scopes`, the grammar of the
+names the program gives its device work:
 
   * `trace`    — the one span primitive (`obs.trace.span`): always on,
                  into a bounded ring and the jax profiler's host plane;

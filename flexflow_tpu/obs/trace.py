@@ -20,8 +20,7 @@ guard), and no span sits inside a per-row or per-token loop.
 
 `trace_dir` decides only whether `Tracer.write` dumps the ring to a
 Chrome trace-event file (Perfetto / chrome://tracing), never whether a
-span is recorded.  `jax.named_scope` on every PCG op
-(executor._exec_op) attributes the device side to operator names.
+span is recorded.  The device side has its own names: `obs/scopes.py`.
 """
 from __future__ import annotations
 

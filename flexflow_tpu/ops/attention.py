@@ -27,6 +27,7 @@ import numpy as np
 
 from ..fftype import DataType, OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT, GlorotUniform
+from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .op import Op, ShapeError, WeightSpec
 
@@ -405,7 +406,50 @@ class MultiHeadAttention(Op):
     def forward(self, inputs, weights, *, training=False, rng=None):
         q, k, v = inputs
         p: MultiHeadAttentionParams = self.params
-        wq, wk, wv, wo = weights[:4]
+        wo = weights[3]
+        with scope("proj"):
+            qh, kh, vh, gate, bo = self._project(q, k, v, weights)
+        scale = 1.0 / np.sqrt(p.k_channels)
+
+        def out_of(ctx):
+            with scope("out"):
+                if gate is not None:
+                    ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
+                out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
+                if bo is not None:
+                    out = out + bo[None, None]
+                return out.astype(q.dtype)
+
+        if self._paged():
+            k_cache, v_cache, btab, slen = weights[-4:]
+            attend = (self._attend_decode_paged_once if p.paged_read_once
+                      else self._attend_decode_paged)
+            with scope("paged_read"):
+                ctx, k_cache, v_cache = attend(
+                    qh, kh, vh, k_cache, v_cache, btab, slen, scale
+                )
+            return [out_of(ctx), k_cache, v_cache, btab, slen]
+        if self._decode_n() > 0:
+            k_cache, v_cache, pos = weights[-3], weights[-2], weights[-1]
+            with scope("core"):
+                ctx, k_cache, v_cache, pos = self._attend_decode(
+                    qh, kh, vh, k_cache, v_cache, pos, scale
+                )
+            return [out_of(ctx), k_cache, v_cache, pos]
+        with scope("core"):
+            if p.group > 1:
+                # no cache: every query head gets its key/value head's copy
+                kh = jnp.repeat(kh, p.group, axis=2)
+                vh = jnp.repeat(vh, p.group, axis=2)
+            ctx = self._attend(qh, kh, vh, scale, training=training, rng=rng)
+        return [out_of(ctx)]
+
+    def _project(self, q, k, v, weights):
+        """(qh, kh, vh, gate or None, output bias or None): the three
+        projections with what is applied to them before the core (biases,
+        appended keys, the output gate's half of q, head norms, rotary)."""
+        p: MultiHeadAttentionParams = self.params
+        wq, wk, wv = weights[:3]
         wi = 4
         # [b, s, e] x [e, h, d] -> [b, s, h, d]
         qh = jnp.einsum("bse,ehd->bshd", q, wq)
@@ -442,42 +486,7 @@ class MultiHeadAttention(Op):
             positions = self._positions(q.shape[0], q.shape[1], weights)
             qh = rotate_half(qh, positions, p.rotary_dim, p.rope_theta)
             kh = rotate_half(kh, positions, p.rotary_dim, p.rope_theta)
-        scale = 1.0 / np.sqrt(p.k_channels)
-        if self._paged():
-            k_cache, v_cache, btab, slen = weights[-4:]
-            attend = (self._attend_decode_paged_once if p.paged_read_once
-                      else self._attend_decode_paged)
-            ctx, k_cache, v_cache = attend(
-                qh, kh, vh, k_cache, v_cache, btab, slen, scale
-            )
-            if gate is not None:
-                ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
-            out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
-            if bo is not None:
-                out = out + bo[None, None]
-            return [out.astype(q.dtype), k_cache, v_cache, btab, slen]
-        if self._decode_n() > 0:
-            k_cache, v_cache, pos = weights[-3], weights[-2], weights[-1]
-            ctx, k_cache, v_cache, pos = self._attend_decode(
-                qh, kh, vh, k_cache, v_cache, pos, scale
-            )
-            if gate is not None:
-                ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
-            out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
-            if bo is not None:
-                out = out + bo[None, None]
-            return [out.astype(q.dtype), k_cache, v_cache, pos]
-        if p.group > 1:
-            # no cache: every query head gets its key/value head's copy
-            kh = jnp.repeat(kh, p.group, axis=2)
-            vh = jnp.repeat(vh, p.group, axis=2)
-        ctx = self._attend(qh, kh, vh, scale, training=training, rng=rng)
-        if gate is not None:
-            ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
-        out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
-        if bo is not None:
-            out = out + bo[None, None]
-        return [out.astype(q.dtype)]
+        return qh, kh, vh, gate, bo
 
     def _positions(self, b, s, weights):
         """[b, s] positions of the step's tokens, for the rotary

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from ..fftype import DataType, OperatorType
 from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
+from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .op import Op, ShapeError, ShardConfig, WeightSpec
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
@@ -209,48 +210,57 @@ class GatedDeltaNet(Op):
         hk, hv, dk, dv = (p.num_k_heads, p.num_v_heads, p.head_k_dim,
                           p.head_v_dim)
         f32 = jnp.float32
-        mixed = jnp.matmul(x, w_qkvz)
-        qkv, z = mixed[..., :p.conv_dim], mixed[..., p.conv_dim:]
-        ba = jnp.matmul(x, w_ba, preferred_element_type=f32)
-        if self._slot_state:
-            tail, S, row_tokens = weights[7:]
-            count = jnp.clip(row_tokens.reshape(b).astype(jnp.int32), 0, s)
-        else:
-            tail = jnp.zeros((b, p.conv_kernel - 1, p.conv_dim), qkv.dtype)
-            S = jnp.zeros((b, hv, dk, dv), f32)
-            count = jnp.full((b,), s, jnp.int32)
-        # causal depthwise conv over [the row's last K - 1 inputs | the
-        # step's]: output t reads inputs t .. t + K - 1 of that window
-        window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
-        conv = jax.nn.silu(causal_depthwise_conv(window, conv_w, s))
-        # the window's K - 1 inputs that end at the row's last real one
-        last = count[:, None] + jnp.arange(p.conv_kernel - 1,
-                                           dtype=jnp.int32)
-        new_tail = jnp.take_along_axis(window, last[..., None], axis=1)
+        with scope("proj"):
+            mixed = jnp.matmul(x, w_qkvz)
+            qkv, z = mixed[..., :p.conv_dim], mixed[..., p.conv_dim:]
+            ba = jnp.matmul(x, w_ba, preferred_element_type=f32)
+        with scope("conv"):
+            if self._slot_state:
+                tail, S, row_tokens = weights[7:]
+                count = jnp.clip(row_tokens.reshape(b).astype(jnp.int32),
+                                 0, s)
+            else:
+                tail = jnp.zeros((b, p.conv_kernel - 1, p.conv_dim),
+                                 qkv.dtype)
+                S = jnp.zeros((b, hv, dk, dv), f32)
+                count = jnp.full((b,), s, jnp.int32)
+            # causal depthwise conv over [the row's last K - 1 inputs |
+            # the step's]: output t reads inputs t .. t + K - 1 of it
+            window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+            conv = jax.nn.silu(causal_depthwise_conv(window, conv_w, s))
+            # the window's K - 1 inputs that end at the row's last real one
+            last = count[:, None] + jnp.arange(p.conv_kernel - 1,
+                                               dtype=jnp.int32)
+            new_tail = jnp.take_along_axis(window, last[..., None], axis=1)
 
-        q = conv[..., :p.key_dim].reshape(b, s, hk, dk)
-        k = conv[..., p.key_dim:2 * p.key_dim].reshape(b, s, hk, dk)
-        v = conv[..., 2 * p.key_dim:].reshape(b, s, hv, dv)
-        q = jnp.repeat(l2norm(q) * dk ** -0.5, hv // hk, axis=2)
-        k = jnp.repeat(l2norm(k), hv // hk, axis=2)
-        real = (jnp.arange(s, dtype=jnp.int32)[None, :]
-                < count[:, None])[..., None]  # [b, s, 1]
-        beta = jnp.where(real, jax.nn.sigmoid(ba[..., :hv]), 0.0)
-        g = jnp.where(real, -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
-            ba[..., hv:] + dt_bias.astype(f32)), 0.0)
-        S = S.astype(f32)
-        if self.recurrence_plan(s) == "kernel":
-            S, o = gated_delta_rule(S, q, k, v, g, beta, count)
-        else:
-            S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
-        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
-                                       keepdims=True) + p.eps)
-        y = (o * norm_w.astype(f32)
-             * jax.nn.silu(z.astype(f32).reshape(b, s, hv, dv)))
-        out = jnp.matmul(y.reshape(b, s, p.value_dim).astype(x.dtype), w_out)
+        with scope("recurrence"):  # with what it is fed: q, k, v, g, beta
+            q = conv[..., :p.key_dim].reshape(b, s, hk, dk)
+            k = conv[..., p.key_dim:2 * p.key_dim].reshape(b, s, hk, dk)
+            v = conv[..., 2 * p.key_dim:].reshape(b, s, hv, dv)
+            q = jnp.repeat(l2norm(q) * dk ** -0.5, hv // hk, axis=2)
+            k = jnp.repeat(l2norm(k), hv // hk, axis=2)
+            real = (jnp.arange(s, dtype=jnp.int32)[None, :]
+                    < count[:, None])[..., None]  # [b, s, 1]
+            beta = jnp.where(real, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+            g = jnp.where(
+                real, -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                    ba[..., hv:] + dt_bias.astype(f32)), 0.0)
+            S = S.astype(f32)
+            if self.recurrence_plan(s) == "kernel":
+                S, o = gated_delta_rule(S, q, k, v, g, beta, count)
+            else:
+                S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
+        with scope("out"):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + p.eps)
+            y = (o * norm_w.astype(f32)
+                 * jax.nn.silu(z.astype(f32).reshape(b, s, hv, dv)))
+            out = jnp.matmul(y.reshape(b, s, p.value_dim).astype(x.dtype),
+                             w_out)
+            out = out.astype(x.dtype)
         if not self._slot_state:
-            return [out.astype(x.dtype)]
-        return [out.astype(x.dtype), new_tail, S, row_tokens]
+            return [out]
+        return [out, new_tail, S, row_tokens]
 
     def flops(self):
         """The four products, the conv, and the recurrence: a position

@@ -49,6 +49,7 @@ import numpy as np
 from ..fftype import DataType, OperatorType
 from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
+from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .norm import rms_normalize
 from .op import Op, ShapeError, WeightSpec
@@ -245,41 +246,48 @@ class MLAttention(Op):
         p: MLAParams = self.params
         wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo = weights[:7]
         dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
-        cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
-        q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
-        q_nope, q_rope = q[..., :dn], rope(q[..., dn:], positions, p)
-        kv = jnp.matmul(x, wkv_a)
-        latent = jnp.concatenate(
-            [rms_normalize(kv[..., :rk], kv_norm, p.eps),
-             rope(kv[..., rk:], positions, p)], axis=-1)  # [b, s, rk + dr]
+        with scope("proj"):
+            cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
+            q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
+            q_nope, q_rope = q[..., :dn], rope(q[..., dn:], positions, p)
+            kv = jnp.matmul(x, wkv_a)
+            latent = jnp.concatenate(
+                [rms_normalize(kv[..., :rk], kv_norm, p.eps),
+                 rope(kv[..., rk:], positions, p)], axis=-1)  # [b, s, rk + dr]
         if self._paged():
             pool, btab, slen = weights[7:]
             if x.shape[1] > 1:
-                ctx, pool = self._attend_paged_chunk(
-                    q_nope, q_rope, latent, wkv_b, pool, btab, slen)
-                out = jnp.einsum("bshd,hde->bse", ctx, wo)
-                return [out.astype(x.dtype), pool, btab, slen]
+                with scope("paged_read"):
+                    ctx, pool = self._attend_paged_chunk(
+                        q_nope, q_rope, latent, wkv_b, pool, btab, slen)
+                with scope("out"):
+                    out = jnp.einsum("bshd,hde->bse", ctx, wo)
+                    return [out.astype(x.dtype), pool, btab, slen]
             # seq 1 keeps the decode step's own trace (no size-1 axes)
-            ctx, pool = self._attend_paged(q_nope[:, 0], q_rope[:, 0],
-                                           latent[:, 0], wkv_b, pool,
-                                           btab, slen)
-            out = jnp.einsum("bhd,hde->be", ctx, wo)[:, None]
-            return [out.astype(x.dtype), pool, btab, slen]
+            with scope("paged_read"):
+                ctx, pool = self._attend_paged(q_nope[:, 0], q_rope[:, 0],
+                                               latent[:, 0], wkv_b, pool,
+                                               btab, slen)
+            with scope("out"):
+                out = jnp.einsum("bhd,hde->be", ctx, wo)[:, None]
+                return [out.astype(x.dtype), pool, btab, slen]
         # expanded: keys and values out of the latent, causal attention
         # over the step's own tokens
-        c, k_rope = latent[..., :rk], latent[..., rk:]
-        kvh = jnp.einsum("bsc,chd->bshd", c, wkv_b)
-        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kvh[..., :dn],
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
-                               preferred_element_type=jnp.float32))
-        s = x.shape[1]
-        keep = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(keep, scores * softmax_scale(p),
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kvh[..., dn:])
-        return [jnp.einsum("bqhd,hde->bqe", ctx, wo).astype(x.dtype)]
+        with scope("core"):
+            c, k_rope = latent[..., :rk], latent[..., rk:]
+            kvh = jnp.einsum("bsc,chd->bshd", c, wkv_b)
+            scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kvh[..., :dn],
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                                   preferred_element_type=jnp.float32))
+            s = x.shape[1]
+            keep = jnp.tril(jnp.ones((s, s), bool))
+            scores = jnp.where(keep, scores * softmax_scale(p),
+                               jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kvh[..., dn:])
+        with scope("out"):
+            return [jnp.einsum("bqhd,hde->bqe", ctx, wo).astype(x.dtype)]
 
     def _attend_paged(self, q_nope, q_rope, latent, wkv_b, pool, btab,
                       slen):

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from ..fftype import DataType, OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT, ZeroInitializer
+from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .dense import gated_mlp
 from .op import Op, ShapeError, WeightSpec, remat_keep
@@ -125,10 +126,12 @@ def dense_experts(h, combine, w_gate, w_up, w_down):
     """The dense product: every held expert (axis x) over every row of
     h [t, e], combined with `combine` [t, held], the routing weights
     (zero where an expert was not chosen) -> [t, e]."""
-    gate = jnp.einsum("te,xef->xtf", h, w_gate)
-    up = jnp.einsum("te,xef->xtf", h, w_up)
-    y = jnp.einsum("xtf,xfe->xte", jax.nn.silu(gate) * up, w_down)
-    return jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
+    with scope("products"):
+        gate = jnp.einsum("te,xef->xtf", h, w_gate)
+        up = jnp.einsum("te,xef->xtf", h, w_up)
+        y = jnp.einsum("xtf,xfe->xte", jax.nn.silu(gate) * up, w_down)
+    with scope("combine"):
+        return jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
 
 
 def grouped_matmul(lhs, rhs, sizes):
@@ -200,47 +203,64 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
     one runs).  The products visit the held runs and nothing after."""
     t, k = landed_on.shape
     held = w_gate.shape[0]
-    order = jnp.argsort(landed_on.reshape(-1), stable=True)  # slot -> pair
-    slot_of = jnp.argsort(order).reshape(t, k).astype(jnp.int32)
-    order = order.astype(jnp.int32)
-    sizes = jnp.sum(jax.nn.one_hot(landed_on.reshape(-1), held,
-                                   dtype=jnp.int32), axis=0)
-    count = jnp.sum(sizes)
-    weights = jnp.where(landed_on < held, w, 0)
+    with scope("dispatch"):
+        order = jnp.argsort(landed_on.reshape(-1), stable=True)  # slot -> pair
+        slot_of = jnp.argsort(order).reshape(t, k).astype(jnp.int32)
+        order = order.astype(jnp.int32)
+        sizes = jnp.sum(jax.nn.one_hot(landed_on.reshape(-1), held,
+                                       dtype=jnp.int32), axis=0)
+        count = jnp.sum(sizes)
+        weights = jnp.where(landed_on < held, w, 0)
 
     m_all = t * k
     m_usual = min(m_all, -(-int(GROUPED_SLACK * expected_pairs)
                            // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
 
     def kept(m, h, weights, w_gate, w_up, w_down):
-        live = (jnp.arange(m, dtype=jnp.int32) < count)[:, None]
+        with scope("dispatch"):
+            live = (jnp.arange(m, dtype=jnp.int32) < count)[:, None]
 
         def product(x, weight):
-            y = grouped_matmul(jnp.where(live, x, 0), weight, sizes)
-            if m == m_usual:
-                # a checkpointed segment may hold the usual buffers'
-                # products; the overflow's (a `cond` keeps BOTH
-                # branches' residuals alive) are computed again
-                y = remat_keep(y)
+            with scope("dispatch"):
+                x = jnp.where(live, x, 0)
+            with scope("products"):
+                y = grouped_matmul(x, weight, sizes)
+                if m == m_usual:
+                    # a checkpointed segment may hold the usual buffers'
+                    # products; the overflow's (a `cond` keeps BOTH
+                    # branches' residuals alive) are computed again
+                    y = remat_keep(y)
             # zeros past the held runs on both sides, so that neither a
             # value nor a gradient of a row nobody multiplied goes on
-            return jnp.where(live, y, 0).astype(h.dtype)
+            with scope("dispatch"):
+                return jnp.where(live, y, 0).astype(h.dtype)
 
-        xs = _rows_to_slots(h, order[:m], slot_of)
-        ys = product(jax.nn.silu(product(xs, w_gate)) * product(xs, w_up),
-                     w_down)
-        pairs = _slots_to_pairs(ys, order[:m], slot_of)
-        return jnp.einsum("tke,tk->te", pairs, weights.astype(pairs.dtype))
+        with scope("dispatch"):
+            xs = _rows_to_slots(h, order[:m], slot_of)
+        gate = product(xs, w_gate)
+        with scope("products"):
+            gate = jax.nn.silu(gate)
+        up = product(xs, w_up)
+        with scope("products"):
+            act = gate * up
+        ys = product(act, w_down)
+        with scope("dispatch"):
+            pairs = _slots_to_pairs(ys, order[:m], slot_of)
+        with scope("combine"):
+            return jnp.einsum("tke,tk->te", pairs,
+                              weights.astype(pairs.dtype))
 
     args = (h, weights, w_gate, w_up, w_down)
     if m_usual == m_all:
         out = kept(m_all, *args)
     else:
-        out = jax.lax.cond(count <= m_usual,
-                           functools.partial(kept, m_usual),
+        with scope("dispatch"):
+            fits = count <= m_usual
+        out = jax.lax.cond(fits, functools.partial(kept, m_usual),
                            functools.partial(kept, m_all), *args)
-    tiles = -(-sizes // GROUPED_ROW_TILE)
-    return out, jnp.sum(tiles) * GROUPED_ROW_TILE
+    with scope("dispatch"):
+        tiles = -(-sizes // GROUPED_ROW_TILE)
+        return out, jnp.sum(tiles) * GROUPED_ROW_TILE
 
 
 class RoutedExperts(Op):
@@ -334,40 +354,52 @@ class RoutedExperts(Op):
         (x,) = inputs
         p: RoutedExpertsParams = self.params
         router, bias, w_gate, w_up, w_down = weights[:5]
-        h = x.reshape(-1, x.shape[-1])
-        chosen, w = route(h, router, bias, p)
-        # [t, k, held]: which held expert each chosen pair landed on;
-        # a pair for an expert that lives elsewhere is all zeros
-        at = chosen - p.first_held
-        landed = jax.nn.one_hot(at, p.experts_held, dtype=jnp.float32)
-        combine = jnp.einsum("tkx,tk->tx", landed, w)
+        with scope("route"):
+            h = x.reshape(-1, x.shape[-1])
+            chosen, w = route(h, router, bias, p)
+        with scope("dispatch"):
+            # [t, k, held]: which held expert each chosen pair landed
+            # on; a pair for an expert that lives elsewhere is all zeros
+            at = chosen - p.first_held
+            landed = jax.nn.one_hot(at, p.experts_held, dtype=jnp.float32)
+            combine = jnp.einsum("tkx,tk->tx", landed, w)
         grouped = self.product_plan() == "grouped"
         if grouped:
+            with scope("dispatch"):
+                landed_on = jnp.where((at >= 0) & (at < p.experts_held),
+                                      at, p.experts_held)
             out, rows_computed = grouped_experts(
-                h, jnp.where((at >= 0) & (at < p.experts_held), at,
-                             p.experts_held), w, w_gate, w_up, w_down,
+                h, landed_on, w, w_gate, w_up, w_down,
                 h.shape[0] * p.top_k * p.experts_held / p.experts_total)
         else:
             out = dense_experts(h, combine, w_gate, w_up, w_down)
-        if p.shared_expert_gate:
-            g = jax.nn.sigmoid(jnp.einsum(
-                "te,e->t", h, weights[8],
-                preferred_element_type=jnp.float32))
-            out = out + gated_mlp(h, *weights[5:8]) * g[:, None].astype(
-                out.dtype)
-        elif p.shared_hidden:
-            out = out + gated_mlp(h, *weights[5:8])
-        rows = jnp.sum(landed, axis=(0, 1)).astype(jnp.int32)  # [held]
-        pairs = jnp.sum(rows)
-        stats = jnp.stack([
-            pairs,
-            pairs - jnp.sum(combine != 0).astype(jnp.int32),
-            jnp.max(rows),
-            jnp.sum(rows > 0).astype(jnp.int32),
-        ])
-        out = out.reshape(x.shape).astype(x.dtype)
+        if p.shared_hidden:
+            with scope("shared"):
+                if p.shared_expert_gate:
+                    g = jax.nn.sigmoid(jnp.einsum(
+                        "te,e->t", h, weights[8],
+                        preferred_element_type=jnp.float32))
+                    shared = gated_mlp(h, *weights[5:8]) * g[
+                        :, None].astype(out.dtype)
+                else:
+                    shared = gated_mlp(h, *weights[5:8])
+            with scope("combine"):
+                out = out + shared
+        with scope("dispatch"):  # the counts of it
+            rows = jnp.sum(landed, axis=(0, 1)).astype(jnp.int32)  # [held]
+            pairs = jnp.sum(rows)
+            stats = jnp.stack([
+                pairs,
+                pairs - jnp.sum(combine != 0).astype(jnp.int32),
+                jnp.max(rows),
+                jnp.sum(rows > 0).astype(jnp.int32),
+            ])
+        with scope("combine"):
+            out = out.reshape(x.shape).astype(x.dtype)
         if grouped:
-            return [out, stats, rows_computed.reshape(1).astype(jnp.int32)]
+            with scope("dispatch"):
+                rows_computed = rows_computed.reshape(1).astype(jnp.int32)
+            return [out, stats, rows_computed]
         return [out, stats]
 
     def flops(self):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..fftype import OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT
+from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .op import Op, ShapeError, WeightSpec
 
@@ -81,13 +82,18 @@ class ShortConv(Op):
         p: ShortConvParams = self.params
         w_in, taps, w_out = weights
         b, s, e = x.shape
-        bcx = jnp.matmul(x, w_in)
-        gate_b, gate_c, xs = bcx[..., :e], bcx[..., e:2 * e], bcx[..., 2 * e:]
-        bx = gate_b * xs
-        window = jnp.concatenate(
-            [jnp.zeros((b, p.kernel - 1, e), bx.dtype), bx], axis=1)
-        y = gate_c * causal_depthwise_conv(window, taps, s).astype(x.dtype)
-        return [jnp.matmul(y, w_out)]
+        with scope("proj"):
+            bcx = jnp.matmul(x, w_in)
+            gate_b, gate_c, xs = (bcx[..., :e], bcx[..., e:2 * e],
+                                  bcx[..., 2 * e:])
+            bx = gate_b * xs
+        with scope("conv"):
+            window = jnp.concatenate(
+                [jnp.zeros((b, p.kernel - 1, e), bx.dtype), bx], axis=1)
+            y = gate_c * causal_depthwise_conv(window, taps, s).astype(
+                x.dtype)
+        with scope("out"):
+            return [jnp.matmul(y, w_out)]
 
     def flops(self):
         p: ShortConvParams = self.params

@@ -51,6 +51,31 @@ def report(name, compiled, seconds):
     return compiled
 
 
+def abstract_train_model(fam, cfg, batch: int, seq: int):
+    """The family's model built and compiled on the CPU with its weights
+    and Adam state left ABSTRACT (`jax.eval_shape`): the real size costs
+    no memory.  Shared with `dump_step_hlo.py`."""
+    import flexflow_tpu.optimizer as opt_mod
+    from flexflow_tpu.executor import GraphExecutor
+
+    real_init = GraphExecutor.init_weights
+    GraphExecutor.init_weights = lambda self, seed=0, state_only=False: \
+        jax.eval_shape(lambda: real_init(self, seed, state_only))
+    real_state = opt_mod.AdamOptimizer.init_state
+
+    def abstract_state(self, w):
+        state = jax.eval_shape(lambda: real_state(self, jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype), w)))
+        # the step counter is placed on the mesh: a real scalar
+        return {k: v if isinstance(v, dict) else jnp.zeros(v.shape, v.dtype)
+                for k, v in state.items()}
+
+    opt_mod.AdamOptimizer.init_state = abstract_state
+    ff = fam.build_model(cfg, batch, seq, 1)
+    fam.compile_model(ff, cfg, jax.devices()[:1])
+    return ff
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -87,25 +112,7 @@ def main() -> int:
             x.shape, x.dtype, sharding=chip), tree)
 
     if not args.no_step:
-        from flexflow_tpu.executor import GraphExecutor
-
-        real_init = GraphExecutor.init_weights
-        GraphExecutor.init_weights = lambda self, seed=0, state_only=False: \
-            jax.eval_shape(lambda: real_init(self, seed, state_only))
-        ff = fam.build_model(cfg, batch, seq, 1)
-        import flexflow_tpu.optimizer as opt_mod
-
-        real_state = opt_mod.AdamOptimizer.init_state
-
-        def abstract_state(self, w):
-            state = jax.eval_shape(lambda: real_state(self, jax.tree.map(
-                lambda x: jnp.zeros(x.shape, x.dtype), w)))
-            # the step counter is placed on the mesh: a real scalar
-            return {k: v if isinstance(v, dict) else jnp.zeros(v.shape, v.dtype)
-                    for k, v in state.items()}
-
-        opt_mod.AdamOptimizer.init_state = abstract_state
-        fam.compile_model(ff, cfg, jax.devices()[:1])
+        ff = abstract_train_model(fam, cfg, batch, seq)
         inputs, labels = fam.make_batch(cfg, batch, seq,
                                         np.random.default_rng(0))
         structs = on_chip((ff._weights, ff._opt_state, ff._state,

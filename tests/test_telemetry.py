@@ -245,11 +245,18 @@ def test_calib_logger_lands_in_telemetry():
 
 
 # ---------------------------------------------------------------------------
-# named_scope: op names in the compiled step HLO
+# device scopes: op kinds and names in the compiled step HLO
 # ---------------------------------------------------------------------------
 
 def test_named_scope_op_names_in_step_hlo():
+    """Every PCG op of the step names its instructions `<Kind>:<name>`
+    (obs/scopes.py), and `parse` reads both back from the compiled
+    HLO's metadata; tests/test_device_scopes.py holds the grammar."""
+    import re
+
     import jax
+
+    from flexflow_tpu.obs.scopes import parse
 
     cfg = FFConfig(batch_size=8, num_devices=1)
     ff = _build_mlp(cfg)
@@ -260,9 +267,12 @@ def test_named_scope_op_names_in_step_hlo():
         ff._weights, ff._opt_state, ff._state, put_inputs, put_labels, rng
     )
     hlo = lowered.compile().as_text()
+    found = {(s.kind, s.name)
+             for s in map(parse, re.findall(r'op_name="([^"]*)"', hlo))}
     for op in ff.operators.topo_order():
         if op.name.startswith("dense"):
-            assert op.name in hlo  # named_scope carried into op metadata
+            assert (type(op).__name__, op.name) in found
+    assert {("loss", None), ("optimizer", None)} <= found
 
 
 # ---------------------------------------------------------------------------
