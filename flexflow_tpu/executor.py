@@ -1011,18 +1011,19 @@ class GraphExecutor:
                 and op.guid not in self._block_guids]
 
     def moe_counts(self, state) -> jax.Array:
-        """int32 [5], summed over the routed-expert layers of one step:
+        """int32 [6], summed over the routed-expert layers of one step:
         `MOE_STATS` in their order, then the rows the chosen product
-        multiplied (the grouped product counts its own in
-        `moe_rows_computed`; the dense one's is static)."""
-        total = jnp.zeros((5,), jnp.int32)
+        multiplied and the layers that took the every-pair size (the
+        grouped product counts both in `moe_rows_computed`; the dense
+        one's rows are static and it has one size)."""
+        total = jnp.zeros((6,), jnp.int32)
         for op in self.routed_expert_ops:
             entries = state[op.name]
-            rows = (entries["moe_rows_computed"][0]
-                    if "moe_rows_computed" in entries
-                    else jnp.int32(op.dense_rows_computed()))
-            total = total + jnp.concatenate(
-                [entries["moe_stats"], rows.reshape(1)])
+            grouped = (entries["moe_rows_computed"]
+                       if "moe_rows_computed" in entries
+                       else jnp.array([op.dense_rows_computed(), 0],
+                                      jnp.int32))
+            total = total + jnp.concatenate([entries["moe_stats"], grouped])
         return total
 
     def build_step(self):

@@ -1244,7 +1244,7 @@ class FFModel:
         return m
 
     def _report_moe(self, counts) -> None:
-        """The routed-expert layers' counts of this step (an int32 [5]
+        """The routed-expert layers' counts of this step (an int32 [6]
         device array the step returns: `executor.moe_counts`) are kept;
         the NEWEST earlier step whose counts have arrived is reported
         as a `train_step.moe` child span and added to the
@@ -1257,16 +1257,17 @@ class FFModel:
             return
         step, counts = pending[ready[-1]]
         del pending[:ready[-1] + 1]
-        pairs, dropped, max_rows, hit, rows_computed = (
+        pairs, dropped, max_rows, hit, rows_computed, overflow = (
             int(v) for v in np.asarray(counts))
         with span("train_step.moe", step=step, moe_pairs=pairs,
                   moe_dropped=dropped, moe_max_rows=max_rows, moe_hit=hit,
-                  moe_rows_computed=rows_computed):
+                  moe_rows_computed=rows_computed, moe_overflow=overflow):
             reg = self.telemetry.metrics
             reg.counter("train/moe_steps").inc()
             reg.counter("train/moe_pairs").inc(pairs)
             reg.counter("train/moe_dropped").inc(dropped)
             reg.counter("train/moe_rows_computed").inc(rows_computed)
+            reg.counter("train/moe_overflow").inc(overflow)
 
     def eval_step(self, inputs: Dict[str, np.ndarray], labels: np.ndarray):
         self._check_not_decode_graph("eval_step()")

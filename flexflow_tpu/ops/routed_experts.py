@@ -31,14 +31,26 @@ which `pick_expert_product` chooses one from the step's shapes:
   router sends here; every pair of the step when there are more), the
   product visits the held runs only.  What a training step's thousands
   of rows take: dense would multiply `experts_total / top_k` times what
-  the routing asked for.
+  the routing asked for.  Forward and backward are written out
+  (`_grouped`, one `custom_vjp`): slot-sized buffers, one gather in and
+  k out a pass, no mask pass (a select inside the two sums over a
+  token's slots drops the pairs not held), and only the usual buffers'
+  three products kept for the backward pass.  A step that OVERFLOWS the
+  usual buffers is as right and as dropless as any other and pays for
+  it twice: its forward runs every pair's rows (`top_k x rows`, 2.7 x
+  the usual buffers in the LFM2 cell) and its backward runs that
+  forward again, because the every-pair size keeps nothing
+  (`moe_overflow` counts such layers; 18.7 against 12.0 ms a layer on
+  the v5e, PERF.md).
 
 `moe_stats` counts what a dispatch with a smaller capacity would have
 to get right: routed pairs that landed on held experts, pairs the
 combine left out (0), the rows of the fullest held expert, and the held
 experts that received a row.  The grouped product also counts the rows
-it multiplied (`moe_rows_computed`, padding of the product's row tiles
-included); the dense product's is the static `rows x experts_held`.
+it multiplied and whether the step overflowed (`moe_rows_computed`
+[2]: padding of the product's row tiles included; 1 where the layer
+took the every-pair size); the dense product's rows are the static
+`rows x experts_held`.
 """
 from __future__ import annotations
 
@@ -137,54 +149,194 @@ def dense_experts(h, combine, w_gate, w_up, w_down):
 def grouped_matmul(lhs, rhs, sizes):
     """lhs [m, k] whose rows run expert after expert, `sizes[g]` rows
     for expert g (their sum may stay below m); rhs [g, k, n] -> [m, n].
-    Rows past the last group come out as zeros."""
+    Rows of lhs past the last group are not read.  Rows of the RESULT
+    past the last group are NOT DEFINED: the CPU's lowering gives
+    zeros, libtpu's kernel leaves them unwritten, whatever the buffer
+    held before, NaN included (measured on the v5e, PR 39).  Nothing may
+    read them but another grouped product, or a select that drops
+    them."""
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
-@jax.custom_vjp
-def _rows_to_slots(h, order, slot_of):
-    """h [t, e] -> [m, e]: slot s < m holds the row of the token of pair
-    `order[s]` (`order` [m]: the first m slots' pairs).  Backward is a
-    gather too (`slot_of` [t, k] is the inverse permutation; a pair
-    whose slot is m or later was not kept), not a scatter-add."""
-    return h[order // slot_of.shape[1]]
+def grouped_matmul_into_lhs(ct, rhs, sizes):
+    """`grouped_matmul`'s gradient into its lhs: ct [m, n], rhs
+    [g, k, n] -> [m, k], each run of rows times its own expert's weight
+    transposed (a copy of the weight a call: libtpu's kernel contracts
+    rhs' middle axis only).  Its rows past the last group: as
+    `grouped_matmul`'s."""
+    return grouped_matmul(ct, jnp.swapaxes(rhs, 1, 2), sizes)
 
 
-def _rows_to_slots_fwd(h, order, slot_of):
-    return _rows_to_slots(h, order, slot_of), slot_of
+def grouped_matmul_into_rhs(lhs, ct, sizes):
+    """`grouped_matmul`'s gradient into its rhs: lhs [m, k], ct [m, n]
+    -> [g, k, n], each run of rows contracted into its own expert's
+    slice.  Rows past the last group are not read and enter no slice,
+    on either backend."""
+    return jax.lax.ragged_dot_general(
+        lhs, ct, sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
 
 
-def _rows_to_slots_bwd(slot_of, d_slots):
-    return jnp.sum(_kept_rows(d_slots, slot_of), axis=1), None, None
+def _activation(gate, up):
+    return jax.nn.silu(gate) * up
 
 
-_rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
+def _sum_of_slots(rows, slot_of, kept, scale=None):
+    """rows [m, e]; slot_of, kept [t, k] -> [t, e]: the rows of a
+    token's `kept` slots summed in float32, each times `scale[t, k]`
+    (rounded to the rows' precision first) where that is given.  A pair
+    not kept adds an exact zero whatever the row its number falls on
+    holds: the one mask of the layer, a select inside the sum.  One
+    gather of t rows a chosen expert, accumulated in turn: nothing of
+    [t, k, e] is written."""
+    at = jnp.minimum(slot_of, rows.shape[0] - 1)
+    total = None
+    for j in range(slot_of.shape[1]):
+        # the select first, on the gathered rows as they are: XLA then
+        # fuses the widening into the sum (measured the other way round:
+        # four [t, e] float32 copies a pass, PR 39)
+        term = jnp.where(kept[:, j, None], rows[at[:, j]], 0).astype(
+            jnp.float32)
+        if scale is not None:
+            term = term * scale[:, j, None].astype(rows.dtype).astype(
+                jnp.float32)
+        total = term if total is None else total + term
+    return total.astype(rows.dtype)
 
 
-def _kept_rows(ys, slot_of):
-    """ys [m, e] -> [t, k, e]: every pair's row; zeros for a pair whose
-    slot was not kept."""
-    m = ys.shape[0]
-    rows = ys[jnp.minimum(slot_of, m - 1)]
-    return jnp.where((slot_of < m)[..., None], rows, 0)
+def _slot_products(m, h, order, slot_of, sizes, w_gate, w_up, w_down):
+    """The experts over the first m slots -> gate, up [m, f] and ys
+    [m, e], the three products.  No mask: a slot past the held runs
+    gathers some token's row, which no group reads, and what the
+    products leave in such a slot (`grouped_matmul`) only a grouped
+    product or `_sum_of_slots`' select meets."""
+    with scope("dispatch"):
+        xs = h[order[:m] // slot_of.shape[1]]
+    with scope("products"):
+        gate = grouped_matmul(xs, w_gate, sizes)
+        up = grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(_activation(gate, up), w_down, sizes)
+    return gate, up, ys
 
 
-@jax.custom_vjp
-def _slots_to_pairs(ys, order, slot_of):
-    """ys [m, e] -> [t, k, e], back in token order (`_kept_rows`);
-    backward the inverse gather."""
-    return _kept_rows(ys, slot_of)
+def _held(slot_of, sizes):
+    """[t, k]: the pairs on held experts, whose runs fill the first
+    slots."""
+    return slot_of < jnp.sum(sizes)
 
 
-def _slots_to_pairs_fwd(ys, order, slot_of):
-    return _slots_to_pairs(ys, order, slot_of), order
+def _slots_forward(m, h, order, slot_of, sizes, weights,
+                   w_gate, w_up, w_down):
+    """The layer over the first m slots, which hold every held pair
+    -> (out [t, e], gate, up, ys)."""
+    products = _slot_products(m, h, order, slot_of, sizes,
+                              w_gate, w_up, w_down)
+    with scope("combine"):
+        return (_sum_of_slots(products[-1], slot_of, _held(slot_of, sizes),
+                              weights), *products)
 
 
-def _slots_to_pairs_bwd(order, d_pairs):
-    return d_pairs.reshape(-1, d_pairs.shape[-1])[order], None, None
+def _slots_backward(m, h, order, slot_of, sizes, weights, w_gate, w_up,
+                    w_down, gate, up, ys, d_out):
+    """`_slots_forward`'s gradient into (h, weights, w_gate, w_up,
+    w_down) from its products.  The routing weight of a slot past the
+    held runs is zero, so the gradient that enters the slots is zero
+    there: the mask of the backward pass is the multiply that makes
+    `d_ys`.  What the products leave there afterwards is dropped where
+    the sums select the held pairs."""
+    with scope("dispatch"):
+        pair = order[:m]
+        token = pair // slot_of.shape[1]
+        xs = h[token]
+        w_slot = weights.reshape(-1)[pair].astype(d_out.dtype)
+        held = _held(slot_of, sizes)
+    with scope("combine"):
+        d_rows = d_out[token]  # what reached the slot's token
+        d_ys = d_rows * w_slot[:, None]
+        d_w_slot = jnp.sum(ys.astype(jnp.float32)
+                           * d_rows.astype(jnp.float32), axis=-1)
+        d_weights = jnp.where(
+            held, d_w_slot[jnp.minimum(slot_of, m - 1)], 0).astype(
+                weights.dtype)
+    with scope("products"):
+        act, d_activation = jax.vjp(_activation, gate, up)
+        d_w_down = grouped_matmul_into_rhs(act, d_ys, sizes)
+        d_gate, d_up = d_activation(
+            grouped_matmul_into_lhs(d_ys, w_down, sizes))
+        d_w_gate = grouped_matmul_into_rhs(xs, d_gate, sizes)
+        d_w_up = grouped_matmul_into_rhs(xs, d_up, sizes)
+        d_xs = (grouped_matmul_into_lhs(d_gate, w_gate, sizes)
+                + grouped_matmul_into_lhs(d_up, w_up, sizes))
+    with scope("dispatch"):
+        d_h = _sum_of_slots(d_xs, slot_of, held)
+    return (d_h, d_weights, d_w_gate.astype(w_gate.dtype),
+            d_w_up.astype(w_up.dtype), d_w_down.astype(w_down.dtype))
 
 
-_slots_to_pairs.defvjp(_slots_to_pairs_fwd, _slots_to_pairs_bwd)
+def _fits(m_usual, slot_of, sizes):
+    """None where the usual buffers hold every pair whatever the load,
+    else whether they hold this step's."""
+    if m_usual == slot_of.size:
+        return None
+    with scope("dispatch"):
+        return jnp.sum(sizes) <= m_usual
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _grouped(m_usual, h, order, slot_of, sizes, weights,
+             w_gate, w_up, w_down):
+    """`grouped_experts` after its sort, forward and backward written
+    out (`_slots_forward`, `_slots_backward`) at two sizes: the first
+    `m_usual` slots or, on a step whose held pairs overflow them, every
+    slot.  The choice (`lax.cond`) is made inside either rule, so both
+    branches give the same shapes and the backward rule's residuals are
+    the usual size's alone."""
+    return _grouped_fwd(m_usual, h, order, slot_of, sizes, weights,
+                        w_gate, w_up, w_down)[0]
+
+
+def _grouped_fwd(m_usual, h, order, slot_of, sizes, weights,
+                 w_gate, w_up, w_down):
+    args = (h, order, slot_of, sizes, weights, w_gate, w_up, w_down)
+    fits = _fits(m_usual, slot_of, sizes)
+    usual = functools.partial(_slots_forward, m_usual)
+    if fits is None:
+        out, *products = usual(*args)
+    else:
+        def overflow(*args):
+            # nothing of this size is kept: its backward runs it again
+            out, *products = _slots_forward(slot_of.size, *args)
+            with scope("dispatch"):
+                return (out, *(jnp.zeros((m_usual,) + y.shape[1:], y.dtype)
+                               for y in products))
+
+        out, *products = jax.lax.cond(fits, usual, overflow, *args)
+    # what a checkpointed segment may hold of this op (the flash
+    # kernels name their outputs the same way)
+    return out, args + tuple(map(remat_keep, products))
+
+
+def _grouped_bwd(m_usual, residuals, d_out):
+    fits = _fits(m_usual, *residuals[2:4])  # slot_of, sizes
+    usual = functools.partial(_slots_backward, m_usual)
+    if fits is None:
+        grads = usual(*residuals, d_out)
+    else:
+        def overflow(h, order, slot_of, sizes, weights, w_gate, w_up,
+                     w_down, _gate, _up, _ys, d_out):
+            m_all = slot_of.size
+            products = _slot_products(m_all, h, order, slot_of, sizes,
+                                      w_gate, w_up, w_down)
+            return _slots_backward(m_all, h, order, slot_of, sizes, weights,
+                                   w_gate, w_up, w_down, *products, d_out)
+
+        grads = jax.lax.cond(fits, usual, overflow, *residuals, d_out)
+    d_h, d_weights, *d_experts = grads
+    return (d_h, None, None, None, d_weights, *d_experts)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
@@ -193,14 +345,15 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
     (0 .. held - 1) each chosen pair landed on, or `held` for an expert
     that lives elsewhere; w [t, k] the routing weights; `expected_pairs`
     the pairs an even router sends to the held experts (t * k * held /
-    total) -> (out [t, e], rows multiplied, tile padding included).
+    total) -> (out [t, e]; int32 [2]: the rows multiplied, tile padding
+    included, and 1 where the step took the every-pair size).
 
     The step's t * k pairs are sorted by expert, the held experts' runs
     first.  Static shapes and no drop at any load: the buffers keep the
     first m slots, where m is `GROUPED_SLACK` times the pairs the held
     experts expect when they hold that many, and EVERY pair when they
-    hold more (one `lax.cond` on the count: both sizes are compiled,
-    one runs).  The products visit the held runs and nothing after."""
+    hold more (`_grouped`: both sizes are compiled, one runs).  The
+    products visit the held runs and nothing after."""
     t, k = landed_on.shape
     held = w_gate.shape[0]
     with scope("dispatch"):
@@ -209,58 +362,17 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
         order = order.astype(jnp.int32)
         sizes = jnp.sum(jax.nn.one_hot(landed_on.reshape(-1), held,
                                        dtype=jnp.int32), axis=0)
-        count = jnp.sum(sizes)
         weights = jnp.where(landed_on < held, w, 0)
-
     m_all = t * k
-    m_usual = min(m_all, -(-int(GROUPED_SLACK * expected_pairs)
+    m_usual = min(m_all, -(-max(1, int(GROUPED_SLACK * expected_pairs))
                            // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
-
-    def kept(m, h, weights, w_gate, w_up, w_down):
-        with scope("dispatch"):
-            live = (jnp.arange(m, dtype=jnp.int32) < count)[:, None]
-
-        def product(x, weight):
-            with scope("dispatch"):
-                x = jnp.where(live, x, 0)
-            with scope("products"):
-                y = grouped_matmul(x, weight, sizes)
-                if m == m_usual:
-                    # a checkpointed segment may hold the usual buffers'
-                    # products; the overflow's (a `cond` keeps BOTH
-                    # branches' residuals alive) are computed again
-                    y = remat_keep(y)
-            # zeros past the held runs on both sides, so that neither a
-            # value nor a gradient of a row nobody multiplied goes on
-            with scope("dispatch"):
-                return jnp.where(live, y, 0).astype(h.dtype)
-
-        with scope("dispatch"):
-            xs = _rows_to_slots(h, order[:m], slot_of)
-        gate = product(xs, w_gate)
-        with scope("products"):
-            gate = jax.nn.silu(gate)
-        up = product(xs, w_up)
-        with scope("products"):
-            act = gate * up
-        ys = product(act, w_down)
-        with scope("dispatch"):
-            pairs = _slots_to_pairs(ys, order[:m], slot_of)
-        with scope("combine"):
-            return jnp.einsum("tke,tk->te", pairs,
-                              weights.astype(pairs.dtype))
-
-    args = (h, weights, w_gate, w_up, w_down)
-    if m_usual == m_all:
-        out = kept(m_all, *args)
-    else:
-        with scope("dispatch"):
-            fits = count <= m_usual
-        out = jax.lax.cond(fits, functools.partial(kept, m_usual),
-                           functools.partial(kept, m_all), *args)
+    out = _grouped(m_usual, h, order, slot_of, sizes, weights,
+                   w_gate, w_up, w_down)
     with scope("dispatch"):
         tiles = -(-sizes // GROUPED_ROW_TILE)
-        return out, jnp.sum(tiles) * GROUPED_ROW_TILE
+        return out, jnp.stack([
+            jnp.sum(tiles) * GROUPED_ROW_TILE,
+            (jnp.sum(sizes) > m_usual).astype(jnp.int32)])
 
 
 class RoutedExperts(Op):
@@ -346,8 +458,9 @@ class RoutedExperts(Op):
         specs.append(WeightSpec(
             "moe_stats", w(len(MOE_STATS), dtype=DataType.INT32), zero))
         if self.product_plan() == "grouped":
+            # the rows multiplied; 1 where the step overflowed
             specs.append(WeightSpec(
-                "moe_rows_computed", w(1, dtype=DataType.INT32), zero))
+                "moe_rows_computed", w(2, dtype=DataType.INT32), zero))
         return specs
 
     def forward(self, inputs, weights, *, training=False, rng=None):
@@ -368,7 +481,7 @@ class RoutedExperts(Op):
             with scope("dispatch"):
                 landed_on = jnp.where((at >= 0) & (at < p.experts_held),
                                       at, p.experts_held)
-            out, rows_computed = grouped_experts(
+            out, grouped_counts = grouped_experts(
                 h, landed_on, w, w_gate, w_up, w_down,
                 h.shape[0] * p.top_k * p.experts_held / p.experts_total)
         else:
@@ -397,9 +510,7 @@ class RoutedExperts(Op):
         with scope("combine"):
             out = out.reshape(x.shape).astype(x.dtype)
         if grouped:
-            with scope("dispatch"):
-                rows_computed = rows_computed.reshape(1).astype(jnp.int32)
-            return [out, stats, rows_computed]
+            return [out, stats, grouped_counts]
         return [out, stats]
 
     def flops(self):

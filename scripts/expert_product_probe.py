@@ -1,18 +1,23 @@
 #!/usr/bin/env python3
 """One routed-expert layer, forward and backward, at a training step's
-shapes: the dense product against the grouped one, and the grouped
-one's matrix product as libtpu's ragged dot against the megablox Pallas
-kernel.  Prints ms a call on the chip (`chiprun -- python3
-scripts/expert_product_probe.py`); `--compile-only` compiles every
-variant for a described v5e in the sandbox and prints no time.
+shapes: the dense product against the grouped one (alone, and as a
+checkpointed segment of the executor's keeping nothing or its
+products), the grouped one's matrix products as libtpu's ragged dot
+against the megablox Pallas kernels, and the step that overflows the
+usual buffers.  Prints ms a call on the chip (`chiprun -- python3
+scripts/expert_product_probe.py`), then the checkpointed layer's device
+time by part (`benchmarks/device_scopes.py` over a trace of its own);
+`--compile-only` compiles every variant for a described v5e in the
+sandbox and prints no time (`--hlo DIR` also writes each variant's
+optimized HLO there).
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -20,12 +25,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from flexflow_tpu.obs.scopes import scope  # noqa: E402
 from flexflow_tpu.ops import routed_experts as rx  # noqa: E402
 
+MATMULS = ("grouped_matmul", "grouped_matmul_into_lhs",
+           "grouped_matmul_into_rhs")
 
-def layer(product, p, keep=None):
+
+def layer(product, p, keep=None, slack=None):
+    """The jitted value and gradient of one layer under `product`;
+    `slack` stands in for `GROUPED_SLACK` while it is traced."""
     def loss(h, router, bias, wg, wu, wd, target):
-        chosen, w = rx.route(h, router, bias, p)
+        with scope("route"):
+            chosen, w = rx.route(h, router, bias, p)
         at = chosen - p.first_held
         on = (at >= 0) & (at < p.experts_held)
         if product == "dense":
@@ -43,7 +55,87 @@ def layer(product, p, keep=None):
         from flexflow_tpu.executor import _REMAT_POLICIES
 
         loss = jax.checkpoint(loss, policy=_REMAT_POLICIES[keep])
-    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5)))
+
+    def named(*args):
+        usual = rx.GROUPED_SLACK
+        rx.GROUPED_SLACK = usual if slack is None else slack
+        try:
+            with scope("RoutedExperts", "probe"):
+                return jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))(
+                    *args)
+        finally:
+            rx.GROUPED_SLACK = usual
+
+    return jax.jit(named)
+
+
+def megablox(tiling):
+    """`MATMULS` on the megablox kernels at one tiling."""
+    import importlib
+
+    # the package's `gmm` is the function; the kernels' module has both
+    mb = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+    def mm(lhs, rhs, sizes):
+        return mb.gmm(lhs, rhs, sizes, lhs.dtype, tiling)
+
+    def into_lhs(ct, rhs, sizes):
+        return mb.gmm(ct, rhs, sizes, ct.dtype, tiling, transpose_rhs=True)
+
+    def into_rhs(lhs, ct, sizes):
+        return mb.tgmm(lhs.swapaxes(0, 1), ct, sizes, lhs.dtype, tiling)
+
+    return mm, into_lhs, into_rhs
+
+
+def tails(m, e, f, n, count):
+    """What this backend's three products do with the rows past the
+    last group (`count` of m rows are in the groups, NaN in the rest,
+    and NaN where the allocator may hand a result its buffer): whether
+    such a row is READ (it must not be: no NaN in a group's rows, nor
+    in a weight's slice), and what the first two forms RETURN there
+    (zeros, or whatever the buffer held: the layer reads neither)."""
+    keys = jax.random.split(jax.random.key(1), 3)
+    live = (jnp.arange(m) < count)[:, None]
+    lhs = jnp.where(live, jax.random.normal(keys[0], (m, e)), jnp.nan)
+    ct = jnp.where(live, jax.random.normal(keys[1], (m, f)), jnp.nan)
+    lhs, ct = lhs.astype(jnp.bfloat16), ct.astype(jnp.bfloat16)
+    rhs = jax.random.normal(keys[2], (n, e, f)).astype(jnp.bfloat16)
+    sizes = jnp.full((n,), count // n, jnp.int32).at[0].add(count % n)
+    mm, into_lhs, into_rhs = (jax.jit(getattr(rx, name)) for name in MATMULS)
+    out = {}
+    for name, fn, x in (("grouped_matmul", mm, lhs),
+                        ("grouped_matmul_into_lhs", into_lhs, ct)):
+        for shape in ((m, e), (m, f)):  # buffers to be handed out again
+            jax.block_until_ready(jnp.full(shape, jnp.nan, jnp.bfloat16))
+        got = fn(x, rhs, sizes).astype(jnp.float32)
+        out[name] = {
+            "reads_past_the_groups": not bool(jnp.all(jnp.isfinite(
+                got[:count]))),
+            "returns_there": "zeros" if not bool(jnp.any(got[count:]))
+            else "unwritten"}
+    out["grouped_matmul_into_rhs"] = {"reads_past_the_groups": not bool(
+        jnp.all(jnp.isfinite(into_rhs(lhs, ct, sizes).astype(jnp.float32))))}
+    return out
+
+
+def by_part(fn, vals, iters):
+    """The by-scope table of `iters` traced calls of `fn`."""
+    from benchmarks import device_scopes
+    from benchmarks.run import find_xplane
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(iters):
+            got = fn(*vals)
+        jax.block_until_ready(got)
+        jax.profiler.stop_trace()
+        rows, dispatches = device_scopes.reduce(find_xplane(trace_dir))
+    with open(os.path.join(os.path.dirname(device_scopes.__file__),
+                           "peaks.json")) as f:
+        peak = next(iter(json.load(f)["devices"].values()))
+    return device_scopes.table(rows, dispatches, peak, least_share=0.002)
 
 
 def main() -> int:
@@ -56,6 +148,7 @@ def main() -> int:
     ap.add_argument("--top-k", type=int, default=4)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--hlo", default="")
     args = ap.parse_args()
     p = rx.RoutedExpertsParams(
         experts_total=args.total, experts_held=args.held, first_held=0,
@@ -65,20 +158,28 @@ def main() -> int:
     shapes = [((t, e), bf), ((e, args.total), jnp.float32),
               ((args.total,), jnp.float32), ((n, e, f), bf), ((n, e, f), bf),
               ((n, f, e), bf), ((t, e), jnp.float32)]
+    ragged = tuple(getattr(rx, name) for name in MATMULS)
+    # name: (product, the three matmuls, what a segment keeps, slack)
+    variants = {
+        "dense": ("dense", ragged, None, None),
+        "grouped.ragged_dot": ("grouped", ragged, None, None),
+        "grouped.megablox_512_1024_1024":
+            ("grouped", megablox((512, 1024, 1024)), None, None),
+        "grouped.ragged_dot.remat_none": ("grouped", ragged, "none", None),
+        "grouped.ragged_dot.remat_products":
+            ("grouped", ragged, "products", None),
+        # a step whose held pairs overflow the usual buffers: the
+        # every-pair size, whose backward runs its forward again
+        "grouped.ragged_dot.remat_products.overflow":
+            ("grouped", ragged, "products", 0.5),
+    }
 
-    from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+    def build(name):
+        product, matmuls, keep, slack = variants[name]
+        for attr, fn in zip(MATMULS, matmuls):
+            setattr(rx, attr, fn)
+        return layer(product, p, keep, slack)
 
-    def megablox(tiling):
-        def mm(lhs, rhs, sizes):
-            return mb.gmm(lhs, rhs, sizes, lhs.dtype, tiling)
-        return mm
-
-    variants = {"dense": ("dense", None),
-                "grouped.ragged_dot": ("grouped", rx.grouped_matmul),
-                "grouped.megablox_512_1024_1024":
-                    ("grouped", megablox((512, 1024, 1024))),
-                "grouped.megablox_512_512_512":
-                    ("grouped", megablox((512, 512, 512)))}
     if args.compile_only:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -87,14 +188,16 @@ def main() -> int:
                                             topology_name="v5e:2x2")
         sh = SingleDeviceSharding(topo.devices[0])
         structs = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
-        for name, (product, mm) in variants.items():
-            if mm is not None:
-                rx.grouped_matmul = mm
-            c = layer(product, p).trace(*structs).lower(
+        for name in variants:
+            c = build(name).trace(*structs).lower(
                 lowering_platforms=("tpu",)).compile()
             m = c.memory_analysis()
             print(name, "compiled; temp bytes", m.temp_size_in_bytes,
                   flush=True)
+            if args.hlo:
+                os.makedirs(args.hlo, exist_ok=True)
+                with open(os.path.join(args.hlo, name + ".hlo"), "w") as fh:
+                    fh.write(c.as_text())
         return 0
 
     keys = jax.random.split(jax.random.key(0), len(shapes))
@@ -102,31 +205,11 @@ def main() -> int:
             .astype(d) for i, (k, (s, d)) in enumerate(zip(keys, shapes))]
     want = None
     out = {"device": jax.devices()[0].device_kind, "shapes": vars(args)}
-    # the shipped product again as a checkpointed segment (PR 37): what
-    # the segment keeps, and with the slots' gather kept beside it
-    variants["grouped.ragged_dot.remat_none"] = (
-        "grouped", rx.grouped_matmul, "none")
-    variants["grouped.ragged_dot.remat_products"] = (
-        "grouped", rx.grouped_matmul, "products")
-    variants["grouped.ragged_dot.remat_products+gather"] = (
-        "grouped", rx.grouped_matmul, "products+gather")
-    variants["grouped.ragged_dot.remat_products+sort"] = (
-        "grouped", rx.grouped_matmul, "products+sort")
-    variants["grouped.ragged_dot.remat_products+sort+route"] = (
-        "grouped", rx.grouped_matmul, "products+sort+route")
-    rows_to_slots, argsort, route = rx._rows_to_slots, jnp.argsort, rx.route
-    for name, (product, mm, *keep) in variants.items():
-        if mm is not None:
-            rx.grouped_matmul = mm
-        keep, *also = keep[0].split("+") if keep else (None,)
-        rx._rows_to_slots, jnp.argsort, rx.route = rows_to_slots, argsort, route
-        if "gather" in also:
-            rx._rows_to_slots = lambda *a: rx.remat_keep(rows_to_slots(*a))
-        if "sort" in also:  # the two permutations, 128 KB each
-            jnp.argsort = lambda *a, **kw: rx.remat_keep(argsort(*a, **kw))
-        if "route" in also:  # the chosen experts and their weights
-            rx.route = lambda *a: tuple(map(rx.remat_keep, route(*a)))
-        fn = layer(product, p, keep)
+    m_usual = int(rx.GROUPED_SLACK * t * args.top_k * n / args.total)
+    out["tails"] = tails(m_usual, e, f, n, m_usual * 2 // 3 + 5)
+    print("tails", json.dumps(out["tails"]), flush=True)
+    for name in variants:
+        fn = build(name)
         try:
             got = jax.block_until_ready(fn(*vals))
         except Exception as ex:  # a variant the chip refuses
@@ -144,37 +227,18 @@ def main() -> int:
         err = float(jnp.linalg.norm(flat - want) / jnp.linalg.norm(want))
         out[name] = {"ms": ms, "grad_rel_l2_vs_dense": err}
         print(name, json.dumps(out[name]), flush=True)
-    rx._rows_to_slots, jnp.argsort, rx.route = rows_to_slots, argsort, route
-    # does the ragged product's time follow the rows in its groups?
-    lhs = vals[0].repeat(args.top_k, axis=0)  # [t * k, e]
-    for share in (0.25, 1.0):
-        sizes = jnp.full((n,), int(lhs.shape[0] * share) // n, jnp.int32)
-        fn = jax.jit(functools.partial(jax.lax.ragged_dot))
-        jax.block_until_ready(fn(lhs, vals[3], sizes))
-        t0 = time.monotonic()
-        for _ in range(args.iters):
-            r = fn(lhs, vals[3], sizes)
-        jax.block_until_ready(r)
-        out[f"ragged_dot.rows_in_groups_{share}"] = \
-            1e3 * (time.monotonic() - t0) / args.iters
-    # the gather that fills the usual buffers, alone: what a backward
-    # pass that does not keep it pays again
-    m = -(-int(rx.GROUPED_SLACK * t * args.top_k * n / args.total)
-          // rx.GROUPED_ROW_TILE) * rx.GROUPED_ROW_TILE
-    order = jax.random.permutation(keys[0], t * args.top_k).astype(jnp.int32)
-    slot_of = jnp.argsort(order).reshape(t, args.top_k).astype(jnp.int32)
-    fn = jax.jit(lambda h, o, s: rows_to_slots(h, o[:m], s))
-    jax.block_until_ready(fn(vals[0], order, slot_of))
-    t0 = time.monotonic()
-    for _ in range(args.iters):
-        r = fn(vals[0], order, slot_of)
-    jax.block_until_ready(r)
-    out["rows_to_slots.gather_ms"] = \
-        1e3 * (time.monotonic() - t0) / args.iters
+    # where the checkpointed layer's time goes, by the program's names
+    # (a device plane: only a chip's trace has one)
+    for name in ("grouped.ragged_dot.remat_products",
+                 "grouped.ragged_dot.remat_products.overflow"
+                 ) if jax.default_backend() == "tpu" else ():
+        fn = build(name)
+        jax.block_until_ready(fn(*vals))
+        out[name + ".by_part"] = by_part(fn, vals, args.iters)
+        print(name, "\n".join(out[name + ".by_part"]), sep="\n", flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/expert_product_probe.json", "w") as fh:
         json.dump(out, fh, indent=1)
-    print(json.dumps(out))
     return 0
 
 

@@ -62,8 +62,8 @@ def main() -> int:
                 "ms_a_step": round(ms, 2),
                 "loss": round(float(m["loss"]), 4),
                 **({k: moe[-1][k] for k in
-                    ("moe_pairs", "moe_max_rows",
-                     "moe_rows_computed")} if moe else {})}), flush=True)
+                    ("moe_pairs", "moe_max_rows", "moe_rows_computed",
+                     "moe_overflow")} if moe else {})}), flush=True)
         del ff
     return 0
 

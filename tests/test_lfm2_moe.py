@@ -317,6 +317,182 @@ def test_the_grouped_buffers_hold_every_pair_when_the_usual_ones_overflow(
     close(outs[2], outs[0])
 
 
+def grouped_case(load, size):
+    """(h, landed_on, w, [w_gate, w_up, w_down], expected_pairs) of one
+    call of `grouped_experts`, t x k = 48 pairs on n = 3 held experts
+    (`landed_on` n: an expert that lives elsewhere), and whether its
+    held pairs overflow the usual buffers.  `size`: "usual" buffers
+    that hold the step's pairs, buffers an "overflow" step runs over,
+    or "one_size": the usual buffers hold every pair at any load."""
+    keys = jax.random.split(jax.random.key(1), 6)
+    t, k, e, n, f = 24, 2, 8, 3, 12
+    h = jax.random.normal(keys[0], (t, e))
+    landed_on = {
+        "light": jnp.where(jax.random.uniform(keys[1], (t, k)) < 0.25,
+                           jax.random.randint(keys[1], (t, k), 0, n), n),
+        "even": jax.random.randint(keys[1], (t, k), 0, n + 1),
+        "every_pair_on_one_expert": jnp.full((t, k), 1),
+    }[load]
+    w = jax.random.uniform(keys[2], (t, k))
+    ws = [0.3 * jax.random.normal(kk, s) for kk, s in zip(
+        keys[3:], [(n, e, f), (n, e, f), (n, f, e)])]
+    count = int(jnp.sum(landed_on < n))
+    assert count > rx.GROUPED_ROW_TILE
+    expected = {"usual": count / rx.GROUPED_SLACK + 1, "overflow": 1.0,
+                "one_size": t * k}[size]
+    return (h, landed_on, w, ws, expected), size == "overflow"
+
+
+def m_usual_of(t_k, expected):
+    return min(t_k, -(-int(rx.GROUPED_SLACK * expected)
+                      // rx.GROUPED_ROW_TILE) * rx.GROUPED_ROW_TILE)
+
+
+def equations(jaxpr):
+    """Every equation of `jaxpr`, sub-jaxprs (a `cond`'s branches, a
+    `custom_vjp`'s rules once differentiated) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
+def unwritten_tails(monkeypatch):
+    """The CPU's grouped product gives zeros past the last group; the
+    chip's leaves those rows unwritten.  Stand in for it: NaN there, out
+    of `grouped_matmul` and (through it) `grouped_matmul_into_lhs`."""
+    product = rx.grouped_matmul
+
+    def chips(lhs, rhs, sizes):
+        out = product(lhs, rhs, sizes)
+        written = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+        return jnp.where(written[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(rx, "grouped_matmul", chips)
+
+
+@pytest.mark.parametrize("tails", ["zeros", "unwritten"])
+@pytest.mark.parametrize("size", ["usual", "overflow", "one_size"])
+@pytest.mark.parametrize("load", ["light", "even",
+                                  "every_pair_on_one_expert"])
+def test_grouped_gradients_equal_the_dense_ones_on_either_size(
+        load, size, tails, monkeypatch):
+    """The hand-written backward rule against autodiff of the dense
+    product: into the rows, the routing weights and the three expert
+    weights, on the usual buffers and on the every-pair ones (whose
+    backward runs their forward again); and the same with NaN in every
+    row the products do not write on the chip, which nothing may
+    read."""
+    if tails == "unwritten":
+        unwritten_tails(monkeypatch)
+    (h, landed_on, w, ws, expected), overflows = grouped_case(load, size)
+    n = ws[0].shape[0]
+    probe = jax.random.normal(jax.random.key(3), h.shape)
+
+    landed = jax.nn.one_hot(landed_on, n, dtype=jnp.float32)
+
+    def dense(h, w, *ws):
+        out = rx.dense_experts(h, jnp.einsum("tkx,tk->tx", landed, w), *ws)
+        return jnp.sum(probe * out), out
+
+    def grouped(h, w, *ws):
+        out, counts = rx.grouped_experts(h, landed_on, w, *ws, expected)
+        return jnp.sum(probe * out), (out, counts)
+
+    want, dense_out = jax.grad(dense, argnums=range(5), has_aux=True)(
+        h, w, *ws)
+    got, (out, counts) = jax.grad(grouped, argnums=range(5), has_aux=True)(
+        h, w, *ws)
+    close(out, dense_out)
+    for g, d in zip(got, want):
+        close(g, d)
+    assert int(counts[1]) == overflows
+    # a pair on an expert that lives elsewhere moves nothing
+    assert not np.any(np.asarray(got[1])[np.asarray(landed_on) == n])
+
+
+def test_the_backward_rule_holds_slot_sized_buffers_only():
+    """What `jax.vjp(grouped_experts)` keeps for the backward pass: the
+    arguments, the two permutations, the sizes and the usual buffers'
+    three products; nothing with a row a pair (t x k, the every-pair
+    size's) of an activation's width."""
+    (h, landed_on, w, ws, expected), _ = grouped_case("even", "usual")
+    (t, k), e, f = landed_on.shape, h.shape[1], ws[0].shape[2]
+    m = m_usual_of(t * k, expected)
+    assert m < t * k and len({t, m, t * k}) == 3
+    _, pull = jax.vjp(lambda h, w, *ws: rx.grouped_experts(
+        h, landed_on, w, *ws, expected)[0], h, w, *ws)
+    kept = [x.shape for x in jax.tree.leaves(pull) if hasattr(x, "shape")]
+    assert sorted(s for s in kept if s[:1] == (m,)) == sorted(
+        [(m, f), (m, f), (m, e)])
+    for shape in kept:
+        assert not (shape[0] == t * k and shape[-1] in (e, f)
+                    and len(shape) > 1), kept
+        assert shape[:2] != (t, k) or len(shape) == 2, kept
+
+
+def test_no_mask_pass_over_a_slot_buffer_in_the_gradient():
+    """The lowered value and gradient select over no whole [m, e] or
+    [m, f] buffer, at either size: the routing weight of a slot past
+    the held runs is zero, no grouped product reads such a slot, and
+    the one mask (the held pairs) is [t, e], inside the two sums over a
+    token's slots."""
+    (h, landed_on, w, ws, expected), _ = grouped_case("even", "usual")
+    (t, k), e, f = landed_on.shape, h.shape[1], ws[0].shape[2]
+    sizes = {m_usual_of(t * k, expected), t * k}
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda h, w, *ws: jnp.sum(rx.grouped_experts(
+            h, landed_on, w, *ws, expected)[0]), argnums=range(5)))(
+                h, w, *ws).jaxpr
+    selects = [v.aval.shape for eqn in equations(jaxpr)
+               if eqn.primitive.name == "select_n" for v in eqn.outvars]
+    assert selects  # the walk does reach them
+    assert not [s for s in selects
+                if len(s) == 2 and s[0] in sizes and s[1] in (e, f)], selects
+    products = [eqn for eqn in equations(jaxpr)
+                if eqn.primitive.name == "ragged_dot_general"]
+    # forward 3 + 3, backward 6 + (3 again + 6): the `cond`s' branches
+    assert len(products) == 21
+
+
+@pytest.mark.parametrize("form", ["grouped_matmul",
+                                  "grouped_matmul_into_lhs",
+                                  "grouped_matmul_into_rhs"])
+def test_the_grouped_products_read_no_row_past_the_last_group(form):
+    """What lets the layer run without a mask pass of its own: no form
+    of the product reads a row past the last group (NaN there reaches
+    no row of a group and no slice of a weight's gradient).  The rows
+    the first two forms RETURN there are zeros on the CPU and unwritten
+    on the chip (`scripts/expert_product_probe.py` says which): the
+    layer reads neither."""
+    keys = jax.random.split(jax.random.key(2), 3)
+    m, e, f, n, count = 40, 8, 12, 3, 21
+    live = (jnp.arange(m) < count)[:, None]
+    clean_lhs = jnp.where(live, jax.random.normal(keys[0], (m, e)), 0)
+    clean_ct = jnp.where(live, jax.random.normal(keys[1], (m, f)), 0)
+    lhs, ct = (jnp.where(live, x, jnp.nan) for x in (clean_lhs, clean_ct))
+    rhs = jax.random.normal(keys[2], (n, e, f))
+    sizes = jnp.array([9, 0, 12], jnp.int32)
+    if form == "grouped_matmul_into_rhs":
+        got = rx.grouped_matmul_into_rhs(lhs, ct, sizes)
+        assert got.shape == (n, e, f) and not np.any(np.asarray(got[1]))
+        # it IS the gradient autodiff takes of the forward product
+        want = jax.grad(lambda r: jnp.sum(
+            rx.grouped_matmul(clean_lhs, r, sizes) * clean_ct))(rhs)
+        close(got, want)
+        return
+    if form == "grouped_matmul":
+        got = rx.grouped_matmul(lhs, rhs, sizes)
+        want = jnp.concatenate([clean_lhs[:9] @ rhs[0],
+                                clean_lhs[9:21] @ rhs[2]])
+    else:
+        got = rx.grouped_matmul_into_lhs(ct, rhs, sizes)
+        want = jnp.concatenate([clean_ct[:9] @ rhs[0].T,
+                                clean_ct[9:21] @ rhs[2].T])
+    close(got[:count], want)
+    assert not np.any(np.asarray(got[count:]))  # the CPU's lowering
+
+
 # -- 4. the share test -----------------------------------------------------------
 def test_the_four_shares_of_8_of_32_experts_add_up_to_the_uncut_layer():
     """At the published counts (32 experts, 8 held, top-4) and toy
@@ -409,7 +585,8 @@ def test_train_step_reports_the_routed_counts_without_a_wait(grouped):
     for r in spans:
         assert set(r.args) == {"step", "moe_pairs", "moe_dropped",
                                "moe_max_rows", "moe_hit",
-                               "moe_rows_computed"}
+                               "moe_rows_computed", "moe_overflow"}
+        assert r.args["moe_overflow"] == 0
         assert r.args["step"] < by_id[r.parent_id].args["step"]
         assert r.args["moe_dropped"] == 0 and r.args["moe_pairs"] > 0
         assert r.args["moe_pairs"] <= r.args["moe_rows_computed"] \

@@ -104,14 +104,16 @@ def toy_stepped(remat=True, keep=None):
     return stepped(build(remat, keep))
 
 
-def count(jaxpr, name: str) -> int:
-    """Equations of primitive `name`, sub-jaxprs (both branches of a
-    `cond`, a backward `checkpoint`) included."""
+def count(jaxpr, name: str, shape=None) -> int:
+    """Equations of primitive `name` (with an output of `shape`, where
+    that is given), sub-jaxprs (both branches of a `cond`, a backward
+    `checkpoint`) included."""
     n = 0
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == name
+        n += eqn.primitive.name == name and (
+            shape is None or any(v.aval.shape == shape for v in eqn.outvars))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += count(sub, name)
+            n += count(sub, name, shape)
     return n
 
 
@@ -201,30 +203,84 @@ def test_gradients_equal_keep_nothing_and_remat_off_for_all_eight_groups(
     same_gradient(toy_stepped().moments, toy_stepped(**other).moments)
 
 
+def in_the_backward_pass(jaxpr, name, shape=None):
+    """`count` over the segments' backward `checkpoint` equations: what
+    a segment computes again and its gradient."""
+    return sum(count(eqn.params["jaxpr"], name, shape)
+               for eqn in jaxpr.eqns if eqn.params.get("differentiated"))
+
+
 def test_the_backward_pass_runs_no_forward_product_or_kernel_again():
-    """A routed layer has 3 grouped products forward and 6 backward;
-    keeping nothing runs the 3 again (12), keeping products does not
-    (9).  Counted over both buffer sizes of the `cond`: the usual
-    buffers' products are kept, the overflow's are computed again.  The
-    attention forward (the twin's `log` of the row sums marks it) is
-    not in the segment's backward equation (keeping nothing, the one
-    the step runs is there: the first pass has no use for its `log`)."""
+    """A routed layer has 3 grouped products forward and 6 backward, at
+    either of two sizes behind a `cond` in each rule of its
+    `custom_vjp`: 3 + 3 forward; backward 6 on the usual buffers, whose
+    products the segment keeps, and 3 again + 6 on the every-pair ones,
+    which keep nothing.  Keeping nothing, the segment's backward pass
+    also runs the forward rule's `cond` again for the usual buffers'
+    products (3 + 0: the every-pair branch hands the backward rule
+    nothing); keeping products it does not.  The attention forward (the twin's `log` of the row
+    sums marks it) is not in the segment's backward equation (keeping
+    nothing, the one the step runs is there: the first pass has no use
+    for its `log`)."""
     kept, nothing = toy().jaxpr, toy(keep="none").jaxpr
     routed = len(toy().ff.executor.routed_expert_ops)
     assert routed == 2
-    assert count(nothing, "ragged_dot_general") == routed * (12 + 12)
-    assert count(kept, "ragged_dot_general") == routed * (9 + 12)
-
-
-    def in_the_backward_pass(jaxpr, name):
-        return sum(count(eqn.params["jaxpr"], name) for eqn in jaxpr.eqns
-                   if eqn.params.get("differentiated"))
-
+    assert count(nothing, "ragged_dot_general") == routed * (6 + 3 + 15)
+    assert count(kept, "ragged_dot_general") == routed * (6 + 15)
     assert in_the_backward_pass(nothing, "log") == 1
     assert in_the_backward_pass(kept, "log") == 0
     assert count(kept, "log") == count(nothing, "log") == 2  # and the loss's
-    assert in_the_backward_pass(nothing, "ragged_dot_general") == 2 * 2 * 9
-    assert in_the_backward_pass(kept, "ragged_dot_general") == 2 * (6 + 9)
+    assert in_the_backward_pass(nothing, "ragged_dot_general") == routed * (
+        3 + 15)
+    assert in_the_backward_pass(kept, "ragged_dot_general") == routed * 15
+
+
+def test_a_routed_segment_computes_no_combine_again():
+    """The gathers of [t, e] rows in a routed segment's backward pass
+    are the input gradient's, k a size (`_sum_of_slots`); the forward
+    combine's (k a size more: the step's forward pass has them) are
+    not there at either level of keeping: nothing of the backward rule
+    reads the combined output."""
+    k, rows = D["k"], (T, D["e"])
+    routed = len(toy().ff.executor.routed_expert_ops)
+    for level in (toy(), toy(keep="none")):
+        assert in_the_backward_pass(level.jaxpr, "gather",
+                                    rows) == routed * 2 * k
+        assert count(level.jaxpr, "gather", rows) == routed * 4 * k
+
+
+def test_moe_overflow_counts_the_layers_that_took_every_pair(monkeypatch):
+    """`train_step.moe` says how many routed layers of a step overflowed
+    their usual buffers: 0 on the toy's steps, every routed layer once
+    the usual buffers are cut to one row tile; the gradient is the
+    same either way."""
+    def overflow_of(t):
+        moe = [s for s in spans_named("train_step.moe")
+               if s.parent_id in {p.span_id for p in
+                                  spans_named("train_step")[-3:]}]
+        assert moe and all(s.args["moe_dropped"] == 0 for s in moe)
+        return {s.args["moe_overflow"] for s in moe}, \
+            t.ff.telemetry.metrics.counter("train/moe_overflow").value
+
+    def three_steps(ff):
+        t = stepped(ff)
+        jax.block_until_ready(ff.train_step(*batch())["loss"])
+        ff.train_step(*batch())  # reports an earlier step's counts
+        return t
+
+    fits = three_steps(build())
+    assert overflow_of(fits) == ({0}, 0)
+    monkeypatch.setattr(rx, "GROUPED_SLACK", 1e-3)
+    over = three_steps(build())
+    routed = len(over.ff.executor.routed_expert_ops)
+    layers, total = overflow_of(over)
+    assert layers == {routed} and total >= routed
+    same_gradient(over.moments, fits.moments)
+    # the usual buffers of that step: one row tile
+    kept = remat_kept(over.ff._step_fn.trace(*step_args(over.ff)).jaxpr.jaxpr,
+                      over.ff.executor._remat_plan)
+    assert (rx.GROUPED_ROW_TILE, D["e"]) in [
+        a.shape for a in kept[segment_of(over.ff, "moe_1")]]
 
 
 # -- 3. the fit ----------------------------------------------------------------
@@ -269,7 +325,7 @@ def test_a_step_that_does_not_fit_is_lowered_keeping_nothing(
     want = toy_stepped(keep="none").moments
     assert jax.tree.all(jax.tree.map(np.array_equal, t.moments, want))
     jaxpr = ff._step_fn.trace(*step_args(ff)).jaxpr.jaxpr
-    assert count(jaxpr, "ragged_dot_general") == 2 * (12 + 12)
+    assert count(jaxpr, "ragged_dot_general") == 2 * (6 + 3 + 15)
 
 
 def test_another_refusal_is_not_taken_for_a_full_device(monkeypatch):
