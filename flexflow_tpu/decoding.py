@@ -48,13 +48,18 @@ class DecoderRecipe:
     `vocab_size`, `max_seq`, ...); `carries` names what the family
     supports beyond being built (`require_carried`), and what its
     graph allows: `prefill_pass` = the seq-1 twin's graph over [b, C]
-    inputs is the seq-C forward (build_paged_prefill_pass)."""
+    inputs is the seq-C forward (build_paged_prefill_pass).
+    `exit_gate` names the op, if the family has one, whose output
+    `[passes, b, s, 1]` is an exit gate's logit after every pass of a
+    repeated region: the decode step program turns it into each row's
+    exit pdf (`exit_pdf`) and returns that beside the logits."""
 
     family: str
     build: Callable
     kwargs: Dict
     dims: Dict
     carries: frozenset
+    exit_gate: str = ""
 
 
 def decoder_recipe(ff: FFModel) -> DecoderRecipe:
@@ -97,6 +102,41 @@ def cache_entries(ff: FFModel) -> Dict[str, tuple]:
     latent pool is found like a k/v pool."""
     return {op.name: op.cache_entries()
             for op in ff.operators.topo_order() if op.cache_entries()}
+
+
+def cache_planes(ff: FFModel) -> Dict[str, int]:
+    """{op name: planes each of its pools holds} for the ops of
+    `cache_entries`: 1, or the passes of the region that runs the op
+    (`Op.cache_planes`).  Row `t x num_blocks + b` of a pool is block
+    b's page in plane t, so whatever moves a block (copy-on-write,
+    export, import) moves that row of every plane."""
+    return {op.name: op.cache_planes
+            for op in ff.operators.topo_order() if op.cache_entries()}
+
+
+def exit_pdf(gate_logits):
+    """gate_logits [passes, b, s, 1] (any float dtype) -> [b, passes]
+    float32 at the step's first position: the probability that a row
+    leaves after pass t, `g_t prod_{j<t} (1 - g_j)` with `g = sigmoid`,
+    the last pass taking what is left."""
+    import jax
+    import jax.numpy as jnp
+
+    g = jax.nn.sigmoid(gate_logits[:, :, 0, 0].astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - g, axis=0)                  # prod_{j<=t}
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    pdf = jnp.concatenate([(g * before)[:-1], before[-1:]])
+    return pdf.T
+
+
+def _exit_gate_guid(ffd: FFModel) -> Optional[int]:
+    """The guid of the recipe's exit-gate output in the compiled twin,
+    None for a family without one."""
+    name = decoder_recipe(ffd).exit_gate
+    if not name:
+        return None
+    (op,) = [op for op in ffd.operators.topo_order() if op.name == name]
+    return op.outputs[0].guid
 
 
 def slot_state_entries(ff: FFModel) -> Dict[str, tuple]:
@@ -581,7 +621,10 @@ def build_paged_decode_step(ffd: FFModel):
 
         step(weights, state, tokens[b], positions[b], block_table
              [, row_tokens[b]])
-            -> (logits [b, vocab], new_state)
+            -> (logits [b, vocab], new_state[, exit_pdf [b, passes]])
+
+    The third output exists for a family with an exit gate only
+    (`DecoderRecipe.exit_gate`): each row's `exit_pdf`, float32.
 
     `row_tokens` is passed by the engine of a twin with per-slot
     recurrent state only (1 for a live row, 0 for an idle slot, whose
@@ -608,6 +651,7 @@ def build_paged_decode_step(ffd: FFModel):
     import jax.numpy as jnp
 
     ex = ffd.executor
+    gate = _exit_gate_guid(ffd)
 
     def step(weights, state, tokens, positions, block_table,
              row_tokens=None):
@@ -615,10 +659,12 @@ def build_paged_decode_step(ffd: FFModel):
         with scopes.scope(scopes.FEED):
             inputs = {"input": tokens[:, None],
                       "positions": positions[:, None].astype(jnp.int32)}
-        logits, new_state, _, _ = ex.run_forward(
+        logits, new_state, _, env = ex.run_forward(
             weights, state, inputs, training=False, rng=None,
         )
         with scopes.scope(scopes.LOGITS):
+            if gate is not None:
+                return logits[:, 0], new_state, exit_pdf(env[gate])
             return logits[:, 0], new_state
 
     with ex.mesh:
@@ -883,7 +929,8 @@ def build_paged_copy_block(ffd: FFModel):
         copy(state, src, dst) -> new_state
 
     copies physical block `src`'s page to block `dst` in EVERY layer's
-    pools (k/v or latent: `cache_entries`; scalar int32 ids; state
+    pools, every plane of them (k/v or latent: `cache_entries`,
+    `cache_planes`; scalar int32 ids; state
     donated, so on TPU the copy is
     in-place scatter, not a pool clone).  The prefix cache's COW path
     (serving/kv_pool.py ensure_writable) runs this before a full-hit
@@ -894,11 +941,20 @@ def build_paged_copy_block(ffd: FFModel):
 
     ex = ffd.executor
     pools = cache_entries(ffd)
+    planes = cache_planes(ffd)
+
+    def copied(op, v, src, dst):
+        if planes[op] == 1:
+            return v.at[dst].set(v[src])
+        # the block's page in every plane: rows t x num_blocks + b
+        rows = jnp.arange(planes[op], dtype=jnp.int32) \
+            * (v.shape[0] // planes[op])
+        return v.at[dst + rows].set(v[src + rows])
 
     def copy(state, src, dst):
         return {
             op: {
-                k: (v.at[dst].set(v[src])
+                k: (copied(op, v, src, dst)
                     if k in pools.get(op, ()) else v)
                 for k, v in entries.items()
             }

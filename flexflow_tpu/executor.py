@@ -18,7 +18,7 @@ and the NCCL optimizer sync (optimizer_kernel.cu:88) — with ONE design:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -211,6 +211,18 @@ class _RematStep:
         self.kept_bytes = remat_kept_bytes(traced.jaxpr.jaxpr, ex._remat_plan)
 
 
+class _LoopPlan(NamedTuple):
+    """A `LoopRegion` resolved against the compiled graph: its ops in
+    execution order and the guids of the tensor a pass is given, the
+    tensor it hands on, and (or None) the stacked per-pass output."""
+
+    region: Any
+    ops: List[Op]
+    in_guid: int
+    out_guid: int
+    passes_guid: Optional[int]
+
+
 class GraphExecutor:
     """Compiles a PCG + strategy into init/step callables on a mesh."""
 
@@ -334,6 +346,67 @@ class GraphExecutor:
         self._z3_gather = (
             self._z3_gather_map() if self.zero_stage >= 3 else None
         )
+        # repeated regions (pcg/graph.py LoopRegion): op guid -> plan
+        self._loop_plans = self._plan_loop_regions()
+        self._loop_of = {op.guid: plan for plan in self._loop_plans
+                         for op in plan.ops}
+
+    def _plan_loop_regions(self) -> List["_LoopPlan"]:
+        """The graph's regions resolved against the compiled ops, and
+        what cannot run one yet refused by name."""
+        from .config import ConfigError
+        from .pcg.segments import external_inputs
+
+        regions = self.graph.regions
+        if not regions:
+            return []
+        first = regions[0].name
+        for feature, on in (
+                ("pipeline blocks", self.pipeline_plan is not None),
+                ("remat (checkpointed segments would straddle it)",
+                 self._remat_plan is not None),
+                ("ZeRO stage 3 (weights gathered a layer ahead)",
+                 self._z3_gather is not None)):
+            if on:
+                raise ConfigError(
+                    f"region {first!r} cannot run under {feature}")
+        by_name = {op.name: op for op in self.order}
+        tensors = {t.name: t for op in self.order for t in op.outputs}
+        plans = []
+        for r in regions:
+            names = set(r.op_names)
+            ops = [op for op in self.order if op.name in names]
+            if len(ops) != len(names):
+                raise ConfigError(
+                    f"region {r.name!r}: ops "
+                    f"{sorted(names - {op.name for op in ops})} are not "
+                    "in the compiled graph")
+            # (the tensor a pass is given may have come through the
+            # strategy's chain on the graph's inputs: found by what the
+            # ops read, not by its frontend name)
+            given = external_inputs(ops)
+            if len(given) != 1:
+                raise ConfigError(
+                    f"region {r.name!r}: its compiled ops read "
+                    f"{len(given)} tensors from outside; a region's own "
+                    "edges take no parallel-op chain")
+            cout = tensors[r.carry_out]
+            passes = by_name[r.passes_op] if r.passes_op else None
+            plans.append(_LoopPlan(r, ops, given[0], cout.guid,
+                                   passes.outputs[0].guid if passes
+                                   else None))
+        return plans
+
+    @property
+    def loop_counts(self) -> Dict[str, int]:
+        """Args of the spans that build step programs (`build_step_fns`,
+        `serve.build_twin`): regions, the passes they add up to, and
+        the ops inside them; {} for a graph without a region."""
+        if not self._loop_plans:
+            return {}
+        return {"loop_regions": len(self._loop_plans),
+                "loop_steps": sum(p.region.times for p in self._loop_plans),
+                "loop_ops": sum(len(p.ops) for p in self._loop_plans)}
 
     @property
     def remat_segments(self) -> int:
@@ -814,8 +887,69 @@ class GraphExecutor:
         op_name metadata) can be summed by operator kind and name;
         a scope runs at trace time only, so the compiled step pays
         nothing per iteration."""
+        plan = self._loop_of.get(op.guid)
+        if plan is not None:
+            # the whole region runs where its first op stands: by then
+            # the one tensor it reads from outside has been made
+            if op is plan.ops[0]:
+                self._run_loop_region(plan, env, ctx)
+            return
+        if op.op_type == OperatorType.LOOP_PASSES:
+            return  # its region's scan made it
         with scopes.op_scope(op):
             self._exec_op_traced(op, env, ctx)
+
+    def _run_loop_region(self, plan: "_LoopPlan", env, ctx: Dict):
+        """Run a `LoopRegion` as ONE `lax.scan` over the pass index.
+        The carry is the region's activation and the state entries of
+        its ops (a paged pool is written by every pass, in place: the
+        scan's carry aliases it); the weights are closed over, so the
+        body reads the one copy and a gradient through the scan is the
+        sum over the passes.  Each op sees its state as
+        `Op.loop_state(entries, t)` shows it to pass t."""
+        from .config import ConfigError
+
+        region = plan.region
+        stateful = [op for op in plan.ops if op.name in ctx["state"]]
+        # (an op executed earlier in this trace has not written the
+        # region's entries: a region's ops are its own)
+        state0 = {op.name: dict(ctx["state"][op.name]) for op in stateful}
+
+        def body(carry, t):
+            act, st = carry
+            view = {op.name: op.loop_state(st[op.name], t)
+                    for op in stateful}
+            written = {name: dict(entries) for name, entries in st.items()}
+            aux: List[jax.Array] = []
+            inner = dict(
+                ctx, state={**ctx["state"], **view}, new_state=written,
+                aux=aux,
+                rng=(None if ctx["rng"] is None
+                     else jax.random.fold_in(ctx["rng"], t)))
+            local = {plan.in_guid: act}
+            for op in plan.ops:
+                with scopes.op_scope(op):
+                    self._exec_op_traced(op, local, inner)
+            if aux:
+                raise ConfigError(
+                    f"region {region.name!r}: an auxiliary loss made "
+                    "inside a region cannot leave its scan yet")
+            for name, entries in st.items():
+                for k, v in entries.items():
+                    if view[name][k] is not v:  # the pass's own view
+                        written[name][k] = v
+            out = local[plan.out_guid]
+            return (out, written), (out if plan.passes_guid is not None
+                                    else None)
+
+        (out, state), passes = jax.lax.scan(
+            body, (env[plan.in_guid], state0),
+            jnp.arange(region.times, dtype=jnp.int32))
+        env[plan.out_guid] = out
+        if plan.passes_guid is not None:
+            env[plan.passes_guid] = passes
+        for name, entries in state.items():
+            ctx["new_state"][name].update(entries)
 
     def _exec_op_traced(self, op: Op, env: Dict[int, jax.Array], ctx: Dict):
         training = ctx["training"]

@@ -113,6 +113,8 @@ class OperatorType(enum.Enum):
     ROUTED_EXPERTS = "routed_experts"
     GATED_DELTA_NET = "gated_delta_net"
     SHORT_CONV = "short_conv"
+    # every pass's output of a repeated region, stacked (pcg LoopRegion)
+    LOOP_PASSES = "loop_passes"
     # Elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"

@@ -17,6 +17,8 @@ Execution differences from the reference, by design (SURVEY §7):
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import logging
 import math
 import time
@@ -27,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .config import FFConfig, FFIterationConfig
+from .config import ConfigError, FFConfig, FFIterationConfig
 from .executor import GraphExecutor
 from .fftype import (
     ActiMode,
@@ -110,7 +112,7 @@ from .ops.shape import (
 from .ops.sources import InputOp, SourceParams
 from .optimizer import AdamOptimizer, Optimizer, SGDOptimizer
 from .parallel.machine import make_mesh
-from .pcg.graph import Graph
+from .pcg.graph import Graph, LoopRegion
 from .strategy import (
     Strategy,
     apply_strategy,
@@ -120,6 +122,86 @@ from .strategy import (
 from .tensor import ParallelTensor, ParallelTensorShape
 
 _log = logging.getLogger("flexflow_tpu.model")
+
+
+class _OpenRegion:
+    """What `FFModel.repeat` yields: the ops added while it is open are
+    the region's; `carry(t)` names the pass's output; `passes()` (after
+    the block) is every pass's output, stacked."""
+
+    def __init__(self, ff: "FFModel", name: str, times: int,
+                 carry_in: ParallelTensor):
+        self._ff, self.name, self.times = ff, name, times
+        self.carry_in = carry_in
+        self.carry_out: Optional[ParallelTensor] = None
+        self._first = len(ff.layers.ops)
+        self._passes: Optional[ParallelTensor] = None
+
+    def carry(self, t: ParallelTensor) -> None:
+        """`t` is what a pass hands the next one in `carry_in`'s place
+        (and, after the last pass, what the ops behind the region see)."""
+        self.carry_out = t
+
+    def passes(self) -> ParallelTensor:
+        """`[times, ...]`: the carried output of every pass."""
+        from .ops.loop import LoopPasses, LoopPassesParams
+
+        ff = self._ff
+        if ff._open_region is self:
+            raise ConfigError(
+                f"region {self.name!r}: passes() is asked after the "
+                "`with` block (it is no op of the region)")
+        if self._passes is None:
+            op = LoopPasses(LoopPassesParams(self.times, self.name),
+                            [self.carry_out], name=f"{self.name}_passes")
+            self._passes = ff._add(op)
+            ff.layers.regions = [
+                dataclasses.replace(r, passes_op=op.name)
+                if r.name == self.name else r for r in ff.layers.regions]
+        return self._passes
+
+    def close(self) -> LoopRegion:
+        ops = self._ff.layers.ops[self._first:]
+        name, cin, cout = self.name, self.carry_in, self.carry_out
+        if not ops or cout is None:
+            raise ConfigError(
+                f"region {name!r} needs ops and carry(<its output>)")
+        mine = {id(op) for op in ops}
+        if id(cout.owner_op) not in mine:
+            raise ConfigError(
+                f"region {name!r}: carry({cout.name}) is not an output of "
+                "the region's ops")
+        if (tuple(cout.shape.logical_shape) != tuple(cin.shape.logical_shape)
+                or cout.shape.dtype != cin.shape.dtype):
+            raise ConfigError(
+                f"region {name!r}: a pass must hand on what it was given: "
+                f"{cout.name} {cout.shape} is not shaped like "
+                f"{cin.name} {cin.shape}")
+        for op in ops:
+            if op.op_type == OperatorType.INPUT:
+                raise ConfigError(
+                    f"region {name!r}: an input ({op.name}) cannot be "
+                    "made inside a region")
+            outside = [t.name for t in op.inputs
+                       if id(t.owner_op) not in mine and t is not cin]
+            if outside:
+                raise ConfigError(
+                    f"region {name!r}: {op.name} reads {outside} from "
+                    f"outside; a region takes ONE tensor in "
+                    f"({cin.name}), which every pass replaces")
+            if op.slot_state_entries():
+                raise ConfigError(
+                    f"region {name!r}: per-slot state inside a region "
+                    f"({op.name}: {op.slot_state_entries()}) is not "
+                    "built; only paged caches get a plane a pass")
+            if op.cache_entries() and op.cache_planes != self.times:
+                raise ConfigError(
+                    f"region {name!r}: {op.name} ({type(op).__name__}) "
+                    f"caches {op.cache_planes} plane(s), the region runs "
+                    f"{self.times} passes; only MultiHeadAttention's "
+                    "paged pool holds a plane a pass")
+        return LoopRegion(name, self.times, tuple(op.name for op in ops),
+                          cin.name, cout.name)
 
 
 def device_put_like(saved, current):
@@ -183,6 +265,7 @@ class FFModel:
         self._cache_ops: List[Op] = []
         self._compiled_cache: Dict[str, Op] = {}
         self._pending_taps = None  # one-step-late cache taps
+        self._open_region: Optional[_OpenRegion] = None  # `repeat`
 
     # ------------------------------------------------------------------
     # tensor / naming helpers
@@ -220,6 +303,50 @@ class FFModel:
         if len(op.outputs) == 1:
             return op.outputs[0]
         return tuple(op.outputs)
+
+    def set_output(self, t: ParallelTensor) -> None:
+        """Say which op's output is the graph's (what the loss, the
+        metrics and a decode step's logits read) where the graph has
+        another sink beside it; without this it is the last sink."""
+        self.layers.output_name = t.owner_op.name
+
+    @contextlib.contextmanager
+    def repeat(self, carry: ParallelTensor, times: int,
+               name: Optional[str] = None):
+        """A region of the graph that runs `times` times over ONE copy
+        of its weights (pcg/graph.py `LoopRegion`):
+
+            with ff.repeat(t, 4, name="loop") as loop:
+                for i in range(layers):
+                    t = ...                  # ops that read `t`
+                loop.carry(t)                # what the next pass starts from
+            per_pass = loop.passes()         # [4, ...], optional
+            logits = ff.dense(t, ...)        # the last pass's output
+
+        The ops added inside the block are the region.  They read ONE
+        tensor from outside, `carry`, which every pass after the first
+        replaces by the previous pass's `loop.carry(...)` tensor (same
+        shape and dtype).  The executor runs the region as one
+        `lax.scan` over the pass index: its weights exist once (in
+        `weights`, `set_weights`, a checkpoint), a gradient through it
+        is the sum over the passes, and an attention op inside it, in a
+        paged twin, caches one plane of keys and values a pass.  What a
+        region cannot be combined with yet is a `ConfigError` at
+        compile, by name."""
+        if self._open_region is not None:
+            raise ConfigError(
+                f"region {self._open_region.name!r} is open: regions do "
+                "not nest")
+        if int(times) < 1:
+            raise ConfigError(f"a region runs at least once, got {times}")
+        region = _OpenRegion(self, self._name("loop", name), int(times),
+                             carry)
+        self._open_region = region
+        try:
+            yield region
+        finally:
+            self._open_region = None
+        self.layers.regions.append(region.close())
 
     # ------------------------------------------------------------------
     # layer API (reference model.h:326-712)
@@ -349,7 +476,10 @@ class FFModel:
                                decode_max_seq=decode_max_seq,
                                kv_page_size=kv_page_size,
                                kv_num_blocks=kv_num_blocks,
-                               kv_kernel=kv_kernel)
+                               kv_kernel=kv_kernel,
+                               # inside `repeat`: a plane a pass
+                               kv_planes=(self._open_region.times
+                                          if self._open_region else 1))
         )
 
     def mla_attention(self, input, positions, params, name=None,
@@ -802,6 +932,10 @@ class FFModel:
             strategy = Strategy.load(cfg.import_strategy_file)
         if strategy is None:
             if cfg.search_budget > 0 and not cfg.only_data_parallel:
+                self._no_region_under(
+                    "the strategy search (its substitutions and its "
+                    "costs walk a flat graph): compile with "
+                    "only_data_parallel or an explicit strategy")
                 # reference: Unity graph_optimize is the default search
                 # path (GRAPH_OPTIMIZE_TASK_ID, graph.cc:2046); MCMC is
                 # the legacy SysML'19 path (model.cc:3285).  The
@@ -886,6 +1020,14 @@ class FFModel:
             )
         return self
 
+    def _no_region_under(self, feature: str) -> None:
+        """ConfigError, by name, where a graph with a repeated region
+        meets a feature that cannot take one yet: never a fallback."""
+        if self.layers.regions:
+            raise ConfigError(
+                f"region {self.layers.regions[0].name!r} (FFModel.repeat) "
+                f"cannot be compiled under {feature}")
+
     def _build_executor(self, strategy: Strategy, devices, num_devices: int,
                         comp_mode: CompMode) -> None:
         """The graph passes between the strategy and the weights: replay
@@ -898,6 +1040,12 @@ class FFModel:
         # substitution.cc:1898-1945), then apply + cancel redundant
         # parallel-op boundaries
         compiled_frontend = self.layers
+        if strategy.rewrites:
+            self._no_region_under("a strategy's graph rewrites")
+        if cfg.perform_fusion:
+            self._no_region_under("--fusion (perform_fusion)")
+        if strategy.pipeline:
+            self._no_region_under("pipeline blocks (strategy.pipeline)")
         if strategy.rewrites:
             from .pcg.rewrite import apply_rewrites, rules_for_replay
 
@@ -918,6 +1066,9 @@ class FFModel:
         self.operators = cancel_all_inverse_parallel_ops(
             apply_strategy(compiled_frontend, strategy)
         )
+        # (ops are rebuilt under their names, and a region names its ops)
+        self.operators.regions = list(compiled_frontend.regions)
+        self.operators.output_name = compiled_frontend.output_name
         # multi-slice execution (topology/, docs/TOPOLOGY.md): lower the
         # strategy's placement (which mesh axis spans the DCN boundary)
         # to a two-level execution mesh — a leading slice dim plus the
@@ -1174,6 +1325,7 @@ class FFModel:
         # segments the train step checkpoints, and what the step is
         # first lowered to keep in them (the step's first call says
         # what fit: `train_step`'s `remat_keep`, `remat_kept_bytes`)
+        counts.update(self.executor.loop_counts)  # repeated regions
         counts["remat_segments"] = self.executor.remat_segments
         if counts["remat_segments"]:
             counts["remat_keep"] = self.executor.remat_keep

@@ -115,13 +115,20 @@ class MultiHeadAttention(Op):
 
     def __init__(self, params, inputs, name="", shard=None,
                  decode_max_seq: int = 0, kv_page_size: int = 0,
-                 kv_num_blocks: int = 0, kv_kernel: str = "gather"):
+                 kv_num_blocks: int = 0, kv_kernel: str = "gather",
+                 kv_planes: int = 1):
         from .op import ShardConfig
 
         # must exist before Op.__init__ runs make_weight_specs
         self._decode_max_seq = int(decode_max_seq)
         self._kv_page_size = int(kv_page_size)
         self._kv_num_blocks = int(kv_num_blocks)
+        # planes of the paged pool: one a pass of the region that runs
+        # this op (FFModel.repeat), in ONE array [planes x num_blocks,
+        # page, h, d]; pass t reads and writes block b at row
+        # t x num_blocks + b (`loop_state`), so the scatter and both
+        # reads address a plane in place
+        self.cache_planes = int(kv_planes)
         # paged READ formulation: "gather" materializes the dense
         # [b, N, h, d] view (the bit-identity oracle); "pallas" streams
         # blocks in place through the fused kernel
@@ -230,12 +237,21 @@ class MultiHeadAttention(Op):
         if not n:
             return {}
         kw = {"decode_max_seq": n}
+        if self.cache_planes > 1:
+            kw["kv_planes"] = self.cache_planes
         if self._paged():
             kw["kv_page_size"] = self._kv_page_size
             kw["kv_num_blocks"] = self._kv_num_blocks
             if getattr(self, "_kv_kernel", "gather") != "gather":
                 kw["kv_kernel"] = self._kv_kernel
         return kw
+
+    def loop_state(self, entries, step):
+        if not self._paged() or self.cache_planes == 1:
+            return entries
+        # pass `step`'s plane: block 0 of every plane is its scratch
+        return dict(entries, block_table=entries["block_table"]
+                    + step * jnp.int32(self._kv_num_blocks))
 
     def num_trainable_weights(self) -> int:
         n = 4
@@ -311,6 +327,11 @@ class MultiHeadAttention(Op):
 
             if self._paged():
                 return specs + self._paged_state_specs(qd, dt)
+            if self.cache_planes > 1:
+                raise ShapeError(
+                    f"{self.name}: the dense per-slot cache holds one "
+                    "plane; inside a repeated region the cache is the "
+                    "paged pool (kv_page_size > 0)")
 
             def cache(d_head):
                 dims = (
@@ -384,7 +405,7 @@ class MultiHeadAttention(Op):
             # table / COW / prefix-sharing plumbing never sees the
             # sharding.
             dims = (
-                ParallelDim(nb), ParallelDim(page),
+                ParallelDim(nb * self.cache_planes), ParallelDim(page),
                 ParallelDim(p.kv_heads, self.shard.channel),
                 ParallelDim(d_head),
                 ParallelDim(1, 1, is_replica_dim=True),
@@ -645,6 +666,14 @@ class MultiHeadAttention(Op):
                         jnp.take_along_axis(btab, at // page, axis=1), 0)
         k_cache = k_cache.at[blk, at % page].set(kh.astype(k_cache.dtype))
         v_cache = v_cache.at[blk, at % page].set(vh.astype(v_cache.dtype))
+        if self._kv_kernel == "pallas":
+            # the same writes, then the row's live pages read in place
+            # (one head count: `infer_output_shapes` keeps grouped
+            # heads on the gather)
+            ctx = self._paged_kernel_read(
+                qh, k_cache, v_cache, btab,
+                slen.reshape(b).astype(jnp.int32), scale)
+            return ctx, k_cache, v_cache
         kv_k = jnp.take(k_cache, btab, axis=0).reshape(
             b, n, p.kv_heads, -1).astype(qh.dtype)
         kv_v = jnp.take(v_cache, btab, axis=0).reshape(
@@ -670,8 +699,6 @@ class MultiHeadAttention(Op):
         attending is equivalent to the oracle's interleaved loop: a
         later chunk position's write lands at a key position the
         earlier queries' masks exclude."""
-        from .pallas.paged_attention import paged_attention
-
         s, page = qh.shape[1], self._kv_page_size
         n = btab.shape[1] * page
         for j in range(s):
@@ -696,6 +723,14 @@ class MultiHeadAttention(Op):
                 kh[:, j].astype(k_cache.dtype))
             v_cache = v_cache.at[blk, off].set(
                 vh[:, j].astype(v_cache.dtype))
+        ctx = self._paged_kernel_read(qh, k_cache, v_cache, btab, pos,
+                                      scale)
+        return ctx, k_cache, v_cache
+
+    def _paged_kernel_read(self, qh, k_cache, v_cache, btab, pos, scale):
+        """One `paged_attention` dispatch over pools already written."""
+        from .pallas.paged_attention import paged_attention
+
         mesh = getattr(self, "_mesh", None)
         if self.shard.channel > 1 and mesh is not None \
                 and mesh.devices.size > 1:
@@ -722,7 +757,7 @@ class MultiHeadAttention(Op):
             )(qh, k_cache, v_cache, btab, pos)
         else:
             ctx = paged_attention(qh, k_cache, v_cache, btab, pos, scale)
-        return ctx, k_cache, v_cache
+        return ctx
 
     # -- attention core dispatch ----------------------------------------
     def _seq_degree(self) -> int:

@@ -174,6 +174,22 @@ class Op:
         each row really advances by."""
         return ()
 
+    #: planes each of `cache_entries()` holds: one, or one per pass of
+    #: the region that runs the op (`pcg.graph.LoopRegion`).  A paged
+    #: pool of N planes is ONE array `[N x num_blocks, page, ...]`, and
+    #: block b of a sequence's table is N pages, one a plane, at rows
+    #: `t x num_blocks + b`
+    cache_planes: int = 1
+
+    def loop_state(self, entries: Dict[str, jax.Array], step
+                   ) -> Dict[str, jax.Array]:
+        """This op's state entries as pass `step` (traced) of the
+        region that runs it sees them.  The executor hands the op that
+        view and keeps, of what the op returns, only the entries the
+        view left as they were: an op that caches points its table at
+        the pass's plane here, and the table it hands back is dropped."""
+        return entries
+
     def memory_bytes(self) -> int:
         total = sum(t.shape.size_bytes() for t in self.outputs)
         total += sum(w.shape.size_bytes() for w in self.weights)

@@ -321,7 +321,8 @@ class IncrementalEvaluator:
             )
         res = self.sim.simulate_ops(order, mesh_axes, training=self.training,
                                     memory_fn=memory_fn, zero_stage=stage,
-                                    placement=placement, remat_plan=plan)
+                                    placement=placement, remat_plan=plan,
+                                    repeats=self.graph.repeats())
         res.ops = order  # applied op sequence, for callers needing shapes
         self._base = _AppliedState(
             mesh_items=tuple(mesh_axes.items()),

@@ -10,15 +10,47 @@ list + derived edge maps; strategy serialization is JSON
 from __future__ import annotations
 
 import collections
+import dataclasses
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from ..fftype import OperatorType
 from ..ops.op import Op
 
 
+@dataclasses.dataclass(frozen=True)
+class LoopRegion:
+    """A contiguous run of ops that executes `times` times over ONE
+    copy of its weights (`FFModel.repeat`).  Pass t + 1 starts from
+    pass t's `carry_out`, which takes the place of `carry_in`: the one
+    tensor the run consumes from outside.  After the last pass
+    `carry_out` holds the last pass's value; `passes_op` (a
+    `LoopPasses`, or "") holds every pass's, stacked `[times, ...]`.
+    Ops and tensors are named, not held: a graph rebuilt under a
+    strategy (`apply_strategy`) keeps its regions."""
+
+    name: str
+    times: int
+    op_names: Tuple[str, ...]
+    carry_in: str   # tensor names (`<op>.out<i>`)
+    carry_out: str
+    passes_op: str = ""
+
+
 class Graph:
-    def __init__(self, ops: Optional[Sequence[Op]] = None):
+    def __init__(self, ops: Optional[Sequence[Op]] = None,
+                 regions: Sequence[LoopRegion] = ()):
         self.ops: List[Op] = list(ops) if ops else []
+        self.regions: List[LoopRegion] = list(regions)
+        #: the op whose output is the graph's, where the graph has
+        #: another sink besides (a gate that is read, not consumed);
+        #: None: the last sink (`FFModel.set_output`)
+        self.output_name: Optional[str] = None
+
+    def repeats(self) -> Dict[str, int]:
+        """{op name: times its region runs it}, for the ops inside a
+        `LoopRegion`; every other op runs once.  What a walk over ops
+        that sums a cost (FLOPs, simulated time) multiplies by."""
+        return {name: r.times for r in self.regions for name in r.op_names}
 
     def add_op(self, op: Op):
         self.ops.append(op)
@@ -75,6 +107,9 @@ class Graph:
         return [op for op in self.ops if op.op_type == OperatorType.INPUT]
 
     def sink_op(self) -> Op:
+        if self.output_name is not None:
+            (op,) = [op for op in self.ops if op.name == self.output_name]
+            return op
         consumed: Set[int] = set()
         for op in self.ops:
             for t in op.inputs:

@@ -169,26 +169,31 @@ def make_measure_fn(device=None, warmup: int = 1, repeats: int = 3,
     return fn
 
 
-_SKIP = {OperatorType.INPUT, OperatorType.WEIGHT, OperatorType.NOOP}
+_SKIP = {OperatorType.INPUT, OperatorType.WEIGHT, OperatorType.NOOP,
+         OperatorType.LOOP_PASSES}
 
 
 def profile_operators(
     ff, device=None, warmup: int = 2, repeats: int = 5,
 ) -> List[Dict[str, object]]:
     """Per-op timing table for a compiled FFModel (reference --profiling
-    printout).  Rows: name, type, fwd_ms, flops, shard shapes."""
+    printout).  Rows: name, type, fwd_ms, flops, shard shapes.  An op
+    inside a repeated region (`Graph.repeats`) is timed once and its
+    row's `fwd_ms` and `flops` are that many times the one call's."""
     graph = ff.operators if ff.operators is not None else ff.layers
+    times = graph.repeats()
     rows: List[Dict[str, object]] = []
     for op in graph.topo_order():
         if op.op_type in _SKIP or op.is_parallel_op():
             continue
         t = measure_op_forward(op, device=device, warmup=warmup,
                                repeats=repeats)
+        n = times.get(op.name, 1)
         rows.append({
             "name": op.name,
             "type": op.op_type.name,
-            "fwd_ms": None if t is None else t * 1e3,
-            "flops": op.flops(),
+            "fwd_ms": None if t is None else t * 1e3 * n,
+            "flops": op.flops() * n,
             "out_shape": [tuple(o.shape.shard_shape) for o in op.outputs],
         })
     return rows

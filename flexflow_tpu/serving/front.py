@@ -384,6 +384,8 @@ class ServingFront:
                 if model.has_slot_state:
                     sp.set(rstate_bytes=model.rstate_bytes,
                            **model.gdn_ops)
+                if model.loop:  # the twin's graph repeats a region
+                    sp.set(**model.loop)
             return model
 
         kw.setdefault("step_timeout", cfg.serving_step_timeout)

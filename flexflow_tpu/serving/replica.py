@@ -112,6 +112,15 @@ class SupervisedDecodeModel:
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)
+        # repeated regions of the twin's graph ({} / 0 without one)
+        self.loop = dict(getattr(model, "loop", {}) or {})
+        self.loop_steps = getattr(model, "loop_steps", 0)
+
+    @property
+    def exit_last(self):
+        # each row's exit pdf from the last decode dispatch (None where
+        # the family has no exit gate)
+        return getattr(self._model, "exit_last", None)
 
     def reset(self):
         reset = getattr(self._model, "reset", None)
@@ -600,6 +609,11 @@ class ServingReplica:
             # dispatches, and the state's bytes
             if "rstate" in sstats:
                 out["rstate"] = sstats["rstate"]
+            # a graph that repeats a region: its regions, the weight
+            # passes of the decode and prefill dispatches, and the exit
+            # gate's pdf summed over the decode dispatches' live rows
+            if "loop" in sstats:
+                out["loop"] = sstats["loop"]
         return out
 
     def close(self, timeout_s: Optional[float] = None) -> None:

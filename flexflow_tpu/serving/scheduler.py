@@ -158,7 +158,7 @@ class PagedKVDecodeModel:
                                 build_paged_prefill_step,
                                 build_paged_verify_step,
                                 build_slot_state_reset, cache_entries,
-                                decoder_recipe, make_decoder,
+                                cache_planes, decoder_recipe, make_decoder,
                                 require_carried, slot_state_entries)
         from ..ops.pallas.paged_attention import have_paged_kernel
 
@@ -267,13 +267,19 @@ class PagedKVDecodeModel:
                     f"draft model has {getattr(dm, 'batch_slots', 0)} "
                     f"slots < the target's {batch_slots} — draft rows "
                     f"mirror engine slots 1:1")
-        # the step fns DONATE their state argument; keep the twin's own
-        # pristine pytree intact and thread a private copy (reset()
-        # rebuilds from the pristine shapes after a failed step)
+        # the step fns DONATE their state argument.  The twin's own
+        # pytree is taken over and threaded, and the twin keeps its
+        # shapes, dtypes and shardings (all that reset() reads after a
+        # failed step): the pools exist ONCE on the device, where a
+        # private copy beside a pristine one held them twice (8 GB
+        # twice does not fit a chip)
         import jax
-        import jax.numpy as jnp
 
-        self._state = jax.tree.map(jnp.copy, self.ffd._state)
+        self._state = self.ffd._state
+        self.ffd._state = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            self._state)
         # bytes of ONE physical block summed across every layer's k/v
         # pool — the unit of the kernel-read telemetry (blocks read *
         # this = per-step KV bytes the fused kernel streams; the
@@ -281,10 +287,15 @@ class PagedKVDecodeModel:
         # Shapes here are GLOBAL (GSPMD arrays report the logical
         # shape); each of a tp replica's chips holds 1/tp of the head
         # axis, so per-chip bytes are the global count / tp.
+        # An op a repeated region runs holds a plane a pass in each
+        # pool (`cache_planes`): a block of the sequence's one table is
+        # that many pages there, and is counted, copied, exported and
+        # imported as such.
         self._pools = cache_entries(self.ffd)
+        self._planes = cache_planes(self.ffd)
         self.kv_block_bytes = sum(
             int(np.prod(self._state[op][k].shape[1:]))
-            * self._state[op][k].dtype.itemsize
+            * self._state[op][k].dtype.itemsize * self._planes[op]
             for op, names in self._pools.items() for k in names)
         self.kv_block_bytes_per_chip = self.kv_block_bytes // self.tp
         # routed-expert layers count their routing in a state entry the
@@ -294,6 +305,15 @@ class PagedKVDecodeModel:
         self._moe_ops = [op for op, entries in self._state.items()
                          if "moe_stats" in entries]
         self.moe_last: Optional[Dict[str, int]] = None
+        # repeated regions of the twin's graph (`loop_regions`,
+        # `loop_steps`, `loop_ops`; {} without one): a step program
+        # reads the regions' weights `loop_steps` times a pass.  A
+        # family with an exit gate also gets each row's exit pdf back
+        # from the decode step, fetched with the logits: `exit_last`
+        # [slots, passes] of the last dispatch
+        self.loop = dict(self.ffd.executor.loop_counts)
+        self.loop_steps = int(self.loop.get("loop_steps", 0))
+        self.exit_last: Optional[np.ndarray] = None
         # per-slot recurrent state (`slot_state_entries`): [slots, ...]
         # arrays beside the pools, of fixed size; a twin that has any
         # takes `row_tokens` in its step programs, and the scheduler
@@ -337,6 +357,7 @@ class PagedKVDecodeModel:
         import jax
         import jax.numpy as jnp
 
+        self._state = None  # (the old pools go before the new ones come)
         self._state = jax.tree.map(
             lambda x: jax.device_put(
                 jnp.zeros(x.shape, x.dtype), x.sharding),
@@ -381,12 +402,18 @@ class PagedKVDecodeModel:
         # happens INSIDE the jitted step and the state pytree is
         # donated — no host-side dict rebuild, no per-layer pool copy
         with span("model.enqueue", first=self._first_call("step")):
-            logits, self._state = self._step_fn(
+            logits, self._state, *exit_pdf = self._step_fn(
                 self.ffd._weights, self._state, tokens, seq_lens,
                 block_tables, *self._row_tokens(row_tokens),
             )
         # the wait for the device, then the logits' copy to the host
         with span("model.fetch"):
+            if exit_pdf:
+                import jax
+
+                logits, self.exit_last = jax.device_get(
+                    (logits, exit_pdf[0]))
+                return np.asarray(logits, np.float32)
             if not self._moe_ops:
                 return np.asarray(logits, np.float32)
             import jax
@@ -447,8 +474,19 @@ class PagedKVDecodeModel:
         Keyed "<op>/<pool entry>" so import lands each page back in the
         matching layer.  Worker-thread only: the state pytree is
         donated to the step programs, so reads must sit between steps."""
-        return {f"{name}/{k}": np.asarray(self._state[name][k][block])
+        return {f"{name}/{k}": np.asarray(
+                    self._state[name][k][self._block_rows(name, k, block)])
                 for name, names in self._pools.items() for k in names}
+
+    def _block_rows(self, name: str, entry: str, block: int):
+        """Where one block of the table lives in a pool: its row, or,
+        in a pool of several planes, its row in each (an index array:
+        the exported page is then `[planes, page, ...]`)."""
+        planes = self._planes[name]
+        if planes == 1:
+            return block
+        return block + np.arange(planes) * (
+            self._state[name][entry].shape[0] // planes)
 
     def import_block(self, block: int,
                      arrays: Dict[str, np.ndarray]) -> None:
@@ -465,7 +503,9 @@ class PagedKVDecodeModel:
             for k in self._pools.get(name, ()):
                 v = e[k]
                 page = jnp.asarray(arrays[f"{name}/{k}"], v.dtype)
-                e[k] = jax.device_put(v.at[block].set(page), v.sharding)
+                e[k] = jax.device_put(
+                    v.at[self._block_rows(name, k, block)].set(page),
+                    v.sharding)
             state[name] = e
         self._state = state
 
@@ -676,6 +716,17 @@ class ContinuousScheduler:
         # routed-expert counters summed over decode dispatches (a model
         # without such layers leaves them None): stats()["moe"]
         self.moe_totals: Optional[Dict[str, int]] = None
+        # a model whose graph repeats a region: weight passes of its
+        # dispatches and, with an exit gate, the exit pdf of the live
+        # rows summed over decode dispatches: stats()["loop"] (a model
+        # without a region leaves these off every span and out of
+        # stats())
+        self._loop_steps = int(getattr(model, "loop_steps", 0) or 0)
+        self.loop_totals: Optional[Dict] = (
+            {"decode_dispatches": 0, "decode_weight_passes": 0,
+             "prefill_dispatches": 0, "prefill_weight_passes": 0,
+             "exit_rows": 0, "exit_mass": []}
+            if self._loop_steps else None)
         # per-slot recurrent state (a model without any leaves these
         # off every span and out of stats()): rows whose state a
         # dispatch had to advance against rows whose state the program
@@ -1062,6 +1113,10 @@ class ContinuousScheduler:
             "latency": self.latency_stats(),
             **({"moe": dict(self.moe_totals)}
                if self.moe_totals is not None else {}),
+            **({"loop": dict(self.loop_totals,
+                             exit_mass=list(self.loop_totals["exit_mass"]),
+                             **getattr(self.model, "loop", {}))}
+               if self.loop_totals is not None else {}),
             **({"rstate": dict(self.rstate_totals,
                                bytes=int(self.model.rstate_bytes))}
                if self.rstate_totals is not None else {}),
@@ -1487,6 +1542,32 @@ class ContinuousScheduler:
         t["rows_touched"] += touched
         t["dispatches"] += 1
 
+    def _note_loop(self, dispatch, program: str, passes: int) -> None:
+        """The `loop_steps` arg of a dispatch span (weight passes: the
+        program's own passes times the region's) and, after a decode
+        dispatch of a model with an exit gate, `exit_mass_<t>`: the
+        exit pdf after pass t, mean over the dispatch's live rows
+        (`model.exit_last`, fetched with the logits).  Summed into
+        `loop_totals`."""
+        t = self.loop_totals
+        steps = passes * self._loop_steps
+        dispatch.set(loop_steps=steps)
+        t[f"{program}_dispatches"] += 1
+        t[f"{program}_weight_passes"] += steps
+        pdf = getattr(self.model, "exit_last", None)
+        if program != "decode" or pdf is None:
+            return
+        live = [i for i, s in enumerate(self._slots) if s is not None]
+        if not live:
+            return
+        mass = np.asarray(pdf, np.float64)[live].sum(axis=0)
+        dispatch.set(**{f"exit_mass_{k}": float(v) / len(live)
+                        for k, v in enumerate(mass)})
+        if not t["exit_mass"]:
+            t["exit_mass"] = [0.0] * len(mass)
+        t["exit_mass"] = [a + float(b) for a, b in zip(t["exit_mass"], mass)]
+        t["exit_rows"] += len(live)
+
     def _note_kernel_reads(self, reads: Dict) -> None:
         """Sum one completed dispatch's `_kv_reads` into the fused
         kernel's counters (stats()["paged_kernel"], obs:
@@ -1547,6 +1628,8 @@ class ContinuousScheduler:
                     tok, slen, btab, *((fed,) if self._rstate else ()))
                 if self._rstate:
                     self._note_rstate(dispatch, len(plan), C)
+                if self._loop_steps:
+                    self._note_loop(dispatch, "prefill", passes)
                 # (the program is enqueued: this runs beside it) a plan
                 # row's prefix is read once a pass: by the scan at each
                 # of its C positions, by the one-pass program once, up
@@ -1889,6 +1972,8 @@ class ContinuousScheduler:
                     self._note_rstate(dispatch, int(alive[0].sum()), 1)
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab, *alive)
+                if self._loop_steps:
+                    self._note_loop(dispatch, "decode", 1)
                 moe = getattr(self.model, "moe_last", None)
                 if moe is not None:
                     dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})

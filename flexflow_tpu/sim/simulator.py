@@ -1403,7 +1403,7 @@ class Simulator:
             topo, mesh_axes, training=training, measured_ops=measured_ops,
             seg_cost_total=seg_cost_total, memory_fn=memory_fn,
             zero_stage=zero_stage, placement=placement,
-            remat_plan=remat_plan,
+            remat_plan=remat_plan, repeats=graph.repeats(),
         )
 
     def simulate_ops(
@@ -1417,9 +1417,13 @@ class Simulator:
         zero_stage: Optional[int] = None,
         placement: Optional[str] = None,
         remat_plan: Optional[Sequence[int]] = None,
+        repeats: Optional[Dict[str, int]] = None,
     ) -> SimResult:
         """Aggregate cached per-op terms over `ops` (a topo-ordered op
-        sequence).  The ONE aggregation path shared by full and delta
+        sequence).  `repeats` ({op name: times}, `Graph.repeats()`)
+        prices an op inside a repeated region at that many times its
+        compute and its partial-sum traffic; its weights, and so its
+        gradient sync and update, exist once.  The ONE aggregation path shared by full and delta
         evaluations: the invariant delta_eval(state) == full_eval(state)
         holds bit-for-bit because both sum identical cached OpTerms in
         identical order.  A remat_plan (docs/PERF.md "Searched
@@ -1478,15 +1482,16 @@ class Simulator:
                 comm += terms.xfer
                 breakdown[op.name] = terms.xfer
                 continue
-            ps = terms.partial
+            times = repeats.get(op.name, 1) if repeats else 1
+            ps = terms.partial * times
             if training and ps:
                 ps *= 2.0  # fwd psum + bwd mirrored all-gather/psum
             comm += ps
             if op.guid in measured_ops:
                 breakdown[op.name] = ps
                 continue
-            analytic_compute += terms.compute
-            breakdown[op.name] = terms.compute + ps
+            analytic_compute += terms.compute * times
+            breakdown[op.name] = terms.compute * times + ps
         if training:
             # weight-update pass (optimizer_update_cost, from cached
             # per-op numel terms)

@@ -86,19 +86,22 @@ def serve_programs(fam, cfg, on_chip):
                          table) + rows))
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--out", required=True)
-    args = ap.parse_args()
+def load_cell(workload: str):
+    """(configuration, traffic, family module) of a cell of
+    BENCHMARK.json."""
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
+    cell = next(w for w in bench["workloads"] if w["name"] == workload)
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = harness.load_json(os.path.join(ROOT, entry["file"]))
     traffic = harness.load_json(os.path.join(
         ROOT, "benchmarks", "traffic", cell["traffic"] + ".json"))
-    fam = harness.load_module("families", cfg["family"])
+    return cfg, traffic, harness.load_module("families", cfg["family"])
 
+
+def described_chip():
+    """(on_chip, rng): a tree's arrays as `ShapeDtypeStruct`s on the
+    first chip of the compile-only `v5e:2x2` topology, and a PRNG key's
+    struct there."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -110,7 +113,17 @@ def main() -> int:
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             np.shape(x), x.dtype, sharding=chip), tree)
 
-    rng = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=chip)
+    return on_chip, jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                         sharding=chip)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--out", required=True)
+    args = ap.parse_args()
+    cfg, traffic, fam = load_cell(args.workload)
+    on_chip, rng = described_chip()
     programs = (train_programs(fam, cfg, traffic, on_chip, rng)
                 if traffic["driver"] == "train"
                 else serve_programs(fam, cfg, on_chip))

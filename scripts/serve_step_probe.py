@@ -1,0 +1,109 @@
+#!/usr/bin/env python3
+"""On the chip: a serving cell's two step programs, called directly at
+the real size BEFORE the first benchmark run (PR 35: a hang inside the
+harness says nothing).
+
+    chiprun --chips 1 -- python3 scripts/serve_step_probe.py \
+        --workload <cell> [--calls 10]
+
+Builds the cell's server as the harness does (`build_server`, the
+seed's weights, `PagedKVDecodeModel` with the front's arguments), gives
+every slot a row at mid length on blocks of its own, and calls the
+decode step and the prefill program: the first call's seconds (trace,
+lower, compile or cache load), then `--calls` more, timed to the
+logits' arrival (host clock around a blocking call: dispatch included).
+Prints the weight tree's parameters and bytes, the pool's bytes, the
+device's `memory_stats` and one JSON line.  A serving family only."""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+
+import run as harness  # noqa: E402  (benchmarks/run.py)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--calls", type=int, default=10)
+    ap.add_argument("--seed", type=int, default=3000000019)
+    args = ap.parse_args()
+    bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    cfg = harness.load_json(os.path.join(ROOT, entry["file"]))
+    fam = harness.load_module("families", cfg["family"])
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    out = {"device": jax.devices()[0].device_kind}
+    t0 = time.monotonic()
+    ff = fam.build_server(cfg, jax.devices()[:1])
+    ff.set_weights(fam.make_weights(cfg, args.seed, "program"))
+    leaves = jax.tree.leaves(ff._weights)
+    out["parameters"] = int(sum(x.size for x in leaves))
+    out["weight_bytes"] = int(sum(x.nbytes for x in leaves))
+    c = ff.config
+    model = PagedKVDecodeModel(
+        ff, batch_slots=c.serving_slots, page_size=c.kv_page_size,
+        num_blocks=c.kv_pool_blocks or None, devices=jax.devices()[:1],
+        prefill_chunk=c.prefill_chunk, prefix_cache=c.prefix_cache)
+    out["build_s"] = round(time.monotonic() - t0, 1)
+    out["paged_kernel"] = model.paged_kernel
+    out["loop"] = model.loop
+    out["kv_block_bytes"] = model.kv_block_bytes
+    out["pool_bytes"] = model.kv_block_bytes * model.num_blocks
+    b, width = model.batch_slots, model.max_blocks_per_seq
+    # every slot mid-sequence on blocks of its own
+    table = 1 + np.arange(b * width, dtype=np.int32).reshape(b, width) \
+        % (model.num_blocks - 1)
+    pos = np.full((b,), model.max_seq // 2, np.int32)
+    tokens = np.arange(1, b + 1, dtype=np.int32)
+    rows = (np.ones((b,), np.int32),) if model.has_slot_state else ()
+
+    def timed(call):
+        t = time.monotonic()
+        call()
+        jax.block_until_ready(model._state)
+        return time.monotonic() - t
+
+    def decode():
+        return model.step(tokens, pos, table, *rows)
+
+    chunk = np.tile(tokens[:, None], (1, model.prefill_chunk))
+    fed = (np.full((b,), model.prefill_chunk, np.int32),) \
+        if model.has_slot_state else ()
+
+    def prefill():
+        return model.prefill_step(chunk, pos, table, *fed)
+
+    for name, call in (("step", decode), ("prefill", prefill)):
+        out[f"{name}_first_call_s"] = round(timed(call), 2)
+        times = [timed(call) for _ in range(args.calls)]
+        out[f"{name}_ms"] = round(1e3 * float(np.median(times)), 3)
+        print(f"{name}: first {out[f'{name}_first_call_s']} s, then "
+              + " ".join(f"{1e3 * t:.2f}" for t in times) + " ms",
+              flush=True)
+    logits = decode()
+    out["logits_finite"] = bool(np.isfinite(logits).all())
+    if model.exit_last is not None:
+        out["exit_pdf_row0"] = [round(float(x), 4)
+                                for x in model.exit_last[0]]
+    stats = jax.devices()[0].memory_stats() or {}
+    out["memory"] = {k: int(stats[k]) for k in (
+        "bytes_in_use", "peak_bytes_in_use", "bytes_limit") if k in stats}
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

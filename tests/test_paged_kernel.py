@@ -170,6 +170,7 @@ class _Attn:
 
     _attend_decode_paged = _M._attend_decode_paged
     _attend_decode_paged_kernel = _M._attend_decode_paged_kernel
+    _paged_kernel_read = _M._paged_kernel_read
 
     def __init__(self, kernel):
         self.params = SimpleNamespace(num_heads=CELL["h"])
