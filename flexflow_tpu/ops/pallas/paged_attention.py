@@ -4,17 +4,17 @@ block table.
 
 The gather formulation (`ops/attention.py _attend_decode_paged`, the
 reference oracle) materializes a dense ``[slots, decode_max_seq, h, d]``
-K/V view from the block pool every step, so per-step HBM traffic is
-proportional to the TABLE WIDTH regardless of how many tokens are
-actually live.  This kernel instead makes the block table part of the
-kernel's index maps: grid ``(slots, table_width / P)`` with the table
-and the per-slot sequence lengths as SCALAR-PREFETCH operands, and P
-K and P V BlockSpecs a grid program (``PAGES_PER_STEP``), the p-th of
-which resolves ``(block_table[i, kb * P + p], 0, 0, 0)`` — Pallas's
-pipeline DMAs exactly the physical pages a row owns, straight from the
-pool's HBM layout, no dense view ever exists.  Every shape and the
-grid are static: how many tokens are live is data, so one program
-serves every mix of lengths.
+K/V view from the block pool every step, so per-step HBM traffic
+follows the TABLE WIDTH whatever is live.  This kernel instead makes
+the block table part of its index maps: grid
+``(slots, table_width / P)`` with the table and the per-slot sequence
+lengths as SCALAR-PREFETCH operands, and P K and P V BlockSpecs a grid
+program (``PAGES_PER_STEP``), the p-th of which resolves
+``(block_table[i, kb * P + p], 0, 0, 0)`` — Pallas's pipeline DMAs
+exactly the physical pages a row owns, straight from the pool's HBM
+layout, no dense view ever exists.  Every shape and the grid are
+static: how many tokens are live is data, so one program serves every
+mix of lengths.
 
 Block shapes (what Mosaic accepts for the pool layout
 ``[num_blocks, page, h, d]``): a K/V block is ALL local heads of one
@@ -27,13 +27,20 @@ HBM and copying pages by hand, with a trip count that follows the
 row's length, is refused by Mosaic for d = 64: a slice of a ref whose
 minor dim is padded to 128 lanes.)
 
-The math stays in that layout, heads on sublanes and the head dim on
-lanes, and runs on the VECTOR unit: with one query a row a score is
-the lane reduction of ``k * q`` and the context the sum over key
-positions of ``p * v``; bf16 products are exact in f32.  Handing the
-MXU a matrix would mean transposing every page to head-major first
-(what this kernel did until PR 28: 0.47 us a page against 0.30 now, my
-chip run, PR 28).
+The math stays in that layout and both products run on the MXU: a
+page ``[page, h, d]`` is, with no data movement, the matrix
+``[page * h, d]`` (row c is key c // h of head c % h; h = 16 is bf16's
+sublane tile), the queries ``[s, h, d]`` likewise ``[s * h, d]``.  ONE
+product contracting d scores a fold's pages for every pair of heads,
+the mask keeps an entry where the heads match and the key is visible,
+the online softmax runs over the lanes of that one f32 matrix, and ONE
+product with ``v [n * page * h, dv]`` gives the context (another
+head's probability is exp(-1e30) = 0).  The MXU does h times the
+useful multiply-adds to spare the vector unit, which from PR 28 to
+PR 42 widened, multiplied and reduced the whole page a query (0.30 us
+a page; until PR 28 pages were transposed to head-major: 0.47).  A
+fold is a chain of MXU round trips, so it takes several pages at once
+(`_paged_kernel`): one page a fold read 0.42 us a page (PERF.md, PR 42).
 
 Traffic discipline: a row with ``pos`` tokens live owns
 ``pos // page + 1`` blocks.  Table columns past that are mapped to the
@@ -42,30 +49,21 @@ pipeline elides (no re-fetch) — and their compute is skipped with
 ``pl.when``, so per-step HBM reads scale with live tokens, not
 ``decode_max_seq``.  What does NOT scale with live tokens is the grid:
 ``slots * table_width / P`` programs of ``2 P + 1`` index maps each,
-0.07 ms a layer at the serving cell's 16 x 64 (PERF.md, PR 28).
-Partial tail blocks and the scratch rows idle
-slots park on (table all zeros, seq_len 0) are handled by the same
-per-position mask the gather oracle uses: key positions past a row's
-own length never enter the softmax.
+0.03 ms a launch at cell 3's 16 x 64 and 0.05 at cell 7's 16 x 20,
+most of either's launch (PERF.md, PR 42).  Partial tail blocks and the
+scratch rows idle slots park on (table all zeros, seq_len 0) fall to
+the gather oracle's own per-position mask: key positions past a row's
+length never enter the softmax.
 
 Two entry points mirror the host-side twins (decoding.py):
-
-  * ``paged_decode_attention`` — the seq-1 decode step;
-  * ``paged_chunk_attention``  — the seq-C chunked-prefill step
-    (``build_paged_chunk_step``): C queries per row, causal within the
-    chunk via the mask ``key_pos <= pos + j``.  The gather twin's
-    per-position scatter/gather/attend loop collapses into ONE kernel
-    dispatch — the k/v scatter stays in plain JAX (it writes O(b*C*h*d)
-    bytes, byte-identical to the oracle's), the kernel absorbs the
-    read side.
-
-Both accumulate the online softmax in f32 (m/l running columns + an
-[s, h, d] accumulator in VMEM scratch carried across the kb grid
-axis), like ops/pallas/flash_attention.py.  On the CPU backend the
-same kernel runs under ``interpret=True`` — the parity tests
-(tests/test_paged_kernel.py) execute the real kernel logic against
-the gather oracle; on TPU it is always compiled by Mosaic, never
-interpreted.
+``paged_decode_attention``, the seq-1 decode step, and
+``paged_chunk_attention``, the seq-C chunked-prefill step
+(``build_paged_chunk_step``): C queries a row, causal within the chunk
+via ``key_pos <= pos + j``, ONE dispatch where the gather twin loops
+over positions; the k/v scatter stays in plain JAX, byte-identical to
+the oracle's.  Both carry the online softmax in f32 VMEM scratch over
+the kb grid axis.  On the CPU the kernel runs under ``interpret=True``
+(tests/test_paged_kernel.py); on TPU Mosaic always compiles it.
 """
 from __future__ import annotations
 
@@ -140,46 +138,67 @@ def scan_blocks_read(seq_lens: np.ndarray, counts: np.ndarray,
     return int(np.where(j < counts, last // page + 1, 0).sum())
 
 
-def _fold_page(q_ref, k, v, m_ref, l_ref, acc_ref, col, pos, *,
-               page: int, scale: float):
-    """Fold one physical page (table column `col`) into the row's
-    online softmax.  k, v: [page, h, d] as the pool holds them."""
-    # bf16 products are exact in f32: operands as the pool holds them,
-    # accumulation in f32
-    q = q_ref[0].astype(jnp.float32) * scale   # [chunk, h, dk]
-    k = k.astype(jnp.float32)
-    v = v.astype(jnp.float32)
-    s = jnp.sum(q[:, None] * k[None], axis=-1,
-                keepdims=True)                 # [chunk, page, h, 1]
-    # chunk token j attends key positions <= pos + j: causal within
-    # the chunk, visible-prefix across steps — exactly the gather
-    # oracle's mask, so partial tail blocks and scratch rows (pos 0,
-    # all-zero table) fall out of the same comparison
-    k_pos = col * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-    m_prev = m_ref[...]                        # [chunk, h, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    pr = jnp.exp(s - m_new[:, None])
+def _mxu(a, b, contract):
+    """a x b over `contract` on the MXU, f32 out: bf16 products are
+    exact there; f32 operands (the parity tests') ask for f32's."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a.astype(dt), b.astype(dt), (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+
+
+def _context(pr, v):
+    """pr [m, n] f32 x v [n, dv], the probabilities NOT rounded: an f32
+    is the sum of three bf16, so over a bf16 pool the three parts ride
+    ONE product stacked on its rows, every term exact, summed in f32."""
+    if v.dtype != jnp.bfloat16:
+        return _mxu(pr, v, ((1,), (0,)))
+    hi = pr.astype(v.dtype)
+    mid = (pr - hi).astype(v.dtype)
+    lo = (pr - hi - mid).astype(v.dtype)
+    hi, mid, lo = jnp.split(
+        _mxu(jnp.concatenate([hi, mid, lo]), v, ((1,), (0,))), 3)
+    return hi + mid + lo
+
+
+def _fold_pages(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
+                page: int, scale: float):
+    """Fold the physical pages of table columns `col`, `col` + 1, ..
+    into the row's online softmax.  ks, vs: a [page, h, d] each, as the
+    pool holds them."""
+    chunk, h, _ = q_ref.shape[1:]
+    rows = chunk * h
+    # a page as the matrix it already is: row c of [page * h, d] is
+    # key c // h of head c % h; scores [chunk * h, pages * page * h]
+    k, v = (jnp.concatenate([x.reshape(page * h, -1) for x in xs])
+            for xs in (ks, vs))
+    s = _mxu(q_ref[0].reshape(rows, -1), k, ((1,), (1,))) * scale
+    # an entry is a score where its two heads match; chunk token j
+    # attends key positions <= pos + j: causal within the chunk,
+    # visible-prefix across steps — the gather oracle's mask, so tail
+    # blocks and scratch rows (pos 0, zero table) fall out of it too
+    r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = (r % h == c % h) & (col * page + c // h <= pos + r // h)
+    s = jnp.where(keep, s, _NEG_INF)
+    m_prev = m_ref[...].reshape(rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    pr = jnp.exp(s - m_new)      # another head's entry: exp(-1e30) = 0
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=1)
-    acc_ref[...] = acc_ref[...] * corr + jnp.sum(
-        pr * v[None], axis=1)                  # [chunk, h, dv]
-    m_ref[...] = m_new
+    l_ref[...] = (l_ref[...].reshape(rows, 1) * corr + jnp.sum(
+        pr, axis=1, keepdims=True)).reshape(l_ref.shape)
+    acc_ref[...] = (acc_ref[...].reshape(rows, -1) * corr
+                    + _context(pr, v)).reshape(acc_ref.shape)
+    m_ref[...] = m_new.reshape(m_ref.shape)
 
 
 def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
                   scale: float, table_width: int, chunk: int, pages: int):
     """One grid program = (row i, table columns kb*pages ..
-    kb*pages+pages-1): fold up to `pages` physical pages of row i —
-    all heads of each — into the row's online softmax.
-
-    The page stays in the pool's own layout ``[page, h, d]`` (heads on
-    sublanes, the head dim on lanes) and the math runs on the vector
-    unit: a score is the lane reduction of ``k * q``, the context the
-    sum over key positions of ``p * v``.  With ONE query a row there
-    is no matrix to hand the MXU without first transposing every page
-    to head-major, which costs more than the products themselves."""
+    kb*pages+pages-1): fold up to `pages` physical pages of row i, all
+    heads of each at once (`_fold_pages`), into the row's online
+    softmax."""
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     i = pl.program_id(0)
@@ -194,13 +213,21 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
     pos = slen_ref[i]
     live = _live_block_count(pos, chunk, page, table_width)
 
-    for p in range(pages):
+    # a fold is one chain of MXU round trips and lane reductions, and
+    # costs a page alone what it costs several: fold at once as many of
+    # the program's pages as keep the scores, [chunk * h, n * page * h]
+    # f32, within the 64 vector registers of 1,024 (a page of the group
+    # past the live ones is all future keys: masked)
+    h = q_ref.shape[2]
+    group = max(1, min(pages, 65536 // (chunk * h * page * h)))
+    for p in range(0, pages, group):
         col = kb * pages + p
 
         @pl.when(col < live)
         def _fold(p=p, col=col):
-            _fold_page(q_ref, k_refs[p][0], v_refs[p][0], m_ref, l_ref,
-                       acc_ref, col, pos, page=page, scale=scale)
+            _fold_pages(q_ref, [r[0] for r in k_refs[p:p + group]],
+                        [r[0] for r in v_refs[p:p + group]], m_ref, l_ref,
+                        acc_ref, col, pos, page=page, scale=scale)
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _write():
@@ -253,8 +280,7 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
     # The clamp is applied to the table HERE, once a dispatch (XLA
     # shares it between the layers of a pass): the scalar core then
     # evaluates 2 * pages index maps a grid step, each one SMEM load
-    # (measured, PERF.md PR 28: a fifth of a short row's cost when the
-    # maps did the clamp themselves)
+    # (PERF.md PR 28: a fifth of a short row's cost with it in the maps)
     steps = -(-table_width // pages)
     live = _live_block_count(seq_lens, s, page, table_width)
     cols = jnp.arange(steps * pages, dtype=jnp.int32)[None, :]

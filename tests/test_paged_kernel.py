@@ -125,6 +125,90 @@ def test_kernel_decode_and_chunk_twins_agree():
                                    rtol=2e-6, atol=2e-6)
 
 
+# -- the fold at the widths of both cells that run it (16 heads, page
+# 16, a bf16 pool: gpt2-medium-serve d = 64 under a table 64 wide,
+# ouro-2.6b-serve d = 128 under one 20 wide), fewer rows than either ----
+
+WIDTHS = [(64, 64), (128, 20)]
+
+
+def _wide_case(d, tw, chunk, q_dtype=jnp.bfloat16):
+    """`_random_case` at a cell's widths over a bf16 pool: a scratch
+    row, partial tails, and row 1 placed so that its LAST page is live
+    for the chunk's last query and wholly in the future of its first."""
+    rng = np.random.RandomState(d + tw + chunk)
+    qh, kp, vp, btab, slen = _random_case(
+        rng, b=5, s=chunk, h=16, d=d, page=16, table_width=tw)
+    # queries a bf16 holds, whatever carries them: the oracle then sees
+    # the kernel's own operands
+    qh = qh.astype(jnp.bfloat16).astype(q_dtype)
+    kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    slen = np.asarray(slen).copy()
+    slen[1] = 3 * 16 - min(3, chunk - 1)
+    return qh, kp, vp, btab, jnp.asarray(slen)
+
+
+def _f32_oracle(qh, kp, vp, btab, slen, scale):
+    return np.asarray(_gather_oracle(
+        *(x.astype(jnp.float32) for x in (qh, kp, vp)), btab, slen, scale))
+
+
+@pytest.mark.parametrize("q_dtype,tol", [(jnp.bfloat16, 1e-2),
+                                         (jnp.float32, 2e-6)])
+@pytest.mark.parametrize("chunk", [1, 8])
+@pytest.mark.parametrize("d,tw", WIDTHS)
+def test_fold_at_cell_widths_matches_f32_oracle(d, tw, chunk, q_dtype, tol):
+    """The merged-rows fold (one product for a page's scores of all 16
+    heads, one for its context) against the gather math in float32 at
+    both cells' widths, one query a row and a chunk of 8.  With bf16
+    queries the context is rounded to bf16 on the way out (1e-2); with
+    the same queries carried in float32 it is not, and 2e-6 shows that
+    the probabilities reach the second product unrounded: rounded to
+    bf16 they would miss by ~4e-3."""
+    qh, kp, vp, btab, slen = _wide_case(d, tw, chunk, q_dtype)
+    scale = 1.0 / np.sqrt(d)
+    got = pk.paged_attention(qh, kp, vp, btab, slen, scale, interpret=True)
+    assert got.dtype == q_dtype
+    want = _f32_oracle(qh, kp, vp, btab, slen, scale)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("chunk", [1, 8])
+@pytest.mark.parametrize("d,tw", WIDTHS)
+def test_fold_keeps_heads_apart(d, tw, chunk):
+    """A page's scores come out of ONE product over all its heads, so
+    an entry pairs every query head with every key head; only matching
+    heads may survive the mask.  Whatever the OTHER heads' keys hold
+    (NaN, +-1e30, noise) and whatever finite values ride beside them
+    (+-1e30 included), a head's context stays equal to the bit: a
+    foreign key's score is selected away before the softmax, a foreign
+    value is multiplied by an exact zero.  (A NaN or infinite VALUE is
+    not finite times zero; the pool holds only what the model's own
+    projections wrote, and a row with one is lost in the output
+    projection over all heads whichever way it is read.)"""
+    qh, kp, vp, btab, slen = _wide_case(d, tw, chunk)
+    scale = 1.0 / np.sqrt(d)
+    want = np.asarray(pk.paged_attention(
+        qh, kp, vp, btab, slen, scale, interpret=True), np.float32)
+    rng = np.random.RandomState(chunk)
+    head = 5
+    others = np.arange(16) != head
+
+    def spoil(pool, fills):
+        pool = np.asarray(pool, np.float32).copy()
+        pick = rng.randint(len(fills), size=pool[:, :, others].shape)
+        pool[:, :, others] = np.asarray(fills, np.float32)[pick]
+        return jnp.asarray(pool, jnp.bfloat16)
+
+    got = np.asarray(pk.paged_attention(
+        qh, spoil(kp, [np.nan, 1e30, -1e30, 7.0]),
+        spoil(vp, [1e30, -1e30, -3.0, 0.5]), btab, slen, scale,
+        interpret=True), np.float32)
+    np.testing.assert_array_equal(got[:, :, head], want[:, :, head])
+    assert np.isfinite(want).all()
+
+
 # -- the serving cell's own shapes (gpt2-medium-serve: 16 slots, table
 # width 64, page 16, 16 heads x 64, bf16 pool of 513 blocks) ----------
 

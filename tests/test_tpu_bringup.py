@@ -93,6 +93,9 @@ def _one_tile_cases():
 #: benchmarks/configs/gpt2-medium-serve.json as its cell runs it: 16
 #: slots, page 16, 16 heads x 64, table width 64, 513 blocks
 CELL_GEOMETRY = (16, 16, 16, 64, 64, 513)
+#: benchmarks/configs/ouro-2.6b-serve.json: 16 heads x 128, table
+#: width 20, four planes of 321 blocks a layer
+LOOP_CELL_GEOMETRY = (16, 16, 16, 128, 20, 4 * 321)
 
 
 def _paged_args(s, dtype, sharding=lambda *_: None, geometry=None):
@@ -119,11 +122,14 @@ def _paged_cases():
         for dtype in (jnp.float32, jnp.bfloat16):
             cases.append((f"paged_s{s}_{jnp.dtype(dtype).name}", _paged,
                           _paged_args(s, dtype), 1))
-    # the seq-1 read the serving cell's step programs scan, and the
-    # seq-8 chunk twin, at the cell's own widths
+    # the seq-1 read the serving cells' step programs run and the
+    # seq-8 chunk twin (the loop cell's one-pass prefill), at each
+    # cell's own widths
     for s in (1, 8):
         cases.append((f"paged_cell_s{s}", _paged, _paged_args(
             s, jnp.bfloat16, geometry=CELL_GEOMETRY), 1))
+        cases.append((f"paged_loop_cell_s{s}", _paged, _paged_args(
+            s, jnp.bfloat16, geometry=LOOP_CELL_GEOMETRY), 1))
     return cases
 
 
