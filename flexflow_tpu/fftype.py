@@ -112,6 +112,7 @@ class OperatorType(enum.Enum):
     MLA_ATTENTION = "mla_attention"
     ROUTED_EXPERTS = "routed_experts"
     GATED_DELTA_NET = "gated_delta_net"
+    KIMI_DELTA_ATTENTION = "kimi_delta_attention"
     SHORT_CONV = "short_conv"
     # every pass's output of a repeated region, stacked (pcg LoopRegion)
     LOOP_PASSES = "loop_passes"

@@ -487,11 +487,12 @@ class FFModel:
                       kv_num_blocks: int = 0, kv_kernel: str = "gather"):
         """Multi-head latent attention (ops/mla.py): `params` is an
         `MLAParams`; the cache keywords are `multihead_attention`'s,
-        and build the paged LATENT cache."""
+        and build the paged LATENT cache.  `positions` is None for an
+        op that rotates nothing (`params.nope`)."""
         from .ops.mla import MLAttention
 
         return self._add(MLAttention(
-            params, [input, positions],
+            params, [input] if positions is None else [input, positions],
             name=self._name("mla_attention", name),
             decode_max_seq=decode_max_seq, kv_page_size=kv_page_size,
             kv_num_blocks=kv_num_blocks, kv_kernel=kv_kernel))
@@ -506,6 +507,15 @@ class FFModel:
         return self._add(GatedDeltaNet(
             params, [input], name=self._name("gated_delta_net", name),
             slot_state=slot_state))
+
+    def kimi_delta_attention(self, input, params, name=None):
+        """A Kimi Delta Attention mixer (ops/kimi_delta_attention.py):
+        the delta rule with a decay per channel; `params` is a
+        `KimiDeltaAttentionParams`.  Stateless (training) only."""
+        from .ops.kimi_delta_attention import KimiDeltaAttention
+
+        return self._add(KimiDeltaAttention(
+            params, [input], name=self._name("kimi_delta_attention", name)))
 
     def short_conv(self, input, kernel: int = 3, name=None):
         """A gated short convolution over the sequence
@@ -1318,6 +1328,11 @@ class FFModel:
             "attn_dense_ops": sum(p in ("dense", "jnp") for p in plans),
             "attn_tile": "+".join(sorted(set(kernels))),
         }
+        chunks = [op.chunk_tokens(op.inputs[0].shape.logical_shape[1])
+                  for op in self.operators.topo_order()
+                  if op.op_type == OperatorType.KIMI_DELTA_ATTENTION]
+        if chunks:  # positions a chunk of the delta rule holds; 0: the scan
+            counts.update({"kda_chunk_tokens": min(chunks)})
         experts = [op.product_plan() for op in self.executor.routed_expert_ops]
         if experts:  # which product each routed-expert layer takes
             counts["expert_grouped_ops"] = experts.count("grouped")

@@ -40,10 +40,14 @@ never by a flag:
   a layer holds a row's `S` in VMEM over the step's positions: read
   once, written once onto the donated input, and rows whose
   `row_tokens` is 0 neither read nor written (their `o` is 0);
-* "plain": everywhere else, a `lax.scan` of `delta_rule_step` over the
-  step's positions in plain jax.numpy, which reads and writes every
-  slot's `S` a position, live or not.  The stateless shape always takes
-  it: the kernel has no backward pass, and no cell runs that shape.
+* "plain": with per-slot state everywhere else, a `lax.scan` of
+  `delta_rule_step` over the step's positions in plain jax.numpy, which
+  reads and writes every slot's `S` a position, live or not;
+* "chunked": the stateless shape, on every backend: the recurrence a
+  chunk of positions at a time (`ops/chunked_delta_rule.py`), this
+  op's one decay a head broadcast over the key's channels.  jax
+  differentiates it and its backward pass keeps the chunk-boundary
+  states only, where the scan a position keeps every position's.
 """
 from __future__ import annotations
 
@@ -57,6 +61,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
+from .chunked_delta_rule import delta_rule_chunked, pick_chunk
 from .op import Op, ShapeError, ShardConfig, WeightSpec
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
 from .short_conv import causal_depthwise_conv
@@ -95,8 +100,9 @@ def l2norm(x, eps: float = 1e-6):
 
 def delta_rule_step(S, q, k, v, g, beta):
     """One position of every row and value head, float32: S [b, h, dk,
-    dv], q / k [b, h, dk], v [b, h, dv], g / beta [b, h] -> (S, o)."""
-    S = S * jnp.exp(g)[..., None, None]
+    dv], q / k [b, h, dk], v [b, h, dv], beta [b, h], g [b, h] (one
+    decay a head) or [b, h, dk] (one a channel of the key) -> (S, o)."""
+    S = S * jnp.exp(g)[(..., None, None) if g.ndim == 2 else (..., None)]
     d = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", S, k,
                                           precision=_HIGHEST))
     S = S + k[..., :, None] * d[..., None, :]
@@ -106,8 +112,8 @@ def delta_rule_step(S, q, k, v, g, beta):
 def delta_rule_scan(S, q, k, v, g, beta):
     """`delta_rule_step` over a step's positions in order, plain
     jax.numpy: S [b, h, dk, dv], q / k [b, s, h, dk], v [b, s, h, dv],
-    g / beta [b, s, h] -> (S, o [b, s, h, dv]).  One position is the
-    step itself, more a `lax.scan` of it."""
+    beta [b, s, h], g [b, s, h] or [b, s, h, dk] -> (S, o [b, s, h, dv]).
+    One position is the step itself, more a `lax.scan` of it."""
     if q.shape[1] == 1:
         S, o = delta_rule_step(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                beta[:, 0])
@@ -136,8 +142,8 @@ class GatedDeltaNet(Op):
         return ("conv_state", "rec_state") if self._slot_state else ()
 
     def recurrence_plan(self, step_tokens: int) -> str:
-        """"kernel" or "plain": what a step of `step_tokens` tokens a
-        row takes on this backend (`pick_recurrence`)."""
+        """"kernel", "plain" or "chunked": what a step of `step_tokens`
+        tokens a row takes on this backend (`pick_recurrence`)."""
         p: GatedDeltaNetParams = self.params
         return pick_recurrence(jax.default_backend(), self._slot_state,
                                p.head_k_dim, p.head_v_dim, step_tokens)
@@ -246,8 +252,13 @@ class GatedDeltaNet(Op):
                 real, -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
                     ba[..., hv:] + dt_bias.astype(f32)), 0.0)
             S = S.astype(f32)
-            if self.recurrence_plan(s) == "kernel":
+            plan = self.recurrence_plan(s)
+            if plan == "kernel":
                 S, o = gated_delta_rule(S, q, k, v, g, beta, count)
+            elif plan == "chunked":
+                S, o = delta_rule_chunked(S, q, k, v, g, beta,
+                                          *pick_chunk(s),
+                                          operand_dtype=x.dtype)
             else:
                 S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
         with scope("out"):

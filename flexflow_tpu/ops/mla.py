@@ -14,8 +14,14 @@ qk_rope_head_dim` values a layer (576 at the published sizes, against
 Two formulations, one set of weights:
 
 * no cache (`decode_max_seq == 0`): keys and values are EXPANDED from
-  the latent and attention is ordinary causal attention over the
-  step's own tokens: the graph a trainer or a one-shot forward runs;
+  the latent, `k_h = [k_nope_h | k_r]` (192 wide at the published
+  sizes) against values of `v_head_dim` (128), and the causal core over
+  the step's own tokens is `MultiHeadAttention`'s choice again: from
+  `flash_min_seq` keys up the flash kernels
+  (`ops/pallas/flash_attention.py flash_mha`, which pads q and k to
+  whole lane tiles and keeps the values' width), so that no `[s, s]`
+  tensor a head exists forward or backward; below it einsum, softmax,
+  einsum.  The graph a trainer or a one-shot forward runs;
 * paged latent cache (`decode_max_seq`, `kv_page_size`,
   `kv_num_blocks`): the state is ONE pool `latent_cache [num_blocks,
   page, rank + rope]` plus the host-owned `block_table` / `seq_lens`
@@ -32,10 +38,18 @@ Two formulations, one set of weights:
   in-place latent kernel is written; Mosaic refused a 64-wide slice in
   PR 28, and the rope part is 64 wide).
 
-Positions arrive as the op's second input; RoPE angles are computed
-from them in float32.  The published code de-interleaves the rope
-channels before rotating half against half; rotating adjacent pairs
-`(2i, 2i+1)` as here gives the same scores.
+Two published variants of the block change the weights or the
+rotation, both read from `MLAParams`: `q_lora_rank == 0` has NO query
+bottleneck (one `wq [e, heads, nope + rope]` stands where `wq_a`,
+`q_norm` and `wq_b` do), and `nope` rotates nothing (the 64 "rope"
+channels of q and the shared `k_r` enter the score as they are: the
+model's other layers carry the order of the tokens).
+
+Positions arrive as the op's second input (an op without positions
+takes x alone); RoPE angles are computed from them in float32.  The
+published code de-interleaves the rope channels before rotating half
+against half; rotating adjacent pairs `(2i, 2i+1)` as here gives the
+same scores.
 """
 from __future__ import annotations
 
@@ -73,6 +87,8 @@ class MLAParams:
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
     eps: float = 1e-5
+    #: no position enters the score: q_rope and k_r are not rotated
+    nope: bool = False
 
     @property
     def latent_width(self) -> int:
@@ -168,13 +184,19 @@ class MLAttention(Op):
         return ("latent_cache",) if self._paged() else ()
 
     def infer_output_shapes(self, input_shapes):
-        x, pos = input_shapes
+        x, *pos = input_shapes
         xd = [d for d in x.dims if not d.is_replica_dim]
-        if len(xd) != 3 or pos.logical_shape != x.logical_shape[:2]:
+        if len(pos) != (0 if self.params.nope else 1):
+            raise ShapeError(
+                f"{self.name}: a rotating op takes x and positions, one "
+                f"without positions (nope) x alone; got {len(pos) + 1} "
+                "inputs")
+        if len(xd) != 3 or any(q.logical_shape != x.logical_shape[:2]
+                               for q in pos):
             raise ShapeError(
                 f"{self.name}: expect x [batch, seq, embed] and positions "
                 f"[batch, seq], got {x.logical_shape} and "
-                f"{pos.logical_shape}")
+                f"{[q.logical_shape for q in pos]}")
         if xd[1].degree != 1 or xd[2].degree != 1 \
                 or not self.shard.is_trivial():
             raise ShapeError(
@@ -184,11 +206,15 @@ class MLAttention(Op):
             raise ShapeError(f"{self.name}: rope width must be even")
         return [x]
 
+    def _query_weights(self) -> int:
+        """Weights that make the queries: wq_a, q_norm, wq_b, or one wq."""
+        return 3 if self.params.q_lora_rank else 1
+
     def num_trainable_weights(self) -> int:
-        return 7
+        return self._query_weights() + 4
 
     def make_weight_specs(self, input_shapes):
-        x, _ = input_shapes
+        x = input_shapes[0]
         p: MLAParams = self.params
         xd = [d for d in x.dims if not d.is_replica_dim]
         rep = ParallelDim(1, x.total_degree, is_replica_dim=True)
@@ -199,12 +225,12 @@ class MLAttention(Op):
 
         init, one = DEFAULT_WEIGHT_INIT, ConstantInitializer(1.0)
         e, h = p.embed_dim, p.num_heads
-        specs = [
+        dq = p.qk_nope_head_dim + p.qk_rope_head_dim
+        specs = ([
             WeightSpec("wq_a", w(e, p.q_lora_rank), init),
             WeightSpec("q_norm", w(p.q_lora_rank), one),
-            WeightSpec("wq_b", w(p.q_lora_rank, h,
-                                 p.qk_nope_head_dim + p.qk_rope_head_dim),
-                       init),
+            WeightSpec("wq_b", w(p.q_lora_rank, h, dq), init),
+        ] if p.q_lora_rank else [WeightSpec("wq", w(e, h, dq), init)]) + [
             WeightSpec("wkv_a", w(e, p.latent_width), init),
             WeightSpec("kv_norm", w(p.kv_lora_rank), one),
             WeightSpec("wkv_b", w(p.kv_lora_rank, h,
@@ -242,20 +268,29 @@ class MLAttention(Op):
 
     # -- forward --------------------------------------------------------
     def forward(self, inputs, weights, *, training=False, rng=None):
-        x, positions = inputs
+        x, *positions = inputs
         p: MLAParams = self.params
-        wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo = weights[:7]
+        nq = self._query_weights()
+        wkv_a, kv_norm, wkv_b, wo = weights[nq:nq + 4]
         dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
+
+        def turn(t):
+            return t if p.nope else rope(t, positions[0], p)
+
         with scope("proj"):
-            cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
-            q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
-            q_nope, q_rope = q[..., :dn], rope(q[..., dn:], positions, p)
+            if p.q_lora_rank:
+                wq_a, q_norm, wq_b = weights[:3]
+                cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
+                q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
+            else:
+                q = jnp.einsum("bse,ehd->bshd", x, weights[0])
+            q_nope, q_rope = q[..., :dn], turn(q[..., dn:])
             kv = jnp.matmul(x, wkv_a)
             latent = jnp.concatenate(
                 [rms_normalize(kv[..., :rk], kv_norm, p.eps),
-                 rope(kv[..., rk:], positions, p)], axis=-1)  # [b, s, rk + dr]
+                 turn(kv[..., rk:])], axis=-1)  # [b, s, rk + dr]
         if self._paged():
-            pool, btab, slen = weights[7:]
+            pool, btab, slen = weights[nq + 4:]
             if x.shape[1] > 1:
                 with scope("paged_read"):
                     ctx, pool = self._attend_paged_chunk(
@@ -276,18 +311,45 @@ class MLAttention(Op):
         with scope("core"):
             c, k_rope = latent[..., :rk], latent[..., rk:]
             kvh = jnp.einsum("bsc,chd->bshd", c, wkv_b)
-            scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kvh[..., :dn],
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
-                                   preferred_element_type=jnp.float32))
-            s = x.shape[1]
-            keep = jnp.tril(jnp.ones((s, s), bool))
-            scores = jnp.where(keep, scores * softmax_scale(p),
-                               jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kvh[..., dn:])
+            if self.core_plan() == "flash":
+                from .pallas.flash_attention import flash_mha
+
+                keys = jnp.concatenate(
+                    [kvh[..., :dn],
+                     jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)],
+                    axis=-1)
+                ctx = flash_mha(jnp.concatenate([q_nope, q_rope], axis=-1),
+                                keys, kvh[..., dn:], softmax_scale(p), True)
+            else:
+                ctx = self._attend_dense(q_nope, q_rope, kvh, k_rope)
         with scope("out"):
             return [jnp.einsum("bqhd,hde->bqe", ctx, wo).astype(x.dtype)]
+
+    def core_plan(self) -> str:
+        """"flash" or "dense": the stateless path's causal core for the
+        op's declared sequence length, `MultiHeadAttention`'s rule
+        (`flash_min_seq`, set on every op at compile)."""
+        from ..config import DEFAULT_FLASH_MIN_SEQ
+
+        s = self.inputs[0].shape.logical_shape[1]
+        flash_min = getattr(self, "_flash_min_seq", DEFAULT_FLASH_MIN_SEQ)
+        return "flash" if s >= flash_min else "dense"
+
+    def _attend_dense(self, q_nope, q_rope, kvh, k_rope):
+        """einsum, softmax, einsum with the `[b, h, s, s]` scores in
+        HBM: short rows."""
+        p: MLAParams = self.params
+        dn = p.qk_nope_head_dim
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kvh[..., :dn],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                               preferred_element_type=jnp.float32))
+        s = q_nope.shape[1]
+        keep = jnp.tril(jnp.ones((s, s), bool))
+        scores = jnp.where(keep, scores * softmax_scale(p),
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, kvh[..., dn:])
 
     def _attend_paged(self, q_nope, q_rope, latent, wkv_b, pool, btab,
                       slen):
@@ -365,9 +427,10 @@ class MLAttention(Op):
         b, s, e = self.inputs[0].shape.logical_shape
         h = p.num_heads
         dq = p.qk_nope_head_dim + p.qk_rope_head_dim
+        query = (e * p.q_lora_rank + p.q_lora_rank * h * dq
+                 if p.q_lora_rank else e * h * dq)
         proj = 2.0 * b * s * (
-            e * p.q_lora_rank + p.q_lora_rank * h * dq
-            + e * p.latent_width + h * p.v_head_dim * e)
+            query + e * p.latent_width + h * p.v_head_dim * e)
         if self._paged():
             # absorbed: the query into the latent and the values out of
             # it, scores and the weighted sum over the gathered view

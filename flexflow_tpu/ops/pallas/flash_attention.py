@@ -82,13 +82,13 @@ def _ref_attention(q, k, v, scale: float, causal: bool):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                 scale: float, causal: bool, seq_k: int):
     q = q_ref[0]  # [bq, d] — native dtype feeds the MXU; accumulate f32
-    block_q, d = q.shape
+    block_q = q.shape[0]
     j = pl.program_id(1)
     q_start = j * block_q
 
     m = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)  # [bq, dv]
 
     num_k = seq_k // block_k
     if causal:
@@ -142,7 +142,7 @@ except Exception:  # pragma: no cover
 def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
                       block_q: int, block_k: int, interpret: bool = False):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = v.shape[1:]
     grid = (bh, sq // block_q)
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, scale=scale, causal=causal, seq_k=sk
@@ -153,42 +153,67 @@ def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, sk, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
+        **_resident_vmem(k, v),
     )(q, k, v)
     return out, lse.reshape(bh, sq)
 
 
+#: whole-row operands a grid cell keeps in VMEM (K and V of the forward
+#: and dq kernels, Q and dO of the dkv kernel), double-buffered, up to
+#: which the 16 MiB a kernel may use without asking hold them beside the
+#: score tiles; past it the call asks for `_RESIDENT_VMEM_BYTES` (8,192
+#: keys of 256 with values of 128 are 12 MiB double-buffered)
+_RESIDENT_FREE_BYTES = 4 << 20
+_RESIDENT_VMEM_BYTES = 64 << 20
+
+
+def _resident_vmem(*rows) -> dict:
+    """`pallas_call` keywords for kernels that hold `rows` ([bh, s, d]
+    each) a grid cell: nothing where the default VMEM holds them (every
+    shape before PR 43 lowers as it did), else a larger limit."""
+    held = 2 * sum(r.shape[1] * r.shape[2] * jnp.dtype(r.dtype).itemsize
+                   for r in rows)
+    if held <= _RESIDENT_FREE_BYTES:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_RESIDENT_VMEM_BYTES)}
+
+
 def _supported(q, k, block_q: Optional[int] = None,
-               block_k: Optional[int] = None) -> bool:
+               block_k: Optional[int] = None, v=None) -> bool:
     if not _HAVE_PALLAS:
         return False
     bh, sq, d = q.shape
     sk = k.shape[1]
     block_q = block_q or _pick_block(sq)
     block_k = block_k or _pick_block(sk)
+    dv = d if v is None else v.shape[-1]
     return (
         block_q is not None
         and block_k is not None
         and sq % block_q == 0
         and sk % block_k == 0
         and (d % 128 == 0 or d == 64)  # lane-dim friendly head sizes
+        # values as wide as the keys, or of whole lane tiles of their own
+        and (dv == d or (d % 128 == 0 and dv % 128 == 0))
         and sq >= block_q
         and sk >= block_k
     )
 
 
-def _use_pallas(q, k) -> bool:
+def _use_pallas(q, k, v=None) -> bool:
     """Pallas kernels on the TPU backend (inside jit tracing array
     placement is unknown, so decide by backend).  A shape `_supported`
     rejects there takes the [s, s] jnp twin VISIBLY: the kernel was
@@ -196,25 +221,30 @@ def _use_pallas(q, k) -> bool:
     shape — Python's warning registry shows it once per shape."""
     if jax.default_backend() != "tpu":
         return False
-    if _supported(q, k):
+    if _supported(q, k, v=v):
         return True
     warnings.warn(
         f"flash attention: no Pallas tiling for q{tuple(q.shape)} "
-        f"k{tuple(k.shape)} (need seq divisible by a 128..1024 "
-        f"power-of-two block and head_dim 64 or a multiple of 128); "
-        f"running the dense [s, s] jnp path on TPU instead")
+        f"k{tuple(k.shape)}"
+        + (f" v{tuple(v.shape)}" if v is not None else "")
+        + " (need seq divisible by a 128..1024 power-of-two block and "
+        "a head width of 64 or a multiple of 128 for q and k; values of "
+        "the same width, or both widths multiples of 128: `flash_mha` "
+        "pads q and k to that); running the dense [s, s] jnp path on "
+        "TPU instead")
     return False
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, scale: float, causal: bool):
-    """q,k,v: [bh, s, d] -> [bh, sq, d].  Pallas on TPU, jnp on CPU."""
+    """q, k [bh, s, d], v [bh, sk, dv] -> [bh, sq, dv].  Pallas on TPU,
+    jnp on CPU."""
     out, _ = _flash_fwd(q, k, v, scale, causal)
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal):
-    if _use_pallas(q, k):
+    if _use_pallas(q, k, v):
         return _flash_fwd_pallas(
             q, k, v, scale, causal,
             *_pick_blocks("fwd", q.shape[1], k.shape[1]),
@@ -331,8 +361,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )  # [bk, d]
         return dk_acc, dv_acc
 
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk_acc, dv_acc = jax.lax.fori_loop(jb_start, num_q, body, (zeros, zeros))
+    dk_acc, dv_acc = jax.lax.fori_loop(
+        jb_start, num_q, body, (jnp.zeros((block_k, d), jnp.float32),
+                                jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = dk_acc.astype(dk_ref.dtype)
     dv_ref[0] = dv_acc.astype(dv_ref.dtype)
 
@@ -344,7 +375,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
     the same pair) tiles the dkv kernel — the two kernels' best tiles
     are opposite-handed (see _PREFERRED)."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = v.shape[1:]
     dkv_bq, dkv_bk = dkv_blocks or (block_q, block_k)
     # delta = rowsum(dO * O): one cheap fused jnp pass, shared by both
     # kernels (standard flash-backward preprocessing)
@@ -362,8 +393,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, sk, dv), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         ],
@@ -371,6 +402,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
         name="flash_attention_bwd_dq",
+        **_resident_vmem(k, v),
     )(q, k, v, dout, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -382,21 +414,22 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
         in_specs=[
             pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, dkv_bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, dkv_bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, dkv_bk, dv), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, sq, dv), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, dkv_bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, dkv_bk, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, dkv_bk, dv), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype),
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
+        **_resident_vmem(q, dout),
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
 
@@ -409,7 +442,7 @@ def _flash_vjp_fwd(q, k, v, scale, causal):
 
 def _flash_vjp_bwd(scale, causal, res, dout):
     q, k, v, out, lse = res
-    if _use_pallas(q, k):
+    if _use_pallas(q, k, v):
         sq, sk = q.shape[1], k.shape[1]
         return _flash_bwd_pallas(
             q, k, v, out, lse, dout, scale, causal,
@@ -439,14 +472,14 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def mha_flash(qh, kh, vh, scale: float, causal: bool):
-    """[b, s, h, d] convenience wrapper -> [b, sq, h, d]."""
+    """q, k [b, s, h, d], v [b, sk, h, dv] -> [b, sq, h, dv]."""
     b, sq, h, d = qh.shape
-    sk = kh.shape[1]
+    sk, dv = kh.shape[1], vh.shape[-1]
     q2 = qh.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     k2 = kh.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    v2 = vh.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    v2 = vh.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
     o = flash_attention(q2, k2, v2, scale, causal)
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -729,11 +762,32 @@ def _one_tile_vjp_bwd(d, scale, causal, res, dout):
 one_tile_attention.defvjp(_one_tile_vjp_fwd, _one_tile_vjp_bwd)
 
 
+def lane_width(d: int) -> int:
+    """The width a head of d channels is given on the lanes: 64 stays,
+    anything else goes up to whole 128-lane tiles."""
+    return d if d == 64 else -(-d // 128) * 128
+
+
 def flash_mha(qh, kh, vh, scale: float, causal: bool):
-    """[b, s, h, d] -> [b, sq, h, d]: the attention core without a
-    [b, h, s, s] tensor in HBM, tiled by `pick_tiling`."""
+    """q, k [b, s, h, d], v [b, sk, h, dv] -> [b, sq, h, dv]: the
+    attention core without a [b, h, s, s] tensor in HBM, tiled by
+    `pick_tiling`.
+
+    Keys wider than the values (latent attention without a query
+    bottleneck: 192 against 128) go through the long-row kernels, q and
+    k padded with zero channels to whole lane tiles (192 -> 256), which
+    adds nothing to a score.  The MXU of the v5e contracts 128 channels
+    a pass, so 192 costs the two passes 256 does; the pad's price is
+    the bytes of q, k, dq and dk (a third more of each), not products.
+    The values keep their own width, so `p v` and its three backward
+    products run at 128 (`_fwd_kernel`: the accumulator takes v's
+    width)."""
     b, sq, h, d = qh.shape
     sk = kh.shape[1]
+    if vh.shape[-1] != d:
+        pad = ((0, 0),) * 3 + ((0, lane_width(d) - d),)
+        return mha_flash(jnp.pad(qh, pad), jnp.pad(kh, pad), vh, scale,
+                         causal)
     if (pick_tiling(sk, d) == "one_tile"
             and _one_tile_supported(qh, kh, vh)):
         o = one_tile_attention(

@@ -47,7 +47,8 @@ thousands of small buffers in VMEM around the call.
 from what can be observed (backend, whether the op carries per-slot
 state, head dims, step length), in the manner of
 `flash_attention.pick_tiling`.  There is no backward pass: the
-stateless shape (what a trainer differentiates) keeps the scan.
+stateless shape (what a trainer differentiates) takes the chunked rule
+(`ops/chunked_delta_rule.py`), which jax differentiates.
 """
 from __future__ import annotations
 
@@ -79,12 +80,15 @@ _BLOCK_STATE_BYTES = 2 << 20
 
 def pick_recurrence(backend: str, slot_state: bool, head_k_dim: int,
                     head_v_dim: int, step_tokens: int) -> str:
-    """Which recurrence a `GatedDeltaNet` step takes: "kernel" (this
-    file) or "plain" (the jax.numpy scan).  A pure function of its
-    arguments: the kernel on a TPU, for the per-slot-state shape, with
-    head dims of whole 128-lane tiles and a step short enough to
-    unroll."""
-    if backend != "tpu" or not _HAVE_PALLAS or not slot_state:
+    """Which recurrence a delta-rule op's step takes: "chunked" (the
+    stateless shape, on every backend: `ops/chunked_delta_rule.py`, a
+    chunk of positions at a time, differentiable), "kernel" (this file)
+    or "plain" (the jax.numpy scan a position).  A pure function of its
+    arguments: with per-slot state the kernel on a TPU, with head dims
+    of whole 128-lane tiles and a step short enough to unroll."""
+    if not slot_state:
+        return "chunked"
+    if backend != "tpu" or not _HAVE_PALLAS:
         return "plain"
     if head_k_dim % 128 or head_v_dim % 128:
         return "plain"

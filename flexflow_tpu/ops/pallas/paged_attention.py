@@ -50,7 +50,13 @@ pipeline elides (no re-fetch) — and their compute is skipped with
 ``decode_max_seq``.  What does NOT scale with live tokens is the grid:
 ``slots * table_width / P`` programs of ``2 P + 1`` index maps each,
 0.03 ms a launch at cell 3's 16 x 64 and 0.05 at cell 7's 16 x 20,
-most of either's launch (PERF.md, PR 42).  Partial tail blocks and the
+most of either's launch (PERF.md, PR 42).  Where a row's table is
+narrow and the launch reads for a chunk of queries
+(`pages_per_program`: cell 7's prefill program, 20 columns), ONE
+program takes the whole row: the columns past its live pages are then
+fetched too (a table that narrow has few), and the read of a prefill
+dispatch takes 9.6 ms where three programs a row took 11.0 (PERF.md,
+PR 43).  Partial tail blocks and the
 scratch rows idle slots park on (table all zeros, seq_len 0) fall to
 the gather oracle's own per-position mask: key positions past a row's
 length never enter the softmax.
@@ -76,10 +82,21 @@ import numpy as np
 
 _NEG_INF = -1e30
 
-#: physical pages one grid program folds (paged_attention): the grid is
-#: (rows, table_width / this), so a dispatch's fixed cost follows it,
-#: and a row's live pages are fetched this many at a time
+#: physical pages one grid program folds (paged_attention) where it does
+#: not take a row's whole table: the grid is (rows, table_width / this),
+#: so a dispatch's fixed cost follows it, and a row's live pages are
+#: fetched this many at a time
 PAGES_PER_STEP = 8
+#: the widest table ONE grid program takes whole, a row: up to three
+#: steps' worth of columns the steps saved outweigh the fetches of the
+#: columns past a row's live pages (each one block; measured at cell 7's
+#: 20 columns: `pages_per_program`); cell 3's 64 columns with a page or
+#: two live stay stepped, where a dead step costs nothing
+_ROW_PAGES = 3 * PAGES_PER_STEP
+#: and only where those K and V pages, double-buffered by the pipeline,
+#: leave room in the 16 MiB of VMEM a kernel may use without asking
+#: (cell 7's 20 pages of [16, 16, 128] bf16 are 5 MiB)
+_ROW_VMEM_BYTES = 6 << 20
 
 try:  # lazy-safe: CPU-only envs without pallas never touch the kernel
     from jax.experimental import pallas as pl
@@ -96,6 +113,30 @@ def have_paged_kernel() -> bool:
     engine build, serving/scheduler.py pick_paged_read, never a deep
     ImportError mid-compile)."""
     return _HAVE_PALLAS
+
+
+def pages_per_program(table_width: int, page_bytes: int, chunk: int) -> int:
+    """Pages a grid program folds, from the launch's shapes alone: the
+    row's whole table where the launch reads for a chunk of queries, the
+    table is narrow (`_ROW_PAGES`) and its K and V pages (`page_bytes`
+    a pair) fit `_ROW_VMEM_BYTES` double-buffered: one program a row, a
+    third of the grid steps at cell 7's 20 columns; else
+    `PAGES_PER_STEP`, which keeps the fetches of a wide table to its
+    live pages.
+
+    With ONE query a row the whole-row launch is the faster alone too
+    (0.064 against 0.073 ms at cell 7's mean, scripts/paged_read_probe.py)
+    and the slower in its program: a decode step is bound by its weights'
+    bytes, the slices XLA prefetches hide under the read, and a read
+    1.4 ms shorter a dispatch left 2.6 ms more of them waited for
+    (`unnamed | slice-done`; `decode.device_ms.capacity` 39.8 -> 40.9),
+    where the prefill program, with eight times the work a weight byte,
+    got 1.6 ms shorter (`prefill.device_ms.capacity` 42.8 -> 41.2; my
+    chip runs, PR 43)."""
+    if (chunk > 1 and table_width <= _ROW_PAGES
+            and 2 * table_width * page_bytes <= _ROW_VMEM_BYTES):
+        return table_width
+    return min(PAGES_PER_STEP, table_width)
 
 
 def _live_block_count(pos, chunk: int, page: int, table_width: int):
@@ -238,7 +279,7 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
 
 def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
                     scale: float, *, interpret: Optional[bool] = None,
-                    pages_per_step: int = PAGES_PER_STEP):
+                    pages_per_step: Optional[int] = None):
     """Fused paged attention over the pool.
 
     qh:          [b, s, h, dk]  this step's queries (s = 1 or chunk C)
@@ -268,7 +309,27 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
     b, s, h, dk = qh.shape
     page, dv = k_pool.shape[1], v_pool.shape[-1]
     table_width = block_table.shape[1]
+    if pages_per_step is None:  # (the tests and the probe name one)
+        pages_per_step = pages_per_program(
+            table_width, page * h * (dk + dv) * k_pool.dtype.itemsize, s)
     pages = max(1, min(int(pages_per_step), table_width))
+    return _paged_launch(qh, k_pool, v_pool, block_table, seq_lens,
+                         scale=float(scale), interpret=bool(interpret),
+                         pages=pages)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pages"))
+def _paged_launch(qh, k_pool, v_pool, block_table, seq_lens, *,
+                  scale: float, interpret: bool, pages: int):
+    """`paged_attention`'s launch as a jitted function of its own: the
+    layers of a model call it with the same shapes, so the kernel is
+    traced and lowered to Mosaic ONCE a step program and not once a
+    layer (48 times in cell 7's programs: a whole-row prefill kernel,
+    ten folds unrolled, took 24 s of set-up that way against the
+    stepped one's 11; PERF.md PR 43); XLA inlines the calls."""
+    b, s, h, dk = qh.shape
+    page, dv = k_pool.shape[1], v_pool.shape[-1]
+    table_width = block_table.shape[1]
     block_table = block_table.astype(jnp.int32)
     seq_lens = seq_lens.reshape(b).astype(jnp.int32)
 

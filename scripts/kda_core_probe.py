@@ -1,0 +1,167 @@
+#!/usr/bin/env python3
+"""The delta rule's core alone, forward and backward, at a training
+step's shapes: `ops/chunked_delta_rule.delta_rule_chunked` by chunk and
+sub-chunk length (bf16 operands, one decay a channel, as
+`KimiDeltaAttention` feeds it) against the floors the benchmark holds
+the core to (`families/kimi_linear.kda_core_flops` at the bf16 peak and
+`kda_core_bytes` at the published bandwidth, for ONE layer).  Prints ms
+a call on the chip and the compiler's temporaries
+(`chiprun -- python3 scripts/kda_core_probe.py`); `--compile-only`
+compiles every variant for a described v5e in the sandbox and prints no
+time.  `--scan` adds the scan a position at a shorter length (its
+backward keeps every position's state, so the cell's length does not
+fit).  The numbers PERF.md quotes for the choice of C (section 6,
+PR 43).
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from benchmarks.families import kimi_linear as fam  # noqa: E402
+from flexflow_tpu.ops.chunked_delta_rule import (CHUNK_TOKENS,  # noqa: E402
+                                                 SUB_CHUNK_TOKENS,
+                                                 delta_rule_chunked)
+from flexflow_tpu.ops.gated_delta_net import delta_rule_scan, l2norm  # noqa: E402
+
+
+def core(rule):
+    """The jitted value and gradient of one layer's core under `rule`
+    ((S, q, k, v, g, beta) -> (S, o)) from a zero state."""
+    def loss(q, k, v, g, beta, probe):
+        b, _, h, d = q.shape
+        _, o = rule(jnp.zeros((b, h, d, v.shape[-1]), jnp.float32),
+                    q, k, v, g, beta)
+        return jnp.sum(o.astype(jnp.float32) * probe)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
+
+
+def shapes(b, s, h, d, dtype):
+    f32 = jnp.float32
+    return [((b, s, h, d), dtype), ((b, s, h, d), dtype),
+            ((b, s, h, d), dtype), ((b, s, h, d), f32), ((b, s, h), f32),
+            ((b, s, h, d), f32)]
+
+
+def values(b, s, h, d, dtype):
+    ks = jax.random.split(jax.random.key(0), 6)
+    q = l2norm(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+    k = l2norm(jax.random.normal(ks[1], (b, s, h, d)))
+    v = jax.nn.silu(jax.random.normal(ks[2], (b, s, h, d)))
+    # a channel forgets over tens to thousands of positions
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, h, d), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1))) * 4.0
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    probe = jax.random.normal(ks[5], (b, s, h, d))
+    return [q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta,
+            probe]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default=os.path.join(
+        ROOT, "benchmarks", "configs", "kimi-linear-ep32-train.json"),
+        help="the configuration whose KDA heads are run")
+    ap.add_argument("--batch", type=int, default=1)
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--chunks", default="32:16,64:16,64:32,128:16,128:32",
+                    help="chunk:sub-chunk pairs to run")
+    ap.add_argument("--scan", type=int, default=0,
+                    help="also the scan a position, at this many positions")
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--compile-only", action="store_true")
+    args = ap.parse_args()
+    with open(args.config) as f:
+        cfg = json.load(f)
+    lin = cfg["linear_attn_config"]
+    b, s, h, d = args.batch, args.seq, lin["num_heads"], lin["head_dim"]
+    bf = jnp.bfloat16
+    pairs = [tuple(int(n) for n in p.split(":"))
+             for p in args.chunks.split(",")]
+    variants = {
+        f"chunked C={c} sub={sub}"
+        + (" (the op's)" if (c, sub) == (CHUNK_TOKENS, SUB_CHUNK_TOKENS)
+           else ""):
+        (core(lambda *a, c=c, sub=sub: delta_rule_chunked(
+            *a, c, sub, operand_dtype=bf)), s)
+        for c, sub in pairs}
+    if args.scan:
+        variants[f"scan a position, {args.scan} positions"] = (
+            core(delta_rule_scan), args.scan)
+
+    # the floors of ONE layer, by the benchmark's own count
+    layers = len(lin["kda_layers"])
+    with open(os.path.join(ROOT, "benchmarks", "peaks.json")) as f:
+        peak = next(iter(json.load(f)["devices"].values()))
+
+    def floors(seq):
+        return (fam.kda_core_flops(cfg, b, seq) / layers
+                / peak["bf16_flops_per_s"],
+                fam.kda_core_bytes(cfg, b, seq) / layers
+                / peak["hbm_bytes_per_s"])
+
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sh = SingleDeviceSharding(topo.devices[0])
+        for name, (fn, seq) in variants.items():
+            structs = [jax.ShapeDtypeStruct(sp, dt, sharding=sh)
+                       for sp, dt in shapes(b, seq, h, d, bf)]
+            t0 = time.monotonic()
+            m = fn.trace(*structs).lower(
+                lowering_platforms=("tpu",)).compile().memory_analysis()
+            print(f"{name}: compiled in {time.monotonic() - t0:.0f} s; "
+                  f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB",
+                  flush=True)
+        return 0
+
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                      "batch": b, "seq": s, "heads": h, "head_dim": d}),
+          flush=True)
+    for name, (fn, seq) in variants.items():
+        vals = values(b, seq, h, d, bf)
+        try:
+            t0 = time.monotonic()
+            compiled = fn.lower(*vals).compile()
+            first = time.monotonic() - t0
+            jax.block_until_ready(compiled(*vals))
+            t0 = time.monotonic()
+            for _ in range(args.iters):
+                got = compiled(*vals)
+            jax.block_until_ready(got)
+            ms = 1e3 * (time.monotonic() - t0) / args.iters
+        except Exception as e:  # a variant that does not fit is a finding
+            print(f"{name}: FAILED {type(e).__name__}: "
+                  f"{str(e).splitlines()[0][:200]}", flush=True)
+            continue
+        by_flops, by_bytes = floors(seq)
+        least = max(by_flops, by_bytes)
+        finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
+                     for x in jax.tree.leaves(got))
+        print(f"{name}: {ms:.2f} ms a call forward + backward "
+              f"({1e3 * ms / seq:.2f} us a position; compiled in "
+              f"{first:.0f} s; temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes / 1e9:.2f} "
+              f"GB; finite {finite}); least {1e3 * by_flops:.2f} ms by "
+              f"operations, {1e3 * by_bytes:.2f} ms by bytes: "
+              f"{100 * least / (ms / 1e3):.1f} % of the floor's rate",
+              flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -4,7 +4,8 @@
 Run by hand on the chip; no switch in the program reads anything here.
 
     chiprun --chips 1 -- python3 scripts/paged_read_probe.py \
-        [--cell ouro-2.6b-serve.loop-decode,gpt2-medium-serve.above-knee]
+        [--cell ouro-2.6b-serve.loop-decode,gpt2-medium-serve.above-knee] \
+        [--pages-per-step 8,10,20]
 
 For each named cell: the kernel at the cell's rows, table width, page,
 heads and head dim over a bf16 pool of the cell's size, with the pages
@@ -16,7 +17,11 @@ one-pass prefill of cell 7: the feeding rows at their mean, the rest
 parked).  Prints ms a launch, and from the least and the full case the
 launch's fixed part and its cost a page, and GB/s over the folded
 pages' keys and values; the largest difference from the gather read's
-math in float32 beside them.
+math in float32 beside them.  `--pages-per-step` (PR 43) runs each
+case once per listed number of pages a grid program folds, in place of
+the kernel's own choice (`paged_attention.pages_per_program`): what a
+launch ALONE gains from fewer grid steps, which its program may not
+keep (a decode step lost what the probe promised, PERF.md PR 43).
 
 Timing: `--iters` launches chained in one jitted `lax.fori_loop` with
 real dataflow (the context feeds the next launch's queries through
@@ -24,6 +29,7 @@ real dataflow (the context feeds the next launch's queries through
 lines under chiprun_out/paged_read_probe/.
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -71,13 +77,16 @@ def main():
     ap.add_argument("--cell", default=",".join(CELLS))
     ap.add_argument("--iters", type=int, default=200)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--pages-per-step", default="",
+                    help="comma list: pages a grid program folds, in "
+                    "place of the kernel's own choice (PR 43)")
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from flexflow_tpu.ops.pallas.paged_attention import paged_attention
+    from flexflow_tpu.ops.pallas import paged_attention as kernel
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -118,7 +127,12 @@ def main():
 
     os.makedirs(OUT, exist_ok=True)
     lines = []
-    for name in args.cell.split(","):
+    sweep = [int(p) for p in args.pages_per_step.split(",") if p] or [None]
+    for name, pages_per_step in ((n, p) for n in args.cell.split(",")
+                                 for p in sweep):
+        paged_attention = kernel.paged_attention if pages_per_step is None \
+            else functools.partial(kernel.paged_attention,
+                                   pages_per_step=pages_per_step)
         c = CELLS[name]
         r = np.random.default_rng(args.seed)
         shape = (c["blocks"], c["page"], c["heads"], c["d"])
@@ -136,6 +150,7 @@ def main():
                                            c["d"])), jnp.bfloat16)
             mean = c["decode"] if chunk == 1 else c["prefill"]
             line = {"cell": name, "chunk": chunk,
+                    "pages_per_step": pages_per_step,
                     "device": {"platform": dev.platform,
                                "kind": dev.device_kind}}
             for case, (rows, pages) in (

@@ -145,9 +145,12 @@ PICKS = [
     (("tpu", True, 128, 128, gdr.MAX_STEP_TOKENS), "kernel"),
     (("tpu", True, 128, 128, gdr.MAX_STEP_TOKENS + 1), "plain"),
     (("tpu", True, 128, 128, 512), "plain"),
-    # stateless keeps the scan: what a trainer differentiates
-    (("tpu", False, 128, 128, 1), "plain"),
-    (("tpu", False, 128, 128, 8), "plain"),
+    # stateless takes the chunked rule, on every backend and at every
+    # width: what a trainer differentiates
+    (("tpu", False, 128, 128, 1), "chunked"),
+    (("tpu", False, 128, 128, 8192), "chunked"),
+    (("cpu", False, 128, 128, 8), "chunked"),
+    (("cpu", False, 8, 8, 16), "chunked"),
     # a CPU (and anything that is not a TPU) keeps the plain path
     (("cpu", True, 128, 128, 1), "plain"),
     (("cpu", True, 128, 128, 8), "plain"),

@@ -310,6 +310,21 @@ def test_pages_per_step_is_not_semantic(pages):
                                rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("tw,page_bytes,chunk,want", [
+    (20, 2 * 16 * 16 * 128 * 2, 8, 20),  # ouro-2.6b-serve's prefill program
+    (20, 2 * 16 * 16 * 128 * 2, 1, 8),   # its decode step: stepped
+    (64, 2 * 16 * 16 * 64 * 2, 8, 8),    # a wide table (gpt2-medium-serve's)
+    (24, 2 * 16 * 16 * 128 * 2, 4, 24),  # the widest taken whole
+    (25, 2 * 16 * 16 * 128 * 2, 4, 8),
+    (20, 2 * 16 * 16 * 128 * 4, 8, 8),   # float32 pages: over the VMEM bound
+    (4, 2 * 4 * 2 * 8 * 4, 4, 4), (4, 2 * 4 * 2 * 8 * 4, 1, 4)])
+def test_pages_a_program_follow_the_launch(tw, page_bytes, chunk, want):
+    """The grid's choice is a function of the launch's shapes: a narrow
+    table whose pages fit in VMEM is one program a row under a chunk of
+    queries."""
+    assert pk.pages_per_program(tw, page_bytes, chunk) == want
+
+
 def test_blocks_read_scales_with_live_tokens():
     """The host telemetry twin of the kernel's traffic discipline:
     per-step blocks follow live tokens, not the table width — the

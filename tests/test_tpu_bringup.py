@@ -63,7 +63,32 @@ def _flash_cases():
             dkv_blocks=fa._pick_blocks("dkv", seq, seq))
 
     return [("flash_fwd", fwd, (x, x, x), 1),
-            ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)] + _one_tile_cases()
+            ("flash_dq_dkv", bwd, (x, x, x, x, lse, x), 2)] \
+        + _latent_flash_cases() + _one_tile_cases()
+
+
+def _latent_flash_cases():
+    """The long-row kernels as a latent-attention training step hands
+    them over (PR 43): keys of 192 padded to 256 lanes against values of
+    128, 8,192 positions, causal: K and V stay resident past the
+    default VMEM (`_resident_vmem`)."""
+    bh, seq = 32, 8192
+    qk = jax.ShapeDtypeStruct((bh, seq, fa.lane_width(192)), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+
+    def fwd(q, k, v):
+        return fa._flash_fwd_pallas(
+            q, k, v, 192 ** -0.5, True, *fa._pick_blocks("fwd", seq, seq))
+
+    def bwd(q, k, v, o, lse, do):
+        return fa._flash_bwd_pallas(
+            q, k, v, o, lse, do, 192 ** -0.5, True,
+            *fa._pick_blocks("dq", seq, seq),
+            dkv_blocks=fa._pick_blocks("dkv", seq, seq))
+
+    return [("latent_flash_fwd", fwd, (qk, qk, v), 1),
+            ("latent_flash_dq_dkv", bwd, (qk, qk, v, v, lse, v), 2)]
 
 
 def _one_tile_cases():
