@@ -1333,6 +1333,12 @@ class FFModel:
                   if op.op_type == OperatorType.KIMI_DELTA_ATTENTION]
         if chunks:  # positions a chunk of the delta rule holds; 0: the scan
             counts.update({"kda_chunk_tokens": min(chunks)})
+        rules = [op.recurrence_plan(op.inputs[0].shape.logical_shape[1])
+                 for op in self.operators.topo_order()
+                 if op.op_type in (OperatorType.KIMI_DELTA_ATTENTION,
+                                   OperatorType.GATED_DELTA_NET)]
+        if rules:  # delta-rule ops that run as the chunked Pallas kernels
+            counts.update({"kda_kernel_ops": rules.count("chunked_kernel")})
         experts = [op.product_plan() for op in self.executor.routed_expert_ops]
         if experts:  # which product each routed-expert layer takes
             counts["expert_grouped_ops"] = experts.count("grouped")

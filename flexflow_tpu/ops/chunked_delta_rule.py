@@ -1,6 +1,11 @@
 """The gated delta rule a CHUNK of positions at a time: what the
 stateless (training) shape of `GatedDeltaNet` and `KimiDeltaAttention`
-runs, forward and backward.
+runs, forward and backward, wherever `pick_recurrence` answers
+"chunked": every backend but the TPU, and on a TPU the rows under one
+full chunk and the head dims that are no whole 128-lane tiles.  (Where
+it answers "chunked_kernel", `ops/pallas/chunked_delta_rule.py` runs
+the same algebra as Pallas kernels, with this file's functions as
+their oracle.)
 
     per head, S a [dk, dv] matrix, for each position in order:
         S' = Diag(exp(g_t)) S;  d_t = beta_t (v_t - S'^T k_t)

@@ -43,11 +43,15 @@ never by a flag:
 * "plain": with per-slot state everywhere else, a `lax.scan` of
   `delta_rule_step` over the step's positions in plain jax.numpy, which
   reads and writes every slot's `S` a position, live or not;
-* "chunked": the stateless shape, on every backend: the recurrence a
-  chunk of positions at a time (`ops/chunked_delta_rule.py`), this
-  op's one decay a head broadcast over the key's channels.  jax
-  differentiates it and its backward pass keeps the chunk-boundary
+* "chunked" / "chunked_kernel": the stateless shape: the recurrence a
+  chunk of positions at a time, this op's one decay a head broadcast
+  over the key's channels; the backward pass keeps the chunk-boundary
   states only, where the scan a position keeps every position's.
+  "chunked" is plain jax.numpy that jax differentiates
+  (`ops/chunked_delta_rule.py`: every backend but the TPU, and rows
+  under a chunk or head dims that are no whole 128-lane tiles there);
+  "chunked_kernel" is the same rule as Pallas kernels, forward and
+  backward (`ops/pallas/chunked_delta_rule.py`).
 """
 from __future__ import annotations
 
@@ -61,8 +65,9 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
-from .chunked_delta_rule import delta_rule_chunked, pick_chunk
+from .chunked_delta_rule import pick_chunk
 from .op import Op, ShapeError, ShardConfig, WeightSpec
+from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
 from .short_conv import causal_depthwise_conv
 
@@ -142,8 +147,9 @@ class GatedDeltaNet(Op):
         return ("conv_state", "rec_state") if self._slot_state else ()
 
     def recurrence_plan(self, step_tokens: int) -> str:
-        """"kernel", "plain" or "chunked": what a step of `step_tokens`
-        tokens a row takes on this backend (`pick_recurrence`)."""
+        """"kernel", "plain", "chunked" or "chunked_kernel": what a step
+        of `step_tokens` tokens a row takes on this backend
+        (`pick_recurrence`)."""
         p: GatedDeltaNetParams = self.params
         return pick_recurrence(jax.default_backend(), self._slot_state,
                                p.head_k_dim, p.head_v_dim, step_tokens)
@@ -255,10 +261,10 @@ class GatedDeltaNet(Op):
             plan = self.recurrence_plan(s)
             if plan == "kernel":
                 S, o = gated_delta_rule(S, q, k, v, g, beta, count)
-            elif plan == "chunked":
-                S, o = delta_rule_chunked(S, q, k, v, g, beta,
-                                          *pick_chunk(s),
-                                          operand_dtype=x.dtype)
+            elif plan in CHUNKED_RULES:
+                S, o = CHUNKED_RULES[plan](S, q, k, v, g, beta,
+                                           *pick_chunk(s),
+                                           operand_dtype=x.dtype)
             else:
                 S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
         with scope("out"):

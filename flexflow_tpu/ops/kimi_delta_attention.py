@@ -21,8 +21,12 @@ the conv (`short_conv.causal_depthwise_conv`) and `l2norm` with it.
 
 Stateless only: every row starts from zero and runs its whole `[b, s]`
 input, which is what a trainer runs.  `recurrence_plan` asks
-`pick_recurrence` as `GatedDeltaNet` does and gets "chunked" on every
-backend; the chunk is `pick_chunk`'s.  The serving twin (a conv tail a
+`pick_recurrence` as `GatedDeltaNet` does and gets a chunk of positions
+at a time: "chunked_kernel" (`ops/pallas/chunked_delta_rule.py`, the
+rule as Pallas kernels, forward and backward) on a TPU with 128-lane
+heads and a row of a full chunk or more, "chunked" (plain jax.numpy)
+everywhere else; the chunk is `pick_chunk`'s.  The serving twin (a conv
+tail a
 projection and `S` a slot, a per-channel decay in
 `ops/pallas/gated_delta_rule.py`'s kernel) is not built (ROADMAP).
 
@@ -44,9 +48,10 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
-from .chunked_delta_rule import delta_rule_chunked, pick_chunk
+from .chunked_delta_rule import pick_chunk
 from .gated_delta_net import delta_rule_scan, l2norm
 from .op import Op, ShapeError, ShardConfig, WeightSpec, remat_keep
+from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import pick_recurrence
 from .short_conv import causal_depthwise_conv
 
@@ -82,7 +87,7 @@ class KimiDeltaAttention(Op):
     def chunk_tokens(self, step_tokens: int) -> int:
         """Positions a chunk of the core holds; 0 for the scan a
         position."""
-        if self.recurrence_plan(step_tokens) != "chunked":
+        if self.recurrence_plan(step_tokens) not in CHUNKED_RULES:
             return 0
         return pick_chunk(step_tokens)[0]
 
@@ -162,10 +167,10 @@ class KimiDeltaAttention(Op):
                                              preferred_element_type=f32))
         with scope("core"):
             S = jnp.zeros((b, h, d, d), f32)
-            if self.recurrence_plan(s) == "chunked":
-                _, o = delta_rule_chunked(S, q, k, v, g, beta,
-                                          *pick_chunk(s),
-                                          operand_dtype=x.dtype)
+            rule = CHUNKED_RULES.get(self.recurrence_plan(s))
+            if rule is not None:
+                _, o = rule(S, q, k, v, g, beta, *pick_chunk(s),
+                            operand_dtype=x.dtype)
             else:
                 _, o = delta_rule_scan(S, q, k, v, g, beta)
             o = remat_keep(o.astype(x.dtype))  # [b, s, h, d]

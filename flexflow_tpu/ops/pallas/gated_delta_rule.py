@@ -46,9 +46,12 @@ thousands of small buffers in VMEM around the call.
 `pick_recurrence` decides between this kernel and the plain recurrence
 from what can be observed (backend, whether the op carries per-slot
 state, head dims, step length), in the manner of
-`flash_attention.pick_tiling`.  There is no backward pass: the
-stateless shape (what a trainer differentiates) takes the chunked rule
-(`ops/chunked_delta_rule.py`), which jax differentiates.
+`flash_attention.pick_tiling`.  There is no backward pass here: the
+stateless shape (what a trainer differentiates) takes the chunked rule,
+`ops/chunked_delta_rule.py` with its scan over chunks differentiated by
+jax, or, where `pick_recurrence` finds a TPU, 128-lane head dims and a
+row of at least one full chunk, `ops/pallas/chunked_delta_rule.py`,
+the same rule as kernels of its own, forward and backward.
 """
 from __future__ import annotations
 
@@ -57,6 +60,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..chunked_delta_rule import CHUNK_TOKENS
 
 try:  # lazy-safe: CPU-only envs without pallas never touch the kernel
     from jax.experimental import pallas as pl
@@ -80,21 +85,25 @@ _BLOCK_STATE_BYTES = 2 << 20
 
 def pick_recurrence(backend: str, slot_state: bool, head_k_dim: int,
                     head_v_dim: int, step_tokens: int) -> str:
-    """Which recurrence a delta-rule op's step takes: "chunked" (the
-    stateless shape, on every backend: `ops/chunked_delta_rule.py`, a
-    chunk of positions at a time, differentiable), "kernel" (this file)
-    or "plain" (the jax.numpy scan a position).  A pure function of its
-    arguments: with per-slot state the kernel on a TPU, with head dims
-    of whole 128-lane tiles and a step short enough to unroll."""
+    """Which recurrence a delta-rule op's step takes.  A pure function
+    of its arguments.  The stateless shape (what a trainer
+    differentiates) runs a chunk of positions at a time: "chunked"
+    (`ops/chunked_delta_rule.py`, plain jax.numpy, every backend) or
+    "chunked_kernel" (`ops/pallas/chunked_delta_rule.py`: the same rule
+    as Pallas kernels, forward and backward) on a TPU, with
+    head dims of whole 128-lane tiles and a row of at least one full
+    chunk.  With per-slot state: "kernel" (this file) on a TPU, with
+    such head dims and a step short enough to unroll, else "plain" (the
+    jax.numpy scan a position)."""
+    tiles = (backend == "tpu" and _HAVE_PALLAS
+             and head_k_dim % 128 == 0 and head_v_dim % 128 == 0)
     if not slot_state:
+        if tiles and step_tokens >= CHUNK_TOKENS:
+            return "chunked_kernel"
         return "chunked"
-    if backend != "tpu" or not _HAVE_PALLAS:
-        return "plain"
-    if head_k_dim % 128 or head_v_dim % 128:
-        return "plain"
-    if not 1 <= step_tokens <= MAX_STEP_TOKENS:
-        return "plain"
-    return "kernel"
+    if tiles and 1 <= step_tokens <= MAX_STEP_TOKENS:
+        return "kernel"
+    return "plain"
 
 
 def heads_per_block(num_heads: int, step_tokens: int,

@@ -2,7 +2,10 @@
 """The delta rule's core alone, forward and backward, at a training
 step's shapes: `ops/chunked_delta_rule.delta_rule_chunked` by chunk and
 sub-chunk length (bf16 operands, one decay a channel, as
-`KimiDeltaAttention` feeds it) against the floors the benchmark holds
+`KimiDeltaAttention` feeds it) and
+`ops/pallas/chunked_delta_rule.delta_rule_chunked_kernel` (the same
+rule as Pallas kernels) by the heads a grid program of its walk over
+the chunks holds (`--kernel-heads`), against the floors the benchmark holds
 the core to (`families/kimi_linear.kda_core_flops` at the bf16 peak and
 `kda_core_bytes` at the published bandwidth, for ONE layer).  Prints ms
 a call on the chip and the compiler's temporaries
@@ -11,11 +14,12 @@ compiles every variant for a described v5e in the sandbox and prints no
 time.  `--scan` adds the scan a position at a shorter length (its
 backward keeps every position's state, so the cell's length does not
 fit).  The numbers PERF.md quotes for the choice of C (section 6,
-PR 43).
+PR 43) and of the heads a program (PR 44).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -32,6 +36,8 @@ from flexflow_tpu.ops.chunked_delta_rule import (CHUNK_TOKENS,  # noqa: E402
                                                  SUB_CHUNK_TOKENS,
                                                  delta_rule_chunked)
 from flexflow_tpu.ops.gated_delta_net import delta_rule_scan, l2norm  # noqa: E402
+from flexflow_tpu.ops.pallas.chunked_delta_rule import (  # noqa: E402
+    delta_rule_chunked_kernel)
 
 
 def core(rule):
@@ -67,6 +73,42 @@ def values(b, s, h, d, dtype):
             probe]
 
 
+def by_kind(compiled, vals, top: int) -> str:
+    """One traced call's device operations: summed by name (copies of
+    one fusion merged), the `top` largest as "name ms (events)"; then
+    the `top` largest single operations, each with the result it has
+    in the compiled program's text."""
+    import collections
+    import glob
+    import re
+    import tempfile
+
+    from benchmarks import reduce_trace as rt
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            jax.block_until_ready(compiled(*vals))
+        planes = rt.read_planes(glob.glob(
+            os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))[0])
+    if not planes:
+        return "the trace holds no TPU plane"
+    plane = planes[0]
+    ms, events, each = (collections.Counter(), collections.Counter(),
+                        collections.Counter())
+    for name, start, end in plane["ops"]:
+        if rt.stem(name) not in rt.ENVELOPES:
+            ms[rt.stem(name)] += 1e3 * (end - start)
+            events[rt.stem(name)] += 1
+            each[rt.short_name(name)] += 1e3 * (end - start)
+    result = dict(re.findall(r"%?([\w.-]+) = (\(?[a-z0-9]+\[[^ ]*) ",
+                             compiled.as_text()))
+    return ("; ".join(f"{n} {v:.2f} ms ({events[n]})"
+                      for n, v in ms.most_common(top))
+            + "\n  single operations: " + "; ".join(
+                f"{n} {result.get(n, '?')} {v:.2f} ms"
+                for n, v in each.most_common(top)))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default=os.path.join(
@@ -76,9 +118,16 @@ def main() -> int:
     ap.add_argument("--seq", type=int, default=8192)
     ap.add_argument("--chunks", default="32:16,64:16,64:32,128:16,128:32",
                     help="chunk:sub-chunk pairs to run")
+    ap.add_argument("--kernel-heads", default="8",
+                    help="the Pallas kernels, a variant each: heads a grid "
+                         "program of the walk over chunks holds (the op's "
+                         "chunk and sub-chunk); empty: no kernel variant")
     ap.add_argument("--scan", type=int, default=0,
                     help="also the scan a position, at this many positions")
     ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--by-kind", type=int, default=0,
+                    help="also trace one call of each variant and print "
+                         "its N largest device operations by name")
     ap.add_argument("--compile-only", action="store_true")
     args = ap.parse_args()
     with open(args.config) as f:
@@ -87,7 +136,7 @@ def main() -> int:
     b, s, h, d = args.batch, args.seq, lin["num_heads"], lin["head_dim"]
     bf = jnp.bfloat16
     pairs = [tuple(int(n) for n in p.split(":"))
-             for p in args.chunks.split(",")]
+             for p in filter(None, args.chunks.split(","))]
     variants = {
         f"chunked C={c} sub={sub}"
         + (" (the op's)" if (c, sub) == (CHUNK_TOKENS, SUB_CHUNK_TOKENS)
@@ -95,6 +144,15 @@ def main() -> int:
         (core(lambda *a, c=c, sub=sub: delta_rule_chunked(
             *a, c, sub, operand_dtype=bf)), s)
         for c, sub in pairs}
+    for hb in filter(None, args.kernel_heads.split(",")):
+        # off the chip (--compile-only) the kernels are lowered for
+        # Mosaic all the same: `interpret` is the default's only there
+        variants[f"kernels C={CHUNK_TOKENS} sub={SUB_CHUNK_TOKENS} "
+                 f"heads a program of the walk={hb}"] = (
+            core(functools.partial(
+                delta_rule_chunked_kernel, chunk=CHUNK_TOKENS,
+                sub=SUB_CHUNK_TOKENS, operand_dtype=bf,
+                heads_block=int(hb), interpret=False)), s)
     if args.scan:
         variants[f"scan a position, {args.scan} positions"] = (
             core(delta_rule_scan), args.scan)
@@ -148,6 +206,8 @@ def main() -> int:
             print(f"{name}: FAILED {type(e).__name__}: "
                   f"{str(e).splitlines()[0][:200]}", flush=True)
             continue
+        if args.by_kind:
+            print("  " + by_kind(compiled, vals, args.by_kind), flush=True)
         by_flops, by_bytes = floors(seq)
         least = max(by_flops, by_bytes)
         finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))

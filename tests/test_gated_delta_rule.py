@@ -1,6 +1,10 @@
-"""The gated delta rule's Pallas kernel (ops/pallas/gated_delta_rule.py)
-under the interpreter against the plain `delta_rule_scan`, the live-row
-list it walks, and the function that picks between kernel and scan.
+"""The gated delta rule's Pallas kernels under the interpreter against
+the plain `delta_rule_scan`: the serving kernel
+(ops/pallas/gated_delta_rule.py) with the live-row list it walks, the
+training kernels (ops/pallas/chunked_delta_rule.py: a chunk of positions
+at a time, forward and gradient, and their tile functions against
+jax's own gradient), and the function that picks between them, the
+jax.numpy rule and the scan.
 
 Tolerance: the same float32 operations in the same order, the two
 reductions over dk summed in another order: 1e-5 of the compared
@@ -14,7 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu import FFConfig, FFModel
+from flexflow_tpu.ops import chunked_delta_rule as cdr
+from flexflow_tpu.ops import gated_delta_net as gdn_op
+from flexflow_tpu.ops import kimi_delta_attention as kda_op
 from flexflow_tpu.ops.gated_delta_net import delta_rule_scan as scanned
+from flexflow_tpu.ops.gated_delta_net import l2norm
+from flexflow_tpu.ops.kimi_delta_attention import KimiDeltaAttentionParams
+from flexflow_tpu.ops.pallas import chunked_delta_rule as cdk
 from flexflow_tpu.ops.pallas import gated_delta_rule as gdr
 
 OP_TOL = 1e-5
@@ -137,6 +148,221 @@ def test_heads_per_block_keeps_the_state_block_inside_default_vmem():
     assert gdr.heads_per_block(32, 8, 256 * 256 * 4) == 8
 
 
+# -- the training kernels: a chunk of positions at a time ----------------------
+def recurrence_inputs(s, per_channel, strong, b=1, h=2, dk=128, dv=128):
+    """As tests/test_kimi_linear.py's, at heads of one 128-lane tile."""
+    keys = jax.random.split(jax.random.key(0), 8)
+    g_shape = (b, s, h, dk) if per_channel else (b, s, h)
+    return dict(
+        S=jax.random.normal(keys[5], (b, h, dk, dv)),
+        q=l2norm(jax.random.normal(keys[0], (b, s, h, dk))) * dk ** -0.5,
+        k=l2norm(jax.random.normal(keys[1], (b, s, h, dk))),
+        v=jax.random.normal(keys[2], (b, s, h, dv)),
+        g=-jax.nn.softplus(jax.random.normal(keys[3], g_shape))
+        * (40.0 if strong else 1.0),
+        beta=jax.nn.sigmoid(jax.random.normal(keys[4], (b, s, h))),
+    ), (jax.random.normal(keys[6], (b, s, h, dv)),
+        jax.random.normal(keys[7], (b, h, dk, dv)))
+
+
+def value_and_gradient(rule, xs, probe_o, probe_s):
+    """((S, o), the gradient of a probe of both) of `rule` over the
+    dict `xs`."""
+    def scalar(args):
+        state, o = rule(*(args[n] for n in "S q k v g beta".split()))
+        return jnp.sum(o * probe_o) + jnp.sum(state * probe_s), (state, o)
+
+    (_, out), grads = jax.value_and_grad(scalar, has_aux=True)(xs)
+    return out, grads
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+@pytest.mark.parametrize("decay", ["per_channel", "per_head"])
+@pytest.mark.parametrize("seq, heads_block", [
+    (128, None),  # two whole chunks, both heads in one grid program
+    (150, 1),     # a ragged last chunk, a head a program
+])
+def test_chunk_kernels_equal_the_scan_forward_and_gradient(
+        seq, heads_block, decay, strong):
+    """The Pallas walk over the chunks (interpreted) from a NONZERO
+    starting state, at the cell's chunk and heads of one 128-lane tile.
+    Strong: a channel decays past e^-88 inside a chunk; the value and
+    the gradient stay finite and equal."""
+    xs, probes = recurrence_inputs(seq, decay == "per_channel", strong)
+    if strong:
+        total = np.cumsum(np.asarray(xs["g"], np.float64), axis=1)
+        assert total[:, :64].min() < -88.0
+
+    def kernels(*a):
+        return cdk.delta_rule_chunked_kernel(*a, 64, 16,
+                                             heads_block=heads_block)
+
+    (s_want, o_want), g_want = value_and_gradient(scanned, xs,
+                                                  *probes)
+    (s_got, o_got), g_got = value_and_gradient(kernels, xs, *probes)
+    close(o_got, o_want)
+    close(s_got, s_want)
+    for name in ("S", "q", "k", "v", "g", "beta"):
+        assert np.all(np.isfinite(np.asarray(g_got[name])))
+        close(g_got[name], g_want[name], 1e-4 if name == "g" else 2e-5)
+
+
+def test_chunk_kernels_with_rounded_operands_stay_near_the_scan():
+    """bf16 operands (what the chip runs) through the kernels: the
+    output within the jax.numpy rule's distance of the scan, and the
+    gradient within a bf16 rounding of the jax.numpy rule's own."""
+    xs, probes = recurrence_inputs(150, True, False)
+    bf = jnp.bfloat16
+    (_, o_want), _ = value_and_gradient(scanned, xs, *probes)
+    (s_jnp, o_jnp), g_jnp = value_and_gradient(
+        lambda *a: cdr.delta_rule_chunked(*a, 64, 16, operand_dtype=bf),
+        xs, *probes)
+    (s_got, o_got), g_got = value_and_gradient(
+        lambda *a: cdk.delta_rule_chunked_kernel(*a, 64, 16,
+                                                 operand_dtype=bf),
+        xs, *probes)
+    close(o_got, o_want, 3e-2)
+    close(o_got, o_jnp, 1e-2)
+    close(s_got, s_jnp, 1e-2)
+    for name in ("S", "q", "k", "v", "g", "beta"):
+        close(g_got[name], g_jnp[name], 3e-2)
+
+
+def test_chunk_kernels_refuse_the_interpreter_on_a_tpu(monkeypatch):
+    xs, _ = recurrence_inputs(64, True, False, h=1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="compiled"):
+        cdk.delta_rule_chunked_kernel(
+            *(xs[n] for n in "S q k v g beta".split()), 64, 16,
+            interpret=True)
+
+
+@pytest.mark.parametrize("heads, want, tile", [
+    (32, 8, 8), (16, 8, 8), (12, 6, 12), (7, 7, 7), (2, 2, 2), (1, 1, 1),
+    (22, 2, 22)])
+def test_heads_per_program_divides_the_rows_heads(heads, want, tile):
+    """The walk's programs hold up to 8 heads that divide the row's; the
+    operands' programs one 8-sublane tile of heads, or all of them."""
+    assert cdk.heads_per_program(heads) == want
+    assert cdk._operand_heads(heads) == tile
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+def test_pairs_tile_equals_the_chunk_matrices_and_its_gradient_is_jaxs(
+        strong):
+    """A head's chunk on tiles: `A`, `B` and the scaled operands against
+    the jax.numpy rule's `_chunk_matrices`, and the hand-written
+    gradient against jax's own of the same function."""
+    C, dk, sub = 64, 128, 16
+    keys = jax.random.split(jax.random.key(1), 9)
+    q = l2norm(jax.random.normal(keys[0], (C, dk))) * dk ** -0.5
+    k = l2norm(jax.random.normal(keys[1], (C, dk)))
+    g = -jax.nn.softplus(jax.random.normal(keys[2], (C, dk))) \
+        * (40.0 if strong else 1.0)
+    G = jnp.cumsum(g, axis=0)
+    outs = cdk._pairs_tile(q, k, G, sub)
+    A, Bm = cdr._chunk_matrices(q, k, G, sub)
+    for got, want in zip(outs, (A, Bm, q * jnp.exp(G), k * jnp.exp(G),
+                                k * jnp.exp(G[-1:] - G), jnp.exp(G[-1:]))):
+        close(got, want, 1e-5)
+    cts = [jax.random.normal(key, o.shape) for key, o in zip(keys[3:], outs)]
+    _, vjp = jax.vjp(lambda *a: cdk._pairs_tile(*a, sub), q, k, G)
+    for got, want in zip(cdk._pairs_tile_bwd(q, k, G, sub, *cts),
+                         vjp(tuple(cts))):
+        assert np.all(np.isfinite(np.asarray(got)))
+        close(got, want, 1e-5)
+
+
+def test_operands_tile_gradient_is_jaxs():
+    """The tile that also scales by beta and builds the solve's system
+    and right side: its hand-written gradient (q, k, v, g, beta) against
+    jax's own; `A` is a residual and takes no cotangent."""
+    C, dk, sub = 64, 128, 16
+    keys = jax.random.split(jax.random.key(2), 12)
+    q = l2norm(jax.random.normal(keys[0], (C, dk))) * dk ** -0.5
+    k = l2norm(jax.random.normal(keys[1], (C, dk)))
+    v = jax.random.normal(keys[2], (C, dk))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (C, dk)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (C, 1)))
+    (A, *outs), vjp = jax.vjp(lambda *a: cdk._operands_tile(*a, sub),
+                              q, k, v, g, beta)
+    close(outs[0] - jnp.eye(C), beta * A)
+    cts = [jax.random.normal(key, o.shape) for key, o in zip(keys[5:], outs)]
+    for got, want in zip(
+            cdk._operands_tile_bwd(q, k, v, g, beta, sub, A, *cts),
+            vjp((jnp.zeros_like(A), *cts))):
+        close(got, want, 1e-5)
+
+
+
+# -- the ops that take the training kernels ------------------------------------
+def as_on_a_tpu(monkeypatch, module):
+    """`module`'s ops ask `pick_recurrence` as a TPU would; the kernels
+    they then take still run interpreted (jax's backend is the CPU)."""
+    monkeypatch.setattr(
+        module, "pick_recurrence",
+        lambda backend, *a: gdr.pick_recurrence("tpu", *a))
+
+
+def plans_agree(monkeypatch, module, op, seq, embed):
+    """`op`'s forward and gradient (input and every weight) under the
+    CPU's plan and under a TPU's answer, which must be `chunked` and
+    `chunked_kernel`."""
+    keys = jax.random.split(jax.random.key(13), len(op.weight_specs) + 1)
+    w = [0.3 * jax.random.normal(k, [d.size for d in spec.shape.dims
+                                     if not d.is_replica_dim])
+         for k, spec in zip(keys, op.weight_specs)]
+    x = jax.random.normal(keys[-1], (1, seq, embed))
+
+    def run():
+        return jax.jit(jax.value_and_grad(lambda x, w: jnp.sum(jnp.sin(
+            op.forward([x], w, training=True)[0])), argnums=(0, 1)))(x, w)
+
+    assert op.recurrence_plan(seq) == "chunked"
+    want = run()
+    as_on_a_tpu(monkeypatch, module)
+    assert op.recurrence_plan(seq) == "chunked_kernel"
+    # float32 sums in another order, on leaves as small as A_log's
+    for a, b in zip(jax.tree.leaves(run()), jax.tree.leaves(want)):
+        close(a, b, 1e-4)
+
+
+def test_kda_op_takes_the_chunk_kernels_where_pick_recurrence_says_so(
+        monkeypatch):
+    """A TPU's answer for heads of one 128-lane tile and a row of a
+    chunk or more: the op runs the Pallas walk with `pick_chunk`'s
+    lengths and agrees with the jax.numpy rule, forward and gradient;
+    shorter rows keep the jax.numpy rule, and `chunk_tokens` reports
+    the chunk under both plans."""
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    op = ff.kimi_delta_attention(
+        ff.create_tensor([1, 80, 32], name="x"),
+        KimiDeltaAttentionParams(embed_dim=32, num_heads=2, head_dim=128),
+        name="op").owner_op
+    calls = []
+    monkeypatch.setitem(
+        kda_op.CHUNKED_RULES, "chunked_kernel",
+        lambda *a, **kw: calls.append(a[-2:]) or
+        cdk.delta_rule_chunked_kernel(*a, **kw))
+    assert op.chunk_tokens(80) == 64
+    plans_agree(monkeypatch, kda_op, op, 80, 32)
+    assert calls == [(64, 16)]
+    assert op.chunk_tokens(80) == 64
+    assert (op.recurrence_plan(40), op.chunk_tokens(40)) == ("chunked", 48)
+
+
+def test_stateless_gated_delta_net_shares_the_chunk_kernels(monkeypatch):
+    """One decay a head, broadcast by the wrapper as the jax.numpy rule
+    broadcasts it: the same kernels, no test of the op's name."""
+    p = gdn_op.GatedDeltaNetParams(embed_dim=16, num_k_heads=1,
+                                   num_v_heads=2, head_k_dim=128,
+                                   head_v_dim=128)
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    op = ff.gated_delta_net(ff.create_tensor([1, 70, 16], name="x"), p,
+                            name="op").owner_op
+    plans_agree(monkeypatch, gdn_op, op, 70, 16)
+
+
 #: (backend, slot state, head_k_dim, head_v_dim, step tokens) -> path
 PICKS = [
     (("tpu", True, 128, 128, 1), "kernel"),    # the cell's decode step
@@ -145,10 +371,23 @@ PICKS = [
     (("tpu", True, 128, 128, gdr.MAX_STEP_TOKENS), "kernel"),
     (("tpu", True, 128, 128, gdr.MAX_STEP_TOKENS + 1), "plain"),
     (("tpu", True, 128, 128, 512), "plain"),
-    # stateless takes the chunked rule, on every backend and at every
-    # width: what a trainer differentiates
+    # stateless (what a trainer differentiates) runs a chunk at a time:
+    # the Pallas walk over the chunks on a TPU, with head dims of whole
+    # 128-lane tiles and a row of at least one full chunk of 64 ...
+    (("tpu", False, 128, 128, 8192), "chunked_kernel"),  # the KDA cell
+    (("tpu", False, 128, 128, 64), "chunked_kernel"),
+    (("tpu", False, 256, 128, 4096), "chunked_kernel"),
+    (("tpu", False, 128, 256, 100), "chunked_kernel"),
+    # ... and the jax.numpy rule everywhere else: short rows, head dims
+    # that are no whole tiles (the toy models' 8), every other backend
+    (("tpu", False, 128, 128, 63), "chunked"),
     (("tpu", False, 128, 128, 1), "chunked"),
-    (("tpu", False, 128, 128, 8192), "chunked"),
+    (("tpu", False, 128, 128, 0), "chunked"),
+    (("tpu", False, 8, 8, 8192), "chunked"),
+    (("tpu", False, 64, 128, 8192), "chunked"),
+    (("tpu", False, 128, 192, 8192), "chunked"),
+    (("cpu", False, 128, 128, 8192), "chunked"),
+    (("gpu", False, 128, 128, 8192), "chunked"),
     (("cpu", False, 128, 128, 8), "chunked"),
     (("cpu", False, 8, 8, 16), "chunked"),
     # a CPU (and anything that is not a TPU) keeps the plain path
@@ -172,9 +411,12 @@ def test_pick_recurrence_is_a_pure_function_of_what_it_is_given(
     assert gdr.pick_recurrence(*args) == want
 
 
-def test_pick_recurrence_without_pallas_is_plain(monkeypatch):
+@pytest.mark.parametrize("slot_state, tokens, want", [
+    (True, 1, "plain"), (False, 8192, "chunked")])
+def test_pick_recurrence_without_pallas_takes_no_kernel(
+        slot_state, tokens, want, monkeypatch):
     monkeypatch.setattr(gdr, "_HAVE_PALLAS", False)
-    assert gdr.pick_recurrence("tpu", True, 128, 128, 1) == "plain"
+    assert gdr.pick_recurrence("tpu", slot_state, 128, 128, tokens) == want
 
 
 def test_kernel_never_interpreted_on_tpu(monkeypatch):

@@ -6,7 +6,9 @@ reference (benchmarks/families/kimi_linear.py, whose recurrence runs a
 position at a time) on seeded weights, at a toy size on the CPU:
 
 1. the chunked rule against `delta_rule_scan`: output, final state and
-   the gradients of q, k, v, g, beta;
+   the gradients of q, k, v, g, beta (the Pallas kernels of the same
+   rule, ops/pallas/chunked_delta_rule.py, and the ops that take them:
+   tests/test_gated_delta_rule.py; here what the step's span counts);
 2. each new op alone, forward and gradient, and the gradient tests
    ROADMAP R0(c) said were missing (the expanded MLA path with its
    bottleneck and rotation, `GatedDeltaNet`'s stateless shape);
@@ -37,10 +39,12 @@ from flexflow_tpu.config import ConfigError
 from flexflow_tpu.models.kimi_linear import build_kimi_linear, layer_kinds
 from flexflow_tpu.obs import trace
 from flexflow_tpu.ops import chunked_delta_rule as cdr
+from flexflow_tpu.ops import kimi_delta_attention as kda_op
 from flexflow_tpu.ops.gated_delta_net import delta_rule_scan, l2norm
 from flexflow_tpu.ops.kimi_delta_attention import KimiDeltaAttentionParams
 from flexflow_tpu.ops.mla import MLAParams
 from flexflow_tpu.ops.pallas import flash_attention as fa
+from flexflow_tpu.ops.pallas import gated_delta_rule as gdr
 from flexflow_tpu.ops.routed_experts import RoutedExpertsParams
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -66,8 +70,7 @@ def close(got, want, tol=OP_TOL):
 
 
 # -- 1. the chunked rule against the scan a position ---------------------------
-def recurrence_inputs(s, per_channel, strong):
-    b, h, dk, dv = 2, 3, 8, 8
+def recurrence_inputs(s, per_channel, strong, b=2, h=3, dk=8, dv=8):
     keys = jax.random.split(jax.random.key(0), 8)
     g_shape = (b, s, h, dk) if per_channel else (b, s, h)
     return dict(
@@ -359,7 +362,34 @@ def test_first_step_gradient_equals_the_reference_by_group(remat):
     # the step the program built says which chunk its cores took
     built = [r for r in trace.spans()[before:] if r.name == "build_step_fns"]
     assert built and built[-1].args["kda_chunk_tokens"] == 16
+    assert built[-1].args["kda_kernel_ops"] == 0  # the CPU's plan
     assert ff.executor.remat_segments == (8 if remat else 0) or remat
+
+
+def test_build_step_fns_counts_the_chunk_kernels_and_their_chunk(
+        monkeypatch):
+    """The toy model at heads of 128 and rows of one chunk: with a
+    TPU's answer from `pick_recurrence` the `build_step_fns` span
+    counts the three KDA layers' kernels and reports their chunk; the
+    CPU's plan reports the same chunk and no kernel."""
+    cfg = dict(CFG, linear_attn_config=dict(
+        CFG["linear_attn_config"], head_dim=128, num_heads=2))
+
+    def built():
+        before = len(trace.spans())
+        ff = fam.build_model(cfg, 1, 64, 1)
+        fam.compile_model(ff, cfg, jax.devices()[:1])
+        return [r.args for r in trace.spans()[before:]
+                if r.name == "build_step_fns"][-1]
+
+    args = built()
+    assert (args["kda_chunk_tokens"], args["kda_kernel_ops"]) == (64, 0)
+    monkeypatch.setattr(  # the ops ask `pick_recurrence` as a TPU would
+        kda_op, "pick_recurrence",
+        lambda backend, *a: gdr.pick_recurrence("tpu", *a))
+    k_args = built()
+    assert (k_args["kda_chunk_tokens"], k_args["kda_kernel_ops"]) == (64, 3)
+    assert k_args["remat_segments"] == args["remat_segments"] > 0
 
 
 def test_a_twin_of_the_trainer_graph_is_refused_by_name():
