@@ -1331,7 +1331,7 @@ class FFModel:
         chunks = [op.chunk_tokens(op.inputs[0].shape.logical_shape[1])
                   for op in self.operators.topo_order()
                   if op.op_type == OperatorType.KIMI_DELTA_ATTENTION]
-        if chunks:  # positions a chunk of the delta rule holds; 0: the scan
+        if chunks:  # positions a chunk of the delta rule holds
             counts.update({"kda_chunk_tokens": min(chunks)})
         rules = [op.recurrence_plan(op.inputs[0].shape.logical_shape[1])
                  for op in self.operators.topo_order()

@@ -5,7 +5,10 @@ runs, forward and backward, wherever `pick_recurrence` answers
 full chunk and the head dims that are no whole 128-lane tiles.  (Where
 it answers "chunked_kernel", `ops/pallas/chunked_delta_rule.py` runs
 the same algebra as Pallas kernels, with this file's functions as
-their oracle.)
+their oracle.)  Both ops reach it through `delta_rule_chunked_plain`,
+the table's signature and layout: the convs' q~, k~ and v, g flat, a
+head a block of channels, and `l2norm` (here too: one formula and eps
+for serving, training and the kernels' tiles) the rule's.
 
     per head, S a [dk, dv] matrix, for each position in order:
         S' = Diag(exp(g_t)) S;  d_t = beta_t (v_t - S'^T k_t)
@@ -84,6 +87,30 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 #: fits the chip with its matrix products kept under `remat`)
 CHUNK_TOKENS = 64
 SUB_CHUNK_TOKENS = 16
+
+
+#: the eps under `l2norm`'s root: one number for serving and training,
+#: jax.numpy and the kernels' tiles
+L2NORM_EPS = 1e-6
+
+
+def row_rsqrt(x, eps: float = L2NORM_EPS):
+    """1 / sqrt(the sum of squares of each row of the last axis + eps)."""
+    return jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def l2norm(x, eps: float = L2NORM_EPS):
+    """Each row of the last axis over its length: what both delta-rule
+    ops do to a head's q and k, in jax.numpy and (the same function on
+    a resident tile) in `ops/pallas/chunked_delta_rule.py`'s kernels."""
+    return x * row_rsqrt(x, eps)
+
+
+def unit_heads(q, k):
+    """What both delta-rule ops feed the rule, from their convs' q~, k~
+    (a head's channels the last axis): q = l2norm(q~) / sqrt(dk),
+    k = l2norm(k~)."""
+    return l2norm(q) * q.shape[-1] ** -0.5, l2norm(k)
 
 
 def pick_chunk(step_tokens: int) -> tuple:
@@ -202,3 +229,20 @@ def delta_rule_chunked(S, q, k, v, g, beta, chunk: int, sub: int,
     S, o = jax.lax.scan(one_chunk, S.astype(f32), xs)
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [b, n, chunk, h, dv]
     return S, o.reshape(b, n * chunk, h, dv)[:, :s]
+
+
+def delta_rule_chunked_plain(S, q, k, v, g, beta, chunk: int, sub: int,
+                             operand_dtype=jnp.float32):
+    """`delta_rule_chunked` behind `CHUNKED_RULES`' signature
+    (`ops/pallas/chunked_delta_rule.py`), as the ops hand over: S
+    [b, h, dk, dv] float32; the convs' q~, k~ `[b, s, h dk]` and v
+    `[b, s, h dv]` FLAT, a head a block of channels; g `[b, s, h dk]`,
+    one decay a channel; beta `[b, s, h]` -> (S, o `[b, s, h dv]`
+    float32).  The rule is fed `unit_heads(q~, k~)`; the by-head form
+    and the norm are jax.numpy's here (off the TPU a reshape is free)."""
+    b, s = q.shape[:2]
+    h = S.shape[1]
+    q, k, v, g = (t.reshape(b, s, h, -1) for t in (q, k, v, g))
+    S, o = delta_rule_chunked(S, *unit_heads(q, k), v, g, beta, chunk, sub,
+                              operand_dtype)
+    return S, o.reshape(b, s, -1)

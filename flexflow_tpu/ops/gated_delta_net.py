@@ -65,7 +65,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
                            ZeroInitializer)
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
-from .chunked_delta_rule import pick_chunk
+from .chunked_delta_rule import l2norm, pick_chunk
 from .op import Op, ShapeError, ShardConfig, WeightSpec
 from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
@@ -96,11 +96,6 @@ class GatedDeltaNetParams:
     def conv_dim(self) -> int:
         """Channels the conv runs over: [q | k | v]."""
         return 2 * self.key_dim + self.value_dim
-
-
-def l2norm(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
-                             + eps)
 
 
 def delta_rule_step(S, q, k, v, g, beta):
@@ -249,8 +244,13 @@ class GatedDeltaNet(Op):
             q = conv[..., :p.key_dim].reshape(b, s, hk, dk)
             k = conv[..., p.key_dim:2 * p.key_dim].reshape(b, s, hk, dk)
             v = conv[..., 2 * p.key_dim:].reshape(b, s, hv, dv)
-            q = jnp.repeat(l2norm(q) * dk ** -0.5, hv // hk, axis=2)
-            k = jnp.repeat(l2norm(k), hv // hk, axis=2)
+            plan = self.recurrence_plan(s)
+            # (the chunked rules norm q and k themselves: per head, so
+            # after the repeat of the key heads is the same)
+            unit = plan not in CHUNKED_RULES
+            q = jnp.repeat(l2norm(q) * dk ** -0.5 if unit else q, hv // hk,
+                           axis=2)
+            k = jnp.repeat(l2norm(k) if unit else k, hv // hk, axis=2)
             real = (jnp.arange(s, dtype=jnp.int32)[None, :]
                     < count[:, None])[..., None]  # [b, s, 1]
             beta = jnp.where(real, jax.nn.sigmoid(ba[..., :hv]), 0.0)
@@ -258,13 +258,16 @@ class GatedDeltaNet(Op):
                 real, -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
                     ba[..., hv:] + dt_bias.astype(f32)), 0.0)
             S = S.astype(f32)
-            plan = self.recurrence_plan(s)
             if plan == "kernel":
                 S, o = gated_delta_rule(S, q, k, v, g, beta, count)
             elif plan in CHUNKED_RULES:
-                S, o = CHUNKED_RULES[plan](S, q, k, v, g, beta,
-                                           *pick_chunk(s),
-                                           operand_dtype=x.dtype)
+                # the table's one layout: a head a block of channels of
+                # a flat tensor, a decay a channel
+                S, o = CHUNKED_RULES[plan](
+                    S, *(t.reshape(b, s, -1) for t in (q, k, v)),
+                    jnp.repeat(g, dk, axis=2), beta, *pick_chunk(s),
+                    operand_dtype=x.dtype)
+                o = o.reshape(b, s, hv, dv)
             else:
                 S, o = delta_rule_scan(S, q, k, v, g, beta)  # [b, s, hv, dv]
         with scope("out"):

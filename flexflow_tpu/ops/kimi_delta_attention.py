@@ -16,8 +16,9 @@ of the key, the linear-attention mixer of three layers in four of the
 the same over a head's channels; its one fused `[q | k | v | z]`
 projection, one conv over `[q | k | v]`, key heads repeated to value
 heads and silu output gate are another layer's weights, so this is an
-op of its own that shares the recurrence (`ops/chunked_delta_rule.py`),
-the conv (`short_conv.causal_depthwise_conv`) and `l2norm` with it.
+op of its own that shares the recurrence (`ops/chunked_delta_rule.py`
+and its kernels, `l2norm`'s formula with them) and the conv
+(`short_conv.causal_depthwise_conv`) with it.
 
 Stateless only: every row starts from zero and runs its whole `[b, s]`
 input, which is what a trainer runs.  `recurrence_plan` asks
@@ -26,9 +27,23 @@ at a time: "chunked_kernel" (`ops/pallas/chunked_delta_rule.py`, the
 rule as Pallas kernels, forward and backward) on a TPU with 128-lane
 heads and a row of a full chunk or more, "chunked" (plain jax.numpy)
 everywhere else; the chunk is `pick_chunk`'s.  The serving twin (a conv
-tail a
-projection and `S` a slot, a per-channel decay in
+tail a projection and `S` a slot, a per-channel decay in
 `ops/pallas/gated_delta_rule.py`'s kernel) is not built (ROADMAP).
+
+Layout: a head is a column block of d channels.  The convs write q~,
+k~, v flat, `[b, s, h d]`, the decay `g` is formed flat (`A_log`
+repeated over a head's channels), and the op hands them to the rule AS
+THEY ARE: the l2norm of q~ and k~ above runs where the rule's operands
+are formed, in the operands' kernels on a head's resident `[C, d]` tile
+on the kernel plan, in jax.numpy on the other (`CHUNKED_RULES`, one
+signature, one layout).  The rule returns `o` as v came, flat, and the
+head norm of the last line is `head_rms`: the heads' sums of squares
+and the spread of their rsqrt are two small products with a 0/1
+membership matrix.  So the op never forms
+`[b, s, h, d]`, of its operands, of `o` or of the gate: on the v5e that
+reshape of a float32 tensor is a copy (the tile is the last two dims),
+~20 ms a step with the norms' own passes beside it (PERF.md section 6,
+PR 45).
 
 Under `remat` a checkpointed segment keeps this op's matrix products
 by the executor's policy (they have no batch dimension) and the core's
@@ -49,11 +64,13 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .chunked_delta_rule import pick_chunk
-from .gated_delta_net import delta_rule_scan, l2norm
 from .op import Op, ShapeError, ShardConfig, WeightSpec, remat_keep
 from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import pick_recurrence
 from .short_conv import causal_depthwise_conv
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +84,28 @@ class KimiDeltaAttentionParams:
     @property
     def proj_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+
+def head_rms(o, num_heads: int, eps: float):
+    """o [b, s, h d] float32, a head a block of d channels -> each head
+    over the root mean square of its channels, with no `[b, s, h, d]`
+    form (a copy on the chip): the heads' sums of squares are a product
+    with the 0/1 membership matrix `[h d, h]`, and its transpose spreads
+    the rsqrt back over a head's channels; float32 products at
+    `HIGHEST`, so a sum is float32's and the spread is exact.  The
+    spread carries the row as a batch dimension: the same program on
+    the chip, and the `products` level of the executor's `remat` (which
+    keeps what has none) then recomputes its `[b, s, h d]` result from
+    the `[b, s, h]` sums it does keep (`jnp.repeat` would do too, and
+    brings the by-head copy back: PERF.md section 6, PR 45)."""
+    d = o.shape[-1] // num_heads
+    member = (jnp.arange(o.shape[-1])[:, None] // d
+              == jnp.arange(num_heads)).astype(o.dtype)
+    mean = jnp.matmul(o * o, member, precision=_HIGHEST) / d
+    return o * jnp.einsum(
+        "bsh,bhc->bsc", jax.lax.rsqrt(mean + eps),
+        jnp.broadcast_to(member.T, o.shape[:1] + member.T.shape),
+        precision=_HIGHEST)
 
 
 class KimiDeltaAttention(Op):
@@ -85,10 +124,8 @@ class KimiDeltaAttention(Op):
                                p.head_dim, step_tokens)
 
     def chunk_tokens(self, step_tokens: int) -> int:
-        """Positions a chunk of the core holds; 0 for the scan a
-        position."""
-        if self.recurrence_plan(step_tokens) not in CHUNKED_RULES:
-            return 0
+        """Positions a chunk of the core holds (both plans run a chunk
+        at a time)."""
         return pick_chunk(step_tokens)[0]
 
     def infer_output_shapes(self, input_shapes):
@@ -148,48 +185,37 @@ class KimiDeltaAttention(Op):
         f32 = jnp.float32
         with scope("proj"):
             mixed = [jnp.matmul(x, w) for w in (w_q, w_k, w_v)]
-        with scope("conv"):
+        with scope("conv"):  # q~, k~, v: flat, [b, s, h d] float32
             def conv(t, taps):
                 window = jnp.concatenate(
                     [jnp.zeros((b, p.conv_kernel - 1, p.proj_dim), t.dtype),
                      t], axis=1)
-                return jax.nn.silu(causal_depthwise_conv(window, taps, s)) \
-                    .reshape(b, s, h, d)
+                return jax.nn.silu(causal_depthwise_conv(window, taps, s))
 
             q, k, v = map(conv, mixed, (cq, ck, cv))
-            q, k = l2norm(q) * d ** -0.5, l2norm(k)
-        with scope("gate"):  # what the core is fed beside q, k, v
+        with scope("gate"):  # what the core is fed beside them, flat too
             decay_in = jnp.matmul(jnp.matmul(x, w_fa), w_fb,
                                   preferred_element_type=f32)
-            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
-                (decay_in + dt_bias.astype(f32)).reshape(b, s, h, d))
+            g = -jnp.repeat(jnp.exp(a_log.astype(f32)), d) \
+                * jax.nn.softplus(decay_in + dt_bias.astype(f32))
             beta = jax.nn.sigmoid(jnp.matmul(x, w_b,
                                              preferred_element_type=f32))
-        with scope("core"):
-            S = jnp.zeros((b, h, d, d), f32)
-            rule = CHUNKED_RULES.get(self.recurrence_plan(s))
-            if rule is not None:
-                _, o = rule(S, q, k, v, g, beta, *pick_chunk(s),
-                            operand_dtype=x.dtype)
-            else:
-                _, o = delta_rule_scan(S, q, k, v, g, beta)
-            o = remat_keep(o.astype(x.dtype))  # [b, s, h, d]
+        with scope("core"):  # with the l2norm of q~ and k~, a head's
+            rule = CHUNKED_RULES[self.recurrence_plan(s)]
+            _, o = rule(jnp.zeros((b, h, d, d), f32), q, k, v, g, beta,
+                        *pick_chunk(s), operand_dtype=x.dtype)
+            o = remat_keep(o.astype(x.dtype))  # [b, s, h d], as v came
         with scope("norm_gate"):
             gate = jnp.matmul(jnp.matmul(x, w_ga), w_gb,
                               preferred_element_type=f32)
-            o = o.astype(f32)
-            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
-                                           keepdims=True) + p.eps)
-            y = (o * norm_w.astype(f32)
-                 * jax.nn.sigmoid(gate.reshape(b, s, h, d)))
+            y = (head_rms(o.astype(f32), h, p.eps)
+                 * jnp.tile(norm_w.astype(f32), h) * jax.nn.sigmoid(gate))
         with scope("out"):
             # y is written once, in the compute precision: left to fuse,
-            # the head norm and the gate (and the core's layout change
-            # before them) are recomputed for every tile of the product
-            # (4.7 ms a layer against 0.95 on the v5e: PERF.md section
-            # 6, PR 43)
-            y = jax.lax.optimization_barrier(
-                y.reshape(b, s, p.proj_dim).astype(x.dtype))
+            # the head norm and the gate are recomputed for every tile
+            # of the product (4.7 ms a layer against 0.95 on the v5e:
+            # PERF.md section 6, PR 43)
+            y = jax.lax.optimization_barrier(y.astype(x.dtype))
             return [jnp.matmul(y, w_o).astype(x.dtype)]
 
     def flops(self):

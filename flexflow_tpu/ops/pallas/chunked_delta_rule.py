@@ -13,14 +13,23 @@ the chunks is a small part.  Here the same algebra is three stages:
 
 1. **the operands** (`_operands`: two kernels, `_operands_tile` and its
    hand-written gradient `_operands_tile_bwd`).  A grid program is
-   (row, 8 heads, chunk) and reads q, k, v, g WHERE THE OP LEFT THEM, a
-   `[C, 8, d]` block of `[b, s, h, d]` (no reshape, which on this
-   layout is a copy, and no `[n, b, h, C, d]` copy of them).  It forms
-   the running sums `G`, `A` and `B` (`_pairs_tile`: a sub-chunk's
-   pairwise decays `exp(G_t - G_i)` a column at a time, masked before
-   the exponential; the blocks left of a sub-chunk as one float32
-   product against the sub-chunk's base, exponents <= 0 on both
-   sides), the scaled operands `q exp(G)`, `k exp(G_C - G)`,
+   (row, 8 heads, chunk) and reads q~, k~, v, g WHERE THE OP'S CONVS
+   AND DECAY PROJECTION LEFT THEM, FLAT: a `[C, 8 d]` block of
+   `[b, s, h d]`, of which head j's `[C, d]` is column block j, whole
+   128-lane tiles (no `[b, s, h, d]` form, whose reshape of a float32
+   tensor is a copy on this layout and whose blocks put a head's rows
+   on one sublane of each position's tile; no `[n, b, h, C, d]` copy
+   either).  It does the ops' l2norm itself, on the tile
+   (`unit_heads`: `q = l2norm(q~) / sqrt(dk)`, `k = l2norm(k~)`, a
+   row's norm one lane reduction; float32, `l2norm` itself), and the
+   backward kernel the norm's gradient
+   (`dq~ = r (dq - q^ (q^ . dq))`), so the gradients leave flat too,
+   dq~, dk~, dv, dg where the convs' and the projection's backward
+   read them.  It forms the running sums `G`, `A` and `B`
+   (`_pairs_tile`: a sub-chunk's pairwise decays `exp(G_t - G_i)` a
+   column at a time, masked before the exponential; the blocks left of
+   a sub-chunk as one float32 product against the sub-chunk's base,
+   exponents <= 0 on both sides), the scaled operands `q exp(G)`, `k exp(G_C - G)`,
    `exp(G_C)`, and the solve's system `I + Diag(beta) A` and right side
    `[beta V | beta exp(G) K]`.
 2. **the solve**, XLA's: `jax.scipy.linalg.solve_triangular`, float32,
@@ -34,6 +43,9 @@ the chunks is a small part.  Here the same algebra is three stages:
    program's output block, which stays in VMEM while the chunk index
    moves, and a chunk is four MXU products on resident tiles.  The
    backward kernel walks the chunks in reverse with `dS` resident.
+   `o` leaves as the operands came, flat `[b, s, h dv]` (head j's
+   output is column block j of the program's `[C, 8 dv]` block), and
+   `do` is read so: no `[n, b, h, C, dv]` to move to `[b, s, h, dv]`.
 
         forward, a chunk (`~` is a cast to the operand dtype):
             d   = u - w S~            o = qg S~ + bm d~
@@ -60,9 +72,9 @@ floats), and `dshrink` is a reduction over sublanes.  `w S~` and
 `qg S~` are one product against the same tile (`[w; qg]`, 2C rows), as
 are the two `[dk, dv]`-shaped terms of `dS` (`[do~; -dd~]^T [qg; w]`).
 
-Residuals: of the operands, q, k, v, g, beta themselves and `A`
-(everything else is recomputed from them in the backward kernel); of
-the walk, its operands and the chunk-boundary states `[n, b, h, dv,
+Residuals: of the operands, q~, k~ (before the norm), v, g, beta
+themselves and `A` (everything else, the norm too, is recomputed from
+them in the backward kernel); of the walk, its operands and the chunk-boundary states `[n, b, h, dv,
 dk]` float32 (268 MB a layer at 8,192 positions of 32 heads: what
 jax's own backward of the scan kept).  Nothing is tagged `remat_keep`:
 under the executor's `remat` the forward kernels and the solve run
@@ -73,7 +85,11 @@ in a step that already counts 13.4 of the chip's 15.75 GB at once.
 `pick_recurrence` (`ops/pallas/gated_delta_rule.py`) answers
 "chunked_kernel" for the stateless shape on a TPU with head dims of
 whole 128-lane tiles and a row of at least one full chunk; everywhere
-else the stateless shape takes `delta_rule_chunked` as before.
+else the stateless shape takes `delta_rule_chunked`, behind the same
+signature and layout (`CHUNKED_RULES`, `delta_rule_chunked_plain`:
+there the by-head form and the norm are jax.numpy's, and
+`ops/chunked_delta_rule.py`'s algebra stays as it was, the kernels'
+oracle).
 """
 from __future__ import annotations
 
@@ -83,7 +99,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..chunked_delta_rule import delta_rule_chunked
+from ..chunked_delta_rule import (delta_rule_chunked_plain, row_rsqrt,
+                                  unit_heads)
 
 try:  # lazy-safe: CPU-only envs without pallas never touch the kernel
     from jax.experimental import pallas as pl
@@ -128,7 +145,7 @@ def _fwd_kernel(s_ref, x_ref, qg_ref, bm_ref, kt_ref, sh_ref,
     nn, nt, tn = _products(exact)
     C = x_ref.shape[-2]
     dt = qg_ref.dtype
-    dv = o_ref.shape[-1]
+    dv = o_ref.shape[-1] // heads
 
     @pl.when(pl.program_id(2) == 0)
     def _start():
@@ -143,7 +160,7 @@ def _fwd_kernel(s_ref, x_ref, qg_ref, bm_ref, kt_ref, sh_ref,
                   Sb)
         d = x[:, :dv] - both[:C]                    # [C, dv]
         db = d.astype(dt)
-        o_ref[0, 0, j] = both[C:] + nn(bm_ref[0, 0, j], db)
+        o_ref[0, :, j * dv:(j + 1) * dv] = both[C:] + nn(bm_ref[0, 0, j], db)
         so_ref[0, j] = St * sh_ref[0, 0, j] + tn(db, kt_ref[0, 0, j])
 
 
@@ -156,7 +173,7 @@ def _bwd_kernel(ds_ref, x_ref, qg_ref, bm_ref, kt_ref, sh_ref, st_ref,
     nn, nt, tn = _products(exact)
     C = x_ref.shape[-2]
     dt = qg_ref.dtype
-    dv = do_ref.shape[-1]
+    dv = do_ref.shape[-1] // heads
 
     @pl.when(pl.program_id(2) == 0)
     def _start():
@@ -171,7 +188,7 @@ def _bwd_kernel(ds_ref, x_ref, qg_ref, bm_ref, kt_ref, sh_ref, st_ref,
         w, qg, kt, bm = (x[:, dv:].astype(dt), qg_ref[0, 0, j],
                          kt_ref[0, 0, j], bm_ref[0, 0, j])
         db = (x[:, :dv] - nt(w, Sb)).astype(dt)
-        dob = do_ref[0, 0, j].astype(dt)            # [C, dv]
+        dob = do_ref[0, :, j * dv:(j + 1) * dv].astype(dt)  # [C, dv]
         dd = tn(bm, dob) + nt(kt, dSb)
         ddb = dd.astype(dt)
         both = nn(jnp.concatenate([ddb, dob], 0), Sb)       # [2C, dk]
@@ -289,12 +306,22 @@ def _pairs_tile_bwd(q, k, G, sub: int, dA, dB, dqg, dkg, dkt, dsh):
     return Y + dqg * eG, X + Z + dkg * eG + dkt * et, dG
 
 
+def _l2norm_bwd(x, dy):
+    """The gradient of `l2norm` at x: with r the row's rsqrt and
+    y = r x, dx = r (dy - y (y . dy)) (exact with the eps)."""
+    r = row_rsqrt(x)
+    y = x * r
+    return r * (dy - y * jnp.sum(y * dy, axis=-1, keepdims=True))
+
+
 def _operands_tile(q, k, v, g, beta, sub: int):
-    """One head's chunk, float32: q, k, g [C, dk], v [C, dv], beta
-    [C, 1] -> (A, the solve's system I + Diag(beta) A [C, C], its right
-    side [beta V | beta exp(G) K] [C, dv + dk], B, q exp(G),
-    k exp(G_C - G), exp(G_C))."""
+    """One head's chunk, float32: the convs' q~, k~ and g [C, dk], v
+    [C, dv], beta [C, 1] -> (A, the solve's system I + Diag(beta) A
+    [C, C], its right side [beta V | beta exp(G) K] [C, dv + dk], B,
+    q exp(G), k exp(G_C - G), exp(G_C)) of q, k = `unit_heads(q~, k~)`,
+    normalised here, on the tile."""
     C = q.shape[0]
+    q, k = unit_heads(q, k)
     A, B, qg, kg, kt, shrink = _pairs_tile(
         q, k, _products(True)[0](_tril(C), g), sub)
     eye = (_iota((C, C), 0) == _iota((C, C), 1)).astype(jnp.float32)
@@ -304,9 +331,11 @@ def _operands_tile(q, k, v, g, beta, sub: int):
 
 def _operands_tile_bwd(q, k, v, g, beta, sub: int, A, d_sys, d_rhs, dB, dqg,
                        dkt, dsh):
-    """The gradient of `_operands_tile` (but for `A`, a residual) to q,
-    k, v, g, beta."""
+    """The gradient of `_operands_tile` (but for `A`, a residual) to
+    the q~, k~ it was given, v, g and beta."""
     nn, _, tn = _products(True)
+    raw = q, k
+    q, k = unit_heads(q, k)
     dv = v.shape[1]
     d_v, d_kg = d_rhs[:, :dv], d_rhs[:, dv:]
     tril = _tril(q.shape[0])
@@ -316,14 +345,18 @@ def _operands_tile_bwd(q, k, v, g, beta, sub: int, A, d_sys, d_rhs, dB, dqg,
               + jnp.sum(d_kg * k * jnp.exp(G), axis=1, keepdims=True))
     dq, dk, dG = _pairs_tile_bwd(q, k, G, sub, beta * d_sys, dB, dqg,
                                  beta * d_kg, dkt, dsh)
-    return dq, dk, beta * d_v, tn(tril, dG), d_beta
+    return (_l2norm_bwd(raw[0], dq * q.shape[-1] ** -0.5),
+            _l2norm_bwd(raw[1], dk), beta * d_v, tn(tril, dG), d_beta)
 
 
-def _specs(kinds: str, shapes, heads: int, n: int, chunk: int, reverse: bool):
+def _specs(kinds: str, shapes, heads: int, grid, chunk: int, reverse: bool):
     """A grid program's block of each array, by its kind: "c" `heads`
     heads of one chunk of `[n, b, h, ...]`, "r" of a row `[b, h, ...]`,
     "p" the chunk's positions of `heads` heads where the op left them,
-    `[b, s, h, d]`; the chunk index runs backwards under `reverse`."""
+    flat: `heads` column blocks of `[b, s, h d]`, a head d lanes wide;
+    the chunk index runs backwards under `reverse`."""
+    _, h, n = grid
+
     def at(t):
         return n - 1 - t if reverse else t
 
@@ -334,8 +367,8 @@ def _specs(kinds: str, shapes, heads: int, n: int, chunk: int, reverse: bool):
         if kind == "r":
             return pl.BlockSpec((1, heads) + sp[2:],
                                 lambda i, j, t: (i, j, 0, 0))
-        return pl.BlockSpec((1, chunk, heads, sp[3]),
-                            lambda i, j, t: (i, at(t), j, 0))
+        return pl.BlockSpec((1, chunk, sp[2] // h * heads),
+                            lambda i, j, t: (i, at(t), j))
 
     return [spec(kind, tuple(sp)) for kind, sp in zip(kinds, shapes)]
 
@@ -346,7 +379,7 @@ def _call(kernel, name, heads, interpret, kinds, args, outs, *, grid, chunk,
     `kinds` names each argument's and then each output's blocks."""
     b, h, n = grid
     specs = _specs(kinds, [a.shape for a in list(args) + list(outs)], heads,
-                   n, chunk, reverse)
+                   grid, chunk, reverse)
     return pl.pallas_call(
         functools.partial(kernel, heads=heads, **static),
         grid=(b, h // heads, n),
@@ -370,9 +403,9 @@ def _walk_fwd(heads, interpret, St, x, qg, bm, kt, shrink):
     f32 = jnp.float32
     St, o, states = _call(
         _fwd_kernel, "delta_rule_chunks_fwd", heads, interpret,
-        "rccccc" "rcc", (St, x, qg, bm, kt, shrink),
+        "rccccc" "rpc", (St, x, qg, bm, kt, shrink),
         [jax.ShapeDtypeStruct(St.shape, f32),
-         jax.ShapeDtypeStruct((n, b, h, C, dv), f32),
+         jax.ShapeDtypeStruct((b, n * C, h * dv), f32),
          jax.ShapeDtypeStruct((n,) + St.shape, f32)],
         grid=(b, h, n), chunk=C, exact=_is_exact(qg))
     return (St, o), (x, qg, bm, kt, shrink, states)
@@ -385,7 +418,7 @@ def _walk_bwd(heads, interpret, res, cts):
     like = jax.ShapeDtypeStruct
     return tuple(_call(
         _bwd_kernel, "delta_rule_chunks_bwd", heads, interpret,
-        "rccccccc" "rccccc", (dSt, x, qg, bm, kt, shrink, states, do),
+        "rcccccc" "p" "rccccc", (dSt, x, qg, bm, kt, shrink, states, do),
         [like(dSt.shape, jnp.float32)]
         + [like(t.shape, t.dtype) for t in (x, qg, bm, kt, shrink)],
         grid=(b, h, n), chunk=C, reverse=True, exact=_is_exact(qg)))
@@ -394,7 +427,8 @@ def _walk_bwd(heads, interpret, res, cts):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _walk(heads, interpret, St, x, qg, bm, kt, shrink):
     """(St [b, h, dv, dk] float32, the solve's [u | w] float32 and the
-    other operands) -> (the final states, o [n, b, h, C, dv] float32)."""
+    other operands) -> (the final states, o [b, n C, h dv] float32: flat
+    as the operands came, head j's output column block j)."""
     return _walk_fwd(heads, interpret, St, x, qg, bm, kt, shrink)[0]
 
 
@@ -414,17 +448,23 @@ def _head_loop(heads: int, body):
     jax.lax.fori_loop(0, heads, step, 0, unroll=True)
 
 
+def _head_columns(ref, j, heads: int):
+    """Where head j's `[C, d]` lies in a flat `(1, C, heads d)` block:
+    column block j, whole lane tiles (d is a multiple of 128 on the
+    chip), so reading or writing it moves no data across lanes."""
+    d = ref.shape[-1] // heads
+    return 0, slice(None), pl.ds(pl.multiple_of(j * d, d), d)
+
+
 def _operands_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *out_refs,
                          heads: int, sub: int):
     """One grid program = (row, head block, chunk): `_operands_tile` of
-    each head, q, k, v, g read where the op left them: a `[C, heads,
-    d]` block of `[b, s, h, d]`, of which `ref[0, :, j]` is head j's
-    `[C, d]` (a sublane of each position's tile)."""
+    each head, q, k, v, g read where the op left them, flat."""
     f32 = jnp.float32
 
     def head(j):
         outs = _operands_tile(
-            *(ref[0, :, j, :].astype(f32)
+            *(ref[_head_columns(ref, j, heads)].astype(f32)
               for ref in (q_ref, k_ref, v_ref, g_ref)), beta_ref[0, 0, j], sub)
         for ref, out in zip(out_refs, outs):
             ref[0, 0, j] = out.astype(ref.dtype)
@@ -434,38 +474,35 @@ def _operands_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *out_refs,
 
 def _operands_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *refs,
                          heads: int, sub: int):
+    """The same grid: the gradients leave flat too, where the convs'
+    and the decay projection's backward read them."""
     f32 = jnp.float32
     places = (q_ref, k_ref, v_ref, g_ref)
     cts, grads = refs[:-5], refs[-5:]  # A and the outputs' | dq .. dbeta
 
     def head(j):
         out = _operands_tile_bwd(
-            *(ref[0, :, j, :].astype(f32) for ref in places),
-            beta_ref[0, 0, j], sub, *(ref[0, 0, j].astype(f32) for ref in cts))
+            *(ref[_head_columns(ref, j, heads)].astype(f32)
+              for ref in places),
+            beta_ref[0, 0, j], sub,
+            *(ref[0, 0, j].astype(f32) for ref in cts))
         for ref, grad in zip(grads[:4], out):
-            ref[0, :, j, :] = grad.astype(ref.dtype)
+            ref[_head_columns(ref, j, heads)] = grad.astype(ref.dtype)
         grads[4][0, 0, j] = out[4]
 
     _head_loop(heads, head)
 
 
-def _operand_heads(num_heads: int) -> int:
-    """Heads a grid program of the operands' kernels holds: the heads
-    are the second-minor dim of their `[C, heads, d]` blocks, so one
-    8-sublane tile of them, or the row's all."""
-    return 8 if num_heads % 8 == 0 else num_heads
-
-
 def _operands_fwd(interpret, chunk, sub, dt, q, k, v, g, beta):
-    b, s, h, dk = q.shape
-    n, dv = s // chunk, v.shape[-1]
+    (b, s, _), h = q.shape, beta.shape[2]
+    n, dk, dv = s // chunk, q.shape[2] // h, v.shape[2] // h
     f32 = jnp.float32
 
     def out(last, dtype):
         return jax.ShapeDtypeStruct((n, b, h) + last, dtype)
 
     A, *outs = _call(
-        _operands_fwd_kernel, "delta_rule_operands_fwd", _operand_heads(h),
+        _operands_fwd_kernel, "delta_rule_operands_fwd", heads_per_program(h),
         interpret, "ppppc" "ccccccc", (q, k, v, g, beta),
         [out((chunk, chunk), f32), out((chunk, chunk), f32),
          out((chunk, dv + dk), f32), out((chunk, chunk), dt),
@@ -475,9 +512,9 @@ def _operands_fwd(interpret, chunk, sub, dt, q, k, v, g, beta):
 
 
 def _operands_bwd(interpret, chunk, sub, dt, res, cts):
-    b, s, h, _ = res[0].shape
+    (b, s, _), h = res[0].shape, res[4].shape[2]
     return tuple(_call(
-        _operands_bwd_kernel, "delta_rule_operands_bwd", _operand_heads(h),
+        _operands_bwd_kernel, "delta_rule_operands_bwd", heads_per_program(h),
         interpret, "ppppc" "ccccccc" "ppppc", tuple(res) + tuple(cts),
         [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in res[:5]],
         grid=(b, h, s // chunk), chunk=chunk, sub=sub))
@@ -485,11 +522,13 @@ def _operands_bwd(interpret, chunk, sub, dt, res, cts):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
 def _operands(interpret, chunk, sub, dt, q, k, v, g, beta):
-    """q, k, g [b, s, h, dk], v [b, s, h, dv] (s whole chunks), beta
-    [n, b, h, C, 1] -> (the solve's system [n, b, h, C, C] and right
-    side [n, b, h, C, dv + dk], float32; B [n, b, h, C, C], q exp(G)
-    and k exp(G_C - G) [n, b, h, C, dk] in `dt`, the walk's operand
-    dtype; exp(G_C) [n, b, h, 1, dk] float32)."""
+    """The convs' q~, k~ and g [b, s, h dk], v [b, s, h dv] (s whole
+    chunks), beta [n, b, h, C, 1] -> (the solve's system [n, b, h, C, C]
+    and right side [n, b, h, C, dv + dk], float32; B [n, b, h, C, C],
+    q exp(G) and k exp(G_C - G) [n, b, h, C, dk] in `dt`, the walk's
+    operand dtype; exp(G_C) [n, b, h, 1, dk] float32), q and k
+    normalised on the tile; the residuals and gradients are q~'s and
+    k~'s."""
     return _operands_fwd(interpret, chunk, sub, dt, q, k, v, g, beta)[0]
 
 
@@ -500,9 +539,10 @@ def delta_rule_chunked_kernel(S, q, k, v, g, beta, chunk: int, sub: int,
                               operand_dtype=jnp.float32, *,
                               heads_block: Optional[int] = None,
                               interpret: Optional[bool] = None):
-    """`ops/chunked_delta_rule.delta_rule_chunked` as the kernels above
-    around XLA's solve: same arguments, same result to rounding,
-    differentiable in S, q, k, v, g and beta.  `interpret`
+    """`CHUNKED_RULES`' rule as the kernels above around XLA's solve:
+    same arguments as `delta_rule_chunked_plain` (q~, k~, v, g flat,
+    `[b, s, h d]`; the l2norm of q~ and k~ the rule's), same result to
+    rounding, differentiable in S, q~, k~, v, g and beta.  `interpret`
     defaults from the backend (compiled by Mosaic on a TPU, interpreted
     on a CPU: the tests' vehicle); `heads_block` (a probe's:
     `scripts/kda_core_probe.py`) overrides the walk's
@@ -518,7 +558,7 @@ def delta_rule_chunked_kernel(S, q, k, v, g, beta, chunk: int, sub: int,
         raise ValueError(f"chunk {chunk} is no multiple of sub-chunk {sub}")
     return _rule(S, q, k, v, g, beta, chunk=chunk, sub=sub,
                  operand_dtype=jnp.dtype(operand_dtype),
-                 heads=heads_block or heads_per_program(q.shape[2]),
+                 heads=heads_block or heads_per_program(S.shape[1]),
                  interpret=interpret)
 
 
@@ -528,33 +568,28 @@ def delta_rule_chunked_kernel(S, q, k, v, g, beta, chunk: int, sub: int,
     "chunk", "sub", "operand_dtype", "heads", "interpret"))
 def _rule(S, q, k, v, g, beta, *, chunk, sub, operand_dtype, heads,
           interpret):
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    b, s = q.shape[:2]
+    h = S.shape[1]
     f32 = jnp.float32
-    if g.ndim == 3:
-        g = jnp.broadcast_to(g[..., None], (b, s, h, dk))
     n = -(-s // chunk)
 
     def whole(t):  # positions that leave the state as it was
-        return jnp.pad(t, ((0, 0), (0, n * chunk - s))
-                       + ((0, 0),) * (t.ndim - 2))
+        return jnp.pad(t, ((0, 0), (0, n * chunk - s), (0, 0)))
 
-    def chunks(t):  # [b, n chunk, h, d] -> [n, b, h, chunk, d]
-        t = t.astype(f32).reshape((b, n, chunk) + t.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 2, 3)
-
+    beta = jnp.moveaxis(whole(beta.astype(f32)).reshape(b, n, chunk, h),
+                        (1, 3), (0, 2))[..., None]  # [n, b, h, chunk, 1]
     system, rhs, bm, qg, kt, shrink = _operands(
         interpret, chunk, sub, operand_dtype,
-        *(whole(t) for t in (q, k, v, g)), chunks(whole(beta)[..., None]))
+        *(whole(t) for t in (q, k, v, g)), beta)
     solved = jax.scipy.linalg.solve_triangular(
         system, rhs, lower=True, unit_diagonal=True)
     St, o = _walk(heads, interpret, jnp.swapaxes(S.astype(f32), -1, -2),
                   solved, qg, bm, kt, shrink)
-    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [b, n, chunk, h, dv]
-    return jnp.swapaxes(St, -1, -2), o.reshape(b, -1, h, dv)[:, :s]
+    return jnp.swapaxes(St, -1, -2), o[:, :s]
 
 
 #: `pick_recurrence`'s answers for the stateless shape and what runs
-#: each: one signature, (S, q, k, v, g, beta, chunk, sub, operand_dtype)
-CHUNKED_RULES = {"chunked": delta_rule_chunked,
+#: each: one signature, (S, q~, k~, v, g, beta, chunk, sub,
+#: operand_dtype), and one layout: q~, k~, v, g flat, `[b, s, h d]`
+CHUNKED_RULES = {"chunked": delta_rule_chunked_plain,
                  "chunked_kernel": delta_rule_chunked_kernel}

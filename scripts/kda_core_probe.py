@@ -1,11 +1,18 @@
 #!/usr/bin/env python3
 """The delta rule's core alone, forward and backward, at a training
-step's shapes: `ops/chunked_delta_rule.delta_rule_chunked` by chunk and
-sub-chunk length (bf16 operands, one decay a channel, as
-`KimiDeltaAttention` feeds it) and
-`ops/pallas/chunked_delta_rule.delta_rule_chunked_kernel` (the same
-rule as Pallas kernels) by the heads a grid program of its walk over
-the chunks holds (`--kernel-heads`), against the floors the benchmark holds
+step's shapes, fed as `KimiDeltaAttention` feeds it: q~, k~, v as the
+convs leave them and one decay a channel, all flat `[b, s, h d]`
+float32, the l2norm of q~ and k~ the core's own (`CHUNKED_RULES`'
+signature and layout); bf16 operands for the walk's products.
+Variants: the jax.numpy rule by chunk and sub-chunk length
+(`--chunks`), and `ops/pallas/chunked_delta_rule.delta_rule_chunked_kernel`
+(the same rule as Pallas kernels) by the heads a grid program of its
+walk over the chunks holds (`--kernel-heads`), each twice: handed over
+flat as the convs leave them (the op's), and through the by-head form
+first (`[b, s, h, d]` and the l2norm in jax.numpy, as the op did before
+PR 45, then flat again for the rule: what the by-head form costs a
+caller on the chip, a copy each way and the norm's passes).
+Against the floors the benchmark holds
 the core to (`families/kimi_linear.kda_core_flops` at the bf16 peak and
 `kda_core_bytes` at the published bandwidth, for ONE layer).  Prints ms
 a call on the chip and the compiler's temporaries
@@ -33,44 +40,86 @@ import jax.numpy as jnp  # noqa: E402
 
 from benchmarks.families import kimi_linear as fam  # noqa: E402
 from flexflow_tpu.ops.chunked_delta_rule import (CHUNK_TOKENS,  # noqa: E402
-                                                 SUB_CHUNK_TOKENS,
-                                                 delta_rule_chunked)
-from flexflow_tpu.ops.gated_delta_net import delta_rule_scan, l2norm  # noqa: E402
+                                                 SUB_CHUNK_TOKENS)
+from flexflow_tpu.ops.chunked_delta_rule import unit_heads  # noqa: E402
+from flexflow_tpu.ops.gated_delta_net import delta_rule_scan  # noqa: E402
+from flexflow_tpu.ops.kimi_delta_attention import head_rms  # noqa: E402
 from flexflow_tpu.ops.pallas.chunked_delta_rule import (  # noqa: E402
-    delta_rule_chunked_kernel)
+    CHUNKED_RULES)
 
 
-def core(rule):
+def by_head(q, k, v, g, h):
+    """The flat operands by head, q~ and k~ through `l2norm`, in
+    jax.numpy: on the chip a copy and two passes a tensor."""
+    b, s = q.shape[:2]
+    q, k, v, g = (t.reshape(b, s, h, -1) for t in (q, k, v, g))
+    return (*unit_heads(q, k), v, g)
+
+
+def core(rule, h, flat=True):
     """The jitted value and gradient of one layer's core under `rule`
-    ((S, q, k, v, g, beta) -> (S, o)) from a zero state."""
+    (`CHUNKED_RULES`' signature but for chunk and sub-chunk, or the
+    scan's by-head one) from a zero state, fed flat; `flat=False`:
+    through `by_head` first, and flat again for a rule of the table."""
+    table = rule is not delta_rule_scan
+
     def loss(q, k, v, g, beta, probe):
-        b, _, h, d = q.shape
-        _, o = rule(jnp.zeros((b, h, d, v.shape[-1]), jnp.float32),
-                    q, k, v, g, beta)
-        return jnp.sum(o.astype(jnp.float32) * probe)
+        b, s, hd = q.shape
+        S = jnp.zeros((b, h, hd // h, hd // h), jnp.float32)
+        if not flat:
+            q, k, v, g = by_head(q, k, v, g, h)
+            if table:
+                q, k, v, g = (t.reshape(b, s, hd) for t in (q, k, v, g))
+        _, o = rule(S, q, k, v, g, beta)
+        return jnp.sum(o.astype(jnp.float32).reshape(probe.shape) * probe)
 
     return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
 
 
-def shapes(b, s, h, d, dtype):
-    f32 = jnp.float32
-    return [((b, s, h, d), dtype), ((b, s, h, d), dtype),
-            ((b, s, h, d), dtype), ((b, s, h, d), f32), ((b, s, h), f32),
-            ((b, s, h, d), f32)]
-
-
-def values(b, s, h, d, dtype):
+def values(b, s, h, d):
     ks = jax.random.split(jax.random.key(0), 6)
-    q = l2norm(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
-    k = l2norm(jax.random.normal(ks[1], (b, s, h, d)))
-    v = jax.nn.silu(jax.random.normal(ks[2], (b, s, h, d)))
+    q, k, v = (jax.nn.silu(jax.random.normal(key, (b, s, h * d)))
+               for key in ks[:3])
     # a channel forgets over tens to thousands of positions
-    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, h, d), jnp.float32,
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, h * d), jnp.float32,
                                     jnp.log(1e-3), jnp.log(1e-1))) * 4.0
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
-    probe = jax.random.normal(ks[5], (b, s, h, d))
-    return [q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta,
-            probe]
+    probe = jax.random.normal(ks[5], (b, s, h * d))
+    return [q, k, v, g, beta, probe]
+
+
+def output_side(h, eps, flat=True):
+    """What `KimiDeltaAttention` does with the core's output `o` (the
+    compute precision), value and gradient: the head norm, the gate and
+    the output projection.  `flat`: the op's, `head_rms` on `[b, s,
+    h d]`; else by head as before PR 45 (o `[b, s, h, d]`, the gate
+    reshaped to it, y reshaped back for the product)."""
+    f32 = jnp.float32
+
+    def loss(o, gate, norm_w, w_o, probe):
+        o = o.astype(f32)
+        if flat:
+            y = head_rms(o, h, eps) * jnp.tile(norm_w, h) \
+                * jax.nn.sigmoid(gate)
+        else:
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + eps)
+            y = (o * norm_w * jax.nn.sigmoid(gate.reshape(o.shape))) \
+                .reshape(gate.shape)
+        y = jax.lax.optimization_barrier(y.astype(w_o.dtype))
+        return jnp.sum(jnp.matmul(y, w_o).astype(f32) * probe)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+
+
+def output_values(b, s, h, d, e, flat=True):
+    ks = jax.random.split(jax.random.key(1), 4)
+    bf = jnp.bfloat16
+    o = jax.random.normal(ks[0], (b, s, h * d) if flat else (b, s, h, d))
+    return [o.astype(bf), jax.random.normal(ks[1], (b, s, h * d)),
+            jnp.ones((d,)), (jax.random.normal(ks[2], (h * d, e))
+                             * (h * d) ** -0.5).astype(bf),
+            jax.random.normal(ks[3], (b, s, e))]
 
 
 def by_kind(compiled, vals, top: int) -> str:
@@ -122,6 +171,9 @@ def main() -> int:
                     help="the Pallas kernels, a variant each: heads a grid "
                          "program of the walk over chunks holds (the op's "
                          "chunk and sub-chunk); empty: no kernel variant")
+    ap.add_argument("--output-side", action="store_true",
+                    help="also the op's norm_gate and out, fed o flat "
+                         "(the op's) and by head (as before PR 45)")
     ap.add_argument("--scan", type=int, default=0,
                     help="also the scan a position, at this many positions")
     ap.add_argument("--iters", type=int, default=10)
@@ -137,25 +189,41 @@ def main() -> int:
     bf = jnp.bfloat16
     pairs = [tuple(int(n) for n in p.split(":"))
              for p in filter(None, args.chunks.split(","))]
+    def fed(seq):
+        return functools.partial(values, b, seq, h, d)
+
+    # name -> (jitted value and gradient, positions, what makes its values)
     variants = {
         f"chunked C={c} sub={sub}"
-        + (" (the op's)" if (c, sub) == (CHUNK_TOKENS, SUB_CHUNK_TOKENS)
-           else ""):
-        (core(lambda *a, c=c, sub=sub: delta_rule_chunked(
-            *a, c, sub, operand_dtype=bf)), s)
+        + (" (the op's off the chip)"
+           if (c, sub) == (CHUNK_TOKENS, SUB_CHUNK_TOKENS) else ""):
+        (core(functools.partial(CHUNKED_RULES["chunked"], chunk=c, sub=sub,
+                                operand_dtype=bf), h), s, fed(s))
         for c, sub in pairs}
     for hb in filter(None, args.kernel_heads.split(",")):
         # off the chip (--compile-only) the kernels are lowered for
         # Mosaic all the same: `interpret` is the default's only there
-        variants[f"kernels C={CHUNK_TOKENS} sub={SUB_CHUNK_TOKENS} "
-                 f"heads a program of the walk={hb}"] = (
-            core(functools.partial(
-                delta_rule_chunked_kernel, chunk=CHUNK_TOKENS,
-                sub=SUB_CHUNK_TOKENS, operand_dtype=bf,
-                heads_block=int(hb), interpret=False)), s)
+        kernels = functools.partial(
+            CHUNKED_RULES["chunked_kernel"], chunk=CHUNK_TOKENS,
+            sub=SUB_CHUNK_TOKENS, operand_dtype=bf, heads_block=int(hb),
+            interpret=False)
+        name = (f"kernels C={CHUNK_TOKENS} sub={SUB_CHUNK_TOKENS} heads a "
+                f"program of the walk={hb}, ")
+        variants[name + "flat, l2norm in the kernels (the op's)"] = (
+            core(kernels, h), s, fed(s))
+        variants[name + "by head and l2norm in jax.numpy first"] = (
+            core(kernels, h, flat=False), s, fed(s))
     if args.scan:
         variants[f"scan a position, {args.scan} positions"] = (
-            core(delta_rule_scan), args.scan)
+            core(delta_rule_scan, h, flat=False), args.scan,
+            fed(args.scan))
+    if args.output_side:
+        for flat, name in ((True, "flat, head_rms's two products (the op's)"),
+                           (False, "by head, reshapes around the norm")):
+            variants["norm_gate and out, " + name] = (
+                output_side(h, cfg["rms_norm_eps"], flat), s,
+                functools.partial(output_values, b, s, h, d,
+                                  cfg["hidden_size"], flat))
 
     # the floors of ONE layer, by the benchmark's own count
     layers = len(lin["kda_layers"])
@@ -175,9 +243,9 @@ def main() -> int:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         sh = SingleDeviceSharding(topo.devices[0])
-        for name, (fn, seq) in variants.items():
-            structs = [jax.ShapeDtypeStruct(sp, dt, sharding=sh)
-                       for sp, dt in shapes(b, seq, h, d, bf)]
+        for name, (fn, seq, make) in variants.items():
+            structs = [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh)
+                       for v in jax.eval_shape(make)]
             t0 = time.monotonic()
             m = fn.trace(*structs).lower(
                 lowering_platforms=("tpu",)).compile().memory_analysis()
@@ -190,8 +258,8 @@ def main() -> int:
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
                       "batch": b, "seq": s, "heads": h, "head_dim": d}),
           flush=True)
-    for name, (fn, seq) in variants.items():
-        vals = values(b, seq, h, d, bf)
+    for name, (fn, seq, make) in variants.items():
+        vals = make()
         try:
             t0 = time.monotonic()
             compiled = fn.lower(*vals).compile()

@@ -150,19 +150,45 @@ def test_heads_per_block_keeps_the_state_block_inside_default_vmem():
 
 # -- the training kernels: a chunk of positions at a time ----------------------
 def recurrence_inputs(s, per_channel, strong, b=1, h=2, dk=128, dv=128):
-    """As tests/test_kimi_linear.py's, at heads of one 128-lane tile."""
+    """As tests/test_kimi_linear.py's, at heads of one 128-lane tile and
+    in `CHUNKED_RULES`' layout, as the ops hand them over: q~, k~ as a
+    conv leaves them (no unit rows) and v flat, `[b, s, h d]`; g one
+    decay a channel, flat too, or one a head `[b, s, h]` (which
+    `the_rule` repeats as `GatedDeltaNet` does)."""
     keys = jax.random.split(jax.random.key(0), 8)
-    g_shape = (b, s, h, dk) if per_channel else (b, s, h)
+    g_shape = (b, s, h * dk) if per_channel else (b, s, h)
     return dict(
         S=jax.random.normal(keys[5], (b, h, dk, dv)),
-        q=l2norm(jax.random.normal(keys[0], (b, s, h, dk))) * dk ** -0.5,
-        k=l2norm(jax.random.normal(keys[1], (b, s, h, dk))),
-        v=jax.random.normal(keys[2], (b, s, h, dv)),
+        q=jax.random.normal(keys[0], (b, s, h * dk)),
+        k=jax.random.normal(keys[1], (b, s, h * dk)),
+        v=jax.random.normal(keys[2], (b, s, h * dv)),
         g=-jax.nn.softplus(jax.random.normal(keys[3], g_shape))
         * (40.0 if strong else 1.0),
         beta=jax.nn.sigmoid(jax.random.normal(keys[4], (b, s, h))),
-    ), (jax.random.normal(keys[6], (b, s, h, dv)),
+    ), (jax.random.normal(keys[6], (b, s, h * dv)),
         jax.random.normal(keys[7], (b, h, dk, dv)))
+
+
+def scanned_flat(S, q, k, v, g, beta):
+    """The scan a position fed what the flat hand-over means: the
+    operands by head, `l2norm(q~) / sqrt(dk)` and `l2norm(k~)`."""
+    (b, s), (_, h, dk, _) = q.shape[:2], S.shape
+    q, k, v = (t.reshape(b, s, h, -1) for t in (q, k, v))
+    if g.shape[2:] != (h,):
+        g = g.reshape(b, s, h, dk)
+    S, o = scanned(S, l2norm(q) * dk ** -0.5, l2norm(k), v, g, beta)
+    return S, o.reshape(b, s, -1)
+
+
+def the_rule(entry, **kw):
+    """`CHUNKED_RULES[entry]` at the cell's chunk and sub-chunk; one
+    decay a head is repeated over the head's channels first."""
+    def rule(S, q, k, v, g, beta):
+        if g.shape[2] == beta.shape[2]:
+            g = jnp.repeat(g, S.shape[2], axis=2)
+        return cdk.CHUNKED_RULES[entry](S, q, k, v, g, beta, 64, 16, **kw)
+
+    return rule
 
 
 def value_and_gradient(rule, xs, probe_o, probe_s):
@@ -176,6 +202,7 @@ def value_and_gradient(rule, xs, probe_o, probe_s):
     return out, grads
 
 
+@pytest.mark.parametrize("entry", list(cdk.CHUNKED_RULES))
 @pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
 @pytest.mark.parametrize("decay", ["per_channel", "per_head"])
 @pytest.mark.parametrize("seq, heads_block", [
@@ -183,23 +210,22 @@ def value_and_gradient(rule, xs, probe_o, probe_s):
     (150, 1),     # a ragged last chunk, a head a program
 ])
 def test_chunk_kernels_equal_the_scan_forward_and_gradient(
-        seq, heads_block, decay, strong):
-    """The Pallas walk over the chunks (interpreted) from a NONZERO
-    starting state, at the cell's chunk and heads of one 128-lane tile.
-    Strong: a channel decays past e^-88 inside a chunk; the value and
-    the gradient stay finite and equal."""
+        seq, heads_block, decay, strong, entry):
+    """The table's entries, the Pallas kernels (interpreted) and the
+    jax.numpy rule behind one signature, from a NONZERO starting state,
+    at the cell's chunk and heads of one 128-lane tile, against the scan
+    a position fed `l2norm`ed q and k: the l2norm is the rule's (in the
+    operands' kernels), and the gradients are q~'s and k~'s.  Strong: a
+    channel decays past e^-88 inside a chunk; the value and the
+    gradient stay finite and equal."""
     xs, probes = recurrence_inputs(seq, decay == "per_channel", strong)
     if strong:
         total = np.cumsum(np.asarray(xs["g"], np.float64), axis=1)
         assert total[:, :64].min() < -88.0
-
-    def kernels(*a):
-        return cdk.delta_rule_chunked_kernel(*a, 64, 16,
-                                             heads_block=heads_block)
-
-    (s_want, o_want), g_want = value_and_gradient(scanned, xs,
-                                                  *probes)
-    (s_got, o_got), g_got = value_and_gradient(kernels, xs, *probes)
+    rule = the_rule(entry, **({"heads_block": heads_block}
+                              if entry == "chunked_kernel" else {}))
+    (s_want, o_want), g_want = value_and_gradient(scanned_flat, xs, *probes)
+    (s_got, o_got), g_got = value_and_gradient(rule, xs, *probes)
     close(o_got, o_want)
     close(s_got, s_want)
     for name in ("S", "q", "k", "v", "g", "beta"):
@@ -207,20 +233,19 @@ def test_chunk_kernels_equal_the_scan_forward_and_gradient(
         close(g_got[name], g_want[name], 1e-4 if name == "g" else 2e-5)
 
 
-def test_chunk_kernels_with_rounded_operands_stay_near_the_scan():
+@pytest.mark.parametrize("decay", ["per_channel", "per_head"])
+def test_chunk_kernels_with_rounded_operands_stay_near_the_scan(decay):
     """bf16 operands (what the chip runs) through the kernels: the
     output within the jax.numpy rule's distance of the scan, and the
-    gradient within a bf16 rounding of the jax.numpy rule's own."""
-    xs, probes = recurrence_inputs(150, True, False)
+    gradient within a bf16 rounding of the jax.numpy rule's own (the
+    table's two entries, one signature)."""
+    xs, probes = recurrence_inputs(150, decay == "per_channel", False)
     bf = jnp.bfloat16
-    (_, o_want), _ = value_and_gradient(scanned, xs, *probes)
+    (_, o_want), _ = value_and_gradient(scanned_flat, xs, *probes)
     (s_jnp, o_jnp), g_jnp = value_and_gradient(
-        lambda *a: cdr.delta_rule_chunked(*a, 64, 16, operand_dtype=bf),
-        xs, *probes)
+        the_rule("chunked", operand_dtype=bf), xs, *probes)
     (s_got, o_got), g_got = value_and_gradient(
-        lambda *a: cdk.delta_rule_chunked_kernel(*a, 64, 16,
-                                                 operand_dtype=bf),
-        xs, *probes)
+        the_rule("chunked_kernel", operand_dtype=bf), xs, *probes)
     close(o_got, o_want, 3e-2)
     close(o_got, o_jnp, 1e-2)
     close(s_got, s_jnp, 1e-2)
@@ -237,14 +262,17 @@ def test_chunk_kernels_refuse_the_interpreter_on_a_tpu(monkeypatch):
             interpret=True)
 
 
-@pytest.mark.parametrize("heads, want, tile", [
-    (32, 8, 8), (16, 8, 8), (12, 6, 12), (7, 7, 7), (2, 2, 2), (1, 1, 1),
-    (22, 2, 22)])
-def test_heads_per_program_divides_the_rows_heads(heads, want, tile):
-    """The walk's programs hold up to 8 heads that divide the row's; the
-    operands' programs one 8-sublane tile of heads, or all of them."""
+@pytest.mark.parametrize("heads, want", [
+    (32, 8), (16, 8), (12, 6), (7, 7), (2, 2), (1, 1), (22, 2)])
+def test_heads_per_program_divides_the_rows_heads(heads, want):
+    """A grid program (the walk's and the operands') holds up to 8 heads
+    that divide the row's; an operand's flat block is then that many
+    column blocks of a head's width."""
     assert cdk.heads_per_program(heads) == want
-    assert cdk._operand_heads(heads) == tile
+    (spec,) = cdk._specs("p", [(1, 128, heads * 128)], want, (1, heads, 2),
+                         64, False)
+    assert spec.block_shape == (1, 64, want * 128)
+    assert spec.index_map(0, 1, 1) == (0, 1, 1)
 
 
 @pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
@@ -273,26 +301,37 @@ def test_pairs_tile_equals_the_chunk_matrices_and_its_gradient_is_jaxs(
         close(got, want, 1e-5)
 
 
-def test_operands_tile_gradient_is_jaxs():
-    """The tile that also scales by beta and builds the solve's system
-    and right side: its hand-written gradient (q, k, v, g, beta) against
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+def test_operands_tile_gradient_is_jaxs(strong):
+    """The tile that takes the convs' q~, k~, normalises them (the
+    `l2norm` of the ops, q also by 1 / sqrt(dk)), scales by beta and
+    builds the solve's system and right side: its value from
+    `_pairs_tile` of the unit rows, and its hand-written gradient
+    (q~, k~, v, g, beta; the norm's by hand, `_l2norm_bwd`) against
     jax's own; `A` is a residual and takes no cotangent."""
     C, dk, sub = 64, 128, 16
     keys = jax.random.split(jax.random.key(2), 12)
-    q = l2norm(jax.random.normal(keys[0], (C, dk))) * dk ** -0.5
-    k = l2norm(jax.random.normal(keys[1], (C, dk)))
+    q, k = (3.0 * jax.random.normal(key, (C, dk)) for key in keys[:2])
     v = jax.random.normal(keys[2], (C, dk))
-    g = -jax.nn.softplus(jax.random.normal(keys[3], (C, dk)))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (C, dk))) \
+        * (40.0 if strong else 1.0)
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (C, 1)))
     (A, *outs), vjp = jax.vjp(lambda *a: cdk._operands_tile(*a, sub),
                               q, k, v, g, beta)
-    close(outs[0] - jnp.eye(C), beta * A)
+    a, b, qg, kg, kt, shrink = cdk._pairs_tile(
+        l2norm(q) * dk ** -0.5, l2norm(k),
+        jnp.matmul(cdk._tril(C), g, precision="highest"), sub)
+    for got, want in zip((A, *outs), (
+            a, jnp.eye(C) + beta * a, jnp.concatenate([beta * v, beta * kg], 1),
+            b, qg, kt, shrink)):
+        close(got, want)
     cts = [jax.random.normal(key, o.shape) for key, o in zip(keys[5:], outs)]
     for got, want in zip(
             cdk._operands_tile_bwd(q, k, v, g, beta, sub, A, *cts),
             vjp((jnp.zeros_like(A), *cts))):
         close(got, want, 1e-5)
-
+    dy = cts[3]  # [C, dk]
+    close(cdk._l2norm_bwd(q, dy), jax.vjp(l2norm, q)[1](dy)[0])
 
 
 # -- the ops that take the training kernels ------------------------------------
@@ -349,6 +388,53 @@ def test_kda_op_takes_the_chunk_kernels_where_pick_recurrence_says_so(
     assert calls == [(64, 16)]
     assert op.chunk_tokens(80) == 64
     assert (op.recurrence_plan(40), op.chunk_tokens(40)) == ("chunked", 48)
+
+
+def _equations(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs its equations hold
+    (jitted calls, checkpoints), but a `pallas_call`'s own body: what
+    XLA is handed."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def test_kda_on_the_kernel_plan_never_forms_a_by_head_tensor(monkeypatch):
+    """On the kernel plan the op hands XLA no `[b, s, h, d]` at all,
+    forward or backward (on the chip that reshape of a float32 tensor
+    is a copy): q~, k~, v, g go to the operands' kernels flat as the
+    convs and the decay projection leave them, their gradients come
+    back so, and `o`, the head norm and the gate stay `[b, s, h d]`."""
+    b, s, h, d, e = 1, 128, 2, 128, 32
+    ff = FFModel(FFConfig(batch_size=b, num_devices=1))
+    op = ff.kimi_delta_attention(
+        ff.create_tensor([b, s, e], name="x"),
+        KimiDeltaAttentionParams(embed_dim=e, num_heads=h, head_dim=d),
+        name="op").owner_op
+    as_on_a_tpu(monkeypatch, kda_op)
+    assert op.recurrence_plan(s) == "chunked_kernel"
+    w = [jnp.ones([dim.size for dim in spec.shape.dims
+                   if not dim.is_replica_dim]) for spec in op.weight_specs]
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x, w: jnp.sum(
+        op.forward([x], w, training=True)[0]), argnums=(0, 1)))(
+            jnp.ones((b, s, e)), w)
+    flat, kernels = (b, s, h * d), {}
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernels[eqn.params["name"]] = eqn
+            continue
+        for v in eqn.outvars:
+            # (weight-sized `[h, d]`, A_log over a head's channels, is none)
+            assert len(v.aval.shape) < 3 or v.aval.shape[-2:] != (h, d), eqn
+    # (the backward kernels were reached: the walk went through both)
+    fwd, bwd, walk, back = (kernels[f"delta_rule_{n}"] for n in (
+        "operands_fwd", "operands_bwd", "chunks_fwd", "chunks_bwd"))
+    assert [v.aval.shape for v in fwd.invars[:4]] == [flat] * 4
+    assert [v.aval.shape for v in bwd.invars[:4]] == [flat] * 4
+    assert [v.aval.shape for v in bwd.outvars[:4]] == [flat] * 4
+    assert walk.outvars[1].aval.shape == back.invars[-1].aval.shape == flat
 
 
 def test_stateless_gated_delta_net_shares_the_chunk_kernels(monkeypatch):
