@@ -14,6 +14,8 @@ through jax.config, which holds until the first device query and keeps
 `backends()` from ever creating a TPU client.
 """
 import os
+import shutil
+import tempfile
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -28,6 +30,33 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+
+# One XLA compile cache a RUN (PR 46): identical programs (a toy model's
+# weight maker and step built anew by a test, `jit(_normal)` and friends
+# in every file) compile once a run, not once a worker and test: a fifth
+# of its CPU-seconds.  The directory is the run's own: made by the
+# process that owns the run (xdist's controller, or the one process of a
+# run without workers), handed to the workers, removed at the end.
+def pytest_configure(config):
+    if hasattr(config, "workerinput"):  # an xdist worker
+        path = config.workerinput["xla_cache"]
+    else:
+        path = config._xla_cache = tempfile.mkdtemp(prefix="t1-xla-")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    node.workerinput["xla_cache"] = node.config._xla_cache
+
+
+def pytest_unconfigure(config):
+    path = getattr(config, "_xla_cache", None)
+    if path is not None:
+        shutil.rmtree(path, ignore_errors=True)
 
 
 @pytest.fixture(scope="session")

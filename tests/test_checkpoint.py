@@ -2,6 +2,7 @@
 cross-mesh restore, and the plain npz weight path."""
 import numpy as np
 import pytest
+from _family import weights_equal
 
 from flexflow_tpu import FFConfig, FFModel, LossType, MetricsType, SGDOptimizer
 from flexflow_tpu.checkpoint import (
@@ -33,14 +34,6 @@ def _data(n=64, seed=0):
     return xs, ys
 
 
-def _weights_equal(a, b):
-    import jax
-
-    fa, fb = jax.tree.leaves(a), jax.tree.leaves(b)
-    assert len(fa) == len(fb)
-    for x, y in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
 
 def test_save_restore_round_trip(devices8, tmp_path):
     ff = _model(devices8)
@@ -55,7 +48,7 @@ def test_save_restore_round_trip(devices8, tmp_path):
     ff.fit(xs, ys, epochs=1, verbose=False)  # diverge
     step = mgr.restore(ff)
     assert step == 1
-    _weights_equal(ff.get_weights(), saved)
+    weights_equal(ff.get_weights(), saved)
     meta = mgr.restore_meta()
     assert meta["step"] == 1 and meta["num_devices"] == 8
     mgr.close()
@@ -76,7 +69,7 @@ def test_resume_training_is_deterministic(devices8, tmp_path):
     mgr.restore(ff_b)
     ff_b.fit(xs, ys, epochs=2, verbose=False)
 
-    _weights_equal(ff_a.get_weights(), ff_b.get_weights())
+    weights_equal(ff_a.get_weights(), ff_b.get_weights())
     mgr.close()
 
 
@@ -90,7 +83,7 @@ def test_cross_mesh_restore(devices8, tmp_path):
 
     ff1 = _model(devices8[:1], seed=5)
     mgr.restore(ff1)
-    _weights_equal(ff1.get_weights(), ff8.get_weights())
+    weights_equal(ff1.get_weights(), ff8.get_weights())
 
     y8 = np.asarray(ff8.forward({"x": xs[:16]}))
     y1 = np.asarray(ff1.forward({"x": xs[:16]}))
@@ -108,7 +101,7 @@ def test_npz_weights_round_trip(devices8, tmp_path):
 
     ff.fit(xs, ys, epochs=1, verbose=False)
     load_weights_npz(ff, path)
-    _weights_equal(ff.get_weights(), saved)
+    weights_equal(ff.get_weights(), saved)
 
 
 def test_local_manager_round_trip_and_retention(devices8, tmp_path):
@@ -128,7 +121,7 @@ def test_local_manager_round_trip_and_retention(devices8, tmp_path):
     ff.fit(xs, ys, epochs=1, verbose=False)  # diverge
     step = mgr.restore(ff)
     assert step == 1
-    _weights_equal(ff.get_weights(), saved)
+    weights_equal(ff.get_weights(), saved)
 
     # keep-last-k pruning: saving steps 2 and 3 drops step 1
     mgr.save(ff, step=2)
@@ -159,7 +152,7 @@ def test_local_manager_corrupt_latest_falls_back(devices8, tmp_path):
     ff.fit(xs, ys, epochs=1, verbose=False)  # diverge further
     step = mgr.restore(ff)
     assert step == 1
-    _weights_equal(ff.get_weights(), w1)
+    weights_equal(ff.get_weights(), w1)
 
     # an explicitly requested corrupt step stays strict
     import pytest as _pytest
@@ -178,7 +171,7 @@ def test_local_manager_cross_mesh_restore(devices8, tmp_path):
 
     ff1 = _model(devices8[:1], seed=5)
     mgr.restore(ff1)
-    _weights_equal(ff1.get_weights(), ff8.get_weights())
+    weights_equal(ff1.get_weights(), ff8.get_weights())
     y8 = np.asarray(ff8.forward({"x": xs[:16]}))
     y1 = np.asarray(ff1.forward({"x": xs[:16]}))
     np.testing.assert_allclose(y8, y1, rtol=2e-5, atol=2e-5)
@@ -201,7 +194,7 @@ def test_orbax_restore_falls_back_on_corrupt(devices8, tmp_path):
     ff.fit(xs, ys, epochs=1, verbose=False)
     step = mgr.restore(ff)
     assert step == 1
-    _weights_equal(ff.get_weights(), w1)
+    weights_equal(ff.get_weights(), w1)
     mgr.close()
 
 

@@ -10,6 +10,7 @@ import re
 import jax
 import numpy as np
 import pytest
+from _family import config
 
 from benchmarks.run import load_module
 from flexflow_tpu import (AdamOptimizer, FFConfig, FFModel, LossType,
@@ -42,8 +43,7 @@ def op_names(compiled, only=None):
 
 
 def toy(name):
-    with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")) as f:
-        cfg = json.load(f)
+    cfg = config(name + ".json")
     return cfg, load_module("families", cfg["family"])
 
 

@@ -11,6 +11,7 @@ import time
 
 import numpy as np
 import pytest
+from _family import weights_equal
 
 from flexflow_tpu import (
     FFConfig,
@@ -61,14 +62,6 @@ def _data(n=128, seed=0):
     return xs, ys
 
 
-def _weights_equal(a, b):
-    import jax
-
-    fa, fb = jax.tree.leaves(a), jax.tree.leaves(b)
-    assert len(fa) == len(fb)
-    for x, y in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
 
 # -- async verified saves ------------------------------------------------
 
@@ -86,7 +79,7 @@ def test_async_save_visible_after_drain(devices8, tmp_path):
     saved = ff.get_weights()
     ff.fit(xs, ys, epochs=1, verbose=False)  # diverge
     assert mgr.restore(ff) == 5
-    _weights_equal(ff.get_weights(), saved)
+    weights_equal(ff.get_weights(), saved)
     mgr.close()
 
 
@@ -143,7 +136,7 @@ def test_per_leaf_corruption_falls_back_to_verified(devices8, tmp_path):
 
     ff.fit(xs, ys, epochs=1, verbose=False)  # diverge further
     assert mgr.restore(ff) == 1
-    _weights_equal(ff.get_weights(), w1)
+    weights_equal(ff.get_weights(), w1)
     # the pointer re-committed to the step that actually verified
     assert mgr.latest_verified_step() == 1
     # an explicitly requested corrupt step stays strict
@@ -213,7 +206,7 @@ def test_supervisor_async_crash_restore_bit_identical(devices8, tmp_path):
     assert rep.final_step == rep_clean.final_step == 7
     assert rep.counters["restarts"] == 1
     assert rep.losses == rep_clean.losses
-    _weights_equal(ff_clean.get_weights(), ff.get_weights())
+    weights_equal(ff_clean.get_weights(), ff.get_weights())
     # post-run drain landed every queued save
     assert sup.manager.latest_verified_step() == 6
 
@@ -361,7 +354,7 @@ def test_hung_step_fault_recovers_bit_identical(devices8, tmp_path):
     assert rep.counters["device_losses"] == 0  # classified, not conflated
     assert ff.mesh.devices.size == 8  # full mesh: nothing was lost
     assert rep.losses == rep_clean.losses
-    _weights_equal(ff_clean.get_weights(), ff.get_weights())
+    weights_equal(ff_clean.get_weights(), ff.get_weights())
 
 
 def test_hung_step_exhausts_restart_budget(devices8, tmp_path):
@@ -451,7 +444,7 @@ def test_sigterm_emergency_save_round_trip(devices8, tmp_path):
     rep2 = sup2.run(xs, ys, num_steps=7, resume=True)
     assert rep2.final_step == 7
     assert rep2.preempted is None
-    _weights_equal(ff_clean.get_weights(), ff2.get_weights())
+    weights_equal(ff_clean.get_weights(), ff2.get_weights())
     assert rep_clean.losses[4:] == rep2.losses  # replayed tail matches
 
 
@@ -514,8 +507,8 @@ def test_sigterm_zero1_sharded_slots_restore(devices8, tmp_path):
                  optimizer=AdamOptimizer(alpha=0.01))
     mgr = LocalCheckpointManager(str(tmp_path / "z"))
     assert mgr.restore(ff4) == rep.final_step
-    _weights_equal(ff4.get_weights(), saved_w)
-    _weights_equal(jax.tree.map(np.asarray, ff4._opt_state), saved_opt)
+    weights_equal(ff4.get_weights(), saved_w)
+    weights_equal(jax.tree.map(np.asarray, ff4._opt_state), saved_opt)
     # the restored model keeps training on the survivor mesh
     ff4.fit(xs, ys, epochs=1, verbose=False)
 

@@ -11,13 +11,11 @@ magnitude for one op, 2e-5 for logits that went through every layer.
 A bf16 run misses that by three orders of magnitude
 (`test_bf16_logits_leave_the_float32_tolerance`).
 """
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _family import Recorder, close, config, padded
 
 from benchmarks import reference as ref
 from benchmarks.families import kimi_k2 as fam
@@ -25,20 +23,11 @@ from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.config import ConfigError
 from flexflow_tpu.models.kimi_k2 import build_kimi_k2
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmarks", "configs", "toy-kimi.json")) as f:
-    CFG = json.load(f)
+CFG = config("toy-kimi.json")
 D = fam.dims(CFG)
 SEED = 11
 KEY = ref.seed_key(SEED)
 OP_TOL, LOGIT_TOL = 1e-5, 2e-5
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    assert got.shape == want.shape
-    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
-    assert err <= tol, err
 
 
 def holder(cfg=CFG, seq=None, precision=None, **ffconfig):
@@ -57,8 +46,8 @@ def holder(cfg=CFG, seq=None, precision=None, **ffconfig):
 
 def reference_logits(tokens):
     return np.asarray(fam.logits_fn(
-        fam.make_weights(CFG, SEED, "reference"),
-        jnp.asarray(tokens, jnp.int32), "float32"))
+        fam.make_weights(CFG, SEED, "reference"), padded(tokens),
+        "float32"))[:len(tokens)]
 
 
 # -- 1. each op alone ----------------------------------------------------------
@@ -120,22 +109,7 @@ def test_op_alone_matches_the_reference(name):
 
 
 # -- 2. prefill then decode through the paged latent cache -----------------------
-class Recorder:
-    """Wraps a scheduler's model so that every decode dispatch's logits
-    are kept beside (request, position) of the row they belong to."""
 
-    def __init__(self, sched):
-        self.sched, self.rows, model = sched, [], sched.model
-        inner = model.step
-
-        def step(tokens, seq_lens, block_tables):
-            logits = inner(tokens, seq_lens, block_tables)
-            for i, live in enumerate(sched._slots):
-                if live is not None:
-                    self.rows.append((live.req, live.pos, logits[i].copy()))
-            return logits
-
-        model.step = step
 
 
 @pytest.fixture(scope="module")

@@ -15,6 +15,7 @@ import zlib
 
 import numpy as np
 import pytest
+from _family import weights_equal
 
 from flexflow_tpu import (
     FFConfig,
@@ -70,14 +71,6 @@ def _data(n=128, seed=0):
     ys = rng.randint(0, 4, size=n).astype(np.int32)
     return xs, ys
 
-
-def _weights_equal(a, b):
-    import jax
-
-    fa, fb = jax.tree.leaves(a), jax.tree.leaves(b)
-    assert len(fa) == len(fb)
-    for x, y in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def _offloader(blob, **kw):
@@ -441,7 +434,7 @@ def test_restore_prefers_local_falls_back_per_checkpoint(devices8, tmp_path):
     )
     step = mgr.restore(ff)
     assert step == 6  # the SAME step, served by the mirror
-    _weights_equal(ff.get_weights(), w6)
+    weights_equal(ff.get_weights(), w6)
     # and the mirror's verified bytes were re-materialized locally
     assert LocalCheckpointManager(ckpt).restore(ff) == 6
 
@@ -499,7 +492,7 @@ def test_fresh_host_restores_from_remote_only(devices8, tmp_path):
     assert mgr.any_restorable()
     step = mgr.restore(ff2)
     assert step == 4
-    _weights_equal(ff2.get_weights(), w4)
+    weights_equal(ff2.get_weights(), w4)
 
 
 def test_orbax_restore_prefers_newer_remote_step(devices8, tmp_path):
@@ -522,7 +515,7 @@ def test_orbax_restore_prefers_newer_remote_step(devices8, tmp_path):
     mgr.save(ff2, step=2)
     step = mgr.restore(ff2)
     assert step == 6  # the newer remote-only step wins
-    _weights_equal(ff2.get_weights(), w6)
+    weights_equal(ff2.get_weights(), w6)
     mgr.close()
 
 
@@ -572,11 +565,11 @@ def test_host_loss_drill_bit_identical(devices8, tmp_path):
     assert rep_b.final_step == 8
     assert rep_b.counters["restarts"] == 0  # resume, not crash-recovery
 
-    _weights_equal(ff_b.get_weights(), ref.get_weights())
+    weights_equal(ff_b.get_weights(), ref.get_weights())
     # ZeRO-1 optimizer slots carried bit-identically too
     import jax
 
-    _weights_equal(
+    weights_equal(
         jax.tree.map(np.asarray, ff_b._opt_state),
         jax.tree.map(np.asarray, ref._opt_state),
     )

@@ -11,33 +11,22 @@ they differ by rounding only: 2e-5 of the compared tensor's largest
 magnitude for logits that went through every layer of every pass, 1e-4
 for a gradient (a sum over the passes of products of such values).
 """
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _family import Recorder, close, config, padded
 
 from benchmarks.families import ouro as fam
 from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
 from flexflow_tpu.config import ConfigError
 from flexflow_tpu.models.ouro import build_ouro
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmarks", "configs", "toy-ouro.json")) as f:
-    CFG = json.load(f)
+CFG = config("toy-ouro.json")
 D = fam.dims(CFG)
 SEED = 11
 LOGIT_TOL, GRAD_TOL = 2e-5, 1e-4
 HELD = fam.held_weights(CFG, SEED)
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    assert got.shape == want.shape
-    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
-    assert err <= tol, err
 
 
 def program_weights():
@@ -46,9 +35,16 @@ def program_weights():
     return jax.tree.map(np.array, fam.to_program_layout(HELD))
 
 
+@jax.jit
+def forward(ids):
+    return fam.forward(HELD, ids, d=D)
+
+
 def reference(ids):
-    """(logits [s, vocab], exit pdf [T, s]) of the plain reference."""
-    return fam.forward(HELD, jnp.asarray(ids, jnp.int32), d=D)
+    """(logits [s, vocab], exit pdf [T, s]) of the plain reference, one
+    program for several lengths (`padded`)."""
+    logits, pdf = forward(padded(ids))
+    return logits[:len(ids)], pdf[:, :len(ids)]
 
 
 def trainer(batch=2, seq=12, **ffconfig):
@@ -129,7 +125,7 @@ def test_gradient_through_the_region_is_the_sum_over_the_passes():
                 logp, jnp.asarray(labels[b])[:, None], axis=-1))
         return jnp.mean(jnp.stack(nll))
 
-    want = fam.to_program_layout(jax.grad(loss)(HELD))
+    want = fam.to_program_layout(jax.jit(jax.grad(loss))(HELD))
     seen = 0
     for op, entries in want.items():
         for k, g in entries.items():
@@ -312,23 +308,7 @@ def test_the_simulator_prices_a_region_at_its_passes():
 
 
 # -- 4. served: a plane a pass under one block table ---------------------------------------
-class Recorder:
-    """Wraps a scheduler's model so that every decode dispatch's logits
-    and exit pdf are kept beside (request, position) of their row."""
 
-    def __init__(self, sched):
-        self.sched, self.rows, model = sched, [], sched.model
-        inner = model.step
-
-        def step(*args):
-            logits = inner(*args)
-            for i, live in enumerate(sched._slots):
-                if live is not None:
-                    self.rows.append((live.req, live.pos, logits[i].copy(),
-                                      model.exit_last[i].copy()))
-            return logits
-
-        model.step = step
 
 
 def serve_toy(paged_kernel):
@@ -344,7 +324,7 @@ def serve_toy(paged_kernel):
         holder(), batch_slots=3, page_size=4, num_blocks=40,
         prefill_chunk=4, paged_kernel=paged_kernel,
         devices=jax.devices()[:1])
-    rec = Recorder(sched)
+    rec = Recorder(sched, lambda model, i: (model.exit_last[i].copy(),))
     since = len(trace.spans())
     try:
         rng = np.random.default_rng(5)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _family import trained_gpt
 
 from flexflow_tpu.config import ConfigError, FFConfig
 from flexflow_tpu.ops.pallas import paged_attention as pk
@@ -439,26 +440,7 @@ def test_dense_cache_rejects_kernel_selection():
 
 @pytest.fixture(scope="module")
 def trained(devices8):
-    from flexflow_tpu import FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.models.transformer import build_gpt
-
-    ff = FFModel(FFConfig(batch_size=B, num_devices=1))
-    build_gpt(ff, batch_size=B, seq_length=S, hidden_size=32,
-              num_layers=2, num_heads=4, intermediate_size=64,
-              vocab_size=V)
-    ff.compile(optimizer=SGDOptimizer(lr=0.5),
-               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-               devices=devices8[:1])
-    rng = np.random.RandomState(0)
-    start = rng.randint(0, V, (B, 1))
-    step = rng.randint(1, 6, (B, 1))
-    seq_ids = (start + step * np.arange(S + 1)) % V
-    ids = seq_ids[:, :-1].astype(np.int32)
-    labels = seq_ids[:, 1:].astype(np.int32)
-    pos = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S)).copy()
-    for _ in range(30):
-        ff.train_step({"input": ids, "positions": pos}, labels)
-    return ff, ids
+    return trained_gpt(devices8, B, S, V, steps=30)
 
 
 def _collect_avals(jaxpr, acc):

@@ -13,13 +13,12 @@ they differ by rounding only: 1e-5 of the compared tensor's largest
 magnitude for one op, 2e-5 for logits that went through every layer.
 """
 import contextlib
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _family import Recorder, close, config, padded
 
 from benchmarks import reference as ref
 from benchmarks.families import qwen3_next as fam
@@ -27,22 +26,12 @@ from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.config import ConfigError
 from flexflow_tpu.models.qwen3_next import build_qwen3_next
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmarks", "configs",
-                       "toy-qwen3-next.json")) as f:
-    CFG = json.load(f)
+CFG = config("toy-qwen3-next.json")
 D = fam.dims(CFG)
 SEED = 11
 KEY = ref.seed_key(SEED)
 OP_TOL, LOGIT_TOL = 1e-5, 2e-5
 LINEAR, FULL = 0, D.interval - 1  # a layer of each kind
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    assert got.shape == want.shape
-    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
-    assert err <= tol, err
 
 
 def holder(cfg=CFG, **ffconfig):
@@ -61,8 +50,8 @@ def holder(cfg=CFG, **ffconfig):
 
 def reference_logits(tokens):
     return np.asarray(fam.logits_fn(
-        fam.make_weights(CFG, SEED, "reference"),
-        jnp.asarray(tokens, jnp.int32), "float32"))
+        fam.make_weights(CFG, SEED, "reference"), padded(tokens),
+        "float32"))[:len(tokens)]
 
 
 # -- 1. each op alone ----------------------------------------------------------
@@ -132,22 +121,7 @@ def test_op_alone_matches_the_reference(name):
 
 
 # -- 2. prefill in chunks, then decode, through ServingFront's scheduler ---------
-class Recorder:
-    """Wraps a scheduler's model so that every decode dispatch's logits
-    are kept beside (request, position) of the row they belong to."""
 
-    def __init__(self, sched):
-        self.sched, self.rows, model = sched, [], sched.model
-        inner = model.step
-
-        def step(*args):
-            logits = inner(*args)
-            for i, live in enumerate(sched._slots):
-                if live is not None:
-                    self.rows.append((live.req, live.pos, logits[i].copy()))
-            return logits
-
-        model.step = step
 
 
 @contextlib.contextmanager
@@ -472,8 +446,7 @@ def test_state_predicates_keep_pages_and_slot_state_apart(twin):
 def test_state_sizes_at_published_widths():
     """From the ops' specs with the catalog's widths (no array is made):
     12.58 MB of delta-rule state and 4,096 B of keys and values a token."""
-    cfg = json.load(open(os.path.join(
-        ROOT, "benchmarks", "configs", "qwen3-next-ep4-serve.json")))
+    cfg = config("qwen3-next-ep4-serve.json")
     ff = FFModel(FFConfig(batch_size=64, num_devices=1))
     build_qwen3_next(ff, 64, 1, **fam.published(cfg), decode_max_seq=4096,
                      kv_page_size=16, kv_num_blocks=16385)

@@ -10,14 +10,13 @@ import functools
 import io
 import re
 import types
-import json
-import os
 from contextlib import redirect_stdout
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _family import config
 
 from benchmarks import check
 from benchmarks.families import lfm2_moe as fam
@@ -27,9 +26,7 @@ from flexflow_tpu.obs import trace
 from flexflow_tpu.ops import routed_experts as rx
 from flexflow_tpu.ops.pallas import flash_attention as fa
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmarks", "configs", "toy-lfm2.json")) as f:
-    CFG = json.load(f)
+CFG = config("toy-lfm2.json")
 B, S, SEED = 2, 16, 13
 LEVELS = list(_REMAT_POLICIES)
 
@@ -39,6 +36,13 @@ def grouped(monkeypatch):
     """The toy's routed layers take the grouped product (the choice is
     by shape; a test moves the threshold)."""
     monkeypatch.setattr(rx, "GROUPED_MIN_ROWS_PER_EXPERT", 1)
+
+
+@functools.cache
+def seeded():
+    """The seed's weights, made once, as host copies (a train step
+    donates what `set_weights` was given)."""
+    return jax.tree.map(np.asarray, fam.make_weights(CFG, SEED, "program"))
 
 
 def build(remat=True, keep=None, strategy=None):
@@ -55,7 +59,7 @@ def build(remat=True, keep=None, strategy=None):
                                        epsilon=o["epsilon"]),
                loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
                metrics=(), devices=jax.devices()[:1], strategy=strategy)
-    ff.set_weights(fam.make_weights(cfg, SEED, "program"))
+    ff.set_weights(seeded())
     if keep is not None:
         ff.executor.remat_keep = keep
     return ff

@@ -5,6 +5,7 @@ elastic re-search + recompile on a degraded mesh — all on the hermetic
 """
 import numpy as np
 import pytest
+from _family import weights_equal
 
 import flexflow_tpu
 from flexflow_tpu import (
@@ -51,14 +52,6 @@ def _data(n=128, seed=0):
     ys = rng.randint(0, 4, size=n).astype(np.int32)
     return xs, ys
 
-
-def _weights_equal(a, b):
-    import jax
-
-    fa, fb = jax.tree.leaves(a), jax.tree.leaves(b)
-    assert len(fa) == len(fb)
-    for x, y in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # -- fault plan / retry policy units ------------------------------------
@@ -138,7 +131,7 @@ def test_crash_restore_bit_identical(devices8, tmp_path, kind):
     rep_fault = fault.run(xs, ys, num_steps=7)
 
     assert rep_clean.final_step == rep_fault.final_step == 7
-    _weights_equal(ff_clean.get_weights(), ff_fault.get_weights())
+    weights_equal(ff_clean.get_weights(), ff_fault.get_weights())
     np.testing.assert_array_equal(
         np.asarray(jax.random.key_data(ff_clean._rng)),
         np.asarray(jax.random.key_data(ff_fault._rng)),
@@ -169,7 +162,7 @@ def test_seeded_fault_plan_run_bit_identical(devices8, tmp_path):
     assert rep.final_step == 10
     assert rep.counters["restarts"] == 2
     assert not plan.remaining()
-    _weights_equal(ff_clean.get_weights(), ff.get_weights())
+    weights_equal(ff_clean.get_weights(), ff.get_weights())
 
 
 def test_restart_budget_exhausted(devices8, tmp_path):
@@ -264,7 +257,7 @@ def test_nan_policy_restore_recovers_bit_identical(devices8, tmp_path):
     assert rep.final_step == 6
     assert rep.counters["restarts"] == 1
     assert all(np.isfinite(v) for v in rep.losses)
-    _weights_equal(ff_clean.get_weights(), ff.get_weights())
+    weights_equal(ff_clean.get_weights(), ff.get_weights())
 
 
 def test_skip_then_restore_losses_stay_aligned(devices8, tmp_path):
@@ -289,7 +282,7 @@ def test_skip_then_restore_losses_stay_aligned(devices8, tmp_path):
     assert rep.counters["skipped_steps"] == 1
     assert rep.counters["restarts"] == 1
     assert rep.losses == rep_clean.losses  # no duplicate/missing entries
-    _weights_equal(ff_clean.get_weights(), ff.get_weights())
+    weights_equal(ff_clean.get_weights(), ff.get_weights())
 
 
 # -- elastic recovery on a degraded mesh --------------------------------
@@ -345,7 +338,7 @@ def test_device_loss_carries_trained_state(devices8, tmp_path):
     step = sup.manager.restore(ff)
     assert step == 4
     assert ff.mesh.devices.size == 2
-    _weights_equal(ff.get_weights(), w4)
+    weights_equal(ff.get_weights(), w4)
 
 
 @pytest.mark.slow

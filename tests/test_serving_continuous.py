@@ -6,10 +6,9 @@ fault recovery through the donated-state reset path, and the Poisson
 a mixed-length burst end to end."""
 import numpy as np
 import pytest
+from _family import trained_gpt
 
-from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.decoding import build_paged_decode_step, make_decoder
-from flexflow_tpu.models.transformer import build_gpt
 from flexflow_tpu.serving import ContinuousScheduler, GenerationEngine
 
 pytestmark = pytest.mark.slow  # search/train-heavy: full tier only
@@ -19,23 +18,7 @@ V, S, B = 32, 16, 4
 
 @pytest.fixture(scope="module")
 def trained(devices8):
-    ff = FFModel(FFConfig(batch_size=B, num_devices=1))
-    build_gpt(ff, batch_size=B, seq_length=S, hidden_size=32,
-              num_layers=2, num_heads=4, intermediate_size=64,
-              vocab_size=V)
-    ff.compile(optimizer=SGDOptimizer(lr=0.5),
-               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-               devices=devices8[:1])
-    rng = np.random.RandomState(0)
-    start = rng.randint(0, V, (B, 1))
-    step = rng.randint(1, 6, (B, 1))
-    seq_ids = (start + step * np.arange(S + 1)) % V
-    ids = seq_ids[:, :-1].astype(np.int32)
-    labels = seq_ids[:, 1:].astype(np.int32)
-    pos = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S)).copy()
-    for _ in range(40):
-        ff.train_step({"input": ids, "positions": pos}, labels)
-    return ff, ids
+    return trained_gpt(devices8, B, S, V)
 
 
 def test_paged_decode_step_bit_identical_to_dense(trained, devices8):

@@ -11,6 +11,7 @@ exact tokens, never corrupt output.  The slow section reruns the token
 kernels with the pool invariant checker armed."""
 import numpy as np
 import pytest
+from _family import engine_factory, trained_gpt
 
 from flexflow_tpu.obs.metrics import MetricsRegistry
 from flexflow_tpu.resilience.faults import Fault, FaultKind, FaultPlan
@@ -364,41 +365,8 @@ MNT = [6, 6, 5, 4]
 
 @pytest.fixture(scope="module")
 def trained(devices8):
-    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.models.transformer import build_gpt
+    return trained_gpt(devices8, B_GPT, S_GPT, V_GPT)[0]
 
-    ff = FFModel(FFConfig(batch_size=B_GPT, num_devices=1))
-    build_gpt(ff, batch_size=B_GPT, seq_length=S_GPT, hidden_size=32,
-              num_layers=2, num_heads=4, intermediate_size=64,
-              vocab_size=V_GPT)
-    ff.compile(optimizer=SGDOptimizer(lr=0.5),
-               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-               devices=devices8[:1])
-    rng = np.random.RandomState(0)
-    start = rng.randint(0, V_GPT, (B_GPT, 1))
-    step = rng.randint(1, 6, (B_GPT, 1))
-    seq_ids = (start + step * np.arange(S_GPT + 1)) % V_GPT
-    ids = seq_ids[:, :-1].astype(np.int32)
-    labels = seq_ids[:, 1:].astype(np.int32)
-    pos = np.broadcast_to(np.arange(S_GPT, dtype=np.int32),
-                          (B_GPT, S_GPT)).copy()
-    for _ in range(40):
-        ff.train_step({"input": ids, "positions": pos}, labels)
-    return ff
-
-
-def engine_factory(ff, kernel, devices):
-    """A front's replica factory with the paged read asked for by name
-    (a front built from the config always asks for "auto"): the kernel
-    under the interpreter against the gather."""
-    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
-
-    def factory(replica_id, survivors=None):
-        return PagedKVDecodeModel(
-            ff, batch_slots=2, page_size=4, num_blocks=12,
-            devices=devices, paged_kernel=kernel,
-            prefill_chunk=4 if kernel == "pallas" else 0)
-    return factory
 
 
 def run_real(front):
