@@ -73,6 +73,7 @@ def decoder_recipe(ff: FFModel) -> DecoderRecipe:
             "a decode twin needs a model built by a models/ builder that "
             "records its recipe (models.transformer.build_gpt, "
             "models.kimi_k2.build_kimi_k2, "
+            "models.longcat_flash.build_longcat_flash, "
             "models.qwen3_next.build_qwen3_next)")
     return recipe
 

@@ -41,8 +41,9 @@ FEED = "feed"
 NOT_OPS = (LOSS, OPTIMIZER, METRICS, LOGITS, FEED)
 
 CAST_WEIGHTS = "cast_weights"
-#: parts of a routed-expert layer (`shared` is its shared expert)
-ROUTED_PARTS = ("route", "dispatch", "products", "shared", "combine")
+#: parts of a routed-expert layer (`shared` is its shared expert, `zero`
+#: the identity experts' term: each row times its identity picks' weights)
+ROUTED_PARTS = ("route", "dispatch", "products", "shared", "combine", "zero")
 #: parts of an attention op (`core` XOR `paged_read`), of a delta-net
 #: layer (`KimiDeltaAttention`: `gate` its decays and step sizes,
 #: `core` the delta rule, `norm_gate` the gated head norm) and of a

@@ -11,6 +11,16 @@ What a token leaves behind is `(c_kv, RoPE(k_r))`: `kv_lora_rank +
 qk_rope_head_dim` values a layer (576 at the published sizes, against
 `2 x heads x head_dim` = 16,384 for full keys and values).
 
+A third published variant puts a CONSTANT on each bottleneck
+(`q_lora_scale` s_q, `kv_lora_scale` s_kv; 1.0 = none):
+`q = (c_q W_qb) s_q` (so on q_nope and on q_rope before its rotation)
+and `c_kv <- RMS(c_kv) s_kv` (so on k_nope and on v, not on k_r).  Both
+are linear in the normed bottleneck, so both formulations apply them
+where the bottleneck's norm applies its gain, in float32, before the
+one rounding to the compute precision: `RMS(.) * (gain * s)`.  The
+paged pool therefore HOLDS `(RMS(c_kv) s_kv, RoPE(k_r))` and the
+absorbed products are the unscaled block's.
+
 Two formulations, one set of weights:
 
 * no cache (`decode_max_seq == 0`): keys and values are EXPANDED from
@@ -89,6 +99,10 @@ class MLAParams:
     eps: float = 1e-5
     #: no position enters the score: q_rope and k_r are not rotated
     nope: bool = False
+    #: constants on the query's and on the key/value bottleneck after
+    #: their norms (module docstring); 1.0 is the block without them
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     @property
     def latent_width(self) -> int:
@@ -147,7 +161,9 @@ def rope(x, positions, p: MLAParams):
 
 
 class MLAttention(Op):
-    """See the module docstring.  Paged, `forward` takes the step's
+    """See the module docstring.  The paged pool `latent_cache` holds,
+    a token, `(RMS(c_kv) * kv_lora_scale, RoPE(k_r))` in the compute
+    precision.  Paged, `forward` takes the step's
     length from its input: seq 1 traces the decode step
     (`_attend_paged`), seq C a prefill chunk in one pass
     (`_attend_paged_chunk`), which is what lets a family built on this
@@ -204,6 +220,9 @@ class MLAttention(Op):
                 "batch only (heads over a model axis are not built yet)")
         if self.params.qk_rope_head_dim % 2:
             raise ShapeError(f"{self.name}: rope width must be even")
+        if self.params.q_lora_scale != 1.0 and not self.params.q_lora_rank:
+            raise ShapeError(f"{self.name}: q_lora_scale without a query "
+                             "bottleneck (q_lora_rank 0)")
         return [x]
 
     def _query_weights(self) -> int:
@@ -277,17 +296,24 @@ class MLAttention(Op):
         def turn(t):
             return t if p.nope else rope(t, positions[0], p)
 
+        def gain(g, s):
+            # a bottleneck's constant rides its norm's gain (float32
+            # there); 1.0 leaves the gain, and the program, as it was
+            return g if s == 1.0 else g.astype(jnp.float32) * s
+
         with scope("proj"):
             if p.q_lora_rank:
                 wq_a, q_norm, wq_b = weights[:3]
-                cq = rms_normalize(jnp.matmul(x, wq_a), q_norm, p.eps)
+                cq = rms_normalize(jnp.matmul(x, wq_a),
+                                   gain(q_norm, p.q_lora_scale), p.eps)
                 q = jnp.einsum("bsr,rhd->bshd", cq, wq_b)
             else:
                 q = jnp.einsum("bse,ehd->bshd", x, weights[0])
             q_nope, q_rope = q[..., :dn], turn(q[..., dn:])
             kv = jnp.matmul(x, wkv_a)
             latent = jnp.concatenate(
-                [rms_normalize(kv[..., :rk], kv_norm, p.eps),
+                [rms_normalize(kv[..., :rk],
+                               gain(kv_norm, p.kv_lora_scale), p.eps),
                  turn(kv[..., rk:])], axis=-1)  # [b, s, rk + dr]
         if self._paged():
             pool, btab, slen = weights[nq + 4:]

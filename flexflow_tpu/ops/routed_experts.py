@@ -5,7 +5,8 @@ router's published width, `experts_held` of them live here, starting
 at `first_held`.  It routes every token over all `experts_total` in
 float32 (scores by the params' `scoring` rule: `sigmoid` of each logit,
 or `softmax` over all the logits; the top `top_k` of score + bias, the
-chosen scores normalised over ALL of the chosen and scaled), and adds
+chosen scores normalised over ALL of the chosen where `norm_topk_prob`
+says so, and scaled), and adds
 
     sum over experts chosen AND held here of w_e E_e(h)  +  g E_shared(h)
 
@@ -14,6 +15,21 @@ with `g = 1`, or `sigmoid(w_sg . h)` where `shared_expert_gate` is set.
 Experts that live on other chips add nothing: on one chip the layer
 runs without its exchange, and nothing stands in for the absent chips.
 `experts_held == experts_total` is the whole layer.
+
+IDENTITY ("zero-compute") EXPERTS.  `zero_experts` of them stand behind
+the `experts_total` real ones: the router (and its choosing bias) is
+`experts_total + zero_experts` wide, `top_k` picks fall anywhere in
+that width, and a pick `j >= experts_total` has no weights and no home:
+it adds `w_j h`, the token's own row times its routing weight, on the
+chip that holds the token (what every chip computes for its own rows,
+like a shared expert: no exchange).  So a token costs a VARYING number
+of expert products (`top_k` less its identity picks).  Neither product
+sees an identity pick: the dense one's `combine` has no column for it,
+the grouped one sorts it with "lives elsewhere", and the pairs an even
+router sends here are counted over the router's whole width.  The term
+is added under the scope part `zero` and counted in the state entry
+`moe_zero` (`MOE_ZERO_STATS`; the entry exists only where
+`zero_experts > 0`, so a layer without them lowers what it always did).
 
 No token is dropped at any load, by either of the two products, of
 which `pick_expert_product` chooses one from the step's shapes:
@@ -69,6 +85,10 @@ from .op import Op, ShapeError, WeightSpec, remat_keep
 
 #: order of the counters in the `moe_stats` state entry
 MOE_STATS = ("pairs", "dropped", "max_rows", "hit")
+#: order of the counters in the `moe_zero` state entry of a layer with
+#: identity experts: the identity picks of the step's rows, and the
+#: least and the most REAL picks (on any chip's experts) a row made
+MOE_ZERO_STATS = ("zero_picks", "real_min", "real_max")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,12 +105,23 @@ class RoutedExpertsParams:
     scoring: str = "sigmoid"  # or "softmax", over all experts_total
     shared_expert_gate: bool = False  # shared expert times sigmoid(w . h)
     norm_eps: float = 1e-20  # added to the chosen scores' sum
+    #: identity experts behind the `experts_total` real ones: router
+    #: outputs that add `w h` and hold no weights (module docstring)
+    zero_experts: int = 0
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of the router: the real experts of every chip and the
+        identity experts."""
+        return self.experts_total + self.zero_experts
 
 
 def route(h, router, bias, p: RoutedExpertsParams):
     """h [t, e] -> (chosen expert ids [t, k], their weights [t, k]),
-    all in float32 at full matmul precision: scores by `p.scoring`, the
-    bias only chooses, the normaliser runs over all k chosen."""
+    all in float32 at full matmul precision: scores by `p.scoring` over
+    the router's whole width (identity experts included: an id `>=
+    p.experts_total` is one), the bias only chooses, the normaliser
+    (where `p.norm_topk_prob`) runs over all k chosen."""
     logits = jnp.matmul(
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
@@ -118,7 +149,9 @@ GROUPED_SLACK = 1.5
 
 def pick_expert_product(rows: int, experts_held: int, experts_total: int,
                         top_k: int, backend: str = "") -> str:
-    """"dense" or "grouped" for a step of `rows` rows.  A pure function
+    """"dense" or "grouped" for a step of `rows` rows; `experts_total`
+    is the router's WHOLE width (identity experts included: a pick that
+    falls on one reaches no expert).  A pure function
     of its arguments; the backend does not enter (both products are
     plain XLA programs), it is taken so that a caller never has to know
     that.  Dense multiplies `rows x experts_held` rows; grouped about
@@ -393,7 +426,7 @@ class RoutedExperts(Op):
         takes (`pick_expert_product`)."""
         p: RoutedExpertsParams = self.params
         return pick_expert_product(self._rows(), p.experts_held,
-                                   p.experts_total, p.top_k,
+                                   p.router_width, p.top_k,
                                    jax.default_backend())
 
     def dense_rows_computed(self) -> int:
@@ -411,11 +444,14 @@ class RoutedExperts(Op):
                 "experts spread over a mesh axis are not built yet")
         if not (0 <= p.first_held
                 and p.first_held + p.experts_held <= p.experts_total
-                and 1 <= p.top_k <= p.experts_total):
+                and p.zero_experts >= 0
+                and 1 <= p.top_k <= p.router_width):
             raise ShapeError(
                 f"{self.name}: held experts [{p.first_held}, "
                 f"{p.first_held + p.experts_held}) and top_k {p.top_k} "
-                f"do not fit {p.experts_total} experts")
+                f"do not fit {p.experts_total} experts"
+                + (f" + {p.zero_experts} identity experts"
+                   if p.zero_experts else ""))
         if p.scoring not in ("sigmoid", "softmax"):
             raise ShapeError(f"{self.name}: scoring {p.scoring!r} is not "
                              "'sigmoid' or 'softmax'")
@@ -441,8 +477,8 @@ class RoutedExperts(Op):
         init, zero = DEFAULT_WEIGHT_INIT, ZeroInitializer()
         n, f = p.experts_held, p.expert_hidden
         specs = [
-            WeightSpec("router", w(e, p.experts_total), init),
-            WeightSpec("router_bias", w(p.experts_total), zero),
+            WeightSpec("router", w(e, p.router_width), init),
+            WeightSpec("router_bias", w(p.router_width), zero),
             WeightSpec("w_gate", w(n, e, f), init),
             WeightSpec("w_up", w(n, e, f), init),
             WeightSpec("w_down", w(n, f, e), init),
@@ -461,6 +497,10 @@ class RoutedExperts(Op):
             # the rows multiplied; 1 where the step overflowed
             specs.append(WeightSpec(
                 "moe_rows_computed", w(2, dtype=DataType.INT32), zero))
+        if p.zero_experts:
+            specs.append(WeightSpec(
+                "moe_zero", w(len(MOE_ZERO_STATS), dtype=DataType.INT32),
+                zero))
         return specs
 
     def forward(self, inputs, weights, *, training=False, rng=None):
@@ -472,7 +512,8 @@ class RoutedExperts(Op):
             chosen, w = route(h, router, bias, p)
         with scope("dispatch"):
             # [t, k, held]: which held expert each chosen pair landed
-            # on; a pair for an expert that lives elsewhere is all zeros
+            # on; a pair for an expert that lives elsewhere, or for an
+            # identity expert, is all zeros
             at = chosen - p.first_held
             landed = jax.nn.one_hot(at, p.experts_held, dtype=jnp.float32)
             combine = jnp.einsum("tkx,tk->tx", landed, w)
@@ -483,7 +524,7 @@ class RoutedExperts(Op):
                                       at, p.experts_held)
             out, grouped_counts = grouped_experts(
                 h, landed_on, w, w_gate, w_up, w_down,
-                h.shape[0] * p.top_k * p.experts_held / p.experts_total)
+                h.shape[0] * p.top_k * p.experts_held / p.router_width)
         else:
             out = dense_experts(h, combine, w_gate, w_up, w_down)
         if p.shared_hidden:
@@ -498,6 +539,18 @@ class RoutedExperts(Op):
                     shared = gated_mlp(h, *weights[5:8])
             with scope("combine"):
                 out = out + shared
+        if p.zero_experts:
+            with scope("zero"):
+                # the identity experts' term: each row times the sum of
+                # its identity picks' weights, on the chip that holds it
+                is_zero = chosen >= p.experts_total
+                w_zero = jnp.sum(jnp.where(is_zero, w, 0.0), axis=-1)
+                out = out + (h.astype(jnp.float32)
+                             * w_zero[:, None]).astype(out.dtype)
+                real = p.top_k - jnp.sum(is_zero, axis=-1, dtype=jnp.int32)
+                zero_stats = jnp.stack([
+                    h.shape[0] * p.top_k - jnp.sum(real),
+                    jnp.min(real), jnp.max(real)])
         with scope("dispatch"):  # the counts of it
             rows = jnp.sum(landed, axis=(0, 1)).astype(jnp.int32)  # [held]
             pairs = jnp.sum(rows)
@@ -509,21 +562,21 @@ class RoutedExperts(Op):
             ])
         with scope("combine"):
             out = out.reshape(x.shape).astype(x.dtype)
-        if grouped:
-            return [out, stats, grouped_counts]
-        return [out, stats]
+        return ([out, stats] + ([grouped_counts] if grouped else [])
+                + ([zero_stats] if p.zero_experts else []))
 
     def flops(self):
-        """The router's product (either scoring rule is a few
-        operations a logit on top), the experts' product as the chosen
-        one multiplies it (dense: every held expert over every row;
-        grouped: the pairs that land on held experts, in expectation),
-        the shared expert and its gate's dot product."""
+        """The router's product over its whole width (either scoring
+        rule is a few operations a logit on top), the experts' product
+        as the chosen one multiplies it (dense: every held expert over
+        every row; grouped: the pairs that land on held experts, in
+        expectation: an identity pick lands on none and counts
+        nothing), the shared expert and its gate's dot product."""
         p: RoutedExpertsParams = self.params
         t = self.inputs[0].shape.num_elements()  # rows x e
-        experts = (p.top_k * p.experts_held / p.experts_total
+        experts = (p.top_k * p.experts_held / p.router_width
                    if self.product_plan() == "grouped" else p.experts_held)
-        return t * (2.0 * p.experts_total
+        return t * (2.0 * p.router_width
                     + 6.0 * experts * p.expert_hidden
                     + 6.0 * p.shared_hidden
                     + 2.0 * int(p.shared_expert_gate))

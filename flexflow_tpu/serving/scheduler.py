@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import operator
 import queue
 import threading
 import time
@@ -56,8 +57,12 @@ import numpy as np
 from ..fftype import OperatorType
 from ..logger import serving_logger
 from ..obs.trace import span
-from ..ops.routed_experts import MOE_STATS
+from ..ops.routed_experts import MOE_STATS, MOE_ZERO_STATS
 from .kv_pool import KVPool
+
+#: how the scheduler's running totals take a dispatch's routed-expert
+#: count that is no sum (every other one is added)
+_MOE_FOLD = {"real_min": min, "real_max": max}
 
 
 def pick_paged_read(asked: str = "auto", *, backend: str,
@@ -302,8 +307,14 @@ class PagedKVDecodeModel:
         # step program returns anyway (ops/routed_experts.py MOE_STATS);
         # `step` fetches it with the logits and keeps the last
         # dispatch's counts, summed over layers, here
+        # a layer with identity experts counts their picks in a second
+        # entry (MOE_ZERO_STATS), fetched and kept the same way: the
+        # picks summed, the least and the most real picks a row over
+        # the layers
         self._moe_ops = [op for op, entries in self._state.items()
                          if "moe_stats" in entries]
+        self._moe_zero_ops = [op for op in self._moe_ops
+                              if "moe_zero" in self._state[op]]
         self.moe_last: Optional[Dict[str, int]] = None
         # repeated regions of the twin's graph (`loop_regions`,
         # `loop_steps`, `loop_ops`; {} without one): a step program
@@ -418,11 +429,17 @@ class PagedKVDecodeModel:
                 return np.asarray(logits, np.float32)
             import jax
 
-            logits, stats = jax.device_get(
+            logits, stats, zero = jax.device_get(
                 (logits, [self._state[op]["moe_stats"]
-                          for op in self._moe_ops]))
+                          for op in self._moe_ops],
+                 [self._state[op]["moe_zero"]
+                  for op in self._moe_zero_ops]))
             self.moe_last = dict(zip(
                 MOE_STATS, (int(v) for v in np.sum(stats, axis=0))))
+            if zero:
+                picks, least, most = np.stack(zero).T
+                self.moe_last.update(zip(MOE_ZERO_STATS, (
+                    int(picks.sum()), int(least.min()), int(most.max()))))
             return np.asarray(logits, np.float32)
 
     def _first_call(self, program: str) -> int:
@@ -1977,12 +1994,14 @@ class ContinuousScheduler:
                 moe = getattr(self.model, "moe_last", None)
                 if moe is not None:
                     dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})
-                    if self.moe_totals is None:
-                        self.moe_totals = dict.fromkeys(
-                            (*moe, "dispatches"), 0)
-                    for k, v in moe.items():
-                        self.moe_totals[k] += v
-                    self.moe_totals["dispatches"] += 1
+                    totals = self.moe_totals
+                    if totals is None:
+                        self.moe_totals = dict(moe, dispatches=1)
+                    else:
+                        for k, v in moe.items():
+                            totals[k] = _MOE_FOLD.get(
+                                k, operator.add)(totals[k], v)
+                        totals["dispatches"] += 1
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 # device-loss-style fault (hung dispatch, lost
