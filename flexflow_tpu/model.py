@@ -1216,6 +1216,21 @@ class FFModel:
             stats = getattr(strategy, "search_stats", None)
             if isinstance(stats, dict):
                 stats["zero_fallback_leaves"] = len(fallback)
+        if comp_mode == CompMode.TRAINING:
+            # what the train step's gradient all-reduces carry, and
+            # whether the step is jitted with the compiler options that
+            # regroup and reschedule them
+            # (executor.grad_sync_overlap_options; docs/PERF.md)
+            sync_options = self.executor.grad_sync_compiler_options()
+            tel.metrics.gauge("parallel/grad_sync_bytes").set(
+                self.executor.grad_sync_bytes())
+            tel.metrics.gauge("parallel/grad_sync_async").set(
+                int(sync_options is not None))
+            if sync_options:
+                _log.info(
+                    "train step compiled for its gradient all-reduces "
+                    "with %s",
+                    ", ".join(f"{k}={v}" for k, v in sync_options.items()))
         # score hooks live on the FRONTEND ops (the user's handles);
         # strategy application clones the compiled PCG's op objects
         self._cache_ops = [

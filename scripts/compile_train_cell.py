@@ -51,10 +51,13 @@ def report(name, compiled, seconds):
     return compiled
 
 
-def abstract_train_model(fam, cfg, batch: int, seq: int):
+def abstract_train_model(fam, cfg, batch: int, seq: int, devices=None):
     """The family's model built and compiled on the CPU with its weights
     and Adam state left ABSTRACT (`jax.eval_shape`): the real size costs
-    no memory.  Shared with `dump_step_hlo.py`."""
+    no memory.  Shared with `dump_step_hlo.py`.  `devices`: the described
+    chips of a compile-only topology to lay the mesh over instead of the
+    first CPU device (`grad_overlap_probe.py`: four); nothing can be
+    placed on those, so the step counter stays abstract too."""
     import flexflow_tpu.optimizer as opt_mod
     from flexflow_tpu.executor import GraphExecutor
 
@@ -63,16 +66,24 @@ def abstract_train_model(fam, cfg, batch: int, seq: int):
         jax.eval_shape(lambda: real_init(self, seed, state_only))
     real_state = opt_mod.AdamOptimizer.init_state
 
+    described = devices is not None
+
     def abstract_state(self, w):
         state = jax.eval_shape(lambda: real_state(self, jax.tree.map(
             lambda x: jnp.zeros(x.shape, x.dtype), w)))
+        if described:
+            return state
         # the step counter is placed on the mesh: a real scalar
         return {k: v if isinstance(v, dict) else jnp.zeros(v.shape, v.dtype)
                 for k, v in state.items()}
 
     opt_mod.AdamOptimizer.init_state = abstract_state
-    ff = fam.build_model(cfg, batch, seq, 1)
-    fam.compile_model(ff, cfg, jax.devices()[:1])
+    if described:
+        GraphExecutor.shard_opt_state = lambda self, opt_state: opt_state
+    else:
+        devices = jax.devices()[:1]
+    ff = fam.build_model(cfg, batch, seq, len(devices))
+    fam.compile_model(ff, cfg, devices)
     return ff
 
 
