@@ -1426,6 +1426,15 @@ class FFModel:
                 # compiled step has been held against the device
                 step_span.set(remat_keep=step_fn.keep,
                               remat_kept_bytes=step_fn.kept_bytes)
+            if first:
+                plans = [op.grouped_product_plan()
+                         for op in self.executor.routed_expert_ops
+                         if op.product_plan() == "grouped"]
+                if plans:  # what the grouped expert products run on
+                    product, tiling = (",".join(dict.fromkeys(x))
+                                       for x in zip(*plans))
+                    step_span.set(experts_product=product,
+                                  experts_tiling=tiling)
         if first:
             self._stepped_fns.add(step_fn)
         self._train_steps += 1

@@ -61,8 +61,10 @@ ARG_LAYOUT = "arg_layout"
 #: and NAMES ITSELF, dropping the scope path (`lax.ragged_dot` becomes a
 #: Mosaic call whose `op_name` is `ragged-dot-none`): the prefix of that
 #: name -> the (kind, part) of the one place in the program that emits
-#: the primitive (`ops/routed_experts.py grouped_matmul`).  Its phase is
-#: lost with the path.
+#: the primitive (`ops/routed_experts.py`: the three `grouped_matmul*`
+#: functions, wherever `pick_grouped_tiling` leaves them the ragged dot;
+#: the Pallas kernel they take on a TPU since PR 50 keeps its scope
+#: path like any other call).  Its phase is lost with the path.
 RENAMED_BY_XLA = {"ragged-dot": ("RoutedExperts", "products")}
 
 FORWARD, BACKWARD, RECOMPUTE = "forward", "backward", "recompute"

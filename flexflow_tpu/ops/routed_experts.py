@@ -41,8 +41,10 @@ which `pick_expert_product` chooses one from the step's shapes:
   of the held experts' weights.  What the serving steps take.
 * "grouped": the step's (token, expert) pairs are sorted by expert,
   those on held experts first; each held expert multiplies its own run
-  of rows (`grouped_matmul`: one ragged product a weight, whose groups
-  are the runs) and the results go back to their tokens weighted by the
+  of rows (`grouped_matmul`: one grouped product a weight, whose groups
+  are the runs; on a TPU a grouped-matmul Pallas kernel at a tiling
+  `pick_grouped_tiling` takes from the shapes, elsewhere the ragged
+  dot) and the results go back to their tokens weighted by the
   routing.  The buffers are static (`GROUPED_SLACK` x the pairs an even
   router sends here; every pair of the step when there are more), the
   product visits the held runs only.  What a training step's thousands
@@ -64,8 +66,10 @@ to get right: routed pairs that landed on held experts, pairs the
 combine left out (0), the rows of the fullest held expert, and the held
 experts that received a row.  The grouped product also counts the rows
 it multiplied and whether the step overflowed (`moe_rows_computed`
-[2]: padding of the product's row tiles included; 1 where the layer
-took the every-pair size); the dense product's rows are the static
+[2]: `rows_multiplied`, the padding of the product's row tiles
+included: under the kernel every visit of a row tile, so a tile that
+two experts' runs share counts twice; 1 where the layer took the
+every-pair size); the dense product's rows are the static
 `rows x experts_held`.
 """
 from __future__ import annotations
@@ -82,6 +86,7 @@ from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .dense import gated_mlp
 from .op import Op, ShapeError, WeightSpec, remat_keep
+from .pallas import grouped_matmul as kernels
 
 #: order of the counters in the `moe_stats` state entry
 MOE_STATS = ("pairs", "dropped", "max_rows", "hit")
@@ -179,36 +184,187 @@ def dense_experts(h, combine, w_gate, w_up, w_down):
         return jnp.einsum("xte,tx->te", y, combine.astype(y.dtype))
 
 
-def grouped_matmul(lhs, rhs, sizes):
+#: rows of the grouped-matmul kernel's row tile: the matrix unit's own
+#: (`pick_grouped_tiling` has the chip's readings of 128 / 256 / 512)
+GROUPED_KERNEL_ROW_TILE = 128
+#: bytes of its 16 MiB of fast memory the forward kernel's tiles may
+#: take by `pick_grouped_tiling`'s count (double-buffered operand and
+#: result tiles and the float32 accumulator); Mosaic's own temporaries
+#: take the rest: of 84 tilings compiled for the v5e none that counts
+#: under 14.5 MiB was refused ((256, 7168, 256) counts that and is;
+#: (128, 2304, 1024) counts 12.2)
+GROUPED_KERNEL_VMEM = 14 * 2 ** 20
+#: the same for the weight-gradient kernel, whose count is 8 bytes an
+#: element of its [tk, tn] accumulator (float32, and the result tile
+#: twice) and 8 an element of its two operand tiles: of 84 tilings
+#: compiled for the v5e none that counts under 19.9 MB was refused
+#: ((128, 1024, 2048) counts that and is; (128, 2048, 896) counts 17.7)
+GROUPED_KERNEL_VMEM_INTO_RHS = 18_000_000
+
+
+def pick_grouped_tiling(m: int, k: int, n: int, groups: int,
+                        rows_a_group: float, backend: str = "",
+                        into_rhs: bool = False):
+    """The kernel's tiling (tm, tk, tn) for one grouped product, or
+    None: the ragged dot.  A pure function of its arguments.  The
+    product is lhs [m, k] x [groups, k, n] -> [m, n] (`grouped_matmul`,
+    and `grouped_matmul_into_lhs` with the weight's axes read the other
+    way round) or, `into_rhs`, [m, k] and [m, n] -> [groups, k, n];
+    `rows_a_group` is what an even router sends a group (`groups`
+    does not enter today).
+
+    The kernel (`ops/pallas/grouped_matmul.py`) is for the TPU, for
+    widths of whole 128-lane tiles, for an m that its row tile divides
+    (`grouped_experts` rounds its buffers to one) and for runs of a row
+    tile or more; anything else keeps the ragged dot.  What the v5e
+    chose (`scripts/expert_product_probe.py --sweep`, PR 50: eight
+    calls a program; ms a product of cell 6, 8 x ~1,024 rows in 12,288
+    slots at 2,048 x 1,792 | of cell 8, 8 x ~256 rows in 3,072 slots at
+    2,304 x 1,024; PERF.md section 7 has the table):
+
+    * tk = k, whole.  A group's [tk, tn] weight tile then stays in fast
+      memory while the group's row tiles pass, and no accumulator is
+      carried between grid steps: (128, 2048, 896) 0.568 against
+      (128, 1024, 896) 0.944 and the ragged dot's 0.821 | (128, 2304,
+      1024) 0.122 against (128, 1152, 1024) 0.135 and 0.235.  Among the
+      (tk, tn) that fit (`GROUPED_KERNEL_VMEM`) the largest tile wins,
+      the larger tk on a tie; tn divides n, so no lane is masked.
+    * tm = 128 rows.  A row tile that two runs share is visited once
+      for each, so at 8 runs in 8,192 rows 71 visits of 128 multiply
+      9,088 rows where 39 of 256 multiply 9,984 and 23 of 512 11,776,
+      while each of the matrix unit's weight loads serves fewer rows:
+      128 / 256 rows read 0.568 / 0.558 | 0.122 / 0.145 (runs of ~256
+      rows touch three tiles of 256 as often as two), and cell 6's step
+      161.7 / 161.9 ms.  The runs are NOT aligned to the tiles inside
+      the buffers: that pads every run by half a tile on average, 68
+      visits of 128 against 71, and the padded slots would have to hold
+      zeros.
+    * `into_rhs`: tm = 128 too, tk = k and the accumulator's [tk, tn] as
+      large as fits (`GROUPED_KERNEL_VMEM_INTO_RHS`): (128, 2048, 896)
+      0.741 against (128, 1024, 896) 0.801 and the ragged dot's 1.069 |
+      (128, 2304, 512) 0.174 against 0.298.
+
+    One routed layer forward and backward, alone: cell 6's 11.35 ms on
+    the ragged dot, 7.81 on the kernel; cell 8's 7.45 and 6.27."""
+    tm = GROUPED_KERNEL_ROW_TILE
+    if (backend != "tpu" or k % 128 or n % 128 or m % tm
+            or rows_a_group < tm):
+        return None
+
+    def fits(tk, tn):
+        if into_rhs:
+            return (8 * tk * tn + 8 * tm * (tk + tn)
+                    <= GROUPED_KERNEL_VMEM_INTO_RHS)
+        return (4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+                <= GROUPED_KERNEL_VMEM)
+
+    tiles = [(tk * tn, tk, tn)
+             for tk in range(128, k + 1, 128) if k % tk == 0
+             for tn in range(128, n + 1, 128) if n % tn == 0
+             if fits(tk, tn)]
+    if not tiles:
+        return None
+    _, tk, tn = max(tiles)
+    return tm, tk, tn
+
+
+def _tiling(m, k, n, groups, rows_a_group, into_rhs=False):
+    return pick_grouped_tiling(m, k, n, groups, rows_a_group,
+                               jax.default_backend(), into_rhs)
+
+
+def _visits(sizes, lhs, tiling, empty_groups=False):
+    """The kernel's grid steps over lhs' rows: one jitted call a
+    product, which the products of a layer that share (sizes, m, tm)
+    trace once and XLA computes once."""
+    return kernels.visits(sizes, m=lhs.shape[0], tm=tiling[0],
+                          empty_groups=empty_groups)
+
+
+def _interpret() -> bool:
+    """The kernel is compiled by Mosaic on a TPU and interpreted
+    anywhere else (where only a test's picker asks for it)."""
+    return jax.default_backend() != "tpu"
+
+
+def grouped_matmul(lhs, rhs, sizes, rows_a_group: float = 0.0):
     """lhs [m, k] whose rows run expert after expert, `sizes[g]` rows
     for expert g (their sum may stay below m); rhs [g, k, n] -> [m, n].
     Rows of lhs past the last group are not read.  Rows of the RESULT
     past the last group are NOT DEFINED: the CPU's lowering gives
-    zeros, libtpu's kernel leaves them unwritten, whatever the buffer
-    held before, NaN included (measured on the v5e, PR 39).  Nothing may
-    read them but another grouped product, or a select that drops
-    them."""
-    return jax.lax.ragged_dot(lhs, rhs, sizes)
+    zeros, libtpu's ragged dot and the grouped-matmul kernel leave them
+    unwritten, whatever the buffer held before, NaN included (measured
+    on the v5e, PRs 39 and 50).  Nothing may read them but another
+    grouped product, or a select that drops them.  `rows_a_group`, the
+    rows a group expects, is what `pick_grouped_tiling` chooses the
+    kernel and its tiling from (0: the ragged dot)."""
+    tiling = _tiling(*lhs.shape, rhs.shape[2], rhs.shape[0], rows_a_group)
+    if tiling is None:
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    return kernels.product(lhs, rhs, _visits(sizes, lhs, tiling),
+                           tiling=tiling, interpret=_interpret())
 
 
-def grouped_matmul_into_lhs(ct, rhs, sizes):
+def grouped_matmul_into_lhs(ct, rhs, sizes, rows_a_group: float = 0.0):
     """`grouped_matmul`'s gradient into its lhs: ct [m, n], rhs
     [g, k, n] -> [m, k], each run of rows times its own expert's weight
-    transposed (a copy of the weight a call: libtpu's kernel contracts
-    rhs' middle axis only).  Its rows past the last group: as
-    `grouped_matmul`'s."""
-    return grouped_matmul(ct, jnp.swapaxes(rhs, 1, 2), sizes)
+    transposed.  The kernel reads the weight as it is stored and
+    contracts its last axis; the ragged dot, which contracts rhs'
+    middle axis only, is handed a transposed copy.  Its rows past the
+    last group: as `grouped_matmul`'s."""
+    tiling = _tiling(*ct.shape, rhs.shape[1], rhs.shape[0], rows_a_group)
+    if tiling is None:
+        return grouped_matmul(ct, jnp.swapaxes(rhs, 1, 2), sizes)
+    return kernels.product(ct, rhs, _visits(sizes, ct, tiling),
+                           tiling=tiling, transpose_rhs=True,
+                           interpret=_interpret())
 
 
-def grouped_matmul_into_rhs(lhs, ct, sizes):
+def grouped_matmul_into_rhs(lhs, ct, sizes, rows_a_group: float = 0.0):
     """`grouped_matmul`'s gradient into its rhs: lhs [m, k], ct [m, n]
     -> [g, k, n], each run of rows contracted into its own expert's
-    slice.  Rows past the last group are not read and enter no slice,
-    on either backend."""
-    return jax.lax.ragged_dot_general(
-        lhs, ct, sizes, jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+    slice (an empty group's slice is zero).  Rows past the last group
+    are not read and enter no slice, on any backend."""
+    tiling = _tiling(*lhs.shape, ct.shape[1], sizes.shape[0], rows_a_group,
+                     into_rhs=True)
+    if tiling is None:
+        return jax.lax.ragged_dot_general(
+            lhs, ct, sizes, jax.lax.RaggedDotDimensionNumbers(
+                dot_dimension_numbers=(((0,), (0,)), ((), ())),
+                lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+    return kernels.product_into_groups(
+        lhs, ct, _visits(sizes, lhs, tiling, empty_groups=True),
+        tiling=tiling, interpret=_interpret())
+
+
+def grouped_slots(m_all: int, expected_pairs: float, e: int, f: int,
+                  held: int):
+    """(m_usual, the kernel's row tile at that size, at the every-pair
+    size `m_all`; None: the ragged dot) of a layer whose `held` experts
+    [e, f] expect `expected_pairs` pairs a step.  The usual buffers
+    hold `GROUPED_SLACK` times those, in whole row tiles of the
+    every-pair size's forward product, which then divide both sizes."""
+    def row_tile(m):
+        tiling = _tiling(m, e, f, held, expected_pairs / held)
+        return tiling[0] if tiling else None
+
+    tile = row_tile(m_all) or GROUPED_ROW_TILE
+    m_usual = min(m_all, -(-max(1, int(GROUPED_SLACK * expected_pairs))
+                           // tile) * tile)
+    return m_usual, row_tile(m_usual), row_tile(m_all)
+
+
+def rows_multiplied(sizes, kernel_row_tile=None):
+    """The rows a grouped product multiplies for runs of `sizes` rows.
+    The ragged dot: each run rounded up to `GROUPED_ROW_TILE`.  The
+    kernel, whose row tiles lie on the buffer's own grid: `tm` a visit,
+    one visit for every tile a run touches, so a tile that two runs
+    share is counted (and multiplied) twice."""
+    if kernel_row_tile is None:
+        return jnp.sum(-(-sizes // GROUPED_ROW_TILE)) * GROUPED_ROW_TILE
+    ends = jnp.cumsum(sizes)
+    visits = -(-ends // kernel_row_tile) - (ends - sizes) // kernel_row_tile
+    return jnp.sum(jnp.where(sizes > 0, visits, 0)) * kernel_row_tile
 
 
 def _activation(gate, up):
@@ -238,18 +394,20 @@ def _sum_of_slots(rows, slot_of, kept, scale=None):
     return total.astype(rows.dtype)
 
 
-def _slot_products(m, h, order, slot_of, sizes, w_gate, w_up, w_down):
-    """The experts over the first m slots -> gate, up [m, f] and ys
-    [m, e], the three products.  No mask: a slot past the held runs
+def _slot_products(size, h, order, slot_of, sizes, w_gate, w_up, w_down):
+    """The experts over the first m slots (`size` = (m, the rows a group
+    expects)) -> gate, up [m, f] and ys [m, e], the three products.  No
+    mask: a slot past the held runs
     gathers some token's row, which no group reads, and what the
     products leave in such a slot (`grouped_matmul`) only a grouped
     product or `_sum_of_slots`' select meets."""
+    m, rows = size
     with scope("dispatch"):
         xs = h[order[:m] // slot_of.shape[1]]
     with scope("products"):
-        gate = grouped_matmul(xs, w_gate, sizes)
-        up = grouped_matmul(xs, w_up, sizes)
-        ys = grouped_matmul(_activation(gate, up), w_down, sizes)
+        gate = grouped_matmul(xs, w_gate, sizes, rows)
+        up = grouped_matmul(xs, w_up, sizes, rows)
+        ys = grouped_matmul(_activation(gate, up), w_down, sizes, rows)
     return gate, up, ys
 
 
@@ -259,18 +417,18 @@ def _held(slot_of, sizes):
     return slot_of < jnp.sum(sizes)
 
 
-def _slots_forward(m, h, order, slot_of, sizes, weights,
+def _slots_forward(size, h, order, slot_of, sizes, weights,
                    w_gate, w_up, w_down):
     """The layer over the first m slots, which hold every held pair
     -> (out [t, e], gate, up, ys)."""
-    products = _slot_products(m, h, order, slot_of, sizes,
+    products = _slot_products(size, h, order, slot_of, sizes,
                               w_gate, w_up, w_down)
     with scope("combine"):
         return (_sum_of_slots(products[-1], slot_of, _held(slot_of, sizes),
                               weights), *products)
 
 
-def _slots_backward(m, h, order, slot_of, sizes, weights, w_gate, w_up,
+def _slots_backward(size, h, order, slot_of, sizes, weights, w_gate, w_up,
                     w_down, gate, up, ys, d_out):
     """`_slots_forward`'s gradient into (h, weights, w_gate, w_up,
     w_down) from its products.  The routing weight of a slot past the
@@ -278,6 +436,7 @@ def _slots_backward(m, h, order, slot_of, sizes, weights, w_gate, w_up,
     there: the mask of the backward pass is the multiply that makes
     `d_ys`.  What the products leave there afterwards is dropped where
     the sums select the held pairs."""
+    m, rows = size
     with scope("dispatch"):
         pair = order[:m]
         token = pair // slot_of.shape[1]
@@ -294,22 +453,23 @@ def _slots_backward(m, h, order, slot_of, sizes, weights, w_gate, w_up,
                 weights.dtype)
     with scope("products"):
         act, d_activation = jax.vjp(_activation, gate, up)
-        d_w_down = grouped_matmul_into_rhs(act, d_ys, sizes)
+        d_w_down = grouped_matmul_into_rhs(act, d_ys, sizes, rows)
         d_gate, d_up = d_activation(
-            grouped_matmul_into_lhs(d_ys, w_down, sizes))
-        d_w_gate = grouped_matmul_into_rhs(xs, d_gate, sizes)
-        d_w_up = grouped_matmul_into_rhs(xs, d_up, sizes)
-        d_xs = (grouped_matmul_into_lhs(d_gate, w_gate, sizes)
-                + grouped_matmul_into_lhs(d_up, w_up, sizes))
+            grouped_matmul_into_lhs(d_ys, w_down, sizes, rows))
+        d_w_gate = grouped_matmul_into_rhs(xs, d_gate, sizes, rows)
+        d_w_up = grouped_matmul_into_rhs(xs, d_up, sizes, rows)
+        d_xs = (grouped_matmul_into_lhs(d_gate, w_gate, sizes, rows)
+                + grouped_matmul_into_lhs(d_up, w_up, sizes, rows))
     with scope("dispatch"):
         d_h = _sum_of_slots(d_xs, slot_of, held)
     return (d_h, d_weights, d_w_gate.astype(w_gate.dtype),
             d_w_up.astype(w_up.dtype), d_w_down.astype(w_down.dtype))
 
 
-def _fits(m_usual, slot_of, sizes):
+def _fits(usual, slot_of, sizes):
     """None where the usual buffers hold every pair whatever the load,
     else whether they hold this step's."""
+    m_usual = usual[0]
     if m_usual == slot_of.size:
         return None
     with scope("dispatch"):
@@ -317,54 +477,56 @@ def _fits(m_usual, slot_of, sizes):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _grouped(m_usual, h, order, slot_of, sizes, weights,
+def _grouped(usual, h, order, slot_of, sizes, weights,
              w_gate, w_up, w_down):
     """`grouped_experts` after its sort, forward and backward written
     out (`_slots_forward`, `_slots_backward`) at two sizes: the first
     `m_usual` slots or, on a step whose held pairs overflow them, every
-    slot.  The choice (`lax.cond`) is made inside either rule, so both
+    slot (`usual` = (m_usual, the rows a group expects): static).  The
+    choice (`lax.cond`) is made inside either rule, so both
     branches give the same shapes and the backward rule's residuals are
     the usual size's alone."""
-    return _grouped_fwd(m_usual, h, order, slot_of, sizes, weights,
+    return _grouped_fwd(usual, h, order, slot_of, sizes, weights,
                         w_gate, w_up, w_down)[0]
 
 
-def _grouped_fwd(m_usual, h, order, slot_of, sizes, weights,
+def _grouped_fwd(usual, h, order, slot_of, sizes, weights,
                  w_gate, w_up, w_down):
     args = (h, order, slot_of, sizes, weights, w_gate, w_up, w_down)
-    fits = _fits(m_usual, slot_of, sizes)
-    usual = functools.partial(_slots_forward, m_usual)
+    fits = _fits(usual, slot_of, sizes)
+    every = (slot_of.size, usual[1])
+    forward = functools.partial(_slots_forward, usual)
     if fits is None:
-        out, *products = usual(*args)
+        out, *products = forward(*args)
     else:
         def overflow(*args):
             # nothing of this size is kept: its backward runs it again
-            out, *products = _slots_forward(slot_of.size, *args)
+            out, *products = _slots_forward(every, *args)
             with scope("dispatch"):
-                return (out, *(jnp.zeros((m_usual,) + y.shape[1:], y.dtype)
+                return (out, *(jnp.zeros(usual[:1] + y.shape[1:], y.dtype)
                                for y in products))
 
-        out, *products = jax.lax.cond(fits, usual, overflow, *args)
+        out, *products = jax.lax.cond(fits, forward, overflow, *args)
     # what a checkpointed segment may hold of this op (the flash
     # kernels name their outputs the same way)
     return out, args + tuple(map(remat_keep, products))
 
 
-def _grouped_bwd(m_usual, residuals, d_out):
-    fits = _fits(m_usual, *residuals[2:4])  # slot_of, sizes
-    usual = functools.partial(_slots_backward, m_usual)
+def _grouped_bwd(usual, residuals, d_out):
+    fits = _fits(usual, *residuals[2:4])  # slot_of, sizes
+    backward = functools.partial(_slots_backward, usual)
     if fits is None:
-        grads = usual(*residuals, d_out)
+        grads = backward(*residuals, d_out)
     else:
         def overflow(h, order, slot_of, sizes, weights, w_gate, w_up,
                      w_down, _gate, _up, _ys, d_out):
-            m_all = slot_of.size
-            products = _slot_products(m_all, h, order, slot_of, sizes,
+            every = (slot_of.size, usual[1])
+            products = _slot_products(every, h, order, slot_of, sizes,
                                       w_gate, w_up, w_down)
-            return _slots_backward(m_all, h, order, slot_of, sizes, weights,
+            return _slots_backward(every, h, order, slot_of, sizes, weights,
                                    w_gate, w_up, w_down, *products, d_out)
 
-        grads = jax.lax.cond(fits, usual, overflow, *residuals, d_out)
+        grads = jax.lax.cond(fits, backward, overflow, *residuals, d_out)
     d_h, d_weights, *d_experts = grads
     return (d_h, None, None, None, d_weights, *d_experts)
 
@@ -397,15 +559,17 @@ def grouped_experts(h, landed_on, w, w_gate, w_up, w_down,
                                        dtype=jnp.int32), axis=0)
         weights = jnp.where(landed_on < held, w, 0)
     m_all = t * k
-    m_usual = min(m_all, -(-max(1, int(GROUPED_SLACK * expected_pairs))
-                           // GROUPED_ROW_TILE) * GROUPED_ROW_TILE)
-    out = _grouped(m_usual, h, order, slot_of, sizes, weights,
-                   w_gate, w_up, w_down)
+    rows_a_group = expected_pairs / held
+    m_usual, tile_usual, tile_all = grouped_slots(
+        m_all, expected_pairs, *w_gate.shape[1:], held)
+    out = _grouped((m_usual, rows_a_group), h, order, slot_of, sizes,
+                   weights, w_gate, w_up, w_down)
     with scope("dispatch"):
-        tiles = -(-sizes // GROUPED_ROW_TILE)
-        return out, jnp.stack([
-            jnp.sum(tiles) * GROUPED_ROW_TILE,
-            (jnp.sum(sizes) > m_usual).astype(jnp.int32)])
+        overflow = jnp.sum(sizes) > m_usual
+        rows = rows_multiplied(sizes, tile_usual)
+        if tile_all != tile_usual:  # the two sizes' products differ
+            rows = jnp.where(overflow, rows_multiplied(sizes, tile_all), rows)
+        return out, jnp.stack([rows, overflow.astype(jnp.int32)])
 
 
 class RoutedExperts(Op):
@@ -428,6 +592,26 @@ class RoutedExperts(Op):
         return pick_expert_product(self._rows(), p.experts_held,
                                    p.router_width, p.top_k,
                                    jax.default_backend())
+
+    def grouped_product_plan(self):
+        """("kernel", the tilings `tmxtkxtn` of the usual buffers' six
+        products, joined by `+`) or ("ragged", ""): what the grouped
+        products of a step of this op's declared rows run on
+        (`pick_grouped_tiling`)."""
+        p: RoutedExpertsParams = self.params
+        e, f = self.inputs[0].shape.logical_shape[-1], p.expert_hidden
+        # pairs an even router sends the held experts a step
+        pairs = self._rows() * p.top_k * p.experts_held / p.router_width
+        m_usual = grouped_slots(self._rows() * p.top_k, pairs, e, f,
+                                p.experts_held)[0]
+        tilings = [_tiling(m_usual, k, n, p.experts_held,
+                           pairs / p.experts_held, into_rhs)
+                   for into_rhs in (False, True)
+                   for k, n in ((e, f), (f, e))]
+        if None in tilings:
+            return "ragged", ""
+        return "kernel", "+".join(dict.fromkeys(
+            "x".join(map(str, t)) for t in tilings))
 
     def dense_rows_computed(self) -> int:
         """Rows the dense product multiplies a step: every held expert

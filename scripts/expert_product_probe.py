@@ -1,19 +1,23 @@
 #!/usr/bin/env python3
 """One routed-expert layer, forward and backward, at a training step's
-shapes: the dense product against the grouped one (alone, and as a
-checkpointed segment of the executor's keeping nothing or its
-products), the grouped one's matrix products as libtpu's ragged dot
-against the megablox Pallas kernels, and the step that overflows the
-usual buffers.  Prints ms a call on the chip (`chiprun -- python3
-scripts/expert_product_probe.py`), then the checkpointed layer's device
-time by part (`benchmarks/device_scopes.py` over a trace of its own);
-`--compile-only` compiles every variant for a described v5e in the
+shapes (`--cell lfm2`: cell 6's, `--cell kimi`: cell 8's): the dense
+product against the grouped one (alone, and as a checkpointed segment
+of the executor's keeping nothing or its products), the grouped one's
+matrix products as libtpu's ragged dot against the grouped-matmul
+kernel at a grid of tilings (`--sweep`: each of the three products
+alone, then the whole layer at the tilings `--layer-tilings` names),
+and the step that overflows the usual buffers.  Prints ms a call on the
+chip (`chiprun -- python3 scripts/expert_product_probe.py`), then the
+checkpointed layer's device time by part (`benchmarks/device_scopes.py`
+over a trace of its own); `--compile-only` compiles every variant (and,
+with `--sweep`, every tiling of the grid) for a described v5e in the
 sandbox and prints no time (`--hlo DIR` also writes each variant's
 optimized HLO there).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -30,6 +34,51 @@ from flexflow_tpu.ops import routed_experts as rx  # noqa: E402
 
 MATMULS = ("grouped_matmul", "grouped_matmul_into_lhs",
            "grouped_matmul_into_rhs")
+#: cell 6's and cell 8's routed layer
+CELLS = {"lfm2": dict(rows=8192, hidden=2048, width=1792, held=8, total=32,
+                      top_k=4),
+         "kimi": dict(rows=8192, hidden=2304, width=1024, held=8, total=256,
+                      top_k=8)}
+PICKER = rx.pick_grouped_tiling
+
+
+def pick_by(plan):
+    """A stand-in for `rx.pick_grouped_tiling`: `plan` = "ragged",
+    "picked" (the program's own choice for a TPU), one tiling for every
+    product, or {(k, n, into_rhs): tiling}."""
+    def pick(m, k, n, groups, rows_a_group, backend="", into_rhs=False):
+        if plan == "ragged":
+            return None
+        if plan == "picked":
+            return PICKER(m, k, n, groups, rows_a_group, "tpu", into_rhs)
+        if isinstance(plan, dict):
+            return plan[k, n, into_rhs]
+        return plan
+    return pick
+
+
+def tile_grid(k, n, into_rhs):
+    """The tilings swept for one product: row tiles of 128-512, the
+    contracted and the output width whole, halved, in thirds where 128
+    lanes divide that, and the 1,024 PR 39 tried."""
+    def widths(x):
+        return sorted({w for w in (x, x // 2, x // 3, 1024)
+                       if w <= x and w % 128 == 0 and (
+                           x % w == 0 or w == 1024)})
+    out = []
+    for tm in (128, 256, 512):
+        for tk in widths(k):
+            for tn in widths(n):
+                # double-buffered operand and result tiles and the
+                # float32 accumulator, against the 16 MiB a kernel may
+                # take: a loose bound, Mosaic refuses what does not fit
+                if into_rhs:
+                    vmem = 4 * tm * (tk + tn) + 8 * tk * tn
+                else:
+                    vmem = 4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+                if vmem <= 16 * 2 ** 20:
+                    out.append((tm, tk, tn))
+    return out
 
 
 def layer(product, p, keep=None, slack=None):
@@ -69,28 +118,100 @@ def layer(product, p, keep=None, slack=None):
     return jax.jit(named)
 
 
-def megablox(tiling):
-    """`MATMULS` on the megablox kernels at one tiling."""
-    import importlib
-
-    # the package's `gmm` is the function; the kernels' module has both
-    mb = importlib.import_module(
-        "jax.experimental.pallas.ops.tpu.megablox.gmm")
-
-    def mm(lhs, rhs, sizes):
-        return mb.gmm(lhs, rhs, sizes, lhs.dtype, tiling)
-
-    def into_lhs(ct, rhs, sizes):
-        return mb.gmm(ct, rhs, sizes, ct.dtype, tiling, transpose_rhs=True)
-
-    def into_rhs(lhs, ct, sizes):
-        return mb.tgmm(lhs.swapaxes(0, 1), ct, sizes, lhs.dtype, tiling)
-
-    return mm, into_lhs, into_rhs
+def products_of(shape):
+    """{name: (function, lhs shape, rhs shape, calls a step's layer
+    makes, the kernel's (k, n, into_rhs))} of the six products a routed
+    layer's forward and backward make, at the usual buffers' size."""
+    t, e, f, n = (shape[x] for x in ("rows", "hidden", "width", "held"))
+    m = usual_slots(shape)
+    mm, into_lhs, into_rhs = (getattr(rx, name) for name in MATMULS)
+    return m, {
+        "gate_up": (mm, (m, e), (n, e, f), 2, (e, f, False)),
+        "down": (mm, (m, f), (n, f, e), 1, (f, e, False)),
+        "d_act": (into_lhs, (m, e), (n, f, e), 1, (e, f, False)),
+        "d_xs": (into_lhs, (m, f), (n, e, f), 2, (f, e, False)),
+        "d_w_gate_up": (into_rhs, (m, e), (m, f), 2, (e, f, True)),
+        "d_w_down": (into_rhs, (m, f), (m, e), 1, (f, e, True)),
+    }
 
 
-def tails(m, e, f, n, count):
-    """What this backend's three products do with the rows past the
+def usual_slots(shape):
+    pairs = shape["rows"] * shape["top_k"] * shape["held"] / shape["total"]
+    return rx.grouped_slots(shape["rows"] * shape["top_k"], pairs,
+                            shape["hidden"], shape["width"],
+                            shape["held"])[0]
+
+
+#: calls of a product one timed program makes, each on operands of its
+#: own: a product of cell 8 takes ~0.1 ms, a dispatch from the host as
+#: long
+CALLS_A_PROGRAM = 8
+
+
+def sweep(shape, iters, structs=None):
+    """ms a call of each product alone under the ragged dot and under
+    every tiling of its grid (`structs`: compile only, for the sharding
+    it names) -> {product: {plan: ms or error}}, the best plan of each."""
+    import numpy as np
+
+    m, products = products_of(shape)
+    n = shape["held"]
+    pairs = shape["rows"] * shape["top_k"] * n // shape["total"]
+    sizes = jnp.asarray(np.random.default_rng(0).multinomial(
+        pairs, [1.0 / n] * n), jnp.int32)
+    keys = jax.random.split(jax.random.key(2), 2)
+    out, best = {}, {}
+    for name, (fn, lhs_shape, rhs_shape, calls, key) in products.items():
+        out[name] = {"calls_a_layer": calls}
+        both = fn is rx.grouped_matmul_into_rhs  # a gradient a call
+        if structs is None:
+            lhs = jax.random.normal(
+                keys[0], (CALLS_A_PROGRAM,) + lhs_shape, jnp.bfloat16)
+            rhs = jax.random.normal(
+                keys[1], (CALLS_A_PROGRAM,) * both + rhs_shape, jnp.bfloat16)
+        for plan in ["ragged"] + tile_grid(*key):
+            rx.pick_grouped_tiling = pick_by(plan)
+
+            def calls(lhs, rhs, sizes, fn=fn, both=both):
+                return tuple(fn(lhs[i], rhs[i] if both else rhs, sizes,
+                                pairs / n) for i in range(CALLS_A_PROGRAM))
+
+            jitted = jax.jit(calls)
+            label = plan if plan == "ragged" else "x".join(map(str, plan))
+            try:
+                if structs is not None:
+                    jitted.trace(
+                        structs((CALLS_A_PROGRAM,) + lhs_shape),
+                        structs((CALLS_A_PROGRAM,) * both + rhs_shape),
+                        structs((n,), jnp.int32)).lower(
+                        lowering_platforms=("tpu",)).compile()
+                    out[name][label] = "compiled"
+                else:
+                    got = jax.block_until_ready(jitted(lhs, rhs, sizes))
+                    t0 = time.monotonic()
+                    for _ in range(iters):
+                        got = jitted(lhs, rhs, sizes)
+                    jax.block_until_ready(got)
+                    out[name][label] = 1e3 * (time.monotonic() - t0) / (
+                        iters * CALLS_A_PROGRAM)
+            except Exception as ex:  # a tiling Mosaic refuses
+                out[name][label] = f"{type(ex).__name__}: {str(ex)[:160]}"
+            print(name, label, out[name][label], flush=True)
+        picked = PICKER(m, key[0], key[1], n, pairs / n, "tpu", key[2])
+        out[name]["picked"] = picked and "x".join(map(str, picked))
+        timed = {k: v for k, v in out[name].items()
+                 if isinstance(v, float) and k != "ragged"}
+        if timed:
+            label = min(timed, key=timed.get)
+            best[key] = tuple(map(int, label.split("x")))
+            out[name]["best"] = label
+    rx.pick_grouped_tiling = PICKER
+    return out, best
+
+
+def tails(m, e, f, n, count, plan):
+    """What this backend's three products, under `plan` (`pick_by`), do
+    with the rows past the
     last group (`count` of m rows are in the groups, NaN in the rest,
     and NaN where the allocator may hand a result its buffer): whether
     such a row is READ (it must not be: no NaN in a group's rows, nor
@@ -103,7 +224,9 @@ def tails(m, e, f, n, count):
     lhs, ct = lhs.astype(jnp.bfloat16), ct.astype(jnp.bfloat16)
     rhs = jax.random.normal(keys[2], (n, e, f)).astype(jnp.bfloat16)
     sizes = jnp.full((n,), count // n, jnp.int32).at[0].add(count % n)
-    mm, into_lhs, into_rhs = (jax.jit(getattr(rx, name)) for name in MATMULS)
+    rx.pick_grouped_tiling = pick_by(plan)
+    mm, into_lhs, into_rhs = (jax.jit(functools.partial(
+        getattr(rx, name), rows_a_group=count / n)) for name in MATMULS)
     out = {}
     for name, fn, x in (("grouped_matmul", mm, lhs),
                         ("grouped_matmul_into_lhs", into_lhs, ct)):
@@ -117,6 +240,7 @@ def tails(m, e, f, n, count):
             else "unwritten"}
     out["grouped_matmul_into_rhs"] = {"reads_past_the_groups": not bool(
         jnp.all(jnp.isfinite(into_rhs(lhs, ct, sizes).astype(jnp.float32))))}
+    rx.pick_grouped_tiling = PICKER
     return out
 
 
@@ -140,46 +264,58 @@ def by_part(fn, vals, iters):
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--rows", type=int, default=8192)
-    ap.add_argument("--hidden", type=int, default=2048)
-    ap.add_argument("--width", type=int, default=1792)
-    ap.add_argument("--held", type=int, default=8)
-    ap.add_argument("--total", type=int, default=32)
-    ap.add_argument("--top-k", type=int, default=4)
+    ap.add_argument("--cell", choices=sorted(CELLS), default="lfm2")
+    for name in CELLS["lfm2"]:
+        ap.add_argument("--" + name.replace("_", "-"), type=int)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--sweep", action="store_true",
+                    help="each product alone by tiling, before the layer")
+    ap.add_argument("--layer-tilings", nargs="*", default=[],
+                    help="tm,tk,tn: the whole layer with every product "
+                    "at that tiling")
     ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--no-layer", action="store_true",
+                    help="the sweep alone")
     ap.add_argument("--hlo", default="")
     args = ap.parse_args()
+    shape = {k: getattr(args, k) or v for k, v in CELLS[args.cell].items()}
     p = rx.RoutedExpertsParams(
-        experts_total=args.total, experts_held=args.held, first_held=0,
-        top_k=args.top_k, expert_hidden=args.width, norm_eps=1e-6)
-    t, e, f, n = args.rows, args.hidden, args.width, args.held
+        experts_total=shape["total"], experts_held=shape["held"],
+        first_held=0, top_k=shape["top_k"], expert_hidden=shape["width"],
+        norm_eps=1e-6)
+    t, e, f, n = (shape[x] for x in ("rows", "hidden", "width", "held"))
     bf = jnp.bfloat16
-    shapes = [((t, e), bf), ((e, args.total), jnp.float32),
-              ((args.total,), jnp.float32), ((n, e, f), bf), ((n, e, f), bf),
-              ((n, f, e), bf), ((t, e), jnp.float32)]
-    ragged = tuple(getattr(rx, name) for name in MATMULS)
-    # name: (product, the three matmuls, what a segment keeps, slack)
+    shapes = [((t, e), bf), ((e, shape["total"]), jnp.float32),
+              ((shape["total"],), jnp.float32), ((n, e, f), bf),
+              ((n, e, f), bf), ((n, f, e), bf), ((t, e), jnp.float32)]
+    # name: (product, the grouped products' plan (`pick_by`), what a
+    # segment keeps, slack)
     variants = {
-        "dense": ("dense", ragged, None, None),
-        "grouped.ragged_dot": ("grouped", ragged, None, None),
-        "grouped.megablox_512_1024_1024":
-            ("grouped", megablox((512, 1024, 1024)), None, None),
-        "grouped.ragged_dot.remat_none": ("grouped", ragged, "none", None),
+        "dense": ("dense", "ragged", None, None),
+        "grouped.ragged_dot": ("grouped", "ragged", None, None),
+        "grouped.kernel_picked": ("grouped", "picked", None, None),
+        "grouped.ragged_dot.remat_none": ("grouped", "ragged", "none", None),
         "grouped.ragged_dot.remat_products":
-            ("grouped", ragged, "products", None),
+            ("grouped", "ragged", "products", None),
+        "grouped.kernel_picked.remat_products":
+            ("grouped", "picked", "products", None),
         # a step whose held pairs overflow the usual buffers: the
         # every-pair size, whose backward runs its forward again
         "grouped.ragged_dot.remat_products.overflow":
-            ("grouped", ragged, "products", 0.5),
+            ("grouped", "ragged", "products", 0.5),
+        "grouped.kernel_picked.remat_products.overflow":
+            ("grouped", "picked", "products", 0.5),
     }
+    for text in args.layer_tilings:
+        variants["grouped.kernel_" + text.replace(",", "x")] = (
+            "grouped", tuple(map(int, text.split(","))), None, None)
 
     def build(name):
-        product, matmuls, keep, slack = variants[name]
-        for attr, fn in zip(MATMULS, matmuls):
-            setattr(rx, attr, fn)
+        product, plan, keep, slack = variants[name]
+        rx.pick_grouped_tiling = pick_by(plan)
         return layer(product, p, keep, slack)
 
+    out = {"shapes": shape}
     if args.compile_only:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -187,13 +323,20 @@ def main() -> int:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         sh = SingleDeviceSharding(topo.devices[0])
-        structs = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
-        for name in variants:
-            c = build(name).trace(*structs).lower(
+        jax.default_backend = lambda: "tpu"  # Mosaic, not the interpreter
+
+        def struct(s, d=bf):
+            return jax.ShapeDtypeStruct(s, d, sharding=sh)
+
+        if args.sweep:
+            out["sweep"], _ = sweep(shape, args.iters, struct)
+        for name in () if args.no_layer else variants:
+            t0 = time.monotonic()
+            c = build(name).trace(*(struct(s, d) for s, d in shapes)).lower(
                 lowering_platforms=("tpu",)).compile()
             m = c.memory_analysis()
-            print(name, "compiled; temp bytes", m.temp_size_in_bytes,
-                  flush=True)
+            print(name, "compiled in %.1f s; temp bytes" % (
+                time.monotonic() - t0), m.temp_size_in_bytes, flush=True)
             if args.hlo:
                 os.makedirs(args.hlo, exist_ok=True)
                 with open(os.path.join(args.hlo, name + ".hlo"), "w") as fh:
@@ -204,16 +347,28 @@ def main() -> int:
     vals = [(0.02 if i else 1.0) * jax.random.normal(k, s, jnp.float32)
             .astype(d) for i, (k, (s, d)) in enumerate(zip(keys, shapes))]
     want = None
-    out = {"device": jax.devices()[0].device_kind, "shapes": vars(args)}
-    m_usual = int(rx.GROUPED_SLACK * t * args.top_k * n / args.total)
-    out["tails"] = tails(m_usual, e, f, n, m_usual * 2 // 3 + 5)
+    out["device"] = jax.devices()[0].device_kind
+    m_usual = usual_slots(shape)
+    out["tails"] = {plan: tails(m_usual, e, f, n, m_usual * 2 // 3 + 5, plan)
+                    for plan in ("ragged", "picked")}
     print("tails", json.dumps(out["tails"]), flush=True)
-    for name in variants:
+    if args.sweep:
+        out["sweep"], best = sweep(shape, args.iters)
+        variants["grouped.kernel_best_of_sweep"] = (
+            "grouped", best, None, None)
+        variants["grouped.kernel_best_of_sweep.remat_products"] = (
+            "grouped", best, "products", None)
+        print("best", json.dumps({str(k): v for k, v in best.items()}),
+              flush=True)
+    for name in () if args.no_layer else variants:
         fn = build(name)
         try:
+            t0 = time.monotonic()
             got = jax.block_until_ready(fn(*vals))
+            first = time.monotonic() - t0
         except Exception as ex:  # a variant the chip refuses
             out[name] = f"{type(ex).__name__}: {str(ex)[:200]}"
+            print(name, out[name], flush=True)
             continue
         t0 = time.monotonic()
         for _ in range(args.iters):
@@ -225,19 +380,23 @@ def main() -> int:
         if want is None:
             want = flat
         err = float(jnp.linalg.norm(flat - want) / jnp.linalg.norm(want))
-        out[name] = {"ms": ms, "grad_rel_l2_vs_dense": err}
+        out[name] = {"ms": ms, "grad_rel_l2_vs_dense": err,
+                     "first_call_s": first}
         print(name, json.dumps(out[name]), flush=True)
     # where the checkpointed layer's time goes, by the program's names
     # (a device plane: only a chip's trace has one)
-    for name in ("grouped.ragged_dot.remat_products",
-                 "grouped.ragged_dot.remat_products.overflow"
-                 ) if jax.default_backend() == "tpu" else ():
+    for name in [v for v in variants if v.endswith(".remat_products")
+                 or v.endswith(".overflow")
+                 ] if jax.default_backend() == "tpu" else ():
+        if not isinstance(out.get(name), dict):
+            continue
         fn = build(name)
         jax.block_until_ready(fn(*vals))
         out[name + ".by_part"] = by_part(fn, vals, args.iters)
         print(name, "\n".join(out[name + ".by_part"]), sep="\n", flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/expert_product_probe.json", "w") as fh:
+    with open(f"chiprun_out/expert_product_probe.{args.cell}.json",
+              "w") as fh:
         json.dump(out, fh, indent=1)
     return 0
 

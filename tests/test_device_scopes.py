@@ -267,13 +267,17 @@ def test_parse_round_trip(template, want):
 
 def test_one_place_emits_what_xla_renames():
     """`RENAMED_BY_XLA` names the ONE op and part that emit a primitive
-    XLA renames: `lax.ragged_dot` is called in `grouped_matmul` alone."""
+    XLA renames: `lax.ragged_dot` / `ragged_dot_general`, and since
+    PR 50 the kernels of `ops/pallas/grouped_matmul.py` that take their
+    place on a TPU and keep their scope path, are entered from
+    `ops/routed_experts.py`'s three `grouped_matmul*` functions alone,
+    all under `RoutedExperts | products`."""
     import subprocess
 
     assert scopes.RENAMED_BY_XLA == {
         "ragged-dot": ("RoutedExperts", "products")}
     found = subprocess.run(
-        ["grep", "-rlE", r"ragged_dot\(|\bgmm\(", "--include=*.py",
+        ["grep", "-rlE", r"ragged_dot(_general)?\(|import grouped_matmul", "--include=*.py",
          os.path.join(ROOT, "flexflow_tpu")],
         capture_output=True, text=True).stdout.split()
     assert [os.path.relpath(f, ROOT) for f in found] == [

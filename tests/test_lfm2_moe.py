@@ -108,6 +108,13 @@ def test_train_step_reports_the_routed_counts_without_a_wait(
     assert 1 <= len(spans) <= 3
     steps = [r for r in trace.spans() if r.name == "train_step"]
     by_id = {r.span_id: r for r in steps}
+    # the step that compiled says what its grouped products run on: off
+    # the TPU the ragged dot (the kernel path: test_grouped_kernel.py)
+    compiled_at = [r for r in steps[-4:] if r.args["first"]]
+    assert [(r.args["experts_product"], r.args["experts_tiling"])
+            for r in compiled_at] == [("ragged", "")]
+    assert not any("experts_product" in r.args for r in steps[-4:]
+                   if not r.args["first"])
     for r in spans:
         assert set(r.args) == {"step", "moe_pairs", "moe_dropped",
                                "moe_max_rows", "moe_hit",
@@ -129,6 +136,8 @@ def test_the_dense_product_reports_its_static_rows(seeded, batch):
     for _ in range(3):
         jax.block_until_ready(ff.train_step(inputs, labels)["loss"])
     last = [r for r in trace.spans() if r.name == "train_step.moe"][-1]
+    assert not any("experts_product" in r.args for r in trace.spans()[-40:]
+                   if r.name == "train_step")  # no grouped product
     # two routed layers x every held expert over every row
     assert last.args["moe_rows_computed"] == 2 * D["held"] * B * S
 

@@ -210,7 +210,7 @@ def unwritten_tails(monkeypatch):
     of `grouped_matmul` and (through it) `grouped_matmul_into_lhs`."""
     product = rx.grouped_matmul
 
-    def chips(lhs, rhs, sizes):
+    def chips(lhs, rhs, sizes, *rows_a_group):
         out = product(lhs, rhs, sizes)
         written = jnp.arange(out.shape[0]) < jnp.sum(sizes)
         return jnp.where(written[:, None], out, jnp.nan)

@@ -207,20 +207,31 @@ def test_pallas_entry_points_lower_for_tpu(devices8):
         assert text.count("tpu_custom_call") >= n_calls, name
 
 
-def test_pallas_entry_points_compile_for_v5e():
-    """Stronger than lowering: the TPU compiler itself (Mosaic, VMEM
-    allocation) accepts every kernel for a v5e 2x2 host.  Needs no
-    chip — libtpu's compile-only topology — and is skipped where that
-    cannot be created."""
+@pytest.fixture(scope="module")
+def v5e():
+    """libtpu's compile-only `v5e:2x2` topology: needs no chip; tests
+    that use it are skipped where it cannot be created."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no libtpu, no topology
         pytest.skip(f"no compile-only TPU topology here: {e!r}")
-    one = SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e):
+    """One described chip of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
+
+
+def test_pallas_entry_points_compile_for_v5e(v5e, v5e_chip):
+    """Stronger than lowering: the TPU compiler itself (Mosaic, VMEM
+    allocation) accepts every kernel for a v5e 2x2 host."""
+    topo, one = v5e, v5e_chip
 
     def on(args, sh):
         return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
@@ -233,6 +244,46 @@ def test_pallas_entry_points_compile_for_v5e():
                 ("data", "model"))
     for name, fn, args, _ in _paged_tp2(mesh):
         _lower(fn, args).compile()
+
+
+#: a routed layer of the two training cells: (usual slots, hidden,
+#: expert width, held, rows a group expects)
+GROUPED_LAYERS = {"cell6_lfm2": (12288, 2048, 1792, 8, 1024.0),
+                  "cell8_kimi": (3072, 2304, 1024, 8, 256.0)}
+
+
+@pytest.mark.parametrize("product", ["gate_up", "down", "d_act", "d_xs",
+                                     "d_w_gate_up", "d_w_down"])
+@pytest.mark.parametrize("cell", sorted(GROUPED_LAYERS))
+def test_grouped_products_compile_for_v5e(cell, product, v5e_chip,
+                                          monkeypatch):
+    """Each of the six grouped products of a routed layer's forward and
+    backward, at the training cells' real shapes and the tiling
+    `pick_grouped_tiling` gives a TPU: Mosaic accepts it within the
+    default fast memory, and the weight enters as it is stored (the
+    compiled program makes no transposed copy of it)."""
+    from flexflow_tpu.ops import routed_experts as rx
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m, e, f, g, rows = GROUPED_LAYERS[cell]
+    fn, lhs, rhs = {
+        "gate_up": (rx.grouped_matmul, (m, e), (g, e, f)),
+        "down": (rx.grouped_matmul, (m, f), (g, f, e)),
+        "d_act": (rx.grouped_matmul_into_lhs, (m, e), (g, f, e)),
+        "d_xs": (rx.grouped_matmul_into_lhs, (m, f), (g, e, f)),
+        "d_w_gate_up": (rx.grouped_matmul_into_rhs, (m, e), (m, f)),
+        "d_w_down": (rx.grouped_matmul_into_rhs, (m, f), (m, e)),
+    }[product]
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    text = _lower(lambda a, b, sizes: fn(a, b, sizes, rows),
+                  (S(lhs), S(rhs), S((g,), jnp.int32))).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    weight = "bf16[%d,%d,%d]" % (g, rhs[-1], rhs[-2])  # its transpose
+    assert not [line for line in text.splitlines() if weight in line
+                and (" copy(" in line or " transpose(" in line)]
 
 
 def test_paged_kernel_never_interpreted_on_tpu(monkeypatch):
