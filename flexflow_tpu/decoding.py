@@ -52,7 +52,10 @@ class DecoderRecipe:
     `exit_gate` names the op, if the family has one, whose output
     `[passes, b, s, 1]` is an exit gate's logit after every pass of a
     repeated region: the decode step program turns it into each row's
-    exit pdf (`exit_pdf`) and returns that beside the logits."""
+    exit pdf (`exit_pdf`) and returns that beside the logits.
+    `logit_columns` > 0: the decode step returns the head's first that
+    many columns only (a head that predicts several positions side by
+    side, of which the server samples the next: `dims["vocab_size"]`)."""
 
     family: str
     build: Callable
@@ -60,6 +63,7 @@ class DecoderRecipe:
     dims: Dict
     carries: frozenset
     exit_gate: str = ""
+    logit_columns: int = 0
 
 
 def decoder_recipe(ff: FFModel) -> DecoderRecipe:
@@ -74,7 +78,8 @@ def decoder_recipe(ff: FFModel) -> DecoderRecipe:
             "records its recipe (models.transformer.build_gpt, "
             "models.kimi_k2.build_kimi_k2, "
             "models.longcat_flash.build_longcat_flash, "
-            "models.qwen3_next.build_qwen3_next)")
+            "models.qwen3_next.build_qwen3_next, "
+            "models.evabyte.build_evabyte)")
     return recipe
 
 
@@ -653,6 +658,7 @@ def build_paged_decode_step(ffd: FFModel):
 
     ex = ffd.executor
     gate = _exit_gate_guid(ffd)
+    columns = decoder_recipe(ffd).logit_columns
 
     def step(weights, state, tokens, positions, block_table,
              row_tokens=None):
@@ -666,6 +672,8 @@ def build_paged_decode_step(ffd: FFModel):
         with scopes.scope(scopes.LOGITS):
             if gate is not None:
                 return logits[:, 0], new_state, exit_pdf(env[gate])
+            if columns:
+                return logits[:, 0, :columns], new_state
             return logits[:, 0], new_state
 
     with ex.mesh:
@@ -905,11 +913,18 @@ def build_slot_state_reset(ffd: FFModel):
     zeroes row `slot` of every `slot_state_entries` array (scalar int32
     id; state donated, so on TPU it is an in-place write).  The
     scheduler runs it when it gives the slot to a request, so a slot's
-    second request starts where a fresh server's first does."""
+    second request starts where a fresh server's first does.  State
+    that is masked by the sequence's own positions
+    (`Op.slot_state_resets` False) is left as it is; None where that
+    leaves nothing to zero."""
     import jax
 
     ex = ffd.executor
-    mine = slot_state_entries(ffd)
+    mine = {op.name: op.slot_state_entries()
+            for op in ffd.operators.topo_order()
+            if op.slot_state_entries() and op.slot_state_resets}
+    if not mine:
+        return None
 
     def reset(state, slot):
         return {

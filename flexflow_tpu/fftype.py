@@ -114,6 +114,7 @@ class OperatorType(enum.Enum):
     GATED_DELTA_NET = "gated_delta_net"
     KIMI_DELTA_ATTENTION = "kimi_delta_attention"
     SHORT_CONV = "short_conv"
+    EVA_ATTENTION = "eva_attention"
     # every pass's output of a repeated region, stacked (pcg LoopRegion)
     LOOP_PASSES = "loop_passes"
     # Elementwise

@@ -508,6 +508,18 @@ class FFModel:
             params, [input], name=self._name("gated_delta_net", name),
             slot_state=slot_state))
 
+    def eva_attention(self, input, params, name=None,
+                      slot_state: bool = False, max_seq: int = 0):
+        """EVA chunked attention (ops/eva_attention.py): `params` is an
+        `EvaAttentionParams`; `slot_state` builds the serving twin's
+        op, which carries a window of keys and values and a store of
+        chunk summaries a slot, for sequences of up to `max_seq`."""
+        from .ops.eva_attention import EvaAttention
+
+        return self._add(EvaAttention(
+            params, [input], name=self._name("eva_attention", name),
+            slot_state=slot_state, max_seq=max_seq))
+
     def kimi_delta_attention(self, input, params, name=None):
         """A Kimi Delta Attention mixer (ops/kimi_delta_attention.py):
         the delta rule with a decay per channel; `params` is a

@@ -15,7 +15,8 @@ The grammar (docs/OBSERVABILITY.md "Device scopes"):
   `GraphExecutor._exec_op`;
 * a part inside an op: `scope(<part>)`, one of `PARTS`, only where a
   question of the records needs it (`RoutedExperts`, the attention ops,
-  `GatedDeltaNet`, `KimiDeltaAttention`, `ShortConv`; `cast_weights`
+  `GatedDeltaNet`, `KimiDeltaAttention`, `ShortConv`, `EvaAttention`;
+  `cast_weights`
   where the executor casts an op's weight to the compute precision);
 * what is not an op: `scope(<one of NOT_OPS>)`: `loss`, `optimizer`,
   `metrics`, `logits` (the sink's way out of the graph: its cast to
@@ -47,9 +48,11 @@ ROUTED_PARTS = ("route", "dispatch", "products", "shared", "combine", "zero")
 #: parts of an attention op (`core` XOR `paged_read`), of a delta-net
 #: layer (`KimiDeltaAttention`: `gate` its decays and step sizes,
 #: `core` the delta rule, `norm_gate` the gated head norm) and of a
-#: short convolution
+#: short convolution; of `EvaAttention`: `summarise` the pooling of the
+#: chunks a step completes, `state_write` the step's keys and values
+#: into the window
 MIXER_PARTS = ("proj", "core", "paged_read", "conv", "recurrence", "out",
-               "gate", "norm_gate")
+               "gate", "norm_gate", "summarise", "state_write")
 PARTS = ROUTED_PARTS + MIXER_PARTS + (CAST_WEIGHTS,)
 #: `parse`'s part for an instruction the compiler made from one of the
 #: step program's ARGUMENTS (the layout copy of a weight or of a paged

@@ -174,6 +174,12 @@ class Op:
         each row really advances by."""
         return ()
 
+    #: whether the scheduler zeroes `slot_state_entries()` when it gives
+    #: the slot to a request.  False for state that is masked by the
+    #: sequence's own positions (`ops/eva_attention.py`): nothing of the
+    #: last tenant can be read, so there is nothing to zero
+    slot_state_resets: bool = True
+
     #: planes each of `cache_entries()` holds: one, or one per pass of
     #: the region that runs the op (`pcg.graph.LoopRegion`).  A paged
     #: pool of N planes is ONE array `[N x num_blocks, page, ...]`, and

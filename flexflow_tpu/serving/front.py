@@ -381,9 +381,11 @@ class ServingFront:
                 )
                 sp.set(slots=model.batch_slots,
                        pool_blocks=model.num_blocks)
-                if model.has_slot_state:
+                if model.rstate_bytes:
                     sp.set(rstate_bytes=model.rstate_bytes,
                            **model.gdn_ops)
+                if model.eva:  # windows and summary stores, per slot
+                    sp.set(eva_state_bytes=model.eva_state_bytes)
                 if model.loop:  # the twin's graph repeats a region
                     sp.set(**model.loop)
             return model

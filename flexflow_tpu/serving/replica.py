@@ -109,6 +109,11 @@ class SupervisedDecodeModel:
         self.rstate_bytes = getattr(model, "rstate_bytes", 0)
         self.rstate_rows_touched = getattr(model, "rstate_rows_touched",
                                            None)
+        # EVA layers' window and summary store: their geometry, their
+        # bytes and the dispatch counters' arithmetic (None without one)
+        self.eva = getattr(model, "eva", None)
+        self.eva_state_bytes = getattr(model, "eva_state_bytes", 0)
+        self.eva_rows = getattr(model, "eva_rows", None)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)
@@ -609,6 +614,10 @@ class ServingReplica:
             # dispatches, and the state's bytes
             if "rstate" in sstats:
                 out["rstate"] = sstats["rstate"]
+            # EVA layers: the `eva_*` dispatch args summed by program,
+            # their geometry and their state's bytes
+            if "eva" in sstats:
+                out["eva"] = sstats["eva"]
             # a graph that repeats a region: its regions, the weight
             # passes of the decode and prefill dispatches, and the exit
             # gate's pdf summed over the decode dispatches' live rows

@@ -214,6 +214,13 @@ class PagedKVDecodeModel:
             kv_page_size=page_size, kv_num_blocks=num_blocks,
             kv_kernel=self.paged_kernel, tp=self.tp,
         )
+        if not cache_entries(self.ffd):
+            # a twin without a paged pool (evabyte: all of a sequence's
+            # state is its slot's own arrays, allocated with the twin):
+            # the block table addresses nothing, so a sequence is
+            # admitted by what it will really hold, a slot, and the
+            # table is made wide enough never to refuse one
+            num_blocks = max(num_blocks, 1 + batch_slots * max_blocks)
         self.batch_slots = batch_slots
         self.page_size = page_size
         self.num_blocks = num_blocks
@@ -330,9 +337,36 @@ class PagedKVDecodeModel:
         # takes `row_tokens` in its step programs, and the scheduler
         # zeroes a slot's rows when it admits a request into it
         self._slot_state = slot_state_entries(self.ffd)
-        self.rstate_bytes = sum(
+        # EVA layers (ops/eva_attention.py) keep a third kind of state
+        # there: a window that fills and starts again and a store that
+        # grows a row a chunk, both masked by position, so the reset at
+        # admission has nothing of theirs to zero.  `eva` is their
+        # geometry (None without such a layer), `eva_state_bytes` what
+        # they hold; `rstate_bytes` stays the recurrent layers' alone
+        eva_ops = [op for op in self.ffd.operators.topo_order()
+                   if op.op_type == OperatorType.EVA_ATTENTION
+                   and op.slot_state_entries()]
+        bytes_of = lambda ops: sum(  # noqa: E731
             int(self._state[op][k].nbytes)
-            for op, names in self._slot_state.items() for k in names)
+            for op, names in self._slot_state.items() if op in ops
+            for k in names)
+        self.eva_state_bytes = bytes_of({op.name for op in eva_ops})
+        self.rstate_bytes = bytes_of(
+            set(self._slot_state) - {op.name for op in eva_ops})
+        self.eva = ({"window": eva_ops[0].params.window_size,
+                     "chunk": eva_ops[0].params.chunk_size,
+                     "store_rows": eva_ops[0].store_rows,
+                     "layers": len(eva_ops)} if eva_ops else None)
+        if self.eva:  # (the op's module is imported by such a twin only)
+            from ..ops.eva_attention import eva_row_counts
+
+            self._eva_row_counts = eva_row_counts
+        if self.eva and self.prefill_chunk > self.eva["window"]:
+            raise ConfigError(
+                f"prefill_chunk {self.prefill_chunk} is longer than "
+                f"{recipe.family}'s window_size {self.eva['window']}: a "
+                "prefill pass reads the window as it was and writes it "
+                "once, so it may cross one window boundary, not two")
         self._reset_slot_fn = (build_slot_state_reset(self.ffd)
                                if self._slot_state else None)
         # which recurrence the built programs take, asked of each
@@ -387,6 +421,18 @@ class PagedKVDecodeModel:
             return rows_live
         return self.batch_slots
 
+    def eva_rows(self, positions, counts) -> Optional[Dict[str, int]]:
+        """The `eva_*` args of a dispatch that advances row i over
+        `positions[i] .. + counts[i] - 1`, summed over the EVA layers
+        (`ops/eva_attention.py eva_row_counts`): host arithmetic on
+        host-owned lengths, no fetch.  None without such a layer."""
+        if self.eva is None:
+            return None
+        one = self._eva_row_counts(
+            self.eva["window"], self.eva["chunk"], self.eva["store_rows"],
+            self.batch_slots, positions, counts)
+        return {k: v * self.eva["layers"] for k, v in one.items()}
+
     def _row_tokens(self, row_tokens) -> tuple:
         """The step programs' trailing argument: `row_tokens` for a
         twin with per-slot state (required there), nothing otherwise."""
@@ -403,6 +449,8 @@ class PagedKVDecodeModel:
         the step stream by jax's state dependency, like copy_block."""
         import jax.numpy as jnp
 
+        if self._reset_slot_fn is None:  # state masked by position only
+            return
         with span("model.enqueue",
                   first=self._first_call("reset_slot_state")):
             self._state = self._reset_slot_fn(self._state, jnp.int32(slot))
@@ -749,9 +797,20 @@ class ContinuousScheduler:
         # dispatch had to advance against rows whose state the program
         # read and wrote (`model.rstate_rows_touched`)
         self._rstate = bool(getattr(model, "has_slot_state", False))
+        # EVA layers' window and summary store are per-slot state too
+        # (`row_tokens` goes to the step programs), but nothing of them
+        # is zeroed or counted as recurrent: their dispatches carry the
+        # `eva_*` args instead (`model.eva_rows`), summed here
+        self._eva_rows = (getattr(model, "eva_rows", None)
+                          if getattr(model, "eva", None) else None)
+        self.eva_totals: Optional[Dict[str, int]] = (
+            {"decode_dispatches": 0, "prefill_dispatches": 0}
+            if self._eva_rows is not None else None)
+        recurrent = self._rstate and (
+            self._eva_rows is None or getattr(model, "rstate_bytes", 0) > 0)
         self.rstate_totals: Optional[Dict[str, int]] = (
             dict.fromkeys(("rows_live", "rows_touched", "dispatches"), 0)
-            if self._rstate else None)
+            if recurrent else None)
         # bench/debug: run the pool's full invariant sweep after every
         # scheduler step (the serving_prefix leg's acceptance bar)
         self._check_invariants = bool(check_invariants)
@@ -1137,6 +1196,9 @@ class ContinuousScheduler:
             **({"rstate": dict(self.rstate_totals,
                                bytes=int(self.model.rstate_bytes))}
                if self.rstate_totals is not None else {}),
+            **({"eva": dict(self.eva_totals, **self.model.eva,
+                            state_bytes=int(self.model.eva_state_bytes))}
+               if self.eva_totals is not None else {}),
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1381,7 +1443,7 @@ class ContinuousScheduler:
                                         hit, plen)
             slot = free.pop(0)
             self._slots[slot] = live
-            if self._rstate:
+            if self.rstate_totals is not None:
                 # the slot's last tenant left its state behind
                 self.model.reset_slot_state(slot)
             # first private block (or a no-op after a full hit):
@@ -1559,6 +1621,18 @@ class ContinuousScheduler:
         t["rows_touched"] += touched
         t["dispatches"] += 1
 
+    def _note_eva(self, dispatch, program: str, positions, counts) -> None:
+        """The `eva_*` args of a dispatch span (`model.eva_rows`: what
+        the advancing rows' queries could see, what the program as
+        built reads, the summaries it writes) and their sums by
+        program."""
+        rows = self._eva_rows(positions, counts)
+        dispatch.set(**rows)
+        t = self.eva_totals
+        t[f"{program}_dispatches"] += 1
+        for k, v in rows.items():
+            t[f"{program}_{k}"] = t.get(f"{program}_{k}", 0) + v
+
     def _note_loop(self, dispatch, program: str, passes: int) -> None:
         """The `loop_steps` arg of a dispatch span (weight passes: the
         program's own passes times the region's) and, after a decode
@@ -1643,8 +1717,10 @@ class ContinuousScheduler:
                 # (recurrent state: riders advance by 0 tokens)
                 self.model.prefill_step(
                     tok, slen, btab, *((fed,) if self._rstate else ()))
-                if self._rstate:
+                if self.rstate_totals is not None:
                     self._note_rstate(dispatch, len(plan), C)
+                if self._eva_rows is not None:
+                    self._note_eva(dispatch, "prefill", slen, fed)
                 if self._loop_steps:
                     self._note_loop(dispatch, "prefill", passes)
                 # (the program is enqueued: this runs beside it) a plan
@@ -1986,7 +2062,10 @@ class ContinuousScheduler:
                 if self._rstate:
                     alive = (np.array([live is not None
                                        for live in self._slots], np.int32),)
+                if self.rstate_totals is not None:
                     self._note_rstate(dispatch, int(alive[0].sum()), 1)
+                if self._eva_rows is not None:
+                    self._note_eva(dispatch, "decode", self._slens, alive[0])
                 logits = self.model.step(
                     self._tokens, self._slens, self._btab, *alive)
                 if self._loop_steps:

@@ -4,7 +4,7 @@ the real size BEFORE the first benchmark run (PR 35: a hang inside the
 harness says nothing).
 
     chiprun --chips 1 -- python3 scripts/serve_step_probe.py \
-        --workload <cell> [--calls 10]
+        --workload <cell> [--calls 10] [--chunks 8,64,128]
 
 Builds the cell's server as the harness does (`build_server`, the
 seed's weights, `PagedKVDecodeModel` with the front's arguments), gives
@@ -13,7 +13,10 @@ decode step and the prefill program: the first call's seconds (trace,
 lower, compile or cache load), then `--calls` more, timed to the
 logits' arrival (host clock around a blocking call: dispatch included).
 Prints the weight tree's parameters and bytes, the pool's bytes, the
-device's `memory_stats` and one JSON line.  A serving family only."""
+device's `memory_stats` and one JSON line.  `--chunks` does that once
+for each `prefill_chunk` of the list in turn (the weights stay, the
+twin and its state are built anew), a JSON line each: the readings a
+configuration's `prefill_chunk` is chosen from.  A serving family only."""
 from __future__ import annotations
 
 import argparse
@@ -37,14 +40,15 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--seed", type=int, default=3000000019)
+    ap.add_argument("--chunks", default=None,
+                    help="prefill_chunk values to probe in turn "
+                         "(default: the configuration's own)")
     args = ap.parse_args()
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = harness.load_json(os.path.join(ROOT, entry["file"]))
     fam = harness.load_module("families", cfg["family"])
-    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
-
     out = {"device": jax.devices()[0].device_kind}
     t0 = time.monotonic()
     ff = fam.build_server(cfg, jax.devices()[:1])
@@ -52,11 +56,24 @@ def main() -> int:
     leaves = jax.tree.leaves(ff._weights)
     out["parameters"] = int(sum(x.size for x in leaves))
     out["weight_bytes"] = int(sum(x.nbytes for x in leaves))
+    chunks = ([int(x) for x in args.chunks.split(",")] if args.chunks
+              else [ff.config.prefill_chunk])
+    for chunk in chunks:
+        probe(ff, chunk, dict(out), args.calls, t0)
+        t0 = time.monotonic()
+    return 0
+
+
+def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
+    """One twin at `prefill_chunk`: its two programs timed, its line."""
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
     c = ff.config
     model = PagedKVDecodeModel(
         ff, batch_slots=c.serving_slots, page_size=c.kv_page_size,
         num_blocks=c.kv_pool_blocks or None, devices=jax.devices()[:1],
-        prefill_chunk=c.prefill_chunk, prefix_cache=c.prefix_cache)
+        prefill_chunk=prefill_chunk, prefix_cache=c.prefix_cache)
+    out["prefill_chunk"] = model.prefill_chunk
     out["build_s"] = round(time.monotonic() - t0, 1)
     out["paged_kernel"] = model.paged_kernel
     out["loop"] = model.loop
@@ -88,7 +105,7 @@ def main() -> int:
 
     for name, call in (("step", decode), ("prefill", prefill)):
         out[f"{name}_first_call_s"] = round(timed(call), 2)
-        times = [timed(call) for _ in range(args.calls)]
+        times = [timed(call) for _ in range(calls)]
         out[f"{name}_ms"] = round(1e3 * float(np.median(times)), 3)
         print(f"{name}: first {out[f'{name}_first_call_s']} s, then "
               + " ".join(f"{1e3 * t:.2f}" for t in times) + " ms",
@@ -102,7 +119,6 @@ def main() -> int:
     out["memory"] = {k: int(stats[k]) for k in (
         "bytes_in_use", "peak_bytes_in_use", "bytes_limit") if k in stats}
     print(json.dumps(out), flush=True)
-    return 0
 
 
 if __name__ == "__main__":
