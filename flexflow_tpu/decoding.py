@@ -24,7 +24,7 @@ are seq-independent).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -55,7 +55,11 @@ class DecoderRecipe:
     exit pdf (`exit_pdf`) and returns that beside the logits.
     `logit_columns` > 0: the decode step returns the head's first that
     many columns only (a head that predicts several positions side by
-    side, of which the server samples the next: `dims["vocab_size"]`)."""
+    side, of which the server samples the next: `dims["vocab_size"]`).
+    `head` names the ops after the last layer (final norm, head, exit
+    gate), every one per token: the prefill pass runs them on each
+    row's last real position, not on the chunk
+    (build_paged_prefill_pass; a family on the pass names them)."""
 
     family: str
     build: Callable
@@ -64,6 +68,7 @@ class DecoderRecipe:
     carries: frozenset
     exit_gate: str = ""
     logit_columns: int = 0
+    head: Tuple[str, ...] = ()
 
 
 def decoder_recipe(ff: FFModel) -> DecoderRecipe:
@@ -621,6 +626,26 @@ def _host_owned(state, block_table, seq_lens, row_tokens=None):
             for op, entries in state.items()}
 
 
+def _sampled_outputs(ffd: FFModel):
+    """finish(logits [b, 1, width], new_state, env) -> what a step
+    program hands the sampler, by the recipe: (logits [b, vocab],
+    new_state), the head's first `logit_columns` only where the recipe
+    says so, and each row's `exit_pdf` third for a family with an exit
+    gate."""
+    gate = _exit_gate_guid(ffd)
+    columns = decoder_recipe(ffd).logit_columns
+
+    def finish(logits, new_state, env):
+        with scopes.scope(scopes.LOGITS):
+            if gate is not None:
+                return logits[:, 0], new_state, exit_pdf(env[gate])
+            if columns:
+                return logits[:, 0, :columns], new_state
+            return logits[:, 0], new_state
+
+    return finish
+
+
 def build_paged_decode_step(ffd: FFModel):
     """ONE compiled step function for continuous batching on a paged
     decode twin (make_decoder with kv_page_size > 0):
@@ -657,8 +682,7 @@ def build_paged_decode_step(ffd: FFModel):
     import jax.numpy as jnp
 
     ex = ffd.executor
-    gate = _exit_gate_guid(ffd)
-    columns = decoder_recipe(ffd).logit_columns
+    finish = _sampled_outputs(ffd)
 
     def step(weights, state, tokens, positions, block_table,
              row_tokens=None):
@@ -669,12 +693,7 @@ def build_paged_decode_step(ffd: FFModel):
         logits, new_state, _, env = ex.run_forward(
             weights, state, inputs, training=False, rng=None,
         )
-        with scopes.scope(scopes.LOGITS):
-            if gate is not None:
-                return logits[:, 0], new_state, exit_pdf(env[gate])
-            if columns:
-                return logits[:, 0, :columns], new_state
-            return logits[:, 0], new_state
+        return finish(logits, new_state, env)
 
     with ex.mesh:
         return jax.jit(step, donate_argnums=(1,))
@@ -751,48 +770,82 @@ def build_paged_prefill_step(ffd: FFModel, chunk: int):
 
 
 def build_paged_prefill_pass(ffd: FFModel, chunk: int):
-    """build_paged_prefill_step's contract (same signature, state
-    donated, no logits, the jitted function still named `prefill`) as
-    ONE forward of the twin over [slots, C]: a dispatch streams the
-    weights once and builds each layer's gathered view once, where the
-    scan does both C times.
+    """The chunked-prefill program of a family whose recipe carries
+    `prefill_pass` (PagedKVDecodeModel chooses by that, never by a flag
+    or a name), as ONE forward of the twin over [slots, C] with the
+    decode step's outputs:
 
-    For a family whose recipe carries `prefill_pass`
-    (PagedKVDecodeModel chooses by that, never by a flag or a name).
+        prefill(weights, state, tokens[b, C], positions[b], block_table,
+                row_tokens[b])
+            -> (logits [b, vocab], new_state[, exit_pdf [b, passes]])
+
+    A dispatch streams the weights once and builds each layer's
+    gathered view once, where build_paged_prefill_step's scan does both
+    C times.  `row_tokens[i]` is how many of the chunk's C tokens row i
+    really has (0 for an idle slot): as far as per-slot state advances
+    (`_host_owned`), and `logits[i]` are the model's at the row's LAST
+    real token, position `positions[i] + row_tokens[i] - 1`.  So a row
+    past its prompt rides the pass with its pending token at column 0
+    and `row_tokens` 1, and is sampled from it as from the decode step
+    (serving/scheduler.py `plan_chunk_rows`); the jitted function keeps
+    the name `prefill`.  The recipe's `head` ops (final norm, head, exit
+    gate) read that one position of each row, gathered before them:
+    the head multiplies [b, hidden], not [b, C, hidden].
+    `logit_columns` and the exit gate's `exit_pdf` are applied as
+    build_paged_decode_step applies them.
+
     The twin's graph is interpreted over [b, C] inputs as it stands:
     the recipe's claim is that every op of it is per-token or takes
     the step's length from its input (ops/mla.py `_attend_paged_chunk`,
-    which also keeps the pad contract: positions >= max_seq write
+    which also keeps the pad contract: a row's columns past its real
+    tokens write past its own frontier, positions >= max_seq write
     scratch).  What the claim gives up is the scan's byte equality with
     seq-1 stepping (a [b*C, e] product is not rowwise-bitwise a [b, e]
     one); such a family's outputs are held to its reference by
-    tolerance, and it carries neither `speculative` nor `handoff`.
-    Without logits XLA drops the last layer's attention read, experts
-    and the head.  A twin with per-slot recurrent state is also passed
-    `row_tokens[b]`: the tokens of the chunk a row really has (0 for a
-    rider), which is as far as its state advances."""
+    tolerance, and it carries neither `speculative` nor `handoff`."""
     import jax
     import jax.numpy as jnp
+
+    from .config import ConfigError
 
     if chunk < 2:
         raise ValueError(f"chunk must be >= 2, got {chunk}")
     require_carried(ffd, "prefill_pass", "build_paged_prefill_pass")
     ex = ffd.executor
-    max_seq = _gpt_dims(ffd)["max_seq"]
+    recipe = decoder_recipe(ffd)
+    max_seq = recipe.dims["max_seq"]
+    finish = _sampled_outputs(ffd)
+    head = [op for op in ffd.operators.topo_order() if op.name in recipe.head]
+    if not head:
+        raise ConfigError(
+            f"{recipe.family} carries prefill_pass but its recipe names no "
+            "`head` ops: the pass cannot tell where the last layer ends")
+    made = {t.guid for op in head for t in op.outputs}
+    # what the head reads of the layers: [..., C, hidden] tensors
+    cut = {t.guid for op in head for t in op.inputs} - made
 
-    def prefill(weights, state, tokens, positions, block_table,
-                row_tokens=None):
+    def prefill(weights, state, tokens, positions, block_table, row_tokens):
         with scopes.scope(scopes.FEED):
             positions = positions.astype(jnp.int32)
+            row_tokens = row_tokens.astype(jnp.int32)
         state = _host_owned(state, block_table, positions, row_tokens)
         with scopes.scope(scopes.FEED):
             grid = positions[:, None] + jnp.arange(chunk, dtype=jnp.int32)
             inputs = {"input": tokens,
                       "positions": jnp.minimum(grid, max_seq - 1)}
-        _, new_state, _, _ = ex.run_forward(
+            last = jnp.clip(row_tokens - 1, 0, chunk - 1)[:, None, None]
+
+        def last_token(x):  # [..., b, C, hidden] -> [..., b, 1, hidden]
+            with scopes.scope(scopes.LOGITS):
+                return jnp.take_along_axis(
+                    x, last.reshape((1,) * (x.ndim - 3) + last.shape),
+                    axis=-2)
+
+        logits, new_state, _, env = ex.run_forward(
             weights, state, inputs, training=False, rng=None,
+            narrow=dict.fromkeys(cut, last_token),
         )
-        return new_state
+        return finish(logits, new_state, env)
 
     with ex.mesh:
         return jax.jit(prefill, donate_argnums=(1,))

@@ -19,7 +19,8 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -819,8 +820,14 @@ class GraphExecutor:
         inputs: Dict[str, jax.Array],
         training: bool,
         rng: Optional[jax.Array],
+        narrow: Optional[Dict[int, Callable]] = None,
     ):
-        """Interpret the PCG. Returns (sink_output, new_state, aux_losses, env)."""
+        """Interpret the PCG. Returns (sink_output, new_state, aux_losses, env).
+
+        `narrow` {tensor guid: fn}: an op outside a repeated region reads
+        that tensor as `fn(value)` (the prefill pass hands its head each
+        row's last real position, not the whole chunk:
+        decoding.build_paged_prefill_pass)."""
         env: Dict[int, jax.Array] = {}
         new_state = {k: dict(v) for k, v in state.items()}
         aux_losses: List[jax.Array] = []
@@ -841,6 +848,7 @@ class GraphExecutor:
             "new_state": new_state,
             "aux": aux_losses,
             "inputs": inputs,
+            "narrow": narrow,
             "training": training,
             "rng": rng,
             "to_compute": to_compute,
@@ -981,7 +989,7 @@ class GraphExecutor:
             aux: List[jax.Array] = []
             inner = dict(
                 ctx, state={**ctx["state"], **view}, new_state=written,
-                aux=aux,
+                aux=aux, narrow=None,
                 rng=(None if ctx["rng"] is None
                      else jax.random.fold_in(ctx["rng"], t)))
             local = {plan.in_guid: act}
@@ -1036,9 +1044,12 @@ class GraphExecutor:
         from .pcg.layout import NHWC, TO_NCHW_PERM, TO_NHWC_PERM
 
         want = self._op_layout.get(op.guid)
+        narrow = ctx.get("narrow") or {}
         ins = []
         for t in op.inputs:
             v = env[t.guid]
+            if t.guid in narrow:
+                v = narrow[t.guid](v)
             have_nhwc = self._t_layout.get(t.guid) == NHWC
             if want == "nhwc" and not have_nhwc and v.ndim == 4:
                 v = jnp.transpose(v, TO_NHWC_PERM)

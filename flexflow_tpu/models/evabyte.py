@@ -125,5 +125,5 @@ def build_evabyte(
               "max_seq": max_position_embeddings,
               "window_size": window_size, "chunk_size": chunk_size},
         carries=frozenset({"paged", "chunked_prefill", "prefill_pass"}),
-        logit_columns=vocab_size)
+        logit_columns=vocab_size, head=("final_norm", "lm_head"))
     return logits

@@ -170,5 +170,6 @@ def build_longcat_flash(
               "num_heads": num_attention_heads, "vocab_size": vocab_size,
               "max_seq": max_position_embeddings},
         carries=frozenset({"paged", "prefix_cache", "chunked_prefill",
-                           "prefill_pass"}))
+                           "prefill_pass"}),
+        head=("final_norm", "lm_head"))
     return logits

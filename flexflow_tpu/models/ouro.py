@@ -130,5 +130,7 @@ def build_ouro(
               "loop_steps": total_ut_steps},
         carries=frozenset({"paged", "chunked_prefill", "prefill_pass",
                            "pallas_read", "prefix_cache"}),
-        exit_gate=EXIT_GATE)
+        exit_gate=EXIT_GATE,
+        # (the final norm is the region's last op: it runs every pass)
+        head=(EXIT_GATE, "lm_head"))
     return logits

@@ -164,5 +164,6 @@ def build_qwen3_next(
               "num_heads": num_attention_heads,
               "num_kv_heads": num_key_value_heads, "vocab_size": vocab_size,
               "max_seq": max_position_embeddings},
-        carries=frozenset({"paged", "chunked_prefill", "prefill_pass"}))
+        carries=frozenset({"paged", "chunked_prefill", "prefill_pass"}),
+        head=("final_norm", "lm_head"))
     return logits

@@ -45,8 +45,8 @@ Two shapes, one set of weights:
   input from position 0, a window of queries at a time;
 * per-slot state: a step of s tokens a row starts at the row's
   `seq_lens[i]` and advances by `row_tokens[i]` of them (host-owned: 0
-  for an idle slot or a rider, 1 on a decode step, up to s on a prefill
-  chunk).  The step reads the window AS IT WAS, attends the step's own
+  for an idle slot, 1 on a decode step or past the prompt in a pass, up
+  to s on a chunk).  The step reads the window AS IT WAS, with its own
   keys beside it, and only then writes: a step may therefore start and
   end anywhere, across a chunk's or a window's end.  It writes the
   summary of every chunk whose LAST position it holds, before the read

@@ -25,9 +25,9 @@ Two shapes, one set of weights:
   its whole `[b, s]` input: what a trainer or a one-shot forward runs;
 * per-slot state: a step of s tokens a row starts from the row's own
   state and returns it advanced by the row's `row_tokens[i]` tokens
-  (host-owned, like `block_table`: 0 for an idle slot or a rider, 1 on
-  a decode step, up to s on a prefill chunk).  Positions past a row's
-  count leave its state exactly as it was (`exp(0) S + k 0`), so s == 1
+  (host-owned, like `block_table`: 0 for an idle slot, 1 on a decode
+  step or past the prompt in a pass, up to s on a chunk).  Steps past it
+  leave a row's state exactly as it was (`exp(0) S + k 0`), so s == 1
   is the decode step and s == C a prefill chunk in one pass.
 
 Which recurrence runs is chosen from what can be observed

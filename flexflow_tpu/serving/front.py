@@ -816,88 +816,106 @@ class ServingFront:
         The base front never diverts."""
         return None
 
+    def _book_next(self):
+        """Under `_cv`: the head of the backlog, popped and booked on
+        the replica picked for it, as `(req, replica, divert)` for
+        `_hand_over`; None when the backlog is empty or no replica has
+        room for its head."""
+        if not self._admission:
+            return None
+        replica = self._pick_replica(self._admission[0])
+        if replica is None:
+            return None
+        req = self._admission.popleft()
+        if req.trace is not None:
+            # dispatch span: covers the routing decision (and
+            # any disagg cost pricing — _divert_plan annotates
+            # it) through the replica submit
+            req.trace.end("queue")
+            req.trace.begin("dispatch",
+                            replica=replica.replica_id,
+                            role=replica.role)
+        # disaggregation hook (serving/disagg.py): a subclass
+        # may claim the request for a prefill pass + KV
+        # migration instead of direct dispatch.  The decision
+        # runs under _cv (it books outstanding slots); the
+        # returned thunk runs OUTSIDE the lock (it submits).
+        divert = self._divert_plan(req, replica)
+        if divert is None:
+            replica.outstanding += 1
+            self._observe_depth(replica)
+        return req, replica, divert
+
+    def _hand_over(self, req: FrontRequest, replica: ServingReplica,
+                   divert: Optional[Callable]) -> None:
+        """Outside the lock: submit what `_book_next` booked."""
+        if divert is not None:
+            divert()
+            return
+        try:
+            replica.submit(
+                req.prompt, req.max_new_tokens, req.temperature,
+                trace=req.trace, seed=req.seed, resume=req.resume,
+                on_done=lambda h, _req=req, _r=replica:
+                    self._on_settle(_req, _r, h),
+            )
+            if req.trace is not None:
+                req.trace.end("dispatch")
+        except ValueError as e:
+            # pool geometry can never serve it: the request's
+            # problem, fail alone
+            with self._cv:
+                replica.outstanding -= 1
+                self._observe_depth(replica)
+            self._fail(req, e)
+        except Exception:
+            # the replica died between pick and submit: back to the
+            # queue head (dispatch never started — no retry spent).
+            # Mid-terminate the residue sweep may already have run,
+            # so requeueing would strand the request until close()
+            # fails it NON-retriably — settle it 503 instead, as
+            # the terminate contract promises.
+            shed_req = None
+            with self._cv:
+                replica.outstanding -= 1
+                self._observe_depth(replica)
+                if self._terminating or self._closed:
+                    shed_req = req
+                else:
+                    if req.trace is not None:
+                        req.trace.end("dispatch", died=True)
+                        req.trace.begin("queue", requeued=True)
+                    self._admission.appendleft(req)
+            if shed_req is not None:
+                self._fail(shed_req, ServiceUnavailable(
+                    "serving front terminated before this request "
+                    "was dispatched",
+                    retry_after_s=self._retry_after(),
+                ))
+
     def _dispatch_loop(self) -> None:
         while True:
             with self._cv:
-                replica = None
+                booked = None
                 while not self._closed:
                     if self._admission:
                         if self._all_permanently_dead():
                             break
-                        replica = self._pick_replica(self._admission[0])
-                        if replica is not None:
+                        booked = self._book_next()
+                        if booked is not None:
                             break
                     self._cv.wait(0.2)
                 if self._closed:
                     return
-                req = self._admission.popleft()
-                if replica is None:  # every replica permanently dead
-                    self._fail(req, ServiceUnavailable(
+                if booked is None:  # every replica permanently dead
+                    self._fail(self._admission.popleft(),
+                               ServiceUnavailable(
                         "all serving replicas are permanently dead "
                         "(restart budgets exhausted)",
                         retry_after_s=self.shed_retry_after_s,
                     ))
                     continue
-                if req.trace is not None:
-                    # dispatch span: covers the routing decision (and
-                    # any disagg cost pricing — _divert_plan annotates
-                    # it) through the replica submit
-                    req.trace.end("queue")
-                    req.trace.begin("dispatch",
-                                    replica=replica.replica_id,
-                                    role=replica.role)
-                # disaggregation hook (serving/disagg.py): a subclass
-                # may claim the request for a prefill pass + KV
-                # migration instead of direct dispatch.  The decision
-                # runs under _cv (it books outstanding slots); the
-                # returned thunk runs OUTSIDE the lock (it submits).
-                divert = self._divert_plan(req, replica)
-                if divert is None:
-                    replica.outstanding += 1
-                    self._observe_depth(replica)
-            if divert is not None:
-                divert()
-                continue
-            try:
-                replica.submit(
-                    req.prompt, req.max_new_tokens, req.temperature,
-                    trace=req.trace, seed=req.seed, resume=req.resume,
-                    on_done=lambda h, _req=req, _r=replica:
-                        self._on_settle(_req, _r, h),
-                )
-                if req.trace is not None:
-                    req.trace.end("dispatch")
-            except ValueError as e:
-                # pool geometry can never serve it: the request's
-                # problem, fail alone
-                with self._cv:
-                    replica.outstanding -= 1
-                    self._observe_depth(replica)
-                self._fail(req, e)
-            except Exception:
-                # the replica died between pick and submit: back to the
-                # queue head (dispatch never started — no retry spent).
-                # Mid-terminate the residue sweep may already have run,
-                # so requeueing would strand the request until close()
-                # fails it NON-retriably — settle it 503 instead, as
-                # the terminate contract promises.
-                shed_req = None
-                with self._cv:
-                    replica.outstanding -= 1
-                    self._observe_depth(replica)
-                    if self._terminating or self._closed:
-                        shed_req = req
-                    else:
-                        if req.trace is not None:
-                            req.trace.end("dispatch", died=True)
-                            req.trace.begin("queue", requeued=True)
-                        self._admission.appendleft(req)
-                if shed_req is not None:
-                    self._fail(shed_req, ServiceUnavailable(
-                        "serving front terminated before this request "
-                        "was dispatched",
-                        retry_after_s=self._retry_after(),
-                    ))
+            self._hand_over(*booked)
 
     def _observe_depth(self, replica: ServingReplica) -> None:
         if self.registry is not None:
@@ -954,7 +972,20 @@ class ServingFront:
         with self._cv:
             replica.outstanding -= 1
             self._observe_depth(replica)
+            # a completion's room goes to the head of the backlog
+            # HERE, on the thread that made it (a decode loop between
+            # two dispatches), so that scheduler's next admission finds
+            # the request.  Left to the dispatcher, the hand-over raced
+            # that admission, and the slot stood empty for an iteration
+            # in some runs and not in others.  A failure's room waits
+            # for the dispatcher: the failed request goes back to the
+            # head first (below), and keeps its seniority.
+            booked = None
+            if handle.error is None and not self._closed:
+                booked = self._book_next()
             self._cv.notify_all()
+        if booked is not None:
+            self._hand_over(*booked)
         err = handle.error
         if err is None:
             self._complete(req, handle, role=replica.role)

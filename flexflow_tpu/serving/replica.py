@@ -50,7 +50,8 @@ FATAL_DECODE_FAULTS = (DeviceLossFault, HungStepFault, HungStepTimeout)
 
 #: per-replica scheduler counters folded into `stats()` across restarts
 _CARRIED_COUNTERS = ("batches_run", "requests_done", "tokens_generated",
-                     "step_failures", "admitted", "queue_wait_s_sum")
+                     "pass_decode_tokens", "step_failures", "admitted",
+                     "queue_wait_s_sum")
 
 
 class SupervisedDecodeModel:
@@ -164,16 +165,20 @@ class SupervisedDecodeModel:
             e.fatal_to_engine = True
             raise
 
-    def prefill_step(self, tokens, positions, block_tables, *row_tokens):
+    def prefill_step(self, tokens, positions, block_tables, *row_tokens,
+                     meanwhile=None):
         # chunked prefill is a decode-fleet step like any other: fault
         # injection and the hang watchdog see it under the same
         # replica-lifetime step index
+        # (`meanwhile`: the one-pass program's; see the model's)
+        beside = {} if meanwhile is None else {"meanwhile": meanwhile}
         idx = next(self._steps)
         try:
             self._fault_plan.check_step(idx)
             return self._watchdog.sync(
                 lambda: self._model.prefill_step(
-                    tokens, positions, block_tables, *row_tokens),
+                    tokens, positions, block_tables, *row_tokens,
+                    **beside),
                 step=idx,
             )
         except FATAL_DECODE_FAULTS as e:

@@ -30,10 +30,21 @@ keeps the decode step's shapes (a family whose recipe carries
 `prefill_pass` runs the chunk as one forward instead, equal by
 tolerance: PagedKVDecodeModel).
 
+The step plan, an iteration (`plan_chunk_rows`, `_iteration`): while
+any live row is still feeding its prompt, the scan's family (GPT) pays
+TWO dispatches, the chunk over the feeding rows (the others ride it on
+scratch) and then the decode step of every row; a family on the
+one-pass program pays ONE, because that pass returns each row's logits
+at its last real token: every live row is a row of it, a row past its
+prompt with its pending token and one token fed, and is sampled from
+it.  When no row is feeding, the plain decode step runs.  Nothing
+switches this: the recipe's `prefill_pass` and whether a row feeds.
+
 Shape discipline (the TPU-native part): one compiled [slots, 1] step
-program serves the engine's whole lifetime — admissions, retirements
-and per-row positions are DATA (block tables + seq_lens), never
-shapes, so steady state has zero recompiles.  Sampling is host-side
+program (and one [slots, C] chunk program) serves the engine's whole
+lifetime — admissions, retirements and per-row positions are DATA
+(block tables + seq_lens), never shapes, so steady state has zero
+recompiles.  Sampling is host-side
 per row, which also lifts the static batcher's same-temperature
 coalescing restriction: a continuous batch freely mixes temperatures.
 
@@ -133,7 +144,10 @@ class PagedKVDecodeModel:
         carries `prefill_pass`: kimi_k2's latent cache): one forward
         over [b, C], the weights streamed and each layer's view built
         once a dispatch; equal to seq-1 stepping by tolerance, not by
-        bytes, for a family that carries none of those three.
+        bytes, for a family that carries none of those three.  It
+        returns host logits [b, vocab] at each row's last real token
+        (`row_tokens`), so the scheduler samples from it and a row
+        past its prompt rides it with its one pending token.
 
     copy_block(src, dst) is the prefix cache's copy-on-write primitive
     (one physical block cloned across every layer's pool, compiled
@@ -433,15 +447,17 @@ class PagedKVDecodeModel:
             self.batch_slots, positions, counts)
         return {k: v * self.eva["layers"] for k, v in one.items()}
 
-    def _row_tokens(self, row_tokens) -> tuple:
+    def _row_tokens(self, row_tokens, one_pass: bool = False) -> tuple:
         """The step programs' trailing argument: `row_tokens` for a
-        twin with per-slot state (required there), nothing otherwise."""
-        if not self._slot_state:
+        twin with per-slot state and for the one-pass prefill program
+        (required there), nothing otherwise."""
+        if not (self._slot_state or one_pass):
             return ()
         if row_tokens is None:
             raise ValueError(
-                "this twin carries per-slot recurrent state: its step "
-                "programs need row_tokens (how far each row advances)")
+                "this twin carries per-slot recurrent state, or this is "
+                "its one-pass prefill program: it needs row_tokens (how "
+                "far each row advances)")
         return (np.asarray(row_tokens, np.int32),)
 
     def reset_slot_state(self, slot: int) -> None:
@@ -465,7 +481,13 @@ class PagedKVDecodeModel:
                 self.ffd._weights, self._state, tokens, seq_lens,
                 block_tables, *self._row_tokens(row_tokens),
             )
-        # the wait for the device, then the logits' copy to the host
+        return self._fetch(logits, exit_pdf, moe=True)
+
+    def _fetch(self, logits, exit_pdf, moe: bool) -> np.ndarray:
+        """The wait for the device, then the logits' copy to the host
+        and with it what the program returned beside them: the rows'
+        exit pdf (`exit_last`) and, with `moe`, the routed layers'
+        counts (`moe_last`)."""
         with span("model.fetch"):
             if exit_pdf:
                 import jax
@@ -473,7 +495,7 @@ class PagedKVDecodeModel:
                 logits, self.exit_last = jax.device_get(
                     (logits, exit_pdf[0]))
                 return np.asarray(logits, np.float32)
-            if not self._moe_ops:
+            if not (moe and self._moe_ops):
                 return np.asarray(logits, np.float32)
             import jax
 
@@ -498,14 +520,31 @@ class PagedKVDecodeModel:
         return 1
 
     def prefill_step(self, tokens: np.ndarray, positions: np.ndarray,
-                     block_tables: np.ndarray, row_tokens=None) -> None:
+                     block_tables: np.ndarray, row_tokens=None,
+                     meanwhile=None) -> Optional[np.ndarray]:
         """Chunked prefill: scatter tokens[b, C] at positions[b]..+C-1
-        into the pool.  No logits come back — prefill ignores them."""
+        into the pool.  The scan returns nothing (the last prompt token
+        runs through the decode program).  The one-pass program
+        (`prefill_passes` 1) takes `row_tokens` whatever the family and
+        returns host logits [b, vocab] at each row's last real token,
+        fetched as `step` fetches its own (the routed layers' counts
+        are left where they are: a pass routes its pad columns too).
+        `meanwhile()` is called once the program is enqueued, before
+        the wait for it: host work that needs no result of the
+        dispatch runs beside the device, not after it."""
+        one_pass = self.prefill_passes == 1
         with span("model.enqueue", first=self._first_call("prefill")):
-            self._state = self._prefill_fn(
+            out = self._prefill_fn(
                 self.ffd._weights, self._state, tokens, positions,
-                block_tables, *self._row_tokens(row_tokens),
+                block_tables, *self._row_tokens(row_tokens, one_pass),
             )
+        if meanwhile is not None:
+            meanwhile()
+        if not one_pass:
+            self._state = out
+            return None
+        logits, self._state, *exit_pdf = out
+        return self._fetch(logits, exit_pdf, moe=False)
 
     def verify_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
                     counts: np.ndarray,
@@ -730,6 +769,31 @@ class _LiveTrace:
                       batch_spans=self.batch_refs)
 
 
+def plan_chunk_rows(slots, chunk: int, sampled: bool) -> List[tuple]:
+    """THE step plan of a chunked-prefill dispatch, a pure function of
+    the slots: `[(slot, live, fed)]`, the rows of the [slots, C]
+    program that really advance and by how many tokens each; `[]` when
+    no row is still feeding its prompt (no chunk dispatch: the plain
+    decode step runs).
+
+    `sampled` False (the scan returns no logits): the feeding rows
+    alone, each up to its feed's last token but one — that one runs
+    through the decode program, whose logits seed sampling — and the
+    decode dispatch follows.  `sampled` True (the one-pass program
+    returns each row's logits at its last real token): EVERY live row.
+    A feeding row takes up to C tokens, its feed's last included; a row
+    past its prompt (or with only that last token left) takes its one
+    pending token.  Every row whose feed ends inside the dispatch is
+    sampled from it, and no decode dispatch follows."""
+    left = [(i, live, len(live.feed) - live.pos)
+            for i, live in enumerate(slots) if live is not None]
+    if not any(n > 1 for _, _, n in left):
+        return []
+    if sampled:
+        return [(i, live, max(1, min(chunk, n))) for i, live, n in left]
+    return [(i, live, min(chunk, n - 1)) for i, live, n in left if n > 1]
+
+
 class ContinuousScheduler:
     """Persistent decode loop with iteration-level admission/retirement.
 
@@ -766,6 +830,11 @@ class ContinuousScheduler:
         self._passes = int(getattr(model, "prefill_passes", self._chunk))
         if self._chunk and getattr(model, "prefill_step", None) is None:
             self._chunk = 0
+        # the one-pass program returns each row's logits at its last
+        # real token: while a row is feeding, an iteration is that ONE
+        # dispatch with every live row in it (`plan_chunk_rows`)
+        self._pass_samples = bool(self._chunk) and self._passes == 1
+        self.pass_decode_tokens = 0  # tokens sampled from such passes
         self._can_cow = getattr(model, "copy_block", None) is not None
         # fused-kernel read telemetry (docs/SERVING.md "Fused paged
         # attention"): under paged_kernel="pallas" every dispatch
@@ -1119,6 +1188,7 @@ class ContinuousScheduler:
             "prefill_steps": self.prefill_steps,
             "prefill_chunk": self._chunk,
             "prefill_passes": self._passes,
+            "pass_decode_tokens": self.pass_decode_tokens,
             "requests_done": self.requests_done,
             "tokens_generated": self.tokens_generated,
             "step_failures": self.step_failures,
@@ -1635,18 +1705,18 @@ class ContinuousScheduler:
 
     def _note_loop(self, dispatch, program: str, passes: int) -> None:
         """The `loop_steps` arg of a dispatch span (weight passes: the
-        program's own passes times the region's) and, after a decode
-        dispatch of a model with an exit gate, `exit_mass_<t>`: the
-        exit pdf after pass t, mean over the dispatch's live rows
-        (`model.exit_last`, fetched with the logits).  Summed into
-        `loop_totals`."""
+        program's own passes times the region's) and, after a dispatch
+        that returns logits (decode, or the one-pass prefill) of a
+        model with an exit gate, `exit_mass_<t>`: the exit pdf after
+        pass t, mean over the dispatch's live rows (`model.exit_last`,
+        fetched with the logits).  Summed into `loop_totals`."""
         t = self.loop_totals
         steps = passes * self._loop_steps
         dispatch.set(loop_steps=steps)
         t[f"{program}_dispatches"] += 1
         t[f"{program}_weight_passes"] += steps
         pdf = getattr(self.model, "exit_last", None)
-        if program != "decode" or pdf is None:
+        if pdf is None or not (program == "decode" or self._pass_samples):
             return
         live = [i for i, s in enumerate(self._slots) if s is not None]
         if not live:
@@ -1679,78 +1749,113 @@ class ContinuousScheduler:
             reg.counter("serving/paged_dense_bytes_avoided").inc(
                 max(0, dense_blocks - blocks) * self._kv_block_bytes)
 
-    def _prefill_chunk_step(self, pre) -> bool:
-        """One [slots, C] chunked-prefill dispatch advancing every
-        mid-prefill row by up to C prompt tokens (never past plen-1:
-        the last prompt token runs through the decode program, whose
-        logits seed sampling).  Decode-phase rows ride along pointed
-        at scratch (all-zero table row, position 0), and a prefill
-        row's trailing pad tokens write garbage only at positions
-        PAST its own frontier — overwritten by its later real writes
-        before any query can attend them, or absorbed by scratch via
-        the table padding — the same argument that makes idle-slot
-        writes safe.  Returns False after a transient fault (already
-        handled); fatal faults propagate."""
-        C, passes = self._chunk, self._passes
+    def _prefill_chunk_step(self, plan) -> bool:
+        """One [slots, C] chunked-prefill dispatch advancing the rows of
+        `plan` (`plan_chunk_rows`) by their tokens.
+
+        The scan (GPT) returns no logits: its rows are the feeding rows,
+        never past plen-1 (the last prompt token runs through the
+        decode program, whose logits seed sampling), and decode-phase
+        rows ride along pointed at scratch (all-zero table row,
+        position 0).  The one-pass program returns every row's logits
+        at its last real token, so every live row is a row of it at its
+        own position and table: a feeding row with up to C tokens of its
+        feed, the last included, a row past its prompt with its pending
+        token at column 0 and one token fed; the rows whose feed is
+        exhausted are then sampled from the pass (`_sample_rows`) and
+        the iteration ends there.
+
+        Either way a row's trailing pad columns write garbage only at
+        positions PAST its own frontier — overwritten by its later real
+        writes before any query can attend them, or absorbed by scratch
+        via the table padding — the same argument that makes idle-slot
+        writes safe; per-slot state advances by the row's real tokens
+        alone (`row_tokens`).  Returns False after a transient fault
+        (already handled); fatal faults propagate."""
+        C, passes, sampled = self._chunk, self._passes, self._pass_samples
         with span("sched.prefill.prepare"):
             tok = np.zeros((self.model.batch_slots, C), np.int32)
             slen = np.zeros(self.model.batch_slots, np.int32)
             fed = np.zeros(self.model.batch_slots, np.int32)
             btab = np.zeros_like(self._btab)
-            plan = []
-            real = 0  # prompt tokens this dispatch really advances
-            for i, live in pre:
-                flen = len(live.feed)
-                upto = min(live.pos + C, flen - 1)
-                self.pool.extend(live.seq_id, upto, written=live.pos)
-                self._btab[i] = self.pool.table_row(live.seq_id)
-                tok[i, :upto - live.pos] = live.feed[live.pos:upto]
+            real = ends = 0  # tokens really advanced; rows to sample
+            for i, live, n in plan:
+                if self.pool.extend(live.seq_id, live.pos + n,
+                                    written=live.pos):
+                    self._btab[i] = self.pool.table_row(live.seq_id)
+                tok[i, :n] = (live.feed[live.pos:live.pos + n]
+                              if live.pos < len(live.feed)
+                              else live.next_token)
                 slen[i] = live.pos
-                fed[i] = upto - live.pos
+                fed[i] = n
                 btab[i] = self._btab[i]
-                plan.append((i, live, upto))
-                real += upto - live.pos
+                real += n
+                ends += live.pos + n >= len(live.feed)
+        lives = [live for _, live, _ in plan]
         try:
             with span("sched.prefill.dispatch", rows=len(plan),
                       tokens=real, passes=passes,
-                      capacity=self.model.batch_slots * C) as dispatch:
-                # (recurrent state: riders advance by 0 tokens)
-                self.model.prefill_step(
-                    tok, slen, btab, *((fed,) if self._rstate else ()))
-                if self.rstate_totals is not None:
-                    self._note_rstate(dispatch, len(plan), C)
-                if self._eva_rows is not None:
-                    self._note_eva(dispatch, "prefill", slen, fed)
+                      capacity=self.model.batch_slots * C,
+                      **({"decode_rows": ends} if sampled else {}),
+                      ) as dispatch:
+                reads = {}
+
+                def count():
+                    # the dispatch's counters, taken while the program
+                    # runs: the scan is enqueued and returns at once,
+                    # the one-pass program calls this before it waits
+                    # for its logits (`meanwhile`)
+                    if self.rstate_totals is not None:
+                        self._note_rstate(dispatch, len(plan), C)
+                    if self._eva_rows is not None:
+                        self._note_eva(dispatch, "prefill", slen, fed)
+                    # a plan row's prefix is read once a pass: by the
+                    # scan at each of its C positions, by the one-pass
+                    # program once, up to the chunk's last; the scan's
+                    # riders sit on scratch and read nothing
+                    counts = np.zeros_like(slen)
+                    counts[[i for i, _, _ in plan]] = passes
+                    first_read = slen + (C - passes)
+                    reads.update(self._kv_reads(first_read, counts,
+                                                steps=passes))
+                    dispatch.set(**reads)
+
+                # (`row_tokens`: the scan's riders advance by 0 tokens,
+                # the pass's rows by their real tokens)
+                if sampled:
+                    logits = self.model.prefill_step(
+                        tok, slen, btab, fed, meanwhile=count)
+                else:
+                    logits = self.model.prefill_step(
+                        tok, slen, btab, *((fed,) if self._rstate else ()))
+                    count()
                 if self._loop_steps:
+                    # (the exit pdf comes back with the logits)
                     self._note_loop(dispatch, "prefill", passes)
-                # (the program is enqueued: this runs beside it) a plan
-                # row's prefix is read once a pass: by the scan at each
-                # of its C positions, by the one-pass program once, up
-                # to the chunk's last; riders sit on scratch and read
-                # nothing
-                counts = np.zeros_like(slen)
-                counts[[i for i, _, _ in plan]] = passes
-                first_read = slen + (C - passes)
-                reads = self._kv_reads(first_read, counts, steps=passes)
-                dispatch.set(**reads)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise
             self._fail_inflight(e)
             return False
-        self._share_dispatch(dispatch, [live for _, live in pre])
-        for _, live in pre:
-            if live.tspan is not None:
-                live.tspan.ref_chunk(dispatch)
+        self._share_dispatch(dispatch, lives)
         self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.prefill_steps += 1
         self._note_kernel_reads(reads)
-        for i, live, upto in plan:
-            live.pos = upto
+        if sampled:
+            self.batches_run += 1  # (a dispatch whose logits are sampled)
+            t0 = self.tokens_generated
+            with self._sample_span():
+                self._sample_rows(logits, dispatch, fed)
+            self.pass_decode_tokens += self.tokens_generated - t0
+            return True
+        for i, live, n in plan:
+            if live.tspan is not None:
+                live.tspan.ref_chunk(dispatch)
+            live.pos += n
             # the freshly written prompt blocks join the prefix index
             # NOW, so a same-prefix arrival in the next admit already
             # shares them
-            self.pool.note_written(live.seq_id, upto)
+            self.pool.note_written(live.seq_id, live.pos)
             live.next_token = live.feed[live.pos]
             self._tokens[i] = live.next_token
             self._slens[i] = live.pos
@@ -2011,14 +2116,22 @@ class ContinuousScheduler:
                     pass
             return True
         if self._chunk:
-            # chunked prefill first: mid-prefill rows jump up to C
-            # positions, then everyone (them included) takes the
-            # normal one-token decode step below
-            pre = [(i, live) for i, live in enumerate(self._slots)
-                   if live is not None
-                   and live.pos < len(live.feed) - 1]
-            if pre and not self._prefill_chunk_step(pre):
-                return True
+            # while a row is feeding its prompt, chunked prefill first.
+            # The scan: mid-prefill rows jump up to C positions, then
+            # everyone (them included) takes the normal one-token
+            # decode step below.  The one-pass program: every live row
+            # is a row of that dispatch, the rows past their prompt
+            # take their token from its logits, and the iteration is
+            # that ONE dispatch
+            plan = plan_chunk_rows(self._slots, self._chunk,
+                                   self._pass_samples)
+            if plan:
+                ran = self._prefill_chunk_step(plan)
+                if ran and self._pass_samples:
+                    with span("sched.observe"):
+                        self._observe_step()
+                if not ran or self._pass_samples:
+                    return True
         props = None
         with span("sched.decode.prepare"):
             decoding = feeding = 0
@@ -2101,14 +2214,22 @@ class ContinuousScheduler:
             self._observe_step()
         return True
 
-    def _sample_rows(self, logits, dispatch) -> None:
-        """After a decode dispatch: advance every live row, sample the
-        rows past their prompt, retire the finished."""
+    def _sample_rows(self, logits, dispatch, fed=None) -> None:
+        """After a dispatch that returned a row of logits a slot:
+        advance every live row (by one token after a decode dispatch,
+        by `fed[slot]` after a one-pass prefill), sample the rows past
+        their prompt, retire the finished."""
         now = time.monotonic()
         for i, live in enumerate(self._slots):
             if live is None:
                 continue
-            live.pos += 1
+            if live.tspan is not None:
+                # a chunk of its prompt, or a step past it
+                if fed is not None and live.pos + 1 < len(live.feed):
+                    live.tspan.ref_chunk(dispatch)
+                else:
+                    live.tspan.ref_step(dispatch)
+            live.pos += 1 if fed is None else int(fed[i])
             # keep the pool's written-token watermark current so
             # fragmentation never over-reports a mid-page tail
             self.pool.note_written(live.seq_id, live.pos)
@@ -2117,12 +2238,8 @@ class ContinuousScheduler:
                 live.next_token = live.feed[live.pos]
                 self._tokens[i] = live.next_token
                 self._slens[i] = live.pos
-                if live.tspan is not None:
-                    live.tspan.ref_step(dispatch)
                 continue
             tok = int(self._sample(logits[i], live))
-            if live.tspan is not None:
-                live.tspan.ref_step(dispatch)
             if not live.generated:
                 live.req.t_first_token = now
                 with self._lat_lock:

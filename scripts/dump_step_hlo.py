@@ -81,9 +81,11 @@ def serve_programs(fam, cfg, on_chip):
     w, st = on_chip((model.ffd._weights, model._state))
     yield "step", model._step_fn.trace(
         w, st, *on_chip((ints, ints, table) + rows))
+    # (the one-pass program takes `row_tokens` whatever the family)
+    fed = (ints,) if model.prefill_passes == 1 else rows
     yield "prefill", model._prefill_fn.trace(
         w, st, *on_chip((np.zeros((b, model.prefill_chunk), np.int32), ints,
-                         table) + rows))
+                         table) + fed))
 
 
 def load_cell(workload: str):

@@ -97,8 +97,9 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
         return model.step(tokens, pos, table, *rows)
 
     chunk = np.tile(tokens[:, None], (1, model.prefill_chunk))
+    # (the one-pass program takes `row_tokens` whatever the family)
     fed = (np.full((b,), model.prefill_chunk, np.int32),) \
-        if model.has_slot_state else ()
+        if model.has_slot_state or model.prefill_passes == 1 else ()
 
     def prefill():
         return model.prefill_step(chunk, pos, table, *fed)

@@ -219,23 +219,35 @@ def trained_gpt(devices, batch, seq, vocab, steps=40):
 
 
 class Recorder:
-    """Wraps a scheduler's model so that every decode dispatch's logits
-    (and what `also(model, row)` adds) are kept beside (request,
-    position) of the row they belong to."""
+    """Wraps a scheduler's model so that every dispatch's logits (a
+    decode step's, and a one-pass prefill's at each row's last real
+    token) and what `also(model, row)`
+    adds are kept beside (request, position) of the row they belong
+    to (`pass_also` where a pass's rows have something else to add)."""
 
-    def __init__(self, sched, also=lambda model, i: ()):
+    def __init__(self, sched, also=lambda model, i: (), pass_also=None):
         self.sched, self.rows, model = sched, [], sched.model
-        inner = model.step
+        step, prefill = model.step, model.prefill_step
 
-        def step(*args):
-            logits = inner(*args)
+        def keep(logits, last, also=also):
             for i, live in enumerate(sched._slots):
-                if live is not None:
-                    self.rows.append((live.req, live.pos, logits[i].copy(),
-                                      *also(model, i)))
+                if live is not None and last[i] >= 0:
+                    self.rows.append((live.req, int(last[i]),
+                                      logits[i].copy(), *also(model, i)))
+
+        def recorded_step(tokens, positions, *rest):
+            logits = step(tokens, positions, *rest)
+            keep(logits, np.asarray(positions))
             return logits
 
-        model.step = step
+        def recorded_prefill(tokens, positions, table, *fed, **beside):
+            logits = prefill(tokens, positions, table, *fed, **beside)
+            if logits is not None:  # idle slots: fed 0, last -1
+                keep(logits, np.asarray(positions) + fed[0] - 1,
+                     pass_also or also)
+            return logits
+
+        model.step, model.prefill_step = recorded_step, recorded_prefill
 
 
 def padded(tokens, to=32):

@@ -85,7 +85,8 @@ def serve_programs(config):
                                      *rows).compile(),
         "prefill": model._prefill_fn.lower(
             w, st, np.zeros((b, chunk), np.int32), zeros, table,
-            *rows).compile(),
+            *model._row_tokens(np.ones((b,), np.int32),
+                               model.prefill_passes == 1)).compile(),
     }
 
 

@@ -201,14 +201,17 @@ def test_dispatch_spans_carry_the_counters_and_the_twin_its_bytes():
     decode = [r.args for r in mine if r.name == "sched.decode.dispatch"]
     prefill = [r.args for r in mine if r.name == "sched.prefill.dispatch"]
     assert decode and prefill
-    # one request: pairs of a pass of 6 and a step from 0, then steps
+    # one request: passes of 6 from 0 while more than its last prompt
+    # token is left (no step between them), then steps
     at = 0
     for a in prefill:
         n = a["tokens"]
         want = eva_row_counts(D.w, D.c, D.p // D.c, slots, [at], [n])
         assert {k: a[k] for k in want} == {
             k: v * D.L for k, v in want.items()}
-        at += n + 1  # the pass, then the one-token step behind it
+        assert a["decode_rows"] == 0 and a["rows"] == 1
+        at += n
+    assert at == 18
     assert all(a["eva_rows_read"] == read for a in decode + prefill)
     assert all("rstate_rows_live" not in a for a in decode + prefill)
     # the last decode step is at position 21 (the 23rd token is its)

@@ -179,10 +179,12 @@ def test_front_reports_one_pass_a_replica_and_the_dispatch_span_carries_it():
             and r.span_id > first]
     dep = CFG["deployment"]
     view = dep["serving_slots"] * D.p // dep["kv_page_size"]
-    # a decode dispatch feeds the row one prompt token between chunks:
-    # the chunks start at 0, 5 and 10 and read up to their last position
-    assert [r.args["tokens"] for r in mine] == [4, 4, 2]
-    assert [r.args["kv_blocks_live"] for r in mine] == [1, 3, 4]
+    # while more than the last prompt token is left an iteration is the
+    # pass alone: the chunks start at 0, 4 and 8 and read up to their
+    # last position; the 13th token then runs through the decode step
+    assert [r.args["tokens"] for r in mine] == [4, 4, 4]
+    assert [r.args["kv_blocks_live"] for r in mine] == [1, 2, 3]
+    assert [r.args["decode_rows"] for r in mine] == [0, 0, 0]
     for r in mine:
         assert r.args["passes"] == 1
         assert r.args["kv_blocks_dense"] == r.args["kv_blocks_read"] == view
@@ -250,12 +252,18 @@ def twin():
     btab = np.arange(1, 1 + SLOTS * D.p // PAGE,
                      dtype=np.int32).reshape(SLOTS, -1)
 
-    def run(name, state, tokens, positions, table):
-        """`step`: (logits, state); `scan` / `pass`: state."""
-        return fns[name](ffd._weights, jax.tree.map(jnp.copy, state),
-                         jnp.asarray(tokens, jnp.int32),
-                         jnp.asarray(positions, jnp.int32),
-                         jnp.asarray(table, jnp.int32))
+    def run(name, state, tokens, positions, table, fed=None):
+        """`step`: (logits, state); `scan` / `pass`: state (the pass
+        with every row fed the whole chunk unless `fed` says how many
+        tokens each row really has: then (logits, state))."""
+        out = fns[name](ffd._weights, jax.tree.map(jnp.copy, state),
+                        jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(positions, jnp.int32),
+                        jnp.asarray(table, jnp.int32),
+                        *((jnp.full(SLOTS, CHUNK, jnp.int32)
+                           if fed is None else jnp.asarray(fed, jnp.int32),)
+                          if name == "pass" else ()))
+        return out[1] if name == "pass" and fed is None else out
 
     return ffd, run, btab
 

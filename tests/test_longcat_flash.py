@@ -317,7 +317,9 @@ def served():
     sched = ContinuousScheduler.from_trained(
         holder(), batch_slots=3, page_size=4, num_blocks=40,
         prefill_chunk=4, prefix_cache=True, devices=jax.devices()[:1])
-    rec = Recorder(sched, also=lambda model, i: (dict(model.moe_last),))
+    # (a pass fetches no routed-expert counts: its pad columns route too)
+    rec = Recorder(sched, also=lambda model, i: (dict(model.moe_last),),
+                   pass_also=lambda model, i: (None,))
     try:
         rng = np.random.default_rng(5)
         a = rng.integers(1, D.v, 16).tolist()  # four full pages
@@ -382,6 +384,8 @@ def test_twin_holds_two_latent_planes_a_layer_under_one_block_table(served):
 def test_decode_dispatches_count_the_identity_picks(served):
     rows, _, stats, *_ = served
     picks = 3 * D.k * D.L  # every slot's row, every routed layer
+    rows = [r for r in rows if r[-1] is not None]  # the decode dispatches'
+    assert rows
     for *_, moe in rows:
         assert 0 <= moe["zero_picks"] <= picks
         assert 0 <= moe["real_min"] <= moe["real_max"] <= D.k
@@ -410,7 +414,7 @@ def test_front_serves_it_and_the_dispatch_span_carries_the_counters():
     assert tokens[13:] == [int(np.argmax(want[p])) for p in (12, 13, 14)]
     mine = [r for r in spans() if r.span_id > first]
     chunks = [r for r in mine if r.name == "sched.prefill.dispatch"]
-    assert [r.args["tokens"] for r in chunks] == [4, 4, 2]
+    assert [r.args["tokens"] for r in chunks] == [4, 4, 4]
     assert all(r.args["passes"] == 1 for r in chunks)
     decodes = [r for r in mine if r.name == "sched.decode.dispatch"]
     assert decodes and all(

@@ -417,7 +417,8 @@ def test_the_planes_of_a_layer_differ_and_a_copied_block_copies_them_all():
     table[0, :2] = [3, 5]
     table[1, :2] = [4, 6]
     tokens = np.array([[7, 8, 9, 10], [11, 12, 13, 14]], np.int32)
-    model.prefill_step(tokens, np.zeros(2, np.int32), table)
+    model.prefill_step(tokens, np.zeros(2, np.int32), table,
+                       np.full(2, 4, np.int32))
     for name in ("attn_0", "attn_1"):
         for entry in ("k_cache", "v_cache"):
             pool = np.asarray(model._state[name][entry])
@@ -453,10 +454,15 @@ def test_front_stats_and_the_twin_span_say_the_loop():
     try:
         h = front.generate_async(list(range(1, 11)), 3, 0.0)
         h.wait(300)
-        loop = front.stats()["replicas"][0]["loop"]
+        replica = front.stats()["replicas"][0]
+        loop = replica["loop"]
     finally:
         front.close(10)
-    assert loop["loop_steps"] == D.T and loop["decode_dispatches"] >= 3
+    assert replica["pass_decode_tokens"] == 1 < replica["tokens_generated"]
+    # the prompt's last chunk holds its last token: the first of the
+    # three tokens is sampled from that pass, the others from two steps
+    assert loop["loop_steps"] == D.T and loop["decode_dispatches"] == 2
+    assert loop["exit_rows"] == 2 + loop["prefill_dispatches"] >= 3
     assert len(loop["exit_mass"]) == D.T
     (twin,) = [r for r in trace.spans()[since:]
                if r.name == "serve.build_twin"]

@@ -1,0 +1,428 @@
+"""The step plan in which a row past its prompt takes its token inside
+the chunked-prefill pass (ISSUE 52; serving/scheduler.py
+`plan_chunk_rows`, decoding.py `build_paged_prefill_pass`), for each of
+the five families on the pass at its toy configuration, on the CPU.
+
+* the plan as a pure function of the slots;
+* the pass against the seq-1 step from ONE state, for rows of one token
+  a position short of a page's end, of an EVA window's end and of
+  `max_seq`, beside a row that feeds a whole chunk: the logits the pass
+  returns, and the next decode step's after it (what the pads left);
+* a server on the fused plan against a server on the pair-by-pair plan
+  (the parent's: a pass of the feeding rows, then a decode dispatch) on
+  a seeded mix of one-token and feeding rows: every sampled row's
+  logits, the greedy tokens, the pool's invariants after every
+  dispatch, and what `decode_rows`, `tokens`, `rows` and
+  `pass_decode_tokens` count;
+* GPT keeps the scan: no logits, no `decode_rows`, the pair;
+* the reader of `decode.in_pass_share.capacity` on a recorded ring.
+
+Tolerance: the pass and the step compute the same float32 arithmetic
+over other shapes, 2e-5 of the logits' largest magnitude (the family
+files' `LOGIT_TOL`).
+"""
+import time
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from _family import Recorder, close, config
+
+from benchmarks.run import load_module
+from flexflow_tpu.obs.trace import next_span_id, span, spans
+from flexflow_tpu.serving.scheduler import (ContinuousScheduler,
+                                            PagedKVDecodeModel,
+                                            plan_chunk_rows)
+
+FAMILIES = {"kimi_k2": "toy-kimi.json", "qwen3_next": "toy-qwen3-next.json",
+            "ouro": "toy-ouro.json", "longcat_flash": "toy-longcat-flash.json",
+            "evabyte": "toy-evabyte.json"}
+SEED, CHUNK, LOGIT_TOL = 11, 4, 2e-5
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def served_model(request):
+    """(family name, its toy server's holder with the seed's weights)."""
+    cfg = config(FAMILIES[request.param])
+    fam = load_module("families", cfg["family"])
+    ff = fam.build_server(cfg, jax.devices()[:1])
+    ff.set_weights(fam.make_weights(cfg, SEED, "program"))
+    return request.param, cfg, ff
+
+
+# -- 1. the plan ---------------------------------------------------------------
+def row(feed_len, pos):
+    return types.SimpleNamespace(feed=list(range(feed_len)), pos=pos)
+
+
+PLANS = {
+    # (feed length, position) a slot -> fed a slot, sampled and not
+    "one_feeding_one_decoding": ([(10, 2), (3, 7), None], [4, 1, 0], [4, 0, 0]),
+    "the_last_chunk_holds_the_last_token": ([(6, 3)], [3], [2]),
+    "only_the_last_token_left_beside_a_feeder": (
+        [(6, 5), (9, 0)], [1, 4], [0, 4]),
+    "two_left": ([(6, 4)], [2], [1]),
+    "nobody_feeding": ([(6, 5), (3, 8), None], None, None),
+    "idle": ([None, None], None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+@pytest.mark.parametrize("sampled", [True, False])
+def test_plan_is_every_live_row_when_the_pass_samples(case, sampled):
+    slots, fused, pair = PLANS[case]
+    slots = [s and row(*s) for s in slots]
+    want = fused if sampled else pair
+    plan = plan_chunk_rows(slots, CHUNK, sampled)
+    if want is None:
+        assert plan == []  # the plain decode step runs
+        return
+    fed = [0] * len(slots)
+    for i, live, n in plan:
+        assert live is slots[i]
+        fed[i] = n
+    assert fed == want
+    # a row that is not sampled never takes its feed's last token
+    if not sampled:
+        assert all(live.pos + n < len(live.feed) for _, live, n in plan)
+
+
+# -- 2. the pass against the step, from one state ------------------------------------
+#: row -> (position of its first token, tokens of the chunk it has);
+#: pages are 4 positions (an EVA chunk too), the EVA window 16, max_seq 64
+ROWS = {"short_of_a_page": (3, 1), "short_of_a_window": (15, 1),
+        "short_of_max_seq": (62, 1), "feeding": (5, CHUNK)}
+
+
+@pytest.fixture(scope="module")
+def pass_and_steps(served_model):
+    """Four rows stepped to their positions a token at a time, then
+    from that ONE state the pass and the seq-1 steps over the same
+    tokens, and one decode step of every row after either."""
+    name, cfg, ff = served_model
+    c = ff.config
+    model = PagedKVDecodeModel(
+        ff, batch_slots=len(ROWS), page_size=c.kv_page_size,
+        num_blocks=1 + len(ROWS) * cfg["n_positions"] // c.kv_page_size,
+        prefill_chunk=CHUNK, prefix_cache=False, devices=jax.devices()[:1])
+    assert model.max_seq == 64 and model.prefill_passes == 1
+    assert model.page_size in (4, 16)
+    if model.eva:
+        assert model.eva["window"] == 16 and model.eva["chunk"] == 4
+    starts = np.array([s for s, _ in ROWS.values()], np.int32)
+    fed = np.array([n for _, n in ROWS.values()], np.int32)
+    slots, width = len(ROWS), model.max_blocks_per_seq
+    btab = np.arange(1, 1 + slots * width, dtype=np.int32).reshape(slots, -1)
+    tokens = np.random.default_rng(17).integers(
+        1, cfg["vocab_size"], (slots, 64)).astype(np.int32)
+
+    def step(live, at):
+        """The rows `live` a token each at positions `at`."""
+        rows = (live.astype(np.int32),) if model.has_slot_state else ()
+        tok = np.take_along_axis(tokens, np.minimum(at, 63)[:, None], 1)[:, 0]
+        return model.step(np.where(live, tok, 0), np.where(live, at, 0),
+                          np.where(live[:, None], btab, 0), *rows)
+
+    for t in range(int(starts.max())):
+        step(starts > t, np.full(slots, t, np.int32))
+    base = model._state
+    out = {}
+    # the pass: every row at its own position and table
+    model._state = jax.tree.map(jnp.copy, base)
+    feed = np.take_along_axis(tokens, np.minimum(
+        starts[:, None] + np.arange(CHUNK), 63), 1)
+    feed[np.arange(CHUNK) >= fed[:, None]] = 0
+    own = model.prefill_step(feed, starts, btab, fed)
+    out["pass"] = (own, step(np.ones(slots, bool), starts + fed))
+    # the steps: a token a row a dispatch
+    model._state = jax.tree.map(jnp.copy, base)
+    own = np.zeros_like(own)
+    for j in range(CHUNK):
+        logits = step(fed > j, starts + j)
+        own[fed == j + 1] = logits[fed == j + 1]
+    out["steps"] = (own, step(np.ones(slots, bool), starts + fed))
+    return name, out
+
+
+@pytest.mark.parametrize("which", sorted(ROWS))
+def test_pass_returns_the_steps_logits_and_leaves_the_steps_state(
+        pass_and_steps, which):
+    """Logits at the row's last real token, and the decode step behind
+    it: a one-token row's 3 pad columns (past a page's end, a window's
+    end, `max_seq`) changed nothing a later step reads."""
+    _, out = pass_and_steps
+    i = list(ROWS).index(which)
+    assert np.abs(out["steps"][0][i]).max() > 0
+    close(out["pass"][0][i], out["steps"][0][i], LOGIT_TOL)
+    close(out["pass"][1][i], out["steps"][1][i], LOGIT_TOL)
+
+
+# -- 3. a server on the fused plan against one on the pair-by-pair plan ----------
+#: (prompt length, new tokens): a prompt of one token (a rider from its
+#: first dispatch), prompts of chunks and a remainder, of whole chunks,
+#: and more requests than slots (a slot is refilled while others decode)
+REQUESTS = ((1, 12), (9, 6), (18, 5), (30, 4), (8, 7), (2, 9), (13, 3))
+
+
+def serve(ff, cfg, pairwise):
+    """One scheduler over REQUESTS, all at once, invariants checked
+    after every dispatch.  `pairwise`: the parent's plan driven by hand
+    (the pass's logits dropped, its riders on scratch, a decode
+    dispatch behind every pass)."""
+    c = ff.config
+    sched = ContinuousScheduler.from_trained(
+        ff, batch_slots=3, page_size=c.kv_page_size,
+        num_blocks=c.kv_pool_blocks or None, prefill_chunk=CHUNK,
+        prefix_cache=c.prefix_cache, check_invariants=True,
+        devices=jax.devices()[:1])
+    assert sched._pass_samples
+    fed_log, beside_log = [], []
+    inner = sched.model.prefill_step
+    rec = Recorder(sched)
+    recorded = sched.model.prefill_step
+
+    def prefill(tok, slen, btab, *fed, **beside):
+        # (the pair plan hands a twin without per-slot state no
+        # `row_tokens`, and nobody reads that pass's logits)
+        fed = fed or (np.ones(len(slen), np.int32),)
+        fed_log.append(fed[0].copy())
+        beside_log.append(set(beside))
+        return (inner if pairwise else recorded)(tok, slen, btab, *fed,
+                                                 **beside)
+
+    sched.model.prefill_step = prefill
+    sched._pass_samples = not pairwise
+    first = next_span_id()
+    try:
+        rng = np.random.default_rng(5)
+        handles = [sched.generate_async(
+            rng.integers(1, cfg["vocab_size"], n).tolist(), new, 0.0)
+            for n, new in REQUESTS]
+        for h in handles:
+            h.wait(300)
+        stats = sched.stats()
+    finally:
+        sched.close(10)
+    mine = [r for r in spans() if r.span_id > first]
+    sampled = {}
+    for req, pos, logits, *_ in rec.rows:
+        if pos >= len(req.prompt) - 1:
+            key = (handles.index(req), pos)
+            assert key not in sampled  # a position is sampled once
+            sampled[key] = logits
+    return dict(handles=handles, stats=stats, sampled=sampled, fed=fed_log,
+                beside=beside_log,
+                passes=[r for r in mine if r.name == "sched.prefill.dispatch"],
+                steps=[r for r in mine if r.name == "sched.decode.dispatch"])
+
+
+@pytest.fixture(scope="module")
+def plans(served_model):
+    name, cfg, ff = served_model
+    return name, {p: serve(ff, cfg, pairwise=(p == "pair"))
+                  for p in ("fused", "pair")}
+
+
+def test_fused_plan_samples_what_the_pair_plan_samples(plans):
+    _, by = plans
+    fused, pair = by["fused"], by["pair"]
+    assert sorted(fused["sampled"]) == sorted(pair["sampled"])
+    assert len(fused["sampled"]) == sum(new for _, new in REQUESTS)
+    for key, logits in fused["sampled"].items():
+        close(logits, pair["sampled"][key], LOGIT_TOL)
+    for a, b, (n, new) in zip(fused["handles"], pair["handles"], REQUESTS):
+        assert a.result == b.result and len(a.result) == n + new
+
+
+def test_while_a_row_feeds_an_iteration_is_the_pass_alone(plans):
+    _, by = plans
+    fused, pair = by["fused"], by["pair"]
+    # no decode dispatch carries a row that is still feeding...
+    assert fused["steps"] and pair["steps"]
+    assert all(r.args["feeding"] == 0 for r in fused["steps"])
+    assert any(r.args["feeding"] > 0 for r in pair["steps"])
+    # ...and the pair plan pays a decode dispatch behind every pass
+    assert len(fused["passes"]) + len(fused["steps"]) \
+        < len(pair["passes"]) + len(pair["steps"])
+    assert fused["stats"]["steps"] == len(fused["passes"]) + len(fused["steps"])
+    assert fused["stats"]["prefill_steps"] == len(fused["passes"])
+
+
+def test_span_args_and_stats_count_what_was_sampled(plans):
+    _, by = plans
+    fused, pair = by["fused"], by["pair"]
+    total = sum(new for _, new in REQUESTS)
+    in_pass = sum(r.args["decode_rows"] for r in fused["passes"])
+    in_step = sum(r.args["rows"] for r in fused["steps"])
+    assert in_pass > 0 and in_pass + in_step == total
+    assert fused["stats"]["pass_decode_tokens"] == in_pass
+    assert fused["stats"]["tokens_generated"] == total
+    assert len(fused["fed"]) == len(fused["passes"])
+    # a pass's counters are taken while its program runs (`meanwhile`:
+    # the model calls it between the enqueue and the wait); the scan's
+    # call returns at once and takes no such argument
+    assert all(b == {"meanwhile"} for b in fused["beside"])
+    assert not any(pair["beside"])
+    assert all("kv_blocks_live" in r.args
+               for r in fused["passes"] + pair["passes"])
+    for r, fed in zip(fused["passes"], fused["fed"]):
+        assert r.args["tokens"] == fed.sum()
+        assert r.args["rows"] == (fed > 0).sum()
+        assert r.args["decode_rows"] <= r.args["rows"] <= 3
+        assert r.args["capacity"] == 3 * CHUNK and r.args["passes"] == 1
+    # the riders' tokens count: more real tokens a pass than prompt
+    # tokens alone
+    prompt = sum(n for n, _ in REQUESTS)
+    assert sum(r.args["tokens"] for r in fused["passes"]) > \
+        sum(r.args["tokens"] for r in pair["passes"])
+    assert sum(r.args["tokens"] for r in pair["passes"]) < prompt
+    # the parent's plan: no such arg, nothing sampled from a pass
+    assert all("decode_rows" not in r.args for r in pair["passes"])
+    assert pair["stats"]["pass_decode_tokens"] == 0
+
+
+def test_a_family_with_layer_state_reports_the_riders_rows(plans):
+    """`rstate_rows_live` / the EVA counters of a pass cover the rows
+    that advanced by one token too."""
+    name, by = plans
+    fused = by["fused"]
+    if name == "qwen3_next":
+        assert [r.args["rstate_rows_live"] for r in fused["passes"]] == \
+            [r.args["rows"] for r in fused["passes"]]
+    elif name == "evabyte":
+        assert all(r.args["eva_rows_window"] >= r.args["tokens"]
+                   for r in fused["passes"])
+    elif name == "ouro":
+        loop = fused["stats"]["loop"]
+        assert loop["exit_rows"] == in_rows(fused)
+        assert sum(loop["exit_mass"]) == pytest.approx(loop["exit_rows"],
+                                                       rel=1e-5)
+    else:
+        assert "rstate_rows_live" not in fused["passes"][0].args
+
+
+def in_rows(run):
+    """Live rows over the dispatches that returned an exit pdf."""
+    return (sum(r.args["rows"] for r in run["passes"])
+            + sum(r.args["rows"] + r.args["feeding"] for r in run["steps"]))
+
+
+# -- 4. GPT keeps the scan and the pair -----------------------------------------------
+def test_gpt_scans_its_chunk_returns_no_logits_and_pairs_its_dispatches():
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.models.transformer import build_gpt
+
+    ff = FFModel(FFConfig(batch_size=1, num_devices=1))
+    build_gpt(ff, 1, 32, hidden_size=32, num_layers=2, num_heads=2,
+              intermediate_size=64, vocab_size=50)
+    ff.compile(optimizer=SGDOptimizer(lr=0.01),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               devices=jax.devices()[:1])
+    assert ff.decoder_recipe.head == ()
+    sched = ContinuousScheduler.from_trained(
+        ff, batch_slots=2, page_size=4, num_blocks=17, prefill_chunk=CHUNK,
+        check_invariants=True, devices=jax.devices()[:1])
+    returned = []
+    inner = sched.model.prefill_step
+
+    def prefill(*args):
+        assert len(args) == 3  # no row_tokens: the scan's signature
+        returned.append(inner(*args))
+        return returned[-1]
+
+    sched.model.prefill_step = prefill
+    first = next_span_id()
+    try:
+        assert not sched._pass_samples
+        a = sched.generate_async([7], 9, 0.0)  # a rider from the start
+        b = sched.generate_async(list(range(1, 15)), 3, 0.0)
+        assert len(a.wait(120)) == 10 and len(b.wait(120)) == 17
+        stats = sched.stats()
+    finally:
+        sched.close(10)
+    mine = [r for r in spans() if r.span_id > first]
+    passes = [r for r in mine if r.name == "sched.prefill.dispatch"]
+    steps = [r for r in mine if r.name == "sched.decode.dispatch"]
+    assert returned and all(r is None for r in returned)
+    assert stats["pass_decode_tokens"] == 0 and stats["prefill_passes"] == CHUNK
+    assert all("decode_rows" not in r.args for r in passes)
+    # b's 14 tokens: chunks never past its 13th, riders not counted
+    assert [r.args["tokens"] for r in passes] == [4, 4, 3]
+    assert all(r.args["rows"] == 1 for r in passes)
+    # a decode dispatch behind every chunk, b still feeding in it
+    # until its last prompt token is the one it is fed
+    assert sum(r.args["feeding"] > 0 for r in steps) == len(passes) - 1
+    assert stats["steps"] == len(steps)
+
+
+def test_a_family_on_the_pass_names_its_head():
+    import dataclasses
+
+    from flexflow_tpu.config import ConfigError
+    from flexflow_tpu.decoding import build_paged_prefill_pass, make_decoder
+
+    cfg = config(FAMILIES["kimi_k2"])
+    fam = load_module("families", cfg["family"])
+    ff = fam.build_server(cfg, jax.devices()[:1])
+    ff.set_weights(fam.make_weights(cfg, SEED, "program"))
+    assert ff.decoder_recipe.head == ("final_norm", "lm_head")
+    ffd = make_decoder(ff, batch_size=2, kv_page_size=4, kv_num_blocks=9,
+                       devices=jax.devices()[:1])
+    ffd.decoder_recipe = dataclasses.replace(ffd.decoder_recipe, head=())
+    with pytest.raises(ConfigError, match="names no `head` ops"):
+        build_paged_prefill_pass(ffd, CHUNK)
+
+
+# -- 5. the reader ----------------------------------------------------------------------
+def reader_ctx(make):
+    """A context whose traced stretch holds the spans `make()` makes."""
+    t0 = time.monotonic()
+    make()
+    said = []
+    return types.SimpleNamespace(
+        _trace_t0=t0, trace_window_s=time.monotonic() - t0,
+        out=said.append), said
+
+
+def dispatches(passes, steps):
+    def make():
+        for args in passes:
+            with span("sched.prefill.dispatch", **args):
+                pass
+        for rows in steps:
+            with span("sched.decode.dispatch", rows=rows, feeding=0, slots=4):
+                pass
+    return make
+
+
+READINGS = {
+    # a ring without `decode_rows` (the parent, or the scan): 0
+    "parent": (([dict(rows=2, tokens=8)] * 3, [3, 4]), 0.0),
+    # 5 + 3 rows sampled from passes, 2 from a decode dispatch
+    "fused": (([dict(rows=4, tokens=9, decode_rows=5),
+                dict(rows=4, tokens=7, decode_rows=3)], [2]), 80.0),
+    "all_in_passes": (([dict(rows=3, tokens=3, decode_rows=3)], []), 100.0),
+    "nothing_dispatched": (([], []), None),
+    "nothing_sampled": (([dict(rows=1, tokens=4, decode_rows=0)], []), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(READINGS))
+def test_in_pass_share_reader_on_a_recorded_ring(case):
+    made, want = READINGS[case]
+    reader = load_module("readers", "decode.in_pass_share.capacity")
+    ctx, said = reader_ctx(dispatches(*made))
+    got = reader.read(ctx, {"name": "decode.in_pass_share.capacity"})
+    if want is None:
+        assert got is None and not said
+    else:
+        assert got == pytest.approx(want) and len(said) == 1
+
+
+def test_in_pass_share_reader_without_a_traced_stretch():
+    reader = load_module("readers", "decode.in_pass_share.capacity")
+    ctx = types.SimpleNamespace(_trace_t0=None, trace_window_s=None,
+                                out=lambda s: None)
+    assert reader.read(ctx, {}) is None

@@ -342,27 +342,31 @@ def chunk_pair(twin):
     feed = np.take_along_axis(tokens, cols, axis=1)
     # riders of a prefill dispatch sit on scratch
     table = np.where((counts > 0)[:, None], btab, 0)
-    stepped = state
+    stepped, last = state, np.zeros((SLOTS, D.v), np.float32)
     for j in range(CHUNK):
         live = counts > j
-        _, stepped = run("step", stepped, np.where(live, feed[:, j], 0),
-                         np.where(live, starts + j, 0),
-                         np.where(live[:, None], table, 0), live)
-    passed = run("pass", state, feed[:, :CHUNK], starts, table, counts)
+        logits, stepped = run("step", stepped, np.where(live, feed[:, j], 0),
+                              np.where(live, starts + j, 0),
+                              np.where(live[:, None], table, 0), live)
+        last[counts == j + 1] = np.asarray(logits, np.float32)[counts == j + 1]
+    at_last, passed = run("pass", state, feed[:, :CHUNK], starts, table,
+                          counts)
     after = {}
-    for name, st in (("steps", stepped), ("pass", passed)):
+    for name, st, own in (("steps", stepped, last),
+                          ("pass", passed, np.asarray(at_last, np.float32))):
         nxt = np.take_along_axis(feed, counts[:, None], axis=1)[:, 0]
         logits, _ = run("step", st, nxt, starts + counts, btab,
                         np.ones(SLOTS))
-        after[name] = (st, np.asarray(logits, np.float32))
+        after[name] = (st, np.asarray(logits, np.float32), own)
     return tokens, state, after
 
 
 @pytest.mark.parametrize("row", sorted(ROWS))
 def test_chunk_in_one_pass_equals_seq1_stepped_over_the_chunk(chunk_pair, row):
     """Conv tail and delta-rule matrix of the row after the chunk, the
-    next decode step's logits, and those logits against the reference's
-    full forward of the row's tokens."""
+    next decode step's logits, those logits against the reference's
+    full forward of the row's tokens, and the logits the pass itself
+    returns against the seq-1 step at the row's last real token."""
     tokens, _, after = chunk_pair
     i = list(ROWS).index(row)
     end = sum(ROWS[row])
@@ -373,6 +377,8 @@ def test_chunk_in_one_pass_equals_seq1_stepped_over_the_chunk(chunk_pair, row):
     close(after["pass"][1][i], after["steps"][1][i], LOGIT_TOL)
     close(after["pass"][1][i], reference_logits(tokens[i, :end + 1])[end],
           LOGIT_TOL)
+    if ROWS[row][1]:  # the pass's own logits: the row's last real token
+        close(after["pass"][2][i], after["steps"][2][i], LOGIT_TOL)
 
 
 def test_rows_that_do_not_advance_keep_their_state_to_the_byte(chunk_pair):

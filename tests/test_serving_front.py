@@ -121,6 +121,38 @@ def test_front_stamps_its_backlog_at_admission_on_every_handle():
         front.close()
 
 
+def test_a_completions_slot_is_refilled_by_the_next_step():
+    """With a backlog at the front, the slot a completion frees holds
+    the backlog's head in the very next step: the hand-over is made on
+    the decode loop's own thread, inside the completion, and does not
+    wait for the dispatcher to wake (it would race the scheduler's
+    next admission, and the slot would stand empty for a step in some
+    runs).  So between the first full step and the last, every step
+    has both rows live."""
+    live = []
+
+    class Counting(FakeStepModel):
+        def step(self, tokens, seq_lens, block_tables):
+            live.append(int((np.asarray(block_tables)[:, 0] != 0).sum()))
+            return super().step(tokens, seq_lens, block_tables)
+
+    front = ServingFront(lambda rid, survivors=None: Counting(delay_s=0.003),
+                         num_replicas=1, sleep=NO_SLEEP)
+    try:
+        rng = np.random.RandomState(3)
+        reqs = [([int(rng.randint(1, V))], int(rng.randint(2, 6)))
+                for _ in range(40)]
+        hs = [front.generate_async(p, m) for p, m in reqs]
+        for h, (p, m) in zip(hs, reqs):
+            assert h.wait(30.0) == expected(p, m)
+    finally:
+        front.close()
+    first = live.index(2)
+    last = len(live) - 1 - live[::-1].index(2)
+    assert last - first > 40
+    assert set(live[first:last + 1]) == {2}
+
+
 def test_front_validates_at_admission():
     front = ServingFront(factory, num_replicas=1, sleep=NO_SLEEP)
     try:
