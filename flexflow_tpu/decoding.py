@@ -777,7 +777,7 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
 
         prefill(weights, state, tokens[b, C], positions[b], block_table,
                 row_tokens[b])
-            -> (logits [b, vocab], new_state[, exit_pdf [b, passes]])
+            -> (logits [b (+ r), vocab], new_state[, exit_pdf [b, passes]])
 
     A dispatch streams the weights once and builds each layer's
     gathered view once, where build_paged_prefill_step's scan does both
@@ -793,6 +793,21 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     the head multiplies [b, hidden], not [b, C, hidden].
     `logit_columns` and the exit gate's `exit_pdf` are applied as
     build_paged_decode_step applies them.
+
+    The routed layers of the graph (`moe_stats` in their state) count
+    the REAL tokens of the pass alone, `column < row_tokens[row]`: the
+    mask goes to them from this closure (`run_forward`'s `count_rows`),
+    never through a state entry, which would make a twin "carry
+    per-slot state" and hand the decode step an argument.  Their output
+    is every column's as before (a pad column multiplies like any
+    other).  Such a graph's counts ride IN the logits' buffer: `r` more
+    rows behind the `b` rows of logits hold every layer's `moe_stats`
+    and then every identity-expert layer's `moe_zero`, in graph order,
+    as float32 (small whole numbers, exact), zero-padded to the row;
+    `split_pass_counts` takes them off on the host.  One buffer comes
+    back, as before: every further buffer a fetch brings costs the host
+    a tenth of a millisecond or two on the v5e, in the `device_get` or
+    beside it (PERF.md, PR 53), and this is every token's path.
 
     The twin's graph is interpreted over [b, C] inputs as it stands:
     the recipe's claim is that every op of it is per-token or takes
@@ -823,6 +838,9 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     made = {t.guid for op in head for t in op.outputs}
     # what the head reads of the layers: [..., C, hidden] tensors
     cut = {t.guid for op in head for t in op.inputs} - made
+    counted = {entry: [op for op, entries in ffd._state.items()
+                       if entry in entries]
+               for entry in ("moe_stats", "moe_zero")}
 
     def prefill(weights, state, tokens, positions, block_table, row_tokens):
         with scopes.scope(scopes.FEED):
@@ -834,6 +852,8 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
             inputs = {"input": tokens,
                       "positions": jnp.minimum(grid, max_seq - 1)}
             last = jnp.clip(row_tokens - 1, 0, chunk - 1)[:, None, None]
+            real = (jnp.arange(chunk, dtype=jnp.int32) < row_tokens[:, None]
+                    if counted["moe_stats"] else None)
 
         def last_token(x):  # [..., b, C, hidden] -> [..., b, 1, hidden]
             with scopes.scope(scopes.LOGITS):
@@ -843,12 +863,38 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
 
         logits, new_state, _, env = ex.run_forward(
             weights, state, inputs, training=False, rng=None,
-            narrow=dict.fromkeys(cut, last_token),
+            narrow=dict.fromkeys(cut, last_token), count_rows=real,
         )
-        return finish(logits, new_state, env)
+        out = finish(logits, new_state, env)
+        if real is None:
+            return out
+        with scopes.scope(scopes.LOGITS):
+            width = out[0].shape[1]
+            flat = jnp.concatenate([
+                new_state[op][entry] for entry, ops in counted.items()
+                for op in ops]).astype(out[0].dtype)
+            rows = -(-flat.shape[0] // width)
+            packed = jnp.pad(flat, (0, rows * width - flat.shape[0]))
+            return (jnp.concatenate([out[0], packed.reshape(rows, width)]),
+                    *out[1:])
 
     with ex.mesh:
         return jax.jit(prefill, donate_argnums=(1,))
+
+
+def split_pass_counts(packed, slots: int, layers: int, zero_layers: int):
+    """(logits [slots, vocab], {"moe_stats": int [layers, 4], "moe_zero":
+    int [zero_layers, 3]}) from the host copy of what
+    `build_paged_prefill_pass` returns for a graph with `layers` routed
+    layers, `zero_layers` of them with identity experts."""
+    from .ops.routed_experts import MOE_STATS, MOE_ZERO_STATS
+
+    flat = np.rint(packed[slots:].reshape(-1)).astype(np.int64)
+    n = layers * len(MOE_STATS)
+    return packed[:slots], {
+        "moe_stats": flat[:n].reshape(layers, len(MOE_STATS)),
+        "moe_zero": flat[n:n + zero_layers * len(MOE_ZERO_STATS)].reshape(
+            zero_layers, len(MOE_ZERO_STATS))}
 
 
 def build_paged_verify_step(ffd: FFModel, chunk: int):

@@ -821,13 +821,20 @@ class GraphExecutor:
         training: bool,
         rng: Optional[jax.Array],
         narrow: Optional[Dict[int, Callable]] = None,
+        count_rows: Optional[jax.Array] = None,
     ):
         """Interpret the PCG. Returns (sink_output, new_state, aux_losses, env).
 
         `narrow` {tensor guid: fn}: an op outside a repeated region reads
         that tensor as `fn(value)` (the prefill pass hands its head each
         row's last real position, not the whole chunk:
-        decoding.build_paged_prefill_pass)."""
+        decoding.build_paged_prefill_pass).
+
+        `count_rows` (bool [batch, seq]): which of the step's rows are
+        real tokens, handed to the ops that count rows
+        (`counts_real_rows`: the routed layers' `moe_stats`), in a
+        region or outside one; the same pass's, from its own closure and
+        not through the state, so no other program gains an argument."""
         env: Dict[int, jax.Array] = {}
         new_state = {k: dict(v) for k, v in state.items()}
         aux_losses: List[jax.Array] = []
@@ -849,6 +856,7 @@ class GraphExecutor:
             "aux": aux_losses,
             "inputs": inputs,
             "narrow": narrow,
+            "count_rows": count_rows,
             "training": training,
             "rng": rng,
             "to_compute": to_compute,
@@ -1070,7 +1078,11 @@ class GraphExecutor:
         op_rng = None
         if ctx["rng"] is not None:
             op_rng = jax.random.fold_in(ctx["rng"], op.guid)
-        results = op.forward(ins, ws, training=training, rng=op_rng)
+        counted = ({"count_rows": ctx["count_rows"]}
+                   if ctx["count_rows"] is not None
+                   and getattr(op, "counts_real_rows", False) else {})
+        results = op.forward(ins, ws, training=training, rng=op_rng,
+                             **counted)
         outs = results[: len(op.outputs)]
         extra = results[len(op.outputs):]
         if extra:

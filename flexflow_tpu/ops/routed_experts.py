@@ -580,6 +580,9 @@ class RoutedExperts(Op):
     #: a segment that holds this op may be rematerialised
     #: (`GraphExecutor._build_remat_plan`)
     state_is_counters = True
+    #: `forward` takes `count_rows`, the rows of the step that are real
+    #: tokens, where the caller has them (`GraphExecutor.run_forward`)
+    counts_real_rows = True
 
     def _rows(self) -> int:
         shape = self.inputs[0].shape
@@ -687,10 +690,18 @@ class RoutedExperts(Op):
                 zero))
         return specs
 
-    def forward(self, inputs, weights, *, training=False, rng=None):
+    def forward(self, inputs, weights, *, training=False, rng=None,
+                count_rows=None):
+        """`count_rows` (bool, the input's shape less its last axis;
+        None: every row) says which rows of the step are real tokens:
+        `moe_stats` and `moe_zero` count those alone.  The OUTPUT is
+        every row's whatever it says: a pad row multiplies like any
+        other (the dense product's cost), only nobody counts it."""
         (x,) = inputs
         p: RoutedExpertsParams = self.params
         router, bias, w_gate, w_up, w_down = weights[:5]
+        real_rows = (None if count_rows is None
+                     else count_rows.reshape(-1))  # [t]
         with scope("route"):
             h = x.reshape(-1, x.shape[-1])
             chosen, w = route(h, router, bias, p)
@@ -732,15 +743,25 @@ class RoutedExperts(Op):
                 out = out + (h.astype(jnp.float32)
                              * w_zero[:, None]).astype(out.dtype)
                 real = p.top_k - jnp.sum(is_zero, axis=-1, dtype=jnp.int32)
-                zero_stats = jnp.stack([
-                    h.shape[0] * p.top_k - jnp.sum(real),
-                    jnp.min(real), jnp.max(real)])
+                if real_rows is None:
+                    zero_stats = jnp.stack([
+                        h.shape[0] * p.top_k - jnp.sum(real),
+                        jnp.min(real), jnp.max(real)])
+                else:
+                    zero_stats = jnp.stack([
+                        jnp.sum(jnp.where(real_rows, p.top_k - real, 0)),
+                        jnp.min(jnp.where(real_rows, real, p.top_k)),
+                        jnp.max(jnp.where(real_rows, real, 0))])
         with scope("dispatch"):  # the counts of it
-            rows = jnp.sum(landed, axis=(0, 1)).astype(jnp.int32)  # [held]
+            counted, combined = landed, combine != 0
+            if real_rows is not None:
+                counted = jnp.where(real_rows[:, None, None], landed, 0.0)
+                combined = combined & real_rows[:, None]
+            rows = jnp.sum(counted, axis=(0, 1)).astype(jnp.int32)  # [held]
             pairs = jnp.sum(rows)
             stats = jnp.stack([
                 pairs,
-                pairs - jnp.sum(combine != 0).astype(jnp.int32),
+                pairs - jnp.sum(combined).astype(jnp.int32),
                 jnp.max(rows),
                 jnp.sum(rows > 0).astype(jnp.int32),
             ])

@@ -178,7 +178,8 @@ class PagedKVDecodeModel:
                                 build_paged_verify_step,
                                 build_slot_state_reset, cache_entries,
                                 cache_planes, decoder_recipe, make_decoder,
-                                require_carried, slot_state_entries)
+                                require_carried, slot_state_entries,
+                                split_pass_counts)
         from ..ops.pallas.paged_attention import have_paged_kernel
 
         recipe = decoder_recipe(ff_train)
@@ -256,7 +257,11 @@ class PagedKVDecodeModel:
              else build_paged_prefill_step)(self.ffd, self.prefill_chunk)
             if self.prefill_chunk else None)
         self._copy_fn = build_paged_copy_block(self.ffd)
-        self._called = set()  # step programs that have run once
+        # {program: the static args of its `model.enqueue` span}, made
+        # at the program's first call (`_enqueue`); {program: bytes its
+        # `model.fetch` brings back}
+        self._moved: Dict[str, Dict] = {}
+        self._fetched: Dict[str, int] = {}
         # speculative verify twin (docs/SERVING.md "Speculative
         # decoding"): ONE [slots, spec_k+1] program scores a pending
         # token plus up to spec_k drafts per row — per-position logits
@@ -331,11 +336,15 @@ class PagedKVDecodeModel:
         # a layer with identity experts counts their picks in a second
         # entry (MOE_ZERO_STATS), fetched and kept the same way: the
         # picks summed, the least and the most real picks a row over
-        # the layers
+        # the layers.  The one-pass prefill program counts its real
+        # tokens alone and returns the layers' counts IN the logits'
+        # buffer, as rows behind them: no further buffer to fetch
+        # (decoding.build_paged_prefill_pass, `split_pass_counts`)
         self._moe_ops = [op for op, entries in self._state.items()
                          if "moe_stats" in entries]
         self._moe_zero_ops = [op for op in self._moe_ops
                               if "moe_zero" in self._state[op]]
+        self._split_pass_counts = split_pass_counts
         self.moe_last: Optional[Dict[str, int]] = None
         # repeated regions of the twin's graph (`loop_regions`,
         # `loop_steps`, `loop_ops`; {} without one): a step program
@@ -467,57 +476,87 @@ class PagedKVDecodeModel:
 
         if self._reset_slot_fn is None:  # state masked by position only
             return
-        with span("model.enqueue",
-                  first=self._first_call("reset_slot_state")):
-            self._state = self._reset_slot_fn(self._state, jnp.int32(slot))
+        self._state = self._enqueue("reset_slot_state", self._reset_slot_fn,
+                                    self._state, jnp.int32(slot))
+
+    def _enqueue(self, program: str, fn, *args):
+        """`fn(*args)`, a jitted program's call, under `model.enqueue`.
+        The span says what the call moved, counted once a program at its
+        first call (the shapes never change): `program`, `arg_leaves`
+        (array leaves of everything passed: weights, state, host arrays)
+        and `host_bytes` (bytes of the host arrays, which the call
+        copies in); `first` is 1 on that call (its lazy compile)."""
+        moved = self._moved.get(program)
+        first = moved is None
+        if first:
+            import jax
+
+            leaves = jax.tree.leaves(args)
+            moved = self._moved[program] = {
+                "program": program, "arg_leaves": len(leaves),
+                "host_bytes": sum(int(x.nbytes) for x in leaves
+                                  if isinstance(x, np.ndarray))}
+        with span("model.enqueue", first=int(first), **moved):
+            return fn(*args)
 
     def step(self, tokens: np.ndarray, seq_lens: np.ndarray,
              block_tables: np.ndarray, row_tokens=None) -> np.ndarray:
         # per-token hot path: the block table / seq_lens override
         # happens INSIDE the jitted step and the state pytree is
         # donated — no host-side dict rebuild, no per-layer pool copy
-        with span("model.enqueue", first=self._first_call("step")):
-            logits, self._state, *exit_pdf = self._step_fn(
-                self.ffd._weights, self._state, tokens, seq_lens,
-                block_tables, *self._row_tokens(row_tokens),
-            )
-        return self._fetch(logits, exit_pdf, moe=True)
+        logits, self._state, *exit_pdf = self._enqueue(
+            "step", self._step_fn, self.ffd._weights, self._state, tokens,
+            seq_lens, block_tables, *self._row_tokens(row_tokens))
+        counts = None
+        if self._moe_ops and not exit_pdf:
+            # (the step leaves its counts in the state: two buffers a
+            # layer)
+            counts = {"moe_stats": [self._state[op]["moe_stats"]
+                                    for op in self._moe_ops],
+                      "moe_zero": [self._state[op]["moe_zero"]
+                                   for op in self._moe_zero_ops]}
+        return self._fetch("step", logits, exit_pdf, counts)
 
-    def _fetch(self, logits, exit_pdf, moe: bool) -> np.ndarray:
+    def _fetch(self, program: str, logits, exit_pdf=(),
+               counts=None) -> np.ndarray:
         """The wait for the device, then the logits' copy to the host
-        and with it what the program returned beside them: the rows'
-        exit pdf (`exit_last`) and, with `moe`, the routed layers'
-        counts (`moe_last`)."""
-        with span("model.fetch"):
+        and with it, in the one `device_get`, what the program returned
+        beside them: the rows' exit pdf (`exit_last`) and, from the
+        decode step, the routed layers' `counts` (`moe_last`; a buffer
+        a layer and entry, as the step leaves them in its state).  The
+        span carries `program` and the `bytes` that came back."""
+        import jax
+
+        with span("model.fetch", program=program) as fetch:
+            beside = {**({"exit": exit_pdf[0]} if exit_pdf else {}),
+                      **(counts or {})}
+            if beside:
+                logits, beside = jax.device_get((logits, beside))
+            else:
+                logits = np.asarray(logits)
+            if program not in self._fetched:
+                self._fetched[program] = sum(
+                    int(x.nbytes) for x in jax.tree.leaves((logits, beside)))
+            fetch.set(bytes=self._fetched[program])
             if exit_pdf:
-                import jax
-
-                logits, self.exit_last = jax.device_get(
-                    (logits, exit_pdf[0]))
-                return np.asarray(logits, np.float32)
-            if not (moe and self._moe_ops):
-                return np.asarray(logits, np.float32)
-            import jax
-
-            logits, stats, zero = jax.device_get(
-                (logits, [self._state[op]["moe_stats"]
-                          for op in self._moe_ops],
-                 [self._state[op]["moe_zero"]
-                  for op in self._moe_zero_ops]))
-            self.moe_last = dict(zip(
-                MOE_STATS, (int(v) for v in np.sum(stats, axis=0))))
-            if zero:
-                picks, least, most = np.stack(zero).T
-                self.moe_last.update(zip(MOE_ZERO_STATS, (
-                    int(picks.sum()), int(least.min()), int(most.max()))))
+                self.exit_last = beside["exit"]
+            if counts:
+                self.moe_last = self._summed(beside["moe_stats"],
+                                             beside["moe_zero"])
             return np.asarray(logits, np.float32)
 
-    def _first_call(self, program: str) -> int:
-        """1 on a program's first call (its lazy compile), else 0."""
-        if program in self._called:
-            return 0
-        self._called.add(program)
-        return 1
+    @staticmethod
+    def _summed(moe_stats, moe_zero) -> Dict[str, int]:
+        """The routed layers' counts ([layers, 4], [layers with identity
+        experts, 3]) over the layers: sums, but the least and the most
+        real picks a row."""
+        out = dict(zip(MOE_STATS,
+                       (int(v) for v in np.sum(moe_stats, axis=0))))
+        if len(moe_zero):
+            picks, least, most = np.asarray(moe_zero).T
+            out.update(zip(MOE_ZERO_STATS, (
+                int(picks.sum()), int(least.min()), int(most.max()))))
+        return out
 
     def prefill_step(self, tokens: np.ndarray, positions: np.ndarray,
                      block_tables: np.ndarray, row_tokens=None,
@@ -527,24 +566,30 @@ class PagedKVDecodeModel:
         runs through the decode program).  The one-pass program
         (`prefill_passes` 1) takes `row_tokens` whatever the family and
         returns host logits [b, vocab] at each row's last real token,
-        fetched as `step` fetches its own (the routed layers' counts
-        are left where they are: a pass routes its pad columns too).
+        fetched as `step` fetches its own; the routed layers' counts
+        over the pass's real tokens come in the same buffer, behind
+        the logits' rows (`moe_last`).
         `meanwhile()` is called once the program is enqueued, before
         the wait for it: host work that needs no result of the
         dispatch runs beside the device, not after it."""
         one_pass = self.prefill_passes == 1
-        with span("model.enqueue", first=self._first_call("prefill")):
-            out = self._prefill_fn(
-                self.ffd._weights, self._state, tokens, positions,
-                block_tables, *self._row_tokens(row_tokens, one_pass),
-            )
+        out = self._enqueue(
+            "prefill", self._prefill_fn, self.ffd._weights, self._state,
+            tokens, positions, block_tables,
+            *self._row_tokens(row_tokens, one_pass))
         if meanwhile is not None:
             meanwhile()
         if not one_pass:
             self._state = out
             return None
         logits, self._state, *exit_pdf = out
-        return self._fetch(logits, exit_pdf, moe=False)
+        logits = self._fetch("prefill", logits, exit_pdf)
+        if self._moe_ops:
+            logits, counts = self._split_pass_counts(
+                logits, self.batch_slots, len(self._moe_ops),
+                len(self._moe_zero_ops))
+            self.moe_last = self._summed(**counts)
+        return logits
 
     def verify_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
                     counts: np.ndarray,
@@ -555,13 +600,10 @@ class PagedKVDecodeModel:
         to what the decode step would have produced feeding
         tokens[i, j] at seq_lens[i]+j (docs/SERVING.md "Speculative
         decoding").  Built only when spec_decode != "off"."""
-        with span("model.enqueue", first=self._first_call("verify")):
-            logits, self._state = self._verify_fn(
-                self.ffd._weights, self._state, tokens,
-                seq_lens, counts, block_tables,
-            )
-        with span("model.fetch"):
-            return np.asarray(logits, np.float32)
+        logits, self._state = self._enqueue(
+            "verify", self._verify_fn, self.ffd._weights, self._state,
+            tokens, seq_lens, counts, block_tables)
+        return self._fetch("verify", logits)
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write: clone physical block src -> dst in every
@@ -847,8 +889,12 @@ class ContinuousScheduler:
         self._kv_block_bytes = int(getattr(model, "kv_block_bytes", 0))
         self.kernel_blocks_read = 0   # physical blocks streamed
         self.kernel_dense_blocks = 0  # gather-equivalent block reads
-        # routed-expert counters summed over decode dispatches (a model
-        # without such layers leaves them None): stats()["moe"]
+        # routed-expert counters summed over the dispatches whose
+        # logits are sampled, by program (a model without such layers
+        # leaves them None): stats()["moe"], the decode step's under the
+        # bare names and `dispatches` (every slot's row), the sampling
+        # passes' under `prefill_<name>` and `prefill_dispatches` (their
+        # real tokens alone)
         self.moe_totals: Optional[Dict[str, int]] = None
         # a model whose graph repeats a region: weight passes of its
         # dispatches and, with an exit gate, the exit pdf of the live
@@ -1703,6 +1749,26 @@ class ContinuousScheduler:
         for k, v in rows.items():
             t[f"{program}_{k}"] = t.get(f"{program}_{k}", 0) + v
 
+    def _note_moe(self, dispatch, program: str) -> None:
+        """The `moe_*` args of a dispatch whose logits were fetched
+        (`model.moe_last`: the routed layers' counts of that dispatch,
+        over every slot's row from the decode step, over the real tokens
+        alone from the one-pass prefill) and their sums by program,
+        `real_min` / `real_max` folded by `_MOE_FOLD`."""
+        moe = getattr(self.model, "moe_last", None)
+        if moe is None:
+            return
+        dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})
+        if self.moe_totals is None:
+            self.moe_totals = {"dispatches": 0, "prefill_dispatches": 0}
+        totals, prefix = self.moe_totals, "" if program == "decode" \
+            else "prefill_"
+        totals[f"{prefix}dispatches"] += 1
+        for k, v in moe.items():
+            name = prefix + k
+            totals[name] = (_MOE_FOLD.get(k, operator.add)(totals[name], v)
+                            if name in totals else v)
+
     def _note_loop(self, dispatch, program: str, passes: int) -> None:
         """The `loop_steps` arg of a dispatch span (weight passes: the
         program's own passes times the region's) and, after a dispatch
@@ -1796,7 +1862,9 @@ class ContinuousScheduler:
             with span("sched.prefill.dispatch", rows=len(plan),
                       tokens=real, passes=passes,
                       capacity=self.model.batch_slots * C,
-                      **({"decode_rows": ends} if sampled else {}),
+                      **({"decode_rows": ends,
+                          "slots": self.model.batch_slots}
+                         if sampled else {}),
                       ) as dispatch:
                 reads = {}
 
@@ -1832,6 +1900,8 @@ class ContinuousScheduler:
                 if self._loop_steps:
                     # (the exit pdf comes back with the logits)
                     self._note_loop(dispatch, "prefill", passes)
+                if sampled:  # (the routed layers' counts came with them)
+                    self._note_moe(dispatch, "prefill")
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise
@@ -2183,17 +2253,7 @@ class ContinuousScheduler:
                     self._tokens, self._slens, self._btab, *alive)
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
-                moe = getattr(self.model, "moe_last", None)
-                if moe is not None:
-                    dispatch.set(**{f"moe_{k}": v for k, v in moe.items()})
-                    totals = self.moe_totals
-                    if totals is None:
-                        self.moe_totals = dict(moe, dispatches=1)
-                    else:
-                        for k, v in moe.items():
-                            totals[k] = _MOE_FOLD.get(
-                                k, operator.add)(totals[k], v)
-                        totals["dispatches"] += 1
+                self._note_moe(dispatch, "decode")
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 # device-loss-style fault (hung dispatch, lost

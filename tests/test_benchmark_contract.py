@@ -102,10 +102,14 @@ def emitted():
     argument and the keywords of every `span(...)`, the keywords of every
     `.set(...)`, and, for args handed over as `**dict`, the string keys
     of dict literals in the modules that open spans (`f"moe_{k}"` over
-    `MOE_STATS`, spelled out)."""
-    from flexflow_tpu.ops.routed_experts import MOE_STATS
+    `MOE_STATS` and `MOE_ZERO_STATS`, spelled out, and the `eva_*`
+    counts, which `ops/eva_attention.py eva_row_counts` names)."""
+    from flexflow_tpu.ops.eva_attention import eva_row_counts
+    from flexflow_tpu.ops.routed_experts import MOE_STATS, MOE_ZERO_STATS
 
-    spans, args = set(), {f"moe_{k}" for k in MOE_STATS}
+    spans = set()
+    args = {f"moe_{k}" for k in MOE_STATS + MOE_ZERO_STATS} | set(
+        eva_row_counts(4, 2, 2, 1, [0], [1]))
     for dirpath, _, files in os.walk(os.path.join(ROOT, "flexflow_tpu")):
         for fn in files:
             if not fn.endswith(".py"):

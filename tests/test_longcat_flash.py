@@ -317,7 +317,8 @@ def served():
     sched = ContinuousScheduler.from_trained(
         holder(), batch_slots=3, page_size=4, num_blocks=40,
         prefill_chunk=4, prefix_cache=True, devices=jax.devices()[:1])
-    # (a pass fetches no routed-expert counts: its pad columns route too)
+    # (`also` records the decode dispatches' counts; a pass's are on its
+    # span and in stats()["moe"] by program: tests/test_pass_decode.py)
     rec = Recorder(sched, also=lambda model, i: (dict(model.moe_last),),
                    pass_also=lambda model, i: (None,))
     try:
@@ -408,6 +409,7 @@ def test_front_serves_it_and_the_dispatch_span_carries_the_counters():
         first = next_span_id()
         prompt = list(range(1, 14))
         tokens = front.generate(prompt, 3, 0.0)
+        moe = front.stats()["replicas"][0]["moe"]
     finally:
         front.close()
     want = reference_logits(tokens)
@@ -417,10 +419,20 @@ def test_front_serves_it_and_the_dispatch_span_carries_the_counters():
     assert [r.args["tokens"] for r in chunks] == [4, 4, 4]
     assert all(r.args["passes"] == 1 for r in chunks)
     decodes = [r for r in mine if r.name == "sched.decode.dispatch"]
-    assert decodes and all(
-        {"moe_pairs", "moe_dropped", "moe_max_rows", "moe_hit",
-         "moe_zero_picks", "moe_real_min", "moe_real_max"} <= set(r.args)
-        for r in decodes)
+    counts = {"moe_pairs", "moe_dropped", "moe_max_rows", "moe_hit",
+              "moe_zero_picks", "moe_real_min", "moe_real_max"}
+    assert decodes and all(counts <= set(r.args) for r in decodes)
+    # the passes count their real tokens, and the front's stats sum the
+    # two programs apart
+    assert all(counts | {"slots", "decode_rows"} <= set(r.args)
+               for r in chunks)
+    assert moe["prefill_dispatches"] == 3
+    assert moe["dispatches"] == len(decodes)
+    assert moe["prefill_pairs"] == sum(r.args["moe_pairs"] for r in chunks)
+    assert moe["zero_picks"] == sum(r.args["moe_zero_picks"]
+                                    for r in decodes)
+    # four real tokens a pass make at most 4 x top_k picks a layer
+    assert all(r.args["moe_zero_picks"] <= 4 * D.k * D.L for r in chunks)
 
 
 # -- 5. what the family counts and does not carry -----------------------------------

@@ -15,7 +15,13 @@ the five families on the pass at its toy configuration, on the CPU.
   dispatch, and what `decode_rows`, `tokens`, `rows` and
   `pass_decode_tokens` count;
 * GPT keeps the scan: no logits, no `decode_rows`, the pair;
-* the reader of `decode.in_pass_share.capacity` on a recorded ring.
+* the reader of `decode.in_pass_share.capacity` on a recorded ring;
+* what the pass reports beside its logits (ISSUE 53): the routed
+  layers' counts over its REAL tokens alone (a rider's row counts what
+  the decode step counts for that row, pad columns and idle slots
+  nothing), on the sampling pass's span and in `stats()["moe"]` by
+  program, with the decode step's program left as it was; and what
+  `model.enqueue` / `model.fetch` say they moved.
 
 Tolerance: the pass and the step compute the same float32 arithmetic
 over other shapes, 2e-5 of the logits' largest magnitude (the family
@@ -213,7 +219,7 @@ def serve(ff, cfg, pairwise):
             assert key not in sampled  # a position is sampled once
             sampled[key] = logits
     return dict(handles=handles, stats=stats, sampled=sampled, fed=fed_log,
-                beside=beside_log,
+                beside=beside_log, spans=mine,
                 passes=[r for r in mine if r.name == "sched.prefill.dispatch"],
                 steps=[r for r in mine if r.name == "sched.decode.dispatch"])
 
@@ -426,3 +432,198 @@ def test_in_pass_share_reader_without_a_traced_stretch():
     ctx = types.SimpleNamespace(_trace_t0=None, trace_window_s=None,
                                 out=lambda s: None)
     assert reader.read(ctx, {}) is None
+
+
+# -- 6. what the pass reports beside its logits (ISSUE 53) -------------------------
+ROUTED = {"kimi_k2": (), "qwen3_next": (), "longcat_flash": ("zero_picks",
+                                                            "real_min",
+                                                            "real_max")}
+MOE = ("pairs", "dropped", "max_rows", "hit")
+SLOTS = 3
+
+
+@pytest.fixture(scope="module")
+def counting(served_model):
+    """A routed family's twin at position 0 and `count(program, tokens,
+    fed)`: the routed layers' counts (`moe_last`) of ONE dispatch from
+    that state: the pass over `tokens [slots, CHUNK]` with `fed` real
+    tokens a row, or the decode step over `tokens [slots]`."""
+    name, cfg, ff = served_model
+    if name not in ROUTED:
+        pytest.skip(f"{name} has no routed layer")
+    c = ff.config
+    model = PagedKVDecodeModel(
+        ff, batch_slots=SLOTS, page_size=c.kv_page_size,
+        num_blocks=1 + SLOTS * cfg["n_positions"] // c.kv_page_size,
+        prefill_chunk=CHUNK, prefix_cache=False, devices=jax.devices()[:1])
+    assert model._moe_ops and bool(model._moe_zero_ops) == bool(ROUTED[name])
+    base = model._state
+    width = model.max_blocks_per_seq
+    btab = np.arange(1, 1 + SLOTS * width, dtype=np.int32).reshape(SLOTS, -1)
+    zeros = np.zeros(SLOTS, np.int32)
+
+    def count(program, tokens, fed):
+        model._state = jax.tree.map(jnp.copy, base)
+        model.moe_last = None
+        tokens, fed = np.asarray(tokens, np.int32), np.asarray(fed, np.int32)
+        if program == "pass":
+            logits = model.prefill_step(tokens, zeros, btab, fed)
+            assert logits.shape == (SLOTS, model.vocab)
+        else:
+            model.step(tokens, zeros, btab,
+                       *((fed,) if model.has_slot_state else ()))
+        return dict(model.moe_last)
+
+    return name, cfg, model, count
+
+
+def chunk_of(cfg, seed):
+    return np.random.default_rng(seed).integers(
+        1, cfg["vocab_size"], (SLOTS, CHUNK)).astype(np.int32)
+
+
+def test_a_riders_row_counts_what_the_decode_step_counts_for_it(counting):
+    """One token at column 0, `row_tokens` 1, the other slots idle: the
+    pass counts that row.  The decode step counts every slot's row, so
+    it is given the same token in every slot (a row picks an expert at
+    most once: `max_rows` and the sums are the slots' multiple)."""
+    name, cfg, _, count = counting
+    tokens = chunk_of(cfg, 3)
+    tokens[:, 0] = tokens[0, 0]
+    rider = count("pass", tokens, [1, 0, 0])
+    step = count("step", tokens[:, 0], [1, 1, 1])
+    assert rider["pairs"] > 0 and rider["dropped"] == 0
+    for k in ("pairs", "max_rows", *ROUTED[name][:1]):
+        assert step[k] == SLOTS * rider[k], k
+    for k in ("hit", "dropped", *ROUTED[name][1:]):
+        assert step[k] == rider[k], k
+    # and whichever slot holds the rider
+    assert count("pass", tokens, [0, 0, 1]) == rider
+
+
+def test_pad_columns_and_idle_slots_count_nothing(counting):
+    name, cfg, model, count = counting
+    tokens, fed = chunk_of(cfg, 5), [3, 1, 0]
+    want = count("pass", tokens, fed)
+    other = chunk_of(cfg, 7)
+    real = np.arange(CHUNK) < np.asarray(fed)[:, None]
+    assert count("pass", np.where(real, tokens, other), fed) == want
+    # the rows' counts add up (each row has its own pages)
+    a, b = count("pass", tokens, [3, 0, 0]), count("pass", tokens, [0, 1, 0])
+    assert want["pairs"] == a["pairs"] + b["pairs"]
+    if ROUTED[name]:
+        assert want["zero_picks"] == a["zero_picks"] + b["zero_picks"]
+        assert want["real_min"] == min(a["real_min"], b["real_min"])
+        assert want["real_max"] == max(a["real_max"], b["real_max"])
+        top_k, layers = cfg["moe_topk"], len(model._moe_zero_ops)
+        assert 0 <= want["zero_picks"] <= 4 * top_k * layers
+    # every column real: what the pass counted before it masked
+    whole = count("pass", tokens, [CHUNK] * SLOTS)
+    assert whole["pairs"] > want["pairs"] >= want["hit"] > 0
+    # nobody real: nothing
+    idle = count("pass", tokens, [0, 0, 0])
+    assert [idle[k] for k in MOE] == [0, 0, 0, 0]
+    assert idle.get("zero_picks", 0) == 0
+
+
+def test_decode_step_never_sees_the_mask(counting, monkeypatch):
+    """The mask reaches the routed layers from the pass's closure: the
+    decode step's program is the one a `forward` without the argument
+    traces, and takes no `row_tokens` for it."""
+    from flexflow_tpu.ops.routed_experts import RoutedExperts
+
+    _, _, model, _ = counting
+    args = (model.ffd._weights, model._state, np.zeros(SLOTS, np.int32),
+            np.zeros(SLOTS, np.int32),
+            np.zeros((SLOTS, model.max_blocks_per_seq), np.int32),
+            *model._row_tokens(np.ones(SLOTS, np.int32)))
+    text = model._step_fn.lower(*args).as_text()
+    assert len(jax.tree.leaves(args)) == len(jax.tree.leaves(
+        (model.ffd._weights, model._state))) + 3 + model.has_slot_state
+    inner, seen = RoutedExperts.forward, []
+
+    def forward(self, inputs, weights, *, training=False, rng=None, **kw):
+        seen.append(set(kw))
+        return inner(self, inputs, weights, training=training, rng=rng)
+
+    monkeypatch.setattr(RoutedExperts, "forward", forward)
+    from flexflow_tpu.decoding import (build_paged_decode_step,
+                                       build_paged_prefill_pass)
+
+    assert build_paged_decode_step(model.ffd).lower(*args).as_text() == text
+    assert seen and not any(seen)
+    del seen[:]
+    jax.make_jaxpr(build_paged_prefill_pass(model.ffd, CHUNK))(
+        *args[:2], np.zeros((SLOTS, CHUNK), np.int32), *args[3:5],
+        np.ones(SLOTS, np.int32))
+    assert seen and all(kw == {"count_rows"} for kw in seen)
+
+
+def test_sampling_pass_span_carries_the_counts_and_stats_sum_by_program(
+        plans):
+    name, by = plans
+    fused, pair = by["fused"], by["pair"]
+    moe = fused["stats"].get("moe")
+    args = {f"moe_{k}" for k in MOE + ROUTED.get(name, ())}
+    if name not in ROUTED:
+        assert moe is None
+        assert not any(k.startswith("moe_") for r in fused["passes"]
+                       for k in r.args)
+        return
+    for r in fused["passes"]:
+        assert args <= set(r.args) and r.args["slots"] == 3
+        assert r.args["moe_dropped"] == 0
+        assert 0 < r.args["moe_hit"] <= r.args["moe_pairs"]
+    for r in fused["steps"]:
+        assert args <= set(r.args)
+    # the pair plan's passes sample nothing and fetch no counts
+    assert not any("moe_pairs" in r.args or "slots" in r.args
+                   for r in pair["passes"])
+    assert moe["prefill_dispatches"] == len(fused["passes"])
+    assert moe["dispatches"] == len(fused["steps"])
+    for k in ("pairs", "max_rows", "hit", "dropped"):
+        assert moe[f"prefill_{k}"] == sum(
+            r.args[f"moe_{k}"] for r in fused["passes"])
+        assert moe[k] == sum(r.args[f"moe_{k}"] for r in fused["steps"])
+    if ROUTED[name]:
+        assert moe["prefill_real_min"] == min(
+            r.args["moe_real_min"] for r in fused["passes"])
+        assert moe["real_max"] == max(
+            r.args["moe_real_max"] for r in fused["steps"])
+
+
+def test_enqueue_and_fetch_say_what_they_moved(plans):
+    """`program`, `arg_leaves`, `host_bytes` on `model.enqueue` and
+    `program`, `bytes` on `model.fetch`, the same on every call of one
+    program; a fetch follows every enqueue of a sampled program."""
+    name, by = plans
+    for run in by.values():
+        calls = [r for r in run["spans"]
+                 if r.name in ("model.enqueue", "model.fetch")]
+        moved = {}
+        for r in calls:
+            static = {k: v for k, v in r.args.items() if k != "first"}
+            assert moved.setdefault((r.name, r.args["program"]),
+                                    static) == static
+        programs = {p for _, p in moved}
+        assert {"step", "prefill"} <= programs <= {
+            "step", "prefill", "reset_slot_state"}
+        for (span_name, program), static in moved.items():
+            if span_name == "model.fetch":
+                assert set(static) == {"program", "bytes"}
+                assert static["bytes"] >= 3 * 4  # a logit a slot at least
+            else:
+                assert set(static) == {"program", "arg_leaves", "host_bytes"}
+                assert static["arg_leaves"] > 3
+        # tokens [3, C], positions, row_tokens and the table against
+        # tokens [3], positions and the table: the pass copies in more
+        assert moved["model.enqueue", "prefill"]["host_bytes"] > \
+            moved["model.enqueue", "step"]["host_bytes"] >= 3 * 4 * 3
+        assert moved["model.enqueue", "prefill"]["arg_leaves"] >= \
+            moved["model.enqueue", "step"]["arg_leaves"]
+        sampled = [r.args["program"] for r in calls
+                   if r.args["program"] in ("step", "prefill")]
+        assert sampled[0::2] == sampled[1::2]  # enqueue, then its fetch
+        assert [r.name for r in calls
+                if r.args["program"] in ("step", "prefill")][:2] == [
+            "model.enqueue", "model.fetch"]

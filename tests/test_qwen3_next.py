@@ -351,6 +351,9 @@ def chunk_pair(twin):
         last[counts == j + 1] = np.asarray(logits, np.float32)[counts == j + 1]
     at_last, passed = run("pass", state, feed[:, :CHUNK], starts, table,
                           counts)
+    # (behind the rows' logits ride the routed layers' counts: a row)
+    assert at_last.shape == (SLOTS + 1, D.v)
+    at_last = at_last[:SLOTS]
     after = {}
     for name, st, own in (("steps", stepped, last),
                           ("pass", passed, np.asarray(at_last, np.float32))):
