@@ -646,16 +646,84 @@ def _sampled_outputs(ffd: FFModel):
     return finish
 
 
+def _column0(tokens, prev_ids, take_prev):
+    """`tokens[b]`, a dispatch's first token a row, with row i's taken
+    from `prev_ids[i]` (the last sampling dispatch's `ids`, still on the
+    device) where `take_prev[i]` is set, else the host's."""
+    import jax.numpy as jnp
+
+    return jnp.where(take_prev > 0, prev_ids.astype(tokens.dtype), tokens)
+
+
+def _greedy_ids(logits):
+    """ids [b] int32: the greedy choice over exactly the logits a row
+    of the program returns, the first of equal maxima as `np.argmax`
+    takes it on the host's float32 copy (a cast that keeps order and
+    ties)."""
+    import jax.numpy as jnp
+
+    with scopes.scope(scopes.LOGITS):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _counted(ffd: FFModel) -> Dict[str, list]:
+    """{count entry: the ops that leave it in their state, in graph
+    order}: the routed layers' `moe_stats`, and `moe_zero` of those with
+    identity experts."""
+    return {entry: [op for op, entries in ffd._state.items()
+                    if entry in entries]
+            for entry in ("moe_stats", "moe_zero")}
+
+
+def _counts_behind(out: tuple, new_state, counted) -> tuple:
+    """`out` with the routed layers' counts IN the logits' buffer
+    (`out[0]`): `r` more rows behind the `b` rows of logits hold every
+    layer's `moe_stats` and then every identity-expert layer's
+    `moe_zero`, in graph order, as the logits' dtype (small whole
+    numbers, exact), zero-padded to the row; `split_pass_counts` takes
+    them off on the host.  One buffer comes back, not one more a layer
+    and entry, and none of them is a buffer of the state, which the
+    next dispatch is donated."""
+    import jax.numpy as jnp
+
+    with scopes.scope(scopes.LOGITS):
+        width = out[0].shape[1]
+        flat = jnp.concatenate([
+            new_state[op][entry] for entry, ops in counted.items()
+            for op in ops]).astype(out[0].dtype)
+        rows = -(-flat.shape[0] // width)
+        packed = jnp.pad(flat, (0, rows * width - flat.shape[0]))
+        return (jnp.concatenate([out[0], packed.reshape(rows, width)]),
+                *out[1:])
+
+
 def build_paged_decode_step(ffd: FFModel):
     """ONE compiled step function for continuous batching on a paged
     decode twin (make_decoder with kv_page_size > 0):
 
         step(weights, state, tokens[b], positions[b], block_table
-             [, row_tokens[b]])
-            -> (logits [b, vocab], new_state[, exit_pdf [b, passes]])
+             [, row_tokens[b][, prev_ids[b], take_prev[b]]])
+            -> (logits [b, vocab], new_state[, exit_pdf [b, passes]]
+                [, ids [b]])
 
     The third output exists for a family with an exit gate only
     (`DecoderRecipe.exit_gate`): each row's `exit_pdf`, float32.
+
+    `prev_ids` / `take_prev` are passed by the engine of a family whose
+    sampling programs keep the greedy id on the device (one on the
+    one-pass prefill program: PagedKVDecodeModel `keeps_ids`):
+    row i is fed `prev_ids[i]`, the `ids` of the sampling dispatch
+    before this one (never fetched in between), where `take_prev[i]` is
+    set, and the host's `tokens[i]` otherwise; the last output is then
+    `ids`, the argmax of each returned row of logits.  So the host can
+    enqueue a dispatch before it has fetched the one before
+    (serving/scheduler.py "one dispatch of lookahead").  Left out, the
+    program is the one it always was (`row_tokens` is then None for a
+    twin without per-slot state).  With them, a graph with routed
+    layers also returns their counts as rows behind the logits'
+    (`_counts_behind`), as the one-pass prefill program does: a flight's
+    counts must outlive the state they were left in, which the next
+    dispatch is donated.
 
     `row_tokens` is passed by the engine of a twin with per-slot
     recurrent state only (1 for a live row, 0 for an idle slot, whose
@@ -683,17 +751,25 @@ def build_paged_decode_step(ffd: FFModel):
 
     ex = ffd.executor
     finish = _sampled_outputs(ffd)
+    counted = _counted(ffd)
 
     def step(weights, state, tokens, positions, block_table,
-             row_tokens=None):
+             row_tokens=None, prev_ids=None, take_prev=None):
         state = _host_owned(state, block_table, positions, row_tokens)
         with scopes.scope(scopes.FEED):
+            if prev_ids is not None:
+                tokens = _column0(tokens, prev_ids, take_prev)
             inputs = {"input": tokens[:, None],
                       "positions": positions[:, None].astype(jnp.int32)}
         logits, new_state, _, env = ex.run_forward(
             weights, state, inputs, training=False, rng=None,
         )
-        return finish(logits, new_state, env)
+        out = finish(logits, new_state, env)
+        if prev_ids is None:
+            return out
+        out = (*out, _greedy_ids(out[0]))
+        return (_counts_behind(out, new_state, counted)
+                if counted["moe_stats"] else out)
 
     with ex.mesh:
         return jax.jit(step, donate_argnums=(1,))
@@ -776,8 +852,9 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     decode step's outputs:
 
         prefill(weights, state, tokens[b, C], positions[b], block_table,
-                row_tokens[b])
-            -> (logits [b (+ r), vocab], new_state[, exit_pdf [b, passes]])
+                row_tokens[b][, prev_ids[b], take_prev[b]])
+            -> (logits [b (+ r), vocab], new_state[, exit_pdf [b, passes]]
+                [, ids [b]])
 
     A dispatch streams the weights once and builds each layer's
     gathered view once, where build_paged_prefill_step's scan does both
@@ -792,7 +869,10 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     gate) read that one position of each row, gathered before them:
     the head multiplies [b, hidden], not [b, C, hidden].
     `logit_columns` and the exit gate's `exit_pdf` are applied as
-    build_paged_decode_step applies them.
+    build_paged_decode_step applies them, and `prev_ids` / `take_prev`
+    / `ids` mean what they mean there: column 0 of row i is
+    `prev_ids[i]` where `take_prev[i]` is set, `ids` the argmax of the
+    `b` rows of logits.
 
     The routed layers of the graph (`moe_stats` in their state) count
     the REAL tokens of the pass alone, `column < row_tokens[row]`: the
@@ -800,14 +880,11 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     never through a state entry, which would make a twin "carry
     per-slot state" and hand the decode step an argument.  Their output
     is every column's as before (a pad column multiplies like any
-    other).  Such a graph's counts ride IN the logits' buffer: `r` more
-    rows behind the `b` rows of logits hold every layer's `moe_stats`
-    and then every identity-expert layer's `moe_zero`, in graph order,
-    as float32 (small whole numbers, exact), zero-padded to the row;
-    `split_pass_counts` takes them off on the host.  One buffer comes
-    back, as before: every further buffer a fetch brings costs the host
-    a tenth of a millisecond or two on the v5e, in the `device_get` or
-    beside it (PERF.md, PR 53), and this is every token's path.
+    other).  Such a graph's counts ride IN the logits' buffer
+    (`_counts_behind`).  One buffer comes back, as before: every
+    further buffer a fetch brings costs the host a tenth of a
+    millisecond or two on the v5e, in the `device_get` or beside it
+    (PERF.md, PR 53), and this is every token's path.
 
     The twin's graph is interpreted over [b, C] inputs as it stands:
     the recipe's claim is that every op of it is per-token or takes
@@ -838,12 +915,14 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
     made = {t.guid for op in head for t in op.outputs}
     # what the head reads of the layers: [..., C, hidden] tensors
     cut = {t.guid for op in head for t in op.inputs} - made
-    counted = {entry: [op for op, entries in ffd._state.items()
-                       if entry in entries]
-               for entry in ("moe_stats", "moe_zero")}
+    counted = _counted(ffd)
 
-    def prefill(weights, state, tokens, positions, block_table, row_tokens):
+    def prefill(weights, state, tokens, positions, block_table, row_tokens,
+                prev_ids=None, take_prev=None):
         with scopes.scope(scopes.FEED):
+            if prev_ids is not None:
+                tokens = tokens.at[:, 0].set(
+                    _column0(tokens[:, 0], prev_ids, take_prev))
             positions = positions.astype(jnp.int32)
             row_tokens = row_tokens.astype(jnp.int32)
         state = _host_owned(state, block_table, positions, row_tokens)
@@ -866,17 +945,10 @@ def build_paged_prefill_pass(ffd: FFModel, chunk: int):
             narrow=dict.fromkeys(cut, last_token), count_rows=real,
         )
         out = finish(logits, new_state, env)
-        if real is None:
-            return out
-        with scopes.scope(scopes.LOGITS):
-            width = out[0].shape[1]
-            flat = jnp.concatenate([
-                new_state[op][entry] for entry, ops in counted.items()
-                for op in ops]).astype(out[0].dtype)
-            rows = -(-flat.shape[0] // width)
-            packed = jnp.pad(flat, (0, rows * width - flat.shape[0]))
-            return (jnp.concatenate([out[0], packed.reshape(rows, width)]),
-                    *out[1:])
+        if prev_ids is not None:
+            out = (*out, _greedy_ids(out[0]))
+        return out if real is None else _counts_behind(out, new_state,
+                                                        counted)
 
     with ex.mesh:
         return jax.jit(prefill, donate_argnums=(1,))

@@ -81,13 +81,18 @@ class span:
 
     def set(self, **args) -> None:
         """Counts known only once the work is done (tokens emitted,
-        requests admitted): into the record and the xplane event."""
+        requests admitted): into the record and the xplane event.
+        After the span has ended (counts that a later fetch brings for
+        a dispatch left on the device) into the ring's record alone:
+        the xplane event is closed."""
         self.args.update(args)
-        self._ann.set_metadata(**args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __exit__(self, exc_type, exc, tb):
         self.t_end = t_end = _now()
         self._ann.__exit__(exc_type, exc, tb)
+        self._ann = None
         _local.stack.pop()
         _ring.append((self.span_id, self.parent_id, self.name, _thread(),
                       self.t_start, t_end, self.args))

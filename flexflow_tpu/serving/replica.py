@@ -50,7 +50,8 @@ FATAL_DECODE_FAULTS = (DeviceLossFault, HungStepFault, HungStepTimeout)
 
 #: per-replica scheduler counters folded into `stats()` across restarts
 _CARRIED_COUNTERS = ("batches_run", "requests_done", "tokens_generated",
-                     "pass_decode_tokens", "step_failures", "admitted",
+                     "pass_decode_tokens", "dispatches_ahead",
+                     "overrun_tokens", "step_failures", "admitted",
                      "queue_wait_s_sum")
 
 
@@ -75,6 +76,10 @@ class SupervisedDecodeModel:
         self.prefill_passes = getattr(model, "prefill_passes",
                                       self.prefill_chunk)
         self.prefix_cache = getattr(model, "prefix_cache", True)
+        # sampling programs that keep the greedy id on the device: the
+        # scheduler may then leave a dispatch in flight (`launch_step`
+        # / `launch_prefill`) and `land` it behind the next one
+        self.keeps_ids = bool(getattr(model, "keeps_ids", False))
         # fused-kernel surface: which paged formulation runs + the
         # per-block byte unit the scheduler's read telemetry uses
         self.paged_kernel = getattr(model, "paged_kernel", "gather")
@@ -139,51 +144,61 @@ class SupervisedDecodeModel:
         # the model has no such layer)
         return getattr(self._model, "moe_last", None)
 
-    def reset_slot_state(self, slot):
-        # a device dispatch like copy_block: same fault plan and
-        # watchdog, so a wedged reset surfaces as a hung step
-        idx = next(self._steps)
+    def _supervised(self, fn, idx=None):
+        """`fn()`, a device dispatch, under the replica-lifetime step
+        index, the seeded fault plan and the hang watchdog; a hung or
+        lost-device dispatch is marked fatal, so the scheduler
+        drains-and-dies into a supervised restart instead of failing
+        the in-flight batch alone.  `idx`: the wait for a dispatch
+        launched under that index (no new index, no second injection:
+        it is the same dispatch, and the wait is where a hang shows)."""
+        launch = idx is None
+        if launch:
+            idx = next(self._steps)
         try:
-            self._fault_plan.check_step(idx)
-            return self._watchdog.sync(
-                lambda: self._model.reset_slot_state(slot), step=idx)
+            if launch:
+                self._fault_plan.check_step(idx)
+            return idx, self._watchdog.sync(fn, step=idx)
         except FATAL_DECODE_FAULTS as e:
             e.fatal_to_engine = True
             raise
 
+    def reset_slot_state(self, slot):
+        # a device dispatch like copy_block: a wedged reset surfaces as
+        # a hung step
+        return self._supervised(
+            lambda: self._model.reset_slot_state(slot))[1]
+
     def step(self, tokens, seq_lens, block_tables, *row_tokens):
-        idx = next(self._steps)
-        try:
-            self._fault_plan.check_step(idx)
-            return self._watchdog.sync(
-                lambda: self._model.step(tokens, seq_lens, block_tables,
-                                         *row_tokens),
-                step=idx,
-            )
-        except FATAL_DECODE_FAULTS as e:
-            # the scheduler must drain-and-die, not fail-in-flight-only
-            e.fatal_to_engine = True
-            raise
+        return self._supervised(lambda: self._model.step(
+            tokens, seq_lens, block_tables, *row_tokens))[1]
 
     def prefill_step(self, tokens, positions, block_tables, *row_tokens,
                      meanwhile=None):
-        # chunked prefill is a decode-fleet step like any other: fault
-        # injection and the hang watchdog see it under the same
-        # replica-lifetime step index
+        # chunked prefill is a decode-fleet step like any other
         # (`meanwhile`: the one-pass program's; see the model's)
         beside = {} if meanwhile is None else {"meanwhile": meanwhile}
-        idx = next(self._steps)
-        try:
-            self._fault_plan.check_step(idx)
-            return self._watchdog.sync(
-                lambda: self._model.prefill_step(
-                    tokens, positions, block_tables, *row_tokens,
-                    **beside),
-                step=idx,
-            )
-        except FATAL_DECODE_FAULTS as e:
-            e.fatal_to_engine = True
-            raise
+        return self._supervised(lambda: self._model.prefill_step(
+            tokens, positions, block_tables, *row_tokens, **beside))[1]
+
+    def launch_step(self, tokens, seq_lens, block_tables, *row_tokens,
+                    take_prev=None):
+        """(step index, what the enqueued decode step left on the
+        device): `land` takes it back."""
+        return self._supervised(lambda: self._model.launch_step(
+            tokens, seq_lens, block_tables, *row_tokens,
+            take_prev=take_prev))
+
+    def launch_prefill(self, tokens, positions, block_tables, *row_tokens,
+                       take_prev=None):
+        return self._supervised(lambda: self._model.launch_prefill(
+            tokens, positions, block_tables, *row_tokens,
+            take_prev=take_prev))
+
+    def land(self, launched, behind=False):
+        idx, launched = launched
+        return self._supervised(
+            lambda: self._model.land(launched, behind=behind), idx)[1]
 
     @property
     def verify_step(self):
@@ -199,17 +214,8 @@ class SupervisedDecodeModel:
             return None
 
         def _verify(tokens, seq_lens, counts, block_tables):
-            idx = next(self._steps)
-            try:
-                self._fault_plan.check_step(idx)
-                return self._watchdog.sync(
-                    lambda: self._model.verify_step(
-                        tokens, seq_lens, counts, block_tables),
-                    step=idx,
-                )
-            except FATAL_DECODE_FAULTS as e:
-                e.fatal_to_engine = True
-                raise
+            return self._supervised(lambda: self._model.verify_step(
+                tokens, seq_lens, counts, block_tables))[1]
 
         return _verify
 
@@ -225,14 +231,8 @@ class SupervisedDecodeModel:
             return None
 
         def _copy(src, dst):
-            idx = next(self._steps)
-            try:
-                self._fault_plan.check_step(idx)
-                return self._watchdog.sync(
-                    lambda: self._model.copy_block(src, dst), step=idx)
-            except FATAL_DECODE_FAULTS as e:
-                e.fatal_to_engine = True
-                raise
+            return self._supervised(
+                lambda: self._model.copy_block(src, dst))[1]
 
         return _copy
 
@@ -597,6 +597,9 @@ class ServingReplica:
             # weight passes of one prefill dispatch (the scan: the
             # chunk; a family's one-pass program: 1)
             out["prefill_passes"] = sstats["prefill_passes"]
+            # why dispatches were fetched at once, or flights ended out
+            # of turn (this engine's, since its last build)
+            out["lookahead_drains"] = sstats["lookahead_drains"]
             # this engine's pool (peak_used_blocks since its last build)
             out["kv_pool"] = sstats["kv_pool"]
             # prefix-cache visibility per replica (each pool caches

@@ -39,14 +39,23 @@ at its last real token: every live row is a row of it, a row past its
 prompt with its pending token and one token fed, and is sampled from
 it.  When no row is feeding, the plain decode step runs.  Nothing
 switches this: the recipe's `prefill_pass` and whether a row feeds.
+Such a family's programs also keep each row's greedy id on the device,
+so the loop leaves a dispatch in flight and enqueues the next one
+before it waits for it: the host's turn (sampling, retiring, admitting,
+preparing) runs beside a pass, not between two.  A dispatch's
+bookkeeping is cut at what it needs to know: `_advance_rows` from the
+plan alone, `_settle_rows` once the logits are on the host; what keeps
+an iteration synchronous is `_synchronous`'s to say.
 
 Shape discipline (the TPU-native part): one compiled [slots, 1] step
 program (and one [slots, C] chunk program) serves the engine's whole
 lifetime — admissions, retirements and per-row positions are DATA
 (block tables + seq_lens), never shapes, so steady state has zero
 recompiles.  Sampling is host-side
-per row, which also lifts the static batcher's same-temperature
-coalescing restriction: a continuous batch freely mixes temperatures.
+per row (a greedy row's id is also chosen on the device, where the
+family's programs keep it: the same id), which also lifts the static
+batcher's same-temperature coalescing restriction: a continuous batch
+freely mixes temperatures.
 
 SLO telemetry (obs.metrics): TTFT and per-token latency histograms,
 queue depth, KV-pool occupancy/fragmentation — drained to
@@ -119,6 +128,19 @@ def pick_paged_read(asked: str = "auto", *, backend: str,
         if kernel == "pallas"
         else "dense block-gather, the bit-identity oracle")
     return kernel
+
+
+class _Launched:
+    """What an enqueued sampling program left on the device, until
+    `PagedKVDecodeModel.land` fetches it: the logits, the rows' exit
+    pdf (`()` without an exit gate) and the decode step's routed-layer
+    count buffers (None without any)."""
+
+    __slots__ = ("program", "logits", "exit_pdf", "counts")
+
+    def __init__(self, program: str, logits, exit_pdf, counts):
+        self.program, self.logits = program, logits
+        self.exit_pdf, self.counts = exit_pdf, counts
 
 
 class PagedKVDecodeModel:
@@ -257,6 +279,18 @@ class PagedKVDecodeModel:
              else build_paged_prefill_step)(self.ffd, self.prefill_chunk)
             if self.prefill_chunk else None)
         self._copy_fn = build_paged_copy_block(self.ffd)
+        # a family on the one-pass program keeps each row's greedy id
+        # on the device (GPT's scan and step are held to byte equality
+        # with their oracle and stay the programs they were: one rule,
+        # the recipe's carry, chooses both): its sampling programs take
+        # the last sampling dispatch's `ids` and `take_prev` (which rows
+        # are fed from them, not from the host's tokens) and return
+        # their own, so a dispatch can be enqueued before the one
+        # before it is fetched (`launch_step` / `launch_prefill` /
+        # `land`).  `step` and `prefill_step` pass `take_prev` all zero:
+        # one program a family either way
+        self.keeps_ids = one_pass
+        self._ids = None  # (made at the first sampling dispatch)
         # {program: the static args of its `model.enqueue` span}, made
         # at the program's first call (`_enqueue`); {program: bytes its
         # `model.fetch` brings back}
@@ -426,6 +460,7 @@ class PagedKVDecodeModel:
         import jax.numpy as jnp
 
         self._state = None  # (the old pools go before the new ones come)
+        self._ids = None
         self._state = jax.tree.map(
             lambda x: jax.device_put(
                 jnp.zeros(x.shape, x.dtype), x.sharding),
@@ -456,18 +491,37 @@ class PagedKVDecodeModel:
             self.batch_slots, positions, counts)
         return {k: v * self.eva["layers"] for k, v in one.items()}
 
-    def _row_tokens(self, row_tokens, one_pass: bool = False) -> tuple:
-        """The step programs' trailing argument: `row_tokens` for a
+    def _row_tokens(self, row_tokens, one_pass: bool = False,
+                    take_prev=None) -> tuple:
+        """The step programs' trailing arguments: `row_tokens` for a
         twin with per-slot state and for the one-pass prefill program
-        (required there), nothing otherwise."""
-        if not (self._slot_state or one_pass):
-            return ()
-        if row_tokens is None:
-            raise ValueError(
-                "this twin carries per-slot recurrent state, or this is "
-                "its one-pass prefill program: it needs row_tokens (how "
-                "far each row advances)")
-        return (np.asarray(row_tokens, np.int32),)
+        (required there), nothing otherwise; then, for a family that
+        keeps its ids on the device, the last sampling dispatch's `ids`
+        and `take_prev` (None: no row takes its token from them)."""
+        rows: tuple = ()
+        if self._slot_state or one_pass:
+            if row_tokens is None:
+                raise ValueError(
+                    "this twin carries per-slot recurrent state, or this "
+                    "is its one-pass prefill program: it needs row_tokens "
+                    "(how far each row advances)")
+            rows = (np.asarray(row_tokens, np.int32),)
+        if not self.keeps_ids:
+            return rows
+        if self._ids is None:
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            # (placed as the programs return theirs, replicated over
+            # the twin's mesh: the first call's program is then every
+            # later call's)
+            self._ids = jax.device_put(
+                np.zeros(self.batch_slots, np.int32),
+                NamedSharding(self.ffd.mesh, PartitionSpec()))
+        if take_prev is None:
+            take_prev = np.zeros(self.batch_slots, np.int32)
+        return (*(rows or (None,)), self._ids,
+                np.asarray(take_prev, np.int32))
 
     def reset_slot_state(self, slot: int) -> None:
         """Zero ONE slot's recurrent state (admission): ordered with
@@ -501,33 +555,61 @@ class PagedKVDecodeModel:
 
     def step(self, tokens: np.ndarray, seq_lens: np.ndarray,
              block_tables: np.ndarray, row_tokens=None) -> np.ndarray:
+        return self.land(self.launch_step(tokens, seq_lens, block_tables,
+                                          row_tokens))
+
+    def launch_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
+                    block_tables: np.ndarray, row_tokens=None,
+                    take_prev=None) -> "_Launched":
+        """Enqueue the decode step and return what it left on the
+        device (`land` fetches it).  `take_prev[i]` set (a family that
+        `keeps_ids` only): row i is fed the id the last sampling
+        dispatch chose for it, which the host has not seen yet."""
         # per-token hot path: the block table / seq_lens override
         # happens INSIDE the jitted step and the state pytree is
         # donated — no host-side dict rebuild, no per-layer pool copy
-        logits, self._state, *exit_pdf = self._enqueue(
+        out = self._enqueue(
             "step", self._step_fn, self.ffd._weights, self._state, tokens,
-            seq_lens, block_tables, *self._row_tokens(row_tokens))
+            seq_lens, block_tables,
+            *self._row_tokens(row_tokens, take_prev=take_prev))
+        logits, exit_pdf = self._took(out)
         counts = None
-        if self._moe_ops and not exit_pdf:
-            # (the step leaves its counts in the state: two buffers a
-            # layer)
+        if self._moe_ops and not exit_pdf and not self.keeps_ids:
+            # (the step leaves its counts in the state, two buffers a
+            # layer; a program that keeps its ids returns them behind
+            # the logits' rows, as the one-pass prefill does: the state
+            # is the next dispatch's before a flight is fetched)
             counts = {"moe_stats": [self._state[op]["moe_stats"]
                                     for op in self._moe_ops],
                       "moe_zero": [self._state[op]["moe_zero"]
                                    for op in self._moe_zero_ops]}
-        return self._fetch("step", logits, exit_pdf, counts)
+        return _Launched("step", logits, exit_pdf, counts)
 
-    def _fetch(self, program: str, logits, exit_pdf=(),
-               counts=None) -> np.ndarray:
+    def _took(self, out) -> tuple:
+        """(logits, exit_pdf) of a sampling program's outputs, the
+        state and the ids kept here."""
+        if self.keeps_ids:
+            *out, self._ids = out
+        logits, self._state, *exit_pdf = out
+        return logits, exit_pdf
+
+    def land(self, launched: "_Launched", behind: bool = False) -> np.ndarray:
         """The wait for the device, then the logits' copy to the host
         and with it, in the one `device_get`, what the program returned
         beside them: the rows' exit pdf (`exit_last`) and, from the
-        decode step, the routed layers' `counts` (`moe_last`; a buffer
-        a layer and entry, as the step leaves them in its state).  The
-        span carries `program` and the `bytes` that came back."""
+        decode step, the routed layers' counts (`moe_last`; a buffer
+        a layer and entry, as the step leaves them in its state; the
+        one-pass program's come as rows behind the logits).  The span
+        carries `program` and the `bytes` that came back; it is
+        `model.fetch`, or `model.fetch_behind` when another sampling
+        dispatch was enqueued since this one (`behind`): the wait is
+        then beside that dispatch's launch, not between two."""
         import jax
 
-        with span("model.fetch", program=program) as fetch:
+        program, logits = launched.program, launched.logits
+        exit_pdf, counts = launched.exit_pdf, launched.counts
+        with span("model.fetch_behind" if behind else "model.fetch",
+                  program=program) as fetch:
             beside = {**({"exit": exit_pdf[0]} if exit_pdf else {}),
                       **(counts or {})}
             if beside:
@@ -543,7 +625,15 @@ class PagedKVDecodeModel:
             if counts:
                 self.moe_last = self._summed(beside["moe_stats"],
                                              beside["moe_zero"])
-            return np.asarray(logits, np.float32)
+            logits = np.asarray(logits, np.float32)
+        if self.keeps_ids and self._moe_ops:
+            # (such a family's sampling programs, pass and step alike,
+            # return the routed layers' counts behind the logits' rows)
+            logits, counts = self._split_pass_counts(
+                logits, self.batch_slots, len(self._moe_ops),
+                len(self._moe_zero_ops))
+            self.moe_last = self._summed(**counts)
+        return logits
 
     @staticmethod
     def _summed(moe_stats, moe_zero) -> Dict[str, int]:
@@ -572,24 +662,28 @@ class PagedKVDecodeModel:
         `meanwhile()` is called once the program is enqueued, before
         the wait for it: host work that needs no result of the
         dispatch runs beside the device, not after it."""
+        launched = self.launch_prefill(tokens, positions, block_tables,
+                                       row_tokens)
+        if meanwhile is not None:
+            meanwhile()
+        return None if launched is None else self.land(launched)
+
+    def launch_prefill(self, tokens: np.ndarray, positions: np.ndarray,
+                       block_tables: np.ndarray, row_tokens=None,
+                       take_prev=None) -> Optional["_Launched"]:
+        """Enqueue the chunked-prefill program; what the one-pass
+        program left on the device for `land`, None from the scan
+        (nothing to fetch).  `take_prev` as `launch_step` takes it, for
+        the rows' column 0."""
         one_pass = self.prefill_passes == 1
         out = self._enqueue(
             "prefill", self._prefill_fn, self.ffd._weights, self._state,
             tokens, positions, block_tables,
-            *self._row_tokens(row_tokens, one_pass))
-        if meanwhile is not None:
-            meanwhile()
+            *self._row_tokens(row_tokens, one_pass, take_prev))
         if not one_pass:
             self._state = out
             return None
-        logits, self._state, *exit_pdf = out
-        logits = self._fetch("prefill", logits, exit_pdf)
-        if self._moe_ops:
-            logits, counts = self._split_pass_counts(
-                logits, self.batch_slots, len(self._moe_ops),
-                len(self._moe_zero_ops))
-            self.moe_last = self._summed(**counts)
-        return logits
+        return _Launched("prefill", *self._took(out), None)
 
     def verify_step(self, tokens: np.ndarray, seq_lens: np.ndarray,
                     counts: np.ndarray,
@@ -603,7 +697,7 @@ class PagedKVDecodeModel:
         logits, self._state = self._enqueue(
             "verify", self._verify_fn, self.ffd._weights, self._state,
             tokens, seq_lens, counts, block_tables)
-        return self._fetch("verify", logits)
+        return self.land(_Launched("verify", logits, (), None))
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write: clone physical block src -> dst in every
@@ -737,7 +831,7 @@ class _Live:
     budget count them exactly as the uninterrupted run would."""
 
     __slots__ = ("req", "seq_id", "pos", "next_token", "generated",
-                 "max_new", "rng", "feed", "tspan")
+                 "max_new", "rng", "feed", "tspan", "unsettled")
 
     def __init__(self, req: _PendingSeq, seq_id: int, max_new: int,
                  start: int = 0, feed=None, generated=None,
@@ -745,8 +839,14 @@ class _Live:
         self.req = req
         self.seq_id = seq_id
         self.feed = req.prompt if feed is None else list(feed)
-        self.pos = start                  # tokens already in the cache
+        # tokens already in the cache, those that a dispatch still in
+        # flight writes included (advanced once it is enqueued)
+        self.pos = start
         self.next_token = self.feed[start]  # token fed at position pos
+        # sampled tokens that dispatches in flight owe this row: the
+        # host has not seen them (`next_token` is then stale: the next
+        # dispatch takes the row's token from the device's ids)
+        self.unsettled = 0
         self.generated: List[int] = list(generated or [])
         self.max_new = max_new            # clamped to the position table
         self.rng = (np.random.RandomState(req.seed)
@@ -809,6 +909,31 @@ class _LiveTrace:
                       spec_proposed=req.spec_proposed,
                       spec_accepted=req.spec_accepted,
                       batch_spans=self.batch_refs)
+
+
+class _Flight:
+    """One sampling dispatch from its enqueue to the settling of its
+    rows: its span, what it left on the device (`_Launched`; None once
+    fetched), `rows` [(slot, live, position before it, tokens fed)] and
+    the `_kv_reads` of it."""
+
+    __slots__ = ("program", "dispatch", "launched", "rows", "reads")
+
+    def __init__(self, program: str, dispatch, launched, rows, reads):
+        self.program, self.dispatch, self.launched = (
+            program, dispatch, launched)
+        self.rows, self.reads = rows, reads
+
+
+def advanced_slots(slots) -> list:
+    """The slots as the NEXT dispatch sees them while one is in flight:
+    a row that the dispatches in flight give its last token (by length:
+    `max_new` is known a dispatch ahead, an EOS is not) is no row of
+    it.  Pure, like the plan it feeds: once the flights are settled it
+    is `slots` without the rows they finished."""
+    return [None if live is None
+            or len(live.generated) + live.unsettled >= live.max_new
+            else live for live in slots]
 
 
 def plan_chunk_rows(slots, chunk: int, sampled: bool) -> List[tuple]:
@@ -877,6 +1002,21 @@ class ContinuousScheduler:
         # dispatch with every live row in it (`plan_chunk_rows`)
         self._pass_samples = bool(self._chunk) and self._passes == 1
         self.pass_decode_tokens = 0  # tokens sampled from such passes
+        # one dispatch of lookahead (docs/SERVING.md "The step plan"):
+        # a model whose sampling programs keep the greedy id on the
+        # device lets a dispatch be enqueued before the one before it
+        # is fetched.  `_flights`: the dispatches enqueued and not yet
+        # settled, oldest first (one between iterations, two while the
+        # next is being enqueued)
+        self._keeps_ids = bool(getattr(model, "keeps_ids", False))
+        self._flights: deque = deque()
+        self._landed_at = 0.0  # when the last flight's logits arrived
+        self.dispatches_ahead = 0  # enqueued behind an unfetched one
+        # why a sampling dispatch was fetched at once or a flight ended
+        # out of turn (`_synchronous`; `fault`: flights dropped)
+        self.lookahead_drains = dict.fromkeys(
+            ("temperature", "spec", "service", "fault", "family"), 0)
+        self.overrun_tokens = 0  # sampled for a row an EOS had ended
         self._can_cow = getattr(model, "copy_block", None) is not None
         # fused-kernel read telemetry (docs/SERVING.md "Fused paged
         # attention"): under paged_kernel="pallas" every dispatch
@@ -1135,6 +1275,11 @@ class ContinuousScheduler:
                 fn, on_dropped = self._service.get_nowait()
             except queue.Empty:
                 return
+            if self._flights:
+                # a service (pause, handoff, a KV import) sees every
+                # row where the device left it: nothing in flight
+                self.lookahead_drains["service"] += 1
+                self._settle_flights()
             try:
                 fn()
             except Exception as e:
@@ -1235,6 +1380,9 @@ class ContinuousScheduler:
             "prefill_chunk": self._chunk,
             "prefill_passes": self._passes,
             "pass_decode_tokens": self.pass_decode_tokens,
+            "dispatches_ahead": self.dispatches_ahead,
+            "lookahead_drains": dict(self.lookahead_drains),
+            "overrun_tokens": self.overrun_tokens,
             "requests_done": self.requests_done,
             "tokens_generated": self.tokens_generated,
             "step_failures": self.step_failures,
@@ -1419,6 +1567,7 @@ class ContinuousScheduler:
         Runs on the worker's way out of _loop AND from close() — which
         overlap only when close() gave up on a wedged worker, so
         retires tolerate the other drain having won the race."""
+        self._drop_flights()
         for i, s in enumerate(self._slots):
             if s is not None:
                 try:
@@ -1647,6 +1796,9 @@ class ContinuousScheduler:
         self.step_failures += 1
         if self.registry is not None:
             self.registry.counter("serving/step_failures").inc()
+        if self._flights:
+            self.lookahead_drains["fault"] += 1
+            self._drop_flights()
         for i, live in enumerate(self._slots):
             if live is None:
                 continue
@@ -1771,25 +1923,32 @@ class ContinuousScheduler:
 
     def _note_loop(self, dispatch, program: str, passes: int) -> None:
         """The `loop_steps` arg of a dispatch span (weight passes: the
-        program's own passes times the region's) and, after a dispatch
-        that returns logits (decode, or the one-pass prefill) of a
-        model with an exit gate, `exit_mass_<t>`: the exit pdf after
-        pass t, mean over the dispatch's live rows (`model.exit_last`,
-        fetched with the logits).  Summed into `loop_totals`."""
+        program's own passes times the region's), summed into
+        `loop_totals`."""
         t = self.loop_totals
         steps = passes * self._loop_steps
         dispatch.set(loop_steps=steps)
         t[f"{program}_dispatches"] += 1
         t[f"{program}_weight_passes"] += steps
+
+    def _note_fetched(self, flight: _Flight) -> None:
+        """What only the fetch of a sampling dispatch brings, onto the
+        record of THAT dispatch (its span may have ended a dispatch
+        ago: `span.set` then reaches the ring's record alone): the
+        routed layers' `moe_*` counts, and of a model with an exit gate
+        `exit_mass_<t>`, the exit pdf after pass t, mean over the
+        dispatch's rows (`model.exit_last`), summed into
+        `loop_totals`."""
+        dispatch = flight.dispatch
+        self._note_moe(dispatch, flight.program)
         pdf = getattr(self.model, "exit_last", None)
-        if pdf is None or not (program == "decode" or self._pass_samples):
+        if pdf is None or not self._loop_steps or not flight.rows:
             return
-        live = [i for i, s in enumerate(self._slots) if s is not None]
-        if not live:
-            return
+        live = [i for i, *_ in flight.rows]
         mass = np.asarray(pdf, np.float64)[live].sum(axis=0)
         dispatch.set(**{f"exit_mass_{k}": float(v) / len(live)
                         for k, v in enumerate(mass)})
+        t = self.loop_totals
         if not t["exit_mass"]:
             t["exit_mass"] = [0.0] * len(mass)
         t["exit_mass"] = [a + float(b) for a, b in zip(t["exit_mass"], mass)]
@@ -1815,7 +1974,7 @@ class ContinuousScheduler:
             reg.counter("serving/paged_dense_bytes_avoided").inc(
                 max(0, dense_blocks - blocks) * self._kv_block_bytes)
 
-    def _prefill_chunk_step(self, plan) -> bool:
+    def _prefill_chunk_step(self, plan, why: Optional[str]) -> bool:
         """One [slots, C] chunked-prefill dispatch advancing the rows of
         `plan` (`plan_chunk_rows`) by their tokens.
 
@@ -1827,9 +1986,11 @@ class ContinuousScheduler:
         at its last real token, so every live row is a row of it at its
         own position and table: a feeding row with up to C tokens of its
         feed, the last included, a row past its prompt with its pending
-        token at column 0 and one token fed; the rows whose feed is
-        exhausted are then sampled from the pass (`_sample_rows`) and
-        the iteration ends there.
+        token at column 0 and one token fed (the device's own id where
+        the host has not seen it yet: `take_prev`); the rows whose feed
+        is exhausted are then sampled from the pass and the iteration
+        ends there.  `why` None leaves that pass in flight (`_fly`);
+        else it is fetched here, for that reason (`_synchronous`).
 
         Either way a row's trailing pad columns write garbage only at
         positions PAST its own frontier — overwritten by its later real
@@ -1843,6 +2004,7 @@ class ContinuousScheduler:
             tok = np.zeros((self.model.batch_slots, C), np.int32)
             slen = np.zeros(self.model.batch_slots, np.int32)
             fed = np.zeros(self.model.batch_slots, np.int32)
+            take = np.zeros(self.model.batch_slots, np.int32)
             btab = np.zeros_like(self._btab)
             real = ends = 0  # tokens really advanced; rows to sample
             for i, live, n in plan:
@@ -1852,27 +2014,29 @@ class ContinuousScheduler:
                 tok[i, :n] = (live.feed[live.pos:live.pos + n]
                               if live.pos < len(live.feed)
                               else live.next_token)
+                take[i] = live.unsettled > 0
                 slen[i] = live.pos
                 fed[i] = n
                 btab[i] = self._btab[i]
                 real += n
                 ends += live.pos + n >= len(live.feed)
-        lives = [live for _, live, _ in plan]
+        rows = [(i, live, live.pos, n) for i, live, n in plan]
+        logits = landed = None
         try:
             with span("sched.prefill.dispatch", rows=len(plan),
                       tokens=real, passes=passes,
                       capacity=self.model.batch_slots * C,
+                      ahead=int(bool(self._flights)),
                       **({"decode_rows": ends,
                           "slots": self.model.batch_slots}
                          if sampled else {}),
                       ) as dispatch:
                 reads = {}
+                flight = _Flight("prefill", dispatch, None, rows, reads)
 
                 def count():
                     # the dispatch's counters, taken while the program
-                    # runs: the scan is enqueued and returns at once,
-                    # the one-pass program calls this before it waits
-                    # for its logits (`meanwhile`)
+                    # runs: after its enqueue, before any wait for it
                     if self.rstate_totals is not None:
                         self._note_rstate(dispatch, len(plan), C)
                     if self._eva_rows is not None:
@@ -1887,48 +2051,42 @@ class ContinuousScheduler:
                     reads.update(self._kv_reads(first_read, counts,
                                                 steps=passes))
                     dispatch.set(**reads)
+                    if self._loop_steps:
+                        self._note_loop(dispatch, "prefill", passes)
 
                 # (`row_tokens`: the scan's riders advance by 0 tokens,
                 # the pass's rows by their real tokens)
-                if sampled:
-                    logits = self.model.prefill_step(
-                        tok, slen, btab, fed, meanwhile=count)
-                else:
-                    logits = self.model.prefill_step(
+                if not sampled:
+                    self.model.prefill_step(
                         tok, slen, btab, *((fed,) if self._rstate else ()))
                     count()
-                if self._loop_steps:
-                    # (the exit pdf comes back with the logits)
-                    self._note_loop(dispatch, "prefill", passes)
-                if sampled:  # (the routed layers' counts came with them)
-                    self._note_moe(dispatch, "prefill")
+                elif why is None:
+                    flight.launched = self.model.launch_prefill(
+                        tok, slen, btab, fed, take_prev=take)
+                    count()
+                    landed = self._fly(flight)
+                else:
+                    logits = self.model.prefill_step(
+                        tok, slen, btab, fed, meanwhile=count)
+                    # (the exit pdf and the routed layers' counts came
+                    # with the logits)
+                    self._note_fetched(flight)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 raise
             self._fail_inflight(e)
             return False
-        self._share_dispatch(dispatch, lives)
+        if sampled:
+            self._sampled(flight, why, logits, landed)
+            return True
+        self._advance_rows(rows)
+        self._share_dispatch(dispatch, [live for _, live, _ in plan])
         self._note_step_time(dispatch.t_end - dispatch.t_start)
         self.prefill_steps += 1
         self._note_kernel_reads(reads)
-        if sampled:
-            self.batches_run += 1  # (a dispatch whose logits are sampled)
-            t0 = self.tokens_generated
-            with self._sample_span():
-                self._sample_rows(logits, dispatch, fed)
-            self.pass_decode_tokens += self.tokens_generated - t0
-            return True
-        for i, live, n in plan:
+        for _, live, _ in plan:
             if live.tspan is not None:
                 live.tspan.ref_chunk(dispatch)
-            live.pos += n
-            # the freshly written prompt blocks join the prefix index
-            # NOW, so a same-prefix arrival in the next admit already
-            # shares them
-            self.pool.note_written(live.seq_id, live.pos)
-            live.next_token = live.feed[live.pos]
-            self._tokens[i] = live.next_token
-            self._slens[i] = live.pos
         if self._check_invariants:
             self.pool.check_invariants()
         return True
@@ -2156,11 +2314,39 @@ class ContinuousScheduler:
                 if not self._iteration():
                     return
 
+    def _synchronous(self) -> Optional[str]:
+        """Why this iteration's sampling dispatch has to be fetched
+        before anything else is enqueued, or None: it may stay in
+        flight while the next one is planned and enqueued behind it.
+        Decided from what the loop can see, nothing switches it: a
+        `family` whose programs leave no ids on the device (GPT's scan
+        and step, a test's fake), a `spec` round to come (its proposer
+        reads the tokens), a `service` waiting for the worker or a
+        drain going on (they see every row where the device left it),
+        a live row sampled at a `temperature` (its RNG stream draws on
+        the host, from the logits)."""
+        if not self._keeps_ids:
+            return "family"
+        if self._spec != "off" and not self._spec_broken:
+            return "spec"
+        if self._draining or not self._service.empty():
+            return "service"
+        if any(live is not None and live.req.temperature > 0.0
+               for live in self._slots):
+            return "temperature"
+        return None
+
     def _iteration(self) -> bool:
         """One turn of the decode loop; False once a drain is complete.
         Every stretch of it runs under a named host span (children of
         `sched.iteration`; docs/OBSERVABILITY.md lists them), so device
-        idle time can be laid at what the host was doing."""
+        idle time can be laid at what the host was doing.
+
+        With a dispatch in flight (`_flights`) the turn is: services,
+        admission, the plan over the ADVANCED slots, this turn's
+        dispatch enqueued behind the one in flight, and only then the
+        wait for that one and the settling of its rows: the host's turn
+        runs beside a pass, not between two."""
         page = self.pool.page_size
         with span("sched.services"):
             self._run_services()
@@ -2170,7 +2356,20 @@ class ContinuousScheduler:
             sp.set(admitted=self.admitted - n0,
                    queue_depth=len(self._waiting),
                    wait_ms=round(1e3 * (self.queue_wait_s_sum - w0), 3))
-        if all(s is None for s in self._slots):
+        why = self._synchronous()
+        slots = self._slots
+        if self._flights:
+            if why is None:
+                slots = advanced_slots(slots)
+            if why is not None or all(s is None for s in slots):
+                # nothing may be enqueued behind the flight, or nothing
+                # is left to enqueue: every live row takes its last
+                # token from it (or an EOS ended it a dispatch ago)
+                if self._settle_flights():
+                    with span("sched.observe"):
+                        self._observe_step()
+                return True
+        if all(s is None for s in slots):
             if (self._draining and not self._waiting
                     and self._queue.empty()):
                 # drain complete: nothing live, nothing queued —
@@ -2193,10 +2392,9 @@ class ContinuousScheduler:
             # is a row of that dispatch, the rows past their prompt
             # take their token from its logits, and the iteration is
             # that ONE dispatch
-            plan = plan_chunk_rows(self._slots, self._chunk,
-                                   self._pass_samples)
+            plan = plan_chunk_rows(slots, self._chunk, self._pass_samples)
             if plan:
-                ran = self._prefill_chunk_step(plan)
+                ran = self._prefill_chunk_step(plan, why)
                 if ran and self._pass_samples:
                     with span("sched.observe"):
                         self._observe_step()
@@ -2205,7 +2403,7 @@ class ContinuousScheduler:
         props = None
         with span("sched.decode.prepare"):
             decoding = feeding = 0
-            for i, live in enumerate(self._slots):
+            for i, live in enumerate(slots):
                 if live is None:
                     continue
                 if live.pos + 1 < len(live.feed):
@@ -2229,31 +2427,46 @@ class ContinuousScheduler:
             # speculative round: every live row rides ONE verify
             # dispatch (drafted rows multi-token, everyone else
             # count-1)
+            self.lookahead_drains[why] += 1
             if self._spec_round(props):
                 with span("sched.observe"):
                     self._observe_step()
             return True
+        rows = [(i, live, live.pos, 1) for i, live in enumerate(slots)
+                if live is not None]
+        logits = landed = None
         try:
             with span("sched.decode.dispatch", rows=decoding,
-                      feeding=feeding,
-                      slots=self.model.batch_slots) as dispatch:
+                      feeding=feeding, slots=self.model.batch_slots,
+                      ahead=int(bool(self._flights))) as dispatch:
                 reads = self._kv_reads(
-                    self._slens,
-                    [live is not None for live in self._slots])
+                    self._slens, [live is not None for live in slots])
+                flight = _Flight("decode", dispatch, None, rows, reads)
                 dispatch.set(**reads)
                 alive = ()  # recurrent state: which rows advance
                 if self._rstate:
                     alive = (np.array([live is not None
-                                       for live in self._slots], np.int32),)
+                                       for live in slots], np.int32),)
                 if self.rstate_totals is not None:
                     self._note_rstate(dispatch, int(alive[0].sum()), 1)
                 if self._eva_rows is not None:
                     self._note_eva(dispatch, "decode", self._slens, alive[0])
-                logits = self.model.step(
-                    self._tokens, self._slens, self._btab, *alive)
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
-                self._note_moe(dispatch, "decode")
+                if why is None:
+                    # (copies: the step buffers move on, at `_advance_rows`
+                    # below, while the program may still read these)
+                    flight.launched = self.model.launch_step(
+                        self._tokens.copy(), self._slens.copy(),
+                        self._btab.copy(), *alive,
+                        take_prev=np.array(
+                            [live is not None and live.unsettled > 0
+                             for live in slots], np.int32))
+                    landed = self._fly(flight)
+                else:
+                    logits = self.model.step(
+                        self._tokens, self._slens, self._btab, *alive)
+                    self._note_fetched(flight)
         except Exception as e:
             if getattr(e, "fatal_to_engine", False):
                 # device-loss-style fault (hung dispatch, lost
@@ -2264,42 +2477,150 @@ class ContinuousScheduler:
                 raise
             self._fail_inflight(e)
             return True
-        self._share_dispatch(dispatch, self._slots)
-        self._note_step_time(dispatch.t_end - dispatch.t_start)
-        self.batches_run += 1
-        self._note_kernel_reads(reads)
-        with self._sample_span():
-            self._sample_rows(logits, dispatch)
+        self._sampled(flight, why, logits, landed)
         with span("sched.observe"):
             self._observe_step()
         return True
 
-    def _sample_rows(self, logits, dispatch, fed=None) -> None:
-        """After a dispatch that returned a row of logits a slot:
-        advance every live row (by one token after a decode dispatch,
-        by `fed[slot]` after a one-pass prefill), sample the rows past
-        their prompt, retire the finished."""
-        now = time.monotonic()
-        for i, live in enumerate(self._slots):
-            if live is None:
-                continue
-            if live.tspan is not None:
-                # a chunk of its prompt, or a step past it
-                if fed is not None and live.pos + 1 < len(live.feed):
-                    live.tspan.ref_chunk(dispatch)
-                else:
-                    live.tspan.ref_step(dispatch)
-            live.pos += 1 if fed is None else int(fed[i])
+    # -- a dispatch's bookkeeping, cut in two at what it needs to know ----
+    def _advance_rows(self, rows) -> None:
+        """ADVANCE: what a dispatch does to its `rows` [(slot, live,
+        position before, tokens fed)] that its plan alone tells, done
+        once it is enqueued: positions, the pool's written watermark
+        (freshly written prompt blocks join the prefix index NOW, so a
+        same-prefix arrival in the next admit already shares them: the
+        program that reads them queues behind the one that writes
+        them), a feeding row's next token, and for a row whose feed
+        ends inside it the token it is owed (`unsettled`); one that
+        reaches `max_new` with it is no row of the next dispatch, so
+        its step buffers go back to scratch."""
+        for i, live, start, n in rows:
+            live.pos = start + n
             # keep the pool's written-token watermark current so
             # fragmentation never over-reports a mid-page tail
             self.pool.note_written(live.seq_id, live.pos)
+            self._slens[i] = live.pos
             if live.pos < len(live.feed):
                 # prefill: the next token is given, logits ignored
                 live.next_token = live.feed[live.pos]
                 self._tokens[i] = live.next_token
-                self._slens[i] = live.pos
+                continue
+            live.unsettled += 1
+            if len(live.generated) + live.unsettled >= live.max_new:
+                self._free_slot_buffers(i)
+
+    def _sampled(self, flight: _Flight, why: Optional[str], logits,
+                 landed: Optional[tuple]) -> None:
+        """Behind a sampling dispatch's span.  Left in flight (`why`
+        None): the flight before it, which `_fly` waited for inside
+        that span (`landed`), is settled.  Fetched at once, for the
+        reason `why`: its own rows are advanced and settled, back to
+        back."""
+        dispatch = flight.dispatch
+        if why is None:
+            self.dispatches_ahead += dispatch.args["ahead"]
+            if landed is not None:
+                self._settle(*landed)
+            return
+        self.lookahead_drains[why] += 1
+        self._advance_rows(flight.rows)
+        self._settle(flight, logits, dispatch.t_end - dispatch.t_start)
+
+    def _fly(self, flight: _Flight) -> Optional[tuple]:
+        """Leave an enqueued dispatch in flight: advance its rows, and
+        now that it is queued behind the one before it, wait for THAT
+        one (`_land_first`; None where nothing was in flight).  Called
+        inside the new dispatch's span, which so covers a pass's time
+        on the device as a synchronous dispatch's does: what the loop
+        spends outside its dispatch spans stays its own host work."""
+        self._flights.append(flight)
+        self._advance_rows(flight.rows)
+        return self._land_first() if len(self._flights) > 1 else None
+
+    def _land_first(self) -> tuple:
+        """The wait for the oldest flight and its counts onto its
+        record: (flight, logits, seconds it took), what `_settle`
+        takes.  A fault leaves the flights as they are for
+        `_fail_inflight` to drop."""
+        flight = self._flights[0]
+        logits = self.model.land(flight.launched,
+                                 behind=len(self._flights) > 1)
+        self._flights.popleft()
+        flight.launched = None
+        # the dispatch's own time, for the step-time EWMA: from its
+        # enqueue, or the arrival of the flight before it if that came
+        # later (it then queued behind it), to its logits' arrival
+        now = time.monotonic()
+        took = now - max(flight.dispatch.t_start, self._landed_at)
+        self._landed_at = now
+        self._note_fetched(flight)
+        return flight, logits, took
+
+    def _settle_flights(self) -> bool:
+        """Settle everything in flight, oldest first (before a
+        synchronous iteration, a service, the end of a stretch).  False
+        after a transient fault (handled: the flights dropped, the
+        in-flight requests failed); fatal faults propagate."""
+        while self._flights:
+            try:
+                landed = self._land_first()
+            except Exception as e:
+                if getattr(e, "fatal_to_engine", False):
+                    raise
+                self._fail_inflight(e)
+                return False
+            self._settle(*landed)
+        return True
+
+    def _drop_flights(self) -> None:
+        """Forget what is in flight (a fault, a close): its rows go
+        back to where the host last saw them, newest flight first, so
+        the resume records made from them are true."""
+        while self._flights:
+            for _, live, start, _ in self._flights.pop().rows:
+                live.pos, live.unsettled = start, 0
+
+    def _settle(self, flight: _Flight, logits, took_s: float) -> None:
+        """A fetched sampling dispatch's counters, then SETTLE its
+        rows."""
+        self._share_dispatch(flight.dispatch,
+                             [live for _, live, _, _ in flight.rows])
+        self._note_step_time(took_s)
+        self.batches_run += 1  # (a dispatch whose logits are sampled)
+        self._note_kernel_reads(flight.reads)
+        t0 = self.tokens_generated
+        with self._sample_span():
+            self._settle_rows(flight, logits)
+        if flight.program == "prefill":
+            self.prefill_steps += 1
+            self.pass_decode_tokens += self.tokens_generated - t0
+
+    def _settle_rows(self, flight: _Flight, logits) -> None:
+        """SETTLE: what needs the fetched logits of a dispatch whose
+        rows were advanced: the sampled token's value into `generated`,
+        the first token's time, EOS, `_finish` (the pool's retire keys
+        the blocks by the tokens), the request's trace spans.  A row
+        that is no longer its slot's (an EOS ended it at the dispatch
+        before, found out after this one was enqueued) drops its
+        token: `overrun_tokens`; what that dispatch wrote for it lay
+        inside the reservation it was admitted with."""
+        now = time.monotonic()
+        dispatch = flight.dispatch
+        for i, live, start, n in flight.rows:
+            sampled = start + n >= len(live.feed)
+            if self._slots[i] is not live:
+                self.overrun_tokens += sampled
+                continue
+            if live.tspan is not None:
+                # a chunk of its prompt, or a step past it
+                if flight.program == "prefill" and start + 1 < len(live.feed):
+                    live.tspan.ref_chunk(dispatch)
+                else:
+                    live.tspan.ref_step(dispatch)
+            if not sampled:
                 continue
             tok = int(self._sample(logits[i], live))
+            live.unsettled -= 1
             if not live.generated:
                 live.req.t_first_token = now
                 with self._lat_lock:
@@ -2322,7 +2643,6 @@ class ContinuousScheduler:
             else:
                 live.next_token = tok
                 self._tokens[i] = tok
-                self._slens[i] = live.pos
 
     def _sample(self, row_logits: np.ndarray, live: _Live) -> int:
         if live.req.temperature <= 0.0:  # greedy hot path: one argmax

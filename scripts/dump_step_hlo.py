@@ -78,14 +78,19 @@ def serve_programs(fam, cfg, on_chip):
     ints = np.zeros((b,), np.int32)
     table = np.zeros((b, model.max_blocks_per_seq), np.int32)
     rows = (ints,) if model.has_slot_state else ()
+    # (a family on the one-pass program keeps its ids on the device: the
+    # last dispatch's `prev_ids` and `take_prev` behind `row_tokens`,
+    # None where the decode step takes none)
+    ids = (ints, ints) if model.keeps_ids else ()
     w, st = on_chip((model.ffd._weights, model._state))
     yield "step", model._step_fn.trace(
-        w, st, *on_chip((ints, ints, table) + rows))
+        w, st, *on_chip((ints, ints, table) + (rows or (None,) * bool(ids))
+                        + ids))
     # (the one-pass program takes `row_tokens` whatever the family)
     fed = (ints,) if model.prefill_passes == 1 else rows
     yield "prefill", model._prefill_fn.trace(
         w, st, *on_chip((np.zeros((b, model.prefill_chunk), np.int32), ints,
-                         table) + fed))
+                         table) + fed + ids))
 
 
 def load_cell(workload: str):

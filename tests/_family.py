@@ -218,36 +218,50 @@ def trained_gpt(devices, batch, seq, vocab, steps=40):
     return ff, ids
 
 
+#: the five families on the one-pass prefill program and their toy
+#: configurations (`tests/test_pass_decode.py`, `tests/test_lookahead.py`)
+PASS_FAMILIES = {"kimi_k2": "toy-kimi.json",
+                 "qwen3_next": "toy-qwen3-next.json", "ouro": "toy-ouro.json",
+                 "longcat_flash": "toy-longcat-flash.json",
+                 "evabyte": "toy-evabyte.json"}
+
+
+def reader_ctx(make):
+    """(a benchmark reader's context whose traced stretch holds the
+    spans `make()` makes, the lines the reader said)."""
+    import time
+    import types
+
+    t0 = time.monotonic()
+    make()
+    said = []
+    return types.SimpleNamespace(
+        _trace_t0=t0, trace_window_s=time.monotonic() - t0,
+        out=said.append), said
+
+
 class Recorder:
-    """Wraps a scheduler's model so that every dispatch's logits (a
+    """Wraps a scheduler so that every sampling dispatch's logits (a
     decode step's, and a one-pass prefill's at each row's last real
-    token) and what `also(model, row)`
-    adds are kept beside (request, position) of the row they belong
-    to (`pass_also` where a pass's rows have something else to add)."""
+    token) and what `also(model, row)` adds are kept beside (request,
+    position) of the row they belong to (`pass_also` where a pass's
+    rows have something else to add).  Taken where the scheduler
+    settles a dispatch's rows (`_settle_rows`: right behind its fetch,
+    whether the dispatch was fetched at once or left in flight)."""
 
     def __init__(self, sched, also=lambda model, i: (), pass_also=None):
         self.sched, self.rows, model = sched, [], sched.model
-        step, prefill = model.step, model.prefill_step
+        settle = sched._settle_rows
 
-        def keep(logits, last, also=also):
-            for i, live in enumerate(sched._slots):
-                if live is not None and last[i] >= 0:
-                    self.rows.append((live.req, int(last[i]),
-                                      logits[i].copy(), *also(model, i)))
+        def recorded(flight, logits):
+            add = (pass_also or also) if flight.program == "prefill" else also
+            for i, live, start, n in flight.rows:
+                if sched._slots[i] is live:
+                    self.rows.append((live.req, start + n - 1,
+                                      logits[i].copy(), *add(model, i)))
+            settle(flight, logits)
 
-        def recorded_step(tokens, positions, *rest):
-            logits = step(tokens, positions, *rest)
-            keep(logits, np.asarray(positions))
-            return logits
-
-        def recorded_prefill(tokens, positions, table, *fed, **beside):
-            logits = prefill(tokens, positions, table, *fed, **beside)
-            if logits is not None:  # idle slots: fed 0, last -1
-                keep(logits, np.asarray(positions) + fed[0] - 1,
-                     pass_also or also)
-            return logits
-
-        model.step, model.prefill_step = recorded_step, recorded_prefill
+        sched._settle_rows = recorded
 
 
 def padded(tokens, to=32):

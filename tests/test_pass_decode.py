@@ -27,14 +27,13 @@ Tolerance: the pass and the step compute the same float32 arithmetic
 over other shapes, 2e-5 of the logits' largest magnitude (the family
 files' `LOGIT_TOL`).
 """
-import time
 import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _family import Recorder, close, config
+from _family import PASS_FAMILIES, Recorder, close, config, reader_ctx
 
 from benchmarks.run import load_module
 from flexflow_tpu.obs.trace import next_span_id, span, spans
@@ -42,9 +41,7 @@ from flexflow_tpu.serving.scheduler import (ContinuousScheduler,
                                             PagedKVDecodeModel,
                                             plan_chunk_rows)
 
-FAMILIES = {"kimi_k2": "toy-kimi.json", "qwen3_next": "toy-qwen3-next.json",
-            "ouro": "toy-ouro.json", "longcat_flash": "toy-longcat-flash.json",
-            "evabyte": "toy-evabyte.json"}
+FAMILIES = PASS_FAMILIES
 SEED, CHUNK, LOGIT_TOL = 11, 4, 2e-5
 
 
@@ -185,20 +182,25 @@ def serve(ff, cfg, pairwise):
         devices=jax.devices()[:1])
     assert sched._pass_samples
     fed_log, beside_log = [], []
-    inner = sched.model.prefill_step
     rec = Recorder(sched)
-    recorded = sched.model.prefill_step
 
-    def prefill(tok, slen, btab, *fed, **beside):
-        # (the pair plan hands a twin without per-slot state no
-        # `row_tokens`, and nobody reads that pass's logits)
-        fed = fed or (np.ones(len(slen), np.int32),)
-        fed_log.append(fed[0].copy())
-        beside_log.append(set(beside))
-        return (inner if pairwise else recorded)(tok, slen, btab, *fed,
-                                                 **beside)
+    def logged(inner):
+        def prefill(tok, slen, btab, *fed, **beside):
+            # (the pair plan hands a twin without per-slot state no
+            # `row_tokens`, and nobody reads that pass's logits)
+            fed = fed or (np.ones(len(slen), np.int32),)
+            fed_log.append(fed[0].copy())
+            beside_log.append(set(beside))
+            return inner(tok, slen, btab, *fed, **beside)
+        return prefill
 
-    sched.model.prefill_step = prefill
+    # the fused plan leaves its passes in flight (`launch_prefill`); the
+    # pair plan is the parent's loop too: every dispatch fetched at once
+    if pairwise:
+        sched.model.prefill_step = logged(sched.model.prefill_step)
+        sched._synchronous = lambda: "family"
+    else:
+        sched.model.launch_prefill = logged(sched.model.launch_prefill)
     sched._pass_samples = not pairwise
     first = next_span_id()
     try:
@@ -266,10 +268,10 @@ def test_span_args_and_stats_count_what_was_sampled(plans):
     assert fused["stats"]["pass_decode_tokens"] == in_pass
     assert fused["stats"]["tokens_generated"] == total
     assert len(fused["fed"]) == len(fused["passes"])
-    # a pass's counters are taken while its program runs (`meanwhile`:
-    # the model calls it between the enqueue and the wait); the scan's
-    # call returns at once and takes no such argument
-    assert all(b == {"meanwhile"} for b in fused["beside"])
+    # a pass left in flight says which rows take their token from the
+    # device's ids (`take_prev`); the pair plan's call is the scan's:
+    # it returns at once and takes no such argument
+    assert all(b == {"take_prev"} for b in fused["beside"])
     assert not any(pair["beside"])
     assert all("kv_blocks_live" in r.args
                for r in fused["passes"] + pair["passes"])
@@ -382,16 +384,6 @@ def test_a_family_on_the_pass_names_its_head():
 
 
 # -- 5. the reader ----------------------------------------------------------------------
-def reader_ctx(make):
-    """A context whose traced stretch holds the spans `make()` makes."""
-    t0 = time.monotonic()
-    make()
-    said = []
-    return types.SimpleNamespace(
-        _trace_t0=t0, trace_window_s=time.monotonic() - t0,
-        out=said.append), said
-
-
 def dispatches(passes, steps):
     def make():
         for args in passes:
@@ -539,7 +531,8 @@ def test_decode_step_never_sees_the_mask(counting, monkeypatch):
             *model._row_tokens(np.ones(SLOTS, np.int32)))
     text = model._step_fn.lower(*args).as_text()
     assert len(jax.tree.leaves(args)) == len(jax.tree.leaves(
-        (model.ffd._weights, model._state))) + 3 + model.has_slot_state
+        (model.ffd._weights, model._state))) + 3 + model.has_slot_state \
+        + 2 * model.keeps_ids  # (the last dispatch's ids, `take_prev`)
     inner, seen = RoutedExperts.forward, []
 
     def forward(self, inputs, weights, *, training=False, rng=None, **kw):
@@ -592,14 +585,20 @@ def test_sampling_pass_span_carries_the_counts_and_stats_sum_by_program(
             r.args["moe_real_max"] for r in fused["steps"])
 
 
+WAITS = ("model.fetch", "model.fetch_behind")
+
+
 def test_enqueue_and_fetch_say_what_they_moved(plans):
     """`program`, `arg_leaves`, `host_bytes` on `model.enqueue` and
-    `program`, `bytes` on `model.fetch`, the same on every call of one
-    program; a fetch follows every enqueue of a sampled program."""
+    `program`, `bytes` on the wait for it, the same on every call of
+    one program.  The k-th wait is for the k-th enqueue of a sampled
+    program: `model.fetch` right behind it on the synchronous loop (the
+    pair plan's), `model.fetch_behind` with exactly one more enqueue in
+    between where the dispatch was left in flight (ISSUE 54)."""
     name, by = plans
-    for run in by.values():
+    for plan, run in by.items():
         calls = [r for r in run["spans"]
-                 if r.name in ("model.enqueue", "model.fetch")]
+                 if r.name in ("model.enqueue", *WAITS)]
         moved = {}
         for r in calls:
             static = {k: v for k, v in r.args.items() if k != "first"}
@@ -609,7 +608,7 @@ def test_enqueue_and_fetch_say_what_they_moved(plans):
         assert {"step", "prefill"} <= programs <= {
             "step", "prefill", "reset_slot_state"}
         for (span_name, program), static in moved.items():
-            if span_name == "model.fetch":
+            if span_name in WAITS:
                 assert set(static) == {"program", "bytes"}
                 assert static["bytes"] >= 3 * 4  # a logit a slot at least
             else:
@@ -621,9 +620,20 @@ def test_enqueue_and_fetch_say_what_they_moved(plans):
             moved["model.enqueue", "step"]["host_bytes"] >= 3 * 4 * 3
         assert moved["model.enqueue", "prefill"]["arg_leaves"] >= \
             moved["model.enqueue", "step"]["arg_leaves"]
-        sampled = [r.args["program"] for r in calls
-                   if r.args["program"] in ("step", "prefill")]
-        assert sampled[0::2] == sampled[1::2]  # enqueue, then its fetch
-        assert [r.name for r in calls
-                if r.args["program"] in ("step", "prefill")][:2] == [
-            "model.enqueue", "model.fetch"]
+        sampled = [r for r in calls if r.args["program"] in ("step", "prefill")]
+        enqueues = [r for r in sampled if r.name == "model.enqueue"]
+        waits = [r for r in sampled if r.name in WAITS]
+        assert [r.args["program"] for r in enqueues] == \
+            [r.args["program"] for r in waits]
+        for k, (enq, wait) in enumerate(zip(enqueues, waits)):
+            assert enq.t_end <= wait.t_start
+            between = sum(enq.t_end <= e.t_start <= wait.t_start
+                          for e in enqueues[k + 1:k + 3])
+            assert between == (wait.name == "model.fetch_behind")
+        behind = sum(r.name == "model.fetch_behind" for r in waits)
+        if plan == "pair":
+            assert behind == 0
+        else:
+            # the stretch ends once: every other wait is behind a launch
+            assert behind == run["stats"]["dispatches_ahead"] > len(waits) / 2
+            assert run["stats"]["overrun_tokens"] == 0
