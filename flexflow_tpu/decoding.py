@@ -78,13 +78,12 @@ def decoder_recipe(ff: FFModel) -> DecoderRecipe:
 
         raise ConfigError(ff.not_served)  # the builder said why, by name
     if recipe is None:
+        from .models import SERVED_BUILDERS
+
         raise ValueError(
             "a decode twin needs a model built by a models/ builder that "
-            "records its recipe (models.transformer.build_gpt, "
-            "models.kimi_k2.build_kimi_k2, "
-            "models.longcat_flash.build_longcat_flash, "
-            "models.qwen3_next.build_qwen3_next, "
-            "models.evabyte.build_evabyte)")
+            "records its recipe ("
+            + ", ".join(f"models.{b}" for b in SERVED_BUILDERS) + ")")
     return recipe
 
 

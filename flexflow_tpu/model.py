@@ -461,11 +461,14 @@ class FFModel:
         kv_page_size: int = 0,
         kv_num_blocks: int = 0,
         kv_kernel: str = "gather",
+        window_ring: int = 0,
         **grouped_rotary_gated,
     ) -> ParallelTensor:
         """`grouped_rotary_gated`: the further fields of
         `MultiHeadAttentionParams` by name (`num_kv_heads`, `qk_norm`,
-        `rotary_dim`, `output_gate`, ...), all off by default."""
+        `rotary_dim`, `output_gate`, `sliding_window`, ...), all off by
+        default.  `window_ring`: rows a slot of a window layer's decode
+        twin (ops/attention.py)."""
         p = MultiHeadAttentionParams(
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, **grouped_rotary_gated,
@@ -477,6 +480,7 @@ class FFModel:
                                kv_page_size=kv_page_size,
                                kv_num_blocks=kv_num_blocks,
                                kv_kernel=kv_kernel,
+                               window_ring=window_ring,
                                # inside `repeat`: a plane a pass
                                kv_planes=(self._open_region.times
                                           if self._open_region else 1))

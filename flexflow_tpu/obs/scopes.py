@@ -45,14 +45,16 @@ CAST_WEIGHTS = "cast_weights"
 #: parts of a routed-expert layer (`shared` is its shared expert, `zero`
 #: the identity experts' term: each row times its identity picks' weights)
 ROUTED_PARTS = ("route", "dispatch", "products", "shared", "combine", "zero")
-#: parts of an attention op (`core` XOR `paged_read`), of a delta-net
+#: parts of an attention op (`core` XOR `paged_read` XOR `window_read`,
+#: a window layer's read of its per-slot ring), of a delta-net
 #: layer (`KimiDeltaAttention`: `gate` its decays and step sizes,
 #: `core` the delta rule, `norm_gate` the gated head norm) and of a
 #: short convolution; of `EvaAttention`: `summarise` the pooling of the
 #: chunks a step completes, `state_write` the step's keys and values
 #: into the window
 MIXER_PARTS = ("proj", "core", "paged_read", "conv", "recurrence", "out",
-               "gate", "norm_gate", "summarise", "state_write")
+               "gate", "norm_gate", "summarise", "state_write",
+               "window_read")
 PARTS = ROUTED_PARTS + MIXER_PARTS + (CAST_WEIGHTS,)
 #: `parse`'s part for an instruction the compiler made from one of the
 #: step program's ARGUMENTS (the layout copy of a weight or of a paged

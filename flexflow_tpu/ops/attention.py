@@ -73,6 +73,29 @@ class MultiHeadAttentionParams:
     # that carries `prefill_pass` builds), instead of once a position
     # at the decode step's shapes (GPT's byte equality)
     paged_read_once: bool = False
+    # the paged pools are `[blocks, kv_heads, page, d]`, a head's rows of
+    # a page side by side, not `[blocks, page, kv_heads, d]`: what the
+    # in-place read of GROUPED heads folds a head at a time
+    # (ops/pallas/paged_attention.py `_fold_head_major`; needs
+    # `paged_read_once`)
+    kv_head_major: bool = False
+    # a causal query at position p sees keys `p - sliding_window < j <=
+    # p` (its own included); 0 = every key before it.  A decode twin of
+    # such a layer keeps a bounded ring a slot instead of pages
+    # (`MultiHeadAttention` docstring)
+    sliding_window: int = 0
+    # ONE gate a head from the layer's input, weight `wg [embed, heads]`:
+    # out = wo (attn * sigmoid(x wg)[..., None])  (`output_gate` is the
+    # gate a channel that `wq` projects)
+    head_gate: bool = False
+    # YaRN on the rotary channels (ops/rope.py yarn_frequencies);
+    # factor 1 = plain RoPE.  cos and sin are multiplied by
+    # `rope_attention_factor` (the published config's attention_factor)
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
 
     @property
     def k_channels(self) -> int:
@@ -92,17 +115,23 @@ class MultiHeadAttentionParams:
         return self.num_heads // self.kv_heads
 
 
-def rotate_half(x, positions, rotary_dim: int, theta: float):
+def rotate_half(x, positions, rotary_dim: int, theta: float, *,
+                freq=None, factor: float = 1.0):
     """Rotary embedding on the first `rotary_dim` channels of
     x [b, s, heads, d] at positions [b, s]: channel i of the first half
     against channel i of the second half, angle `pos * theta^(-2i /
-    rotary_dim)`, computed in float32; the other channels pass."""
+    rotary_dim)`, computed in float32; the other channels pass.
+    `freq` [rotary_dim / 2] replaces those frequencies (YaRN's), and
+    cos and sin are multiplied by `factor`."""
     half = rotary_dim // 2
-    freq = theta ** (-np.arange(0, rotary_dim, 2, dtype=np.float64)
-                     / rotary_dim)
+    if freq is None:
+        freq = theta ** (-np.arange(0, rotary_dim, 2, dtype=np.float64)
+                         / rotary_dim)
     angle = (positions.astype(jnp.float32)[..., None, None]
              * jnp.asarray(freq, jnp.float32))  # [b, s, 1, half]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:rotary_dim]
     return jnp.concatenate(
@@ -110,17 +139,45 @@ def rotate_half(x, positions, rotary_dim: int, theta: float):
         axis=-1).astype(x.dtype)
 
 
+def window_rows_live(window: int, positions, counts) -> int:
+    """Ring rows that SOME query of a dispatch sees, ONE window layer's
+    worth, from host-owned lengths alone: row i advances over positions
+    `positions[i] .. + counts[i] - 1` (numpy, [slots]); its queries see
+    the positions in `(positions[i] - window, positions[i] + counts[i])`
+    that exist: `min(p + n, window + n - 1)` rows, none where n is 0.
+    Never more than the ring holds, so a share of the rows read."""
+    pos = np.asarray(positions, np.int64)
+    n = np.asarray(counts, np.int64)
+    return int((np.minimum(pos + n, window + n - 1) * (n > 0)).sum())
+
+
 class MultiHeadAttention(Op):
+    """A decode twin of a layer with `sliding_window` W keeps no pages:
+    its keys and values live in per-slot RINGS `win_k`, `win_v`
+    `[slots, kv_heads, R, d]` (`slot_state_entries`; R = `window_ring`
+    >= W + the longest step - 1), position p at ring row `p % R`; a
+    head's rows lie side by side, as the score product reads them (laid
+    out `[slots, R, kv_heads, d]`, the TPU compiler transposes every
+    ring on every pass).  A step writes its real tokens' rows first
+    (`row_tokens` of them a row; a pad's write is dropped) and then
+    query p attends the ring rows whose position, recovered from the
+    row's length after the step and the ring index, lies in `(p - W,
+    p]`.  Nothing is zeroed at admission: a row the last tenant left
+    holds a position the new one's mask excludes."""
+
     op_type = OperatorType.MULTIHEAD_ATTENTION
+    #: the ring is masked by the sequence's own positions
+    slot_state_resets = False
 
     def __init__(self, params, inputs, name="", shard=None,
                  decode_max_seq: int = 0, kv_page_size: int = 0,
                  kv_num_blocks: int = 0, kv_kernel: str = "gather",
-                 kv_planes: int = 1):
+                 kv_planes: int = 1, window_ring: int = 0):
         from .op import ShardConfig
 
         # must exist before Op.__init__ runs make_weight_specs
         self._decode_max_seq = int(decode_max_seq)
+        self._window_ring = int(window_ring)
         self._kv_page_size = int(kv_page_size)
         self._kv_num_blocks = int(kv_num_blocks)
         # planes of the paged pool: one a pass of the region that runs
@@ -163,14 +220,37 @@ class MultiHeadAttention(Op):
             if p.add_bias_kv or p.add_zero_attn:
                 raise ShapeError(f"{self.name}: kv-append options "
                                  "unsupported with grouped-query heads")
-            if self._decode_n() and not (self._paged() and p.paged_read_once
-                                         and self._kv_kernel == "gather"):
+            if self._decode_n() and not self._ring() and not (
+                    self._paged() and p.paged_read_once):
                 raise ShapeError(
                     f"{self.name}: grouped-query heads are cached in the "
-                    "paged pool and read by one gather a step "
-                    "(kv_page_size > 0, paged_read_once, kv_kernel "
-                    "'gather'); the dense cache, the per-position read "
-                    "and the Pallas read keep one head count")
+                    "paged pool and read once a step (kv_page_size > 0, "
+                    "paged_read_once), by the gather or the Pallas read; "
+                    "the dense cache and the per-position read keep one "
+                    "head count")
+        if p.kv_head_major and self._paged() and not p.paged_read_once:
+            raise ShapeError(
+                f"{self.name}: a head-major pool (kv_head_major) is read "
+                "once a step (paged_read_once)")
+        if p.sliding_window:
+            if p.sliding_window < 0 or not p.causal or p.add_bias_kv \
+                    or p.add_zero_attn:
+                raise ShapeError(
+                    f"{self.name}: sliding_window {p.sliding_window} needs "
+                    "a causal layer without appended keys")
+            if qd[1].degree != 1:
+                raise ShapeError(
+                    f"{self.name}: a sliding window is not built into "
+                    "ring attention (sequence sharding)")
+            if self._decode_n() and (
+                    self._window_ring < p.sliding_window + qd[1].size - 1
+                    or self.cache_planes > 1 or qd[0].degree != 1):
+                raise ShapeError(
+                    f"{self.name}: a window layer's decode twin keeps a "
+                    f"ring of window_ring ({self._window_ring}) >= "
+                    f"sliding_window {p.sliding_window} + step "
+                    f"{qd[1].size} - 1 rows a slot, one plane, slots "
+                    "unsharded")
         if qd[1].degree != 1 or kd[1].degree != 1 or vd[1].degree != 1:
             # Seq partitioning lowers to ring attention — legal only
             # when q/k/v share one seq sharding (self-attention SP).
@@ -226,17 +306,28 @@ class MultiHeadAttention(Op):
     # on extend / frees on retire and rewrites them between steps);
     # in-graph they are read-only and returned unchanged.
     def _paged(self) -> bool:
-        return self._decode_n() > 0 and \
+        return self._decode_n() > 0 and not self._ring() and \
             int(getattr(self, "_kv_page_size", 0) or 0) > 0
 
+    def _ring(self) -> bool:
+        """A window layer's decode twin: rings a slot, no pages."""
+        return self._decode_n() > 0 and self.params.sliding_window > 0
+
     def cache_entries(self):
+        if self._ring():
+            return ()
         return ("k_cache", "v_cache") if self._decode_n() else ()
+
+    def slot_state_entries(self):
+        return ("win_k", "win_v") if self._ring() else ()
 
     def ctor_kwargs(self) -> dict:
         n = self._decode_n()
         if not n:
             return {}
         kw = {"decode_max_seq": n}
+        if self._ring():
+            return dict(kw, window_ring=self._window_ring)
         if self.cache_planes > 1:
             kw["kv_planes"] = self.cache_planes
         if self._paged():
@@ -262,6 +353,8 @@ class MultiHeadAttention(Op):
             n += 2
         if p.qk_norm:
             n += 2
+        if p.head_gate:
+            n += 1
         return n
 
     def make_weight_specs(self, input_shapes):
@@ -313,6 +406,8 @@ class MultiHeadAttention(Op):
                 WeightSpec("q_norm", w((p.k_channels,), None), ident),
                 WeightSpec("k_norm", w((p.k_channels,), None), ident),
             ]
+        if p.head_gate:
+            specs.append(WeightSpec("wg", w((embed, p.num_heads), 1), init))
         n = self._decode_n()
         if n > 0:
             if p.add_bias_kv or p.add_zero_attn:
@@ -325,6 +420,8 @@ class MultiHeadAttention(Op):
                     f"{self.name}: decode mode needs an unsharded seq dim"
                 )
 
+            if self._ring():
+                return specs + self._ring_state_specs(qd, dt)
             if self._paged():
                 return specs + self._paged_state_specs(qd, dt)
             if self.cache_planes > 1:
@@ -354,6 +451,31 @@ class MultiHeadAttention(Op):
                 WeightSpec("cache_pos", pos_shape, zero),
             ]
         return specs
+
+    def _ring_state_specs(self, qd, dt):
+        """State specs of a window layer's decode twin: the two rings
+        and the host-owned lengths (`seq_lens`, `row_tokens`)."""
+        from ..initializer import ZeroInitializer
+
+        p: MultiHeadAttentionParams = self.params
+        zero = ZeroInitializer()
+        one = ParallelDim(1, 1, is_replica_dim=True)
+
+        def ring(d_head):
+            return ParallelTensorShape(
+                (ParallelDim(qd[0].size),
+                 ParallelDim(p.kv_heads, self.shard.channel),
+                 ParallelDim(self._window_ring), ParallelDim(d_head), one),
+                dt)
+
+        ints = ParallelTensorShape((ParallelDim(qd[0].size), one),
+                                   DataType.INT32)
+        return [
+            WeightSpec("win_k", ring(p.k_channels), zero),
+            WeightSpec("win_v", ring(p.v_channels), zero),
+            WeightSpec("seq_lens", ints, zero),
+            WeightSpec("row_tokens", ints, zero),
+        ]
 
     def _paged_state_specs(self, qd, dt):
         """State specs for paged decode: block-pool k/v caches plus the
@@ -404,9 +526,11 @@ class MultiHeadAttention(Op):
             # unsharded block/page dims, so the host-owned block
             # table / COW / prefix-sharing plumbing never sees the
             # sharding.
+            heads = ParallelDim(p.kv_heads, self.shard.channel)
             dims = (
-                ParallelDim(nb * self.cache_planes), ParallelDim(page),
-                ParallelDim(p.kv_heads, self.shard.channel),
+                ParallelDim(nb * self.cache_planes),
+                *((heads, ParallelDim(page)) if p.kv_head_major
+                  else (ParallelDim(page), heads)),
                 ParallelDim(d_head),
                 ParallelDim(1, 1, is_replica_dim=True),
             )
@@ -436,11 +560,21 @@ class MultiHeadAttention(Op):
             with scope("out"):
                 if gate is not None:
                     ctx = ctx * jax.nn.sigmoid(gate).astype(ctx.dtype)
+                if p.head_gate:  # `wg`: the last trainable weight
+                    wg = weights[self.num_trainable_weights() - 1]
+                    ctx = ctx * jax.nn.sigmoid(jnp.einsum(
+                        "bse,eh->bsh", q, wg)).astype(ctx.dtype)[..., None]
                 out = jnp.einsum("bqhd,hde->bqe", ctx, wo)
                 if bo is not None:
                     out = out + bo[None, None]
                 return out.astype(q.dtype)
 
+        if self._ring():
+            win_k, win_v, slen, row_tokens = weights[-4:]
+            with scope("window_read"):
+                ctx, win_k, win_v = self._attend_ring(
+                    qh, kh, vh, win_k, win_v, slen, row_tokens, scale)
+            return [out_of(ctx), win_k, win_v, slen, row_tokens]
         if self._paged():
             k_cache, v_cache, btab, slen = weights[-4:]
             attend = (self._attend_decode_paged_once if p.paged_read_once
@@ -505,8 +639,19 @@ class MultiHeadAttention(Op):
             kh = rms_normalize(kh, k_norm, p.norm_eps, p.norm_zero_centered)
         if p.rotary_dim:
             positions = self._positions(q.shape[0], q.shape[1], weights)
-            qh = rotate_half(qh, positions, p.rotary_dim, p.rope_theta)
-            kh = rotate_half(kh, positions, p.rotary_dim, p.rope_theta)
+            yarn = {}
+            if p.rope_factor > 1 or p.rope_attention_factor != 1.0:
+                from .rope import yarn_frequencies
+
+                yarn = dict(
+                    freq=yarn_frequencies(
+                        p.rotary_dim, p.rope_theta, p.rope_factor,
+                        p.rope_original_max, p.beta_fast, p.beta_slow),
+                    factor=float(p.rope_attention_factor))
+            qh = rotate_half(qh, positions, p.rotary_dim, p.rope_theta,
+                             **yarn)
+            kh = rotate_half(kh, positions, p.rotary_dim, p.rope_theta,
+                             **yarn)
         return qh, kh, vh, gate, bo
 
     def _positions(self, b, s, weights):
@@ -514,6 +659,8 @@ class MultiHeadAttention(Op):
         embedding: each row's `seq_lens` on (paged), the cache position
         on (dense cache), 0..s-1 (no cache)."""
         steps = jnp.arange(s, dtype=jnp.int32)[None, :]
+        if self._ring():
+            return weights[-2].reshape(b, 1).astype(jnp.int32) + steps
         if self._paged():
             return weights[-1].reshape(b, 1).astype(jnp.int32) + steps
         if self._decode_n() > 0:
@@ -556,6 +703,64 @@ class MultiHeadAttention(Op):
         probs = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache.astype(qh.dtype))
         return ctx, k_cache, v_cache, (pos0 + s).reshape(1)
+
+    def _attend_ring(self, qh, kh, vh, win_k, win_v, slen, row_tokens,
+                     scale):
+        """A window layer's step from each row's own position (class
+        docstring): the step's real rows into the ring, then one read
+        of the whole ring, masked to each query's window by position."""
+        p: MultiHeadAttentionParams = self.params
+        b, s = qh.shape[:2]
+        window, ring = p.sliding_window, win_k.shape[2]
+        start = slen.reshape(b).astype(jnp.int32)
+        count = jnp.clip(row_tokens.reshape(b).astype(jnp.int32), 0, s)
+        at = start[:, None] + jnp.arange(s, dtype=jnp.int32)  # [b, s]
+        rows = jnp.arange(b)[:, None]
+        # the row's real tokens only; a pad's row index is dropped
+        slot = jnp.where(jnp.arange(s)[None, :] < count[:, None],
+                         at % ring, ring)
+        if s == 1:
+            # (every index but the channels' is a scatter index: with
+            # the heads in the update's window the TPU compiler re-lays
+            # the whole ring row-major for the scatter and back for the
+            # product)
+            heads = jnp.arange(p.kv_heads)
+            at_row = (rows[:, :, None], heads[None, None, :],
+                      slot[:, :, None])
+            win_k = win_k.at[at_row].set(kh.astype(win_k.dtype), mode="drop")
+            win_v = win_v.at[at_row].set(vh.astype(win_v.dtype), mode="drop")
+        else:
+            # a chunk's rows by ONE product with a one-hot [b, R, s]
+            # (exact: a ring row is 1.0 x the row it takes) and a select:
+            # the scatter above takes ~50 ns a row of 128 channels on a
+            # v5e, 7 ms a pass of [32, 32] over nine layers (PERF.md,
+            # PR 55); a pad's `slot` is R and hits no row
+            hit = slot[:, None, :] == jnp.arange(ring)[None, :, None]
+            took = jnp.any(hit, axis=-1)[:, None, :, None]  # [b, 1, R, 1]
+            exact = (jax.lax.Precision.HIGHEST
+                     if kh.dtype == jnp.float32 else None)
+
+            def placed(x, old):
+                new = jnp.einsum("brs,bskd->bkrd", hit.astype(x.dtype), x,
+                                 precision=exact)
+                return jnp.where(took, new.astype(old.dtype), old)
+
+            win_k, win_v = placed(kh, win_k), placed(vh, win_v)
+        # ring row r holds the last position under the row's new length
+        # that is r mod R (negative: never written by this sequence)
+        last = (start + count)[:, None] - 1
+        held = last - (last - jnp.arange(ring, dtype=jnp.int32)) % ring
+        held = held[:, None, :]  # [b, 1, R] against at [b, s, 1]
+        visible = ((held >= 0) & (held <= at[:, :, None])
+                   & (held > at[:, :, None] - window))
+        qg = qh.reshape(b, s, p.kv_heads, p.group, -1)
+        scores = jnp.einsum("bskgd,bkrd->bkgsr", qg, win_k.astype(qh.dtype),
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(visible[:, None, None], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(qh.dtype)
+        ctx = jnp.einsum("bkgsr,bkrd->bskgd", probs, win_v.astype(qh.dtype))
+        return ctx.reshape(b, s, p.num_heads, -1), win_k, win_v
 
     def _attend_decode_paged(self, qh, kh, vh, k_cache, v_cache, btab,
                              slen, scale):
@@ -664,20 +869,30 @@ class MultiHeadAttention(Op):
         at = jnp.minimum(pos, n - 1)
         blk = jnp.where(pos < n,
                         jnp.take_along_axis(btab, at // page, axis=1), 0)
-        k_cache = k_cache.at[blk, at % page].set(kh.astype(k_cache.dtype))
-        v_cache = v_cache.at[blk, at % page].set(vh.astype(v_cache.dtype))
+        if p.kv_head_major:
+            # (every index but the channels' a scatter index, as the
+            # rings': `_attend_ring`)
+            where = (blk[:, :, None], jnp.arange(p.kv_heads)[None, None, :],
+                     (at % page)[:, :, None])
+        else:
+            where = (blk, at % page)
+        k_cache = k_cache.at[where].set(kh.astype(k_cache.dtype))
+        v_cache = v_cache.at[where].set(vh.astype(v_cache.dtype))
         if self._kv_kernel == "pallas":
             # the same writes, then the row's live pages read in place
-            # (one head count: `infer_output_shapes` keeps grouped
-            # heads on the gather)
+            # (the kernel takes the query heads grouped as they are)
             ctx = self._paged_kernel_read(
                 qh, k_cache, v_cache, btab,
                 slen.reshape(b).astype(jnp.int32), scale)
             return ctx, k_cache, v_cache
-        kv_k = jnp.take(k_cache, btab, axis=0).reshape(
-            b, n, p.kv_heads, -1).astype(qh.dtype)
-        kv_v = jnp.take(v_cache, btab, axis=0).reshape(
-            b, n, p.kv_heads, -1).astype(qh.dtype)
+
+        def view(pool):  # [b, n, kv_heads, d] of the row's table
+            got = jnp.take(pool, btab, axis=0)
+            if p.kv_head_major:  # [b, tw, h, page, d]
+                got = got.transpose(0, 1, 3, 2, 4)
+            return got.reshape(b, n, p.kv_heads, -1).astype(qh.dtype)
+
+        kv_k, kv_v = view(k_cache), view(v_cache)
         qg = qh.reshape(b, s, p.kv_heads, p.group, -1)
         scores = jnp.einsum("bskgd,bnkd->bkgsn", qg, kv_k,
                             preferred_element_type=jnp.float32) * scale
@@ -731,6 +946,7 @@ class MultiHeadAttention(Op):
         """One `paged_attention` dispatch over pools already written."""
         from .pallas.paged_attention import paged_attention
 
+        major = bool(getattr(self.params, "kv_head_major", False))
         mesh = getattr(self, "_mesh", None)
         if self.shard.channel > 1 and mesh is not None \
                 and mesh.devices.size > 1:
@@ -745,10 +961,11 @@ class MultiHeadAttention(Op):
 
             batch_spec, _, head_spec = self._view_specs()
             qspec = PartitionSpec(batch_spec, None, head_spec, None)
-            pool_spec = PartitionSpec(None, None, head_spec, None)
+            pool_spec = (PartitionSpec(None, head_spec, None, None) if major
+                         else PartitionSpec(None, None, head_spec, None))
             ctx = jax.shard_map(
                 lambda q_, k_, v_, bt_, ps_: paged_attention(
-                    q_, k_, v_, bt_, ps_, scale),
+                    q_, k_, v_, bt_, ps_, scale, head_major=major),
                 mesh=mesh,
                 in_specs=(qspec, pool_spec, pool_spec,
                           PartitionSpec(None, None), PartitionSpec(None)),
@@ -756,7 +973,8 @@ class MultiHeadAttention(Op):
                 check_vma=False,
             )(qh, k_cache, v_cache, btab, pos)
         else:
-            ctx = paged_attention(qh, k_cache, v_cache, btab, pos, scale)
+            ctx = paged_attention(qh, k_cache, v_cache, btab, pos, scale,
+                                  head_major=major)
         return ctx
 
     # -- attention core dispatch ----------------------------------------
@@ -811,7 +1029,8 @@ class MultiHeadAttention(Op):
         part = max(1, data_deg) * max(1, self.shard.channel)
         scores_bytes = b * h * sq * sk * itemsize // part
         force_flash = scores_bytes > _FLASH_FORCE_SCORE_BYTES
-        blocked = use_dropout or (p.causal and kv_appended)
+        blocked = (use_dropout or (p.causal and kv_appended)
+                   or p.sliding_window > 0)
         if force_flash and blocked:
             import warnings
 
@@ -820,6 +1039,8 @@ class MultiHeadAttention(Op):
                 "scores will materialize per device — the flash path "
                 "cannot take over because of "
                 + ("attention dropout" if use_dropout
+                   else "a sliding window (the flash kernel has no "
+                        "window in its mask)" if p.sliding_window
                    else "causal attention with appended kv "
                         "(add_bias_kv/add_zero_attn)")
             )
@@ -886,6 +1107,9 @@ class MultiHeadAttention(Op):
             # appended bias_kv/zero_attn keys are always attendable;
             # real keys follow absolute-position causality
             mask = jnp.tril(jnp.ones((qlen, klen), bool))
+            if p.sliding_window:
+                mask &= ~jnp.tril(jnp.ones((qlen, klen), bool),
+                                  -p.sliding_window)
             if kv_appended:
                 mask = mask.at[:, klen - kv_appended:].set(True)
             scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
@@ -918,9 +1142,11 @@ class MultiHeadAttention(Op):
         ks = self.inputs[1].shape.logical_shape[1]
         # q (and its gate) and the output per query head, k and v per
         # key/value head
-        gate = p.v_channels if p.output_gate else 0
+        gate = (p.v_channels if p.output_gate else 0) + int(p.head_gate)
         proj = 2.0 * b * s * e * (
             p.num_heads * (p.k_channels + gate + p.v_channels)
             + p.kv_heads * (p.k_channels + p.v_channels))
+        if p.sliding_window:
+            ks = min(ks, p.sliding_window)
         attn = 2.0 * b * p.num_heads * s * ks * (p.k_channels + p.v_channels)
         return proj + attn

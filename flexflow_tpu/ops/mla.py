@@ -77,6 +77,7 @@ from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .norm import rms_normalize
 from .op import Op, ShapeError, WeightSpec
+from .rope import yarn_frequencies as _yarn_frequencies
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,24 +117,10 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 def yarn_frequencies(p: MLAParams) -> np.ndarray:
     """Rotation per position of each adjacent pair of rope channels,
-    [qk_rope_head_dim / 2] float64: the published YaRN blend of the
-    extrapolated (`theta^(-2i/d)`) and the interpolated (`/ factor`)
-    frequencies over a linear ramp between the pairs that turn
-    `beta_fast` and `beta_slow` times in the original context."""
-    d = p.qk_rope_head_dim
-    extra = p.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    if p.rope_factor <= 1:
-        return extra
-
-    def pair_of(turns):
-        return (d * math.log(p.rope_original_max / (2 * math.pi * turns))
-                / (2 * math.log(p.rope_theta)))
-
-    low = max(math.floor(pair_of(p.beta_fast)), 0)
-    high = min(math.ceil(pair_of(p.beta_slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    return extra / p.rope_factor * ramp + extra * (1.0 - ramp)
+    [qk_rope_head_dim / 2] float64 (`ops/rope.py yarn_frequencies` at
+    this op's sizes)."""
+    return _yarn_frequencies(p.qk_rope_head_dim, p.rope_theta, p.rope_factor,
+                             p.rope_original_max, p.beta_fast, p.beta_slow)
 
 
 def softmax_scale(p: MLAParams) -> float:

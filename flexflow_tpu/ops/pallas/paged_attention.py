@@ -32,7 +32,9 @@ page ``[page, h, d]`` is, with no data movement, the matrix
 ``[page * h, d]`` (row c is key c // h of head c % h; h = 16 is bf16's
 sublane tile), the queries ``[s, h, d]`` likewise ``[s * h, d]``.  ONE
 product contracting d scores a fold's pages for every pair of heads,
-the mask keeps an entry where the heads match and the key is visible,
+the mask keeps an entry where the heads match (grouped-query heads:
+where the query head's key/value head, `q_head // G`, is the key's;
+one program for every G) and the key is visible,
 the online softmax runs over the lanes of that one f32 matrix, and ONE
 product with ``v [n * page * h, dv]`` gives the context (another
 head's probability is exp(-1e30) = 0).  The MXU does h times the
@@ -179,27 +181,31 @@ def scan_blocks_read(seq_lens: np.ndarray, counts: np.ndarray,
     return int(np.where(j < counts, last // page + 1, 0).sum())
 
 
-def _mxu(a, b, contract):
-    """a x b over `contract` on the MXU, f32 out: bf16 products are
-    exact there; f32 operands (the parity tests') ask for f32's."""
+def _mxu(a, b, contract, batch=((), ())):
+    """a x b over `contract` on the MXU (a product a `batch` dim: the
+    heads of a head-major fold), f32 out: bf16 products are exact there;
+    f32 operands (the parity tests') ask for f32's."""
     dt = jnp.promote_types(a.dtype, b.dtype)
     return jax.lax.dot_general(
-        a.astype(dt), b.astype(dt), (contract, ((), ())),
+        a.astype(dt), b.astype(dt), (contract, batch),
         precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
         preferred_element_type=jnp.float32)
 
 
-def _context(pr, v):
-    """pr [m, n] f32 x v [n, dv], the probabilities NOT rounded: an f32
-    is the sum of three bf16, so over a bf16 pool the three parts ride
-    ONE product stacked on its rows, every term exact, summed in f32."""
+def _context(pr, v, batch=((), ())):
+    """pr [.., m, n] f32 x v [.., n, dv] (a leading head dim with
+    `batch`), the probabilities NOT rounded: an f32 is the sum of three
+    bf16, so over a bf16 pool the three parts ride ONE product stacked
+    on its rows, every term exact, summed in f32."""
+    rows = pr.ndim - 2
+    contract = ((rows + 1,), (rows,))
     if v.dtype != jnp.bfloat16:
-        return _mxu(pr, v, ((1,), (0,)))
+        return _mxu(pr, v, contract, batch)
     hi = pr.astype(v.dtype)
     mid = (pr - hi).astype(v.dtype)
     lo = (pr - hi - mid).astype(v.dtype)
-    hi, mid, lo = jnp.split(
-        _mxu(jnp.concatenate([hi, mid, lo]), v, ((1,), (0,))), 3)
+    hi, mid, lo = jnp.split(_mxu(jnp.concatenate([hi, mid, lo], axis=rows),
+                                 v, contract, batch), 3, axis=rows)
     return hi + mid + lo
 
 
@@ -207,9 +213,12 @@ def _fold_pages(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
                 page: int, scale: float):
     """Fold the physical pages of table columns `col`, `col` + 1, ..
     into the row's online softmax.  ks, vs: a [page, h, d] each, as the
-    pool holds them."""
-    chunk, h, _ = q_ref.shape[1:]
-    rows = chunk * h
+    pool holds them; the queries' `hq` heads are `hq / h` to a
+    key/value head (grouped-query attention), query head g on key/value
+    head `g // (hq / h)`."""
+    chunk, hq, _ = q_ref.shape[1:]
+    h = ks[0].shape[1]
+    rows = chunk * hq
     # a page as the matrix it already is: row c of [page * h, d] is
     # key c // h of head c % h; scores [chunk * h, pages * page * h]
     k, v = (jnp.concatenate([x.reshape(page * h, -1) for x in xs])
@@ -221,7 +230,8 @@ def _fold_pages(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
     # blocks and scratch rows (pos 0, zero table) fall out of it too
     r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    keep = (r % h == c % h) & (col * page + c // h <= pos + r // h)
+    q_head = r % hq if hq == h else (r % hq) // (hq // h)
+    keep = (q_head == c % h) & (col * page + c // h <= pos + r // hq)
     s = jnp.where(keep, s, _NEG_INF)
     m_prev = m_ref[...].reshape(rows, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -259,8 +269,8 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
     # the program's pages as keep the scores, [chunk * h, n * page * h]
     # f32, within the 64 vector registers of 1,024 (a page of the group
     # past the live ones is all future keys: masked)
-    h = q_ref.shape[2]
-    group = max(1, min(pages, 65536 // (chunk * h * page * h)))
+    group = max(1, min(pages, 65536 // (
+        chunk * q_ref.shape[2] * page * k_refs[0].shape[2])))
     for p in range(0, pages, group):
         col = kb * pages + p
 
@@ -277,19 +287,104 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
+def _fold_head_major(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
+                     page: int, scale: float, chunk: int):
+    """`_fold_pages` for a HEAD-MAJOR pool: ks, vs are refs of a
+    `[1, h, page, d]` block each, q_ref `[1, h, G * chunk, d]` with a key/value head's G query
+    heads side by side (row `g * chunk + t` is query head `j * G + g`,
+    chunk token t).  A key/value head's queries against ITS keys alone,
+    `[h, G * chunk, n * page]` scores by one product batched over the
+    heads, so nothing is computed for another head's pair (the page-major fold multiplies
+    and exponentiates every pair and masks `h - 1` of `h` away: at 8
+    key/value heads under 48 query heads and a chunk of 32 that was
+    36 ms a launch; PERF.md, PR 55)."""
+    h, rows = q_ref.shape[1:3]
+    cols = len(ks) * page
+    # chunk token t attends key positions <= pos + t (`_fold_pages`)
+    if chunk == 1:
+        tok = 0
+    elif chunk % 8 == 0:  # (rows / chunk, chunk, cols) merges for free
+        tok = jax.lax.broadcasted_iota(
+            jnp.int32, (rows // chunk, chunk, cols), 1).reshape(rows, cols)
+    else:
+        tok = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) % chunk
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    keep = col * page + c <= pos + tok
+    # every head in ONE batched product a stage (a loop over the heads
+    # is a chain of small products, each waiting for the last: 1.0 us a
+    # page at 8 heads and a chunk of 32; PERF.md, PR 55)
+    k, v = (jnp.concatenate([r[0] for r in refs], axis=1)
+            for refs in (ks, vs))  # [h, cols, d]
+    heads = ((0,), (0,))
+    s = _mxu(q_ref[0], k, ((2,), (2,)), heads) * scale  # [h, rows, cols]
+    s = jnp.where(keep[None], s, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    pr = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=2, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + _context(pr, v, heads)
+    m_ref[...] = m_new
+
+
+def _head_major_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
+                       scale: float, table_width: int, chunk: int,
+                       pages: int):
+    """`_paged_kernel` over a head-major pool (`_fold_head_major`)."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    i = pl.program_id(0)
+    kb = pl.program_id(1)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = slen_ref[i]
+    live = _live_block_count(pos, chunk, page, table_width)
+    # as many pages a fold as keep the heads' scores, [h, G * chunk, n *
+    # page] f32, near the vector registers' 64 x 1,024
+    group = max(1, min(pages, 131072 // (
+        q_ref.shape[1] * q_ref.shape[2] * page)))
+    for p in range(0, pages, group):
+        col = kb * pages + p
+
+        @pl.when(col < live)
+        def _fold(p=p, col=col):
+            _fold_head_major(q_ref, k_refs[p:p + group],
+                             v_refs[p:p + group], m_ref, l_ref, acc_ref,
+                             col, pos, page=page, scale=scale, chunk=chunk)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _write():
+        l = l_ref[...]
+        l_safe = jnp.where(l > 0.0, l, 1.0)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
 def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
                     scale: float, *, interpret: Optional[bool] = None,
-                    pages_per_step: Optional[int] = None):
+                    pages_per_step: Optional[int] = None,
+                    head_major: bool = False):
     """Fused paged attention over the pool.
 
-    qh:          [b, s, h, dk]  this step's queries (s = 1 or chunk C)
+    qh:          [b, s, h * G, dk]  this step's queries (s = 1 or chunk
+                 C): G query heads to a key/value head, query head g
+                 reads key/value head g // G (G = 1: one head count)
     k_pool:      [num_blocks, page, h, dk]  the physical K pool
     v_pool:      [num_blocks, page, h, dv]
     block_table: [b, table_width] int32 (host-owned, scratch-padded)
     seq_lens:    [b] int32 — row i's incoming position (its chunk
                  occupies positions seq_lens[i] .. seq_lens[i]+s-1,
                  already scattered into the pool by the caller)
-    ->           [b, s, h, dv] context, qh's dtype
+    ->           [b, s, h * G, dv] context, qh's dtype
+
+    `head_major`: the pools are `[num_blocks, h, page, d]` (a head's
+    rows of a page side by side: `MultiHeadAttentionParams.
+    kv_head_major`), and the kernel folds one key/value head at a time
+    (`_fold_head_major`): what a grouped layer's pool takes.
 
     Every shape and the grid are static in (b, s, table_width): how
     many tokens are live is DATA (`seq_lens`), so one program serves
@@ -306,8 +401,13 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
         raise ValueError(
             "paged_attention(interpret=True) on the TPU backend: the "
             "kernel must run compiled there")
-    b, s, h, dk = qh.shape
-    page, dv = k_pool.shape[1], v_pool.shape[-1]
+    b, s, hq, dk = qh.shape
+    h, page = k_pool.shape[1:3][::1 if head_major else -1]
+    dv = v_pool.shape[-1]
+    if hq % h:
+        raise ValueError(
+            f"paged_attention: {hq} query heads are no multiple of the "
+            f"pool's {h} key/value heads")
     table_width = block_table.shape[1]
     if pages_per_step is None:  # (the tests and the probe name one)
         pages_per_step = pages_per_program(
@@ -315,20 +415,23 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
     pages = max(1, min(int(pages_per_step), table_width))
     return _paged_launch(qh, k_pool, v_pool, block_table, seq_lens,
                          scale=float(scale), interpret=bool(interpret),
-                         pages=pages)
+                         pages=pages, head_major=bool(head_major))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pages"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pages",
+                                             "head_major"))
 def _paged_launch(qh, k_pool, v_pool, block_table, seq_lens, *,
-                  scale: float, interpret: bool, pages: int):
+                  scale: float, interpret: bool, pages: int,
+                  head_major: bool = False):
     """`paged_attention`'s launch as a jitted function of its own: the
     layers of a model call it with the same shapes, so the kernel is
     traced and lowered to Mosaic ONCE a step program and not once a
     layer (48 times in cell 7's programs: a whole-row prefill kernel,
     ten folds unrolled, took 24 s of set-up that way against the
     stepped one's 11; PERF.md PR 43); XLA inlines the calls."""
-    b, s, h, dk = qh.shape
-    page, dv = k_pool.shape[1], v_pool.shape[-1]
+    b, s, hq, dk = qh.shape
+    h, page = k_pool.shape[1:3][::1 if head_major else -1]
+    dv = v_pool.shape[-1]
     table_width = block_table.shape[1]
     block_table = block_table.astype(jnp.int32)
     seq_lens = seq_lens.reshape(b).astype(jnp.int32)
@@ -353,24 +456,52 @@ def _paged_launch(qh, k_pool, v_pool, block_table, seq_lens, *,
             return btab[i, kb * pages + p], 0, 0, 0
         return index
 
+    if head_major:
+        # a key/value head's G query heads side by side: [b, h, G * s, d]
+        g = hq // h
+        rows = g * s
+        qm = qh.reshape(b, s, h, g, dk).transpose(0, 2, 3, 1, 4).reshape(
+            b, h, rows, dk)
+        out = pl.pallas_call(
+            functools.partial(_head_major_kernel, page=page, scale=scale,
+                              table_width=table_width, chunk=s, pages=pages),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b, steps),
+                in_specs=[pl.BlockSpec((1, h, rows, dk), q_map)]
+                + [pl.BlockSpec((1, h, page, dk), kv_map(p))
+                   for p in range(pages)]
+                + [pl.BlockSpec((1, h, page, dv), kv_map(p))
+                   for p in range(pages)],
+                out_specs=pl.BlockSpec((1, h, rows, dv), q_map),
+                scratch_shapes=[
+                    pltpu.VMEM((h, rows, 1), jnp.float32),
+                    pltpu.VMEM((h, rows, 1), jnp.float32),
+                    pltpu.VMEM((h, rows, dv), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((b, h, rows, dv), qh.dtype),
+            interpret=interpret, name="paged_attention_head_major",
+        )(block_table, seq_lens, qm, *([k_pool] * pages),
+          *([v_pool] * pages))
+        return out.reshape(b, h, g, s, dv).transpose(0, 3, 1, 2, 4).reshape(
+            b, s, hq, dv)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, steps),
-        in_specs=[pl.BlockSpec((1, s, h, dk), q_map)]
+        in_specs=[pl.BlockSpec((1, s, hq, dk), q_map)]
         + [pl.BlockSpec((1, page, h, dk), kv_map(p)) for p in range(pages)]
         + [pl.BlockSpec((1, page, h, dv), kv_map(p)) for p in range(pages)],
-        out_specs=pl.BlockSpec((1, s, h, dv), q_map),
+        out_specs=pl.BlockSpec((1, s, hq, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((s, h, 1), jnp.float32),   # running max
-            pltpu.VMEM((s, h, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((s, h, dv), jnp.float32),  # context accumulator
+            pltpu.VMEM((s, hq, 1), jnp.float32),   # running max
+            pltpu.VMEM((s, hq, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((s, hq, dv), jnp.float32),  # context accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, page=page, scale=scale,
                           table_width=table_width, chunk=s, pages=pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, dv), qh.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, hq, dv), qh.dtype),
         interpret=interpret,
         name="paged_attention",
     )(block_table, seq_lens, qh, *([k_pool] * pages), *([v_pool] * pages))

@@ -386,6 +386,8 @@ class ServingFront:
                            **model.gdn_ops)
                 if model.eva:  # windows and summary stores, per slot
                     sp.set(eva_state_bytes=model.eva_state_bytes)
+                if model.swa:  # window layers' rings, per slot
+                    sp.set(swa_state_bytes=model.swa_state_bytes)
                 if model.loop:  # the twin's graph repeats a region
                     sp.set(**model.loop)
             return model

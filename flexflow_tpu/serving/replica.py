@@ -120,6 +120,10 @@ class SupervisedDecodeModel:
         self.eva = getattr(model, "eva", None)
         self.eva_state_bytes = getattr(model, "eva_state_bytes", 0)
         self.eva_rows = getattr(model, "eva_rows", None)
+        # window layers' rings, likewise
+        self.swa = getattr(model, "swa", None)
+        self.swa_state_bytes = getattr(model, "swa_state_bytes", 0)
+        self.swa_rows = getattr(model, "swa_rows", None)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)
@@ -626,6 +630,10 @@ class ServingReplica:
             # their geometry and their state's bytes
             if "eva" in sstats:
                 out["eva"] = sstats["eva"]
+            # window layers: the `swa_*` dispatch args summed, the
+            # rings' geometry and their bytes
+            if "swa" in sstats:
+                out["swa"] = sstats["swa"]
             # a graph that repeats a region: its regions, the weight
             # passes of the decode and prefill dispatches, and the exit
             # gate's pdf summed over the decode dispatches' live rows

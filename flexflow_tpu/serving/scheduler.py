@@ -407,9 +407,35 @@ class PagedKVDecodeModel:
             int(self._state[op][k].nbytes)
             for op, names in self._slot_state.items() if op in ops
             for k in names)
+        # window layers (ops/attention.py, `sliding_window`) keep a
+        # fourth: a ring of keys and values a slot, masked by position
+        # like EVA's window.  `swa` is their geometry (None without such
+        # a layer), `swa_state_bytes` what the rings hold
+        swa_ops = [op for op in self.ffd.operators.topo_order()
+                   if op.op_type == OperatorType.MULTIHEAD_ATTENTION
+                   and op.slot_state_entries()]
         self.eva_state_bytes = bytes_of({op.name for op in eva_ops})
+        self.swa_state_bytes = bytes_of({op.name for op in swa_ops})
         self.rstate_bytes = bytes_of(
-            set(self._slot_state) - {op.name for op in eva_ops})
+            set(self._slot_state) - {op.name for op in eva_ops + swa_ops})
+        self.swa = ({"window": swa_ops[0].params.sliding_window,
+                     "ring": swa_ops[0]._window_ring,
+                     "layers": len(swa_ops)} if swa_ops else None)
+        if self.swa:  # (the counters' arithmetic, as `_eva_row_counts`)
+            from ..ops.attention import window_rows_live
+
+            self._window_rows_live = window_rows_live
+        if self.swa and self.prefill_chunk > (self.swa["ring"]
+                                              - self.swa["window"]):
+            raise ConfigError(
+                f"prefill_chunk {self.prefill_chunk} is longer than the "
+                f"{self.swa['ring'] - self.swa['window']} rows that "
+                f"{recipe.family}'s window layers' rings hold beside "
+                f"their window (ring {self.swa['ring']}, sliding_window "
+                f"{self.swa['window']}): a pass writes its rows before it "
+                "reads, and a longer one would overwrite keys its first "
+                "queries still see; build the model with that "
+                "prefill_chunk")
         self.eva = ({"window": eva_ops[0].params.window_size,
                      "chunk": eva_ops[0].params.chunk_size,
                      "store_rows": eva_ops[0].store_rows,
@@ -490,6 +516,21 @@ class PagedKVDecodeModel:
             self.eva["window"], self.eva["chunk"], self.eva["store_rows"],
             self.batch_slots, positions, counts)
         return {k: v * self.eva["layers"] for k, v in one.items()}
+
+    def swa_rows(self, positions, counts) -> Optional[Dict[str, int]]:
+        """The `swa_*` args of a dispatch that advances row i over
+        `positions[i] .. + counts[i] - 1`: `swa_rows_live`, the ring
+        rows some query of it sees, summed over the rows and the window
+        layers (`ops/attention.py window_rows_live`), against
+        `swa_rows_read`, the rows the program as built reads (every
+        slot's whole ring, a layer).  Host arithmetic on host-owned
+        lengths, no fetch.  None without such a layer."""
+        if self.swa is None:
+            return None
+        n = self.swa["layers"]
+        return {"swa_rows_live": n * self._window_rows_live(
+                    self.swa["window"], positions, counts),
+                "swa_rows_read": n * self.batch_slots * self.swa["ring"]}
 
     def _row_tokens(self, row_tokens, one_pass: bool = False,
                     take_prev=None) -> tuple:
@@ -1061,8 +1102,16 @@ class ContinuousScheduler:
         self.eva_totals: Optional[Dict[str, int]] = (
             {"decode_dispatches": 0, "prefill_dispatches": 0}
             if self._eva_rows is not None else None)
+        # and so are the window layers' rings: the `swa_*` args
+        # (`model.swa_rows`)
+        self._swa_rows = (getattr(model, "swa_rows", None)
+                          if getattr(model, "swa", None) else None)
+        self.swa_totals: Optional[Dict[str, int]] = (
+            dict.fromkeys(("dispatches", "swa_rows_live", "swa_rows_read"),
+                          0) if self._swa_rows is not None else None)
         recurrent = self._rstate and (
-            self._eva_rows is None or getattr(model, "rstate_bytes", 0) > 0)
+            (self._eva_rows is None and self._swa_rows is None)
+            or getattr(model, "rstate_bytes", 0) > 0)
         self.rstate_totals: Optional[Dict[str, int]] = (
             dict.fromkeys(("rows_live", "rows_touched", "dispatches"), 0)
             if recurrent else None)
@@ -1113,6 +1162,11 @@ class ContinuousScheduler:
                 int(getattr(model, "kv_block_bytes_per_chip",
                             getattr(model, "kv_block_bytes", 0)))
                 * int(getattr(model, "num_blocks", 0)))
+            if self._swa_rows is not None:
+                registry.gauge("serving/swa_state_bytes").set(
+                    int(model.swa_state_bytes))
+                registry.gauge("serving/swa_ring_rows").set(
+                    int(model.swa["ring"]))
         self._queue: "queue.Queue[_PendingSeq]" = queue.Queue()
         self._waiting: deque = deque()  # worker-local FIFO admit order
         # worker-marshalled service calls (KV block import, export):
@@ -1463,6 +1517,9 @@ class ContinuousScheduler:
             **({"eva": dict(self.eva_totals, **self.model.eva,
                             state_bytes=int(self.model.eva_state_bytes))}
                if self.eva_totals is not None else {}),
+            **({"swa": dict(self.swa_totals, **self.model.swa,
+                            state_bytes=int(self.model.swa_state_bytes))}
+               if self.swa_totals is not None else {}),
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1901,6 +1958,16 @@ class ContinuousScheduler:
         for k, v in rows.items():
             t[f"{program}_{k}"] = t.get(f"{program}_{k}", 0) + v
 
+    def _note_swa(self, dispatch, positions, counts) -> None:
+        """The `swa_*` args of a dispatch span (`model.swa_rows`) and
+        their sums over both kinds of dispatch."""
+        rows = self._swa_rows(positions, counts)
+        dispatch.set(**rows)
+        t = self.swa_totals
+        t["dispatches"] += 1
+        for k, v in rows.items():
+            t[k] += v
+
     def _note_moe(self, dispatch, program: str) -> None:
         """The `moe_*` args of a dispatch whose logits were fetched
         (`model.moe_last`: the routed layers' counts of that dispatch,
@@ -2041,6 +2108,8 @@ class ContinuousScheduler:
                         self._note_rstate(dispatch, len(plan), C)
                     if self._eva_rows is not None:
                         self._note_eva(dispatch, "prefill", slen, fed)
+                    if self._swa_rows is not None:
+                        self._note_swa(dispatch, slen, fed)
                     # a plan row's prefix is read once a pass: by the
                     # scan at each of its C positions, by the one-pass
                     # program once, up to the chunk's last; the scan's
@@ -2451,6 +2520,8 @@ class ContinuousScheduler:
                     self._note_rstate(dispatch, int(alive[0].sum()), 1)
                 if self._eva_rows is not None:
                     self._note_eva(dispatch, "decode", self._slens, alive[0])
+                if self._swa_rows is not None:
+                    self._note_swa(dispatch, self._slens, alive[0])
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
                 if why is None:

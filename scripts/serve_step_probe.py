@@ -4,7 +4,8 @@ the real size BEFORE the first benchmark run (PR 35: a hang inside the
 harness says nothing).
 
     chiprun --chips 1 -- python3 scripts/serve_step_probe.py \
-        --workload <cell> [--calls 10] [--chunks 8,64,128]
+        --workload <cell> [--calls 10] [--chunks 8,64,128] \
+        [--position 2048] [--scopes]
 
 Builds the cell's server as the harness does (`build_server`, the
 seed's weights, `PagedKVDecodeModel` with the front's arguments), gives
@@ -16,7 +17,11 @@ Prints the weight tree's parameters and bytes, the pool's bytes, the
 device's `memory_stats` and one JSON line.  `--chunks` does that once
 for each `prefill_chunk` of the list in turn (the weights stay, the
 twin and its state are built anew), a JSON line each: the readings a
-configuration's `prefill_chunk` is chosen from.  A serving family only."""
+configuration's `prefill_chunk` is chosen from.  `--position` puts the
+rows at another length than half the table's; `--scopes` also traces
+three calls of each program and prints where their device time went by
+the program's names (`benchmarks/device_scopes.py`).  A serving family
+only."""
 from __future__ import annotations
 
 import argparse
@@ -43,6 +48,10 @@ def main() -> int:
     ap.add_argument("--chunks", default=None,
                     help="prefill_chunk values to probe in turn "
                          "(default: the configuration's own)")
+    ap.add_argument("--position", type=int, default=None,
+                    help="every row's length (default: half the table)")
+    ap.add_argument("--scopes", action="store_true",
+                    help="trace three calls a program: the by-scope table")
     args = ap.parse_args()
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
@@ -59,12 +68,14 @@ def main() -> int:
     chunks = ([int(x) for x in args.chunks.split(",")] if args.chunks
               else [ff.config.prefill_chunk])
     for chunk in chunks:
-        probe(ff, chunk, dict(out), args.calls, t0)
+        probe(ff, chunk, dict(out), args.calls, t0, args.position,
+              args.scopes)
         t0 = time.monotonic()
     return 0
 
 
-def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
+def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float,
+          position=None, scopes: bool = False) -> None:
     """One twin at `prefill_chunk`: its two programs timed, its line."""
     from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
 
@@ -83,7 +94,8 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
     # every slot mid-sequence on blocks of its own
     table = 1 + np.arange(b * width, dtype=np.int32).reshape(b, width) \
         % (model.num_blocks - 1)
-    pos = np.full((b,), model.max_seq // 2, np.int32)
+    pos = np.full((b,), model.max_seq // 2 if position is None
+                  else position, np.int32)
     tokens = np.arange(1, b + 1, dtype=np.int32)
     rows = (np.ones((b,), np.int32),) if model.has_slot_state else ()
 
@@ -111,6 +123,8 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
         print(f"{name}: first {out[f'{name}_first_call_s']} s, then "
               + " ".join(f"{1e3 * t:.2f}" for t in times) + " ms",
               flush=True)
+    if scopes:
+        scope_table(decode, prefill, model, prefill_chunk)
     logits = decode()
     out["logits_finite"] = bool(np.isfinite(logits).all())
     if model.exit_last is not None:
@@ -120,6 +134,20 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float) -> None:
     out["memory"] = {k: int(stats[k]) for k in (
         "bytes_in_use", "peak_bytes_in_use", "bytes_limit") if k in stats}
     print(json.dumps(out), flush=True)
+
+
+def scope_table(decode, prefill, model, chunk: int) -> None:
+    """Three traced calls of each program, then the by-scope table."""
+    from benchmarks import device_scopes
+
+    trace_dir = os.path.join(ROOT, "benchmarks", "out",
+                             f"step_probe_{chunk}")
+    with jax.profiler.trace(trace_dir):
+        for call in (decode, prefill):
+            for _ in range(3):
+                call()
+            jax.block_until_ready(model._state)
+    device_scopes.main(["", harness.find_xplane(trace_dir)])
 
 
 if __name__ == "__main__":

@@ -481,6 +481,53 @@ def test_yarn_frequencies_and_softmax_scale_against_the_closed_forms():
     assert abs(softmax_scale(mla_params()) - fam.softmax_scale(D)) < 1e-12
 
 
+def test_yarn_frequencies_are_bit_equal_after_their_move_to_ops_rope():
+    """PR 55 moved the YaRN blend to `ops/rope.py`, a function of
+    (rotary dim, theta, factor, original, beta_fast, beta_slow) that
+    `ops/mla.py` and `ops/attention.py` both call: this family's
+    frequencies are the bytes they were (the expression of PR 29,
+    written out here), and plain RoPE is the factor-1 case."""
+    import math
+
+    from flexflow_tpu.ops.mla import MLAParams, yarn_frequencies
+    from flexflow_tpu.ops.rope import yarn_frequencies as shared
+
+    def as_it_was(d, theta, factor, original, beta_fast, beta_slow):
+        extra = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+        if factor <= 1:
+            return extra
+
+        def pair_of(turns):
+            return (d * math.log(original / (2 * math.pi * turns))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(pair_of(beta_fast)), 0)
+        high = min(math.ceil(pair_of(beta_slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        return extra / factor * ramp + extra * (1.0 - ramp)
+
+    published = (64, 50000.0, 64.0, 4096, 32.0, 1.0)
+    p = MLAParams(embed_dim=7168, num_heads=64, q_lora_rank=1536,
+                  kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=50000.0,
+                  rope_factor=64.0, rope_original_max=4096, beta_fast=32,
+                  beta_slow=1)
+    for got in (yarn_frequencies(p), shared(*published)):
+        assert got.dtype == np.float64
+        np.testing.assert_array_equal(got, as_it_was(*published))
+    np.testing.assert_array_equal(yarn_frequencies(mla_params()), as_it_was(
+        D.dr, D.theta, *(float(D.rope[k]) for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow"))))
+    # Laguna's full layers: 32 pairs of a 64-channel rotary half
+    laguna = (64, 500000.0, 64.0, 4096, 64.0, 1.0)
+    np.testing.assert_array_equal(shared(*laguna), as_it_was(*laguna))
+    np.testing.assert_array_equal(
+        shared(128, 10000.0),
+        10000.0 ** (-np.arange(0, 128, 2, dtype=np.float64) / 128))
+
+
 def test_router_chooses_in_float32_when_compute_dtype_is_bfloat16():
     """The executor hands the router's weights over in float32, and the
     experts chosen for bf16-computed activations are the float32

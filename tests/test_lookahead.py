@@ -430,8 +430,8 @@ def test_ahead_share_is_a_registered_metric_of_the_serving_cells():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    metric = bench["per_layer"][-1]
-    assert metric["name"] == "sched.ahead_share.capacity"
+    (metric,) = [m for m in bench["per_layer"]
+                 if m["name"] == "sched.ahead_share.capacity"]
     assert metric["moves"] == "serve_tokens_per_s"
     assert metric["workloads"] == [
         w["name"] for w in bench["workloads"] if "-serve." in w["name"]]

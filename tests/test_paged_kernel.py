@@ -126,6 +126,72 @@ def test_kernel_decode_and_chunk_twins_agree():
                                    rtol=2e-6, atol=2e-6)
 
 
+# -- grouped query heads: G query heads read one key/value head's pages ---------
+
+class _GroupedAttn:
+    """`MultiHeadAttention`'s one-view paged step (`paged_read_once`)
+    without a graph around it: the gather oracle of a grouped layer and
+    the same writes under the Pallas read."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention as _M
+
+    _attend_decode_paged_once = _M._attend_decode_paged_once
+    _paged_kernel_read = _M._paged_kernel_read
+
+    def __init__(self, kernel, heads, kv_heads, page, head_major=False):
+        self.params = SimpleNamespace(num_heads=heads, kv_heads=kv_heads,
+                                      group=heads // kv_heads,
+                                      kv_head_major=head_major)
+        self._kv_page_size, self._kv_kernel = page, kernel
+        self.shard = SimpleNamespace(channel=1)
+
+
+@pytest.mark.parametrize("head_major", [False, True],
+                         ids=["page_major", "head_major"])
+@pytest.mark.parametrize("chunk", [1, 5, 8])
+@pytest.mark.parametrize("group", [2, 6])
+def test_grouped_heads_match_the_one_view_gather(group, chunk, head_major):
+    """`q [b, s, kv_heads * G, d]` against the pool `[nb, page,
+    kv_heads, d]` (or head-major, `[nb, kv_heads, page, d]`, folded a
+    key/value head at a time): query head g reads key/value head `g //
+    G`.  The whole step (the chunk's writes, then the read) under the
+    kernel equals `_attend_decode_paged_once`'s gather, decode and
+    chunk, and leaves the same pools."""
+    kv_heads, d, page, tw = 2, 16, 4, 5
+    rng = np.random.RandomState(31 * group + chunk)
+    _, kp, vp, btab, slen = _random_case(
+        rng, b=4, s=chunk, h=kv_heads, d=d, page=page, table_width=tw)
+    if head_major:
+        kp, vp = (x.transpose(0, 2, 1, 3) for x in (kp, vp))
+    qh = jnp.asarray(rng.randn(4, chunk, kv_heads * group, d), jnp.float32)
+    kh, vh = (jnp.asarray(rng.randn(4, chunk, kv_heads, d), jnp.float32)
+              for _ in range(2))
+    scale = 1.0 / np.sqrt(d)
+    got, gk, gv = _GroupedAttn(
+        "pallas", kv_heads * group, kv_heads, page, head_major
+    )._attend_decode_paged_once(qh, kh, vh, kp, vp, btab, slen, scale)
+    want, wk, wv = _GroupedAttn(
+        "gather", kv_heads * group, kv_heads, page, head_major
+    )._attend_decode_paged_once(qh, kh, vh, kp, vp, btab, slen, scale)
+    assert got.shape == (4, chunk, kv_heads * group, d)
+    live = np.asarray(slen) > 0  # (row 0 is the scratch row)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-6, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(gk), np.asarray(wk))
+    np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv))
+    # the heads of a group differ (their queries do), the groups read
+    # different pages' heads
+    assert not np.allclose(np.asarray(got)[1, 0, 0], np.asarray(got)[1, 0, 1])
+
+
+def test_query_heads_must_be_a_multiple_of_the_pools():
+    rng = np.random.RandomState(2)
+    _, kp, vp, btab, slen = _random_case(rng, b=2, s=1, h=2, d=8, page=4,
+                                         table_width=2)
+    with pytest.raises(ValueError, match="no multiple"):
+        pk.paged_attention(jnp.zeros((2, 1, 3, 8)), kp, vp, btab, slen, 1.0,
+                           interpret=True)
+
+
 # -- the fold at the widths of both cells that run it (16 heads, page
 # 16, a bf16 pool: gpt2-medium-serve d = 64 under a table 64 wide,
 # ouro-2.6b-serve d = 128 under one 20 wide), fewer rows than either ----

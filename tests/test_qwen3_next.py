@@ -568,17 +568,27 @@ def test_feature_not_carried_is_a_config_error_by_name(feature):
 
 
 def test_grouped_heads_refuse_the_reads_that_keep_one_head_count():
+    """The per-position read and the dense cache keep one head count;
+    the one-view read takes grouped heads under the gather AND, since
+    PR 55, under the Pallas kernel (this family's recipe still asks for
+    the gather: it does not carry `pallas_read`)."""
     from flexflow_tpu.ops.op import ShapeError
 
     embed, heads, kw = attention_fields()
-    for bad in (dict(kv_kernel="pallas"), dict(paged_read_once=False)):
+
+    def build(**over):
         ff = FFModel(FFConfig(batch_size=2, num_devices=1))
         x = ff.create_tensor([2, 1, D.e], name="x")
+        return ff.multihead_attention(
+            x, x, x, embed, heads, name="op", decode_max_seq=16,
+            **{"kv_page_size": 4, "kv_num_blocks": 9, **kw, **over})
+
+    for bad in (dict(paged_read_once=False),
+                dict(kv_page_size=0, kv_num_blocks=0)):
         with pytest.raises(ShapeError, match="grouped-query heads"):
-            ff.multihead_attention(
-                x, x, x, embed, heads, name="op",
-                decode_max_seq=16, kv_page_size=4, kv_num_blocks=9,
-                **{**kw, **bad})
+            build(**bad)
+    assert build(kv_kernel="pallas").owner_op._kv_kernel == "pallas"
+    assert "pallas_read" not in holder().decoder_recipe.carries
 
 
 # -- 5. the ops that were adapted keep what GPT and Kimi lower -----------------------
