@@ -22,10 +22,31 @@ physical page, ``(1, page, h, d)``, whose last two dims equal the
 array's (the TPU rule: divisible by (8, 128) or equal to the full dim;
 a per-head ``(1, page, 1, d)`` block is refused for every h > 1).
 Under `--serving-tp` the shard_map'd local pool is
-``[nb, page, h/tp, d]`` and the same rule holds.  (Leaving the pool in
+``[nb, page, h/tp, d]`` and the same rule holds.  (Leaving THIS pool in
 HBM and copying pages by hand, with a trip count that follows the
 row's length, is refused by Mosaic for d = 64: a slice of a ref whose
 minor dim is padded to 128 lanes.)
+
+A HEAD-MAJOR pool ``[num_blocks, h, page, d]`` (a grouped layer's:
+`MultiHeadAttentionParams.kv_head_major`, laguna's d = 128) is read
+that way (`_head_major_kernel`, PR 56): grid ``(slots,)``, one program
+a row, the K and V pools passed whole with ``memory_space=pl.ANY``, and
+inside the program a ``fori_loop`` over the row's live pages N at a
+time (`pages_per_tile`: as many as keep the K and V tiles, two of each,
+within 4 MiB of VMEM; 32 at laguna's 32 KB a page).  A block there is
+one contiguous, lane-aligned slab ``[h, page, 128]`` (page = 16 is
+bf16's sublane tile), which Mosaic accepts as a copy's source; each of
+a tile's 2 N copies lands where its keys fall in the tile's buffer
+``[h, N * page, d]``, so a head's keys of the whole tile are ONE matrix
+and the fold (`_fold_head_major`: a product batched over the key/value
+heads, nothing computed for another head's pair) pays its mask, its
+rescale of the online softmax and its MXU round trips once a tile.
+Tile j + 1's copies are started before tile j is folded.  The trip
+count is ``ceil(live / N)``: no step exists for a column past a row's
+live pages, and a parked row walks its one scratch page.  The spare
+columns of a row's LAST tile repeat its last live block (their keys are
+masked by position; every byte the fold reads was written by a copy,
+so no product meets stale VMEM).
 
 The math stays in that layout and both products run on the MXU: a
 page ``[page, h, d]`` is, with no data movement, the matrix
@@ -70,7 +91,8 @@ Two entry points mirror the host-side twins (decoding.py):
 via ``key_pos <= pos + j``, ONE dispatch where the gather twin loops
 over positions; the k/v scatter stays in plain JAX, byte-identical to
 the oracle's.  Both carry the online softmax in f32 VMEM scratch over
-the kb grid axis.  On the CPU the kernel runs under ``interpret=True``
+the kb grid axis (the head-major walk: over its tiles).  On the CPU the
+kernel runs under ``interpret=True``
 (tests/test_paged_kernel.py); on TPU Mosaic always compiles it.
 """
 from __future__ import annotations
@@ -99,6 +121,8 @@ _ROW_PAGES = 3 * PAGES_PER_STEP
 #: leave room in the 16 MiB of VMEM a kernel may use without asking
 #: (cell 7's 20 pages of [16, 16, 128] bf16 are 5 MiB)
 _ROW_VMEM_BYTES = 6 << 20
+#: what the head-major walk's K and V tiles, two of each, may take of it
+_TILE_VMEM_BYTES = 4 << 20
 
 try:  # lazy-safe: CPU-only envs without pallas never touch the kernel
     from jax.experimental import pallas as pl
@@ -139,6 +163,14 @@ def pages_per_program(table_width: int, page_bytes: int, chunk: int) -> int:
             and 2 * table_width * page_bytes <= _ROW_VMEM_BYTES):
         return table_width
     return min(PAGES_PER_STEP, table_width)
+
+
+def pages_per_tile(page_bytes: int) -> int:
+    """Pages a tile of the head-major walk holds (`_head_major_kernel`),
+    from the launch's shapes alone: as many as keep the K and V tiles,
+    two of each in flight (`page_bytes` a K and V pair), within
+    `_TILE_VMEM_BYTES`."""
+    return max(1, _TILE_VMEM_BYTES // (2 * page_bytes))
 
 
 def _live_block_count(pos, chunk: int, page: int, table_width: int):
@@ -287,10 +319,11 @@ def _paged_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def _fold_head_major(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
+def _fold_head_major(q_ref, k, v, m_ref, l_ref, acc_ref, col, pos, *,
                      page: int, scale: float, chunk: int):
-    """`_fold_pages` for a HEAD-MAJOR pool: ks, vs are refs of a
-    `[1, h, page, d]` block each, q_ref `[1, h, G * chunk, d]` with a key/value head's G query
+    """`_fold_pages` for a HEAD-MAJOR pool: k, v are `[h, n * page, d]`,
+    the pages of table columns `col` .. `col + n - 1` a head, q_ref
+    `[1, h, G * chunk, d]` with a key/value head's G query
     heads side by side (row `g * chunk + t` is query head `j * G + g`,
     chunk token t).  A key/value head's queries against ITS keys alone,
     `[h, G * chunk, n * page]` scores by one product batched over the
@@ -298,8 +331,7 @@ def _fold_head_major(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
     and exponentiates every pair and masks `h - 1` of `h` away: at 8
     key/value heads under 48 query heads and a chunk of 32 that was
     36 ms a launch; PERF.md, PR 55)."""
-    h, rows = q_ref.shape[1:3]
-    cols = len(ks) * page
+    rows, cols = q_ref.shape[2], k.shape[1]
     # chunk token t attends key positions <= pos + t (`_fold_pages`)
     if chunk == 1:
         tok = 0
@@ -313,8 +345,6 @@ def _fold_head_major(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
     # every head in ONE batched product a stage (a loop over the heads
     # is a chain of small products, each waiting for the last: 1.0 us a
     # page at 8 heads and a chunk of 32; PERF.md, PR 55)
-    k, v = (jnp.concatenate([r[0] for r in refs], axis=1)
-            for refs in (ks, vs))  # [h, cols, d]
     heads = ((0,), (0,))
     s = _mxu(q_ref[0], k, ((2,), (2,)), heads) * scale  # [h, rows, cols]
     s = jnp.where(keep[None], s, _NEG_INF)
@@ -327,41 +357,57 @@ def _fold_head_major(q_ref, ks, vs, m_ref, l_ref, acc_ref, col, pos, *,
     m_ref[...] = m_new
 
 
-def _head_major_kernel(btab_ref, slen_ref, q_ref, *refs, page: int,
-                       scale: float, table_width: int, chunk: int,
-                       pages: int):
-    """`_paged_kernel` over a head-major pool (`_fold_head_major`)."""
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+def _head_major_kernel(btab_ref, slen_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
+                       page: int, scale: float, table_width: int,
+                       chunk: int, pages: int):
+    """One grid program = one row: walk the row's live pages `pages` at
+    a time (a tile).  The pools stay in HBM; a tile's pages are copied
+    by hand into `k_buf` / `v_buf` `[2, h, pages * page, d]`, each page
+    where its keys fall in the tile, so the fold reads a head's keys of
+    the whole tile as one matrix; tile j + 1's copies are in flight
+    while tile j is folded (`_fold_head_major`), and the trip count is
+    the row's length."""
     i = pl.program_id(0)
-    kb = pl.program_id(1)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     pos = slen_ref[i]
     live = _live_block_count(pos, chunk, page, table_width)
-    # as many pages a fold as keep the heads' scores, [h, G * chunk, n *
-    # page] f32, near the vector registers' 64 x 1,024
-    group = max(1, min(pages, 131072 // (
-        q_ref.shape[1] * q_ref.shape[2] * page)))
-    for p in range(0, pages, group):
-        col = kb * pages + p
+    tiles = pl.cdiv(live, pages)
 
-        @pl.when(col < live)
-        def _fold(p=p, col=col):
-            _fold_head_major(q_ref, k_refs[p:p + group],
-                             v_refs[p:p + group], m_ref, l_ref, acc_ref,
-                             col, pos, page=page, scale=scale, chunk=chunk)
+    def copies(tile, slot):
+        # a column past the row's live pages repeats its last live
+        # block (its keys are masked by position): every byte the fold
+        # reads was written by a copy, so no product meets stale VMEM
+        for p in range(pages):
+            block = btab_ref[i, jnp.minimum(tile * pages + p, live - 1)]
+            where = pl.ds(p * page, page)
+            yield pltpu.make_async_copy(
+                k_hbm.at[block], k_buf.at[slot, :, where], sems.at[0, slot])
+            yield pltpu.make_async_copy(
+                v_hbm.at[block], v_buf.at[slot, :, where], sems.at[1, slot])
 
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _write():
-        l = l_ref[...]
-        l_safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for copy in copies(0, 0):
+        copy.start()
+
+    def tile_step(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < tiles)
+        def _ahead():
+            for copy in copies(j + 1, 1 - slot):
+                copy.start()
+
+        for copy in copies(j, slot):
+            copy.wait()
+        _fold_head_major(q_ref, k_buf[slot], v_buf[slot], m_ref, l_ref,
+                         acc_ref, j * pages, pos, page=page, scale=scale,
+                         chunk=chunk)
+
+    jax.lax.fori_loop(0, tiles, tile_step, None)
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
 
 
 def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
@@ -383,12 +429,18 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
 
     `head_major`: the pools are `[num_blocks, h, page, d]` (a head's
     rows of a page side by side: `MultiHeadAttentionParams.
-    kv_head_major`), and the kernel folds one key/value head at a time
-    (`_fold_head_major`): what a grouped layer's pool takes.
+    kv_head_major`), and the kernel walks each row's live pages itself
+    and folds a key/value head's queries against its own keys
+    (`_head_major_kernel`): what a grouped layer's pool takes.
 
     Every shape and the grid are static in (b, s, table_width): how
     many tokens are live is DATA (`seq_lens`), so one program serves
     every mix of lengths.
+
+    `pages_per_step` (the tests and the probe name one) takes the place
+    of the launch's own choice from its shapes: pages a grid program
+    folds (`pages_per_program`), pages a tile of the head-major walk
+    (`pages_per_tile`).
 
     `interpret` defaults from the backend: the Mosaic-compiled kernel
     on TPU, the Pallas interpreter on CPU (the parity-test vehicle).
@@ -410,8 +462,9 @@ def paged_attention(qh, k_pool, v_pool, block_table, seq_lens,
             f"pool's {h} key/value heads")
     table_width = block_table.shape[1]
     if pages_per_step is None:  # (the tests and the probe name one)
-        pages_per_step = pages_per_program(
-            table_width, page * h * (dk + dv) * k_pool.dtype.itemsize, s)
+        page_bytes = page * h * (dk + dv) * k_pool.dtype.itemsize
+        pages_per_step = pages_per_tile(page_bytes) if head_major \
+            else pages_per_program(table_width, page_bytes, s)
     pages = max(1, min(int(pages_per_step), table_width))
     return _paged_launch(qh, k_pool, v_pool, block_table, seq_lens,
                          scale=float(scale), interpret=bool(interpret),
@@ -436,6 +489,46 @@ def _paged_launch(qh, k_pool, v_pool, block_table, seq_lens, *,
     block_table = block_table.astype(jnp.int32)
     seq_lens = seq_lens.reshape(b).astype(jnp.int32)
 
+    if head_major:
+        # a key/value head's G query heads side by side: [b, h, G * s, d]
+        g = hq // h
+        rows = g * s
+        qm = qh.reshape(b, s, h, g, dk).transpose(0, 2, 3, 1, 4).reshape(
+            b, h, rows, dk)
+
+        def row(i, btab, slen):
+            return i, 0, 0, 0
+
+        # a copy is ~11 scalar bundles and Mosaic's bounds checks of its
+        # two ends 14 more, and the walk issues 2 N of them a tile ahead
+        # of a fold it cannot overlap: 0.97 ms a launch without them
+        # against 1.17 (PERF.md, PR 56).  The checks are off and the
+        # table is held to the pool HERE, once a dispatch (XLA shares it
+        # between the layers of a pass)
+        block_table = jnp.clip(block_table, 0, k_pool.shape[0] - 1)
+        out = pl.pallas_call(
+            functools.partial(_head_major_kernel, page=page, scale=scale,
+                              table_width=table_width, chunk=s, pages=pages),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b,),
+                in_specs=[pl.BlockSpec((1, h, rows, dk), row),
+                          pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, h, rows, dv), row),
+                scratch_shapes=[
+                    pltpu.VMEM((2, h, pages * page, dk), k_pool.dtype),
+                    pltpu.VMEM((2, h, pages * page, dv), v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((h, rows, 1), jnp.float32),
+                    pltpu.VMEM((h, rows, 1), jnp.float32),
+                    pltpu.VMEM((h, rows, dv), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((b, h, rows, dv), qh.dtype),
+            compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
+            interpret=interpret, name="paged_attention_head_major",
+        )(block_table, seq_lens, qm, k_pool, v_pool)
+        return out.reshape(b, h, g, s, dv).transpose(0, 3, 1, 2, 4).reshape(
+            b, s, hq, dv)
+
     def q_map(i, kb, btab, slen):
         return i, 0, 0, 0
 
@@ -455,34 +548,6 @@ def _paged_launch(qh, k_pool, v_pool, block_table, seq_lens, *,
         def index(i, kb, btab, slen):
             return btab[i, kb * pages + p], 0, 0, 0
         return index
-
-    if head_major:
-        # a key/value head's G query heads side by side: [b, h, G * s, d]
-        g = hq // h
-        rows = g * s
-        qm = qh.reshape(b, s, h, g, dk).transpose(0, 2, 3, 1, 4).reshape(
-            b, h, rows, dk)
-        out = pl.pallas_call(
-            functools.partial(_head_major_kernel, page=page, scale=scale,
-                              table_width=table_width, chunk=s, pages=pages),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=(b, steps),
-                in_specs=[pl.BlockSpec((1, h, rows, dk), q_map)]
-                + [pl.BlockSpec((1, h, page, dk), kv_map(p))
-                   for p in range(pages)]
-                + [pl.BlockSpec((1, h, page, dv), kv_map(p))
-                   for p in range(pages)],
-                out_specs=pl.BlockSpec((1, h, rows, dv), q_map),
-                scratch_shapes=[
-                    pltpu.VMEM((h, rows, 1), jnp.float32),
-                    pltpu.VMEM((h, rows, 1), jnp.float32),
-                    pltpu.VMEM((h, rows, dv), jnp.float32)]),
-            out_shape=jax.ShapeDtypeStruct((b, h, rows, dv), qh.dtype),
-            interpret=interpret, name="paged_attention_head_major",
-        )(block_table, seq_lens, qm, *([k_pool] * pages),
-          *([v_pool] * pages))
-        return out.reshape(b, h, g, s, dv).transpose(0, 3, 1, 2, 4).reshape(
-            b, s, hq, dv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -61,12 +61,13 @@ def test_logits_equal_the_reference(seeded, batch, reference):
 def test_first_step_gradient_equals_the_reference_by_group(
         remat, seeded, batch, reference):
     cfg = dict(CFG, assumed=dict(CFG["assumed"], remat=remat))
-    before = len(trace.spans())
+    before = trace.next_span_id()
     ff = compiled(fam, cfg, seeded["program"], B, S)
     first_step_equals_the_reference(fam, CFG, ff, batch, reference,
                                     GROUP_TOL)
     # the step the program built says which chunk its cores took
-    built = [r for r in trace.spans()[before:] if r.name == "build_step_fns"]
+    built = [r for r in trace.spans()
+             if r.span_id > before and r.name == "build_step_fns"]
     assert built and built[-1].args["kda_chunk_tokens"] == 16
     assert built[-1].args["kda_kernel_ops"] == 0  # the CPU's plan
     assert ff.executor.remat_segments == (8 if remat else 0) or remat
@@ -82,11 +83,11 @@ def test_build_step_fns_counts_the_chunk_kernels_and_their_chunk(
         CFG["linear_attn_config"], head_dim=128, num_heads=2))
 
     def built():
-        before = len(trace.spans())
+        before = trace.next_span_id()
         ff = fam.build_model(cfg, 1, 64, 1)
         fam.compile_model(ff, cfg, jax.devices()[:1])
-        return [r.args for r in trace.spans()[before:]
-                if r.name == "build_step_fns"][-1]
+        return [r.args for r in trace.spans() if r.span_id > before
+                and r.name == "build_step_fns"][-1]
 
     args = built()
     assert (args["kda_chunk_tokens"], args["kda_kernel_ops"]) == (64, 0)

@@ -325,7 +325,7 @@ def serve_toy(paged_kernel):
         prefill_chunk=4, paged_kernel=paged_kernel,
         devices=jax.devices()[:1])
     rec = Recorder(sched, lambda model, i: (model.exit_last[i].copy(),))
-    since = len(trace.spans())
+    since = trace.next_span_id()
     try:
         rng = np.random.default_rng(5)
         prompts = [rng.integers(1, D.v, n).tolist() for n in (16, 15, 9, 5)]
@@ -340,7 +340,8 @@ def serve_toy(paged_kernel):
         stats = sched.stats()
     finally:
         sched.close(10)
-    return rec.rows, handles, stats, trace.spans()[since:]
+    return rec.rows, handles, stats, [
+        r for r in trace.spans() if r.span_id > since]
 
 
 @pytest.fixture(scope="module")
@@ -449,7 +450,7 @@ def test_front_stats_and_the_twin_span_say_the_loop():
     from flexflow_tpu.obs import trace
     from flexflow_tpu.serving import build_front
 
-    since = len(trace.spans())
+    since = trace.next_span_id()
     front = build_front(holder())
     try:
         h = front.generate_async(list(range(1, 11)), 3, 0.0)
@@ -464,8 +465,8 @@ def test_front_stats_and_the_twin_span_say_the_loop():
     assert loop["loop_steps"] == D.T and loop["decode_dispatches"] == 2
     assert loop["exit_rows"] == 2 + loop["prefill_dispatches"] >= 3
     assert len(loop["exit_mass"]) == D.T
-    (twin,) = [r for r in trace.spans()[since:]
-               if r.name == "serve.build_twin"]
+    (twin,) = [r for r in trace.spans()
+               if r.span_id > since and r.name == "serve.build_twin"]
     assert twin.args["loop_regions"] == 1
     assert twin.args["loop_steps"] == D.T
     assert twin.args["loop_ops"] == 8 * D.L + 1

@@ -183,6 +183,91 @@ def test_grouped_heads_match_the_one_view_gather(group, chunk, head_major):
     assert not np.allclose(np.asarray(got)[1, 0, 0], np.asarray(got)[1, 0, 1])
 
 
+@pytest.fixture(scope="module")
+def grouped_oracle():
+    """`_gather_oracle` over a head-major pool under G query heads a
+    key/value head, jitted: query head g reads key/value head g // G."""
+    @jax.jit
+    def oracle(qh, kp, vp, btab, slen, scale):
+        group = qh.shape[2] // kp.shape[1]
+        kp, vp = (jnp.repeat(x.transpose(0, 2, 1, 3), group, axis=2)
+                  for x in (kp, vp))
+        return _gather_oracle(qh, kp, vp, btab, slen, scale)
+    return oracle
+
+
+def _walk_case(group, chunk, tile, page=8, tw=9, kv_heads=8, d=16):
+    """A head-major launch whose rows hold, in live pages, 1 on scratch
+    (parked: zero table, position 0), 1, N - 1, N, N + 1 and the whole
+    table for a tile of N = `tile` pages, each ending on its page's last
+    slot; and two rows whose chunk's LAST token alone stands on a new
+    page (the first of a tile, and the one after)."""
+    rng = np.random.RandomState(97 * group + 7 * chunk + tile)
+    live = [1, 1, max(tile - 1, 1), tile, tile + 1, tw]
+    slen = [0] + [n * page - chunk for n in live[1:]]
+    slen += [n * page - chunk + 1 for n in (tile, tile + 1)]
+    slen = np.maximum(np.asarray(slen, np.int32), 0)
+    b = len(slen)
+    nb = 1 + b * tw
+    kp, vp = (jnp.asarray(rng.randn(nb, kv_heads, page, d), jnp.float32)
+              for _ in "kv")
+    qh = jnp.asarray(rng.randn(b, chunk, kv_heads * group, d), jnp.float32)
+    btab = rng.permutation(np.arange(1, nb)).reshape(b, tw).astype(np.int32)
+    btab[0] = 0
+    want = live + [tile + 1, tile + 2]
+    np.testing.assert_array_equal(pk._live_block_count(
+        slen, chunk, page, tw), want)
+    return qh, kp, vp, jnp.asarray(btab), jnp.asarray(slen)
+
+
+@pytest.mark.parametrize("tile", [2, 4])
+@pytest.mark.parametrize("chunk", [1, 5])
+@pytest.mark.parametrize("group", [6, 8])
+def test_head_major_walk_follows_each_rows_length(group, chunk, tile,
+                                                  grouped_oracle):
+    """The head-major launch walks a row's live pages N at a time
+    inside the kernel, a trip count a row: rows of every length around
+    a tile's edge in ONE launch read what the gather reads, float32 to
+    2e-6, with 8 key/value heads under 48 and 64 query heads' ratios
+    and N forced to 2 and 4 (a column past a row's live pages repeats
+    its last live block and is masked by position)."""
+    qh, kp, vp, btab, slen = _walk_case(group, chunk, tile)
+    scale = 0.25
+    got = pk.paged_attention(qh, kp, vp, btab, slen, scale, interpret=True,
+                             pages_per_step=tile, head_major=True)
+    want = grouped_oracle(qh, kp, vp, btab, slen, scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_head_major_walk_reads_no_page_past_a_rows_length():
+    """What stands in the pool past a row's live pages, NaN included,
+    never reaches its context: the walk's trip count is the row's
+    length, and its last tile's spare columns repeat a live block."""
+    qh, kp, vp, btab, slen = _walk_case(6, 5, 4)
+    want = pk.paged_attention(qh, kp, vp, btab, slen, 0.25, interpret=True,
+                              pages_per_step=4, head_major=True)
+    live = np.asarray(pk._live_block_count(np.asarray(slen), 5, 8, 9))
+    dead = np.concatenate([np.asarray(btab)[i, n:]
+                           for i, n in enumerate(live) if i])
+    spoiled = [x.at[dead].set(jnp.nan) for x in (kp, vp)]
+    got = pk.paged_attention(qh, *spoiled, btab, slen, 0.25, interpret=True,
+                             pages_per_step=4, head_major=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("page_bytes,want", [
+    (2 * 16 * 8 * 128 * 2, 32),  # laguna-xs2-ep8-serve's pool: 512 keys
+    (2 * 16 * 8 * 128 * 4, 16),  # the same pool in float32
+    (2 * 8 * 8 * 16 * 4, 256),   # the tests': more than their tables hold
+    (8 << 20, 1)])
+def test_pages_a_tile_follow_the_page(page_bytes, want):
+    """N is a function of the launch's shapes: the K and V tiles, two
+    of each in flight, within 4 MiB of VMEM."""
+    assert pk.pages_per_tile(page_bytes) == want
+
+
 def test_query_heads_must_be_a_multiple_of_the_pools():
     rng = np.random.RandomState(2)
     _, kp, vp, btab, slen = _random_case(rng, b=2, s=1, h=2, d=8, page=4,

@@ -246,6 +246,36 @@ def test_pallas_entry_points_compile_for_v5e(v5e, v5e_chip):
         _lower(fn, args).compile()
 
 
+#: benchmarks/configs/laguna-xs2-ep8-serve.json: a head-major pool of 8
+#: key/value heads x 128 a full layer, 32 slots, table width 1,024
+MIXED_CELL_GEOMETRY = (32, 16, 8, 128, 1024, 16385)
+
+
+@pytest.mark.parametrize("chunk", [1, 16])
+@pytest.mark.parametrize("heads", [48, 64])
+def test_head_major_walk_compiles_for_v5e(heads, chunk, v5e_chip):
+    """The head-major read at the mixed-context cell's shapes: Mosaic
+    accepts the hand-issued copies from the HBM-resident pool (a block
+    `[8, 16, 128]` bf16 is one lane-aligned slab) and the tiles the
+    launch chooses from its shapes fit the kernel's VMEM, under the
+    decode step's one query a row and the pass's chunk, at both of the
+    configuration's head ratios."""
+    slots, page, h, d, tw, nb = MIXED_CELL_GEOMETRY
+
+    def S(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def read(q, k, v, bt, sl):
+        return pk.paged_attention(q, k, v, bt, sl, d ** -0.5,
+                                  interpret=False, head_major=True)
+
+    lowered = _lower(read, (S(slots, chunk, heads, d), S(nb, h, page, d),
+                            S(nb, h, page, d), S(slots, tw, dtype=jnp.int32),
+                            S(slots, dtype=jnp.int32)))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
 #: a routed layer of the two training cells: (usual slots, hidden,
 #: expert width, held, rows a group expects)
 GROUPED_LAYERS = {"cell6_lfm2": (12288, 2048, 1792, 8, 1024.0),
