@@ -488,15 +488,18 @@ class FFModel:
 
     def mla_attention(self, input, positions, params, name=None,
                       decode_max_seq: int = 0, kv_page_size: int = 0,
-                      kv_num_blocks: int = 0, kv_kernel: str = "gather"):
+                      kv_num_blocks: int = 0, kv_kernel: str = "gather",
+                      picks=None):
         """Multi-head latent attention (ops/mla.py): `params` is an
         `MLAParams`; the cache keywords are `multihead_attention`'s,
         and build the paged LATENT cache.  `positions` is None for an
-        op that rotates nothing (`params.nope`)."""
+        op that rotates nothing (`params.nope`).  With an indexer
+        (`params.indexer`) a "full" op returns (output, picks) and a
+        "shared" one takes such `picks`."""
         from .ops.mla import MLAttention
 
         return self._add(MLAttention(
-            params, [input] if positions is None else [input, positions],
+            params, [t for t in (input, positions, picks) if t is not None],
             name=self._name("mla_attention", name),
             decode_max_seq=decode_max_seq, kv_page_size=kv_page_size,
             kv_num_blocks=kv_num_blocks, kv_kernel=kv_kernel))

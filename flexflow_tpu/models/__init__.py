@@ -17,4 +17,4 @@ SERVED_BUILDERS = (
     "transformer.build_gpt", "kimi_k2.build_kimi_k2",
     "qwen3_next.build_qwen3_next", "ouro.build_ouro",
     "longcat_flash.build_longcat_flash", "evabyte.build_evabyte",
-    "laguna.build_laguna")
+    "laguna.build_laguna", "glm_dsa.build_glm_dsa")

@@ -51,10 +51,15 @@ ROUTED_PARTS = ("route", "dispatch", "products", "shared", "combine", "zero")
 #: `core` the delta rule, `norm_gate` the gated head norm) and of a
 #: short convolution; of `EvaAttention`: `summarise` the pooling of the
 #: chunks a step completes, `state_write` the step's keys and values
-#: into the window
+#: into the window; of `MLAttention` with an indexer: `index_proj` the
+#: indexer's queries, key and head weights, `index_scores` its scores
+#: against the row's index keys, `topk` the picks, `selected_read` the
+#: picked latents' gather and the attention over them (`state_write`
+#: there: the step's latents and index keys into their pools)
 MIXER_PARTS = ("proj", "core", "paged_read", "conv", "recurrence", "out",
                "gate", "norm_gate", "summarise", "state_write",
-               "window_read")
+               "window_read", "index_proj", "index_scores", "topk",
+               "selected_read")
 PARTS = ROUTED_PARTS + MIXER_PARTS + (CAST_WEIGHTS,)
 #: `parse`'s part for an instruction the compiler made from one of the
 #: step program's ARGUMENTS (the layout copy of a weight or of a paged

@@ -124,6 +124,9 @@ class SupervisedDecodeModel:
         self.swa = getattr(model, "swa", None)
         self.swa_state_bytes = getattr(model, "swa_state_bytes", 0)
         self.swa_rows = getattr(model, "swa_rows", None)
+        # layers that read selected keys, likewise
+        self.dsa = getattr(model, "dsa", None)
+        self.dsa_rows = getattr(model, "dsa_rows", None)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)

@@ -436,6 +436,23 @@ class PagedKVDecodeModel:
                 "reads, and a longer one would overwrite keys its first "
                 "queries still see; build the model with that "
                 "prefill_chunk")
+        # latent-attention layers that read a SELECTION of their keys
+        # (ops/mla.py "Selected keys"): `dsa` is their geometry (None
+        # without one, or where selection is the identity at this
+        # table's width); the `full` ones among them keep a pool of
+        # index keys beside their latents, found by `cache_entries`
+        dsa_ops = [op for op in self.ffd.operators.topo_order()
+                   if op.op_type == OperatorType.MLA_ATTENTION
+                   and op.reads_selection()]
+        self.dsa = ({"topk": dsa_ops[0].params.index_topk,
+                     "layers": len(dsa_ops),
+                     "full_layers": sum(op.params.indexer == "full"
+                                        for op in dsa_ops)}
+                    if dsa_ops else None)
+        if self.dsa:
+            from ..ops.mla import selection_counts
+
+            self._selection_counts = selection_counts
         self.eva = ({"window": eva_ops[0].params.window_size,
                      "chunk": eva_ops[0].params.chunk_size,
                      "store_rows": eva_ops[0].store_rows,
@@ -531,6 +548,28 @@ class PagedKVDecodeModel:
         return {"swa_rows_live": n * self._window_rows_live(
                     self.swa["window"], positions, counts),
                 "swa_rows_read": n * self.batch_slots * self.swa["ring"]}
+
+    def dsa_rows(self, positions, counts) -> Optional[Dict[str, int]]:
+        """The selection's args of a dispatch that advances row i over
+        `positions[i] .. + counts[i] - 1` (`ops/mla.py
+        selection_counts`, a layer's): `dsa_keys_live` and
+        `dsa_keys_selected` a layer, `dsa_keys_scored` (the indexers
+        score every live key: `keys_live` x the full layers),
+        `dsa_rows_past_topk`, and `index_blocks_live`, the advancing
+        rows' blocks of index keys, summed over the full layers.  Host
+        arithmetic on host-owned lengths, no fetch.  None without such
+        a layer."""
+        if self.dsa is None:
+            return None
+        one = self._selection_counts(self.dsa["topk"], positions, counts)
+        n = np.asarray(counts, np.int64)
+        blocks = -(-(np.asarray(positions, np.int64) + n) // self.page_size)
+        full = self.dsa["full_layers"]
+        return {"dsa_keys_live": one["keys_live"],
+                "dsa_keys_selected": one["keys_selected"],
+                "dsa_keys_scored": full * one["keys_live"],
+                "dsa_rows_past_topk": one["rows_past_topk"],
+                "index_blocks_live": full * int(blocks[n > 0].sum())}
 
     def _row_tokens(self, row_tokens, one_pass: bool = False,
                     take_prev=None) -> tuple:
@@ -1109,6 +1148,12 @@ class ContinuousScheduler:
         self.swa_totals: Optional[Dict[str, int]] = (
             dict.fromkeys(("dispatches", "swa_rows_live", "swa_rows_read"),
                           0) if self._swa_rows is not None else None)
+        # layers that read selected keys: the selection's args
+        # (`model.dsa_rows`)
+        self._dsa_rows = (getattr(model, "dsa_rows", None)
+                          if getattr(model, "dsa", None) else None)
+        self.dsa_totals: Optional[Dict[str, int]] = (
+            {"dispatches": 0} if self._dsa_rows is not None else None)
         recurrent = self._rstate and (
             (self._eva_rows is None and self._swa_rows is None)
             or getattr(model, "rstate_bytes", 0) > 0)
@@ -1520,6 +1565,8 @@ class ContinuousScheduler:
             **({"swa": dict(self.swa_totals, **self.model.swa,
                             state_bytes=int(self.model.swa_state_bytes))}
                if self.swa_totals is not None else {}),
+            **({"dsa": dict(self.dsa_totals, **self.model.dsa)}
+               if self.dsa_totals is not None else {}),
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1968,6 +2015,16 @@ class ContinuousScheduler:
         for k, v in rows.items():
             t[k] += v
 
+    def _note_dsa(self, dispatch, positions, counts) -> None:
+        """The selection's args of a dispatch span (`model.dsa_rows`)
+        and their sums over both kinds of dispatch."""
+        rows = self._dsa_rows(positions, counts)
+        dispatch.set(**rows)
+        t = self.dsa_totals
+        t["dispatches"] += 1
+        for k, v in rows.items():
+            t[k] = t.get(k, 0) + v
+
     def _note_moe(self, dispatch, program: str) -> None:
         """The `moe_*` args of a dispatch whose logits were fetched
         (`model.moe_last`: the routed layers' counts of that dispatch,
@@ -2110,6 +2167,8 @@ class ContinuousScheduler:
                         self._note_eva(dispatch, "prefill", slen, fed)
                     if self._swa_rows is not None:
                         self._note_swa(dispatch, slen, fed)
+                    if self._dsa_rows is not None:
+                        self._note_dsa(dispatch, slen, fed)
                     # a plan row's prefix is read once a pass: by the
                     # scan at each of its C positions, by the one-pass
                     # program once, up to the chunk's last; the scan's
@@ -2522,6 +2581,9 @@ class ContinuousScheduler:
                     self._note_eva(dispatch, "decode", self._slens, alive[0])
                 if self._swa_rows is not None:
                     self._note_swa(dispatch, self._slens, alive[0])
+                if self._dsa_rows is not None:
+                    self._note_dsa(dispatch, self._slens,
+                                   [live is not None for live in slots])
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
                 if why is None:

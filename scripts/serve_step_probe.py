@@ -5,7 +5,7 @@ harness says nothing).
 
     chiprun --chips 1 -- python3 scripts/serve_step_probe.py \
         --workload <cell> [--calls 10] [--chunks 8,64,128] \
-        [--position 2048] [--scopes]
+        [--position 2048] [--scopes] [--selected-read gather,view]
 
 Builds the cell's server as the harness does (`build_server`, the
 seed's weights, `PagedKVDecodeModel` with the front's arguments), gives
@@ -20,11 +20,19 @@ twin and its state are built anew), a JSON line each: the readings a
 configuration's `prefill_chunk` is chosen from.  `--position` puts the
 rows at another length than half the table's; `--scopes` also traces
 three calls of each program and prints where their device time went by
-the program's names (`benchmarks/device_scopes.py`).  A serving family
-only."""
+the program's names (`benchmarks/device_scopes.py`).  For a family
+whose latent attention reads picked keys (`ops/mla.py`),
+`--selected-read` times each chunk once a formulation of that read,
+forced on both step programs: `gather` (`pool[table[idx // page], idx %
+page]`, a `[b, s, k, width]` gather), `view` (the row's view gathered
+once over the table's width and the picks as a mask on dense scores),
+`plan` (the default: what the op's own rule takes for each step
+length).  A serving
+family only."""
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -52,6 +60,9 @@ def main() -> int:
                     help="every row's length (default: half the table)")
     ap.add_argument("--scopes", action="store_true",
                     help="trace three calls a program: the by-scope table")
+    ap.add_argument("--selected-read", default="plan",
+                    help="formulations of the selected read to time in "
+                         "turn: plan (the op's rule), gather, view")
     args = ap.parse_args()
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
@@ -67,11 +78,42 @@ def main() -> int:
     out["weight_bytes"] = int(sum(x.nbytes for x in leaves))
     chunks = ([int(x) for x in args.chunks.split(",")] if args.chunks
               else [ff.config.prefill_chunk])
-    for chunk in chunks:
-        probe(ff, chunk, dict(out), args.calls, t0, args.position,
-              args.scopes)
-        t0 = time.monotonic()
+    for read in args.selected_read.split(","):
+        for chunk in chunks:
+            try:
+                with selected_read(read):
+                    probe(ff, chunk, dict(out, selected_read=read),
+                          args.calls, t0, args.position, args.scopes)
+            except Exception as e:  # a formulation that does not fit
+                if read == "plan":
+                    raise
+                print(json.dumps({"selected_read": read,
+                                  "prefill_chunk": chunk,
+                                  "failed": f"{type(e).__name__}: "
+                                            f"{str(e)[:300]}"}), flush=True)
+            t0 = time.monotonic()
     return 0
+
+
+@contextlib.contextmanager
+def selected_read(formulation: str):
+    """`MLAttention.selected_plan` answering `formulation` ("gather" or
+    "view") for every step length while the block runs, "plan" the op's
+    own rule: both formulations of the selected read are the op's, the
+    probe's readings are the constants of its rule."""
+    from flexflow_tpu.ops.mla import MLAttention
+
+    if formulation == "plan":
+        yield
+        return
+    if formulation not in ("gather", "view"):
+        raise SystemExit(f"--selected-read: no formulation {formulation!r}")
+    own = MLAttention.selected_plan
+    MLAttention.selected_plan = lambda self, s, n: formulation
+    try:
+        yield
+    finally:
+        MLAttention.selected_plan = own
 
 
 def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float,

@@ -390,18 +390,40 @@ def test_gpt_scheduler_keeps_the_scanned_prefill_program():
 
 
 # -- 3. the share test -----------------------------------------------------------
-def test_every_share_of_an_expert_layer_adds_up_to_the_uncut_layer():
+def _glm_dsa_share():
+    """The same test on `glm_dsa`'s toy (16 experts in shares of 4):
+    its reference walks blocks of a padded sequence."""
+    from benchmarks.families import glm_dsa as glm
+
+    cfg = config("toy-glm52.json")
+
+    def experts(row, key, layer, d, q, held):
+        rows = jnp.zeros((d.p, d.e)).at[:len(row)].set(row)
+        return tuple(part[:len(row)] for part in glm.experts(
+            rows, len(row), key, layer, d, q, held=held))
+
+    return glm, cfg, experts
+
+
+SHARES = {"kimi_k2": lambda: (fam, CFG, fam.experts),
+          "glm_dsa": _glm_dsa_share}
+
+
+@pytest.mark.parametrize("family", sorted(SHARES))
+def test_every_share_of_an_expert_layer_adds_up_to_the_uncut_layer(family):
     """The routed parts that all `total / held` shares give, with the
     shared expert counted once, are the uncut reference's whole layer:
     through the PROGRAM's op for each share, against the reference
     given every expert."""
+    fam, CFG, experts = SHARES[family]()
+    D = fam.dims(CFG)
     x = np.asarray(jax.random.normal(jax.random.key(7), (2, 12, D.e)))
     pos = np.zeros((2, 12), np.int32)
     with jax.default_matmul_precision("highest"):
-        whole = np.stack([sum(fam.experts(
+        whole = np.stack([sum(experts(
             jnp.asarray(row), KEY, 1, D, lambda v: v, held=(0, D.total)))
             for row in x])
-        shared = np.stack([fam.experts(
+        shared = np.stack([experts(
             jnp.asarray(row), KEY, 1, D, lambda v: v, held=(0, 0))[1]
             for row in x])
     total = np.zeros_like(whole)
