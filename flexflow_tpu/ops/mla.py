@@ -76,13 +76,20 @@ cache cover both (`k^I` is a function of the prefix alone); a step
 writes ALL its tokens' latents and index keys before any read, scores
 its queries against the row's index keys in blocks of heads (no
 `[b, s, heads, n]` float32 tensor exists), takes `lax.top_k`, and every
-op with picks reads them one of two ways, by the step's shapes
-(`selected_plan`): BY SELECTION, `pool[block_table[i, idx // page], idx
-% page]`: `[b, s, index_topk, .]`, not the table's width (the decode
-step, and every step under a table long enough); or the row's view
-gathered once over the table's width with the picks as a mask on dense
-scores (a prefill chunk under a table of a few times `index_topk`: a
-token row is 1.3 kB and the gather moves rows one by one).  Such an
+op with picks reads them one of three ways, by the step's shapes and
+the backend (`selected_plan`): IN PLACE on a TPU (`ops/pallas/
+selected_attention.py`: a kernel walks the row's LIVE pages of the pool
+once for the chunk's `s x heads` query rows, the picks a one-byte mask
+`[b, s, n]` inside its fold, float32 scores that never leave VMEM, a
+trip count that is the row's length; every step under a table of up to
+~24 k positions, a chunk of 16 up to ~64 k); BY SELECTION,
+`pool[block_table[i, idx // page], idx % page]`: `[b, s, index_topk,
+.]`, not the table's width (the CPU tier's decode step, and every step
+under a table long enough); or the
+row's view gathered once over the table's width with the picks as a
+mask on dense scores (the CPU tier's prefill chunk under a table of a
+few times `index_topk`, and the walk's parity oracle: a token row is
+1.3 kB and the gather moves rows one by one).  Such an
 op's pool rows are padded to whole lane tiles (`pool_width`).  Where the
 keys in reach are no more than `index_topk` (`decode_max_seq`, or
 stateless the sequence length) selection is the identity BY SHAPE: the
@@ -628,37 +635,63 @@ class MLAttention(Op):
                     .reshape(b, n, -1).astype(x.dtype)
             picks = self._index_picks(index[0], index[2], keys, at)
         with scope("selected_read"):
-            attend = (self._attend_selected
-                      if self.selected_plan(s, n) == "gather"
-                      else self._attend_masked_view)
-            ctx = attend(q_nope, q_rope, wkv_b, pool, btab, picks)
+            plan = self.selected_plan(s, n)
+            if plan == "walk":
+                ctx = self._attend_walk(q_nope, q_rope, wkv_b, pool, btab,
+                                        slen, picks)
+            else:
+                attend = (self._attend_selected if plan == "gather"
+                          else self._attend_masked_view)
+                ctx = attend(q_nope, q_rope, wkv_b, pool, btab, picks)
         with scope("out"):
             out = jnp.einsum("bshd,hde->bse", ctx, wo).astype(x.dtype)
         return ([out] + ([picks] if index is not None else [])
                 + [pool, *index_pool, btab, slen])
 
-    #: what the two formulations of the read cost a layer on the chip,
-    #: in nanoseconds (`scripts/serve_step_probe.py --selected-read`,
-    #: v5e, 32 rows at position 6,000 under a table of 12,800; PERF.md
-    #: section 6, PR 57): a picked key gathered and attended; a key of
-    #: the table's width gathered into the row's view; a (query, key)
-    #: pair of the dense masked scores over that view
+    #: what the three formulations of the read cost a layer on the chip,
+    #: in nanoseconds (v5e, 32 rows under a table of 12,800; PERF.md
+    #: section 6).  `scripts/serve_step_probe.py --selected-read
+    #: gather,view`, PR 57, both step programs: a picked key gathered and
+    #: attended; a key of the table's width gathered into the row's
+    #: view; a (query, key) pair of the dense masked scores over that
+    #: view.  `scripts/selected_read_probe.py --forms walk`, PR 58, the
+    #: kernel's launch alone at chunks of 16 and 1, rows from parked to
+    #: the table's width: a key of a row's live pages walked (copied, its
+    #: value row cleared, its 64 heads' scores against one query), and a
+    #: further (query, key) pair of the fold (a tile of 512 keys under
+    #: 1,024 query rows in 7.6 us: 159 TFLOP/s); `serve_step_probe.py
+    #: --selected-read plan,walk` read the decode step at 28.6 ms
+    #: gathering and 25.0 walking
     GATHER_NS_A_PICK, VIEW_NS_A_KEY, DENSE_NS_A_PAIR = 29.0, 4.3, 1.5
+    WALK_NS_A_KEY, WALK_NS_A_PAIR = 1.7, 0.82
 
     def selected_plan(self, s: int, n: int) -> str:
-        """"gather" or "view": how a step of `s` tokens a row reads its
-        picked keys under a table of `n` positions, from the shapes
-        alone.  The gather moves `s x index_topk` token rows a row of
-        the batch, one by one (1.3 kB each: 19 ns a row in XLA's gather,
-        whatever the bandwidth); the view moves the table's `n` rows
-        page by page ONCE and pays a masked dense product for every
-        pair.  At 12,800 positions and 2,048 picks the decode step
-        gathers and a chunk of 2 or more takes the view; from ~34 k
-        positions a chunk of 16 gathers too."""
+        """"walk", "gather" or "view": how a step of `s` tokens a row
+        reads its picked keys under a table of `n` positions, from the
+        shapes and the backend alone.  The gather moves `s x index_topk`
+        token rows a row of the batch, one by one (1.3 kB each: 19 ns a
+        row in XLA's gather, whatever the bandwidth); the view moves the
+        table's `n` rows page by page ONCE and pays a masked dense
+        product for every pair; the walk (a TPU's: `ops/pallas/
+        selected_attention.py`) copies the row's LIVE pages once and
+        folds them under the picks' mask, costed here at the table's
+        width, which is all a shape says of a row's length.  On the TPU
+        at 12,800 positions and 2,048 picks every step walks (a chunk
+        of 16: 2.4 ms a layer at the cell's mix of lengths, where the
+        view took 15); the decode step gathers again from ~24 k
+        positions, a chunk of 16 from ~64 k.  Elsewhere (the CPU tier)
+        the decode step gathers and a chunk under a short table takes
+        the view."""
         k = self.params.index_topk
-        gather = self.GATHER_NS_A_PICK * s * k
-        view = n * (self.VIEW_NS_A_KEY + self.DENSE_NS_A_PAIR * s)
-        return "gather" if gather <= view else "view"
+        costs = {"gather": self.GATHER_NS_A_PICK * s * k,
+                 "view": n * (self.VIEW_NS_A_KEY + self.DENSE_NS_A_PAIR * s)}
+        if jax.default_backend() == "tpu":
+            from .pallas.selected_attention import walk_fits
+
+            if walk_fits(s, self._kv_page_size, self.pool_width()):
+                costs["walk"] = n * (self.WALK_NS_A_KEY
+                                     + self.WALK_NS_A_PAIR * s)
+        return min(costs, key=costs.get)
 
     def _latent_queries(self, q_nope, q_rope, wkv_b, width: int):
         """The queries taken into the latent space, `[b, s, h, width]`:
@@ -671,28 +704,53 @@ class MLAttention(Op):
                         q_rope.dtype)
         return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
 
+    def _picks_mask(self, picks, n: int, dtype):
+        """`picks [b, s, k]` (positions, `-1` = none) as a mask `[b, s,
+        n]` bool over the table's positions.  A product, not a scatter
+        (1 M scalar updates took 17 ms a `[32, 16, 2048]` set of picks):
+        a position is `hi x 128 + lo`, and `one_hot(hi)^T one_hot(lo)`
+        summed over a query's picks is 1 exactly where a pick stands
+        (`-1` is no row of either).  The layers that share a set of
+        picks share the mask: one computation of one tensor, which XLA
+        keeps once."""
+        b, s, _ = picks.shape
+        lanes = 128
+        hi = jax.nn.one_hot(picks // lanes, -(-n // lanes), dtype=dtype)
+        lo = jax.nn.one_hot(jnp.where(picks >= 0, picks % lanes, -1), lanes,
+                            dtype=dtype)
+        keep = jnp.einsum("bskh,bskl->bshl", hi, lo,
+                          preferred_element_type=jnp.float32)
+        return keep.reshape(b, s, -1)[..., :n] > 0
+
+    def _attend_walk(self, q_nope, q_rope, wkv_b, pool, btab, slen, picks):
+        """The same read in place (`ops/pallas/selected_attention.py`):
+        the kernel walks each row's LIVE pages of the pool once for the
+        chunk's `s x heads` query rows, with the picks as a mask inside
+        its fold; no view over the table's width and no scores in HBM.
+        The value up-projection stays here."""
+        from .pallas.selected_attention import selected_latent_attention
+
+        p: MLAParams = self.params
+        n = btab.shape[1] * self._kv_page_size
+        out_lat = selected_latent_attention(
+            self._latent_queries(q_nope, q_rope, wkv_b, pool.shape[-1]),
+            pool, btab, slen, self._picks_mask(picks, n, q_nope.dtype),
+            softmax_scale(p), p.kv_lora_rank)
+        return jnp.einsum("bshc,chd->bshd", out_lat.astype(q_nope.dtype),
+                          wkv_b[..., p.qk_nope_head_dim:])
+
     def _attend_masked_view(self, q_nope, q_rope, wkv_b, pool, btab, picks):
         """The same read as `_attend_selected` by the table's width:
         the row's view `[b, n, .]` gathered once, page by page, dense
-        scores `[b, h, s, n]`, and the picks as a MASK on them.  The
-        mask is a product, not a scatter (1 M scalar updates took 17 ms
-        a `[32, 16, 2048]` set of picks): a position is `hi x 128 + lo`,
-        and `one_hot(hi)^T one_hot(lo)` summed over a query's picks is
-        1 exactly where a pick stands (`-1` is no row of either)."""
+        scores `[b, h, s, n]`, and the picks as a MASK on them
+        (`_picks_mask`).  The CPU tier's formulation of a chunk's read,
+        and the walk's parity oracle."""
         p: MLAParams = self.params
         dn, rk = p.qk_nope_head_dim, p.kv_lora_rank
-        b, s, _ = picks.shape
-        n = btab.shape[1] * self._kv_page_size
+        b, n = btab.shape[0], btab.shape[1] * self._kv_page_size
         view = jnp.take(pool, btab, axis=0).reshape(b, n, -1) \
             .astype(q_nope.dtype)
-        lanes = 128
-        hi = jax.nn.one_hot(picks // lanes, -(-n // lanes),
-                            dtype=q_nope.dtype)
-        lo = jax.nn.one_hot(jnp.where(picks >= 0, picks % lanes, -1), lanes,
-                            dtype=q_nope.dtype)
-        keep = jnp.einsum("bskh,bskl->bshl", hi, lo,
-                          preferred_element_type=jnp.float32)
-        keep = keep.reshape(b, s, -1)[..., :n] > 0
+        keep = self._picks_mask(picks, n, q_nope.dtype)
         scores = jnp.einsum(
             "bshc,bnc->bhsn",
             self._latent_queries(q_nope, q_rope, wkv_b, pool.shape[-1]),

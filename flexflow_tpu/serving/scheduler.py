@@ -453,6 +453,7 @@ class PagedKVDecodeModel:
             from ..ops.mla import selection_counts
 
             self._selection_counts = selection_counts
+            self._dsa_plan = dsa_ops[0].selected_plan
         self.eva = ({"window": eva_ops[0].params.window_size,
                      "chunk": eva_ops[0].params.chunk_size,
                      "store_rows": eva_ops[0].store_rows,
@@ -549,27 +550,41 @@ class PagedKVDecodeModel:
                     self.swa["window"], positions, counts),
                 "swa_rows_read": n * self.batch_slots * self.swa["ring"]}
 
-    def dsa_rows(self, positions, counts) -> Optional[Dict[str, int]]:
+    def dsa_rows(self, positions, counts,
+                 chunk: int = 1) -> Optional[Dict[str, int]]:
         """The selection's args of a dispatch that advances row i over
-        `positions[i] .. + counts[i] - 1` (`ops/mla.py
-        selection_counts`, a layer's): `dsa_keys_live` and
-        `dsa_keys_selected` a layer, `dsa_keys_scored` (the indexers
-        score every live key: `keys_live` x the full layers),
-        `dsa_rows_past_topk`, and `index_blocks_live`, the advancing
-        rows' blocks of index keys, summed over the full layers.  Host
-        arithmetic on host-owned lengths, no fetch.  None without such
-        a layer."""
+        `positions[i] .. + counts[i] - 1` in a program of `chunk` tokens
+        a row (`ops/mla.py selection_counts`, a layer's):
+        `dsa_keys_live` and `dsa_keys_selected` a layer,
+        `dsa_keys_scored` (the indexers score every live key:
+        `keys_live` x the full layers), `dsa_rows_past_topk`,
+        `index_blocks_live`, the advancing rows' blocks of index keys,
+        summed over the full layers, and `dsa_keys_read`, the keys a
+        layer's read touches under the plan the program's shape takes
+        (`MLAttention.selected_plan`): under the walk the advancing
+        rows' live keys, `positions[i] + chunk` each, once a chunk;
+        under the view every slot's table width; under the gather every
+        slot's `chunk x index_topk` picks.  Host arithmetic on
+        host-owned lengths, no fetch.  None without such a layer."""
         if self.dsa is None:
             return None
         one = self._selection_counts(self.dsa["topk"], positions, counts)
         n = np.asarray(counts, np.int64)
-        blocks = -(-(np.asarray(positions, np.int64) + n) // self.page_size)
+        first = np.asarray(positions, np.int64)
+        blocks = -(-(first + n) // self.page_size)
         full = self.dsa["full_layers"]
+        plan = self._dsa_plan(chunk, self.max_seq)
+        if plan == "walk":
+            read = int(np.minimum(first + chunk, self.max_seq)[n > 0].sum())
+        else:
+            read = self.batch_slots * (self.max_seq if plan == "view"
+                                       else chunk * self.dsa["topk"])
         return {"dsa_keys_live": one["keys_live"],
                 "dsa_keys_selected": one["keys_selected"],
                 "dsa_keys_scored": full * one["keys_live"],
                 "dsa_rows_past_topk": one["rows_past_topk"],
-                "index_blocks_live": full * int(blocks[n > 0].sum())}
+                "index_blocks_live": full * int(blocks[n > 0].sum()),
+                "dsa_keys_read": read}
 
     def _row_tokens(self, row_tokens, one_pass: bool = False,
                     take_prev=None) -> tuple:
@@ -2015,10 +2030,10 @@ class ContinuousScheduler:
         for k, v in rows.items():
             t[k] += v
 
-    def _note_dsa(self, dispatch, positions, counts) -> None:
+    def _note_dsa(self, dispatch, positions, counts, chunk: int) -> None:
         """The selection's args of a dispatch span (`model.dsa_rows`)
         and their sums over both kinds of dispatch."""
-        rows = self._dsa_rows(positions, counts)
+        rows = self._dsa_rows(positions, counts, chunk)
         dispatch.set(**rows)
         t = self.dsa_totals
         t["dispatches"] += 1
@@ -2168,7 +2183,7 @@ class ContinuousScheduler:
                     if self._swa_rows is not None:
                         self._note_swa(dispatch, slen, fed)
                     if self._dsa_rows is not None:
-                        self._note_dsa(dispatch, slen, fed)
+                        self._note_dsa(dispatch, slen, fed, C)
                     # a plan row's prefix is read once a pass: by the
                     # scan at each of its C positions, by the one-pass
                     # program once, up to the chunk's last; the scan's
@@ -2583,7 +2598,7 @@ class ContinuousScheduler:
                     self._note_swa(dispatch, self._slens, alive[0])
                 if self._dsa_rows is not None:
                     self._note_dsa(dispatch, self._slens,
-                                   [live is not None for live in slots])
+                                   [live is not None for live in slots], 1)
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
                 if why is None:

@@ -5,7 +5,8 @@ harness says nothing).
 
     chiprun --chips 1 -- python3 scripts/serve_step_probe.py \
         --workload <cell> [--calls 10] [--chunks 8,64,128] \
-        [--position 2048] [--scopes] [--selected-read gather,view]
+        [--position 2048 | --positions mix] [--scopes] \
+        [--selected-read walk,gather,view]
 
 Builds the cell's server as the harness does (`build_server`, the
 seed's weights, `PagedKVDecodeModel` with the front's arguments), gives
@@ -18,17 +19,21 @@ device's `memory_stats` and one JSON line.  `--chunks` does that once
 for each `prefill_chunk` of the list in turn (the weights stay, the
 twin and its state are built anew), a JSON line each: the readings a
 configuration's `prefill_chunk` is chosen from.  `--position` puts the
-rows at another length than half the table's; `--scopes` also traces
+rows at another length than half the table's, `--positions mix` each
+row at its length in the iteration at the middle of the cell's window
+with the tokens it is fed there (`scripts/serve_window_replay.py
+window_rows` over the cell's traffic: a pass whose cost follows the
+rows' lengths reads otherwise at one length for all); `--scopes` also traces
 three calls of each program and prints where their device time went by
 the program's names (`benchmarks/device_scopes.py`).  For a family
 whose latent attention reads picked keys (`ops/mla.py`),
 `--selected-read` times each chunk once a formulation of that read,
-forced on both step programs: `gather` (`pool[table[idx // page], idx %
-page]`, a `[b, s, k, width]` gather), `view` (the row's view gathered
-once over the table's width and the picks as a mask on dense scores),
-`plan` (the default: what the op's own rule takes for each step
-length).  A serving
-family only."""
+forced on both step programs: `walk` (the kernel over the row's live
+pages with the picks as its mask), `gather` (`pool[table[idx // page],
+idx % page]`, a `[b, s, k, width]` gather), `view` (the row's view
+gathered once over the table's width and the picks as a mask on dense
+scores), `plan` (the default: what the op's own rule takes for each
+step length).  A serving family only."""
 from __future__ import annotations
 
 import argparse
@@ -58,11 +63,17 @@ def main() -> int:
                          "(default: the configuration's own)")
     ap.add_argument("--position", type=int, default=None,
                     help="every row's length (default: half the table)")
+    ap.add_argument("--positions", choices=["mix"], default=None,
+                    help="mix: the rows' lengths and fed tokens of the "
+                         "iteration at the middle of the cell's window")
+    ap.add_argument("--pass-ms", type=float, default=114.6,
+                    help="the iteration's time the mix is replayed at "
+                         "(default: cell 12's accepted pass)")
     ap.add_argument("--scopes", action="store_true",
                     help="trace three calls a program: the by-scope table")
     ap.add_argument("--selected-read", default="plan",
                     help="formulations of the selected read to time in "
-                         "turn: plan (the op's rule), gather, view")
+                         "turn: plan (the op's rule), walk, gather, view")
     args = ap.parse_args()
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
@@ -78,12 +89,19 @@ def main() -> int:
     out["weight_bytes"] = int(sum(x.nbytes for x in leaves))
     chunks = ([int(x) for x in args.chunks.split(",")] if args.chunks
               else [ff.config.prefill_chunk])
+    mix = None
+    if args.positions == "mix":
+        from serve_window_replay import window_rows
+
+        mix = window_rows(cell["traffic"], ff.config.serving_slots,
+                          ff.config.prefill_chunk, args.pass_ms)
+        out["positions"] = "mix"
     for read in args.selected_read.split(","):
         for chunk in chunks:
             try:
                 with selected_read(read):
                     probe(ff, chunk, dict(out, selected_read=read),
-                          args.calls, t0, args.position, args.scopes)
+                          args.calls, t0, args.position, args.scopes, mix)
             except Exception as e:  # a formulation that does not fit
                 if read == "plan":
                     raise
@@ -97,16 +115,16 @@ def main() -> int:
 
 @contextlib.contextmanager
 def selected_read(formulation: str):
-    """`MLAttention.selected_plan` answering `formulation` ("gather" or
-    "view") for every step length while the block runs, "plan" the op's
-    own rule: both formulations of the selected read are the op's, the
-    probe's readings are the constants of its rule."""
+    """`MLAttention.selected_plan` answering `formulation` ("walk",
+    "gather" or "view") for every step length while the block runs,
+    "plan" the op's own rule: the formulations of the selected read are
+    the op's, the probe's readings are the constants of its rule."""
     from flexflow_tpu.ops.mla import MLAttention
 
     if formulation == "plan":
         yield
         return
-    if formulation not in ("gather", "view"):
+    if formulation not in ("walk", "gather", "view"):
         raise SystemExit(f"--selected-read: no formulation {formulation!r}")
     own = MLAttention.selected_plan
     MLAttention.selected_plan = lambda self, s, n: formulation
@@ -117,8 +135,10 @@ def selected_read(formulation: str):
 
 
 def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float,
-          position=None, scopes: bool = False) -> None:
-    """One twin at `prefill_chunk`: its two programs timed, its line."""
+          position=None, scopes: bool = False, mix=None) -> None:
+    """One twin at `prefill_chunk`: its two programs timed, its line.
+    `mix`: ([length a slot], [tokens fed a slot]) in place of one
+    `position` for every row."""
     from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
 
     c = ff.config
@@ -136,8 +156,16 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float,
     # every slot mid-sequence on blocks of its own
     table = 1 + np.arange(b * width, dtype=np.int32).reshape(b, width) \
         % (model.num_blocks - 1)
-    pos = np.full((b,), model.max_seq // 2 if position is None
-                  else position, np.int32)
+    feeds = None
+    if mix is not None:
+        pos = np.minimum(np.asarray(mix[0], np.int32),
+                         model.max_seq - model.prefill_chunk)
+        feeds = np.minimum(np.asarray(mix[1], np.int32),
+                           model.prefill_chunk)
+        out["live_keys"], out["rows_fed"] = int(pos.sum()), feeds.tolist()
+    else:
+        pos = np.full((b,), model.max_seq // 2 if position is None
+                      else position, np.int32)
     tokens = np.arange(1, b + 1, dtype=np.int32)
     rows = (np.ones((b,), np.int32),) if model.has_slot_state else ()
 
@@ -152,7 +180,8 @@ def probe(ff, prefill_chunk: int, out: dict, calls: int, t0: float,
 
     chunk = np.tile(tokens[:, None], (1, model.prefill_chunk))
     # (the one-pass program takes `row_tokens` whatever the family)
-    fed = (np.full((b,), model.prefill_chunk, np.int32),) \
+    fed = ((np.full((b,), model.prefill_chunk, np.int32)
+            if feeds is None else feeds),) \
         if model.has_slot_state or model.prefill_passes == 1 else ()
 
     def prefill():

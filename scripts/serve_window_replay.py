@@ -40,10 +40,13 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 from drivers.serve import make_schedule  # noqa: E402
 
 
-def replay(tr, slots, chunk, pass_s, step_s, seconds, refill_after=0):
+def replay(tr, slots, chunk, pass_s, step_s, seconds, refill_after=0,
+           rows=None):
     """[(t_done, new tokens)] of every request of the trace, from the
     run's start.  `refill_after` 1 leaves a freed slot empty for an
-    iteration (the dispatcher's lost race, before PR 52)."""
+    iteration (the dispatcher's lost race, before PR 52).  `rows` (a
+    list) collects, an iteration, `(t at its start, [(length, tokens
+    fed) a slot, None where idle])`."""
     reqs = [(due, len(prompt), new)
             for due, prompt, new in make_schedule(tr, 1, seconds, 1000)]
     t, it, nxt, held = 0.0, 0, 0, 0
@@ -59,13 +62,18 @@ def replay(tr, slots, chunk, pass_s, step_s, seconds, refill_after=0):
         for s in range(slots):
             if live[s] is None and queue and queue[0][0] <= it:
                 _, i = queue.pop(0)
-                live[s] = [reqs[i][1], reqs[i][2], reqs[i][2]]
+                live[s] = [reqs[i][1], reqs[i][2], reqs[i][2], reqs[i][1]]
         if not any(live):
             if nxt == len(reqs):
                 break
             t = max(t, reqs[nxt][0])
             continue
         feeding = any(row is not None and row[0] > 1 for row in live)
+        if rows is not None:  # prompt fed so far + tokens sampled so far
+            rows.append((t, [None if row is None else (
+                row[3] - row[0] + row[2] - row[1],
+                min(chunk, row[0]) if feeding and row[0] else 1)
+                for row in live]))
         t += pass_s if feeding else step_s
         it += 1
         for s, row in enumerate(live):
@@ -93,6 +101,22 @@ def window(tr, done, seconds):
     rel = [(t - t0, n) for t, n in done]
     inside = [n for t, n in rel if 0 < t <= seconds]
     return sum(inside) / seconds, len(inside), rel
+
+
+def window_rows(traffic: str, slots: int, chunk: int, pass_ms: float,
+                seconds: float = 30.0, step_ratio: float = 0.65):
+    """The rows of the iteration at the middle of `traffic`'s window,
+    at `pass_ms` an iteration: ([length a slot, 0 where idle], [tokens
+    fed a slot]): the mix of lengths a probe times a pass at."""
+    tr = json.load(open(os.path.join(ROOT, "benchmarks", "traffic",
+                                     traffic + ".json")))
+    rows = []
+    done = sorted(replay(tr, slots, chunk, pass_ms / 1e3,
+                         step_ratio * pass_ms / 1e3, seconds, rows=rows))
+    middle = done[tr["warm_completions"] - 1][0] + seconds / 2
+    _, at = min(rows, key=lambda r: abs(r[0] - middle))
+    return ([0 if r is None else r[0] for r in at],
+            [0 if r is None else r[1] for r in at])
 
 
 def main() -> int:

@@ -306,6 +306,11 @@ def test_dispatch_spans_carry_the_selections_counters(served):
         assert a["dsa_keys_scored"] == full * a["dsa_keys_live"]
         assert a["dsa_keys_selected"] <= a["dsa_keys_live"]
         assert a["index_blocks_live"] % full == 0
+        # the keys a layer's read touches under the CPU tier's plan: the
+        # decode step gathers 3 slots' picks, a chunk of 4 takes the view
+        # over 3 slots' tables of 64
+        assert a["dsa_keys_read"] == (
+            3 * 64 if r.name == "sched.prefill.dispatch" else 3 * D.topk)
     first = next(r.args for r in mine if r.name == "sched.prefill.dispatch")
     # request a alone: 4 tokens from position 0, all under index_topk
     assert first["tokens"] == 4 and first["dsa_keys_live"] == 10 \
@@ -322,6 +327,32 @@ def test_dispatch_spans_carry_the_selections_counters(served):
         r.args["dsa_keys_selected"] for r in mine)
     assert dsa["dsa_keys_selected"] < dsa["dsa_keys_live"]
     assert dsa["dsa_rows_past_topk"] > 0
+    assert dsa["dsa_keys_read"] == sum(r.args["dsa_keys_read"] for r in mine)
+
+
+@pytest.mark.parametrize("plan,chunk,want", [
+    ("walk", 16, (6 + 16) + (40 + 16) + 64),   # live keys, held to the table
+    ("walk", 1, 7 + 41 + 61),
+    ("view", 16, 4 * 64),                       # every slot's table
+    ("gather", 1, 4 * 1 * 8),                   # every slot's picks
+    ("gather", 16, 4 * 16 * 8)])
+def test_keys_read_follow_the_plan_in_force(plan, chunk, want):
+    """`dsa_keys_read` of a dispatch: what a layer's read touches under
+    the plan its program's shape takes, the idle slot nothing under the
+    walk."""
+    import types
+
+    from flexflow_tpu.ops.mla import selection_counts
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+
+    model = types.SimpleNamespace(
+        dsa={"topk": 8, "layers": 4, "full_layers": 2}, page_size=4,
+        max_seq=64, batch_slots=4, _selection_counts=selection_counts,
+        _dsa_plan=lambda s, n: plan)
+    rows = PagedKVDecodeModel.dsa_rows(
+        model, [6, 40, 60, 0], [min(chunk, 4)] * 3 + [0], chunk)
+    assert rows["dsa_keys_read"] == want
+    assert rows["dsa_keys_scored"] == 2 * rows["dsa_keys_live"]
 
 
 # -- 3. where selection is the identity, and the pad contract ----------------------

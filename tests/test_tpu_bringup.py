@@ -276,6 +276,41 @@ def test_head_major_walk_compiles_for_v5e(heads, chunk, v5e_chip):
     lowered.compile()
 
 
+#: benchmarks/configs/glm52-ep16-serve.json: latent pools of 25,601 pages
+#: `[16, 640]` (576 padded to whole lane tiles), 32 slots, 64 heads, a
+#: table of 800 pages, latent rank 512
+SELECTED_CELL_GEOMETRY = (32, 16, 64, 640, 512, 800, 25601)
+
+
+@pytest.mark.parametrize("chunk", [1, 16])
+def test_selected_walk_compiles_for_v5e(chunk, v5e_chip):
+    """The selected read in place at the long-context cell's shapes:
+    Mosaic accepts the hand-issued copies of `[16, 640]` bf16 pages out
+    of the HBM-resident latent pool, the one-byte mask a tile at a time,
+    the count of a key's picks transposed by a product, and the
+    launch's own tile and fold fit the VMEM it asks for, under the
+    decode step's one query a row and the pass's chunk."""
+    from flexflow_tpu.ops.pallas import selected_attention as sa
+
+    slots, page, heads, width, rank, tw, nb = SELECTED_CELL_GEOMETRY
+    assert sa.walk_fits(chunk, page, width)
+
+    def S(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def read(q, pool, bt, sl, keep):
+        return sa.selected_latent_attention(q, pool, bt, sl, keep,
+                                            width ** -0.5, rank,
+                                            interpret=False)
+
+    lowered = _lower(read, (S(slots, chunk, heads, width),
+                            S(nb, page, width), S(slots, tw, dtype=jnp.int32),
+                            S(slots, dtype=jnp.int32),
+                            S(slots, chunk, tw * page, dtype=jnp.bool_)))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
 #: a routed layer of the two training cells: (usual slots, hidden,
 #: expert width, held, rows a group expects)
 GROUPED_LAYERS = {"cell6_lfm2": (12288, 2048, 1792, 8, 1024.0),
