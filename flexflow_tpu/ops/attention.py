@@ -29,7 +29,7 @@ from ..fftype import DataType, OperatorType
 from ..initializer import DEFAULT_WEIGHT_INIT, GlorotUniform
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
-from .op import Op, ShapeError, WeightSpec
+from .op import DispatchGroup, Op, ShapeError, WeightSpec
 
 
 # force the flash kernel when the per-device [b, h, q, k] score tensor
@@ -1150,3 +1150,37 @@ class MultiHeadAttention(Op):
             ks = min(ks, p.sliding_window)
         attn = 2.0 * b * p.num_heads * s * ks * (p.k_channels + p.v_channels)
         return proj + attn
+
+    def dispatch_group(self):
+        return "swa" if self._ring() else None
+
+    @classmethod
+    def dispatch_group_of(cls, ops, *, family, batch_slots, prefill_chunk,
+                          state_bytes, **twin):
+        """The window layers' rings: `swa_rows_live`, the ring rows some
+        query of the dispatch sees, summed over the rows and the layers
+        (`window_rows_live`), against `swa_rows_read`, the rows the
+        program as built reads (every slot's whole ring, a layer)."""
+        window, ring = ops[0].params.sliding_window, ops[0]._window_ring
+        if prefill_chunk > ring - window:
+            from ..config import ConfigError
+
+            raise ConfigError(
+                f"prefill_chunk {prefill_chunk} is longer than the "
+                f"{ring - window} rows that {family}'s window layers' "
+                f"rings hold beside their window (ring {ring}, "
+                f"sliding_window {window}): a pass writes its rows before "
+                "it reads, and a longer one would overwrite keys its first "
+                "queries still see; build the model with that "
+                "prefill_chunk")
+        n = len(ops)
+
+        def counts(positions, counts, chunk):
+            return {"swa_rows_live": n * window_rows_live(window, positions,
+                                                          counts),
+                    "swa_rows_read": n * batch_slots * ring}
+
+        return DispatchGroup(
+            geometry={"window": window, "ring": ring}, counts=counts,
+            build_args={"swa_state_bytes": state_bytes},
+            gauges={"swa_state_bytes": state_bytes, "swa_ring_rows": ring})

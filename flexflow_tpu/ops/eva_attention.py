@@ -71,7 +71,7 @@ from ..initializer import DEFAULT_WEIGHT_INIT, ZeroInitializer
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .attention import rotate_half
-from .op import Op, ShapeError, ShardConfig, WeightSpec
+from .op import DispatchGroup, Op, ShapeError, ShardConfig, WeightSpec
 
 #: the per-slot arrays, in the order the op takes and returns them
 STATE = ("win_k", "win_v", "sum_k", "sum_v", "pend_k", "pend_v")
@@ -162,6 +162,37 @@ class EvaAttention(Op):
     def store_rows(self) -> int:
         """Rows of `sum_k` / `sum_v` a slot: a chunk of `max_seq` each."""
         return self._max_seq // self.params.chunk_size
+
+    def dispatch_group(self):
+        return "eva" if self._slot_state else None
+
+    @classmethod
+    def dispatch_group_of(cls, ops, *, family, batch_slots, prefill_chunk,
+                          state_bytes, **twin):
+        """The windows and summary stores: `eva_row_counts`, summed over
+        the layers."""
+        p: EvaAttentionParams = ops[0].params
+        window, chunk, store_rows = (p.window_size, p.chunk_size,
+                                     ops[0].store_rows)
+        if prefill_chunk > window:
+            from ..config import ConfigError
+
+            raise ConfigError(
+                f"prefill_chunk {prefill_chunk} is longer than "
+                f"{family}'s window_size {window}: a "
+                "prefill pass reads the window as it was and writes it "
+                "once, so it may cross one window boundary, not two")
+        n = len(ops)
+
+        def counts(positions, counts, chunk_tokens):
+            one = eva_row_counts(window, chunk, store_rows, batch_slots,
+                                 positions, counts)
+            return {k: v * n for k, v in one.items()}
+
+        return DispatchGroup(
+            geometry={"window": window, "chunk": chunk,
+                      "store_rows": store_rows},
+            counts=counts, build_args={"eva_state_bytes": state_bytes})
 
     def infer_output_shapes(self, input_shapes):
         (x,) = input_shapes

@@ -66,7 +66,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .chunked_delta_rule import l2norm, pick_chunk
-from .op import Op, ShapeError, ShardConfig, WeightSpec
+from .op import DispatchGroup, Op, ShapeError, ShardConfig, WeightSpec
 from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
 from .short_conv import causal_depthwise_conv
@@ -292,3 +292,36 @@ class GatedDeltaNet(Op):
         conv = 2.0 * p.conv_dim * p.conv_kernel
         rec = 7.0 * p.num_v_heads * p.head_k_dim * p.head_v_dim
         return b * s * (proj + conv + rec)
+
+    def dispatch_group(self):
+        return "rstate" if self._slot_state else None
+
+    @classmethod
+    def dispatch_group_of(cls, ops, *, batch_slots, prefill_chunk,
+                          state_bytes, **twin):
+        """The recurrent state: `rstate_rows_live`, the rows a dispatch
+        had to advance, against `rstate_rows_touched`, the rows whose
+        state the program of that step length read and wrote: the
+        advanced rows where every layer of it takes the kernel
+        (`recurrence_plan`, asked once for each step length the twin
+        runs), every slot under the plain recurrence.  `gdn_kernel_ops`
+        layers take the kernel in every step program, `gdn_plain_ops`
+        the plain scan in some."""
+        lengths = (1, *((prefill_chunk,) if prefill_chunk else ()))
+        in_kernel = [[op.recurrence_plan(s) == "kernel" for s in lengths]
+                     for op in ops]
+        skips_idle = {s: all(op[i] for op in in_kernel)
+                      for i, s in enumerate(lengths)}
+        kernels = sum(all(op) for op in in_kernel)
+        built = {"gdn_kernel_ops": kernels,
+                 "gdn_plain_ops": len(ops) - kernels}
+
+        def counts(positions, counts, chunk):
+            live = len([n for n in counts if n])
+            return {"rstate_rows_live": live,
+                    "rstate_rows_touched": (live if skips_idle.get(chunk)
+                                            else batch_slots)}
+
+        return DispatchGroup(
+            geometry=built, counts=counts,
+            build_args={"rstate_bytes": state_bytes, **built})

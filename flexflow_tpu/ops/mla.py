@@ -126,7 +126,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .norm import rms_normalize
-from .op import Op, ShapeError, WeightSpec
+from .op import DispatchGroup, Op, ShapeError, WeightSpec
 from .rope import yarn_frequencies as _yarn_frequencies
 
 
@@ -915,3 +915,46 @@ class MLAttention(Op):
         expand = 2.0 * b * s * p.kv_lora_rank * h * (
             p.qk_nope_head_dim + p.v_head_dim)
         return proj + expand + 2.0 * b * h * s * s * (dq + p.v_head_dim)
+
+    def dispatch_group(self):
+        return "dsa" if self.reads_selection() else None
+
+    @classmethod
+    def dispatch_group_of(cls, ops, *, batch_slots, page_size, max_seq,
+                          **twin):
+        """The selection (`selection_counts`, a layer's):
+        `dsa_keys_live` and `dsa_keys_selected` a layer,
+        `dsa_keys_scored` (the indexers score every live key:
+        `keys_live` x the full layers), `dsa_rows_past_topk`,
+        `index_blocks_live`, the advancing rows' blocks of index keys,
+        summed over the full layers, and `dsa_keys_read`, the keys a
+        layer's read touches under the plan the program's shape takes
+        (`selected_plan`): under the walk the advancing rows' live keys,
+        `positions[i] + chunk` each, once a chunk; under the view every
+        slot's table width; under the gather every slot's `chunk x
+        index_topk` picks."""
+        topk = ops[0].params.index_topk
+        full = sum(op.params.indexer == "full" for op in ops)
+        plans = {}  # {chunk: the plan its program took}
+
+        def counts(positions, counts, chunk):
+            one = selection_counts(topk, positions, counts)
+            n = np.asarray(counts, np.int64)
+            first = np.asarray(positions, np.int64)
+            blocks = -(-(first + n) // page_size)
+            if chunk not in plans:
+                plans[chunk] = ops[0].selected_plan(chunk, max_seq)
+            if plans[chunk] == "walk":
+                read = int(np.minimum(first + chunk, max_seq)[n > 0].sum())
+            else:
+                read = batch_slots * (max_seq if plans[chunk] == "view"
+                                      else chunk * topk)
+            return {"dsa_keys_live": one["keys_live"],
+                    "dsa_keys_selected": one["keys_selected"],
+                    "dsa_keys_scored": full * one["keys_live"],
+                    "dsa_rows_past_topk": one["rows_past_topk"],
+                    "index_blocks_live": full * int(blocks[n > 0].sum()),
+                    "dsa_keys_read": read}
+
+        return DispatchGroup(geometry={"topk": topk, "full_layers": full},
+                             counts=counts)

@@ -24,7 +24,8 @@ a per-op `ShardConfig`, mutated by the strategy search.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import numpy as np
@@ -79,6 +80,28 @@ class WeightSpec:
     name: str
     shape: ParallelTensorShape
     initializer: Optional[Initializer] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class DispatchGroup:
+    """What the ops of one `Op.dispatch_group()` tell a paged twin
+    (`Op.dispatch_group_of`; docs/SERVING.md "What a mixer with serving
+    state declares")."""
+
+    #: the group's fixed facts, a layer's or summed as the ops choose;
+    #: the twin adds `layers` and `state_bytes`, `stats()[group]` holds
+    #: them beside the sums
+    geometry: Dict[str, int]
+    #: `counts(positions, counts, chunk)`: the args of the span of a
+    #: dispatch that advances row i over `positions[i] .. + counts[i] -
+    #: 1` (numpy, [slots]) in a program of `chunk` tokens a row, summed
+    #: by program in `stats()[group]`.  Host arithmetic on host-owned
+    #: lengths: no fetch, no argument to a program
+    counts: Callable[[Any, Any, int], Dict[str, int]]
+    #: args of the twin's `serve.build_twin` span
+    build_args: Dict[str, int] = dataclasses.field(default_factory=dict)
+    #: gauges `serving/<name>`, set once an engine
+    gauges: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class Op:
@@ -179,6 +202,31 @@ class Op:
     #: sequence's own positions (`ops/eva_attention.py`): nothing of the
     #: last tenant can be read, so there is nothing to zero
     slot_state_resets: bool = True
+
+    def dispatch_group(self) -> Optional[str]:
+        """The name under which this op, as built, tells the serving
+        tier what a dispatch costs it ("swa": a window layer's ring;
+        None: nothing to tell).  The ops of one name answer together,
+        once a twin (`dispatch_group_of`): the serving tier knows the
+        names it is handed and no op's."""
+        return None
+
+    @classmethod
+    def dispatch_group_of(cls, ops: Sequence["Op"], *, family: str,
+                          batch_slots: int, page_size: int, max_seq: int,
+                          prefill_chunk: int,
+                          state_bytes: int) -> "DispatchGroup":
+        """What `ops`, a paged twin's ops of one `dispatch_group()` in
+        graph order, tell that twin at its build: `batch_slots` rows of
+        up to `max_seq` positions in pages of `page_size`, step programs
+        of 1 and of `prefill_chunk` tokens a row (0: the step alone),
+        `state_bytes` in the ops' `slot_state_entries()`.  A
+        `prefill_chunk` the ops cannot take is a `ConfigError` here,
+        naming `family`.  Asked of the ops together because some of
+        what they count is one layer's and some a sum over them."""
+        raise NotImplementedError(
+            f"{cls.__name__} names a dispatch group and does not "
+            "describe it")
 
     #: planes each of `cache_entries()` holds: one, or one per pass of
     #: the region that runs the op (`pcg.graph.LoopRegion`).  A paged

@@ -381,13 +381,9 @@ class ServingFront:
                 )
                 sp.set(slots=model.batch_slots,
                        pool_blocks=model.num_blocks)
-                if model.rstate_bytes:
-                    sp.set(rstate_bytes=model.rstate_bytes,
-                           **model.gdn_ops)
-                if model.eva:  # windows and summary stores, per slot
-                    sp.set(eva_state_bytes=model.eva_state_bytes)
-                if model.swa:  # window layers' rings, per slot
-                    sp.set(swa_state_bytes=model.swa_state_bytes)
+                for told in model.groups.values():
+                    # (what its mixers keep a slot: `Op.dispatch_group`)
+                    sp.set(**told.build_args)
                 if model.loop:  # the twin's graph repeats a region
                     sp.set(**model.loop)
             return model

@@ -108,25 +108,15 @@ class SupervisedDecodeModel:
         if not self._has_verify:
             self.spec_decode = "off"
         self._has_copy = getattr(model, "copy_block", None) is not None
-        # per-slot recurrent state: the scheduler then passes
-        # `row_tokens` to both step programs and zeroes a slot's state
-        # at admission (`reset_slot_state`)
+        # per-slot state: the scheduler then passes `row_tokens` to both
+        # step programs, and zeroes a slot's state at admission
+        # (`reset_slot_state`) where some of it is zeroed (`rstate_bytes`)
         self.has_slot_state = bool(getattr(model, "has_slot_state", False))
         self.rstate_bytes = getattr(model, "rstate_bytes", 0)
-        self.rstate_rows_touched = getattr(model, "rstate_rows_touched",
-                                           None)
-        # EVA layers' window and summary store: their geometry, their
-        # bytes and the dispatch counters' arithmetic (None without one)
-        self.eva = getattr(model, "eva", None)
-        self.eva_state_bytes = getattr(model, "eva_state_bytes", 0)
-        self.eva_rows = getattr(model, "eva_rows", None)
-        # window layers' rings, likewise
-        self.swa = getattr(model, "swa", None)
-        self.swa_state_bytes = getattr(model, "swa_state_bytes", 0)
-        self.swa_rows = getattr(model, "swa_rows", None)
-        # layers that read selected keys, likewise
-        self.dsa = getattr(model, "dsa", None)
-        self.dsa_rows = getattr(model, "dsa_rows", None)
+        # what the model's mixers tell of themselves and count of a
+        # dispatch (`Op.dispatch_group`; {} / None without a group)
+        self.groups = getattr(model, "groups", None) or {}
+        self.dispatch_counts = getattr(model, "dispatch_counts", None)
         self._has_export = (
             getattr(model, "export_block", None) is not None
             and getattr(model, "import_block", None) is not None)
@@ -614,34 +604,16 @@ class ServingReplica:
             if "prefix_cache" in sstats:
                 out["prefix_cache"] = sstats["prefix_cache"]
             # which paged formulation this replica runs + its fused
-            # kernel's KV-read counters (zeroes under the gather oracle)
-            if "paged_kernel" in sstats:
-                out["paged_kernel"] = sstats["paged_kernel"]
-            # tensor-parallel geometry: chips spanned + per-chip KV share
-            if "tp" in sstats:
-                out["tp"] = sstats["tp"]
-            # routed-expert layers: pairs on held experts, dropped (0),
-            # fullest expert's rows, experts hit, over decode dispatches
-            if "moe" in sstats:
-                out["moe"] = sstats["moe"]
-            # per-slot recurrent state: rows advanced against rows the
-            # programs read and wrote, over decode and prefill
-            # dispatches, and the state's bytes
-            if "rstate" in sstats:
-                out["rstate"] = sstats["rstate"]
-            # EVA layers: the `eva_*` dispatch args summed by program,
-            # their geometry and their state's bytes
-            if "eva" in sstats:
-                out["eva"] = sstats["eva"]
-            # window layers: the `swa_*` dispatch args summed, the
-            # rings' geometry and their bytes
-            if "swa" in sstats:
-                out["swa"] = sstats["swa"]
-            # a graph that repeats a region: its regions, the weight
-            # passes of the decode and prefill dispatches, and the exit
-            # gate's pdf summed over the decode dispatches' live rows
-            if "loop" in sstats:
-                out["loop"] = sstats["loop"]
+            # kernel's KV-read counters (zeroes under the gather oracle);
+            # tensor-parallel geometry; routed-expert layers' counts and
+            # a repeated region's weight passes, by program; and every
+            # group the model's mixers named (`Op.dispatch_group`):
+            # their dispatch args summed by program, beside their
+            # geometry
+            for k in ("paged_kernel", "tp", "moe", "loop",
+                      *sched.group_totals):
+                if k in sstats:
+                    out[k] = sstats[k]
         return out
 
     def close(self, timeout_s: Optional[float] = None) -> None:

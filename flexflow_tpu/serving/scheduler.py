@@ -64,6 +64,7 @@ run_telemetry.jsonl and surfaced in /v2/stats (docs/SERVING.md).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import itertools
 import operator
 import queue
@@ -74,9 +75,9 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..fftype import OperatorType
 from ..logger import serving_logger
 from ..obs.trace import span
+from ..ops.op import DispatchGroup
 from ..ops.routed_experts import MOE_STATS, MOE_ZERO_STATS
 from .kv_pool import KVPool
 
@@ -394,97 +395,37 @@ class PagedKVDecodeModel:
         # takes `row_tokens` in its step programs, and the scheduler
         # zeroes a slot's rows when it admits a request into it
         self._slot_state = slot_state_entries(self.ffd)
-        # EVA layers (ops/eva_attention.py) keep a third kind of state
-        # there: a window that fills and starts again and a store that
-        # grows a row a chunk, both masked by position, so the reset at
-        # admission has nothing of theirs to zero.  `eva` is their
-        # geometry (None without such a layer), `eva_state_bytes` what
-        # they hold; `rstate_bytes` stays the recurrent layers' alone
-        eva_ops = [op for op in self.ffd.operators.topo_order()
-                   if op.op_type == OperatorType.EVA_ATTENTION
-                   and op.slot_state_entries()]
+        # what a dispatch costs the mixers that say so, and what they
+        # keep (`Op.dispatch_group`; docs/SERVING.md "What a mixer with
+        # serving state declares"): the ops of a name answer together,
+        # once, here (`Op.dispatch_group_of`, which also refuses a
+        # `prefill_chunk` they cannot take), and the engine knows the
+        # names it is handed, no op's.  `groups` {name: what they said,
+        # its geometry with `layers` and `state_bytes` added}
+        named: Dict[str, list] = {}
+        for op in self.ffd.operators.topo_order():
+            if (name := op.dispatch_group()):
+                named.setdefault(name, []).append(op)
         bytes_of = lambda ops: sum(  # noqa: E731
-            int(self._state[op][k].nbytes)
-            for op, names in self._slot_state.items() if op in ops
-            for k in names)
-        # window layers (ops/attention.py, `sliding_window`) keep a
-        # fourth: a ring of keys and values a slot, masked by position
-        # like EVA's window.  `swa` is their geometry (None without such
-        # a layer), `swa_state_bytes` what the rings hold
-        swa_ops = [op for op in self.ffd.operators.topo_order()
-                   if op.op_type == OperatorType.MULTIHEAD_ATTENTION
-                   and op.slot_state_entries()]
-        self.eva_state_bytes = bytes_of({op.name for op in eva_ops})
-        self.swa_state_bytes = bytes_of({op.name for op in swa_ops})
+            int(self._state[op.name][k].nbytes)
+            for op in ops for k in op.slot_state_entries())
+        self.groups: Dict[str, DispatchGroup] = {}
+        for name, ops in named.items():
+            held = bytes_of(ops)
+            told = type(ops[0]).dispatch_group_of(
+                ops, family=recipe.family, batch_slots=batch_slots,
+                page_size=page_size, max_seq=max_seq,
+                prefill_chunk=self.prefill_chunk, state_bytes=held)
+            self.groups[name] = dataclasses.replace(
+                told, geometry=dict(told.geometry, layers=len(ops),
+                                    state_bytes=held))
+        # the state that the reset at admission zeroes (a state masked
+        # by the sequence's own positions has nothing to zero)
         self.rstate_bytes = bytes_of(
-            set(self._slot_state) - {op.name for op in eva_ops + swa_ops})
-        self.swa = ({"window": swa_ops[0].params.sliding_window,
-                     "ring": swa_ops[0]._window_ring,
-                     "layers": len(swa_ops)} if swa_ops else None)
-        if self.swa:  # (the counters' arithmetic, as `_eva_row_counts`)
-            from ..ops.attention import window_rows_live
-
-            self._window_rows_live = window_rows_live
-        if self.swa and self.prefill_chunk > (self.swa["ring"]
-                                              - self.swa["window"]):
-            raise ConfigError(
-                f"prefill_chunk {self.prefill_chunk} is longer than the "
-                f"{self.swa['ring'] - self.swa['window']} rows that "
-                f"{recipe.family}'s window layers' rings hold beside "
-                f"their window (ring {self.swa['ring']}, sliding_window "
-                f"{self.swa['window']}): a pass writes its rows before it "
-                "reads, and a longer one would overwrite keys its first "
-                "queries still see; build the model with that "
-                "prefill_chunk")
-        # latent-attention layers that read a SELECTION of their keys
-        # (ops/mla.py "Selected keys"): `dsa` is their geometry (None
-        # without one, or where selection is the identity at this
-        # table's width); the `full` ones among them keep a pool of
-        # index keys beside their latents, found by `cache_entries`
-        dsa_ops = [op for op in self.ffd.operators.topo_order()
-                   if op.op_type == OperatorType.MLA_ATTENTION
-                   and op.reads_selection()]
-        self.dsa = ({"topk": dsa_ops[0].params.index_topk,
-                     "layers": len(dsa_ops),
-                     "full_layers": sum(op.params.indexer == "full"
-                                        for op in dsa_ops)}
-                    if dsa_ops else None)
-        if self.dsa:
-            from ..ops.mla import selection_counts
-
-            self._selection_counts = selection_counts
-            self._dsa_plan = dsa_ops[0].selected_plan
-        self.eva = ({"window": eva_ops[0].params.window_size,
-                     "chunk": eva_ops[0].params.chunk_size,
-                     "store_rows": eva_ops[0].store_rows,
-                     "layers": len(eva_ops)} if eva_ops else None)
-        if self.eva:  # (the op's module is imported by such a twin only)
-            from ..ops.eva_attention import eva_row_counts
-
-            self._eva_row_counts = eva_row_counts
-        if self.eva and self.prefill_chunk > self.eva["window"]:
-            raise ConfigError(
-                f"prefill_chunk {self.prefill_chunk} is longer than "
-                f"{recipe.family}'s window_size {self.eva['window']}: a "
-                "prefill pass reads the window as it was and writes it "
-                "once, so it may cross one window boundary, not two")
+            op for op in self.ffd.operators.topo_order()
+            if op.slot_state_resets)
         self._reset_slot_fn = (build_slot_state_reset(self.ffd)
                                if self._slot_state else None)
-        # which recurrence the built programs take, asked of each
-        # recurrent layer for each step length this twin runs
-        # (`GatedDeltaNet.recurrence_plan`): the kernel walks the rows
-        # that advance, the plain scan every slot
-        gdn = [op for op in self.ffd.operators.topo_order()
-               if op.op_type == OperatorType.GATED_DELTA_NET
-               and op.slot_state_entries()]
-        lengths = (1, *((self.prefill_chunk,) if self.prefill_chunk else ()))
-        in_kernel = [[op.recurrence_plan(s) == "kernel" for s in lengths]
-                     for op in gdn]
-        self._rstate_skips_idle = {
-            s: all(op[i] for op in in_kernel) for i, s in enumerate(lengths)}
-        kernels = sum(all(op) for op in in_kernel)
-        self.gdn_ops = {"gdn_kernel_ops": kernels,
-                        "gdn_plain_ops": len(gdn) - kernels}
         self.mesh_shape = {
             str(k): int(s)
             for k, s in zip(self.ffd.mesh.axis_names,
@@ -514,77 +455,15 @@ class PagedKVDecodeModel:
     def has_slot_state(self) -> bool:
         return bool(self._slot_state)
 
-    def rstate_rows_touched(self, rows_live: int, step_tokens: int) -> int:
-        """Rows whose recurrent state a dispatch of `step_tokens` tokens
-        a row reads and writes when `rows_live` of them advance: those
-        rows where every recurrent layer of that program takes the
-        kernel, every slot under the plain recurrence."""
-        if self._rstate_skips_idle.get(step_tokens, False):
-            return rows_live
-        return self.batch_slots
-
-    def eva_rows(self, positions, counts) -> Optional[Dict[str, int]]:
-        """The `eva_*` args of a dispatch that advances row i over
-        `positions[i] .. + counts[i] - 1`, summed over the EVA layers
-        (`ops/eva_attention.py eva_row_counts`): host arithmetic on
-        host-owned lengths, no fetch.  None without such a layer."""
-        if self.eva is None:
-            return None
-        one = self._eva_row_counts(
-            self.eva["window"], self.eva["chunk"], self.eva["store_rows"],
-            self.batch_slots, positions, counts)
-        return {k: v * self.eva["layers"] for k, v in one.items()}
-
-    def swa_rows(self, positions, counts) -> Optional[Dict[str, int]]:
-        """The `swa_*` args of a dispatch that advances row i over
-        `positions[i] .. + counts[i] - 1`: `swa_rows_live`, the ring
-        rows some query of it sees, summed over the rows and the window
-        layers (`ops/attention.py window_rows_live`), against
-        `swa_rows_read`, the rows the program as built reads (every
-        slot's whole ring, a layer).  Host arithmetic on host-owned
-        lengths, no fetch.  None without such a layer."""
-        if self.swa is None:
-            return None
-        n = self.swa["layers"]
-        return {"swa_rows_live": n * self._window_rows_live(
-                    self.swa["window"], positions, counts),
-                "swa_rows_read": n * self.batch_slots * self.swa["ring"]}
-
-    def dsa_rows(self, positions, counts,
-                 chunk: int = 1) -> Optional[Dict[str, int]]:
-        """The selection's args of a dispatch that advances row i over
-        `positions[i] .. + counts[i] - 1` in a program of `chunk` tokens
-        a row (`ops/mla.py selection_counts`, a layer's):
-        `dsa_keys_live` and `dsa_keys_selected` a layer,
-        `dsa_keys_scored` (the indexers score every live key:
-        `keys_live` x the full layers), `dsa_rows_past_topk`,
-        `index_blocks_live`, the advancing rows' blocks of index keys,
-        summed over the full layers, and `dsa_keys_read`, the keys a
-        layer's read touches under the plan the program's shape takes
-        (`MLAttention.selected_plan`): under the walk the advancing
-        rows' live keys, `positions[i] + chunk` each, once a chunk;
-        under the view every slot's table width; under the gather every
-        slot's `chunk x index_topk` picks.  Host arithmetic on
-        host-owned lengths, no fetch.  None without such a layer."""
-        if self.dsa is None:
-            return None
-        one = self._selection_counts(self.dsa["topk"], positions, counts)
-        n = np.asarray(counts, np.int64)
-        first = np.asarray(positions, np.int64)
-        blocks = -(-(first + n) // self.page_size)
-        full = self.dsa["full_layers"]
-        plan = self._dsa_plan(chunk, self.max_seq)
-        if plan == "walk":
-            read = int(np.minimum(first + chunk, self.max_seq)[n > 0].sum())
-        else:
-            read = self.batch_slots * (self.max_seq if plan == "view"
-                                       else chunk * self.dsa["topk"])
-        return {"dsa_keys_live": one["keys_live"],
-                "dsa_keys_selected": one["keys_selected"],
-                "dsa_keys_scored": full * one["keys_live"],
-                "dsa_rows_past_topk": one["rows_past_topk"],
-                "index_blocks_live": full * int(blocks[n > 0].sum()),
-                "dsa_keys_read": read}
+    def dispatch_counts(self, positions, counts,
+                        chunk: int = 1) -> Dict[str, Dict[str, int]]:
+        """{group: the args of the span of a dispatch that advances row
+        i over `positions[i] .. + counts[i] - 1` in a program of `chunk`
+        tokens a row}, as each group's ops count it
+        (`DispatchGroup.counts`): host arithmetic on host-owned
+        lengths, no fetch.  {} for a twin without a group."""
+        return {name: told.counts(positions, counts, chunk)
+                for name, told in self.groups.items()}
 
     def _row_tokens(self, row_tokens, one_pass: bool = False,
                     take_prev=None) -> tuple:
@@ -1142,39 +1021,19 @@ class ContinuousScheduler:
              "prefill_dispatches": 0, "prefill_weight_passes": 0,
              "exit_rows": 0, "exit_mass": []}
             if self._loop_steps else None)
-        # per-slot recurrent state (a model without any leaves these
-        # off every span and out of stats()): rows whose state a
-        # dispatch had to advance against rows whose state the program
-        # read and wrote (`model.rstate_rows_touched`)
+        # a model with per-slot state takes `row_tokens` in its step
+        # programs; where some of that state is zeroed at admission
+        # (`rstate_bytes`), the scheduler asks for it
         self._rstate = bool(getattr(model, "has_slot_state", False))
-        # EVA layers' window and summary store are per-slot state too
-        # (`row_tokens` goes to the step programs), but nothing of them
-        # is zeroed or counted as recurrent: their dispatches carry the
-        # `eva_*` args instead (`model.eva_rows`), summed here
-        self._eva_rows = (getattr(model, "eva_rows", None)
-                          if getattr(model, "eva", None) else None)
-        self.eva_totals: Optional[Dict[str, int]] = (
-            {"decode_dispatches": 0, "prefill_dispatches": 0}
-            if self._eva_rows is not None else None)
-        # and so are the window layers' rings: the `swa_*` args
-        # (`model.swa_rows`)
-        self._swa_rows = (getattr(model, "swa_rows", None)
-                          if getattr(model, "swa", None) else None)
-        self.swa_totals: Optional[Dict[str, int]] = (
-            dict.fromkeys(("dispatches", "swa_rows_live", "swa_rows_read"),
-                          0) if self._swa_rows is not None else None)
-        # layers that read selected keys: the selection's args
-        # (`model.dsa_rows`)
-        self._dsa_rows = (getattr(model, "dsa_rows", None)
-                          if getattr(model, "dsa", None) else None)
-        self.dsa_totals: Optional[Dict[str, int]] = (
-            {"dispatches": 0} if self._dsa_rows is not None else None)
-        recurrent = self._rstate and (
-            (self._eva_rows is None and self._swa_rows is None)
-            or getattr(model, "rstate_bytes", 0) > 0)
-        self.rstate_totals: Optional[Dict[str, int]] = (
-            dict.fromkeys(("rows_live", "rows_touched", "dispatches"), 0)
-            if recurrent else None)
+        self._resets = bool(getattr(model, "rstate_bytes", 0))
+        # what the model's mixers count of a dispatch (`model.groups`,
+        # `model.dispatch_counts`: `Op.dispatch_group`; a model without
+        # a group leaves these off every span and out of stats()), and
+        # {group: the counts summed by program}
+        self._groups = dict(getattr(model, "groups", None) or {})
+        self.group_totals: Dict[str, Dict[str, int]] = {
+            name: {"decode_dispatches": 0, "prefill_dispatches": 0}
+            for name in self._groups}
         # bench/debug: run the pool's full invariant sweep after every
         # scheduler step (the serving_prefix leg's acceptance bar)
         self._check_invariants = bool(check_invariants)
@@ -1222,11 +1081,9 @@ class ContinuousScheduler:
                 int(getattr(model, "kv_block_bytes_per_chip",
                             getattr(model, "kv_block_bytes", 0)))
                 * int(getattr(model, "num_blocks", 0)))
-            if self._swa_rows is not None:
-                registry.gauge("serving/swa_state_bytes").set(
-                    int(model.swa_state_bytes))
-                registry.gauge("serving/swa_ring_rows").set(
-                    int(model.swa["ring"]))
+            for told in self._groups.values():
+                for name, value in told.gauges.items():
+                    registry.gauge(f"serving/{name}").set(int(value))
         self._queue: "queue.Queue[_PendingSeq]" = queue.Queue()
         self._waiting: deque = deque()  # worker-local FIFO admit order
         # worker-marshalled service calls (KV block import, export):
@@ -1571,17 +1428,8 @@ class ContinuousScheduler:
                              exit_mass=list(self.loop_totals["exit_mass"]),
                              **getattr(self.model, "loop", {}))}
                if self.loop_totals is not None else {}),
-            **({"rstate": dict(self.rstate_totals,
-                               bytes=int(self.model.rstate_bytes))}
-               if self.rstate_totals is not None else {}),
-            **({"eva": dict(self.eva_totals, **self.model.eva,
-                            state_bytes=int(self.model.eva_state_bytes))}
-               if self.eva_totals is not None else {}),
-            **({"swa": dict(self.swa_totals, **self.model.swa,
-                            state_bytes=int(self.model.swa_state_bytes))}
-               if self.swa_totals is not None else {}),
-            **({"dsa": dict(self.dsa_totals, **self.model.dsa)}
-               if self.dsa_totals is not None else {}),
+            **{name: dict(totals, **self._groups[name].geometry)
+               for name, totals in self.group_totals.items()},
         }
 
     def close(self, timeout_s: Optional[float] = None):
@@ -1827,7 +1675,7 @@ class ContinuousScheduler:
                                         hit, plen)
             slot = free.pop(0)
             self._slots[slot] = live
-            if self.rstate_totals is not None:
+            if self._resets:
                 # the slot's last tenant left its state behind
                 self.model.reset_slot_state(slot)
             # first private block (or a no-op after a full hit):
@@ -1991,54 +1839,21 @@ class ContinuousScheduler:
                                    else dense),
                 "kv_blocks_dense": dense, "kv_blocks_live": live}
 
-    def _note_rstate(self, dispatch, rows_live: int,
-                     step_tokens: int) -> None:
-        """The `rstate_rows_*` args of a dispatch span and their sums:
-        rows whose recurrent state the pass had to advance, and rows
-        whose state the program of that step length read and wrote, as
-        the twin built it (`rstate_rows_touched`: the advanced rows
-        under the kernel, every slot under the plain recurrence)."""
-        ask = getattr(self.model, "rstate_rows_touched", None)
-        touched = (ask(rows_live, step_tokens) if ask is not None
-                   else self.model.batch_slots)
-        dispatch.set(rstate_rows_live=rows_live,
-                     rstate_rows_touched=touched)
-        t = self.rstate_totals
-        t["rows_live"] += rows_live
-        t["rows_touched"] += touched
-        t["dispatches"] += 1
-
-    def _note_eva(self, dispatch, program: str, positions, counts) -> None:
-        """The `eva_*` args of a dispatch span (`model.eva_rows`: what
-        the advancing rows' queries could see, what the program as
-        built reads, the summaries it writes) and their sums by
-        program."""
-        rows = self._eva_rows(positions, counts)
-        dispatch.set(**rows)
-        t = self.eva_totals
-        t[f"{program}_dispatches"] += 1
-        for k, v in rows.items():
-            t[f"{program}_{k}"] = t.get(f"{program}_{k}", 0) + v
-
-    def _note_swa(self, dispatch, positions, counts) -> None:
-        """The `swa_*` args of a dispatch span (`model.swa_rows`) and
-        their sums over both kinds of dispatch."""
-        rows = self._swa_rows(positions, counts)
-        dispatch.set(**rows)
-        t = self.swa_totals
-        t["dispatches"] += 1
-        for k, v in rows.items():
-            t[k] += v
-
-    def _note_dsa(self, dispatch, positions, counts, chunk: int) -> None:
-        """The selection's args of a dispatch span (`model.dsa_rows`)
-        and their sums over both kinds of dispatch."""
-        rows = self._dsa_rows(positions, counts, chunk)
-        dispatch.set(**rows)
-        t = self.dsa_totals
-        t["dispatches"] += 1
-        for k, v in rows.items():
-            t[k] = t.get(k, 0) + v
+    def _note_counts(self, dispatch, program: str, positions, counts,
+                     chunk: int) -> None:
+        """What the model's mixers count of a dispatch that advances
+        row i over `positions[i] .. + counts[i] - 1` in a program of
+        `chunk` tokens a row (`model.dispatch_counts`, {group: args}):
+        onto its span, and summed by program into `group_totals`."""
+        if not self.group_totals:
+            return
+        rows = self.model.dispatch_counts(positions, counts, chunk)
+        for group, args in rows.items():
+            dispatch.set(**args)
+            t = self.group_totals[group]
+            t[f"{program}_dispatches"] += 1
+            for k, v in args.items():
+                t[f"{program}_{k}"] = t.get(f"{program}_{k}", 0) + v
 
     def _note_moe(self, dispatch, program: str) -> None:
         """The `moe_*` args of a dispatch whose logits were fetched
@@ -2176,14 +1991,7 @@ class ContinuousScheduler:
                 def count():
                     # the dispatch's counters, taken while the program
                     # runs: after its enqueue, before any wait for it
-                    if self.rstate_totals is not None:
-                        self._note_rstate(dispatch, len(plan), C)
-                    if self._eva_rows is not None:
-                        self._note_eva(dispatch, "prefill", slen, fed)
-                    if self._swa_rows is not None:
-                        self._note_swa(dispatch, slen, fed)
-                    if self._dsa_rows is not None:
-                        self._note_dsa(dispatch, slen, fed, C)
+                    self._note_counts(dispatch, "prefill", slen, fed, C)
                     # a plan row's prefix is read once a pass: by the
                     # scan at each of its C positions, by the one-pass
                     # program once, up to the chunk's last; the scan's
@@ -2586,19 +2394,13 @@ class ContinuousScheduler:
                     self._slens, [live is not None for live in slots])
                 flight = _Flight("decode", dispatch, None, rows, reads)
                 dispatch.set(**reads)
-                alive = ()  # recurrent state: which rows advance
-                if self._rstate:
-                    alive = (np.array([live is not None
-                                       for live in slots], np.int32),)
-                if self.rstate_totals is not None:
-                    self._note_rstate(dispatch, int(alive[0].sum()), 1)
-                if self._eva_rows is not None:
-                    self._note_eva(dispatch, "decode", self._slens, alive[0])
-                if self._swa_rows is not None:
-                    self._note_swa(dispatch, self._slens, alive[0])
-                if self._dsa_rows is not None:
-                    self._note_dsa(dispatch, self._slens,
-                                   [live is not None for live in slots], 1)
+                alive = ()  # per-slot state: which rows advance
+                if self._rstate or self.group_totals:
+                    advance = np.array([live is not None for live in slots],
+                                       np.int32)
+                    alive = (advance,) if self._rstate else ()
+                    self._note_counts(dispatch, "decode", self._slens,
+                                      advance, 1)
                 if self._loop_steps:
                     self._note_loop(dispatch, "decode", 1)
                 if why is None:

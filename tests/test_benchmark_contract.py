@@ -101,9 +101,11 @@ def emitted():
     """(span names, span args) the package's source can emit: the first
     argument and the keywords of every `span(...)`, the keywords of every
     `.set(...)`, and, for args handed over as `**dict`, the string keys
-    of dict literals in the modules that open spans (`f"moe_{k}"` over
-    `MOE_STATS` and `MOE_ZERO_STATS`, spelled out, and the `eva_*`
-    counts, which `ops/eva_attention.py eva_row_counts` names)."""
+    of dict literals in the modules that open spans and in the op
+    modules that count a dispatch's args for the scheduler to set
+    (`Op.dispatch_group_of`, ISSUE 59); `f"moe_{k}"` over `MOE_STATS`
+    and `MOE_ZERO_STATS`, spelled out, and the `eva_*` counts, which
+    `ops/eva_attention.py eva_row_counts` names."""
     from flexflow_tpu.ops.eva_attention import eva_row_counts
     from flexflow_tpu.ops.routed_experts import MOE_STATS, MOE_ZERO_STATS
 
@@ -121,8 +123,10 @@ def emitted():
                     if _callee(n) == "span":
                         opened.update(_strs(n.args[:1]))
                     args.update(k.arg for k in n.keywords if k.arg)
-            if opened:
-                spans |= opened
+            spans |= opened
+            if opened or any(isinstance(n, ast.FunctionDef)
+                             and n.name == "dispatch_group_of"
+                             for n in ast.walk(tree)):
                 args.update(k for n in ast.walk(tree)
                             if isinstance(n, ast.Dict)
                             for k in _strs(n.keys))

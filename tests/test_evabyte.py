@@ -241,9 +241,10 @@ def test_a_twin_without_pools_admits_by_slot_whatever_pool_was_asked(model):
                               devices=jax.devices()[:1])
     assert twin.kv_block_bytes == 0 and not twin._pools
     assert twin.num_blocks == 1 + 2 * (D.p // D.w)
-    assert twin.eva == {"window": D.w, "chunk": D.c,
-                        "store_rows": D.p // D.c, "layers": D.L}
-    assert twin.eva_state_bytes == 2 * fam.eva_state_bytes(CFG) // 4
+    assert twin.groups["eva"].geometry == {
+        "window": D.w, "chunk": D.c, "store_rows": D.p // D.c,
+        "layers": D.L, "state_bytes": 2 * fam.eva_state_bytes(CFG) // 4}
+    assert list(twin.groups) == ["eva"]
     assert twin.rstate_bytes == 0 and twin.has_slot_state
     sched = ContinuousScheduler(twin)
     try:

@@ -240,14 +240,17 @@ def served():
     """One scheduler over the toy model and a scenario with chunked
     prefill of prompts well past `index_topk`, a full-prompt prefix hit
     (copy-on-write), a partial hit and a slot reused after a finished
-    request: (recorded rows, handles, scheduler stats, dispatch
-    spans)."""
-    from flexflow_tpu.serving.scheduler import ContinuousScheduler
+    request: (recorded rows, handles, scheduler stats with the
+    replica's own under `replica`, dispatch spans).  The engine is the
+    one replica of a front, so what the replica forwards is seen."""
+    from flexflow_tpu.serving.front import ServingFront
+    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
 
     ff = holder()
-    sched = ContinuousScheduler.from_trained(
+    front = ServingFront(lambda replica_id, survivors=None: PagedKVDecodeModel(
         ff, batch_slots=3, page_size=4, num_blocks=60, prefill_chunk=4,
-        prefix_cache=True, devices=jax.devices()[:1])
+        prefix_cache=True, devices=jax.devices()[:1]), 1)
+    sched = front.replicas[0].scheduler
     rec = Recorder(sched)
     first = next_span_id()
     try:
@@ -264,9 +267,9 @@ def served():
             h.wait(120)
         handles.append(sched.generate_async(c[:5], 4, 0.0))  # a slot again
         handles[-1].wait(120)
-        stats = sched.stats()
+        stats = dict(sched.stats(), replica=front.stats()["replicas"][0])
     finally:
-        sched.close(10)
+        front.close()
     mine = [r for r in spans() if r.span_id > first and r.name in (
         "sched.prefill.dispatch", "sched.decode.dispatch")]
     return rec.rows, handles, stats, mine
@@ -323,11 +326,16 @@ def test_dispatch_spans_carry_the_selections_counters(served):
     dsa = stats["dsa"]
     assert dsa["topk"] == D.topk and dsa["layers"] == D.L \
         and dsa["full_layers"] == full
-    assert dsa["dsa_keys_selected"] == sum(
-        r.args["dsa_keys_selected"] for r in mine)
-    assert dsa["dsa_keys_selected"] < dsa["dsa_keys_live"]
-    assert dsa["dsa_rows_past_topk"] > 0
-    assert dsa["dsa_keys_read"] == sum(r.args["dsa_keys_read"] for r in mine)
+    # the sums, by program; and the replica forwards them (ISSUE 59)
+    assert stats["replica"]["dsa"] == dsa
+    for program in ("prefill", "decode"):
+        args = [r.args for r in mine if r.name == f"sched.{program}.dispatch"]
+        assert dsa[f"{program}_dispatches"] == len(args) > 0
+        for k in ("dsa_keys_selected", "dsa_keys_read"):
+            assert dsa[f"{program}_{k}"] == sum(a[k] for a in args)
+        assert dsa[f"{program}_dsa_keys_selected"] \
+            < dsa[f"{program}_dsa_keys_live"]
+        assert dsa[f"{program}_dsa_rows_past_topk"] > 0
 
 
 @pytest.mark.parametrize("plan,chunk,want", [
@@ -342,15 +350,17 @@ def test_keys_read_follow_the_plan_in_force(plan, chunk, want):
     walk."""
     import types
 
-    from flexflow_tpu.ops.mla import selection_counts
-    from flexflow_tpu.serving.scheduler import PagedKVDecodeModel
+    from flexflow_tpu.ops.mla import MLAttention
 
-    model = types.SimpleNamespace(
-        dsa={"topk": 8, "layers": 4, "full_layers": 2}, page_size=4,
-        max_seq=64, batch_slots=4, _selection_counts=selection_counts,
-        _dsa_plan=lambda s, n: plan)
-    rows = PagedKVDecodeModel.dsa_rows(
-        model, [6, 40, 60, 0], [min(chunk, 4)] * 3 + [0], chunk)
+    ops = [types.SimpleNamespace(
+        params=types.SimpleNamespace(index_topk=8, indexer=role),
+        selected_plan=lambda s, n: plan)
+        for role in ("full", "shared", "full", "shared")]
+    told = MLAttention.dispatch_group_of(
+        ops, family="toy", batch_slots=4, page_size=4, max_seq=64,
+        prefill_chunk=chunk, state_bytes=0)
+    assert told.geometry == {"topk": 8, "full_layers": 2}
+    rows = told.counts([6, 40, 60, 0], [min(chunk, 4)] * 3 + [0], chunk)
     assert rows["dsa_keys_read"] == want
     assert rows["dsa_keys_scored"] == 2 * rows["dsa_keys_live"]
 

@@ -263,10 +263,11 @@ def test_state_predicates_keep_pages_and_rings_apart(model):
     ring = window_ring_rows(W, 4, PAGE)
     assert twin._state["attn_1"]["win_k"].shape == (2, D.kvh, ring, D.hd)
     assert "block_table" not in twin._state["attn_1"]
-    assert twin.swa == {"window": W, "ring": ring,
-                        "layers": D.window_layers}
-    assert twin.swa_state_bytes == fam.swa_state_bytes(CFG, ring) * 2 \
-        // CFG["deployment"]["serving_slots"]
+    assert twin.groups["swa"].geometry == {
+        "window": W, "ring": ring, "layers": D.window_layers,
+        "state_bytes": fam.swa_state_bytes(CFG, ring) * 2
+        // CFG["deployment"]["serving_slots"]}
+    assert list(twin.groups) == ["swa"]
     assert twin.rstate_bytes == 0 and twin.has_slot_state
     assert twin.kv_block_bytes == fam.latent_block_bytes(CFG)
     assert twin._reset_slot_fn is None  # masked by position: nothing to zero
@@ -343,7 +344,10 @@ def test_dispatch_spans_carry_the_counters_and_the_twin_its_bytes():
     assert decode[-1]["swa_rows_live"] == D.window_layers * W  # position 21
     (r,) = replicas
     assert r["swa"]["state_bytes"] == fam.swa_state_bytes(CFG, ring)
-    assert r["swa"]["dispatches"] == len(decode) + len(prefill)
+    assert (r["swa"]["decode_dispatches"], r["swa"]["prefill_dispatches"]) \
+        == (len(decode), len(prefill))
+    assert r["swa"]["prefill_swa_rows_live"] == sum(
+        a["swa_rows_live"] for a in prefill)
     assert (r["swa"]["window"], r["swa"]["ring"]) == (W, ring)
     # what the readers' floors count
     assert fam.swa_read_bytes(CFG, 3) == 3 * 2 * D.kvh * D.hd * 4

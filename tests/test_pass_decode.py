@@ -112,8 +112,9 @@ def pass_and_steps(served_model):
         prefill_chunk=CHUNK, prefix_cache=False, devices=jax.devices()[:1])
     assert model.max_seq == 64 and model.prefill_passes == 1
     assert model.page_size in (4, 16)
-    if model.eva:
-        assert model.eva["window"] == 16 and model.eva["chunk"] == 4
+    if "eva" in model.groups:
+        eva = model.groups["eva"].geometry
+        assert eva["window"] == 16 and eva["chunk"] == 4
     starts = np.array([s for s, _ in ROWS.values()], np.int32)
     fed = np.array([n for _, n in ROWS.values()], np.int32)
     slots, width = len(ROWS), model.max_blocks_per_seq

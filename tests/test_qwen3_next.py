@@ -209,14 +209,19 @@ def test_scheduler_counts_rows_advanced_against_rows_touched(
     kernel is in."""
     _, _, stats = served
     r = stats["rstate"]
-    assert 3 * r["dispatches"] > r["rows_live"] > 0
-    if request.node.callspec.params["served"] == "kernel":
-        assert r["rows_touched"] == r["rows_live"]
-    else:
-        assert r["rows_touched"] == 3 * r["dispatches"]
+    for program in ("decode", "prefill"):  # (the sums are by program)
+        n, live, touched = (r[f"{program}_{k}"] for k in (
+            "dispatches", "rstate_rows_live", "rstate_rows_touched"))
+        assert 3 * n >= live > 0
+        if request.node.callspec.params["served"] == "kernel":
+            assert touched == live
+        else:
+            assert touched == 3 * n
+    assert 3 * r["decode_dispatches"] > r["decode_rstate_rows_live"]
     # 6 linear layers x (4 heads x 8 x 8 + a tail of 3 x 64) float32, 3 slots
-    assert r["bytes"] == 6 * (4 * 8 * 8 + 3 * 64) * 4 * 3
-    assert r["bytes"] == fam.rstate_row_bytes(CFG) * 3
+    assert r["state_bytes"] == 6 * (4 * 8 * 8 + 3 * 64) * 4 * 3
+    assert r["state_bytes"] == fam.rstate_row_bytes(CFG) * 3
+    assert r["layers"] == 6
     assert stats["prefix_cache"]["hits"] == 0
 
 
@@ -238,8 +243,11 @@ def test_front_reports_rstate_and_the_dispatch_spans_carry_it(plan):
     # row, the plain recurrence every slot
     touched = 1 if plan == "kernel" else slots
     for r in replicas:
-        assert r["rstate"]["rows_touched"] == touched * r["rstate"]["dispatches"]
-        assert r["rstate"]["rows_live"] == r["rstate"]["dispatches"]
+        for program in ("decode", "prefill"):
+            n = r["rstate"][f"{program}_dispatches"]
+            assert n > 0
+            assert r["rstate"][f"{program}_rstate_rows_touched"] == touched * n
+            assert r["rstate"][f"{program}_rstate_rows_live"] == n
     mine = [r for r in spans() if r.span_id > first]
     twin = next(r for r in mine if r.name == "serve.build_twin")
     assert twin.args["rstate_bytes"] == fam.rstate_row_bytes(CFG) * slots
@@ -256,7 +264,7 @@ def test_front_reports_rstate_and_the_dispatch_spans_carry_it(plan):
 
 
 def test_twin_asks_each_step_length_for_its_recurrence(monkeypatch):
-    """`rstate_rows_touched` answers per program: a chunk too long for
+    """`rstate_rows_touched` is counted per program: a chunk too long for
     the kernel keeps the scan (every slot) while the decode step skips
     idle rows."""
     from flexflow_tpu.ops.gated_delta_net import GatedDeltaNet
@@ -269,10 +277,15 @@ def test_twin_asks_each_step_length_for_its_recurrence(monkeypatch):
                                num_blocks=40, prefill_chunk=4,
                                prefix_cache=False,
                                devices=jax.devices()[:1])
-    assert model.rstate_rows_touched(2, 1) == 2
-    assert model.rstate_rows_touched(2, 4) == 3
+    at, two = [5, 9, 0], [1, 1, 0]  # two of three rows advance
+    assert model.dispatch_counts(at, two, 1) == {
+        "rstate": {"rstate_rows_live": 2, "rstate_rows_touched": 2}}
+    assert model.dispatch_counts(at, two, 4) == {
+        "rstate": {"rstate_rows_live": 2, "rstate_rows_touched": 3}}
     linear = D.L - D.full_layers
-    assert model.gdn_ops == {"gdn_kernel_ops": 0, "gdn_plain_ops": linear}
+    assert model.groups["rstate"].geometry == {
+        "gdn_kernel_ops": 0, "gdn_plain_ops": linear, "layers": linear,
+        "state_bytes": model.rstate_bytes}
 
 
 # -- 2b. the twin's chunk pass against C single steps ------------------------------
