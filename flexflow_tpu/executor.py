@@ -1065,7 +1065,7 @@ class GraphExecutor:
                 v = jnp.transpose(v, TO_NCHW_PERM)
             ins.append(v)
         nt = _num_trainable(op)
-        ws: List[jax.Array] = []
+        ws: List[jax.Array] = self._borrowed(op, ctx)
         for i, spec in enumerate(op.weight_specs):
             src = ctx["weights"] if i < nt else ctx["state"]
             w = src[op.name][spec.name]
@@ -1365,3 +1365,14 @@ class GraphExecutor:
 
         with self.mesh:
             return jax.jit(step, donate_argnums=(1,))
+
+    def _borrowed(self, op: Op, ctx: Dict) -> List[jax.Array]:
+        """The weights `op` reads of other ops' (`Op.borrowed_weights`:
+        a tied head reads the embedding's table), as compute copies, at
+        the head of its weight list.  The leaf is the owner's: one entry
+        of the tree, one buffer, and a gradient that sums its readers.
+        (Defined last, and called where `ws` starts: a line added
+        above `op.forward`, or a column moved on it, changes every
+        kernel's compile-cache key, ROADMAP D16.)"""
+        return [ctx["to_compute"](ctx["weights"][owner][name])
+                for owner, name in op.borrowed_weights()]

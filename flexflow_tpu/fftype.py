@@ -115,6 +115,7 @@ class OperatorType(enum.Enum):
     KIMI_DELTA_ATTENTION = "kimi_delta_attention"
     SHORT_CONV = "short_conv"
     EVA_ATTENTION = "eva_attention"
+    MAMBA2_MIXER = "mamba2_mixer"
     # every pass's output of a repeated region, stacked (pcg LoopRegion)
     LOOP_PASSES = "loop_passes"
     # Elementwise

@@ -1931,3 +1931,39 @@ class FFModel:
 
     def get_parameter(self, op_name: str, weight_name: str) -> np.ndarray:
         return np.asarray(self._weights[op_name][weight_name])
+
+    # -- layer API, continued.  (Defined last: a line added above
+    #    `train_step` can move a kernel's compile-cache key, ROADMAP D16)
+    def tied_dense(self, input: ParallelTensor, tied_to: str,
+                   name: Optional[str] = None) -> ParallelTensor:
+        """A bias-free dense layer whose kernel IS the table of the
+        embedding op named `tied_to`, transposed (a tied output head):
+        `out_dim` = the table's entries.  One leaf in the weights tree,
+        under the embedding's name; `set_weights` / `get_weights` carry
+        it once and its gradient is the sum of both uses."""
+        from .ops.dense import Embedding
+
+        owner = next((op for op in self.layers.topo_order()
+                      if op.name == tied_to), None)
+        if not isinstance(owner, Embedding):
+            raise ValueError(
+                f"tied_dense: {tied_to!r} is not an embedding of this model")
+        entries, channels = owner.weights[0].shape.logical_shape
+        if input.shape.logical_shape[-1] != channels:
+            raise ValueError(
+                f"tied_dense: {tied_to}'s table is [{entries}, {channels}] "
+                f"and the input's last axis {input.shape.logical_shape[-1]}")
+        p = LinearParams(entries, False, ActiMode.NONE, owner.params.dtype)
+        return self._add(Linear(p, [input], name=self._name("dense", name),
+                                tied_to=tied_to))
+
+    def mamba2_mixer(self, input, params, name=None,
+                     slot_state: bool = False):
+        """A Mamba-2 mixer (ops/mamba2.py): `params` is a `Mamba2Params`;
+        `slot_state` builds the serving twin's op, which carries a conv
+        tail and a state-space state a slot."""
+        from .ops.mamba2 import Mamba2Mixer
+
+        return self._add(Mamba2Mixer(
+            params, [input], name=self._name("mamba2_mixer", name),
+            slot_state=slot_state))

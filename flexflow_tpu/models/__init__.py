@@ -17,4 +17,5 @@ SERVED_BUILDERS = (
     "transformer.build_gpt", "kimi_k2.build_kimi_k2",
     "qwen3_next.build_qwen3_next", "ouro.build_ouro",
     "longcat_flash.build_longcat_flash", "evabyte.build_evabyte",
-    "laguna.build_laguna", "glm_dsa.build_glm_dsa")
+    "laguna.build_laguna", "glm_dsa.build_glm_dsa",
+    "granite_hybrid.build_granite_hybrid")

@@ -15,7 +15,8 @@ The grammar (docs/OBSERVABILITY.md "Device scopes"):
   `GraphExecutor._exec_op`;
 * a part inside an op: `scope(<part>)`, one of `PARTS`, only where a
   question of the records needs it (`RoutedExperts`, the attention ops,
-  `GatedDeltaNet`, `KimiDeltaAttention`, `ShortConv`, `EvaAttention`;
+  `GatedDeltaNet`, `Mamba2Mixer`, `KimiDeltaAttention`, `ShortConv`,
+  `EvaAttention`;
   `cast_weights`
   where the executor casts an op's weight to the compute precision);
 * what is not an op: `scope(<one of NOT_OPS>)`: `loss`, `optimizer`,

@@ -88,14 +88,14 @@ class MultiHeadAttentionParams:
     # out = wo (attn * sigmoid(x wg)[..., None])  (`output_gate` is the
     # gate a channel that `wq` projects)
     head_gate: bool = False
-    # YaRN on the rotary channels (ops/rope.py yarn_frequencies);
-    # factor 1 = plain RoPE.  cos and sin are multiplied by
-    # `rope_attention_factor` (the published config's attention_factor)
+    # YaRN on the rotary channels (ops/rope.py yarn_frequencies; factor 1
+    # = plain RoPE); cos and sin times the published `attention_factor`
     rope_factor: float = 1.0
     rope_original_max: int = 4096
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     rope_attention_factor: float = 1.0
+    softmax_scale: Optional[float] = None  # None: 1 / sqrt(head channels)
 
     @property
     def k_channels(self) -> int:
@@ -554,7 +554,7 @@ class MultiHeadAttention(Op):
         wo = weights[3]
         with scope("proj"):
             qh, kh, vh, gate, bo = self._project(q, k, v, weights)
-        scale = 1.0 / np.sqrt(p.k_channels)
+        scale = p.softmax_scale or 1.0 / np.sqrt(p.k_channels)
 
         def out_of(ctx):
             with scope("out"):

@@ -29,7 +29,7 @@ from jax import lax
 from ..fftype import ActiMode, AggrMode, DataType, OperatorType
 from ..initializer import DEFAULT_BIAS_INIT, DEFAULT_WEIGHT_INIT
 from ..tensor import ParallelDim, ParallelTensorShape
-from .op import Op, ShapeError, WeightSpec
+from .op import Op, ShapeError, ShardConfig, WeightSpec
 
 
 def apply_activation(x: jax.Array, act: ActiMode) -> jax.Array:
@@ -55,11 +55,32 @@ class LinearParams:
 
 
 class Linear(Op):
+    """`tied_to` names an Embedding whose `[entries, channels]` table
+    this op reads as its kernel, transposed: `entries` = `out_channels`.
+    The op then owns no weight (`borrowed_weights`): the table is one
+    leaf of the weights tree, under the embedding's name."""
+
     op_type = OperatorType.LINEAR
+
+    def __init__(self, params, inputs, name="", shard=ShardConfig(),
+                 tied_to: str = ""):
+        # must exist before Op.__init__ runs make_weight_specs
+        self._tied_to = tied_to
+        super().__init__(params, inputs, name=name, shard=shard)
+
+    def ctor_kwargs(self) -> dict:
+        return {"tied_to": self._tied_to} if self._tied_to else {}
+
+    def borrowed_weights(self):
+        return ((self._tied_to, "weight"),) if self._tied_to else ()
 
     def infer_output_shapes(self, input_shapes):
         (ishape,) = input_shapes
         p: LinearParams = self.params
+        if self._tied_to and (p.use_bias or not self.shard.is_trivial()):
+            raise ShapeError(
+                f"{self.name}: a kernel tied to {self._tied_to}'s table "
+                "has no bias and is sharded as the table is, not inside")
         dims = list(ishape.dims)
         data_dims = [d for d in dims if not d.is_replica_dim]
         in_dim = data_dims[-1]
@@ -81,6 +102,8 @@ class Linear(Op):
         p: LinearParams = self.params
         data_dims = [d for d in ishape.dims if not d.is_replica_dim]
         in_dim = data_dims[-1]
+        if self._tied_to:
+            return []
         batch_degree = 1
         for d in data_dims[:-1]:
             batch_degree *= d.degree
@@ -107,6 +130,8 @@ class Linear(Op):
     def forward(self, inputs, weights, *, training=False, rng=None):
         (x,) = inputs
         p: LinearParams = self.params
+        if self._tied_to:  # the table, [out_channels, in]
+            return [jnp.einsum("...e,ve->...v", x, weights[0])]
         kernel = weights[0]
         y = jnp.matmul(x, kernel)
         if p.use_bias:

@@ -66,7 +66,7 @@ from ..initializer import (DEFAULT_WEIGHT_INIT, ConstantInitializer,
 from ..obs.scopes import scope
 from ..tensor import ParallelDim, ParallelTensorShape
 from .chunked_delta_rule import l2norm, pick_chunk
-from .op import DispatchGroup, Op, ShapeError, ShardConfig, WeightSpec
+from .op import Op, ShapeError, ShardConfig, WeightSpec, rstate_group
 from .pallas.chunked_delta_rule import CHUNKED_RULES
 from .pallas.gated_delta_rule import gated_delta_rule, pick_recurrence
 from .short_conv import causal_depthwise_conv
@@ -307,21 +307,7 @@ class GatedDeltaNet(Op):
         runs), every slot under the plain recurrence.  `gdn_kernel_ops`
         layers take the kernel in every step program, `gdn_plain_ops`
         the plain scan in some."""
-        lengths = (1, *((prefill_chunk,) if prefill_chunk else ()))
-        in_kernel = [[op.recurrence_plan(s) == "kernel" for s in lengths]
-                     for op in ops]
-        skips_idle = {s: all(op[i] for op in in_kernel)
-                      for i, s in enumerate(lengths)}
-        kernels = sum(all(op) for op in in_kernel)
-        built = {"gdn_kernel_ops": kernels,
-                 "gdn_plain_ops": len(ops) - kernels}
-
-        def counts(positions, counts, chunk):
-            live = len([n for n in counts if n])
-            return {"rstate_rows_live": live,
-                    "rstate_rows_touched": (live if skips_idle.get(chunk)
-                                            else batch_slots)}
-
-        return DispatchGroup(
-            geometry=built, counts=counts,
-            build_args={"rstate_bytes": state_bytes, **built})
+        return rstate_group(
+            ops, lambda op, s: op.recurrence_plan(s) == "kernel", "gdn",
+            batch_slots=batch_slots, prefill_chunk=prefill_chunk,
+            state_bytes=state_bytes)

@@ -270,6 +270,15 @@ class Op:
         outs = ",".join(str(t.shape) for t in self.outputs)
         return f"{self.name}({ins} -> {outs})"
 
+    def borrowed_weights(self) -> Tuple[Tuple[str, str], ...]:
+        """(op name, weight name) of every trainable weight this op
+        READS and another op OWNS (a head tied to the embedding's
+        table).  The executor hands them over BEFORE the op's own, in
+        this order; the leaf stays one leaf of the weights tree, under
+        its owner's name, and its gradient is jax's sum over its
+        readers."""
+        return ()
+
 
 # ---------------------------------------------------------------------------
 # Shared shape-rule helpers
@@ -300,3 +309,33 @@ def trainable_weight_count(op: Op) -> int:
     running stats).  Ops opt in via a num_trainable_weights method."""
     fn = getattr(op, "num_trainable_weights", None)
     return fn() if fn is not None else len(op.weight_specs)
+
+
+def rstate_group(ops: Sequence[Op], takes_kernel, prefix: str, *,
+                 batch_slots: int, prefill_chunk: int,
+                 state_bytes: int) -> DispatchGroup:
+    """The `rstate` group of ops whose per-slot state is a recurrence's
+    (`GatedDeltaNet`, `Mamba2Mixer`): `rstate_rows_live`, the rows a
+    dispatch had to advance, against `rstate_rows_touched`, the rows
+    whose state the program of that step length read and wrote: the
+    advanced rows where `takes_kernel(op, step_tokens)` holds for every
+    layer (a kernel that skips idle rows; asked once for each step
+    length the twin runs), every slot otherwise.  `<prefix>_kernel_ops`
+    layers take the kernel in every step program, `<prefix>_plain_ops`
+    the plain recurrence in some."""
+    lengths = (1, *((prefill_chunk,) if prefill_chunk else ()))
+    in_kernel = [[takes_kernel(op, s) for s in lengths] for op in ops]
+    skips_idle = {s: all(op[i] for op in in_kernel)
+                  for i, s in enumerate(lengths)}
+    kernels = sum(all(op) for op in in_kernel)
+    built = {f"{prefix}_kernel_ops": kernels,
+             f"{prefix}_plain_ops": len(ops) - kernels}
+
+    def counts(positions, counts, chunk):
+        live = len([n for n in counts if n])
+        return {"rstate_rows_live": live,
+                "rstate_rows_touched": (live if skips_idle.get(chunk)
+                                        else batch_slots)}
+
+    return DispatchGroup(geometry=built, counts=counts,
+                         build_args={"rstate_bytes": state_bytes, **built})
